@@ -29,12 +29,11 @@ app_name, app_args = sys.argv[5], list(sys.argv[6:])
 
 jax.config.update("jax_platforms", "cpu")
 if nprocs > 1:
-    # gloo needs the distributed client on older jax (0.4.x requires it at
-    # backend init): only request it when this worker actually joins a group
+    # only request gloo when this worker actually joins a group
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
 from twtml_tpu.utils.backend import set_cpu_device_count_hint  # noqa: E402
 
-set_cpu_device_count_hint(ndev)  # jax_num_cpu_devices or XLA_FLAGS fallback
+set_cpu_device_count_hint(ndev)
 
 if nprocs > 1:
     app_args += [

@@ -30,7 +30,7 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 from twtml_tpu.utils.backend import set_cpu_device_count_hint  # noqa: E402
 
-set_cpu_device_count_hint(2)  # jax_num_cpu_devices or XLA_FLAGS fallback
+set_cpu_device_count_hint(2)
 
 
 def main() -> None:

@@ -1,6 +1,6 @@
 """Bounded ingest backpressure (ISSUE 4 tentpole, part 1): the intake queue
-was the pipeline's last unbounded buffer — a source burst or a slow tunnel
-phase grew host RSS without limit. `--maxQueueRows` bounds it by ROW count
+was the pipeline's last unbounded buffer — a source burst or a slow
+stretch downstream grew host RSS without limit. `--maxQueueRows` bounds it by ROW count
 with two policies (block: producers wait; shed-oldest: oldest rows drop,
 counted), `--shedPolicy` picks one, and the parity law holds on survivors:
 shedding from the FRONT never reorders the rows that remain."""

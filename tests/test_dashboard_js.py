@@ -149,7 +149,7 @@ def test_config_frame_resets_counters_and_rebuilds_iframes():
 
 def test_metrics_frame_updates_observability_panel():
     """Metrics frames (telemetry/metrics.py snapshots) drive the pipeline
-    panel: tunnel badge with phase class, rtt, wire MB, rss, fetch depth."""
+    panel: fetch-health badge with phase class, rtt, wire MB, rss, fetch depth."""
     h = dashboard()
     h.ws.server_open()
     h.ws.server_message(frame(
@@ -158,8 +158,8 @@ def test_metrics_frame_updates_observability_panel():
         gauges={"host.rss_mb": 512.5, "fetch.queue_depth": 7},
         health={"phase": "degraded", "rtt_ms": 412.5, "transitions": 3},
     ))
-    assert h.el("tunnelPhase").text == "degraded"
-    assert "degraded" in h.el("tunnelPhase").class_set
+    assert h.el("fetchPhase").text == "degraded"
+    assert "degraded" in h.el("fetchPhase").class_set
     assert h.el("rttMs").text == "412.5"
     assert h.el("wireMb").text == "2.5"
     assert h.el("rssMb").text == "512.5"
@@ -170,9 +170,9 @@ def test_metrics_frame_updates_observability_panel():
         jsonClass="Metrics", counters={}, gauges={},
         health={"phase": "healthy", "rtt_ms": 71.0, "transitions": 4},
     ))
-    assert h.el("tunnelPhase").text == "healthy"
-    assert "healthy" in h.el("tunnelPhase").class_set
-    assert "degraded" not in h.el("tunnelPhase").class_set
+    assert h.el("fetchPhase").text == "healthy"
+    assert "healthy" in h.el("fetchPhase").class_set
+    assert "degraded" not in h.el("fetchPhase").class_set
 
 
 def test_metrics_frame_updates_ingest_guard_tiles():

@@ -599,7 +599,12 @@ def test_lockstep_peer_death_watchdog_aborts_survivor():
         for p in procs:
             p.kill()
     assert procs[1].returncode == 42  # the hard kill
-    assert out1.strip() == ""  # it never got to print
+    # it never got to print its result (the installed gloo prints its own
+    # "[Gloo] Rank ..." connection banner on stdout — not the worker's)
+    assert [
+        line for line in out1.splitlines()
+        if line.strip() and not line.startswith("[Gloo]")
+    ] == []
     assert procs[0].returncode == 0, f"survivor crashed:\n{err0[-3000:]}"
     res = json.loads(out0.strip().splitlines()[-1])
     assert res["terminated"], "survivor never left the lockstep loop"

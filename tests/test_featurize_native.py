@@ -303,7 +303,7 @@ def test_block_parity_empty_block():
 
 @needs_native
 def test_block_packed_wire_byte_parity():
-    """featurize → pack: the packed wire (the bytes the tunnel sees) is
+    """featurize → pack: the packed wire (the bytes that get uploaded) is
     byte-identical with the fused featurize on vs off."""
     feat = Featurizer(now_ms=NOW)
     block = block_from([rt("packed row %d" % i) for i in range(32)])
@@ -323,7 +323,7 @@ def test_block_packed_wire_byte_parity():
 @pytest.mark.parametrize("form", ["flat", "sharded", "group"])
 def test_packed_wire_parity_every_form(form, codec):
     """featurize on vs off → every packed wire form × codec: the bytes
-    the tunnel sees are identical (flat pack, shard-aligned pack,
+    that get uploaded are identical (flat pack, shard-aligned pack,
     coalesced group pack)."""
     from twtml_tpu.features.batch import (
         align_ragged_shards, pack_ragged_group, pack_ragged_sharded,

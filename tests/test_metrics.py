@@ -1,4 +1,4 @@
-"""Metrics registry + tunnel-health classifier (telemetry/metrics.py):
+"""Metrics registry + fetch-health classifier (telemetry/metrics.py):
 counter/gauge/histogram semantics, snapshot isolation, and health-phase
 transitions on synthetic latency series — the observability layer's
 contracts, independent of any pipeline."""
@@ -7,7 +7,7 @@ import threading
 
 from twtml_tpu.telemetry.metrics import (
     MetricsRegistry,
-    TunnelHealthMonitor,
+    FetchHealthMonitor,
 )
 
 
@@ -103,31 +103,31 @@ def test_counter_thread_safety():
 
 def test_health_steady_rtt_stays_healthy():
     reg = MetricsRegistry()
-    mon = TunnelHealthMonitor(registry=reg)
+    mon = FetchHealthMonitor(registry=reg)
     for i in range(50):
         mon.observe(0.07 + 0.005 * (i % 3), now=float(i))
-    assert mon.phase == TunnelHealthMonitor.HEALTHY
+    assert mon.phase == FetchHealthMonitor.HEALTHY
     assert mon.transitions == []
     assert mon.observations["degraded"] == 0
 
 
 def test_health_degrades_and_recovers():
     reg = MetricsRegistry()
-    mon = TunnelHealthMonitor(registry=reg)
+    mon = FetchHealthMonitor(registry=reg)
     t = iter(range(1000))
     for _ in range(20):  # healthy baseline ~70 ms
         mon.observe(0.07, now=float(next(t)))
-    assert mon.phase == TunnelHealthMonitor.HEALTHY
+    assert mon.phase == FetchHealthMonitor.HEALTHY
     for _ in range(20):  # stall burst: 600 ms medians
         mon.observe(0.6, now=float(next(t)))
-    assert mon.phase == TunnelHealthMonitor.DEGRADED
+    assert mon.phase == FetchHealthMonitor.DEGRADED
     for _ in range(40):  # back to RTT scale
         mon.observe(0.07, now=float(next(t)))
-    assert mon.phase == TunnelHealthMonitor.HEALTHY
+    assert mon.phase == FetchHealthMonitor.HEALTHY
     phases = [p for _, p in mon.transitions]
     assert phases == ["degraded", "healthy"]
     # transition count landed in the registry too
-    assert reg.counter("tunnel.phase_transitions").snapshot() == 2
+    assert reg.counter("fetch_health.phase_transitions").snapshot() == 2
     assert mon.observations["degraded"] > 0
     summary = mon.summary()
     assert summary["phase"] == "healthy" and summary["transitions"] == 2
@@ -135,20 +135,20 @@ def test_health_degrades_and_recovers():
 
 
 def test_health_floor_keeps_cpu_jitter_healthy():
-    """µs-scale latencies (CPU backend, fake models) sit far below tunnel-RTT
-    scale: relative jitter there must never classify as degraded."""
-    mon = TunnelHealthMonitor(registry=MetricsRegistry())
+    """µs-scale latencies (CPU backend, fake models) sit under the floor:
+    relative jitter there must never classify as degraded."""
+    mon = FetchHealthMonitor(registry=MetricsRegistry())
     for i in range(100):
         mon.observe(1e-6 if i % 2 else 2e-5, now=float(i))  # 20x swings
-    assert mon.phase == TunnelHealthMonitor.HEALTHY
+    assert mon.phase == FetchHealthMonitor.HEALTHY
     assert mon.transitions == []
 
 
 def test_health_hysteresis_no_flap_on_single_outlier():
-    mon = TunnelHealthMonitor(registry=MetricsRegistry())
+    mon = FetchHealthMonitor(registry=MetricsRegistry())
     for i in range(30):
         mon.observe(0.07, now=float(i))
     mon.observe(5.0, now=31.0)  # one stalled fetch
     # a single outlier does not move the rolling median past the threshold
-    assert mon.phase == TunnelHealthMonitor.HEALTHY
+    assert mon.phase == FetchHealthMonitor.HEALTHY
     assert mon.transitions == []

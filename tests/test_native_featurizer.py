@@ -143,3 +143,94 @@ def test_custom_label_fn_uses_native_hashing_with_python_labels():
     assert rows_as_dicts(fast)[:3] == rows_as_dicts(slow)[:3]
     np.testing.assert_array_equal(fast.label[:3], slow.label[:3])
     assert list(fast.label[:3]) == [1.0, 0.0, 1.0]
+
+
+# ---------------------------------------------------------------------------
+# the build stamp: a library is loaded only when it was built on THIS host
+# from the CURRENT sources with the CURRENT flags (mtime is not evidence —
+# a copy of the tree carries another machine's -march=native code along)
+
+@pytest.fixture()
+def scratch_lib(tmp_path, monkeypatch):
+    """Point the loader at a scratch library path with a fresh loader
+    state; the real library's state comes back afterwards."""
+    real_lib, real_state = native._LIB, (native._lib, native._tried)
+    assert native.get_lib() is not None
+    monkeypatch.setattr(native, "_LIB", str(tmp_path / "libfasthash.so"))
+    native._lib, native._tried = None, False
+    builds = []
+
+    def fake_build(stamp):
+        # "compile": the real, already-built image + the stamp the loader
+        # asked for (no g++ run per test)
+        import shutil
+
+        builds.append(stamp)
+        shutil.copyfile(real_lib, native._LIB)
+        with open(native._LIB + ".stamp", "w", encoding="utf-8") as fh:
+            json.dump(stamp, fh)
+        return True
+
+    yield fake_build, builds
+    native._lib, native._tried = real_state
+    native.rebind_flags()
+
+
+@pytest.mark.parametrize("tamper", ["sources", "flags", "host", "no-stamp"])
+def test_foreign_stamp_is_rebuilt_never_loaded(scratch_lib, monkeypatch, tamper):
+    fake_build, builds = scratch_lib
+    monkeypatch.setattr(native, "_build", fake_build)
+    # a library that was NOT built here: garbage bytes (dlopen would fail,
+    # like another CPU's instructions would SIGILL) beside a stamp naming
+    # other sources / other flags / another host — or no stamp at all
+    with open(native._LIB, "wb") as fh:
+        fh.write(b"not built on this machine")
+    stamp = native.expected_stamp()
+    if tamper == "flags":
+        stamp["flags"] = [*stamp["flags"], "-mavx512bw"]
+    elif tamper != "no-stamp":
+        stamp[tamper] = "0" * 64
+    if tamper != "no-stamp":
+        with open(native._LIB + ".stamp", "w", encoding="utf-8") as fh:
+            json.dump(stamp, fh)
+    assert native.read_stamp() != native.expected_stamp()
+    lib = native.get_lib()
+    assert lib is not None  # the garbage was replaced, not dlopen'ed
+    assert builds == [native.expected_stamp()]
+    assert native.read_stamp() == native.expected_stamp()
+
+
+def test_matching_stamp_loads_without_a_build(scratch_lib, monkeypatch):
+    fake_build, builds = scratch_lib
+    fake_build(native.expected_stamp())  # "built here", stamped
+    builds.clear()
+    monkeypatch.setattr(native, "_build", fake_build)
+    assert native.get_lib() is not None
+    assert builds == []
+    live = native.require_live()
+    assert live["symbols"] == list(native.SYMBOLS)
+
+
+def test_hidden_compiler_means_no_library_and_a_loud_smoke(
+        scratch_lib, monkeypatch, tmp_path, caplog):
+    """With g++ off PATH nothing can be built: the apps degrade (get_lib()
+    is None, WARNING logged) but the measurement gate fails loudly instead
+    of timing the Python fallback."""
+    import logging
+
+    empty = tmp_path / "empty-bin"
+    empty.mkdir()
+    monkeypatch.setenv("PATH", str(empty))
+    with caplog.at_level(logging.WARNING, logger="twtml_tpu.features.native"):
+        assert native.get_lib() is None
+    assert any("build failed" in r.message for r in caplog.records)
+    assert not os.path.exists(native._LIB)
+    with pytest.raises(RuntimeError, match="refusing to measure"):
+        native.require_live()
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
+    import chip_smoke
+
+    with pytest.raises(RuntimeError, match="native fast path is not live"):
+        chip_smoke.phase_native()

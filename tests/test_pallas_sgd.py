@@ -74,6 +74,7 @@ def test_matches_xla_loop(kw):
         step_size=0.005,
         l2_reg=kw.get("l2_reg", 0.0),
         convergence_tol=kw.get("convergence_tol", 0.001),
+        interpret=True,
     )
     # bf16 storage of the design matrix: integer bigram counts are exact,
     # the scaled numerics round — the documented ~1e-3 relative envelope
@@ -94,7 +95,7 @@ def test_padding_rows_do_not_leak():
         np.concatenate([np.asarray(f), np.zeros((16,) + f.shape[1:], f.dtype)])
         for f in small
     ))
-    kw = dict(num_iterations=10, step_size=0.005)
+    kw = dict(num_iterations=10, step_size=0.005, interpret=True)
     w_a, _ = pallas_sgd.fused_dense_sgd(
         dense_design(small), jnp.asarray(small.label), jnp.asarray(small.mask),
         zero_weights(F_TEXT), **kw)
@@ -113,7 +114,7 @@ def test_masked_rows_zeroed_defensively():
     x_dirty[14:] = np.nan  # NaN garbage: multiply-masking would poison all
     label_dirty = np.asarray(batch.label).copy()
     label_dirty[14:] = np.inf
-    kw = dict(num_iterations=10, step_size=0.005)
+    kw = dict(num_iterations=10, step_size=0.005, interpret=True)
     w_clean, _ = pallas_sgd.fused_dense_sgd(
         jnp.asarray(x), jnp.asarray(batch.label), jnp.asarray(batch.mask),
         zero_weights(F_TEXT), **kw)
@@ -127,9 +128,21 @@ def test_empty_batch_no_update():
     batch = make_batch(n=0)
     w, preds = pallas_sgd.fused_dense_sgd(
         dense_design(batch), jnp.asarray(batch.label), jnp.asarray(batch.mask),
-        zero_weights(F_TEXT), num_iterations=10, step_size=0.005)
+        zero_weights(F_TEXT), num_iterations=10, step_size=0.005,
+        interpret=True)
     assert np.all(np.asarray(w) == 0.0)
     np.testing.assert_allclose(np.asarray(preds), 0.0, atol=1e-7)
+
+
+def test_interpret_is_the_callers_explicit_choice():
+    """No caller gets interpret mode without asking for it: the keyword is
+    required, so a chip run can never silently measure the interpreter."""
+    batch = make_batch()
+    with pytest.raises(TypeError, match="interpret"):
+        pallas_sgd.fused_dense_sgd(
+            dense_design(batch), jnp.asarray(batch.label),
+            jnp.asarray(batch.mask), zero_weights(F_TEXT),
+            num_iterations=1, step_size=0.005)
 
 
 def test_supports_gating():

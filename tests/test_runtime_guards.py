@@ -1,7 +1,7 @@
 """Runtime self-healing guards below the source layer (ISSUE 2): the fetch
 watchdog (deadline / bounded re-issue / clean abort over the pooled
 device_get), the publish circuit breaker (a dead dashboard stops taxing
-the hot path), degraded-tunnel series shedding, and the satellite fixes
+the hot path), degraded-phase series shedding, and the satellite fixes
 (stale checkpoint tmp sweep, wedged-producer stop warning, --webTimeout)."""
 
 import logging
@@ -71,7 +71,7 @@ def test_fetch_deadline_derives_from_health_rtt(monkeypatch):
 
     # no samples yet: maximally patient (first fetch of a run)
     assert FetchWatchdog(H(0)).deadline() == FETCH_DEADLINE_MAX_S
-    # healthy tunnel RTT (~70ms): the floor binds
+    # a 70 ms median fetch latency: the floor binds
     assert FetchWatchdog(H(70)).deadline() == FETCH_DEADLINE_MIN_S
     # multi-second stall regime: the cap binds
     assert FetchWatchdog(H(10_000)).deadline() == FETCH_DEADLINE_MAX_S
@@ -278,7 +278,7 @@ def test_breaker_keeps_hot_path_fast_when_dashboard_is_dead():
     assert reg.counter("publish.web.dropped").snapshot() >= 20
 
 
-def test_series_sheds_to_every_nth_when_tunnel_degraded():
+def test_series_sheds_to_every_nth_when_fetch_health_degraded():
     from twtml_tpu.telemetry.session_stats import SERIES_SHED_EVERY, SessionStats
 
     closed = "http://127.0.0.1:9"
@@ -533,7 +533,7 @@ def test_cadence_disagreement_abort_dumps_postmortem_bundle(
 
 def test_superbatcher_flush_refunds_undelivered_groups():
     """Grouped dispatches (the coalesced-wire path included) that are
-    in flight when the tunnel wedges: flush drops them AND refunds every
+    in flight when the transport wedges: flush drops them AND refunds every
     batch they carried."""
     import time as _time
 

@@ -414,7 +414,7 @@ def test_config_flag_resolution():
 
     conf = ConfArguments().parse(["--seconds", "0"])
     assert conf.wireCodec == "auto"
-    assert conf.effective_wire_codec() == "off"  # auto = off, tunnel pending
+    assert conf.effective_wire_codec() == "off"  # auto = off, on-chip verdict pending
     conf = ConfArguments().parse(["--seconds", "0", "--wireCodec", "dict"])
     assert conf.effective_wire_codec() == "dict"
     # dict + superbatch resolves the coalesced group wire

@@ -9,8 +9,8 @@ pooled arena lease. How much host does that buy — on the pack stage
 alone, and diluted across the full host chain (bytes → packed wire)?
 
 Method: the house harness only (tools/pairedbench.py) — interleaved
-single passes, paired per-round ratios (each pair shares a tunnel-phase
-window), byte parity asserted per window (the assembler may never change
+single passes, paired per-round ratios (each pair shares one window),
+byte parity asserted per window (the assembler may never change
 the wire). Three windows per regime (object / block ingest):
 
 - **pack stage** — pack-only passes (k=1 flat + K-group coalesced),
@@ -22,8 +22,8 @@ the wire). Three windows per regime (object / block ingest):
   assembler cannot touch).
 - **CPU control + modeled upload** — the chain ratio is wire-neutral by
   construction (identical bytes both arms), so the modeled window adds
-  EXACT upload arithmetic wire_bytes/BW across the measured 45-70 MB/s
-  envelope to show the end-to-end dilution an upload-bound tunnel pays.
+  EXACT upload arithmetic wire_bytes/BW across a MODELED 45-70 MB/s
+  envelope to show the end-to-end dilution an upload-bound link would pay.
 
 Pack-only arms retire each lease immediately (nothing is in flight), so
 the arms measure the steady state: recycled arena buffers, zero fresh
@@ -45,7 +45,8 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# the tunnel's measured upload-bandwidth envelope (BENCHMARKS.md r2)
+# MODELED upload-bandwidth envelope (an upload-bound link; not a property
+# of any machine the ledger runs on)
 UPLOAD_MBS_SWEEP = (45.0, 55.0, 70.0)
 
 

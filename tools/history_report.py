@@ -155,7 +155,7 @@ def render(s: dict) -> str:
         f"{s['rss_slope_mb_per_min']:.3f} MB/min"
     )
     if s["phase_intervals"]:
-        out.append("  tunnel health phases:")
+        out.append("  fetch health phases:")
         for iv in s["phase_intervals"]:
             mins = (iv["end_ms"] - iv["start_ms"]) / 60000.0
             out.append(

@@ -25,7 +25,12 @@ from .findings import Finding, Malformed
 from .rules import FileContext, RepoContext, all_rules, rule_ids
 from .suppress import scan as scan_suppressions
 
-_SKIP_DIRS = {".git", "__pycache__", ".pytest_cache", "node_modules", "doc"}
+# generated / gitignored trees are not the repo's code (a tree unpacked
+# under target/ for a chip rehearsal must not be judged twice)
+_SKIP_DIRS = {
+    ".git", "__pycache__", ".pytest_cache", "node_modules", "doc",
+    "target", "chiprun_out", ".jax_cache",
+}
 _DEFAULT_BASELINE = os.path.join(os.path.dirname(__file__), "baseline.json")
 
 

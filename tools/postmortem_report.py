@@ -121,7 +121,7 @@ def render(s: dict) -> str:
     health = s["health"]
     if health:
         out.append(
-            f"  tunnel: {health.get('phase', '?')} "
+            f"  fetch health: {health.get('phase', '?')} "
             f"(rtt {health.get('rtt_ms', 0)} ms, "
             f"{health.get('transitions', 0)} transitions)"
         )

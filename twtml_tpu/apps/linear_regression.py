@@ -54,7 +54,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
     session = SessionStats(conf).open() if lead else None
 
     log.info("Initializing TPU-native streaming model...")
-    select_backend(conf)
+    device = select_backend(conf)
     featurizer = Featurizer.from_conf(conf)
     model, row_multiple = build_model(conf)
     import jax
@@ -89,10 +89,12 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
     )
 
     # tenant count in the run record: callers (bench suite, tests) can see
-    # how many models this run's one jit program trained
+    # how many models this run's one jit program trained — and which device
+    # it ran on, so a CPU run is never read as a device result
     totals = {
         "count": 0, "batches": 0,
         "tenants": int(getattr(model, "num_tenants", 1) or 1),
+        "device": device,
     }
 
     # checkpoint/resume (upgrade over the reference, SURVEY.md §5.4)

@@ -52,7 +52,7 @@ log = get_logger("apps.logistic")
 def run(conf: ConfArguments, max_batches: int = 0) -> dict:
     lead = init_distributed(conf)  # before any backend use (apps/common)
     session = SessionStats(conf).open() if lead else None
-    select_backend(conf)
+    device = select_backend(conf)
     featurizer = Featurizer.from_conf(conf)
     featurizer.label_fn = sentiment_label
     featurizer.batch_label_fn = sentiment_labels  # C hot path, same labels
@@ -87,6 +87,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
     totals = {
         "count": 0, "batches": 0,
         "tenants": int(getattr(model, "num_tenants", 1) or 1),
+        "device": device,
     }
 
     # checkpoint/resume — same upgrade as the flagship app (SURVEY.md §5.4)

@@ -53,7 +53,7 @@ def run(conf: ConfArguments, started=None, stop_event=None,
             "checkpoint snapshots (train with --checkpointDir/"
             "--checkpointEvery to produce them)"
         )
-    select_backend(conf)
+    device = select_backend(conf)
     install_trace(conf)
     install_chaos(conf)
     install_blackbox(conf)
@@ -128,7 +128,9 @@ def run(conf: ConfArguments, started=None, stop_event=None,
     finally:
         promoter.stop()
         plane.stop()
-        stats = plane.stats()
+        # the run record names the device it served from (not part of
+        # the published Serving view)
+        stats = dict(plane.stats(), device=device)
         server.stop()
         from ..telemetry import trace as pipeline_trace
 

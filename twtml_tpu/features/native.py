@@ -2,15 +2,25 @@
 
 Builds the shared library on first use (g++ is in the image; no network or
 pybind11 required), loads it via ctypes, and exposes ``fasthash_batch``
-filling padded numpy buffers in place. Falls back silently when a compiler
-isn't available — features/hashing.py stays the semantic ground truth and
-the parity test asserts the two implementations agree bigram-for-bigram.
+filling padded numpy buffers in place. The library is only ever loaded when
+the stamp beside it says it was built ON THIS HOST from the CURRENT sources
+with the CURRENT flags (``-march=native`` code from another CPU dies with
+SIGILL; an mtime survives a copy, a stamp of what was compiled does not).
+Where no compiler is available the apps degrade — at WARNING — to the
+Python path: features/hashing.py stays the semantic ground truth and the
+parity test asserts the two implementations agree bigram-for-bigram.
+Measurement entry points (``chip_smoke.py``, ``bench.py``) call
+``require_live()`` and fail instead of timing the fallback.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import hashlib
+import json
 import os
+import platform
 import subprocess
 import threading
 
@@ -75,11 +85,48 @@ def _sources_ok() -> bool:
     return all(os.path.exists(s) for s in _SRCS)
 
 
-def _sources_newer_than_lib() -> bool:
-    lib_mtime = os.path.getmtime(_LIB)
-    return any(
-        os.path.exists(s) and os.path.getmtime(s) > lib_mtime for s in _SRCS
-    )
+def _host_id() -> str:
+    """What ``-march=native`` resolves against: the machine type plus the
+    CPU model and ISA flag set the kernel reports."""
+    lines = []
+    try:
+        with open("/proc/cpuinfo", encoding="utf-8") as fh:
+            for line in fh:
+                if line.startswith(("model name", "flags", "Features")):
+                    lines.append(line.strip())
+                elif not line.strip() and lines:
+                    break  # first processor block only
+    except OSError:
+        lines = [platform.processor(), platform.node()]
+    return hashlib.sha256(
+        "\n".join([platform.machine(), *lines]).encode()
+    ).hexdigest()
+
+
+def expected_stamp() -> dict:
+    """The identity a loadable library must carry: a hash of the tracked
+    sources, the compile flags, and the host it was compiled on."""
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as fh:
+            h.update(os.path.basename(src).encode() + b"\0" + fh.read())
+    return {
+        "sources": h.hexdigest(),
+        "flags": _build_flags(),
+        "host": _host_id(),
+    }
+
+
+def read_stamp() -> "dict | None":
+    """The stamp beside the library, or None (no library / never stamped /
+    unreadable — all of which mean "do not load it")."""
+    if not os.path.exists(_LIB):
+        return None
+    try:
+        with open(_LIB + ".stamp", encoding="utf-8") as fh:
+            return json.load(fh)
+    except (OSError, ValueError):
+        return None
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -105,25 +152,32 @@ _assemble_missing = False
 _featurize_missing = False
 
 
-def _build() -> bool:
+def _build(stamp: dict) -> bool:
     # build to a temp path and os.replace: dlopen caches by inode, so a
     # rebuild in place would hand a retrying loader the same stale image —
-    # the replace gives the retry a fresh inode (and never destroys a
-    # still-loadable old library when the compile itself fails)
-    tmp = _LIB + ".tmp"
+    # the replace gives the retry a fresh inode. The stamp goes first and
+    # comes back last, so a half-finished build is never taken for a
+    # stamped one.
+    # Temp names carry the pid: several processes of one run may find the
+    # library missing at once, and each must compile into its own file.
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
+        with contextlib.suppress(FileNotFoundError):
+            os.remove(_LIB + ".stamp")
         subprocess.run(
-            ["g++", *_build_flags(), "-o", tmp, *_SRCS],
+            ["g++", *stamp["flags"], "-o", tmp, *_SRCS],
             check=True, capture_output=True, timeout=120,
         )
         os.replace(tmp, _LIB)
+        with open(tmp, "w", encoding="utf-8") as fh:
+            json.dump(stamp, fh)
+        os.replace(tmp, _LIB + ".stamp")
+        log.info("native library built on this host from native/*.cpp")
         return True
-    except Exception as exc:
+    except (OSError, subprocess.SubprocessError) as exc:
         log.warning("native featurizer build failed (%s); using python path", exc)
-        try:
+        with contextlib.suppress(OSError):
             os.remove(tmp)
-        except OSError:
-            pass
         return False
 
 
@@ -134,27 +188,22 @@ def get_lib() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB) or _sources_newer_than_lib():
-            if not _sources_ok() or not _build():
-                return None
+        if not _sources_ok():
+            log.warning(
+                "native/*.cpp sources missing: no library can be built or "
+                "trusted; using python path"
+            )
+            return None
+        stamp = expected_stamp()
+        if read_stamp() != stamp and not _build(stamp):
+            return None
         try:
             lib = _load(_LIB)
         except AttributeError:
-            # stale .so from before a symbol was added (mtime-equal artifact
-            # copy defeats the rebuild check): rebuild once (to a fresh
-            # inode — see _build) and retry
-            if _sources_ok() and _build():
-                try:
-                    lib = _load(_LIB)
-                except AttributeError:
-                    lib = _try_degraded_load()
-                except OSError as exc:
-                    log.warning("native featurizer load failed (%s)", exc)
-                    return None
-            else:
-                # cannot rebuild: keep the stale library usable for the
-                # symbols it HAS — only the wire entry degrades (loudly)
-                lib = _try_degraded_load()
+            # the CURRENT sources lack a symbol the binder expects (a
+            # development-time mismatch; a rebuild would give the same
+            # image): keep the symbols it has, flag the rest loudly
+            lib = _try_degraded_load()
             if lib is None:
                 return None
         except OSError as exc:
@@ -615,6 +664,37 @@ def rebind_flags() -> None:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+# every entry point the C sources export; the fast host path is LIVE only
+# when all of them bound
+SYMBOLS = (
+    "fasthash_batch", "pad_units_batch", "pad_units_batch_u8",
+    "lexicon_score_batch", "parse_tweet_block", "parse_tweet_block_wire",
+    "digram_encode", "wire_assemble", "featurize_wire",
+)
+
+
+def require_live() -> dict:
+    """The measurement entry points' gate (``chip_smoke.py``, ``bench.py``):
+    return {"lib", "stamp", "symbols"} when the library was built on this
+    host from the tracked sources and EVERY symbol bound; raise otherwise —
+    a host-bound number taken on the Python fallback is a tenth of the real
+    one with nothing said."""
+    lib = get_lib()
+    if lib is None:
+        raise RuntimeError(
+            "native fast path is not live: native/libfasthash.so could not "
+            "be built or loaded on this host (is g++ on PATH?) — refusing "
+            "to measure the Python fallback"
+        )
+    missing = [name for name in SYMBOLS if not hasattr(lib, name)]
+    if missing:
+        raise RuntimeError(
+            f"native fast path is degraded: symbol(s) {missing} did not "
+            "bind — refusing to measure the fallback"
+        )
+    return {"lib": _LIB, "stamp": read_stamp(), "symbols": list(SYMBOLS)}
 
 
 def _thread_count_from_env() -> int:

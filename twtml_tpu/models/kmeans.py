@@ -135,9 +135,7 @@ class StreamingKMeans:
                     _update_step,
                     decay_factor=cfg[0], time_unit=cfg[1], axis_name=data_axis,
                 )
-                from ..utils import shard_map
-
-                self._step = jax.jit(shard_map()(
+                self._step = jax.jit(jax.shard_map(
                     body,
                     mesh=self.mesh,
                     # centers/weights replicated; rows sharded over 'data'

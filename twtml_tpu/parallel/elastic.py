@@ -100,9 +100,9 @@ from ..utils import get_logger
 log = get_logger("parallel.elastic")
 
 # transport-level heartbeat detection is DISABLED (the app watchdog owns
-# death detection); the interval still paces the agent's liveness RPCs
-_HEARTBEAT_INTERVAL_S = 10
-_HEARTBEAT_DISABLED = 1_000_000
+# death detection): a member is only declared dead by the coordination
+# service after this many seconds without a heartbeat
+_HEARTBEAT_TIMEOUT_S = 10_000_000
 
 # beacon request cap: one JSON line per connection, bounded
 _BEACON_MAX_BYTES = 65536
@@ -440,7 +440,7 @@ class ElasticRuntime:
         caches cleared so the next jax call builds the new world's
         backend."""
         from jax._src import distributed as _dist
-        from jax._src.lib import xla_extension as _xe
+        from jaxlib import _jax
 
         members = sorted(int(u) for u in members)
         if self.uid not in members:
@@ -455,12 +455,11 @@ class ElasticRuntime:
         state = _dist.global_state
         if pid == 0:
             self._spawn_service_host(port, nprocs)
-        client = _xe.get_distributed_runtime_client(
+        client = _jax.get_distributed_runtime_client(
             coordinator, pid,
             init_timeout=_init_timeout_s(),
             shutdown_timeout=5,
-            heartbeat_interval=_HEARTBEAT_INTERVAL_S,
-            max_missing_heartbeats=_HEARTBEAT_DISABLED,
+            heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
             shutdown_on_destruction=False,
             use_compression=True,
         )

@@ -38,8 +38,7 @@ import time
 
 # mirrors parallel/elastic.py: detection stays OFF — the app-level
 # lockstep watchdog is the one death detector
-_HEARTBEAT_INTERVAL_S = 10
-_HEARTBEAT_DISABLED = 1_000_000
+_HEARTBEAT_TIMEOUT_S = 10_000_000
 
 LINGER_ENV = "TWTML_ELASTIC_SERVICE_LINGER_S"
 LINGER_DEFAULT_S = 45.0
@@ -60,12 +59,11 @@ def main(argv: "list[str] | None" = None) -> None:
     linger_s = float(args[4]) if len(args) > 4 else float(
         os.environ.get(LINGER_ENV, "") or LINGER_DEFAULT_S
     )
-    import jaxlib.xla_extension as _xe  # jaxlib only: no jax, no backend
+    from jaxlib import _jax  # jaxlib only: no jax, no backend
 
-    service = _xe.get_distributed_runtime_service(
+    service = _jax.get_distributed_runtime_service(
         f"[::]:{port}", nprocs,
-        heartbeat_interval=_HEARTBEAT_INTERVAL_S,
-        max_missing_heartbeats=_HEARTBEAT_DISABLED,
+        heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
     )
     last_ok = time.monotonic()
     while True:

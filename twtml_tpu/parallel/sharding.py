@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-
-from ..utils.backend import axis_size as _axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..features.batch import (
@@ -225,7 +223,7 @@ def _make_feature_sharded_step(
 
         # ---- Gram (dual) basis when it applies (see docstring) ----------
         b_local = mask.shape[0]
-        b_global = b_local * _axis_size(data_axis)
+        b_global = b_local * lax.axis_size(data_axis)
         gram = (
             dtype == jnp.float32
             and fits_gram(b_global, f_text_local, num_iterations)
@@ -448,9 +446,7 @@ class ParallelSGDModel:
                         weights, unpack_batch(pb.buffer, pb.layout)
                     )
 
-            from ..utils import shard_map
-
-            sharded = shard_map()(
+            sharded = jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(self._w_spec, _pspecs_for(batch_cls, self.data_axis)),
@@ -488,9 +484,7 @@ class ParallelSGDModel:
 
                 in_spec = _stacked(_pspecs_for(batch_cls, self.data_axis))
 
-            from ..utils import shard_map
-
-            sharded = shard_map()(
+            sharded = jax.shard_map(
                 scanned,
                 mesh=self.mesh,
                 in_specs=(self._w_spec, in_spec),

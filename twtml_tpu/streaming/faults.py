@@ -10,9 +10,9 @@ unchanged; a crash loses the in-flight iterator exactly like a dropped
 socket, so delivery gaps behave like the real failure mode.
 
 ``ChaosInjector`` (``--chaos SPEC``) extends the same idea BELOW the source
-layer, to the external dependencies the tunnel facts make the real failure
-domain (BENCHMARKS.md "Measurement integrity": stalls burst for minutes,
-RTT 50–90 ms): seeded latency spikes / multi-second stalls / exceptions at
+layer, to the external dependencies that are the real failure domain (a
+host fetch that stalls or is lost, a dispatch that raises, a dashboard that
+hangs): seeded latency spikes / multi-second stalls / exceptions at
 three injection points —
 
 - ``fetch``  — the pooled ``device_get``s (FetchPipeline / SuperBatcher),
@@ -152,7 +152,7 @@ class ChaosInjector:
     each injection point: it may sleep (latency spike / stall) and/or raise
     ``InjectedFault`` according to the parsed rules. Thread-safe — the
     pooled fetch calls it from worker threads; sleeps happen outside the
-    lock so concurrent fetches stall independently, like real tunnel
+    lock so concurrent fetches stall independently, like real fetch
     stalls. Deterministic for a given seed and per-target call sequence."""
 
     def __init__(self, spec: str):

@@ -56,10 +56,10 @@ class Series:
 class Metrics:
     """Pipeline metrics snapshot — an ADDITIVE message type (no reference
     equivalent) carrying the process-local registry (telemetry/metrics.py)
-    and the tunnel-health summary to the dashboard's observability panel.
+    and the fetch-health summary to the dashboard's observability panel.
     Rides the jsonClass-discriminated wire like Series, so legacy dashboards
     ignore it. ``counters``/``gauges`` are flat name→value maps; ``health``
-    is TunnelHealthMonitor.summary() (phase, rtt_ms, transitions,
+    is FetchHealthMonitor.summary() (phase, rtt_ms, transitions,
     observations); ``histograms`` (r8) maps name → derived
     count/mean/p50/p95/p99 (the latency tile — raw buckets stay
     registry-side)."""

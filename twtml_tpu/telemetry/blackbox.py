@@ -9,7 +9,7 @@ a SIGTERM) triggers ``abort_dump``, which writes the bundle next to the
 checkpoint directory: config snapshot, last-verified-checkpoint note, the
 event ring (trace spans when ``--trace`` is live, health transitions, chaos
 firings, guard events, per-tick sideband rows), a metrics-registry
-snapshot, the tunnel-health summary, and the last per-host sideband view.
+snapshot, the fetch-health summary, and the last per-host sideband view.
 ``tools/postmortem_report.py`` renders it (exit 2 on malformed bundles,
 like trace_report).
 

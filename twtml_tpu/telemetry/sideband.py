@@ -9,7 +9,7 @@ and zero added collectives** — the sideband is a compact fixed-width float
 vector of host-side bookkeeping that rides the EXISTING per-tick cadence
 allgather (the flags array widens; no new collective is ever issued), and
 every value in it is read from state the pipeline already maintains
-(the stage clock below, the metrics registry, the tunnel-health monitor).
+(the stage clock below, the metrics registry, the fetch-health monitor).
 
 Three pieces:
 
@@ -55,7 +55,7 @@ FIELDS = (
     "fetch_ms",
     "publish_ms",
     "queue_rows",       # intake queue depth (ingest.queue_rows gauge)
-    "fetch_rtt_ms",     # tunnel-health rolling median
+    "fetch_rtt_ms",     # fetch-health rolling median
     "rollbacks",        # divergence-sentinel rollbacks (model.rollbacks)
     "rows_shed",        # ingest.rows_shed counter
     "health_degraded",  # 0 healthy / 1 degraded

@@ -12,7 +12,7 @@ the r2/r3 bottleneck ladder:
 
 Attribution rule: with enough history (``min_history`` ticks), the stage
 whose current value deviates most ABOVE that host's own rolling median —
-self-relative, like the tunnel-health classifier, so a host that is simply
+self-relative, like the fetch-health classifier, so a host that is simply
 configured slower than its peers doesn't drown the signal of what CHANGED.
 Cold (or when no host stage moved), the largest absolute stage time wins;
 and when the host's stage clocks account for almost none of its tick time,

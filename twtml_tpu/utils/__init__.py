@@ -4,7 +4,6 @@ from .clock import now_ms, now_s
 from .backend import (
     force_virtual_cpu_devices,
     set_cpu_device_count_hint,
-    shard_map,
 )
 
 __all__ = [
@@ -14,5 +13,4 @@ __all__ = [
     "now_s",
     "force_virtual_cpu_devices",
     "set_cpu_device_count_hint",
-    "shard_map",
 ]

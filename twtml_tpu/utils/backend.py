@@ -2,114 +2,83 @@
 
 One place for the "force an n-device virtual CPU mesh" dance used by the
 driver entry (``__graft_entry__.dryrun_multichip``) and the CLI backend
-selector (``apps.linear_regression.select_backend``): both need to set
+selector (``apps.common.select_backend``): both need to set
 ``jax_num_cpu_devices`` *before* any backend initialization and degrade
 gracefully when one is already live. tests/conftest.py deliberately does not
 import this (it must configure jax before the repo is even on sys.path), but
 follows the same recipe.
+
+Also the one place that says where compiled programs are cached
+(``configure_compile_cache``) and which device a run's arrays land on
+(``device_identity``) — every entry point, the bench children and
+``chip_smoke.py`` share both through ``select_backend``.
 """
 
 from __future__ import annotations
 
+import os
 
-def backends_initialized() -> bool | None:
-    """True/False when jax can report whether a backend is initialized in
-    this process (after which device-count configs can no longer change);
-    None when the probe (a jax-internal symbol, no stability guarantee) is
-    unavailable — the helpers below then fall back to public-API behavior:
-    attempt the ``jax_num_cpu_devices`` update first and catch the
-    RuntimeError jax raises for it post-init (``jax_platforms`` never
-    raises, so callers that only flip the platform must verify the outcome
-    via ``jax.default_backend()`` instead)."""
-    try:
-        from jax._src import xla_bridge
-
-        return bool(xla_bridge.backends_are_initialized())
-    except Exception:  # lawcheck: disable=TW005 -- documented probe contract: None means 'jax-internal symbol unavailable', callers fall back to public-API behavior (docstring above)
-        return None
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed, in-checkout, never a temp name / pid / time: the directory is part
+# of how a later process finds the entries, so a path that moves never hits
+_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def shard_map():
-    """The shard_map entry point across jax versions: top-level
-    ``jax.shard_map`` where it exists (newer jax), the experimental module
-    otherwise (the 0.4.3x line) — same keyword surface
-    (``mesh``/``in_specs``/``out_specs``) either way."""
+def backends_initialized() -> bool:
+    """Whether a backend is initialized in this process (after which
+    device-count configs can no longer change)."""
+    from jax._src import xla_bridge
+
+    return bool(xla_bridge.backends_are_initialized())
+
+
+def configure_compile_cache() -> str:
+    """Point jax's persistent compilation cache somewhere a LATER process
+    finds again, and return that directory. Where ``JAX_COMPILATION_CACHE_DIR``
+    is set the operator placed the cache (jax reads the variable itself) and
+    nothing is set in code; otherwise the one fixed in-checkout directory."""
+    placed = os.environ.get(COMPILE_CACHE_ENV, "")
+    if placed:
+        return placed
     import jax
 
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _sm
-
-    # check_rep=False: the 0.4.x replication checker false-positives on the
-    # scan-carry + psum pattern our superbatch programs use ("mismatched
-    # replication types"); it is a static lint, not a semantic change, and
-    # later jax versions accept the same programs with checking on
-    return functools.partial(_sm, check_rep=False)
+    if jax.config.jax_compilation_cache_dir != _COMPILE_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", _COMPILE_CACHE_DIR)
+    return _COMPILE_CACHE_DIR
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside a shard_map body, across
-    jax versions: ``lax.axis_size`` where it exists, the axis environment on
-    the 0.4.3x line. Always a Python int (shape arithmetic depends on it)."""
-    from jax import lax
+def device_identity() -> dict:
+    """The device this process's un-placed arrays and programs land on, as
+    jax reports it — what every run record and benchmark line names so a
+    CPU run can never be read as a device result. Honors
+    ``jax_default_device`` (how ``chip_smoke.py`` runs its CPU reference
+    inside a process that holds the chip). Initializes the backend."""
+    import jax
 
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    from jax._src import core
-
-    return int(core.get_axis_env().axis_sizes[axis_name])
-
-
-def pcast_varying(x, axis_name):
-    """``lax.pcast(..., to="varying")`` where it exists (the new shard_map
-    varying-manual-axes system); identity on older jax, whose experimental
-    shard_map (run with ``check_rep=False`` — see ``shard_map``) has no
-    replication types to convert between."""
-    from jax import lax
-
-    fn = getattr(lax, "pcast", None)
-    if fn is None:
-        return x
-    return fn(x, axis_name, to="varying")
-
-
-def set_host_device_count_flag(n_devices: int) -> None:
-    """Pre-init fallback for jax builds without the ``jax_num_cpu_devices``
-    config option (it landed after the 0.4.3x line this image may carry):
-    the classic ``XLA_FLAGS --xla_force_host_platform_device_count`` route,
-    which the CPU backend reads at initialization. Replaces any existing
-    count flag so repeated calls converge instead of appending."""
-    import os
-
-    flag = f"--xla_force_host_platform_device_count={n_devices}"
-    parts = [
-        f for f in os.environ.get("XLA_FLAGS", "").split()
-        if not f.startswith("--xla_force_host_platform_device_count")
-    ]
-    parts.append(flag)
-    os.environ["XLA_FLAGS"] = " ".join(parts)
+    dev = jax.config.jax_default_device
+    if isinstance(dev, str):
+        dev = jax.devices(dev)[0]
+    if dev is None:
+        dev = jax.devices()[0]
+    return {
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "count": len(jax.devices(dev.platform)),
+    }
 
 
 def _set_cpu_device_count(n_devices: int) -> bool:
-    """``jax_num_cpu_devices`` when this jax has it, XLA_FLAGS otherwise.
-    Returns False when a live backend makes the change impossible."""
+    """``jax_num_cpu_devices``; False when a live backend with a different
+    CPU device count makes the change impossible."""
     import jax
 
     try:
         jax.config.update("jax_num_cpu_devices", n_devices)
         return True
-    except AttributeError:
-        # older jax: no such config option — the env-var route below works
-        # as long as no backend is initialized (callers checked)
-        set_host_device_count_flag(n_devices)
-        return True
     except RuntimeError:
-        # probe was unavailable and a backend with a different CPU device
-        # count is already live
         return False
 
 

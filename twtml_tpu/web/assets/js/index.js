@@ -36,7 +36,7 @@
     const gauges = json.gauges || {};
     const health = json.health || {};
     const phase = health.phase || "—";
-    const badge = document.getElementById("tunnelPhase");
+    const badge = document.getElementById("fetchPhase");
     badge.textContent = phase;
     badge.classList.toggle("healthy", phase === "healthy");
     badge.classList.toggle("degraded", phase === "degraded");
