@@ -178,8 +178,8 @@ int32_t fasthash_batch(const uint16_t* units, const int64_t* offsets,
 
 // Ragged→padded copy for the on-device featurization wire format
 // (UnitBatch): concatenated code units → [padded_rows, l_max] uint16 with
-// zero padding, plus per-row unit counts. Row-sliced memcpys beat numpy's
-// vectorized gather ~10x at tweet sizes. Rows in [batch, padded_rows) are
+// zero padding, plus per-row unit counts (row-sliced memcpys instead of
+// numpy's vectorized gather). Rows in [batch, padded_rows) are
 // zeroed here too, so the caller can hand in uninitialized buffers.
 // ascii_lower != 0 folds 'A'-'Z' to lowercase during the copy: the Python
 // caller then only pays str.lower() for texts containing non-ASCII chars
@@ -221,9 +221,8 @@ int32_t pad_units_batch(const uint16_t* units, const int64_t* offsets,
 
 // uint8 variant of pad_units_batch: the narrow wire format for batches the
 // caller KNOWS are byte-ranged (every row ASCII-flagged by the parser /
-// isascii() on the host path) — host→device transfer is the streaming hot
-// loop's bottleneck and the units buffer is its largest tensor, so the
-// narrow pad halves it with zero extra scans. Units >= 256 must not reach
+// isascii() on the host path) — the units buffer is the largest tensor on
+// the host→device wire, so the narrow pad halves it with zero extra scans. Units >= 256 must not reach
 // this function (the caller's ascii gate guarantees < 128).
 int32_t pad_units_batch_u8(const uint16_t* units, const int64_t* offsets,
                            int32_t batch, int32_t padded_rows, int32_t l_max,

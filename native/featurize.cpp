@@ -1,9 +1,8 @@
 // One-pass host featurize (r18) — the fused numeric+label+mask+wire
 // emitter behind --featurizeNative.
 //
-// BENCHMARKS r17 left the host chain featurize-dominated: between the
-// native parse (PR 6) and the native pack (PR 14), the featurize stage
-// still ran several separate numpy passes (float64 scale + f32 cast,
+// Between the native parse (PR 6) and the native pack (PR 14), the
+// featurize stage still ran several separate numpy passes (float64 scale + f32 cast,
 // label/mask fills, the ragged-wire zero+copy) plus — on object ingest —
 // four per-tweet Python traversals. This entry collapses the array half
 // of that stage into ONE C sweep: given the batch's encoded units +

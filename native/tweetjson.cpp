@@ -2,9 +2,9 @@
 //
 // The reference delegates ingestion to Twitter4j/Spark receivers (external
 // JVM dependencies, SURVEY.md §2.4); our replay/stream sources parse
-// newline-delimited tweet JSON. CPython json.loads + object assembly tops
-// out near ~90k tweets/s on one core — an order of magnitude below the
-// compute pipeline — so this parser extracts exactly the fields the
+// newline-delimited tweet JSON. CPython json.loads + object assembly is
+// the object path's per-tweet host cost (rates on this machine: not
+// measured, PERF.md), so this parser extracts exactly the fields the
 // featurizer reads (MllibHelper.scala:42-95: the retweeted status' text,
 // retweet_count, user counts, timestamp) straight into columnar buffers,
 // applying the isRetweet + retweet-count-interval filter in-line

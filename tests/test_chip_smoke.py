@@ -61,6 +61,8 @@ def test_phases_tiny_on_cpu(tmp_path, capsys):
         n_batches=3, n_devices=4, master="local[4]",
     )
     assert multi["totals"]["batches"] == 3
+    assert multi["totals"]["device_span"] == {"weights": 4, "batch": 4}
+    assert "device_span" not in one["totals"]  # single-device model
     text = capsys.readouterr().out
     assert "max|dw|/max|w| = 0.000e+00" in text  # same backend: exact
     assert "multichip: OK — 4 devices" in text
@@ -127,6 +129,48 @@ def test_bench_exits_nonzero_with_no_metric_when_device_child_fails():
             assert "value" not in json.loads(line)
 
 
+def test_mesh_devices_follow_the_platform_the_run_record_names(monkeypatch):
+    """The smoke's CPU reference runs inside a process whose first jax
+    device is the chip, with ``jax_default_device`` pinned to the CPU: the
+    identity in the run record, the local[N] cap and the mesh's devices
+    must all come from the pinned platform, never from ``jax.devices()``
+    (a 'CPU reference' sharded over the chips would agree vacuously)."""
+    import collections
+
+    import jax
+
+    import twtml_tpu.parallel as parallel
+    from twtml_tpu.apps import common
+    from twtml_tpu.config import ConfArguments
+    from twtml_tpu.utils import backend
+
+    Dev = collections.namedtuple("Dev", "platform device_kind id")
+    fake = {
+        None: [Dev("tpu", "TPU v5 lite", i) for i in range(4)],
+        "tpu": [Dev("tpu", "TPU v5 lite", i) for i in range(4)],
+        "cpu": [Dev("cpu", "cpu", i) for i in range(2)],
+    }
+    monkeypatch.setattr(jax, "devices", lambda platform=None: fake[platform])
+    monkeypatch.setattr(
+        parallel, "make_mesh", lambda num_data, devices: list(devices)
+    )
+    conf = ConfArguments().parse(["--master", "local[*]"])
+    prev = jax.config.jax_default_device
+    try:
+        assert backend.device_identity() == {
+            "platform": "tpu", "kind": "TPU v5 lite", "count": 4,
+        }
+        assert common.build_mesh(conf) == fake["tpu"]
+        jax.config.update("jax_default_device", "cpu")
+        assert backend.device_identity() == {
+            "platform": "cpu", "kind": "cpu", "count": 2,
+        }
+        assert common.mesh_shape(conf) == 2
+        assert common.build_mesh(conf) == fake["cpu"]
+    finally:
+        jax.config.update("jax_default_device", prev)
+
+
 # ---------------------------------------------------------------------------
 # the compile cache can be placed from outside (utils/backend.py)
 
@@ -139,8 +183,10 @@ def test_compile_cache_env_wins_and_code_sets_nothing(monkeypatch):
     jax.config.update("jax_compilation_cache_dir", "/sentinel/left-alone")
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/by/operator")
+        size = jax.config.jax_compilation_cache_max_size
         assert configure_compile_cache() == "/placed/by/operator"
         assert jax.config.jax_compilation_cache_dir == "/sentinel/left-alone"
+        assert jax.config.jax_compilation_cache_max_size == size
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
 
@@ -149,7 +195,8 @@ def test_compile_cache_default_is_one_fixed_in_checkout_path(tmp_path):
     code = (
         "import jax; from twtml_tpu.utils.backend import "
         "configure_compile_cache as c; print(c()); "
-        "print(jax.config.jax_compilation_cache_dir)"
+        "print(jax.config.jax_compilation_cache_dir); "
+        "assert jax.config.jax_compilation_cache_max_size == 1 << 30"
     )
     env = {k: v for k, v in os.environ.items()
            if k != "JAX_COMPILATION_CACHE_DIR"}

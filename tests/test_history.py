@@ -317,7 +317,7 @@ def test_perf_guard_baseline_round_trip_and_sustained_regression(
 
 
 def test_guard_ignores_noise_scale_stages(tmp_path, monkeypatch):
-    """Stages under GUARD_MIN_BASELINE_MS are jitter on the one-core host:
+    """Stages under GUARD_MIN_BASELINE_MS are jitter:
     a 0.01 -> 0.05 ms "5x" never pages."""
     clock = _Clock(monkeypatch)
     cum = _seed_stages(monkeypatch)

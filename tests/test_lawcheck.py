@@ -1,4 +1,4 @@
-"""Law-checker (tools/lawcheck): the measured laws, enforced statically.
+"""Law-checker (tools/lawcheck): the repo laws, enforced statically.
 
 Every rule must FIRE on a seeded violation and stay quiet on the blessed
 pattern right next to it — a checker that can't catch the violation it was
@@ -463,7 +463,7 @@ def test_rule_registry_is_stable():
     ids = [r.id for r in rules]
     assert len(ids) == len(set(ids)) and len(ids) >= 7
     for r in rules:
-        assert r.title and r.law, f"{r.id} must cite its measured law"
+        assert r.title and r.law, f"{r.id} must cite its law"
 
 
 def test_repo_is_clean_with_empty_baseline():

@@ -1,8 +1,8 @@
 """Per-shard packed ragged wire (r5): ``pack_ragged_sharded`` lays a
 shard-aligned RaggedUnitBatch into ONE buffer whose S equal segments are the
 shards, so the mesh data axis shards the single buffer and each device
-rebuilds its local batch in-program — the +11.4% packing win (BENCHMARKS.md)
-extended to every layout. Parity bar: bit-identical weights vs both the
+rebuilds its local batch in-program — one-buffer packing extended to
+every layout. Parity bar: bit-identical weights vs both the
 unpacked ragged wire and the padded units wire on the same mesh."""
 
 import jax

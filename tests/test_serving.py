@@ -448,8 +448,8 @@ def test_chaos_fetch_error_trips_watchdog_not_client_hang(monkeypatch):
 def test_idle_stalled_fetch_reissues_and_recovers(monkeypatch):
     """The idle-server wedged-fetch case: ONE stalled fetch with no
     follow-up traffic must still hit the watchdog deadline (the serve
-    loop's poll path enforces it), re-issue — a device_get is an RTT-bound
-    request, the r3 law — and the request completes instead of hanging
+    loop's poll path enforces it), re-issue — the arrays stay resident, so a
+    duplicate device_get is safe — and the request completes instead of hanging
     until the next request arrives."""
     monkeypatch.setenv("TWTML_FETCH_DEADLINE_S", "0.3")
     monkeypatch.setenv("TWTML_FETCH_RETRIES", "3")

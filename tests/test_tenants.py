@@ -10,7 +10,7 @@ The law under test is threefold:
   moves rows, never semantics);
 - **one fetch per tick**: a real M=8 app run makes exactly ONE
   ``jax.device_get`` per dispatched batch — the PR 1/5 counting idiom on
-  the new plane (fetch amortization is the whole point, the r2 law).
+  the new plane (one fetch however many tenants is the whole point).
 """
 
 from __future__ import annotations

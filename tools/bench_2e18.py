@@ -1,7 +1,8 @@
 """Config #4 (hashing_2e18_l2) operating-point sweep — VERDICT r2 #4.
 
-The 2^18 Gram-domain step is device-bound at batch 2048 (~21 ms: the
-G = Z·Zᵀ matmul is ~2.2 TFLOP, ~53% of bf16 peak — BENCHMARKS.md). But the
+The 2^18 Gram-domain step is the one device-heavy program (the G = Z·Zᵀ
+matmul is 2·B²·F = ~2.2 TFLOP at B=2048 by arithmetic; its time on this
+machine is not measured beyond PERF.md §5's one smoke figure). The
 G build costs B²·F FLOPs, i.e. PER-TWEET device cost scales linearly with
 batch size, so a smaller batch trades per-batch overheads for less G work
 per tweet. This tool interleaves arms (batch size × wire × superbatch)

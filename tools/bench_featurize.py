@@ -1,8 +1,7 @@
 """One-pass featurize verdict (ISSUE 15): the featurize stage split into
 its sub-stages and paired off/on, on BOTH ingest paths.
 
-The question BENCHMARKS r17 left open: the host chain is
-featurize-dominated (61-70 ms per 65k-tweet pass vs ~1.4 ms of pack), so
+The question: where the host chain is featurize-dominated,
 which HALF of featurize gates the host — the Python traversals, the
 UTF-16 encode, the numeric scaling, or the wire build? This tool
 measures the split BEFORE the attack (the r9/r17 honest-miss discipline:

@@ -1,12 +1,12 @@
 """Aggregate fleet QPS + pooled p99 vs fleet size, paired (ISSUE 11).
 
 The regime the read fleet exists for: open-loop predict traffic through a
-front-door router over N serve replicas, on a transport where every result
-fetch is a ~70-100 ms RTT-bound REQUEST (BENCHMARKS r2/r3). A single
-replica's throughput ceiling in that regime is its in-flight fetch budget
-(``--depth`` pipelined fetches / RTT); a fleet multiplies that budget by N
-— IF the router and the one-core host don't bind first. This bench
-measures which it is.
+front-door router over N serve replicas, where a result fetch has a
+latency that dwarfs the device step (the fetch latency of this machine is
+not measured; PERF.md). A single replica's throughput ceiling in that
+regime is its in-flight fetch budget (``--depth`` pipelined fetches /
+fetch latency); a fleet multiplies that budget by N — IF the router and
+the host don't bind first. This bench measures which it is.
 
 Arms (single passes round-robin in one budget window on the shared
 tools/pairedbench.py harness; PAIRED per-round ratios are the verdict):

@@ -1,11 +1,11 @@
 """Coalesced + pipelined serving vs naive per-request serving, paired.
 
 The regime the serving plane exists for (ISSUE 9): query traffic against a
-device-resident snapshot through a transport where a host fetch is a
-~70-100 ms RTT-bound REQUEST (BENCHMARKS r2/r3). Naive per-request serving
-pays that round trip PER QUERY; the plane coalesces requests into one
-dispatch per batch and pipelines the result fetches at depth K (the measured
-6.2x-at-depth-8 trick, ``apps/common.FetchPipeline``).
+device-resident snapshot where a host fetch has a latency that dwarfs the
+device step (this machine's is not measured; PERF.md). Naive per-request
+serving pays that latency PER QUERY; the plane coalesces requests into one
+dispatch per batch and pipelines the result fetches at depth K
+(``apps/common.FetchPipeline``).
 
 Arms (single passes round-robin in one budget window on the shared
 tools/pairedbench.py harness; PAIRED per-round ratios are the verdict):

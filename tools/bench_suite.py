@@ -93,7 +93,7 @@ def _pipeline_rate(model, feat, statuses, batch_size, row_multiple=1, shard=None
     def featurize(chunk):
         # units wire format → bigram hashing on device (ops/text_hash.py);
         # ragged = concatenated units, no pad bytes, shipped as ONE packed
-        # buffer (features/batch.py — both measured wins, BENCHMARKS.md)
+        # buffer (features/batch.py)
         b = (
             feat.featurize_batch_ragged(
                 chunk, row_bucket=batch_size, pre_filtered=True,
@@ -122,21 +122,14 @@ def run_config(name: str, n_tweets: int, batch_size: int = 0) -> dict:
     below; 2048 where no sweep moved it); an explicit value is honored
     everywhere."""
     explicit_batch = batch_size > 0
-    # per-config r4 operating points (paired sweeps, BENCHMARKS.md "r4
-    # operating point"): the upload-bound transport rewards larger batches
-    # once per-batch fixed costs dominate — block ingest (#1) measured
-    # 1.155x paired at b8192 vs b2048; the object-ingest dense pipeline
-    # (#3 shares the headline's profile) 1.62x at b16384. Mesh configs
-    # keep 2048 (program validation on a virtual CPU mesh, not a speed
-    # claim).
+    # per-config operating points, inherited from sweeps taken on another
+    # machine (git history at f46e967); not re-measured here — ROADMAP S3
+    # re-derives them on the chip. Mesh configs keep 2048.
     # Explicit --batch always wins; default batches cap at n_tweets/4 so
     # a small-corpus run still measures a multi-chunk pipeline instead of
     # one half-padding batch.
-    # (config #4 stays at 2048: the b3072 long-pass win inverts at the
-    # suite's shorter pass shape — an A/B/A/B suite run measured b2048
-    # 139-154k vs b3072 118-123k in one window, and 65536 divides 2048
-    # exactly; b3072 remains the LONG-pass operating point, re-checkable
-    # via tools/bench_2e18.py's b3072 arm)
+    # (config #4 stays at 2048, which divides 65536 exactly;
+    # tools/bench_2e18.py sweeps its batch size)
     if not explicit_batch:
         batch_size = {
             "replay_linear": 8192,
@@ -188,10 +181,9 @@ def run_config(name: str, n_tweets: int, batch_size: int = 0) -> dict:
             for s in SyntheticSource(total=n_tweets, seed=3).produce()
         ]
         # 3 corpus replays per window (the server replays on reconnect):
-        # a one-corpus window is RAMP-dominated — the fetch pipeline's
-        # fill/drain tails and first-batch costs weighed ~2× at 32 batches
-        # (33k) vs 96 (68k) in the same r5 probe window — and the steady
-        # state is what the config claims
+        # a one-corpus window is RAMP-dominated (the fetch pipeline's
+        # fill/drain tails and first-batch costs), and the steady state
+        # is what the config claims
         n_batches = max(1, 3 * (n_tweets // batch_size))
         # snapshot the process-global property table: the fake bench creds
         # + local streamBaseURL must not leak past this measurement (a
@@ -341,11 +333,9 @@ def run_config(name: str, n_tweets: int, batch_size: int = 0) -> dict:
 
             def pipeline_source():
                 # copy=False: blocks are views, featurized promptly; 4MB
-                # blocks amortize per-call overhead (measured best on this
-                # host with the view path). wire=True: the r9 zero-copy
-                # emitter — the shipped config-#1 path (--blockWire auto
-                # resolves on for the ragged wire; paired 1.6× on the parse
-                # stage, BENCHMARKS.md "Zero-copy block parse")
+                # blocks amortize per-call overhead. wire=True: the
+                # zero-copy emitter — the shipped config-#1 path
+                # (--blockWire auto resolves on for the ragged wire)
                 return BlockReplayFileSource(
                     path, copy=False, block_bytes=4 << 20, wire=True
                 ).produce()

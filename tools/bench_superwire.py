@@ -7,17 +7,14 @@ per-transfer cost once. ``--wirePack group``
 per K batches, uint16-delta offsets, unpacked inside the scanned program.
 
 Verdict comes from the house method only (tools/pairedbench.py):
-interleaved single passes + paired per-round ratios, in BOTH regimes the
-measured record names —
+interleaved single passes + paired per-round ratios, in two regimes —
 
-- telemetry  : the upload-bound per-batch-telemetry regime (f_text=1000,
+- telemetry  : the per-batch-telemetry regime (f_text=1000,
                the SuperBatcher path end-to-end, per-batch handler work
                included — the regime where the wire binds);
 - 2e18       : config #4 at its b1024 operating point (Gram-domain,
-               device-bound — where r3 measured --superBatch itself
-               NEGATIVE; if coalescing is negative here too it must ship
-               flag-off for this config, per the "measure in the target
-               regime" law).
+               device-heavy; if coalescing is negative here it must ship
+               flag-off for this config — measure in the target regime).
 
 Each regime also reports the wire accounting directly: bytes per group on
 both layouts and the offset bytes the uint16-delta sideband deletes.
@@ -160,7 +157,7 @@ def main(argv=None) -> None:
     out = {"bench": "superwire"}
     per = budget / (2 if regime == "both" else 1)
     if regime in ("telemetry", "both"):
-        # the upload-bound regime: f_text=1000, b2048 (the telemetry
+        # the per-batch-telemetry regime: f_text=1000, b2048 (the telemetry
         # operating point the fetch-pipeline/superbatch record uses)
         out["telemetry"] = _regime(
             "telemetry", 1000, 0.0, None, batch or 2048, k, n_tweets, per

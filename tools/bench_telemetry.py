@@ -6,16 +6,15 @@ that caps the back-to-back telemetry-on rate far below the free-dispatch
 rate. Arms (single passes round-robin in one window; paired
 per-round ratios are the phase-robust comparison):
 
-- sync     : device_get right after each dispatch (the r2 baseline);
-- lag      : one-batch-lag fetch (VERDICT r2 #2's proposal) — measured
-             NEUTRAL here, kept for the record;
-- pool8    : concurrent in-order fetches on a thread pool — the measured
-             6.2x winner, the mechanism FetchPipeline ships;
+- sync     : device_get right after each dispatch;
+- lag      : one-batch-lag fetch;
+- pool8    : concurrent in-order fetches on a thread pool — the mechanism
+             FetchPipeline ships;
 - fetchpipe: the SHIPPED path end-to-end — apps/common.FetchPipeline over
-             the ragged+packed wire, per-batch handler included. This is
-             the arm behind the r4 batch-retune claim (2.2x paired at
-             --batch 16384 vs 2048: the per-batch fetch amortizes over 8x
-             more tweets — BENCHMARKS.md).
+             the ragged+packed wire, per-batch handler included.
+
+No arm has been run on this machine (PERF.md); the earlier verdicts were
+taken elsewhere and are not carried here.
 
 Usage: python tools/bench_telemetry.py [--tweets N] [--batch B] [--budget S]
 Prints one JSON line.
@@ -79,9 +78,8 @@ def main(argv=None) -> None:
 
     def lag_pass():
         """One-batch-lag fetch (dispatch k, then fetch k-1; async copy at
-        dispatch) — kept as an arm for the record: measured NEUTRAL on this
-        transport (device_get is an RTT-bound request), which is why the
-        shipped pipeline is the concurrent pool below instead."""
+        dispatch) — kept as an arm beside the concurrent pool the
+        shipped pipeline uses."""
         model.reset()
         pending = None
         t0 = time.perf_counter()
@@ -101,11 +99,9 @@ def main(argv=None) -> None:
 
     def pool_pass(workers=8):
         """Fetch each batch's StepOutput on a thread pool while the main
-        thread keeps dispatching; consume in order. If the transport
-        accepts concurrent host-fetch requests, N in-flight requests
-        pipeline the RTT (throughput → N/RTT); if it serializes them,
-        this matches sync. (device_put off-main collapses throughput —
-        measured r2 — but these are GETs.)"""
+        thread keeps dispatching; consume in order. If concurrent
+        host fetches overlap, N in-flight requests pipeline the fetch
+        latency; if the runtime serializes them, this matches sync."""
         model.reset()
         t0 = time.perf_counter()
         with ThreadPoolExecutor(max_workers=workers) as pool:

@@ -2,8 +2,7 @@
 
 The regime the plane exists for: M scenario models (per-topic / per-language
 / per-A/B-arm) with per-batch telemetry. Today that costs M full pipelines —
-M featurize passes, M wires, M dispatches, and above all M host fetches at
-~70–100 ms RTT each (the r2 law). The tenant stack routes one shared stream
+M featurize passes, M wires, M dispatches and M host fetches. The tenant stack routes one shared stream
 into M models inside ONE jit program with ONE stacked stats fetch per tick.
 
 Arms (single passes round-robin in one budget window on the shared

@@ -1,9 +1,9 @@
 """Compressed-wire verdict (ISSUE 12): ``--wireCodec dict`` off vs on,
-paired, in the upload-bound ingest regimes.
+paired, in the two ingest regimes.
 
 The question: the digram codec (features/wirecodec.py) shrinks the
-dominant wire tensor ~1.3-2x on ASCII tweet text, paying a one-core host
-encode (~60 µs/64 KiB in C) and an in-jit gather-expand decode. Does the
+dominant wire tensor on ASCII tweet text, paying a host encode (one C
+pass) and an in-jit gather-expand decode. Does the
 byte saving beat the encode cost where upload binds?
 
 Method: the house harness only (tools/pairedbench.py) — interleaved
@@ -18,7 +18,7 @@ Each regime answers twice:
 
 - CPU control — the full pipeline (pack → step → completion fetch) on the
   CPU backend. Wire-insensitive by design: this isolates the codec's HOST
-  cost (the one-core encode) as a paired ratio ~1x-minus-encode.
+  cost (the encode) as a paired ratio ~1x-minus-encode.
 - modeled upload-bound transport — paired pack-only passes (the codec's
   only timed host delta) plus EXACT upload arithmetic wire_bytes/BW over
   a MODELED 45-70 MB/s envelope (an upload-bound link: upload is the top

@@ -1,18 +1,19 @@
-"""Law-checker: a repo-specific static analyzer for the measured laws.
+"""Law-checker: a repo-specific static analyzer for the repo's structural laws.
 
-Nine PRs of benchmarking bought a set of *measured* transport/parity
-invariants — one counted fetch per tick, main-thread-only ``device_put``
-(the r2 throughput collapse), no scatter into 2^18 (the ~220 ns/update XLA
-serialization trap), Try-parity on publish paths, no module-scope backend
-init before the conftest mesh pin, the ``TWTML_NOW_MS`` determinism seam,
-and flag/doc sync. Each was enforced only by convention and a handful of
-runtime counting tests; a single unreviewed call site could silently
-reintroduce a failure mode that cost a benchmark round to discover. This
-package enforces them over the AST, in CI, before any TPU window is spent.
+The repo keeps a set of structural invariants — one counted fetch per tick,
+main-thread-only ``device_put``, no scatter into 2^18 (XLA serializes it),
+Try-parity on publish paths, no module-scope backend init before the
+conftest mesh pin, the ``TWTML_NOW_MS`` determinism seam, and flag/doc
+sync. Each was enforced only by convention and a handful of runtime
+counting tests; a single unreviewed call site could silently break one.
+This package enforces them over the AST, in CI. Which of the
+performance-motivated ones still earn their place on this machine is
+ROADMAP D5's question (nothing about their cost is measured here yet —
+PERF.md).
 
 One rule per law (``python -m tools.lawcheck --list-rules``); every finding
-message cites the BENCHMARKS.md/CLAUDE.md fact it encodes. Pure stdlib
-(``ast``), no jax import, no third-party deps.
+message states the mechanism it guards. Pure stdlib (``ast``), no jax
+import, no third-party deps.
 
 Usage::
 

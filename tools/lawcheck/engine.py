@@ -190,7 +190,7 @@ def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m tools.lawcheck",
         description=(
-            "Static analyzer for this repo's measured transport/parity "
+            "Static analyzer for this repo's structural and parity "
             "laws (exit 0 clean / 1 findings / 2 malformed)"
         ),
     )
@@ -205,7 +205,7 @@ def main(argv: list[str] | None = None) -> int:
                         help="write current findings to the baseline file "
                              "(for grandfathering; target state is empty)")
     parser.add_argument("--list-rules", action="store_true",
-                        help="print every rule with the measured law it "
+                        help="print every rule with the law it "
                              "encodes")
     args = parser.parse_args(argv)
 

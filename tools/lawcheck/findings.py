@@ -18,7 +18,7 @@ class Finding:
     rule: str  # "TW001".."TW007"
     path: str  # repo-relative posix path ("" for repo-level rules)
     line: int  # 1-based; 0 for repo-level findings with no anchor line
-    message: str  # states the violation AND cites the measured law
+    message: str  # states the violation AND cites the law
 
     @property
     def fingerprint(self) -> str:

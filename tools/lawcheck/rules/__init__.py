@@ -1,4 +1,4 @@
-"""Rule registry. One rule per measured law; ids are stable (baseline and
+"""Rule registry. One rule per law; ids are stable (baseline and
 suppression comments reference them), so retired rules must not be reused.
 
 A rule is either per-file (``check(FileContext) -> list[Finding]``) or
@@ -36,7 +36,7 @@ class RepoContext:
 class Rule:
     id: str = ""
     title: str = ""  # one line, shown by --list-rules and cited in docs
-    law: str = ""  # the measured fact this encodes, with its source doc
+    law: str = ""  # the mechanism this guards, with its source doc
 
     def check(self, ctx: FileContext):  # per-file rules override
         return []

@@ -1,13 +1,12 @@
 """Device-program law: no scatter in jitted step code (the Gram densify).
 
-The one XLA trap that cost a full benchmark round: a [B*L]-update scatter
-into the [B, 2^18] feature space runs ~220 ns/update SERIALIZED on this
-backend — the 2^18 sparse config only became viable when ops/gram.py
-replaced 50 scatters per batch with one [B, B] Gram matmul (one-hot
-two-level matmul densify, ~21 ms/step). Any ``.at[...].add/.set`` that
-creeps back into step code silently reopens that cliff, and nothing at
-runtime would flag it — the program still produces correct bits, just
-hundreds of times slower.
+XLA serializes a [B*L]-update scatter into the [B, 2^18] feature space
+update by update; ops/gram.py exists to avoid it, replacing 50 scatters per
+batch with one [B, B] Gram matmul (one-hot two-level matmul densify). Any
+``.at[...].add/.set`` that creeps back into step code reopens that path,
+and nothing at runtime would flag it — the program still produces correct
+bits. What the scatter costs on this machine is not measured (PERF.md);
+the rule guards the design, not a number.
 """
 
 from __future__ import annotations
@@ -27,13 +26,12 @@ class TW004Scatter(Rule):
     id = "TW004"
     title = "indexed-update scatter in jitted step code"
     law = (
-        "a [B*L]-update scatter into [B, 2^18] runs ~220 ns/update "
-        "serialized on this backend; ops/gram.py's one-hot two-level "
-        "matmul densify replaced it (one [B,B] Gram matmul per batch, "
-        "~21 ms/step at 2^18) — scatters must not creep back into step "
-        "code (BENCHMARKS.md 'XLA perf traps'; CLAUDE.md). Bounded "
-        "small-domain scatters (K centers, fixed columns) are exempt via "
-        "an inline suppression stating the bound"
+        "XLA serializes a [B*L]-update scatter into [B, 2^18] update by "
+        "update; ops/gram.py's one-hot two-level matmul densify replaced "
+        "it (one [B,B] Gram matmul per batch) — scatters must not creep "
+        "back into step code (its cost on this machine: not measured, "
+        "PERF.md). Bounded small-domain scatters (K centers, fixed "
+        "columns) are exempt via an inline suppression stating the bound"
     )
 
     def check(self, ctx: FileContext):

@@ -2,8 +2,8 @@
 
 A suppression silences named rules on ITS OWN line only, and the trailing
 reason is mandatory — the whole point of the law checker is that every
-deviation from a measured law carries its justification next to the code
-(the same discipline BENCHMARKS.md applies to honest misses). A reasonless
+deviation from a law carries its justification next to the code
+(nothing is exempt silently). A reasonless
 suppression, an unknown rule id, or a malformed comment body is a
 ``Malformed`` record (exit 2), not a silent no-op: a typo'd suppression
 that silently failed to apply would surface as a phantom finding, and one
@@ -62,7 +62,7 @@ def scan(path: str, source: str, known_rules: frozenset[str]) -> Suppressions:
             out.malformed.append(Malformed(
                 path, lineno,
                 "suppression without a reason — every deviation from a "
-                "measured law must carry its justification "
+                "law must carry its justification "
                 "('disable=TW004 -- why this site is exempt')",
             ))
             continue

@@ -9,7 +9,7 @@ Two phases over the same replay corpus (identical flags except the ceiling):
 
 1. CALIBRATE: run the app with recycling off, sampling its RSS from the
    OUTSIDE (/proc/<pid>/statm, ~4 Hz) — yields the post-compile baseline
-   and the corpus' natural retention growth on this transport.
+   and the corpus' natural retention growth.
 2. DEMONSTRATE: ceiling = baseline + 60% of the measured growth (guaranteed
    to cross mid-file), TWTML_RECYCLE_MAX=1. The harness keeps sampling the
    SAME pid across the os.execv and asserts, from the run's own logs:
@@ -153,7 +153,7 @@ def main(argv=None) -> None:
         if env.get("PYTHONPATH") else REPO
     )
 
-    # ---- phase 1: calibrate the natural retention on this transport ----
+    # ---- phase 1: calibrate the natural retention ----
     run_a = _AppRun(
         _app_argv(replay, os.path.join(work, "ck_a"), batch, 0), env
     )

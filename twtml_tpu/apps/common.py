@@ -29,8 +29,7 @@ log = get_logger("apps.common")
 
 # fetch-watchdog policy (see FetchWatchdog): the deadline derives from the
 # health monitor's rolling fetch latency — generous multiples, because a
-# stalled transport can legitimately stall for a long time and a re-issue
-# only helps a LOST request, not a stalled one
+# re-issue only helps a LOST request, not a stalled one
 FETCH_DEADLINE_MULT = 25.0
 FETCH_DEADLINE_MIN_S = 30.0
 FETCH_DEADLINE_MAX_S = 180.0
@@ -478,8 +477,7 @@ def build_source(
         if conf.ingest == "block":
             # live block ingest (r5): raw stream lines batch into byte
             # blocks for the native C parser — no per-tweet Python objects
-            # between the socket and the featurizer (closes most of the
-            # config-#2 full-app vs protocol-stage gap, BENCHMARKS.md)
+            # between the socket and the featurizer
             begin, end = (
                 block_interval
                 if block_interval is not None
@@ -532,10 +530,10 @@ def _wrap_faults(source: Source, conf) -> Source:
 def mesh_shape(conf) -> int:
     """Data-axis size the conf + attached devices call for: the number of
     visible devices, capped by the ``--master local[N]`` hint."""
-    import jax
+    from ..utils.backend import run_devices
 
     shards = conf.local_shards()
-    n_devices = len(jax.devices())
+    n_devices = len(run_devices())
     return min(shards, n_devices) if shards else n_devices
 
 
@@ -566,9 +564,13 @@ def build_mesh(conf, what: str = "training"):
         return None
 
     from ..parallel import make_mesh
+    from ..utils.backend import run_devices
 
     log.info("mesh-sharded %s: %d-way data parallel", what, n_data)
-    return make_mesh(num_data=n_data, devices=jax.devices()[:n_data])
+    # the platform select_backend reported, never another one that happens
+    # to be jax's first (a default device pinned to the CPU in a process
+    # that also holds a chip)
+    return make_mesh(num_data=n_data, devices=run_devices()[:n_data])
 
 
 def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
@@ -1252,8 +1254,7 @@ class DivergenceSentinel:
     ONLY the StepOutput scalars the pipeline already fetched per batch
     (mse/stdevs — NaN labels or NaN weights propagate into all of them
     through the on-device stats reduction). Healthy-path cost is three
-    ``math.isfinite`` calls per batch (paired-neutral on the CPU control,
-    BENCHMARKS.md).
+    ``math.isfinite`` calls per batch.
 
     On a non-finite delivery: the batch is SKIPPED (never handed to the
     app handler — its stats are garbage; the dispatch slot is refunded so
@@ -1695,15 +1696,15 @@ class FetchWatchdog:
     """Deadline + bounded-retry + clean-abort guard over the pooled host
     fetches (FetchPipeline / SuperBatcher).
 
-    Why it is safe to retry: a ``device_get`` through this transport is an
-    RTT-bound REQUEST, not a wait-for-arrival (BENCHMARKS.md r3) — the
-    device arrays stay resident, so a fetch that missed its deadline or
-    raised can simply be RE-ISSUED; a duplicate concurrent get reads the
-    same bytes. The deadline derives from the health monitor's rolling
-    fetch RTT (``FETCH_DEADLINE_MULT`` × median, clamped to
+    Why it is safe to retry: a ``device_get`` reads arrays that stay
+    resident on the device, so a fetch that missed its deadline or raised
+    can simply be RE-ISSUED; a duplicate concurrent get reads the same
+    bytes. The deadline derives from the health monitor's rolling fetch
+    latency (``FETCH_DEADLINE_MULT`` × median, clamped to
     [``FETCH_DEADLINE_MIN_S``, ``FETCH_DEADLINE_MAX_S``]) — deliberately
-    generous, because a transport stall can legitimately last a long time
-    and a retry only helps a LOST request, not a stalled transport.
+    generous, because a retry only helps a LOST request, not a stalled
+    device. Whether a fetch is ever lost on this machine is not known
+    (ROADMAP D5).
 
     After ``retries`` re-issues the run aborts CLEANLY instead of the
     pre-guard behavior (an untimed ``future.result()`` = a silent permanent
@@ -1780,8 +1781,8 @@ class FetchWatchdog:
             self._retry_count.inc()
             _blackbox.record("fetch_retry", attempt=attempts, why=why)
             log.warning(
-                "pooled stats fetch %s; re-issuing (retry %d/%d — a "
-                "device_get is an RTT-bound request, a duplicate is safe)",
+                "pooled stats fetch %s; re-issuing (retry %d/%d — the "
+                "arrays stay resident, a duplicate device_get is safe)",
                 why, attempts, self.retries,
             )
             future = reissue()
@@ -2354,10 +2355,9 @@ class FetchPipeline:
         # compressed units wire (--wireCodec, r15): forwarded to the plain
         # pack_batch below; model-aware packers carry their own attribute
         self.wire_codec = wire_codec
-        # one-buffer wire: measured +11.4% paired on the ragged wire
-        # through this transport (per-ARRAY request overhead stops hiding
-        # once the wire is lean); handlers still receive the UNPACKED
-        # batch. The pack itself is model-aware (r5): mesh models lay the
+        # one-buffer wire: one device_put per batch instead of one per
+        # array (its gain on this machine: not measured, ROADMAP S3);
+        # handlers still receive the UNPACKED batch. The pack itself is model-aware (r5): mesh models lay the
         # buffer out PER SHARD so the data axis can shard it
         # (ParallelSGDModel.pack_for_wire), multi-host models additionally
         # assemble the global buffer from every host's local shard segments
@@ -2406,7 +2406,7 @@ class FetchPipeline:
         """The pooled host fetch, timed for the fetch-health monitor and
         the ``fetch`` trace stage. This wraps the ONE fetch the pipeline
         already makes per batch — instrumentation never adds a
-        ``device_get`` (BENCHMARKS.md measurement integrity)."""
+        ``device_get``."""
         import time as _time
 
         import jax
@@ -2503,7 +2503,7 @@ class FetchPipeline:
             )
         else:
             wire = batch
-        # argument uploads ride the dispatch on this transport (no
+        # argument uploads ride the dispatch (no
         # separate device_put on the single-host hot path); timed
         # unconditionally for the sideband's upload attribution, with the
         # --chaos injection INSIDE the window so injected dispatch stalls
@@ -3072,9 +3072,8 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
         # inert (r5 review) — impose a default recycle-check cadence
         boundary_every = 64
 
-    # the ragged wire additionally ships as ONE packed buffer (measured
-    # +11.4% paired — per-array request overhead stops hiding once the
-    # wire is lean; bit-identical unpack inside the jit step). Since r5
+    # the ragged wire additionally ships as ONE packed buffer (one put
+    # per batch; bit-identical unpack inside the jit step). Since r5
     # every layout packs: mesh models lay the buffer out per shard and
     # multi-host models assemble it globally (pack_for_wire), so the fast
     # path survives every deployment shape.
@@ -3094,9 +3093,10 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
 
     if k <= 1:
         if conf.seconds <= 0:
-            # back-to-back: concurrent in-order stats fetches pipeline the
-            # transport round trip (measured 6.2x paired at depth 8 —
-            # FetchPipeline); checkpoint cadence points drain the pipeline
+            # back-to-back: concurrent in-order stats fetches overlap
+            # their latencies (FetchPipeline; what depth 8 buys on this
+            # machine is not measured, ROADMAP S3); checkpoint cadence
+            # points drain the pipeline
             # so saves see current weights. Multi-host runs emit only at
             # deterministic points so stop/refund side effects land on the
             # same tick on every lockstep host.
@@ -3188,8 +3188,7 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
         abort=abort,
         # the coalesced one-buffer group wire applies exactly where the
         # k=1 pack does (ragged wire + a model that unpacks in-jit);
-        # --wirePack auto resolves to the measured default
-        # (config.effective_wire_pack, BENCHMARKS.md "Lean wire v2")
+        # --wirePack auto resolves in config.effective_wire_pack
         wire_pack=(
             "group"
             if pack and getattr(

@@ -215,6 +215,10 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
         # model build, and the warmup compile are startup, not streaming
         # (the suite's twitter_live config reads this, VERDICT r3 #4)
         totals["stream_seconds"] = _time.perf_counter() - t_stream
+        if hasattr(model, "device_span"):
+            # mesh runs: how many devices the weights and the wire buffer
+            # really spanned (parallel/sharding.ParallelSGDModel)
+            totals["device_span"] = model.device_span()
         tracer.stop()
         if session is not None:
             # final metrics snapshot so the dashboard panel ends current

@@ -395,12 +395,12 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                (device, default) or on the host CPU (host);
                                                bit-identical features either way. Default: {self.hashOn}
   --ingest <object|block>                      Replay ingestion: per-tweet Status objects, or
-                                               columnar blocks via the native C parser (~10x
-                                               ingest throughput; replay source only). Default: {self.ingest}
+                                               columnar blocks via the native C parser
+                                               (replay source only). Default: {self.ingest}
   --wire <auto|padded|ragged>                  Units wire format: ragged ships concatenated
-                                               units + offsets (no pad bytes; the measured-
-                                               fastest wire on every layout — packed, sharded,
-                                               superbatched), padded ships a [B, L] buffer.
+                                               units + offsets (no pad bytes, on every layout —
+                                               packed, sharded, superbatched), padded ships
+                                               a [B, L] buffer.
                                                auto = ragged for hashOn=device back-to-back
                                                runs (--seconds 0); padded for wall-clock
                                                streaming (pre-compilable before the stream
@@ -611,8 +611,8 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                admitted request has waited this long.
                                                Default: {self.serveMaxWaitMs}
   --serveDepth <int>                           Concurrent in-flight predict-result fetches
-                                               (the measured 6.2x-at-depth-8 transport
-                                               pipelining, BENCHMARKS r3).
+                                               (overlapping device_gets, as the trainer's
+                                               FetchPipeline does).
                                                Default: {self.serveDepth}
   --servePromoteEvery <float seconds>          Snapshot promoter poll cadence over
                                                --checkpointDir (new verified checkpoints
@@ -657,9 +657,9 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                'group' coalesces the K batches into ONE
                                                contiguous buffer (one put; uint16-delta offsets)
                                                unpacked inside the scanned program; 'stacked'
-                                               ships K per-field arrays. auto = the measured
-                                               winner (currently stacked pending an on-chip
-                                               paired verdict, ROADMAP S3; bit-identical
+                                               ships K per-field arrays. auto = stacked until
+                                               an on-chip paired verdict (ROADMAP S3;
+                                               bit-identical
                                                features either way).
                                                Default: {self.wirePack}
   --wireCodec <auto|off|dict>                  Compressed ragged units wire: 'dict' digram-
@@ -672,9 +672,8 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                (uint16) units and incompressible batches ship
                                                raw, counted in wire.codec_fallbacks. With
                                                --superBatch, 'dict' + --wirePack auto resolves
-                                               the group (coalesced) wire. auto = the measured
-                                               default (currently off pending an on-chip
-                                               paired verdict, ROADMAP S3).
+                                               the group (coalesced) wire. auto = off until an
+                                               on-chip paired verdict (ROADMAP S3).
                                                Default: {self.wireCodec}
   --wireAssemble <auto|on|off>                 Fused one-pass wire assembly (r17): 'on' builds
                                                every packed wire (flat / per-shard / coalesced
@@ -938,17 +937,17 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
 
     # -- derived ------------------------------------------------------------
     def effective_wire(self) -> str:
-        """Resolve ``--wire auto`` (the default) to the measured-best wire
-        for this configuration: RAGGED whenever the device hashes in a
-        back-to-back regime (the headline/bench path — +14% paired on
-        object ingest, +28% from blocks, packed for another +11.4%,
-        sharded on every layout since r4/r5); PADDED for host hashing (the
+        """Resolve ``--wire auto`` (the default) for this configuration:
+        RAGGED whenever the device hashes in a back-to-back regime (the
+        bench path; no pad bytes uploaded, on every layout — what it buys
+        on this machine is not measured, ROADMAP S3); PADDED for host
+        hashing (the
         ragged wire ships raw code units by definition) and for WALL-CLOCK
         streaming (--seconds > 0): the ragged units bucket is
         data-dependent, so it cannot pre-compile before the stream starts
-        (apps/common.warmup_compile) — a live run would stall ~30 s on its
-        first batch — while wall-clock intervals are latency-dominated and
-        wire bytes don't bind there. Explicit ``--wire ragged``/``padded``
+        (apps/common.warmup_compile) — a live run would stall for an
+        in-stream compile on its first batch — while wall-clock intervals
+        are latency-dominated and wire bytes don't bind there. Explicit ``--wire ragged``/``padded``
         always wins; explicit ragged with --hashOn host is rejected at
         source construction (apps/common.build_source)."""
         if self.wire != "auto":
@@ -974,7 +973,7 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
         return self.effective_wire() == "ragged"
 
     def effective_wire_pack(self) -> str:
-        """Resolve ``--wirePack auto`` to the measured-default superbatch
+        """Resolve ``--wirePack auto`` to the default superbatch
         wire layout. The coalesced group wire (one contiguous buffer per K
         batches, uint16-delta offsets) is bit-identical to the stacked wire
         and ships one large transfer where the stacked wire ships K sets
@@ -1002,7 +1001,7 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
         return "stacked"
 
     def effective_wire_codec(self) -> str:
-        """Resolve ``--wireCodec auto`` to the measured-default units
+        """Resolve ``--wireCodec auto`` to the default units
         codec. ``dict`` (the digram codec, features/wirecodec.py) is only
         meaningful on the ragged raw-units wire — explicit ``dict`` with a
         padded/host-hash wire is rejected, like explicit ragged with

@@ -31,8 +31,8 @@ class FeatureBatch(NamedTuple):
 
     ``token_idx``/``token_val`` travel in the narrowest lossless dtype
     (int16/uint16 when the feature space and counts fit — see
-    ``compact_tokens``): host→device transfer is the measured bottleneck of
-    the streaming hot loop, and the learner steps upcast on device.
+    ``compact_tokens``): fewer bytes on the host→device wire, and the
+    learner steps upcast on device.
     """
 
     token_idx: np.ndarray
@@ -178,13 +178,12 @@ class RaggedUnitBatch:
 
     Why: the padded ``UnitBatch`` units buffer is the dominant wire tensor
     of the streaming hot loop, and every unit beyond a row's length is pure
-    waste on the upload-bound transport (the padded [B, L] carries
-    B·L units where only Σlengths are real — the padding fraction is
-    measured in BENCHMARKS.md). The ragged wire carries Σlengths units
+    waste on the wire (the padded [B, L] carries B·L units where only
+    Σlengths are real). The ragged wire carries Σlengths units
     (rounded up to ``RAGGED_UNIT_MULTIPLE`` so program count stays finite)
     plus a [B+1] int32 offsets vector; the learner re-pads INSIDE the jit
-    step with one [B, L] gather (ops-side cost ~nothing; TPU gathers are
-    cheap — it is scatters that serialize) and case-folds ASCII on device,
+    step with one [B, L] gather (its device cost: not measured, ROADMAP
+    S2) and case-folds ASCII on device,
     producing bit-identical features (tests/test_ragged_wire.py).
 
     ``row_len`` (the padded L the device gather rebuilds) is STATIC aux
@@ -247,8 +246,8 @@ _WIRE_FIELDS = (
 
 def wire_nbytes(batch) -> int:
     """Bytes this batch puts on the host→device wire (the sum of its array
-    fields' nbytes, whatever the batch type) — the per-batch cost the
-    upload-bound transport actually pays, recorded by the telemetry layer
+    fields' nbytes, whatever the batch type) — the per-batch upload
+    volume, recorded by the telemetry layer
     (telemetry/trace.py spans, ``wire.bytes`` counter)."""
     total = 0
     for name in _WIRE_FIELDS:
@@ -487,8 +486,8 @@ def _decode_offsets(arr, num_segments: int):
 # coding, C-side encode, in-jit gather-expand decode) shrinks the dominant
 # wire tensor another ~1.4-2x on ASCII tweet text. It applies ONLY to the
 # PACKED wire forms (pack_batch / pack_ragged_sharded / pack_ragged_group):
-# compression compounds the per-array-overhead trap that already made
-# packing the lean-wire default (+11.4% paired, r3), and every host-side
+# compression keeps to the one-buffer forms that are the lean-wire
+# default, and every host-side
 # consumer between featurize and pack (tenant routing, shard alignment,
 # stacking) indexes RAW units by offset. Two gates, both loud and lossless:
 # uint16 (non-ASCII-widened) units ship uncompressed — a metadata gate,
@@ -634,9 +633,9 @@ def pack_ragged_sharded(
     codec_bucket: "int | None" = None,
 ) -> PackedBatch:
     """A SHARD-ALIGNED ragged batch → one wire buffer laid out PER SHARD, so
-    a mesh data axis can shard the single buffer (r5: the +11.4% packing
-    win was single-device-only because ``pack_batch``'s field-major layout
-    has no row sharding).
+    a mesh data axis can shard the single buffer (``pack_batch``'s
+    field-major layout has no row sharding, so one-buffer packing was
+    single-device-only before this).
 
     Layout: the buffer is S equal segments; segment s holds shard s's five
     fields back to back (units sub-buffer, segment-relative offsets,

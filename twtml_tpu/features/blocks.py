@@ -3,9 +3,8 @@
 A ParsedBlock is a filtered batch of tweets in columnar form, straight from
 the C parser (native/tweetjson.cpp): the featurizer-relevant numeric fields,
 plus the original tweets' text as concatenated UTF-16 code units. It skips
-per-tweet Python objects entirely — the ~11 µs/tweet of json.loads +
-Status assembly that caps the object ingest path near 90k tweets/s on one
-core. ``Featurizer.featurize_parsed_block`` turns one (or several merged)
+per-tweet Python objects entirely — the json.loads + Status assembly
+that is the object ingest path's per-tweet host cost. ``Featurizer.featurize_parsed_block`` turns one (or several merged)
 blocks directly into the UnitBatch wire format.
 
 The Python object path (sources.ReplayFileSource → Status → featurize_*)

@@ -1,9 +1,8 @@
 """One-pass host featurize (r18) — the fused native fast path of the
 ragged-wire featurize stage, behind ``--featurizeNative``.
 
-BENCHMARKS r17 measured the host chain featurize-dominated (61-70 ms per
-65k-tweet pass vs ~1.4 ms of pack): PR 6 made parse native and PR 14
-made pack native, but the stage between them still ran several separate
+PR 6 made parse native and PR 14 made pack native, but the stage
+between them still ran several separate
 numpy array passes (float64 scale + f32 cast, label/mask fills, the
 ragged-wire zero+copy) on BOTH ingest paths. This module routes the
 array half of featurize through ONE C sweep (native/featurize.cpp): the

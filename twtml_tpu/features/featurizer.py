@@ -86,9 +86,8 @@ def _pad_ragged_units(
 
     ``narrow=True`` ships the buffer as uint8 — the half-width wire format
     for batches every caller-known-ASCII row fits (the overwhelmingly common
-    case). Host→device transfer is the measured bottleneck of the streaming
-    hot loop and the units buffer is its largest tensor, so this halves the
-    dominant wire cost with ZERO extra data passes: the flag comes from
+    case). The units buffer is the largest tensor on the host→device wire,
+    so this halves the dominant wire tensor with ZERO extra data passes: the flag comes from
     metadata both builders already have (parser ascii flags / isascii), the
     narrow write happens inside the same C pad copy, and the device hash
     upcasts to int32 either way (ops/text_hash.py) — identical features. A
@@ -461,8 +460,7 @@ class Featurizer:
         ingest path previously paid four separate per-tweet Python
         traversals (the filtrate comprehension with two method calls per
         row, the originals comprehension, the isascii/lower loop, the
-        attrgetter fromiter) — on the one-core host that WAS the
-        featurize stage (BENCHMARKS r17 → r18).
+        attrgetter fromiter) — that was most of the featurize stage.
 
         Returns (keep, texts, cols float64 [n, 5] in _NUMERIC_COLS
         order). ``keep`` is the kept Status objects when a custom

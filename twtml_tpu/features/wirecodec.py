@@ -113,8 +113,7 @@ def encode_np(buf: np.ndarray) -> np.ndarray:
     at (the preceding position either emitted a literal and stepped 1, or
     closed a pair of the previous run and stepped past it), so the whole
     decision is position arithmetic over runs. That makes the encode three
-    vectorized passes over the buffer — it must ride the one-core host
-    budget (CLAUDE.md), never a per-byte Python loop.
+    vectorized passes over the buffer, never a per-byte Python loop.
     """
     b = np.ascontiguousarray(buf).reshape(-1)
     if b.dtype != np.uint8:

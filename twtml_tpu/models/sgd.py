@@ -141,21 +141,23 @@ def sgd_inner_loop(
 
     converged0 = jnp.array(False)
     if vary_axis:
-        to_varying = lambda x: lax.pcast(x, vary_axis, to="varying")
-        weights = jax.tree_util.tree_map(to_varying, weights)
-        converged0 = to_varying(converged0)
-    # The body may hand the converged flag back varying over MORE manual
-    # mesh axes than it went in with (tenants on the 'model' axis: each
-    # shard's tenants converge on their own, and no psum makes the flag
-    # invariant), and a loop carry must enter as it leaves. One abstract
-    # evaluation of the body says which axes; outside shard_map there are
-    # none, nothing is cast, and the single-device program is unchanged.
-    flag_out = jax.eval_shape(lambda c: body(0, c), (weights, converged0))[1]
-    extra = tuple(sorted(
-        (flag_out.vma or frozenset()) - jax.typeof(converged0).vma
-    ))
-    if extra:
-        converged0 = lax.pcast(converged0, extra, to="varying")
+        weights = jax.tree_util.tree_map(
+            lambda x: lax.pcast(x, vary_axis, to="varying"), weights
+        )
+    # A loop carry must enter varying over every manual mesh axis it leaves
+    # varying over. The converged flag enters varying over the axes EVERY
+    # weights leaf varies over: ``vary_axis`` once they are cast, and e.g.
+    # 'model' with tenants sharded over it (each shard's tenants converge
+    # on their own; no psum makes the flag invariant). Over such an axis
+    # the flag cannot widen any weights carry, so the cast is always safe;
+    # an axis only SOME leaves vary over (feature-sharded text vs numeric)
+    # is the caller's to reduce in ``norm_sq``. Outside shard_map the set
+    # is empty, nothing is cast, and the single-device program is unchanged.
+    flag_axes = tuple(sorted(frozenset.intersection(*(
+        jax.typeof(leaf).vma for leaf in jax.tree_util.tree_leaves(weights)
+    ))))
+    if flag_axes:
+        converged0 = lax.pcast(converged0, flag_axes, to="varying")
     w_final, _ = lax.fori_loop(0, num_iterations, body, (weights, converged0))
     return w_final
 
@@ -263,14 +265,15 @@ def make_sgd_train_step(
     The inner loop is always the XLA-compiled ``sgd_inner_loop``. A
     VMEM-resident pallas variant exists as reference code
     (ops/pallas_sgd.py, semantics pinned by tests) but is deliberately NOT a
-    knob here: at these shapes the step is micro-seconds on device for both
-    implementations and the difference is unmeasurable through this build's
-    dispatch transport — see BENCHMARKS.md for the full measurement story.
+    knob here: nothing calls it. On the v5e it compiles and runs (PERF.md
+    §5 has the one isolated-loop timing); whether the inner loop is where
+    a batch's time goes is not measured, so ROADMAP D7 decides its fate.
 
     In the sparse regime the iterations run in the dual (Gram) basis by
     default (ops/gram.py): one MXU matmul builds G = Z·Zᵀ per batch and the
-    loop never touches the 2^18 feature space — ~25× the per-iteration
-    gather/scatter formulation on a v5e chip at B=2048. With a data axis the
+    loop never touches the 2^18 feature space (the per-iteration
+    gather/scatter formulation it replaced is gone; no comparison exists on
+    this machine). With a data axis the
     batch is all-gathered once (G needs cross-shard row products), each
     shard computes its row panel of G (matmul FLOPs scale 1/shards), one
     all-gather replicates G, and the tiny dual loop runs replicated with NO
@@ -597,10 +600,9 @@ class StreamingSGDModel:
 
         Accepts the one-buffer wire format too (``pack_batch``) — bit-
         identical unpack inside the jit step. On the lean RAGGED wire the
-        packed form is the shipped default (+11.4% paired, r3 — per-array
-        request overhead stops hiding once the wire is lean; the app paths
-        pack via the fetch pipeline, apps/common.py); on the padded wire it
-        stays an opt-in (measured neutral there — BENCHMARKS.md)."""
+        packed form is the shipped default (one put per batch; the app
+        paths pack via the fetch pipeline, apps/common.py); on the padded
+        wire it stays an opt-in."""
         self._weights, out = self._step(self._weights, batch)
         return out
 

@@ -31,9 +31,9 @@ learner. Nothing here is approximate: it is the same recursion in a
 different basis (floating-point summation order differs; differential tests
 in tests/test_gram_sgd.py pin both paths together).
 
-Even the G build avoids scatters. XLA lowers a [B·L]-update scatter into
-[B, 2^18] to ~220 ns/update on a v5e chip (~28 ms/batch — it would dominate
-the whole step), so the dense count matrix is instead built as a batched
+Even the G build avoids scatters. XLA serializes a [B·L]-update scatter
+into [B, 2^18] update by update (its cost on this machine: not measured,
+PERF.md), so the dense count matrix is instead built as a batched
 MXU matmul over a two-level split of the feature index, ``f = hi·K + lo``:
 
     C[b, hi, lo] = Σ_l val[b,l] · 1[hi_l = hi] · 1[lo_l = lo]
@@ -171,8 +171,8 @@ def text_gram(
     row's total absolute mass is ≤ 127, which PROVES every count is an
     integer in [−127, 127] and therefore int8-exact — so the count matrix is
     built by the one-hot matmul straight into int8 and the product is one
-    s8×s8→s32 MXU matmul (~2× bf16 peak on v5e, half the count-matrix
-    bytes), bit-exact. Row mass in (127, 255] keeps the bf16 plane (counts
+    s8×s8→s32 MXU matmul (the v5e's int8 peak is twice its bf16 peak by
+    specification; half the count-matrix bytes), bit-exact. Row mass in (127, 255] keeps the bf16 plane (counts
     ≤ 255 are bf16-exact). The predicates cost one pass over the [B, L]
     token values (not the [B, F] counts). Anything else — fractional values,
     a degenerate row with > 255 mass — takes the exact fallback: f32 scatter

@@ -3,9 +3,9 @@ observability plane (ISSUE 8).
 
 One fixed, small ``[QUALITY_WIDTH]`` f32 vector computed INSIDE the existing
 fused predict-then-train step and appended as a new leaf of ``StepOutput``,
-so it rides the ONE ``device_get`` per tick the pipeline already makes (the
-r2/r3 measurement law: fetches cost ~70–100 ms RTT, device FLOPs are µs and
-nowhere near binding). Everything here is observation-only: no value feeds
+so it rides the ONE ``device_get`` per tick the pipeline already makes (no
+added fetch; what the extra reductions cost on the chip is not measured —
+PERF.md). Everything here is observation-only: no value feeds
 back into the weights, the predictions, or the reported stats — the parity
 law stands, and with the quality leaf disabled the step program is
 structurally the pre-ISSUE-8 program (the leaf is ``None``, an empty
@@ -28,8 +28,8 @@ off the names, tests key off the indices):
   ``QUALITY_NBINS``-bin histogram of the hashed token mass — occupancy is
   the fraction of folded bins touched, top_share the largest bin's mass
   share (a collision/skew proxy for the hash-bucket space; computed as
-  ``QUALITY_NBINS`` fused masked reductions, never a scatter — the [B·L]
-  scatter runs ~220 ns/update serialized, the r2 XLA trap).
+  ``QUALITY_NBINS`` fused masked reductions, never a scatter — XLA
+  serializes a [B·L]-update scatter, lawcheck TW004).
 
 Every reduction takes the optional ``axis_name`` so the same code runs
 single-device and data-parallel (psum over the mesh — all outputs are then

@@ -4,9 +4,8 @@ wire semantics cannot drift between layouts.
 
 The ragged wire (features/batch.py ``RaggedUnitBatch``) ships text as
 concatenated code units + row offsets — no per-row pad bytes on the
-upload-bound transport. The learner rebuilds the padded [B, L] layout
-INSIDE the jit program with one gather (cheap on TPU — it is scatters that
-serialize, not gathers) and case-folds ASCII there, which the padded wire's
+wire. The learner rebuilds the padded [B, L] layout INSIDE the jit
+program with one gather and case-folds ASCII there, which the padded wire's
 C pad copy did on the host. Features are bit-identical either way
 (tests/test_ragged_wire.py).
 

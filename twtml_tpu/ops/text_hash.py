@@ -1,9 +1,8 @@
 """On-device char-bigram HashingTF — featurization moved into the XLA program.
 
 The host featurizer (features/hashing.py, native/fasthash.cpp) hashes bigram
-strings on the CPU and ships (idx, count) pairs. On TPU the host is the
-bottleneck of the streaming hot loop (one usable core), while the hash itself
-is trivially vectorizable: MLlib's HashingTF index for a 2-char term is
+strings on the CPU and ships (idx, count) pairs. That is host work per
+tweet, while the hash itself is trivially vectorizable: MLlib's HashingTF index for a 2-char term is
 ``nonNegativeMod(javaStringHashCode(term), F)`` and Java ``String.hashCode``
 of a 2-unit string is just ``31*c1 + c2`` over its UTF-16 code units
 (max 31*65535 + 65535 < 2^31 — no wraparound, always non-negative). So the

@@ -280,8 +280,8 @@ class MultiHostSGDModel:
     def step(self, local_batch):
         """Dispatch only — returns the StepOutput with predictions still
         GLOBAL (row-sharded). Localization + host transfer live in
-        ``fetch_output`` so the main thread never blocks a transport round
-        trip at dispatch time (r3 advisor: the synchronous lead-side
+        ``fetch_output`` so the main thread never blocks on a device
+        fetch at dispatch time (the synchronous lead-side
         ``local_rows`` here re-introduced exactly the per-batch sync the
         FetchPipeline exists to remove). A PackedBatch from
         ``pack_for_wire`` is already the assembled global wire — pass it
@@ -507,8 +507,7 @@ class MultiHostSGDModel:
         ``jax.device_get`` the fetch paths use (FetchPipeline workers and
         the wall-clock per-batch fetch): global scalars for every host,
         predictions localized to THIS host's contributed rows on the lead
-        only (telemetry is lead-owned; followers skip the row fetch —
-        each is a full transport round trip, BENCHMARKS.md)."""
+        only (telemetry is lead-owned; followers skip the row fetch)."""
         from ..models.base import StepOutput
 
         count, mse, real_stdev, pred_stdev, quality = jax.device_get(  # lawcheck: disable=TW002 -- fetch_output IS the counted seam: FetchPipeline installs it as _fetch, one pooled get per tick (counted in tests/test_distributed_multiprocess.py)

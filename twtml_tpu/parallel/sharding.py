@@ -341,6 +341,8 @@ class ParallelSGDModel:
         self.data_axis = axes[0]
         self.model_axis = axes[1] if len(axes) > 1 else None
         self.num_data = mesh.shape[self.data_axis]
+        # sharding of the last dispatched wire buffer (device_span)
+        self._wire_sharding = None
         out_pred_spec = P(self.data_axis)
         scalar = P()
         if quality and self.model_axis is not None:
@@ -525,6 +527,21 @@ class ParallelSGDModel:
             return np.asarray(multihost_utils.process_allgather(arr, tiled=True))
         return np.asarray(arr)
 
+    def device_span(self) -> dict:
+        """How many devices the weights and the last dispatched packed
+        wire buffer really occupy, read off their shardings (host-side
+        metadata, no fetch). The run record carries it, so N shards on one
+        device can never pass for an N-device run. ``batch`` is 0 until a
+        packed batch was dispatched."""
+        wire = self._wire_sharding
+        return {
+            "weights": min(
+                len(leaf.sharding.device_set)
+                for leaf in jax.tree_util.tree_leaves(self._weights)
+            ),
+            "batch": len(wire.device_set) if wire is not None else 0,
+        }
+
     @property
     def latest_weights(self) -> np.ndarray:
         if isinstance(self._weights, dict):
@@ -655,6 +672,7 @@ class ParallelSGDModel:
                     ),
                     batch.layout,
                 )
+            self._wire_sharding = batch.buffer.sharding
         else:
             self._check_rows(batch.mask.shape[0])
             if (
@@ -688,6 +706,7 @@ class ParallelSGDModel:
                     ),
                     stacked.layout,
                 )
+            self._wire_sharding = stacked.buffer.sharding
             self._weights, outs = self._scan_for(PackedBatch)(
                 self._weights, stacked
             )

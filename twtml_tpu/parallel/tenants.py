@@ -718,8 +718,8 @@ class MultiHostTenantModel:
     def step(self, local_batch) -> StepOutput:
         """Route + split THIS host's rows, stack, assemble the global
         tenant wire on the row axis, and run the stacked program. Dispatch
-        only — the host transfer lives in ``fetch_output`` (the r3 law:
-        the main thread never blocks a transport round trip)."""
+        only — the host transfer lives in ``fetch_output`` (the main
+        thread never blocks on a device fetch)."""
         parts = self.inner.split(local_batch)
         if isinstance(parts[0], RaggedUnitBatch):
             # ragged tenant wire (r20): shared-bucket aligned stack; the

@@ -14,8 +14,8 @@ existed fused inside the train step. This package splits it out as a product:
                   predictions are BIT-identical to the train step's
                   pre-update predictions — the parity law on the read path);
 - ``plane``     — the bounded-latency request coalescer + depth-K pipelined
-                  result fetches through ``apps/common.FetchPipeline`` (the
-                  measured 6.2x-at-depth-8 transport trick, BENCHMARKS r3);
+                  result fetches through ``apps/common.FetchPipeline``
+                  (overlapping ``device_get``s, as the trainer does);
 - ``client``    — the library-level HTTP client (``POST /api/predict``) for
                   load generation and ops scripts;
 - ``fleet``     — the read-fleet router (ISSUE 11): N serve replicas behind

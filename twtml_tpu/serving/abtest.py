@@ -8,9 +8,7 @@ online quality vector. This module closes the A/B loop at serve time:
   predict-only trick on a ``TenantStackModel(num_iterations=0)``, but every
   variant sees the SAME rows — the coalesced predict batch is MIRRORED to
   all M tenants (``prepare_wire_from_parts([batch] * M)``), so challengers
-  ride the champion's dispatch and fetch instead of costing their own
-  (device FLOPs are µs and nowhere near binding; fetches are what cost —
-  the r2 law);
+  ride the champion's dispatch and fetch instead of costing their own;
 - **the champion answers**: live responses select the champion tenant's row
   of the already-fetched ``[M, B]`` predictions. The champion index is
   captured at DISPATCH time and rides the device round trip with the

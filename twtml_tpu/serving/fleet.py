@@ -69,12 +69,11 @@ HEALTH_EVERY_S = 1.0
 HEALTH_TIMEOUT_S = 2.0
 
 # concurrent forward budget: forwards are IO-bound urllib calls that sleep
-# on replica sockets (threads hide IO waits — the one-core law), and the
-# fleet's aggregate in-flight ceiling is N replicas x serve depth, so the
-# router must hold MORE in flight than any one replica can. asyncio's
-# default executor (cpu+4 = 5 threads on the one-core host) capped a
-# 4-replica modeled-RTT fleet at ONE replica's throughput — measured, see
-# BENCHMARKS.md "Read fleet"
+# on replica sockets (threads hide IO waits), and the fleet's aggregate
+# in-flight ceiling is N replicas x serve depth, so the router must hold
+# MORE in flight than any one replica can. asyncio's default executor is
+# cpu+4 threads, which on a small host caps the fleet near one replica's
+# in-flight budget
 FORWARD_WORKERS = 64
 
 

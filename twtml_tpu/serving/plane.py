@@ -7,8 +7,8 @@ serving pays a full dispatch + fetch round trip PER QUERY, while CONCURRENT
 
 - **coalesces** requests into one featurize + ONE dispatch per batch: admit
   until ``--serveBatchRows`` rows or ``--serveMaxWaitMs`` since the oldest
-  admitted request (the bounded-latency knob) — batching is where device
-  FLOPs are free and transfers amortize;
+  admitted request (the bounded-latency knob) — per-dispatch and
+  per-fetch overheads amortize over the batch's rows;
 - **pipelines** the result fetches through the EXISTING
   ``apps/common.FetchPipeline`` at ``--serveDepth`` (default 8): micro-batch
   N+1..N+K dispatch while batch N's predictions are still in flight, so
@@ -136,16 +136,15 @@ class ServingPlane:
             collections.deque(maxlen=COMPLETION_WINDOW)
         )
         self._started_s = time.monotonic()
-        # depth-K pipelined result fetches — the measured 6.2x transport
-        # trick, reused verbatim from the train path (apps/common.py); the
+        # depth-K pipelined result fetches, reused verbatim from the
+        # train path (apps/common.py); the
         # --chaos fetch/step injection points and the FetchWatchdog come
         # with it, so a wedged fetch aborts cleanly instead of hanging
         # every client
         self._pipe = FetchPipeline(
             self._engine, self._deliver, depth=self.depth,
             # the lean one-buffer wire, exactly like the train path (the
-            # measured +11.4% packed-ragged win; the tenant engine's pack
-            # IS its routed tenant wire)
+            # tenant engine's pack IS its routed tenant wire)
             pack=self._engine.accepts_packed,
             abort=self._on_abort,
         )

@@ -197,7 +197,7 @@ class _RowCountQueue(queue.Queue):
         Why not get_nowait in a loop: every ``Queue.get`` notifies
         ``not_full``, so a 2048-row drain woke a bound-blocked producer
         2048 times to re-check and re-sleep against a still-full queue —
-        measurable lock churn on the one-core host. One acquire + one
+        needless lock churn. One acquire + one
         ``notify_all`` per drain instead, and the producer wakes exactly
         once, into a freshly drained bound."""
         out: list = []
@@ -424,15 +424,14 @@ class FeatureStream(RawStream):
         if self.device_hash:
             if self.ragged:
                 # concatenated units + offsets: no per-row pad bytes on the
-                # upload-bound wire (features/batch.RaggedUnitBatch —
-                # measured +14% paired vs the padded wire, BENCHMARKS.md)
+                # wire (features/batch.RaggedUnitBatch)
                 return self.featurizer.featurize_batch_ragged(
                     statuses, row_bucket=self.row_bucket,
                     unit_bucket=self.token_bucket,
                     row_multiple=self.row_multiple,
                 )
             # ship raw code units; the learner hashes bigrams on device
-            # (ops/text_hash.py) — bit-identical features, ~2x host headroom
+            # (ops/text_hash.py) — bit-identical features, less host work
             return self.featurizer.featurize_batch_units(
                 statuses, row_bucket=self.row_bucket,
                 unit_bucket=self.token_bucket, row_multiple=self.row_multiple,

@@ -9,7 +9,7 @@ intake seam (post-parse, pre-featurize — ``FeatureStream._process`` /
 CRC32-framed record with a monotonic lineage id, and every recovery path
 re-ingests from the cursor its checkpoint stamped instead of skipping.
 
-Design points, in the measured-law vocabulary of this repo:
+Design points:
 
 - **Host-side only.** Appends are buffered file writes + one ``flush()``
   (no fsync — a SIGKILL'd process's flushed pages survive in the page
@@ -78,7 +78,7 @@ _PAYLOAD_MAX = 1 << 31  # sanity bound when scanning possibly-garbage tails
 # [text, retweet_count, followers_count, favourites_count, friends_count,
 #  created_at_ms, lang, id, retweeted_status-row-or-null]. Rows, not
 # key-value objects: the C-speed attrgetter + positional JSON encode is
-# ~3.5x faster and ~4x smaller than per-status dicts, and the append sits
+# faster and smaller than per-status dicts, and the append sits
 # on the hot intake seam (bench_journal.py gates the paired overhead).
 _STATUS_FIELDS = operator.attrgetter(
     "text", "retweet_count", "followers_count", "favourites_count",
@@ -188,8 +188,8 @@ class IntakeJournal:
         self._replay_draining = False
         self._committed = (self.next_id, self.rows_total)
         # incrementally-maintained disk total: the per-append gauge update
-        # must not pay an os.listdir + stat sweep per batch on the one-core
-        # host (recomputed exactly at open and on retire/drop)
+        # must not pay an os.listdir + stat sweep per batch
+        # (recomputed exactly at open and on retire/drop)
         self._disk_bytes = self.disk_bytes()
         self._update_disk_gauge()
 

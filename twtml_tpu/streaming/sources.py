@@ -218,7 +218,7 @@ class ReplayFileSource(Source):
         self.loop = loop
 
     # tweets per aggregated ``parse`` span: per-line spans would swamp the
-    # trace at the ~1.2M tweets/s parse rate, so the source thread batches
+    # trace at the C parser's rate, so the source thread batches
     # its parse time into one complete event per this many lines
     PARSE_SPAN_EVERY = 1024
 
@@ -249,8 +249,8 @@ class ReplayFileSource(Source):
                             t_parse, n_parse = 0.0, 0
                     else:
                         # per-line timing stays trace-gated: two clock
-                        # reads per tweet would tax the ~1.2M tweets/s
-                        # parser — the sideband's parse attribution on
+                        # reads per tweet would tax the parser
+                        # — the sideband's parse attribution on
                         # OBJECT ingest therefore needs --trace (the block
                         # parser below always contributes)
                         status = Status.from_json(json.loads(line))
@@ -267,7 +267,7 @@ class ReplayFileSource(Source):
                     else:
                         # as-fast-as-possible replays sample every
                         # PARSE_SPAN_EVERY statuses — per-tweet clock reads
-                        # would tax the ~1.2M tweets/s parser
+                        # would tax the C parser
                         n_lag += 1
                         if n_lag >= self.PARSE_SPAN_EVERY:
                             n_lag = 0

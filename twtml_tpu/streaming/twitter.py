@@ -152,10 +152,9 @@ class BlockTwitterSource(BlockParserMixin, TwitterSource):
     tested against the Status path), yielding columnar ParsedBlocks with no
     per-tweet Python objects between the socket and the featurizer.
 
-    Why: config #2's full-app rate sat ~2× below its protocol stage —
-    the gap is exactly the per-line ``json.loads`` + Status assembly on the
-    one usable core, which the replay path already deletes with this
-    parser (~14× — BENCHMARKS.md component rates).
+    Why: the per-line ``json.loads`` + Status assembly is the live path's
+    host cost per tweet; the replay path already deletes it with this
+    parser (its rate on this machine: not measured, PERF.md).
 
     Flush policy: a block parses when the buffer reaches ``block_bytes``
     OR the first stream activity (line or keep-alive) at least

@@ -78,8 +78,8 @@ BASELINE_NAME = "baseline.json"
 # must sit above ratio x baseline before ONE episode fires (the freshness
 # BREACH_WINDOW shape — burst noise never pages)
 GUARD_WINDOW = 8
-# stages cheaper than this per tick are below timing-noise scale on the
-# one-core host; the sentinel ignores them (a 0.01 ms -> 0.03 ms "3x
+# stages cheaper than this per tick are below timing-noise scale;
+# the sentinel ignores them (a 0.01 ms -> 0.03 ms "3x
 # regression" is jitter, not a verdict)
 GUARD_MIN_BASELINE_MS = 0.5
 # healthy samples required before a baseline stamp is meaningful

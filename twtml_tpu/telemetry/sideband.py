@@ -19,7 +19,7 @@ Three pieces:
   work the batch loop already does). Per-BATCH cost is a handful of
   ``perf_counter`` reads and one dict add — no device traffic, no threads.
   The per-tweet object-parse path stays trace-gated (two clock reads per
-  tweet would tax the ~1.2M tweets/s parser measurably), so ``parse``
+  tweet would tax the parser), so ``parse``
   attribution on object ingest needs ``--trace``; the block parser times
   per MB-scale chunk and always contributes.
 - **SidebandCollector**: turns the clock deltas + registry gauges +

@@ -5,7 +5,8 @@ cadence allgather is a barrier). Given the gathered ``[hosts,
 sideband.WIDTH]`` matrix, this classifier names the gating host (largest
 ``tick_prep_ms`` — the wall time each host spent on its OWN work between
 allgathers, waiting-in-collective excluded) and attributes it to a stage on
-the r2/r3 bottleneck ladder:
+the bottleneck ladder (an ordering inherited from before this machine;
+ROADMAP S2 re-derives it from traces):
 
     upload (dispatch — argument uploads ride it) > parse > featurize >
     fetch > device
@@ -36,8 +37,8 @@ from ..utils import get_logger
 
 log = get_logger("telemetry.straggler")
 
-# sideband stage field → bottleneck-ladder name (dispatch is the upload
-# carrier on this transport — BENCHMARKS.md r2)
+# sideband stage field → bottleneck-ladder name (argument uploads ride
+# the dispatch call)
 LADDER = {
     "dispatch_ms": "upload",
     "parse_ms": "parse",

@@ -9,9 +9,9 @@ import this (it must configure jax before the repo is even on sys.path), but
 follows the same recipe.
 
 Also the one place that says where compiled programs are cached
-(``configure_compile_cache``) and which device a run's arrays land on
-(``device_identity``) — every entry point, the bench children and
-``chip_smoke.py`` share both through ``select_backend``.
+(``configure_compile_cache``) and which devices a run computes on
+(``run_devices`` / ``device_identity``) — every entry point, the bench
+children and ``chip_smoke.py`` share both through ``select_backend``.
 """
 
 from __future__ import annotations
@@ -25,6 +25,11 @@ _COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     ".jax_cache",
 )
+# bound for the in-checkout default only (jax evicts least-recently-used
+# entries past it): the ragged wire's bucketed shapes keep minting programs
+# in a long-lived app, and an unbounded directory inside a checkout is a
+# slow leak. A cache the operator placed is the operator's to size.
+_COMPILE_CACHE_MAX_BYTES = 1 << 30
 
 
 def backends_initialized() -> bool:
@@ -39,7 +44,11 @@ def configure_compile_cache() -> str:
     """Point jax's persistent compilation cache somewhere a LATER process
     finds again, and return that directory. Where ``JAX_COMPILATION_CACHE_DIR``
     is set the operator placed the cache (jax reads the variable itself) and
-    nothing is set in code; otherwise the one fixed in-checkout directory."""
+    nothing is set in code; otherwise the one fixed in-checkout directory,
+    size-bounded. Either way the cache outlives the process only where the
+    directory does: a machine that starts from a fresh copy of the tree
+    (every chip tool call) starts cold unless the variable points at
+    storage that machine keeps."""
     placed = os.environ.get(COMPILE_CACHE_ENV, "")
     if placed:
         return placed
@@ -47,26 +56,37 @@ def configure_compile_cache() -> str:
 
     if jax.config.jax_compilation_cache_dir != _COMPILE_CACHE_DIR:
         jax.config.update("jax_compilation_cache_dir", _COMPILE_CACHE_DIR)
+        jax.config.update(
+            "jax_compilation_cache_max_size", _COMPILE_CACHE_MAX_BYTES
+        )
     return _COMPILE_CACHE_DIR
 
 
-def device_identity() -> dict:
-    """The device this process's un-placed arrays and programs land on, as
-    jax reports it — what every run record and benchmark line names so a
-    CPU run can never be read as a device result. Honors
-    ``jax_default_device`` (how ``chip_smoke.py`` runs its CPU reference
-    inside a process that holds the chip). Initializes the backend."""
+def run_devices() -> list:
+    """The devices this process computes on: every device of the platform
+    its un-placed arrays and programs land on. Honors ``jax_default_device``
+    (how ``chip_smoke.py`` runs its CPU reference inside a process that
+    holds the chip), so a mesh built from this list and the identity in the
+    run record can never name different platforms. Initializes the backend."""
     import jax
 
     dev = jax.config.jax_default_device
     if isinstance(dev, str):
-        dev = jax.devices(dev)[0]
+        return jax.devices(dev)
     if dev is None:
-        dev = jax.devices()[0]
+        return jax.devices()
+    return jax.devices(dev.platform)
+
+
+def device_identity() -> dict:
+    """``run_devices()`` as jax reports it — what every run record and
+    benchmark line names so a CPU run can never be read as a device
+    result."""
+    devices = run_devices()
     return {
-        "platform": dev.platform,
-        "kind": dev.device_kind,
-        "count": len(jax.devices(dev.platform)),
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
     }
 
 

@@ -148,9 +148,8 @@ class Server:
             body = await request.read()
             loop = asyncio.get_event_loop()
             # the router's OWN forward pool: asyncio's default executor is
-            # cpu+4 threads — 5 on the one-core host, which would cap a
-            # whole fleet at ~one replica's in-flight budget (measured,
-            # BENCHMARKS.md "Read fleet")
+            # cpu+4 threads, which on a small host would cap a whole
+            # fleet near one replica's in-flight budget
             status, payload = await loop.run_in_executor(
                 getattr(self._fleet, "executor", None),
                 self._fleet.predict, body,
