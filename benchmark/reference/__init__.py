@@ -1,0 +1,1 @@
+"""Files found by name (benchmark/README.md)."""

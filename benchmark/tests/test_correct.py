@@ -1,0 +1,67 @@
+"""Tests of the comparison that decides ``correct``, at sizes a test run can
+hold (CPU, rehearsal sizes). Run by hand:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+
+1. the CONTROL (the reference in bfloat16, in the program's place) comes out
+   not correct, on three seeds, in every cell;
+2. the rest of a run, driven with the harness's look for a chip skipped
+   (``--rehearse``) and the timed path BROKEN underneath — a train step that
+   returns its state unchanged — sees ``correct`` come out false;
+   unbroken, true.
+"""
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from benchmark import control, manifest, run
+
+ROOT = manifest.ROOT
+CELLS = [w["name"] for w in manifest.load()["workloads"]]
+
+BREAK_TRAIN = """
+from twtml_tpu.models import sgd
+_step = sgd.StreamingSGDModel.step
+def step(self, batch):
+    w = self._weights + 0          # donated below: keep a copy
+    out = _step(self, batch)
+    self._weights = w              # the state comes back unchanged
+    return out
+sgd.StreamingSGDModel.step = step
+"""
+
+def _cell(name):
+    cell = manifest.cell(manifest.load(), name)
+    run.shrink_for_rehearsal(cell)
+    return cell
+
+
+@pytest.mark.parametrize("seed", [11, 2147483659, 3000000019])
+@pytest.mark.parametrize("name", CELLS)
+def test_control_is_not_correct(name, seed):
+    args = argparse.Namespace(seed=seed, control="bf16")
+    assert control.run(_cell(name), args)["correct"] is False
+
+
+def _drive(name, patch):
+    code = patch + (
+        "\nimport sys\nfrom benchmark import run\n"
+        f"sys.exit(run.main(['--workload', {name!r}, '--seed', '2147483659', "
+        "'--seconds', '2', '--trace', '0', '--rehearse']))\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("patch,want", [("", True), (BREAK_TRAIN, False)])
+def test_broken_path_is_seen(name, patch, want):
+    assert _drive(name, patch)["correct"] is want
