@@ -1,0 +1,9 @@
+"""Device time per batch in the step's ``other`` stage: everything under no reported stage: the `unpack` and `quality` scopes and operations that carry no scope name (copies and the like that the compiler adds).
+``benchmark/stage_times.py``: every nanosecond of the profile's ``XLA Ops``
+line goes to the innermost operation covering it, an operation's stage is
+the first ``jax.named_scope`` name on its op-name path, and the eight
+``stage_ms.*`` sum to ``step_device_ms``."""
+
+from benchmark import stage_times
+
+read = stage_times.reader("other")

@@ -27,7 +27,8 @@ def test_null_tracer_is_noop():
     with tr.span("anything", rows=1):
         pass
     tr.instant("x")
-    tr.counter("y", v=1)
+    with tr.batch_scope(1):
+        pass
     tr.close()  # all no-ops
 
 
@@ -67,15 +68,14 @@ def test_crash_flush_leaves_events_on_disk(tmp_path):
     assert ev["args"]["error"] == "RuntimeError"
 
 
-def test_instant_and_counter_events(tmp_path):
+def test_instant_events(tmp_path):
     path = str(tmp_path / "i.trace")
     tr = trace.install(path)
     tr.instant("health_phase", phase="degraded", latency_ms=412.0)
-    tr.counter("fetch.queue_depth", depth=5)
     trace.uninstall()
     events = trace_report.load_events(path)
     kinds = {e["ph"] for e in events}
-    assert "i" in kinds and "C" in kinds
+    assert "i" in kinds
     summary = trace_report.summarize(events)
     assert summary["health_transitions"] == [
         {"phase": "degraded", "latency_ms": 412.0}
@@ -276,3 +276,182 @@ def test_trace_off_leaves_no_file(tmp_path):
     _run_linear(tmp_path, [])
     assert not list(tmp_path.glob("*.trace"))
     assert not trace.get().enabled
+
+
+# ---------------------------------------------------------------------------
+# PR 24: batch ids, compile spans, waits — and nothing at all while off
+
+
+def _spans(path, name):
+    return [e for e in trace_report.load_events(str(path))
+            if e.get("ph") == "X" and e["name"] == name]
+
+
+def test_every_published_batch_keeps_one_id_through_the_pipeline(tmp_path):
+    """featurize, dispatch, fetch (a pool thread) and stats_publish of the
+    n-th batch all carry batch == n, the scheduler's own count; and the
+    fetch count is still one per batch with the new spans in the run."""
+    metrics_mod.reset_for_tests()
+    trace_path = tmp_path / "ids.trace"
+    totals, fetches = _run_linear(tmp_path / "on", ["--trace", str(trace_path)])
+    assert totals["batches"] == 4 and fetches == 4
+    for stage in ("featurize", "dispatch", "fetch", "stats_publish"):
+        ids = sorted(e["args"]["batch"] for e in _spans(trace_path, stage)
+                     if "batch" in e.get("args", {}))
+        assert ids == [1, 2, 3, 4], (stage, ids)
+    # stats_publish keeps the batch's row count beside its id
+    assert all(e["args"]["rows"] == 16
+               for e in _spans(trace_path, "stats_publish"))
+    # the ragged wire's bucket is data-dependent: warmup_compile compiles
+    # nothing, and the step compiles ONCE, inside batch 1's dispatch, under
+    # a name that no bucket or wire form changes
+    (warm,) = _spans(trace_path, "warmup_compile")
+    compiles = _spans(trace_path, "compile")
+    assert not [e for e in compiles
+                if e["args"]["during"] == "warmup_compile"]
+    (step,) = [e for e in compiles if e["args"]["fun"] == "jit(train_step)"]
+    (first,) = [e for e in _spans(trace_path, "dispatch")
+                if e["args"]["batch"] == 1]
+    assert step["args"]["during"] == "dispatch"
+    assert first["ts"] <= step["ts"] + step["dur"] <= first["ts"] + first["dur"] + 1
+    sig = step["args"]["signature"]
+    assert (sig["rows"], sig["row_len"], sig["units"]) == (16, 64, "uint8")
+    assert sig["wire"] and sig["units_len"] > 0
+
+
+def test_compile_span_for_a_new_shape_and_not_for_its_repeat(tmp_path):
+    import jax
+    import numpy as np
+
+    path = tmp_path / "c.trace"
+    tr = trace.install(str(path))
+    step = jax.jit(lambda x: x * 2.0 + 1.0)
+
+    def dispatch(rows):
+        with tr.batch_scope(rows), tr.span(
+            "dispatch", signature=lambda: {"rows": rows, "wire": "test"}
+        ):
+            step(np.ones(rows, np.float32)).block_until_ready()
+
+    dispatch(3)
+    dispatch(3)   # the repeat: served by jit's cache, compiles nothing
+    dispatch(5)
+    step(np.ones(7, np.float32))   # outside any span
+    trace.uninstall()
+    compiles = _spans(path, "compile")
+    by_rows = [e["args"]["signature"]["rows"] for e in compiles
+               if e["args"]["during"] == "dispatch"]
+    assert sorted(by_rows) == [3, 5]
+    for e in compiles:
+        assert e["args"]["seconds"] >= 0 and "cache_hit" in e["args"]
+        assert e["dur"] == pytest.approx(e["args"]["seconds"] * 1e6, abs=100)
+    (outside,) = [e for e in compiles if e["args"]["during"] == "startup"]
+    assert outside["args"]["signature"] is None
+    # the dispatch span's own args carry the id, not the signature
+    assert {e["args"]["batch"] for e in _spans(path, "dispatch")} == {3, 5}
+
+
+def _fill(q, n_items, rows):
+    class Block:
+        pass
+
+    for _ in range(n_items):
+        item = Block()
+        item.rows = rows
+        q.put(item)
+
+
+@pytest.mark.parametrize("consumer_sleep_s, bound, waits", [
+    (0.15, 16, True),     # bound = one batch, slow consumer: the producer waits
+    (0.0, 16 * 64, False),  # room for everything: it never does
+])
+def test_intake_wait_only_when_the_producer_waited(
+    tmp_path, consumer_sleep_s, bound, waits
+):
+    import threading
+    import time
+
+    from twtml_tpu.streaming.context import _RowCountQueue
+
+    path = tmp_path / "w.trace"
+    trace.install(str(path))
+    q = _RowCountQueue()
+    q.configure_bound(bound, "block")
+    producer = threading.Thread(target=_fill, args=(q, 4, 16), daemon=True)
+    producer.start()
+    drained = 0
+    deadline = time.monotonic() + 20.0
+    while drained < 4 and time.monotonic() < deadline:
+        time.sleep(consumer_sleep_s)
+        drained += len(q.drain_rows(16))
+    producer.join(timeout=10.0)
+    assert not producer.is_alive() and drained == 4
+    trace.uninstall()
+    spans = _spans(path, "intake_wait")
+    if not waits:
+        assert spans == []
+        return
+    assert spans and all(e["args"]["rows"] == 16 for e in spans)
+    assert sum(e["dur"] for e in spans) >= 0.1e6
+
+
+def test_deliver_wait_only_when_the_result_was_not_ready(tmp_path):
+    """FetchPipeline._emit_one spans ``deliver_wait`` (with the delivered
+    batch's id) only if it had to wait for the oldest in-flight fetch."""
+    import threading
+
+    from twtml_tpu.apps.common import FetchPipeline
+
+    gate = threading.Event()
+
+    class Model:
+        def step(self, batch):
+            return batch
+
+        def fetch_output(self, out):
+            if out == "slow":
+                gate.wait(10.0)
+            return out
+
+    delivered = []
+    path = tmp_path / "d.trace"
+    tr = trace.install(str(path))
+    pipe = FetchPipeline(
+        Model(), lambda out, batch, t, at_boundary: delivered.append(out),
+        depth=4, deterministic=True,
+    )
+    try:
+        for seq, batch in enumerate(["fast", "slow"], start=1):
+            with tr.batch_scope(seq):
+                pipe.on_batch(batch, 0.0)
+        pipe._pending[0][0].result(timeout=10.0)   # batch 1 is ready
+        threading.Timer(0.05, gate.set).start()
+    finally:
+        pipe.flush()
+    trace.uninstall()
+    assert delivered == ["fast", "slow"]
+    (wait,) = _spans(path, "deliver_wait")
+    assert wait["args"]["batch"] == 2 and wait["dur"] >= 0.02e6
+    assert sorted(e["args"]["batch"] for e in _spans(path, "fetch")) == [1, 2]
+
+
+def test_tracing_off_registers_no_listener_and_writes_nothing(tmp_path):
+    import jax
+    from jax._src import monitoring as mon
+
+    def listening():
+        return (trace._on_compile in mon.get_event_duration_listeners(),
+                trace._on_cache_hit in mon.get_event_listeners())
+
+    assert listening() == (False, False)
+    jax.jit(lambda x: x - 3.0)(1.0)   # compiles; nobody is told
+    assert not list(tmp_path.iterdir())
+    path = tmp_path / "on.trace"
+    trace.install(str(path))
+    assert listening() == (True, True)
+    trace.install(str(path))          # a second install registers no second
+    assert mon.get_event_duration_listeners().count(trace._on_compile) == 1
+    trace.uninstall()
+    assert listening() == (False, False)
+    jax.jit(lambda x: x - 4.0)(1.0)
+    assert _spans(path, "compile") == []

@@ -12,6 +12,7 @@ models/kmeans.py), with the CLI face unchanged.
 
 from __future__ import annotations
 
+from ..features.batch import wire_signature
 from ..models.linear import StreamingLinearRegressionWithSGD
 from ..streaming import faults as _faults
 from ..streaming import journal as _journal
@@ -1951,8 +1952,10 @@ class SuperBatcher:
             deadline_s=fetch_deadline_s, retries=fetch_retries,
         )
         self._buf: list = []
+        self._seqs: list = []  # the buffered batches' --trace batch ids
         self._sig = None
-        self._inflight: list = []  # [(future, group, outs)] oldest first
+        # [(future, group, outs, lease, batch ids)] oldest first
+        self._inflight: list = []
         self._dispatched = 0
         # checkpoint cadence runs on its own MONOTONIC counter, exactly as
         # in FetchPipeline: a refund_dispatch adjusts only the cap
@@ -1984,20 +1987,28 @@ class SuperBatcher:
             self._close_group()  # shape/dtype changed: close, never drop
         self._sig = sig
         self._buf.append((batch, batch_time))
+        self._seqs.append(_trace.current_batch())
         if len(self._buf) >= self.k:
             self._close_group()
 
     def _emit_group(self) -> None:
         from ..models.base import StepOutput
 
-        future, group, outs, lease = self._inflight.pop(0)
+        future, group, outs, lease, seqs = self._inflight.pop(0)
+        tr = _trace.get()
         try:
-            host = self._watchdog.await_result(
-                future,
-                lambda: self._pool.submit(
-                    self._timed_fetch_many, outs, len(group)
-                ),
-            )
+            # the scheduler's wait for the oldest in-flight group (see
+            # FetchPipeline._emit_one)
+            with tr.batch_scope(seqs[0]), (
+                _trace.NULL_SPAN if future.done()
+                else tr.span("deliver_wait", group=len(group))
+            ):
+                host = self._watchdog.await_result(
+                    future,
+                    lambda: self._pool.submit(
+                        self._timed_fetch_many, outs, len(group), seqs[0]
+                    ),
+                )
         except FetchAbort:
             # the group trained but its outputs are gone with the wedged
             # transport: refund the cap slots so every dispatched batch is
@@ -2015,62 +2026,51 @@ class SuperBatcher:
         # drained is the whole weights-current condition
         boundary_ok = not self._inflight
         for k, (batch, t) in enumerate(group):
-            self.handle(
-                # a multi-host follower's predictions field is None (the
-                # lead owns per-row telemetry) — pass None through
-                StepOutput(*(
-                    None if f is None else f[k] for f in host
-                )),
-                batch, t,
-                at_boundary=(k == last and boundary_ok),
-            )
+            with tr.batch_scope(seqs[k]):
+                self.handle(
+                    # a multi-host follower's predictions field is None
+                    # (the lead owns per-row telemetry) — pass None through
+                    StepOutput(*(
+                        None if f is None else f[k] for f in host
+                    )),
+                    batch, t,
+                    at_boundary=(k == last and boundary_ok),
+                )
         if lease is not None:
             # fetch delivered ⇒ the dispatch consumed its wire bytes;
             # retired AFTER the handlers (the lease may chain the group
             # batches' featurize-stage arrays — see FetchPipeline)
             lease.retire()
 
-    def _timed_fetch_many(self, outs, group_len: int):
-        """Timed pooled group fetch — see FetchPipeline._timed_fetch."""
-        import time as _time
+    def _timed_fetch_many(self, outs, group_len: int, seq=None):
+        """Timed pooled group fetch — see FetchPipeline._timed_fetch
+        (``seq``: the batch id of the group's first batch)."""
+        return self._timed(
+            self._fetch_many, outs, seq,
+            depth=self.fetch_depth, group=group_len,
+        )
 
-        import jax
-
-        fetch = self._fetch_many or jax.device_get
-        t0 = _time.perf_counter()
-        _faults.perturb("fetch")  # --chaos: inside the timed window, so
-        # injected stalls feed the health monitor like real ones
-        host = fetch(outs)
-        dt = _time.perf_counter() - t0
-        self._fetch_count.inc()
-        self._fetch_hist.observe(dt)
-        self._health.observe(dt)
-        _sideband.record_stage("fetch", dt)
-        tr = _trace.get()
-        if tr.enabled:
-            tr.complete("fetch", t0, dt, depth=self.fetch_depth,
-                        group=group_len)
-        return host
-
-    def _timed_fetch_one(self, out_dev):
+    def _timed_fetch_one(self, out_dev, seq=None):
         """Single-batch pooled fetch (the partial-group path), timed like
         ``_timed_fetch_many``."""
+        return self._timed(self._fetch_one, out_dev, seq, depth=1)
+
+    def _timed(self, fetch, out, seq, **span_args):
         import time as _time
 
         import jax
 
-        fetch = self._fetch_one or jax.device_get
+        tr = _trace.get()
         t0 = _time.perf_counter()
-        _faults.perturb("fetch")
-        host = fetch(out_dev)
+        with tr.batch_scope(seq), tr.span("fetch", **span_args):
+            _faults.perturb("fetch")  # --chaos: inside the timed window,
+            # so injected stalls feed the health monitor like real ones
+            host = (fetch or jax.device_get)(out)
         dt = _time.perf_counter() - t0
         self._fetch_count.inc()
         self._fetch_hist.observe(dt)
         self._health.observe(dt)
         _sideband.record_stage("fetch", dt)
-        tr = _trace.get()
-        if tr.enabled:
-            tr.complete("fetch", t0, dt, depth=1)
         return host
 
     def refund_dispatch(self) -> None:
@@ -2102,7 +2102,7 @@ class SuperBatcher:
         if not self._inflight:
             return 0
         groups, rows = len(self._inflight), 0
-        for future, group, _outs, lease in self._inflight:
+        for future, group, _outs, lease, _seqs in self._inflight:
             future.cancel()  # not-yet-started fetches never run
             for batch, _t in group:
                 rows += int(getattr(batch, "num_valid", 0) or 0)
@@ -2175,6 +2175,7 @@ class SuperBatcher:
         if not self._buf:
             return
         group, self._buf = self._buf, []
+        seqs, self._seqs = self._seqs, []
         if len(group) < self.k:
             # partial group (tail, or a shape change): plain steps — the
             # same math, and no fresh scan compile for a one-off length.
@@ -2185,7 +2186,7 @@ class SuperBatcher:
             # coalesced layout's lean offsets.
             self._drain()
             tr = _trace.get()
-            for batch, t in group:
+            for (batch, t), seq in zip(group, seqs):
                 if self.max_dispatch and self._dispatched >= self.max_dispatch:
                     return
                 import time as _time
@@ -2210,13 +2211,15 @@ class SuperBatcher:
                     )
                     _record_wire_codec(wire, self._codec_requested())
                 t0 = _time.perf_counter()
-                _faults.perturb("step")  # --chaos dispatch injection
-                out_dev = self.model.step(wire)
+                with tr.batch_scope(seq), tr.span(
+                    "dispatch",
+                    signature=lambda: wire_signature(wire, batch),
+                ):
+                    _faults.perturb("step")  # --chaos dispatch injection
+                    out_dev = self.model.step(wire)
                 dt = _time.perf_counter() - t0
                 _sideband.record_stage("dispatch", dt)
                 _lineage.mark_dispatch()
-                if tr.enabled:
-                    tr.complete("dispatch", t0, dt)
                 # dispatch-time accounting, as on the grouped path; if the
                 # awaited fetch aborts, the slot is refunded (the batch
                 # trained but was never delivered — cap accounting follows
@@ -2229,9 +2232,11 @@ class SuperBatcher:
                 lease = _dispatch_lease(wire, batch)
                 try:
                     out = self._watchdog.await_result(
-                        self._pool.submit(self._timed_fetch_one, out_dev),
+                        self._pool.submit(
+                            self._timed_fetch_one, out_dev, seq
+                        ),
                         lambda: self._pool.submit(
-                            self._timed_fetch_one, out_dev
+                            self._timed_fetch_one, out_dev, seq
                         ),
                     )
                 except FetchAbort:
@@ -2239,7 +2244,8 @@ class SuperBatcher:
                         lease.discard()  # wedged dispatch: no reuse
                     self.refund_dispatch()
                     raise
-                self.handle(out, batch, t, at_boundary=True)
+                with tr.batch_scope(seq):
+                    self.handle(out, batch, t, at_boundary=True)
                 if lease is not None:
                     lease.retire()  # after the handler — see _emit_one
             return
@@ -2256,17 +2262,21 @@ class SuperBatcher:
 
         tr = _trace.get()
         t0 = _time.perf_counter()
-        _faults.perturb("step")  # --chaos dispatch injection
-        outs = self.model.step_many(wire)
+        # one dispatch for the whole group: it carries its first batch's id
+        with tr.batch_scope(seqs[0]), tr.span(
+            "dispatch", group=len(group), depth=len(self._inflight),
+            signature=lambda: wire_signature(wire, group[0][0]),
+        ):
+            _faults.perturb("step")  # --chaos dispatch injection
+            outs = self.model.step_many(wire)
         dt = _time.perf_counter() - t0
         _sideband.record_stage("dispatch", dt)
         _lineage.mark_dispatch(len(group))
-        if tr.enabled:
-            tr.complete("dispatch", t0, dt, group=len(group),
-                        depth=len(self._inflight))
         self._inflight.append(
-            (self._pool.submit(self._timed_fetch_many, outs, len(group)),
-             group, outs, _dispatch_lease(wire, *(b for b, _ in group)))
+            (self._pool.submit(
+                self._timed_fetch_many, outs, len(group), seqs[0]),
+             group, outs, _dispatch_lease(wire, *(b for b, _ in group)),
+             seqs)
         )
         self._depth_gauge.set(len(self._inflight))
         self._dispatched += len(group)
@@ -2290,7 +2300,7 @@ class SuperBatcher:
                 # are gone with the wedged transport — cap accounting follows
                 # deliveries; buffered batches never dispatched, nothing to
                 # refund there)
-                for _future, group, _outs, lease in self._inflight:
+                for _future, group, _outs, lease, _seqs in self._inflight:
                     if lease is not None:
                         lease.discard()  # wedged dispatches: no reuse
                     for _ in group:
@@ -2302,6 +2312,7 @@ class SuperBatcher:
                 )
                 self._inflight.clear()
                 self._buf.clear()
+                self._seqs.clear()
         finally:
             # shutdown in a finally: an exception re-raised from
             # future.result() during the drain must not leak the executor
@@ -2393,7 +2404,8 @@ class FetchPipeline:
             self._health, abort=abort,
             deadline_s=fetch_deadline_s, retries=fetch_retries,
         )
-        self._pending: list = []  # [(future, out, batch, t)] oldest first
+        # [(future, out, batch, t, lease, batch id)] oldest first
+        self._pending: list = []
         self._head_since = None  # poll()'s head-fetch deadline bookkeeping
         self._dispatched = 0
         # checkpoint cadence runs on its own MONOTONIC counter: a
@@ -2402,43 +2414,54 @@ class FetchPipeline:
         self._cadence = 0
         self._last_boundary = 0
 
-    def _timed_fetch(self, out):
+    def _timed_fetch(self, out, seq=None):
         """The pooled host fetch, timed for the fetch-health monitor and
-        the ``fetch`` trace stage. This wraps the ONE fetch the pipeline
-        already makes per batch — instrumentation never adds a
-        ``device_get``."""
+        the ``fetch`` trace stage (``seq``: the batch id it carries). This
+        wraps the ONE fetch the pipeline already makes per batch —
+        instrumentation never adds a ``device_get``."""
         import time as _time
 
         import jax
 
         fetch = self._fetch or jax.device_get
+        tr = _trace.get()
         t0 = _time.perf_counter()
-        _faults.perturb("fetch")  # --chaos: inside the timed window, so
-        # injected stalls feed the health monitor like real ones
-        host = fetch(out)
+        with tr.batch_scope(seq), tr.span("fetch", depth=self.depth):
+            _faults.perturb("fetch")  # --chaos: inside the timed window,
+            # so injected stalls feed the health monitor like real ones
+            host = fetch(out)
         dt = _time.perf_counter() - t0
         self._fetch_count.inc()
         self._fetch_hist.observe(dt)
         self._health.observe(dt)
         _sideband.record_stage("fetch", dt)
-        tr = _trace.get()
-        if tr.enabled:
-            tr.complete("fetch", t0, dt, depth=self.depth)
         return host
 
     def _emit_one(self) -> None:
-        future, out, batch, t, lease = self._pending.pop(0)
-        try:
-            host = self._watchdog.await_result(
-                future, lambda: self._pool.submit(self._timed_fetch, out)
-            )
-        except FetchAbort:
-            # the dispatch may still execute on the wedged backend: never
-            # donate its wire buffer back for reuse (features/arena.py)
-            if lease is not None:
-                lease.discard()
-            raise
-        self.handle(host, batch, t, at_boundary=not self._pending)
+        future, out, batch, t, lease, seq = self._pending.pop(0)
+        tr = _trace.get()
+        # the delivered batch's id, for deliver_wait and the handler's
+        # stats_publish (a newer batch's scope may be open around this)
+        with tr.batch_scope(seq):
+            try:
+                # the scheduler's wait for the oldest in-flight result: its
+                # slack (PERF.md §3); a span only when it has to wait
+                with (_trace.NULL_SPAN if future.done()
+                      else tr.span("deliver_wait")):
+                    host = self._watchdog.await_result(
+                        future,
+                        lambda: self._pool.submit(
+                            self._timed_fetch, out, seq
+                        ),
+                    )
+            except FetchAbort:
+                # the dispatch may still execute on the wedged backend:
+                # never donate its wire buffer back for reuse
+                # (features/arena.py)
+                if lease is not None:
+                    lease.discard()
+                raise
+            self.handle(host, batch, t, at_boundary=not self._pending)
         if lease is not None:
             # fetch delivered ⇒ the dispatch consumed its wire bytes: the
             # arena lease retires to the pool. AFTER the handler — the
@@ -2509,16 +2532,17 @@ class FetchPipeline:
         # --chaos injection INSIDE the window so injected dispatch stalls
         # attribute like real ones
         t0 = _time.perf_counter()
-        _faults.perturb("step")  # --chaos dispatch injection
-        out = self.model.step(wire)  # dispatch on the MAIN thread
+        with tr.span("dispatch", depth=len(self._pending),
+                     signature=lambda: wire_signature(wire, batch)):
+            _faults.perturb("step")  # --chaos dispatch injection
+            out = self.model.step(wire)  # dispatch on the MAIN thread
         dt = _time.perf_counter() - t0
         _sideband.record_stage("dispatch", dt)
         _lineage.mark_dispatch()
-        if tr.enabled:
-            tr.complete("dispatch", t0, dt, depth=len(self._pending))
+        seq = _trace.current_batch()
         self._pending.append(
-            (self._pool.submit(self._timed_fetch, out), out, batch, t,
-             _dispatch_lease(wire, batch))
+            (self._pool.submit(self._timed_fetch, out, seq), out, batch, t,
+             _dispatch_lease(wire, batch), seq)
         )
         self._depth_gauge.set(len(self._pending))
         self._dispatched += 1
@@ -2561,7 +2585,7 @@ class FetchPipeline:
         if not self._pending:
             return 0
         n, rows = len(self._pending), 0
-        for future, _out, batch, _t, lease in self._pending:
+        for future, _out, batch, _t, lease, _seq in self._pending:
             future.cancel()  # not-yet-started fetches never run
             rows += int(getattr(batch, "num_valid", 0) or 0)
             self.refund_dispatch()
@@ -2627,7 +2651,7 @@ class FetchPipeline:
                     "dropping %d undelivered batch output(s) after the "
                     "fetch abort", len(self._pending),
                 )
-                for _f, _o, _b, _t, lease in self._pending:
+                for _f, _o, _b, _t, lease, _seq in self._pending:
                     if lease is not None:
                         lease.discard()  # wedged dispatches: no reuse
                 self._pending.clear()
@@ -3152,25 +3176,24 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
                 wire = batch
             lease = _dispatch_lease(wire, batch)
             td = _time.perf_counter()
-            _faults.perturb("step")  # --chaos dispatch injection
-            out = model.step(wire)
+            with tr.span("dispatch",
+                         signature=lambda: wire_signature(wire, batch)):
+                _faults.perturb("step")  # --chaos dispatch injection
+                out = model.step(wire)
             d_dt = _time.perf_counter() - td
             _sideband.record_stage("dispatch", d_dt)
             _lineage.mark_dispatch()
-            if tr.enabled:
-                tr.complete("dispatch", td, d_dt)
             fetch = getattr(model, "fetch_output", None) or jax.device_get
             t0 = _time.perf_counter()
-            _faults.perturb("fetch")
-            out = fetch(out)
+            with tr.span("fetch", depth=1):
+                _faults.perturb("fetch")
+                out = fetch(out)
             dt = _time.perf_counter() - t0
             reg = _metrics.get_registry()
             reg.counter("fetch.count").inc()
             reg.histogram("fetch.latency_s").observe(dt)
             _metrics.get_health_monitor().observe(dt)
             _sideband.record_stage("fetch", dt)
-            if tr.enabled:
-                tr.complete("fetch", t0, dt, depth=1)
             handle(out, batch, t, at_boundary=True)
             if lease is not None:
                 lease.retire()  # synchronous fetch: dispatch consumed it
@@ -3231,6 +3254,15 @@ def warmup_compile(stream, model, super_batch: int = 1) -> None:
     weights untouched)."""
     if stream.row_bucket <= 0 or stream.token_bucket <= 0:
         return
+    # what compiled in here is in the trace as ``compile`` spans with
+    # ``during: warmup_compile`` (telemetry/trace.py)
+    with _trace.get().span(
+        "warmup_compile", rows=stream.row_bucket, super_batch=super_batch
+    ):
+        _warmup_compile(stream, model, super_batch)
+
+
+def _warmup_compile(stream, model, super_batch: int) -> None:
     import time as _time
 
     import numpy as np

@@ -258,6 +258,25 @@ def wire_nbytes(batch) -> int:
     return total
 
 
+def wire_signature(wire, batch) -> dict:
+    """What a ``compile`` trace span says of the call being dispatched
+    (telemetry/trace.py): everything of ``batch`` (the unpacked view of
+    ``wire``) that the compiled program's shape depends on — rows, row
+    length, units dtype and bucket — and the wire form. Shapes only."""
+    units = getattr(batch, "units", None)
+    width = units if units is not None else batch.token_idx
+    return {
+        "rows": int(batch.mask.shape[-1]),
+        "row_len": int(getattr(batch, "row_len", 0) or width.shape[-1]),
+        "units": str(units.dtype) if units is not None else "hashed",
+        "units_len": int(units.shape[-1]) if units is not None else 0,
+        "wire": (
+            wire.layout[0] if isinstance(wire, PackedBatch)
+            else type(wire).__name__
+        ),
+    }
+
+
 def wire_composition(batch) -> "dict[str, int]":
     """The per-batch wire split {units, offsets, sideband} in bytes — what
     the Lean-wire-v2 offset shrink moves, surfaced as gauges in the metrics

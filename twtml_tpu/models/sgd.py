@@ -63,6 +63,18 @@ DENSE_TEXT_FEATURE_LIMIT = 8192
 
 MLLIB_SAMPLING_SEED = 42  # GradientDescent samples with seed 42+i
 
+# The device stages of the train step, by name: ``jax.named_scope``s here
+# and in ops/{ragged,text_hash,gram}.py, so a profile sums kernels by stage
+# and not by HLO text that any edit renumbers (benchmark/stage_times.py
+# reads them; PERF.md §3). Metadata only: the compiled program is the same
+# with or without them. ``predict`` is the raw margin and the batch stats
+# with the pre-update weights (the ``u`` gather); ``gram_count`` the plane
+# gate and the count matrix, ``gram_matmul`` G itself, on whichever plane.
+STAGE_SCOPES = (
+    "unpack", "repad", "hash", "predict", "gram_count", "gram_matmul",
+    "dual_loop", "writeback", "quality",
+)
+
 
 def sgd_inner_loop(
     weights,
@@ -198,19 +210,20 @@ def run_dual_loop(
         residual = residual_fn(raw, labels) * sel
         return {"c": jnp.zeros((), dtype), "alpha": residual}, jnp.sum(sel)
 
-    return sgd_inner_loop(
-        {"c": jnp.ones((), dtype), "alpha": jnp.zeros(labels.shape, dtype)},
-        num_iterations=num_iterations,
-        step_size=step_size,
-        mini_batch_fraction=mini_batch_fraction,
-        l2_reg=l2_reg,
-        convergence_tol=convergence_tol,
-        mask=mask,
-        sample_key=sampling_key(None, mini_batch_fraction),
-        grad_and_count=grad_and_count,
-        norm_sq=dual_norm_sq(p_prev, u, g),
-        vary_axis=vary_axis,
-    )
+    with jax.named_scope("dual_loop"):
+        return sgd_inner_loop(
+            {"c": jnp.ones((), dtype), "alpha": jnp.zeros(labels.shape, dtype)},
+            num_iterations=num_iterations,
+            step_size=step_size,
+            mini_batch_fraction=mini_batch_fraction,
+            l2_reg=l2_reg,
+            convergence_tol=convergence_tol,
+            mask=mask,
+            sample_key=sampling_key(None, mini_batch_fraction),
+            grad_and_count=grad_and_count,
+            norm_sq=dual_norm_sq(p_prev, u, g),
+            vary_axis=vary_axis,
+        )
 
 
 def dual_scale_and_alpha(dual, axis_name: str, rows: int):
@@ -370,14 +383,18 @@ def make_sgd_train_step(
         )
         if axis_name:
             l_idx, l_val, l_num = local_args
-            c, alpha_local = dual_scale_and_alpha(dual, axis_name, l_val.shape[0])
-            delta_text = lax.psum(
-                sparse_grad_text(l_idx, l_val, alpha_local, f_text), axis_name
-            )
-            w_text_new = weights[:f_text] * c + delta_text
-            w_num_new = weights[f_text:] * c + lax.psum(
-                l_num.T @ alpha_local, axis_name
-            )
+            with jax.named_scope("writeback"):
+                c, alpha_local = dual_scale_and_alpha(
+                    dual, axis_name, l_val.shape[0]
+                )
+                delta_text = lax.psum(
+                    sparse_grad_text(l_idx, l_val, alpha_local, f_text),
+                    axis_name,
+                )
+                w_text_new = weights[:f_text] * c + delta_text
+                w_num_new = weights[f_text:] * c + lax.psum(
+                    l_num.T @ alpha_local, axis_name
+                )
         else:
             w_text_new, w_num_new = dual_writeback(
                 weights[:f_text],
@@ -388,14 +405,16 @@ def make_sgd_train_step(
                 token_val,
                 numeric,
             )
-        return jnp.concatenate([w_text_new, w_num_new])
+        with jax.named_scope("writeback"):
+            return jnp.concatenate([w_text_new, w_num_new])
 
     def train_step(weights, batch: FeatureBatch | UnitBatch | PackedBatch):
         dtype = weights.dtype
         if isinstance(batch, PackedBatch):
             # one-buffer wire format: reinterpret in-place (features/batch.py
             # PackedBatch — bit-identical arrays, transfer-count 5 → 1)
-            batch = unpack_batch(batch.buffer, batch.layout)
+            with jax.named_scope("unpack"):
+                batch = unpack_batch(batch.buffer, batch.layout)
         if isinstance(batch, RaggedUnitBatch):
             # ragged wire: the units arrive concatenated (no per-row pad
             # bytes on the transport); ops/ragged.py rebuilds the padded
@@ -418,12 +437,13 @@ def make_sgd_train_step(
             )
         # tokens arrive in a compact wire dtype (batch.compact_tokens);
         # upcast once on device before any gather/scatter
-        batch = batch._replace(
-            token_idx=batch.token_idx.astype(jnp.int32),
-            token_val=batch.token_val.astype(dtype),
-        )
-        mask = batch.mask.astype(dtype)
-        labels = batch.label.astype(dtype)
+        with jax.named_scope("unpack"):
+            batch = batch._replace(
+                token_idx=batch.token_idx.astype(jnp.int32),
+                token_val=batch.token_val.astype(dtype),
+            )
+            mask = batch.mask.astype(dtype)
+            labels = batch.label.astype(dtype)
         x_dense = None
         if not sparse:
             x_dense = jnp.concatenate(
@@ -435,24 +455,26 @@ def make_sgd_train_step(
             )
 
         # ---- predict + stats with pre-update weights --------------------
-        raw = _predict_raw(weights, batch, x_dense)
-        preds = prediction_fn(raw)
-        if round_predictions:
-            preds = jnp_round_half_up(preds)
-        stats = batch_stats(labels, preds, mask, axis_name)
+        with jax.named_scope("predict"):
+            raw = _predict_raw(weights, batch, x_dense)
+            preds = prediction_fn(raw)
+            if round_predictions:
+                preds = jnp_round_half_up(preds)
+            stats = batch_stats(labels, preds, mask, axis_name)
 
         def _quality(w_new):
             # the ISSUE-8 side channel against the post-update weights;
             # None (plane off) keeps the output pytree the HEAD program's
             if not quality:
                 return None
-            return quality_vector(
-                weights, w_new,
-                residual=residual_fn(raw, labels) * mask,
-                preds=preds, labels=labels, mask=mask,
-                numeric=batch.numeric, token_idx=batch.token_idx,
-                token_val=batch.token_val, axis_name=axis_name,
-            )
+            with jax.named_scope("quality"):
+                return quality_vector(
+                    weights, w_new,
+                    residual=residual_fn(raw, labels) * mask,
+                    preds=preds, labels=labels, mask=mask,
+                    numeric=batch.numeric, token_idx=batch.token_idx,
+                    token_val=batch.token_val, axis_name=axis_name,
+                )
 
         # ---- numIterations of mini-batch SGD ----------------------------
         b_global = batch.mask.shape[0] * (lax.axis_size(axis_name) if axis_name else 1)

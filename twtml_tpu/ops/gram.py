@@ -63,6 +63,7 @@ from __future__ import annotations
 
 import os
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -180,21 +181,27 @@ def text_gram(
     """
     if int8_plane is None:
         int8_plane = GRAM_INT8_PLANE
-    val_f = token_val.astype(jnp.float32)
-    # integral, bf16-representable values with row ABSOLUTE mass ≤ 255 ⇒
-    # every count is an integer of magnitude ≤ 255 ⇒ counts and their bf16
-    # products are exact (plain sum would be unsound for mixed-sign values:
-    # cancellation can hide a per-feature count above the bf16 range)
-    integral = jnp.all(val_f == jnp.round(val_f))
-    row_mass = jnp.sum(jnp.abs(val_f), axis=1)
-    vals_ok = (
-        integral
-        & jnp.all(val_f.astype(jnp.bfloat16).astype(jnp.float32) == val_f)
-        & jnp.all(row_mass <= 255.0)
-    )
-    # row absolute mass ≤ 127 tightens every bound to the int8 range: each
-    # |value| ≤ 127 (s8 operand) and each |count| ≤ 127 (s8 count matrix)
-    vals_ok_i8 = integral & jnp.all(row_mass <= 127.0)
+    # device stage names (models/sgd.py STAGE_SCOPES): the three planes
+    # share ``gram_count`` (the plane gate and the count matrix) and
+    # ``gram_matmul``; which plane ran is read from the operand types
+    with jax.named_scope("gram_count"):
+        val_f = token_val.astype(jnp.float32)
+        # integral, bf16-representable values with row ABSOLUTE mass ≤ 255
+        # ⇒ every count is an integer of magnitude ≤ 255 ⇒ counts and their
+        # bf16 products are exact (plain sum would be unsound for
+        # mixed-sign values: cancellation can hide a per-feature count
+        # above the bf16 range)
+        integral = jnp.all(val_f == jnp.round(val_f))
+        row_mass = jnp.sum(jnp.abs(val_f), axis=1)
+        vals_ok = (
+            integral
+            & jnp.all(val_f.astype(jnp.bfloat16).astype(jnp.float32) == val_f)
+            & jnp.all(row_mass <= 255.0)
+        )
+        # row absolute mass ≤ 127 tightens every bound to the int8 range:
+        # each |value| ≤ 127 (s8 operand) and each |count| ≤ 127 (s8 count
+        # matrix)
+        vals_ok_i8 = integral & jnp.all(row_mass <= 127.0)
 
     def left(c):
         """The (possibly row-sliced) left operand. The slice makes the G
@@ -208,18 +215,26 @@ def text_gram(
         return c
 
     def fast_i8(i, v):
-        c = onehot_counts_int8(i, v, f_text)  # [B, F] int8, exact
-        g = jnp.matmul(left(c), c.T, preferred_element_type=jnp.int32)
-        # |G| ≤ (Σ|c_a|)·max|c_b| ≤ 127² < 2²⁴: the f32 cast is exact
-        return g.astype(jnp.float32)
+        with jax.named_scope("gram_count"):
+            c = onehot_counts_int8(i, v, f_text)  # [B, F] int8, exact
+        with jax.named_scope("gram_matmul"):
+            g = jnp.matmul(left(c), c.T, preferred_element_type=jnp.int32)
+            # |G| ≤ (Σ|c_a|)·max|c_b| ≤ 127² < 2²⁴: the f32 cast is exact
+            return g.astype(jnp.float32)
 
     def fast(i, v):
-        c = onehot_counts(i, v, f_text)  # [B, F] bf16, exact
-        return jnp.matmul(left(c), c.T, preferred_element_type=jnp.float32)
+        with jax.named_scope("gram_count"):
+            c = onehot_counts(i, v, f_text)  # [B, F] bf16, exact
+        with jax.named_scope("gram_matmul"):
+            return jnp.matmul(
+                left(c), c.T, preferred_element_type=jnp.float32
+            )
 
     def exact(i, v):
-        c = densify_text(i, v, f_text)  # [B, F] f32
-        return jnp.matmul(left(c), c.T, precision=lax.Precision.HIGHEST)
+        with jax.named_scope("gram_count"):
+            c = densify_text(i, v, f_text)  # [B, F] f32
+        with jax.named_scope("gram_matmul"):
+            return jnp.matmul(left(c), c.T, precision=lax.Precision.HIGHEST)
 
     idx = vals_ok.astype(jnp.int32)
     branches = [exact, fast]
@@ -229,6 +244,7 @@ def text_gram(
     return lax.switch(idx, branches, token_idx, val_f)
 
 
+@jax.named_scope("gram_matmul")
 def add_numeric_block(g_text, numeric, dtype=jnp.float32):
     """G = g_text + N·Nᵀ, cast to the dual loop's dtype — the one place the
     numeric features enter G (shared by every layout so precision handling
@@ -266,6 +282,7 @@ def dual_norm_sq(p_prev, u, g):
     return norm_sq
 
 
+@jax.named_scope("writeback")
 def dual_writeback(w_text, w_num, c, alpha, token_idx, token_val, numeric):
     """W_new = c·W_prev + Zᵀ·α — the one feature-space scatter of the batch.
 

@@ -89,6 +89,7 @@ def units_from_codes(codes, out_len: int):
     return out.reshape(lead + (out_len,))
 
 
+@jax.named_scope("repad")  # device stage name (models/sgd.py STAGE_SCOPES)
 def ragged_repad(units, offsets, row_len: int, rows: int | None = None,
                  deltas: bool = False):
     """(flat units [N], offsets, static L) → (padded int32 [B, L]

@@ -26,9 +26,11 @@ MllibHelper.scala:42-56 + Scala ``text.sliding(2)``):
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("hash")  # device stage name (models/sgd.py STAGE_SCOPES)
 def hash_bigrams_device(units, length, num_features: int, dtype=jnp.float32):
     """[B, L] uint16 code units + [B] lengths → ([B, L-1] idx, [B, L-1] val).
 

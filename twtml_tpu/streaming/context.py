@@ -146,6 +146,7 @@ class _RowCountQueue(queue.Queue):
         if self.max_rows <= 0:
             return super().put(item, block, timeout)
         rows = getattr(item, "rows", 1)
+        waited_since = 0.0
         with self.not_full:
             if self.policy == "block":
                 # admit when empty regardless of size: one item larger
@@ -155,6 +156,7 @@ class _RowCountQueue(queue.Queue):
                     and self.rows_queued + rows > self.max_rows
                     and not self._closed
                 ):
+                    waited_since = waited_since or time.perf_counter()
                     # timed wait belt-and-braces: queue.Queue.get always
                     # notifies not_full, but a missed wakeup must not
                     # wedge the producer forever
@@ -179,6 +181,16 @@ class _RowCountQueue(queue.Queue):
             self._put(item)
             self.unfinished_tasks += 1
             self.not_empty.notify()
+        if waited_since:
+            # the producer's wait on the row bound: the source thread's
+            # slack (PERF.md §3). Written after the mutex is released, and
+            # only when a wait happened
+            tr = _trace.get()
+            if tr.enabled:
+                tr.complete(
+                    "intake_wait", waited_since,
+                    time.perf_counter() - waited_since, rows=rows,
+                )
 
     def putback(self, item) -> None:
         """Return an item to the FRONT of the queue (the drain splitter's
@@ -281,6 +293,7 @@ class FeatureStream(RawStream):
                 "--wire ragged requires --hashOn device"
             )
         self._bucket_overflow_warned = False
+        self.batches_seen = 0  # the batch id of the --trace spans
         # the pinned row shape includes the mesh-divisibility round-up,
         # matching every batch the featurizer emits; fixed at construction
         from ..features.batch import pad_row_count
@@ -451,21 +464,28 @@ class FeatureStream(RawStream):
     def _process(
         self, statuses: list[Status], batch_time: float
     ) -> "FeatureBatch | UnitBatch":
-        # freshness lineage (r16): stamp the batch's record as it enters
-        # featurize — the event-time span + a stage-clock snapshot; no-op
-        # unless the plane is on
-        _lineage.open_batch(statuses)
-        # durable intake journal (r21): the ONE blessed append seam with
-        # _run_batch_aligned below (lawcheck TW009) — raw rows become a
-        # CRC-framed replay record BEFORE featurize, so every recovery
-        # path re-ingests bytes the unchanged featurize path re-reads
-        _journal.record_intake(statuses)
-        batch = self._featurize(statuses)
-        self._check_buckets(batch)
-        self._record_metrics(batch)
-        for fn in self._outputs:
-            fn(batch, batch_time)
-        return batch
+        # one id per batch: the spans this thread opens for the batch
+        # (featurize, wire_pack, dispatch) carry the scheduler's count, and
+        # the fetch pipeline hands it on to fetch, deliver_wait and
+        # stats_publish (telemetry/trace.py batch_scope)
+        self.batches_seen += 1
+        with _trace.get().batch_scope(self.batches_seen):
+            # freshness lineage (r16): stamp the batch's record as it
+            # enters featurize — the event-time span + a stage-clock
+            # snapshot; no-op unless the plane is on
+            _lineage.open_batch(statuses)
+            # durable intake journal (r21): the ONE blessed append seam
+            # with _run_batch_aligned below (lawcheck TW009) — raw rows
+            # become a CRC-framed replay record BEFORE featurize, so every
+            # recovery path re-ingests bytes the unchanged featurize path
+            # re-reads
+            _journal.record_intake(statuses)
+            batch = self._featurize(statuses)
+            self._check_buckets(batch)
+            self._record_metrics(batch)
+            for fn in self._outputs:
+                fn(batch, batch_time)
+            return batch
 
 
 class StreamingContext:

@@ -17,10 +17,11 @@ from __future__ import annotations
 
 import socket
 import ssl
+import time
 from typing import Iterator
 from urllib.parse import urlsplit
 
-__all__ = ["StreamHTTPError", "RateLimitedError", "open_stream"]
+__all__ = ["StreamHTTPError", "RateLimitedError", "RecvClock", "open_stream"]
 
 
 class StreamHTTPError(ConnectionError):
@@ -35,6 +36,39 @@ class StreamHTTPError(ConnectionError):
 class RateLimitedError(StreamHTTPError):
     """HTTP 420 (Twitter's 'Enhance Your Calm') / 429: the caller must back
     off exponentially starting at a full minute (Twitter streaming rules)."""
+
+
+class RecvClock:
+    """Seconds spent inside socket reads and bytes read, summed by
+    ``open_stream`` for a caller that asked (the ``source_recv`` trace
+    span, twitter.BlockTwitterSource). Read and reset by the thread that
+    iterates the stream."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.bytes = 0
+
+    def take(self) -> "tuple[float, int]":
+        out = (self.seconds, self.bytes)
+        self.seconds, self.bytes = 0.0, 0
+        return out
+
+
+class _TimedSocket:
+    """A connected socket whose ``recv`` is timed into a ``RecvClock``."""
+
+    def __init__(self, sock: socket.socket, clock: RecvClock):
+        self._sock, self._clock = sock, clock
+
+    def recv(self, n: int) -> bytes:
+        t0 = time.perf_counter()
+        data = self._sock.recv(n)
+        self._clock.seconds += time.perf_counter() - t0
+        self._clock.bytes += len(data)
+        return data
+
+    def close(self) -> None:
+        self._sock.close()
 
 
 def _read_line(sock: socket.socket, buf: bytearray) -> bytes:
@@ -112,9 +146,11 @@ def open_stream(
     body: bytes | None = None,
     timeout: float = 90.0,
     ssl_context: ssl.SSLContext | None = None,
+    recv_clock: RecvClock | None = None,
 ) -> Iterator[str]:
     """Open ``url`` and yield decoded text lines (without terminators) as
     they arrive. Blank keep-alive lines ARE yielded — the consumer decides.
+    With a ``recv_clock`` every read of the response is timed into it.
 
     Raises ``RateLimitedError`` on 420/429, ``StreamHTTPError`` on any other
     non-200, plain ``ConnectionError``/``OSError``/``TimeoutError`` on
@@ -151,6 +187,8 @@ def open_stream(
             f"{k}: {v}\r\n" for k, v in req_headers.items()
         ) + "\r\n"
         sock.sendall(request.encode("ascii") + (body or b""))
+        if recv_clock is not None:
+            sock = _TimedSocket(sock, recv_clock)
 
         buf = bytearray()
         status_line = _read_line(sock, buf)

@@ -32,7 +32,12 @@ from typing import Callable, Iterator
 from .. import config as _config
 from ..features.featurizer import Status
 from ..utils import get_logger
-from .httpstream import RateLimitedError, StreamHTTPError, open_stream
+from .httpstream import (
+    RateLimitedError,
+    RecvClock,
+    StreamHTTPError,
+    open_stream,
+)
 from .oauth1 import authorization_header
 from .sources import BlockParserMixin, Source
 
@@ -72,6 +77,8 @@ class TwitterSource(Source):
         self.credentials = credentials
         self.url = url
         self._connect_fn = connect_fn
+        # set by a source that traces its socket reads (BlockTwitterSource)
+        self._recv_clock = None
 
     @classmethod
     def from_properties(cls, **kw) -> "TwitterSource":
@@ -110,7 +117,8 @@ class TwitterSource(Source):
         )
         # 90s read timeout: the stream keep-alives every ~30s, so a silent
         # socket for 90s is a stall and must raise into the supervisor
-        return open_stream(self.url, headers={"Authorization": auth})
+        return open_stream(self.url, headers={"Authorization": auth},
+                           recv_clock=self._recv_clock)
 
     def _backoff(self, exc: Exception, restarts: int) -> float:
         """Twitter streaming reconnect rules (what Twitter4j implements for
@@ -206,9 +214,15 @@ class BlockTwitterSource(BlockParserMixin, TwitterSource):
     def produce(self) -> "Iterator":
         import time as _time
 
+        from ..telemetry import trace as _trace
+
+        tr = _trace.get()
+        if tr.enabled:
+            self._recv_clock = RecvClock()
         buf: list[bytes] = []
         nbytes = 0
         first_t = 0.0
+        loop_t0 = _time.perf_counter()  # where this block's line loop began
         for line in self._connect():
             line = line.strip()
             now = _time.monotonic()
@@ -222,13 +236,31 @@ class BlockTwitterSource(BlockParserMixin, TwitterSource):
                 nbytes >= self.block_bytes
                 or now - first_t >= self.flush_seconds
             ):
+                if tr.enabled:
+                    self._trace_line_loop(tr, loop_t0, len(buf), nbytes)
                 block = self._parse_block(b"".join(buf))
                 buf, nbytes = [], 0
                 if block is not None:
                     yield block
+                loop_t0 = _time.perf_counter()  # not the time at ``yield``
         if buf:
             block = self._parse_block(b"".join(buf))
             if block is not None:
                 yield block
         if self._connect_fn is None:
             raise ConnectionError("stream ended by server; reconnecting")
+
+    def _trace_line_loop(self, tr, t0: float, lines: int, nbytes: int) -> None:
+        """One ``source_lines`` span per parsed block: this thread's line
+        loop (``open_stream``'s reassembly and ``produce``'s per-line work)
+        from the block's first line to the call of ``_parse_block`` — never
+        the parse, never the time suspended while the consumer takes the
+        block — and inside it one ``source_recv`` span: the part spent in
+        socket reads. Their difference is the loop's own Python
+        (PERF.md §3)."""
+        import time as _time
+
+        tr.complete("source_lines", t0, _time.perf_counter() - t0,
+                    lines=lines, bytes=nbytes)
+        recv_s, recv_bytes = self._recv_clock.take()
+        tr.complete("source_recv", t0, recv_s, bytes=recv_bytes)

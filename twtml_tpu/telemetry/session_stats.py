@@ -130,7 +130,9 @@ class SessionStats:
                 "stats_publish", _time.perf_counter() - t0
             )
             return
-        with tr.span("stats_publish", batch=int(batch)):
+        # ``batch`` here is the batch's ROW count; the span's ``batch`` arg
+        # is the scheduler's sequence number (trace.batch_scope)
+        with tr.span("stats_publish", rows=int(batch)):
             self._update(count, batch, mse, real_stdev, pred_stdev, real, pred)
         _sideband.record_stage("stats_publish", _time.perf_counter() - t0)
 

@@ -35,6 +35,37 @@ crosses ``max_bytes`` it becomes ``PATH.1`` (replacing any previous
 starts. ``tools/trace_report.py`` stitches ``PATH.1`` + ``PATH`` back into
 one report. ``--traceMaxMb 0`` disables rotation.
 
+One clock (PR 24): while a tracer is installed every ``span()`` also opens
+a ``jax.profiler.TraceAnnotation`` of the same name (made in
+``utils/tracing.annotate``, the one place), so a ``jax.profiler`` trace
+taken meanwhile shows the program's spans on its ``/host:CPU`` plane, on
+the clock of the device's own events — ``--profileDir``'s trace and the
+benchmark's (``benchmark/stage_times.py`` puts each device idle gap down
+to the span open on the scheduler thread). No profiler session: one
+flag test per span.
+
+Batch ids: ``batch_scope(n)`` marks the calling thread as working for the
+scheduler's n-th batch; every span opened inside carries ``batch=n``
+(``featurize``, ``wire_pack``, ``dispatch``, ``deliver_wait``,
+``stats_publish``; ``fetch`` runs on a pool thread and is handed its id),
+and ``dispatch`` takes it into its annotation, so the n-th
+``jit_train_step`` on the device plane is batch n.
+
+Waits, written only when one happened: ``intake_wait`` (the source thread
+on the intake queue's row bound, streaming/context.py), ``deliver_wait``
+(the scheduler on the oldest in-flight fetch, apps/common.py); and per
+parsed block ``source_lines`` / ``source_recv`` (the source thread's line
+loop and its socket reads, streaming/twitter.py). PERF.md §3 names the
+benchmark metric that reads each.
+
+``compile`` spans: ``install()`` registers ``jax.monitoring`` listeners
+(``uninstall()`` takes them away again; nothing is registered while
+tracing is off) that write one ``compile`` span per backend compilation
+or persistent-cache fetch: ``seconds``, ``cache_hit``, ``fun``,
+``during`` (the innermost span open on the compiling thread, else
+``startup``) and ``signature`` (what the dispatch site says of the call:
+rows, row length, units dtype, wire form).
+
 Event sink (r8): the crash flight recorder (telemetry/blackbox.py) attaches
 via ``set_event_sink`` so recent spans ride its bounded in-memory ring —
 one callback per written event, no second file, nothing when tracing is
@@ -50,6 +81,7 @@ import threading
 import time
 
 from ..utils import get_logger
+from ..utils.tracing import annotate as _annotate
 
 log = get_logger("telemetry.trace")
 
@@ -69,6 +101,9 @@ STAGES = (
     "stats_publish", # telemetry POSTs (SessionStats)
 )
 
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
 
 class _NullSpan:
     __slots__ = ()
@@ -80,7 +115,7 @@ class _NullSpan:
         return False
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()  # also for sites that span only sometimes
 
 
 class _NullTrace:
@@ -89,8 +124,8 @@ class _NullTrace:
     enabled = False
     path = ""
 
-    def span(self, name, **args):
-        return _NULL_SPAN
+    def span(self, name, signature=None, **args):
+        return NULL_SPAN
 
     def complete(self, name, t0_s, dur_s, **args):
         pass
@@ -98,8 +133,8 @@ class _NullTrace:
     def instant(self, name, **args):
         pass
 
-    def counter(self, name, **values):
-        pass
+    def batch_scope(self, batch):
+        return NULL_SPAN
 
     def close(self):
         pass
@@ -107,18 +142,68 @@ class _NullTrace:
 
 _NULL = _NullTrace()
 
+# per thread: the spans open on it, innermost last (``compile``'s
+# ``during``), the batch it works for, and whether the compile in progress
+# was served by the persistent cache
+_tls = threading.local()
+
+
+def _open_spans() -> list:
+    try:
+        return _tls.spans
+    except AttributeError:
+        _tls.spans = []
+        return _tls.spans
+
+
+class _BatchScope:
+    """``with trace.batch_scope(n):`` — spans opened on this thread inside
+    the block carry ``batch=n``. Nests (the fetch pipeline delivers an older
+    batch in the middle of a newer one's dispatch)."""
+
+    __slots__ = ("_batch", "_outer")
+
+    def __init__(self, batch):
+        self._batch = batch
+
+    def __enter__(self):
+        self._outer = getattr(_tls, "batch", None)
+        _tls.batch = self._batch
+        return self
+
+    def __exit__(self, *exc):
+        _tls.batch = self._outer
+        return False
+
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, open
+    meanwhile as a profiler annotation of the same name. ``signature``: a
+    callable returning what a ``compile`` span should say of the call being
+    dispatched; it runs only if something compiles."""
 
-    __slots__ = ("_trace", "_name", "_args", "_t0")
+    __slots__ = ("_trace", "_name", "_args", "_t0", "_annotation",
+                 "signature")
 
-    def __init__(self, trace: "PipelineTrace", name: str, args: dict):
+    def __init__(self, trace: "PipelineTrace", name: str, args: dict,
+                 signature=None):
         self._trace = trace
         self._name = name
         self._args = args
+        self.signature = signature
 
     def __enter__(self):
+        batch = current_batch()
+        if batch is not None:
+            self._args.setdefault("batch", batch)
+        # the span's twin on the profiler's clock; ``dispatch`` takes its
+        # batch id along (module docstring)
+        if self._name == "dispatch" and "batch" in self._args:
+            self._annotation = _annotate("dispatch", batch=self._args["batch"])
+        else:
+            self._annotation = _annotate(self._name)
+        self._annotation.__enter__()
+        _open_spans().append(self)
         self._t0 = time.perf_counter()
         return self
 
@@ -128,12 +213,12 @@ class _Span:
         self._args.update(args)
 
     def __exit__(self, exc_type, exc, tb):
+        dur = time.perf_counter() - self._t0
+        _open_spans().remove(self)
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._args["error"] = exc_type.__name__
-        self._trace.complete(
-            self._name, self._t0, time.perf_counter() - self._t0,
-            **self._args,
-        )
+        self._trace.complete(self._name, self._t0, dur, **self._args)
         return False
 
 
@@ -223,11 +308,11 @@ class PipelineTrace:
         }
 
     # -- public API ----------------------------------------------------------
-    def span(self, name: str, **args) -> _Span:
+    def span(self, name: str, signature=None, **args) -> _Span:
         """``with trace.span("featurize", rows=...):`` — one complete event
         spanning the with-block. Nest freely; Chrome's viewer nests X events
-        by time containment per thread."""
-        return _Span(self, name, args)
+        by time containment per thread. ``signature``: see ``_Span``."""
+        return _Span(self, name, args, signature)
 
     def complete(self, name: str, t0_s: float, dur_s: float, **args) -> None:
         """Record a complete event from an already-taken (start, duration)
@@ -251,13 +336,10 @@ class PipelineTrace:
             ev["args"] = args
         self._event(ev)
 
-    def counter(self, name: str, **values) -> None:
-        """Chrome counter track (e.g. fetch queue depth over time)."""
-        ev = self._base(name)
-        ev["ph"] = "C"
-        ev["ts"] = round(time.perf_counter() * 1e6, 1)
-        ev["args"] = values
-        self._event(ev)
+    def batch_scope(self, batch: int) -> _BatchScope:
+        """Spans opened on this thread inside the block carry
+        ``batch=<batch>`` (the scheduler's sequence number)."""
+        return _BatchScope(batch)
 
     def close(self) -> None:
         with self._lock:
@@ -285,6 +367,53 @@ def set_event_sink(sink) -> None:
     _SINK = sink
 
 
+def _on_cache_hit(event: str, **_kw) -> None:
+    if event == CACHE_HIT_EVENT:
+        _tls.cache_hit = True  # read by the duration event that follows
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    """One ``compile`` span per backend compilation, written when jax
+    reports its duration (on the compiling thread, inside whatever program
+    span made the call)."""
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    hit, _tls.cache_hit = getattr(_tls, "cache_hit", False), False
+    tr = _active
+    if not tr.enabled:
+        return
+    spans = _open_spans()
+    signature = next(
+        (sp.signature() for sp in reversed(spans) if sp.signature), None
+    )
+    tr.complete(
+        "compile", time.perf_counter() - secs, secs,
+        seconds=round(secs, 4), cache_hit=hit,
+        fun=str(kw.get("fun_name", "")),
+        during=spans[-1]._name if spans else "startup",
+        signature=signature,
+    )
+
+
+_listening = False
+
+
+def _listen(on: bool) -> None:
+    """Register / take away the two ``jax.monitoring`` listeners."""
+    global _listening
+    if on == _listening:
+        return
+    import jax.monitoring as mon
+
+    if on:
+        mon.register_event_listener(_on_cache_hit)
+        mon.register_event_duration_secs_listener(_on_compile)
+    else:
+        mon.unregister_event_listener(_on_cache_hit)
+        mon.unregister_event_duration_listener(_on_compile)
+    _listening = on
+
+
 def install(path: str, max_bytes: int = 0) -> "PipelineTrace | _NullTrace":
     """Activate tracing to ``path`` (empty path → stays off). Closes any
     previously installed tracer; registered atexit so a crash still flushes
@@ -296,6 +425,7 @@ def install(path: str, max_bytes: int = 0) -> "PipelineTrace | _NullTrace":
         _active.close()
     _active = PipelineTrace(path, max_bytes=max_bytes)
     atexit.register(_active.close)
+    _listen(True)
     log.info("pipeline trace → %s (Perfetto-loadable)", path)
     return _active
 
@@ -306,7 +436,15 @@ def uninstall() -> None:
     if _active.enabled:
         _active.close()
     _active = _NULL
+    _listen(False)
 
 
 def get() -> "PipelineTrace | _NullTrace":
     return _active
+
+
+def current_batch() -> "int | None":
+    """The batch id of the ``batch_scope`` this thread is in, if any — what
+    a pipeline keeps beside an in-flight dispatch to hand to its fetch and
+    delivery."""
+    return getattr(_tls, "batch", None)

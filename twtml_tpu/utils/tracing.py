@@ -10,7 +10,14 @@ no-op mode when disabled, so apps can call it unconditionally:
     tracer.stop()
 
 Traces are TensorBoard-compatible (xplane) under ``profile_dir``; on TPU they
-include device timelines and XLA op breakdowns.
+include device timelines and XLA op breakdowns, the device stages by name
+(the ``jax.named_scope``s of the train step: ``repad``, ``hash``,
+``predict``, ``gram_count``, ``gram_matmul``, ``dual_loop``, ``writeback``
+...) and, with ``--trace`` also on, the pipeline's spans on the host plane
+(``annotate`` below; telemetry/trace.py). The profiler runs with the options
+the benchmark's harness uses — Python tracer OFF: tracing every Python call
+halves the ingest rate through the per-line source loop (PERF.md §3), and
+the program's own spans say more.
 """
 
 from __future__ import annotations
@@ -34,7 +41,10 @@ class Tracer:
             return
         import jax
 
-        jax.profiler.start_trace(self.profile_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(self.profile_dir, profiler_options=options)
         self._active = True
         log.info("jax.profiler trace started → %s", self.profile_dir)
 
@@ -55,8 +65,11 @@ class Tracer:
         self.stop()
 
 
-def annotate(name: str):
-    """Named region visible in trace timelines (TraceAnnotation)."""
+def annotate(name: str, **kwargs):
+    """Named region on the host plane of a ``jax.profiler`` trace
+    (TraceAnnotation) — the one place the program makes one; every
+    ``--trace`` span opens its twin here (telemetry/trace.py). Costs a
+    flag test while no profiler session runs."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **kwargs)
