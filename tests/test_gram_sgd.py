@@ -9,6 +9,7 @@ densify-matmul reference, including the cond-gated two-plane split for
 counts > 255 and non-integral token values."""
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -125,7 +126,7 @@ def test_gram_matrix_int8_plane_is_bit_exact():
     from twtml_tpu.ops.gram import text_gram
 
     got = np.asarray(
-        text_gram(jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val), F_TEXT)
+        text_gram(jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val), F_TEXT)[0]
     )
     np.testing.assert_array_equal(got, ref)
 
@@ -146,7 +147,7 @@ def test_gram_matrix_int8_gate_mixed_sign_boundary():
             densify_text(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
         )
         ref = dense @ dense.T
-        got = np.asarray(text_gram(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT))
+        got = np.asarray(text_gram(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)[0])
         if exact:
             np.testing.assert_array_equal(got, ref)
         else:
@@ -165,7 +166,7 @@ def test_gram_matrix_int8_gate_count_wrap_boundary():
         token_idx = np.array([[7, 0], [7, 0]], np.int32)
         token_val = np.array([[count, 0.0], [1.0, 0.0]], np.float32)
         got = np.asarray(
-            text_gram(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
+            text_gram(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)[0]
         )
         expected = np.array([[count * count, count], [count, 1.0]], np.float32)
         if exact:
@@ -192,7 +193,7 @@ def test_gram_matrix_int8_plane_disabled_still_matches():
             jnp.asarray(batch.token_val),
             F_TEXT,
             int8_plane=False,
-        )
+        )[0]
     )
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-4)
 
@@ -530,3 +531,166 @@ def test_data_axis_gram_sampling_matches_single_device():
     for b in batches:
         model.step(shard_batch(b, mesh))
     np.testing.assert_allclose(model.latest_weights, w_ref, rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# PR 25: the bf16 gate per (row, feature) — the plane is the index
+# ``text_gram`` hands out with G (0 exact, 1 bf16, 2 s8)
+
+GATE_L = 512  # the row length a 280-unit text is padded to
+
+
+def _gate_rows(first_idx, first_val, short_rows=3):
+    """Row 0 as given (padded to GATE_L with the batch contract's
+    ``(idx 0, val 0.0)`` slots) among short rows of 20 unit tokens."""
+    rng = np.random.default_rng(25)
+    idx = np.zeros((1 + short_rows, GATE_L), np.int32)
+    val = np.zeros((1 + short_rows, GATE_L), np.float32)
+    idx[0, : len(first_idx)] = first_idx
+    val[0, : len(first_val)] = first_val
+    for r in range(1, 1 + short_rows):
+        idx[r, :20] = rng.integers(1, F_TEXT, size=20)
+        val[r, :20] = 1.0
+    return idx, val
+
+
+def _gate_case(name):
+    """(token_idx, token_val, plane the gate must take)."""
+    ones = lambda n: np.ones(n, np.float32)
+    if name == "row_of_279_distinct_bigrams":   # a 280-unit tweet
+        return *_gate_rows(np.arange(1, 280), ones(279)), 1
+    if name == "count_256_on_one_feature":
+        return *_gate_rows(np.r_[np.full(256, 7), np.arange(8, 28)], ones(276)), 1
+    if name == "count_257_on_one_feature":
+        return *_gate_rows(np.r_[np.full(257, 7), np.arange(8, 28)], ones(277)), 0
+    if name == "pads_have_multiplicity_not_mass":
+        # 20 real tokens and 492 pad slots at index 0, in a batch of such
+        # rows: nothing reaches rung 1's limit, the s8 plane stands
+        return *_gate_rows(np.arange(1, 21), ones(20)), 2
+    if name == "pads_beside_a_long_row":
+        # rung 2 is reached (row 0) and must not count the short rows' 492
+        # repeats of (0, 0.0), nor row 0's own 233
+        return *_gate_rows(np.arange(0, 279), ones(279)), 1
+    if name == "mixed_signs_on_one_feature":
+        # +200 −100: the count is 100, the absolute mass 300
+        return *_gate_rows(
+            np.r_[7, 7, np.arange(8, 108)], np.r_[200.0, -100.0, ones(100)]), 0
+    if name == "row_mass_above_65536":
+        # 257 distinct features of count 256: every count passes, G does not
+        return *_gate_rows(np.arange(1, 258), np.full(257, 256.0, np.float32)), 0
+    if name == "fractional_values":
+        return *_gate_rows(np.arange(1, 280), np.full(279, 0.5, np.float32)), 0
+    raise KeyError(name)
+
+
+GATE_CASES = [
+    "row_of_279_distinct_bigrams", "count_256_on_one_feature",
+    "count_257_on_one_feature", "pads_have_multiplicity_not_mass",
+    "pads_beside_a_long_row", "mixed_signs_on_one_feature",
+    "row_mass_above_65536", "fractional_values",
+]
+
+
+def _dense64(token_idx, token_val):
+    dense = np.zeros((token_idx.shape[0], F_TEXT), np.float64)
+    for r in range(token_idx.shape[0]):
+        np.add.at(dense[r], token_idx[r], token_val[r].astype(np.float64))
+    return dense @ dense.T
+
+
+@pytest.mark.parametrize("name", GATE_CASES)
+def test_gate_takes_the_plane_its_proof_covers(name):
+    """Per (row, feature) absolute mass ≤ 256 and row mass ≤ 65,536 ⇒ the
+    bf16 plane, whose G is then the exact plane's and the float64
+    reference's integer for integer; anything the proof does not cover ⇒
+    the exact plane."""
+    from jax import lax
+
+    from twtml_tpu.ops.gram import text_gram
+
+    token_idx, token_val, want = _gate_case(name)
+    g, plane = jax.jit(lambda i, v: text_gram(i, v, F_TEXT))(token_idx, token_val)
+    assert int(plane) == want
+    ref = _dense64(token_idx, token_val)
+    if want >= 1:
+        np.testing.assert_array_equal(np.asarray(g, np.float64), ref)
+        c = densify_text(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
+        exact = jnp.matmul(c, c.T, precision=lax.Precision.HIGHEST)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(exact))
+    else:
+        np.testing.assert_allclose(np.asarray(g, np.float64), ref, rtol=1e-6)
+
+
+def test_gate_count_257_is_not_rounded_to_256():
+    """What the gate is for: bf16 holds 256 and not 257, so a count of 257
+    on the bf16 plane would give G[0,0] = 256² + … — the exact plane gives
+    257² + 20, to the unit."""
+    from twtml_tpu.ops.gram import onehot_counts, text_gram
+
+    token_idx, token_val, _ = _gate_case("count_257_on_one_feature")
+    g, plane = text_gram(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
+    assert int(plane) == 0 and float(g[0, 0]) == 257.0**2 + 20.0
+    c = onehot_counts(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
+    assert float(c[0, 7]) == 256.0   # the rounding the gate keeps out
+
+
+def test_gate_without_the_int8_plane_takes_bf16_where_s8_would_do():
+    from twtml_tpu.ops.gram import text_gram
+
+    token_idx, token_val, _ = _gate_case("pads_have_multiplicity_not_mass")
+    _g, plane = text_gram(
+        jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT, int8_plane=False
+    )
+    assert int(plane) == 1
+
+
+def test_gate_on_a_feature_slice_ignores_clipped_zeroed_tokens(monkeypatch):
+    """The 2-D sharded caller clips out-of-slice tokens to its slice's edge
+    with their value zeroed: hundreds of repeats of one index that carry no
+    mass. Row 0 holds 300 distinct unit features in slice 0 and 300 in
+    slice 2 of four: on those two model shards the slice's row mass is 300
+    (rung 1 fails) and 300 clipped tokens share one index; both must take
+    the bf16 plane, no shard the exact one, and the weights are the
+    single-device step's."""
+    from twtml_tpu.parallel import ParallelSGDModel, make_mesh
+    from twtml_tpu.parallel import sharding
+    from twtml_tpu.parallel.sharding import shard_batch
+
+    f_text, b, l = 2048, 8, 640
+    rng = np.random.default_rng(26)
+    token_idx = np.zeros((b, l), np.int32)
+    token_val = np.zeros((b, l), np.float32)
+    token_idx[0, :600] = np.r_[np.arange(0, 300), np.arange(1024, 1324)]
+    token_val[0, :600] = 1.0
+    for r in range(1, b):
+        token_idx[r, :20] = rng.integers(0, f_text, size=20)
+        token_val[r, :20] = 1.0
+    batch = FeatureBatch(
+        token_idx, token_val,
+        rng.normal(size=(b, NUM_NUMBER_FEATURES)).astype(np.float32) * 0.1,
+        rng.uniform(0, 50, size=(b,)).astype(np.float32),
+        np.ones((b,), np.float32),
+    )
+    kw = dict(num_text_features=f_text, num_iterations=10, step_size=0.001)
+    single = make_sgd_train_step(use_sparse=True, use_gram=True, quality=True, **kw)
+    w_ref, out = single(zero_weights(f_text), batch)
+    assert float(out.quality[-1]) == 1.0   # the whole row: mass 600, counts 1
+
+    planes = []
+    real = sharding.text_gram
+
+    def watched(*args, **kwargs):
+        g, plane = real(*args, **kwargs)
+        jax.debug.callback(lambda p: planes.append(int(p)), plane)
+        return g, plane
+
+    monkeypatch.setattr(sharding, "text_gram", watched)
+    mesh = make_mesh(num_data=2, num_model=4)
+    model = ParallelSGDModel(mesh, use_gram=True, **kw)
+    model.step(shard_batch(batch, mesh))
+    jax.effects_barrier()
+    np.testing.assert_allclose(
+        model.latest_weights, np.asarray(w_ref), rtol=2e-4, atol=2e-4
+    )
+    assert len(planes) == 8 and 0 not in planes
+    assert planes.count(1) == 4   # model shards 0 and 2, on both data shards

@@ -104,6 +104,59 @@ def test_quality_vector_shape_fields_and_ranges():
     )
 
 
+def _plane_batch(kind):
+    """Four rows of token pairs at 512 text dims whose Gram gate
+    (ops/gram.text_gram) takes the named plane."""
+    from twtml_tpu.features.batch import NUM_NUMBER_FEATURES, FeatureBatch
+
+    rng = np.random.default_rng(25)
+    idx = np.zeros((4, 320), np.int32)
+    val = np.zeros((4, 320), np.float32)
+    idx[:, :20] = rng.integers(1, 512, size=(4, 20))
+    val[:, :20] = 1.0
+    if kind == "bf16":      # a row of 279 distinct bigrams: rung 2
+        idx[0, :279], val[0, :279] = np.arange(1, 280), 1.0
+    elif kind == "exact":   # one bigram 300 times over
+        idx[0, :300], val[0, :300] = 7, 1.0
+    return FeatureBatch(
+        idx, val, rng.normal(size=(4, NUM_NUMBER_FEATURES)).astype(np.float32),
+        rng.uniform(0, 50, size=(4,)).astype(np.float32), np.ones(4, np.float32),
+    )
+
+
+@pytest.mark.parametrize("kind, want", [
+    ("bf16", 1.0), ("exact", 0.0), ("s8", 2.0), ("not_gram", -1.0),
+])
+def test_gram_plane_is_the_last_field_and_names_the_plane_taken(kind, want):
+    """The counter of PR 25: the index ``text_gram``'s switch took rides
+    the quality vector as its last field; −1 where the step is not in the
+    Gram basis (the reference's 1,004-dim dense model)."""
+    from twtml_tpu.models.sgd import make_sgd_train_step, zero_weights
+
+    assert QUALITY_FIELDS[-1] == "gram_plane"
+    assert QUALITY_INDEX["gram_plane"] == QUALITY_WIDTH - 1
+    if kind == "not_gram":
+        out = StreamingLinearRegressionWithSGD(quality=True).step(
+            _ragged_batches()[0]
+        )
+    else:
+        step = make_sgd_train_step(
+            num_text_features=512, use_sparse=True, num_iterations=5,
+            step_size=1e-6, quality=True,
+        )
+        _w, out = step(zero_weights(512), _plane_batch(kind))
+    assert float(out.quality[QUALITY_INDEX["gram_plane"]]) == want
+
+
+def test_watcher_keeps_the_gram_plane_as_a_gauge():
+    """[M, Q]: the slowest plane any tenant's step took this tick."""
+    q = np.zeros((2, QUALITY_WIDTH), np.float64)
+    q[:, QUALITY_INDEX["gram_plane"]] = (2.0, 1.0)
+    ModelWatch().observe(q, np.array([8.0, 8.0]), np.array([4.0, 2.0]))
+    gauges = _metrics.get_registry().snapshot()["gauges"]
+    assert gauges["model.gram_plane"] == 1.0
+
+
 def test_off_program_is_structurally_head_and_observation_only():
     """ACCEPTANCE (off bit-parity): quality=False leaves the output pytree
     the HEAD 5-leaf StepOutput (the quality leaf is None — same compiled

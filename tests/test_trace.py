@@ -319,6 +319,25 @@ def test_every_published_batch_keeps_one_id_through_the_pipeline(tmp_path):
     assert sig["wire"] and sig["units_len"] > 0
 
 
+@pytest.mark.parametrize("flags, plane", [
+    (["--numTextFeatures", "16384"], 2),   # Gram basis, short text: s8
+    ([], -1),                              # the 1,004-dim dense model
+])
+def test_gram_plane_instant_once_per_batch(tmp_path, flags, plane):
+    """PR 25: which plane each batch's Gram build took, from the quality
+    vector the one fetch already carried, under the batch's id."""
+    metrics_mod.reset_for_tests()
+    trace_path = tmp_path / "plane.trace"
+    totals, fetches = _run_linear(
+        tmp_path / "on", ["--trace", str(trace_path)] + flags
+    )
+    assert totals["batches"] == 4 and fetches == 4
+    marks = [e for e in trace_report.load_events(str(trace_path))
+             if e.get("ph") == "i" and e["name"] == "gram_plane"]
+    assert [e["args"]["batch"] for e in marks] == [1, 2, 3, 4]
+    assert [e["args"]["plane"] for e in marks] == [plane] * 4
+
+
 def test_compile_span_for_a_new_shape_and_not_for_its_repeat(tmp_path):
     import jax
     import numpy as np

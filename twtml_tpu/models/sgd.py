@@ -46,7 +46,6 @@ from ..ops.gram import (
     dual_norm_sq,
     dual_writeback,
     fits_gram,
-    gram_matrix,
     text_gram,
 )
 from ..ops.quality import quality_vector
@@ -350,7 +349,7 @@ def make_sgd_train_step(
         # is f32-only — the bf16-plane G build would silently downgrade f64).
         if axis_name:
             rows = u.shape[0] // lax.axis_size(axis_name)
-            panel = text_gram(
+            panel, plane = text_gram(
                 token_idx,
                 token_val,
                 f_text,
@@ -360,11 +359,14 @@ def make_sgd_train_step(
             )  # [B_local, B_global]: the G matmul's FLOPs scale 1/shards
             # (the count build replicates per shard — see text_gram.left)
             g_text = lax.all_gather(panel, axis_name, axis=0, tiled=True)
-            g = add_numeric_block(g_text, numeric, dtype)
+            # every shard gated the same global rows: pmin only makes the
+            # index statically invariant, like ``c`` in dual_scale_and_alpha
+            plane = lax.pmin(plane, axis_name)
         else:
-            g = gram_matrix(
-                token_idx, token_val, numeric, f_text, dtype, int8_plane=gram_int8
+            g_text, plane = text_gram(
+                token_idx, token_val, f_text, int8_plane=gram_int8
             )
+        g = add_numeric_block(g_text, numeric, dtype)
 
         dual = run_dual_loop(
             u=u,
@@ -406,7 +408,7 @@ def make_sgd_train_step(
                 numeric,
             )
         with jax.named_scope("writeback"):
-            return jnp.concatenate([w_text_new, w_num_new])
+            return jnp.concatenate([w_text_new, w_num_new]), plane
 
     def train_step(weights, batch: FeatureBatch | UnitBatch | PackedBatch):
         dtype = weights.dtype
@@ -462,7 +464,7 @@ def make_sgd_train_step(
                 preds = jnp_round_half_up(preds)
             stats = batch_stats(labels, preds, mask, axis_name)
 
-        def _quality(w_new):
+        def _quality(w_new, gram_plane=None):
             # the ISSUE-8 side channel against the post-update weights;
             # None (plane off) keeps the output pytree the HEAD program's
             if not quality:
@@ -473,7 +475,8 @@ def make_sgd_train_step(
                     residual=residual_fn(raw, labels) * mask,
                     preds=preds, labels=labels, mask=mask,
                     numeric=batch.numeric, token_idx=batch.token_idx,
-                    token_val=batch.token_val, axis_name=axis_name,
+                    token_val=batch.token_val, gram_plane=gram_plane,
+                    axis_name=axis_name,
                 )
 
         # ---- numIterations of mini-batch SGD ----------------------------
@@ -497,9 +500,9 @@ def make_sgd_train_step(
                     lax.all_gather(a, axis_name, axis=0, tiled=True)
                     for a in row_args
                 )
-            w_new = _gram_sgd(weights, row_args, local_args)
+            w_new, plane = _gram_sgd(weights, row_args, local_args)
             return w_new, StepOutput(
-                predictions=preds, quality=_quality(w_new), **stats
+                predictions=preds, quality=_quality(w_new, plane), **stats
             )
 
         def grad_and_count(w, sel):

@@ -43,20 +43,43 @@ i.e. one ``[B, √F, L] × [B, L, √F]`` batched matmul (~0.07 TFLOP at
 B=2048, F=2^18 — 3% of the G matmul itself), with 0/1 one-hot operands
 that are exact in bf16 and f32 accumulation, so counts come out exact.
 
-Exactness is cond-gated at runtime, never assumed: token values that don't
-round-trip through bf16 fall back to the f32 scatter densify, and a count
-matrix that doesn't round-trip through bf16 (a per-row-feature count above
-256 — beyond any real tweet) promotes the G matmul to
-``Precision.HIGHEST``. G is therefore (near-)exact for every input the
-scatter path accepts, and fast for every input that can occur.
+Exactness is gated at runtime, never assumed. ``text_gram`` picks one of
+three planes from what it observes in the batch's [B, L] (idx, val) pairs —
+never from the [B, F] counts — and hands the index it took out with G:
 
-A third, faster plane rides the same gate ladder: when every row's total
-absolute token mass is ≤ 127 (true for every real tweet — per-occurrence
-1.0 values, ≤ ~70 bigrams), every count is an integer in [−127, 127] and
-therefore EXACT in int8, so both matmuls run s8×s8→s32 on the MXU — ~2×
-bf16 peak on v5e, and the [B, F] count matrix is half the bytes. Integer
-accumulation makes this plane bit-exact (no rounding at all), strictly
-stronger than the bf16 plane it tightens.
+  2  s8    integral values, every row's ABSOLUTE token mass ≤ 127;
+  1  bf16  integral, bf16-representable values and either
+           rung 1: every row's absolute mass ≤ 255 (one pass over the
+                   values; every 20–140-unit text passes here), or
+           rung 2, evaluated only when rung 1 fails: every (row, feature)
+                   absolute mass ≤ 256 and every row's absolute mass
+                   ≤ 65,536 (a row-wise sort of the pairs along L, a
+                   cumulative sum and its difference at run boundaries);
+  0  exact anything else — fractional values, one feature carrying more
+           than 256 of a row's mass, a row of mass above 65,536: f32
+           scatter densify + ``Precision.HIGHEST`` matmul.
+
+Why the bf16 plane's G is the exact plane's, integer for integer:
+(1) values: each is an integer bf16 holds, so the one-hot operands are
+exact; (2) counts: a count is an integer whose magnitude — and every
+partial sum of the one-hot build's f32 accumulation — is at most the
+ABSOLUTE mass its row puts on that feature, ≤ 256, and bf16 holds every
+integer up to 256 (absolute mass, because with mixed signs a plain sum can
+hide a partial sum beyond that); a row's LENGTH does not enter: a 280-unit
+tweet has 279 bigrams and would have to repeat one hashed bigram 257 times
+to need the exact plane; (3) G: with every |count| ≤ 256,
+Σ_f |c_af·c_bf| ≤ 256 · row_mass ≤ 256 · 65,536 = 2²⁴, so every partial
+sum of the bf16×bf16→f32 product, in any order, is an integer of magnitude
+≤ 2²⁴ and f32 holds it. Pad slots (idx 0, val 0.0) and the tokens a
+feature-sharded caller clips to its slice edge with their value zeroed
+carry no mass, so they cannot trip a gate that sums |val|.
+
+The s8 plane tightens the same ladder: row absolute mass ≤ 127 (true of
+texts up to 128 units — per-occurrence 1.0 values) makes every count an
+integer in [−127, 127], EXACT in int8, so both matmuls run s8×s8→s32 on
+the MXU (twice the bf16 peak on v5e by specification, half the
+count-matrix bytes) with integer accumulation: bit-exact, no rounding at
+all.
 """
 
 from __future__ import annotations
@@ -163,45 +186,80 @@ def text_gram(
     rows: int = 0,
     int8_plane: bool | None = None,
 ):
-    """Text-feature Gram block: X·Xᵀ ([B,B] f32), or the row slice
+    """Text-feature Gram block and the plane it was built on: ``(G, plane)``
+    with G = X·Xᵀ ([B,B] f32), or the row slice
     ``X[row_start:row_start+rows]·Xᵀ`` ([rows, B]) when ``rows`` > 0 — the
     building block sharded layouts use (each shard computes its row panel
-    and/or its feature slice's partial G, then all-gathers/psums).
+    and/or its feature slice's partial G, then all-gathers/psums) — and
+    ``plane`` the int32 index the switch took: 2 s8, 1 bf16, 0 exact (the
+    gate is computed here, once per step; ops/quality.py carries it out).
 
-    Common path (every real tweet): token values are small integers and each
-    row's total absolute mass is ≤ 127, which PROVES every count is an
-    integer in [−127, 127] and therefore int8-exact — so the count matrix is
-    built by the one-hot matmul straight into int8 and the product is one
-    s8×s8→s32 MXU matmul (the v5e's int8 peak is twice its bf16 peak by
-    specification; half the count-matrix bytes), bit-exact. Row mass in (127, 255] keeps the bf16 plane (counts
-    ≤ 255 are bf16-exact). The predicates cost one pass over the [B, L]
-    token values (not the [B, F] counts). Anything else — fractional values,
-    a degenerate row with > 255 mass — takes the exact fallback: f32 scatter
-    densify + full-f32 (``Precision.HIGHEST``) matmul.
+    The gate ladder and its proof are the module docstring's. Every rung
+    reads the [B, L] token pairs, never the [B, F] counts: row absolute
+    mass ≤ 127 ⇒ s8 (one s8×s8→s32 MXU matmul, bit-exact); ≤ 255 ⇒ bf16;
+    a batch with a longer row (a 257–280-unit text) reaches rung 2, which
+    sorts each row's pairs and takes the bf16 plane while no (row, feature)
+    absolute mass exceeds 256 and no row's exceeds 65,536. Anything else —
+    fractional values, a feature repeated past 256 within one row — takes
+    the exact fallback: f32 scatter densify + full-f32
+    (``Precision.HIGHEST``) matmul.
     """
     if int8_plane is None:
         int8_plane = GRAM_INT8_PLANE
     # device stage names (models/sgd.py STAGE_SCOPES): the three planes
     # share ``gram_count`` (the plane gate and the count matrix) and
-    # ``gram_matmul``; which plane ran is read from the operand types
+    # ``gram_matmul``; which plane ran is the index handed out with G
     with jax.named_scope("gram_count"):
         val_f = token_val.astype(jnp.float32)
-        # integral, bf16-representable values with row ABSOLUTE mass ≤ 255
-        # ⇒ every count is an integer of magnitude ≤ 255 ⇒ counts and their
-        # bf16 products are exact (plain sum would be unsound for
-        # mixed-sign values: cancellation can hide a per-feature count
-        # above the bf16 range)
         integral = jnp.all(val_f == jnp.round(val_f))
-        row_mass = jnp.sum(jnp.abs(val_f), axis=1)
-        vals_ok = (
-            integral
-            & jnp.all(val_f.astype(jnp.bfloat16).astype(jnp.float32) == val_f)
-            & jnp.all(row_mass <= 255.0)
+        # ABSOLUTE mass: a plain sum would be unsound for mixed-sign values
+        # (cancellation can hide a partial sum above the bf16 range)
+        max_row_mass = jnp.max(jnp.sum(jnp.abs(val_f), axis=1))
+        vals_bf16 = integral & jnp.all(
+            val_f.astype(jnp.bfloat16).astype(jnp.float32) == val_f
         )
+        # rung 1: row mass ≤ 255 bounds every count of the row with it
+        rung1 = vals_bf16 & (max_row_mass <= 255.0)
         # row absolute mass ≤ 127 tightens every bound to the int8 range:
         # each |value| ≤ 127 (s8 operand) and each |count| ≤ 127 (s8 count
         # matrix)
-        vals_ok_i8 = integral & jnp.all(row_mass <= 127.0)
+        vals_ok_i8 = integral & (max_row_mass <= 127.0)
+
+        def feature_mass_ok(i, v):
+            """Rung 2: max over (row, feature) of Σ|val| ≤ 256, on the
+            sorted [B, L] pairs. Integral values and row mass ≤ 65,536 <
+            2²⁴ (the cond's predicate) keep the f32 cumsum exact; |val| ≥ 0
+            keeps it monotone, so ``cummax`` carries each run's starting
+            level to the run's end."""
+            s_idx, s_abs = lax.sort(
+                (i, jnp.abs(v)), dimension=1, num_keys=1, is_stable=False
+            )
+            level = jnp.cumsum(s_abs, axis=1)
+            run_start = jnp.concatenate(
+                [jnp.ones_like(s_idx[:, :1], bool), s_idx[:, 1:] != s_idx[:, :-1]],
+                axis=1,
+            )
+            before_run = lax.cummax(
+                jnp.where(run_start, level - s_abs, 0.0), axis=1
+            )
+            return jnp.max(level - before_run) <= 256.0
+
+        def not_reached(i, v):
+            # under shard_map both branches must vary over the same axes
+            axes = tuple(sorted(jax.typeof(i).vma | jax.typeof(v).vma))
+            no = jnp.zeros((), bool)
+            return lax.pcast(no, axes, to="varying") if axes else no
+
+        # 65,536 = 2²⁴ ÷ 256 bounds G's own f32 accumulation once a row's
+        # mass is no longer bounded by 255 (module docstring, part 3)
+        rung2 = lax.cond(
+            vals_bf16 & ~rung1 & (max_row_mass <= 65536.0),
+            feature_mass_ok,
+            not_reached,
+            token_idx,
+            val_f,
+        )
+        vals_ok = rung1 | rung2
 
     def left(c):
         """The (possibly row-sliced) left operand. The slice makes the G
@@ -241,7 +299,7 @@ def text_gram(
     if int8_plane:
         idx = idx + vals_ok_i8.astype(jnp.int32)  # i8-ok ⊆ bf16-ok: 0/1/2
         branches.append(fast_i8)
-    return lax.switch(idx, branches, token_idx, val_f)
+    return lax.switch(idx, branches, token_idx, val_f), idx
 
 
 @jax.named_scope("gram_matmul")
@@ -263,7 +321,7 @@ def gram_matrix(
 ):
     """G = Z·Zᵀ ([B,B] ``dtype``) for Z = [text counts | numeric features]."""
     return add_numeric_block(
-        text_gram(token_idx, token_val, f_text, int8_plane=int8_plane),
+        text_gram(token_idx, token_val, f_text, int8_plane=int8_plane)[0],
         numeric,
         dtype,
     )

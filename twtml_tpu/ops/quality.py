@@ -29,7 +29,10 @@ off the names, tests key off the indices):
   the fraction of folded bins touched, top_share the largest bin's mass
   share (a collision/skew proxy for the hash-bucket space; computed as
   ``QUALITY_NBINS`` fused masked reductions, never a scatter — XLA
-  serializes a [B·L]-update scatter, lawcheck TW004).
+  serializes a [B·L]-update scatter, lawcheck TW004);
+- ``gram_plane``: which plane ``ops/gram.text_gram``'s gate took for this
+  batch's G — 0 exact, 1 bf16, 2 s8; −1 where the step is not in the Gram
+  basis. The gate's own index, handed through; nothing recomputes it.
 
 Every reduction takes the optional ``axis_name`` so the same code runs
 single-device and data-parallel (psum over the mesh — all outputs are then
@@ -73,6 +76,7 @@ QUALITY_FIELDS = (
     "num_var_3",
     "bucket_occupancy",
     "bucket_top_share",
+    "gram_plane",
 )
 QUALITY_WIDTH = len(QUALITY_FIELDS)
 QUALITY_INDEX = {name: i for i, name in enumerate(QUALITY_FIELDS)}
@@ -96,6 +100,7 @@ def quality_vector(
     numeric,
     token_idx,
     token_val,
+    gram_plane=None,
     axis_name: str | None = None,
 ) -> jnp.ndarray:
     """The ``[QUALITY_WIDTH]`` f32 quality vector for one micro-batch.
@@ -105,7 +110,8 @@ def quality_vector(
     the valid-row mask; all row-dimensioned inputs are shard-LOCAL under a
     data axis — the psums here make every output global, exactly like
     ``ops/stats.batch_stats``. Weights are replicated over any data axis,
-    so their norms need no collective."""
+    so their norms need no collective. ``gram_plane`` is ``text_gram``'s
+    plane index (axis-invariant already), None outside the Gram basis."""
     f32 = jnp.float32
     m = mask.astype(f32)
     n = _maybe_psum(jnp.sum(m), axis_name)
@@ -176,4 +182,5 @@ def quality_vector(
         + [num_mean[i] for i in range(NUM_NUMERIC)]
         + [num_var[i] for i in range(NUM_NUMERIC)]
         + [occupancy, top_share]
+        + [jnp.asarray(-1.0 if gram_plane is None else gram_plane, f32)]
     ).astype(f32)

@@ -237,7 +237,7 @@ def _make_feature_sharded_step(
             )
             rel_g = idx_g - lo
             in_g = ((rel_g >= 0) & (rel_g < f_text_local)).astype(dtype)
-            panel = text_gram(
+            panel, _ = text_gram(
                 jnp.clip(rel_g, 0, f_text_local - 1),
                 val_g * in_g,
                 f_text_local,

@@ -49,6 +49,7 @@ import numpy as np
 from ..utils import get_logger
 from . import blackbox as _blackbox
 from . import metrics as _metrics
+from . import trace as _trace
 from ..ops.quality import QUALITY_INDEX, QUALITY_WIDTH
 
 log = get_logger("telemetry.modelwatch")
@@ -266,6 +267,11 @@ class ModelWatch:
                 un = float((aw * qa[:, iu]).sum())
                 gn = float((aw * qa[:, ig]).sum())
             self._last_norms = (wn, un, gn)
+            # which Gram plane the step took (ops/gram.text_gram's index;
+            # the slowest over tenants): 0 exact, 1 bf16, 2 s8, -1 no Gram
+            plane = float(
+                q[active, QUALITY_INDEX["gram_plane"]].min()
+            ) if active.any() else -1.0
             drift = max((t.drift for t in self._tracks[:m]), default=0.0)
             trend = max((t.trend for t in self._tracks[:m]), default=0.0)
             alert_run = max(
@@ -280,7 +286,12 @@ class ModelWatch:
             if episode:
                 self._episodes += 1
             level = self._level
-        self._publish(m, level, drift, trend, wn, un, gn)
+        self._publish(m, level, drift, trend, wn, un, gn, plane)
+        # once per delivered batch, under the scheduler's sequence number
+        # (the delivery runs inside its batch_scope); a no-op without --trace
+        _trace.get().instant(
+            "gram_plane", batch=_trace.current_batch(), plane=int(plane)
+        )
         if flipped:
             _blackbox.record(
                 "model_health", level=level, prev=prev_level,
@@ -306,8 +317,9 @@ class ModelWatch:
             "flipped": flipped,
         }
 
-    def _publish(self, m, level, drift, trend, wn, un, gn) -> None:
+    def _publish(self, m, level, drift, trend, wn, un, gn, plane) -> None:
         reg = _metrics.get_registry()
+        reg.gauge("model.gram_plane").set(plane)
         reg.gauge("model.weight_norm").set(round(wn, 4))
         reg.gauge("model.update_norm").set(round(un, 4))
         reg.gauge("model.grad_norm").set(round(gn, 4))
