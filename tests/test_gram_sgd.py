@@ -649,9 +649,11 @@ def test_gate_on_a_feature_slice_ignores_clipped_zeroed_tokens(monkeypatch):
     with their value zeroed: hundreds of repeats of one index that carry no
     mass. Row 0 holds 300 distinct unit features in slice 0 and 300 in
     slice 2 of four: on those two model shards the slice's row mass is 300
-    (rung 1 fails) and 300 clipped tokens share one index; both must take
-    the bf16 plane, no shard the exact one, and the weights are the
-    single-device step's."""
+    and 300 clipped tokens share one index, on the other two every token
+    of the row is clipped. The gate reads the WHOLE row's figures (reduced
+    over ``model``, PR 27: mass 600, every count 1), so every shard takes
+    the bf16 plane by rung 2 — none the exact one, none s8 on its own
+    slice's lighter share — and the weights are the single-device step's."""
     from twtml_tpu.parallel import ParallelSGDModel, make_mesh
     from twtml_tpu.parallel import sharding
     from twtml_tpu.parallel.sharding import shard_batch
@@ -692,5 +694,4 @@ def test_gate_on_a_feature_slice_ignores_clipped_zeroed_tokens(monkeypatch):
     np.testing.assert_allclose(
         model.latest_weights, np.asarray(w_ref), rtol=2e-4, atol=2e-4
     )
-    assert len(planes) == 8 and 0 not in planes
-    assert planes.count(1) == 4   # model shards 0 and 2, on both data shards
+    assert planes == [1] * 8   # every model shard, on both data shards

@@ -538,11 +538,19 @@ def mesh_shape(conf) -> int:
     return min(shards, n_devices) if shards else n_devices
 
 
-def build_mesh(conf, what: str = "training"):
-    """The one-flag cluster story: the ('data',) mesh the conf calls for, or
-    None when a single device (or local[1]) keeps execution unsharded. Every
+def build_mesh(conf, what: str = "training", model_axis: bool = False):
+    """The one-flag cluster story: the mesh the conf calls for, or None
+    when a single device (or local[1]) keeps execution unsharded. Every
     entry point routes through here so device selection / local[N] capping
     can never diverge between apps.
+
+    The mesh is ``('data',)`` over the run's devices. A caller whose model
+    can shard its WEIGHTS (``build_model``'s single-model SGD learners)
+    passes ``model_axis``: then ``--modelShards M`` (> 1) makes it
+    ``('data', 'model')`` = (devices / M) x M, and M must divide the device
+    count and ``--numTextFeatures``. Any other caller refuses M > 1 — a
+    flag that is silently ignored would train another deployment than the
+    one asked for.
 
     Multi-host runs span the WHOLE process group's devices; jax.devices()
     is process-major, so the 1D data axis is automatically process-aligned
@@ -550,6 +558,13 @@ def build_mesh(conf, what: str = "training"):
     parallel/distributed.py)."""
     import jax
 
+    n_model = int(getattr(conf, "modelShards", 1) or 1)
+    if n_model > 1 and (not model_axis or jax.process_count() > 1):
+        raise SystemExit(
+            f"--modelShards {n_model}: the model axis is wired at the entry "
+            f"point for the single-model SGD learners on one host, not for "
+            f"{what}" + (" in a multi-host run" if model_axis else "")
+        )
     if jax.process_count() > 1:
         from ..parallel import make_mesh
 
@@ -560,18 +575,35 @@ def build_mesh(conf, what: str = "training"):
             what, jax.process_count(), jax.device_count(),
         )
         return make_mesh(num_data=jax.device_count(), devices=jax.devices())
-    n_data = mesh_shape(conf)
-    if n_data <= 1:
+    n_devices = mesh_shape(conf)
+    if n_model > 1:
+        f_text = conf.numTextFeatures
+        if n_devices % n_model or f_text % n_model:
+            raise SystemExit(
+                f"--modelShards {n_model} must divide the run's "
+                f"{n_devices} device(s) and --numTextFeatures {f_text}"
+            )
+    elif n_devices <= 1:
         return None
 
     from ..parallel import make_mesh
     from ..utils.backend import run_devices
 
-    log.info("mesh-sharded %s: %d-way data parallel", what, n_data)
     # the platform select_backend reported, never another one that happens
     # to be jax's first (a default device pinned to the CPU in a process
     # that also holds a chip)
-    return make_mesh(num_data=n_data, devices=run_devices()[:n_data])
+    devices = run_devices()[:n_devices]
+    if n_model == 1:
+        log.info("mesh-sharded %s: %d-way data parallel", what, n_devices)
+        return make_mesh(num_data=n_devices, devices=devices)
+    log.info(
+        "mesh-sharded %s: %d-way data x %d-way model (feature) parallel, "
+        "%d hashed features a shard",
+        what, n_devices // n_model, n_model, f_text // n_model,
+    )
+    return make_mesh(
+        num_data=n_devices // n_model, num_model=n_model, devices=devices
+    )
 
 
 def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
@@ -666,7 +698,9 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
             tenants, model.tenant_key, model.wire_pack,
         )
         return model, (mesh.shape[mesh.axis_names[0]] if mesh else 1)
-    mesh = build_mesh(conf, what=f"training ({model_cls.__name__})")
+    mesh = build_mesh(
+        conf, what=f"training ({model_cls.__name__})", model_axis=True
+    )
     codec = getattr(conf, "effective_wire_codec", lambda: "off")()
     if mesh is not None:
         from ..parallel import ParallelSGDModel
@@ -2916,6 +2950,10 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
     import jax
 
     from ..utils.rss import RssWatchdog
+
+    if hasattr(model, "mesh_layout"):
+        # start-up mark of a mesh model (a no-op without --trace)
+        _trace.get().instant("mesh_layout", **model.mesh_layout())
 
     # RSS watchdog on the batch cadence: the long-running loops are where
     # slow host-memory growth accumulates (utils/rss.py)

@@ -115,6 +115,7 @@ class ConfArguments:
         self.replayFile: str = conf.get("replayFile", "")
         self.replaySpeed: float = float(conf.get("replaySpeed", "0.0"))
         self.batchBucket: int = int(conf.get("batchBucket", "0"))
+        self.modelShards: int = int(conf.get("modelShards", "1"))
         self.tokenBucket: int = int(conf.get("tokenBucket", "0"))
         self.hashOn: str = conf.get("hashOn", "device")
         if self.hashOn not in ("device", "host"):
@@ -387,6 +388,14 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
   --replayFile <path.jsonl>                    Tweet replay file (source=replay)
   --replaySpeed <float>                        0 = as-fast-as-possible, else x realtime
   --batchBucket <int>                          Pad batches up to this bucket size (0 = auto)
+  --modelShards <int M>                        Shard the hashed text weights (and the Gram
+                                               state built over them) by feature over M of the
+                                               run's devices: the mesh is (devices / M) data x
+                                               M model (SCALING.md). For a hashed width whose
+                                               count matrix one chip cannot hold (2^20 dims at
+                                               2048 rows). M must divide the device count and
+                                               --numTextFeatures. 1 = the data-only mesh.
+                                               Default: {self.modelShards}
   --tokenBucket <int>                          Pad per-tweet tokens/units to this bucket
                                                (0 = auto per batch); pinning BOTH buckets
                                                fixes the XLA program shape, enabling the
@@ -759,6 +768,10 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
             self.replaySpeed = float(take())
         elif flag == "--batchBucket":
             self.batchBucket = int(take())
+        elif flag == "--modelShards":
+            self.modelShards = int(take())
+            if self.modelShards < 1:
+                self.printUsage(1)
         elif flag == "--tokenBucket":
             self.tokenBucket = int(take())
         elif flag == "--hashOn":

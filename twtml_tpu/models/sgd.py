@@ -233,7 +233,8 @@ def dual_scale_and_alpha(dual, axis_name: str, rows: int):
     alpha_local = lax.dynamic_slice_in_dim(
         dual["alpha"], lax.axis_index(axis_name) * rows, rows
     )
-    c = lax.psum(dual["c"], axis_name) / lax.axis_size(axis_name)
+    with jax.named_scope("collective"):
+        c = lax.psum(dual["c"], axis_name) / lax.axis_size(axis_name)
     return c, alpha_local
 
 

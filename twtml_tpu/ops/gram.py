@@ -74,6 +74,22 @@ sum of the bf16×bf16→f32 product, in any order, is an integer of magnitude
 feature-sharded caller clips to its slice edge with their value zeroed
 carry no mass, so they cannot trip a gate that sums |val|.
 
+Across feature slices (``feature_axis``: the 2-D step of
+parallel/sharding.py, each shard holding ``F / m`` of the features) the
+proof is per count matrix for (1) and (2) — a feature lives in exactly one
+slice, so a shard's counts are the whole row's counts on its features —
+and needs one more line for (3): G is the psum over the slices of their
+partial Gs, each an integer matrix, and Σ over ALL slices of
+Σ_f |c_af·c_bf| ≤ 256 · (GLOBAL row mass), so that psum is exact in f32
+while the global row mass is ≤ 65,536 — a bound no shard can see in its
+own slice. So the gate's inputs are reduced over ``feature_axis`` before
+any rung is decided: the ``[B]`` row masses by a psum (each shard's is its
+slice's share), ``integral`` and bf16-representable by a pmin (a value is
+out of every slice but one, where it is zeroed), and rung 2's verdict — a
+max over (row, feature), each pair in one slice — by a pmin of each
+shard's own. Every rung then reads the whole row's figures, every shard
+takes the SAME plane, and the index handed out is that one plane.
+
 The s8 plane tightens the same ladder: row absolute mass ≤ 127 (true of
 texts up to 128 units — per-occurrence 1.0 values) makes every count an
 integer in [−127, 127], EXACT in int8, so both matmuls run s8×s8→s32 on
@@ -185,6 +201,7 @@ def text_gram(
     row_start=None,
     rows: int = 0,
     int8_plane: bool | None = None,
+    feature_axis: str | None = None,
 ):
     """Text-feature Gram block and the plane it was built on: ``(G, plane)``
     with G = X·Xᵀ ([B,B] f32), or the row slice
@@ -203,6 +220,14 @@ def text_gram(
     fractional values, a feature repeated past 256 within one row — takes
     the exact fallback: f32 scatter densify + full-f32
     (``Precision.HIGHEST``) matmul.
+
+    ``feature_axis`` names the mesh axis the caller sliced the FEATURES
+    over (``f_text`` is then the slice's width, the pairs hold only this
+    slice's mass): the row masses (psum), the two value flags (pmin) and
+    rung 2's verdict (pmin) are reduced over it, three small collectives
+    under the ``collective`` scope, so that every shard takes the same
+    plane on the whole row's figures (module docstring). G stays this
+    slice's PARTIAL product; the caller psums it.
     """
     if int8_plane is None:
         int8_plane = GRAM_INT8_PLANE
@@ -214,10 +239,22 @@ def text_gram(
         integral = jnp.all(val_f == jnp.round(val_f))
         # ABSOLUTE mass: a plain sum would be unsound for mixed-sign values
         # (cancellation can hide a partial sum above the bf16 range)
-        max_row_mass = jnp.max(jnp.sum(jnp.abs(val_f), axis=1))
-        vals_bf16 = integral & jnp.all(
+        row_mass = jnp.sum(jnp.abs(val_f), axis=1)
+        if feature_axis:
+            # the whole row's mass, not this slice's (module docstring)
+            with jax.named_scope("collective"):
+                row_mass = lax.psum(row_mass, feature_axis)
+        max_row_mass = jnp.max(row_mass)
+        representable = jnp.all(
             val_f.astype(jnp.bfloat16).astype(jnp.float32) == val_f
         )
+        if feature_axis:
+            with jax.named_scope("collective"):
+                integral, representable = lax.pmin(
+                    jnp.stack([integral, representable]).astype(jnp.int32),
+                    feature_axis,
+                ).astype(bool)
+        vals_bf16 = integral & representable
         # rung 1: row mass ≤ 255 bounds every count of the row with it
         rung1 = vals_bf16 & (max_row_mass <= 255.0)
         # row absolute mass ≤ 127 tightens every bound to the int8 range:
@@ -259,6 +296,14 @@ def text_gram(
             token_idx,
             val_f,
         )
+        if feature_axis:
+            # every shard took the same branch (the predicate reads reduced
+            # figures); a (row, feature) pair lives in one slice, so the
+            # global maximum passes iff every slice's does
+            with jax.named_scope("collective"):
+                rung2 = lax.pmin(
+                    rung2.astype(jnp.int32), feature_axis
+                ).astype(bool)
         vals_ok = rung1 | rung2
 
     def left(c):
@@ -347,6 +392,6 @@ def dual_writeback(w_text, w_num, c, alpha, token_idx, token_val, numeric):
     Contributions for duplicate (row, feature) occurrences sum, exactly as
     the per-iteration ``sparse_grad_text`` scatter summed them."""
     contrib = token_val * alpha[:, None]  # [B, L]
-    w_text_new = (w_text * c).at[token_idx.reshape(-1)].add(contrib.reshape(-1))  # lawcheck: disable=TW004 -- the ONE budgeted scatter per batch the Gram design ships (50 per-iteration scatters folded into a single writeback, ~21 ms/step measured)
+    w_text_new = (w_text * c).at[token_idx.reshape(-1)].add(contrib.reshape(-1))  # lawcheck: disable=TW004 -- the ONE budgeted scatter per batch the Gram design ships (50 per-iteration scatters folded into a single writeback; its cost is stage_ms.writeback, PERF.md section 5)
     w_num_new = w_num * c + numeric.T @ alpha
     return w_text_new, w_num_new

@@ -102,6 +102,8 @@ def quality_vector(
     token_val,
     gram_plane=None,
     axis_name: str | None = None,
+    weight_sq=None,
+    update_sq=None,
 ) -> jnp.ndarray:
     """The ``[QUALITY_WIDTH]`` f32 quality vector for one micro-batch.
 
@@ -110,20 +112,23 @@ def quality_vector(
     the valid-row mask; all row-dimensioned inputs are shard-LOCAL under a
     data axis — the psums here make every output global, exactly like
     ``ops/stats.batch_stats``. Weights are replicated over any data axis,
-    so their norms need no collective. ``gram_plane`` is ``text_gram``'s
-    plane index (axis-invariant already), None outside the Gram basis."""
+    so their norms need no collective; a layout that shards the WEIGHTS
+    (the feature-sharded step, parallel/sharding.py) hands in ``weight_sq``
+    = ‖w_new‖² and ``update_sq`` = ‖w_new − w_prev‖² already reduced over
+    its model axis. ``gram_plane`` is ``text_gram``'s plane index
+    (axis-invariant already), None outside the Gram basis."""
     f32 = jnp.float32
     m = mask.astype(f32)
     n = _maybe_psum(jnp.sum(m), axis_name)
     denom = jnp.maximum(n, 1.0)
 
-    w_sq = _tree_sq_sum(w_new)
+    w_sq = _tree_sq_sum(w_new) if weight_sq is None else weight_sq
     upd_sq = sum(
         jnp.sum((a.astype(f32) - b.astype(f32)) ** 2)
         for a, b in zip(
             jax.tree_util.tree_leaves(w_new), jax.tree_util.tree_leaves(w_prev)
         )
-    )
+    ) if update_sq is None else update_sq
     grad_sq = _maybe_psum(jnp.sum(residual.astype(f32) ** 2), axis_name)
 
     def moments(x):

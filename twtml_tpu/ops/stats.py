@@ -18,7 +18,12 @@ import jax.numpy as jnp
 
 
 def _maybe_psum(x, axis_name):
-    return jax.lax.psum(x, axis_name) if axis_name else x
+    if not axis_name:
+        return x
+    # the mesh steps' collectives carry one scope name, whatever stage
+    # holds them (parallel/sharding.py)
+    with jax.named_scope("collective"):
+        return jax.lax.psum(x, axis_name)
 
 
 def masked_sum(x, mask, axis_name=None):

@@ -12,12 +12,19 @@ Two layouts:
   reuses the single-device fused step (models/sgd.py) with ``axis_name`` so
   gradient/stat reductions turn into ICI collectives. This is BASELINE
   config #5 (4-way sharded stream + gradient allreduce).
-- **feature-sharded** (2D mesh): the hashed text-feature axis of the weights
-  is sharded over ``model`` for numTextFeatures=2^18 (BASELINE config #4):
-  each shard gathers/scatter-adds only tokens hashing into its slice, with a
-  ``psum`` over ``model`` reassembling predictions — a sharded-embedding
-  pattern, not a translation of any reference code (the reference caps at
-  1000 dims in one JVM).
+- **feature-sharded** (2D mesh, ``--modelShards M``): the hashed
+  text-feature axis of the weights is sharded over ``model`` — for a hashed
+  width whose dense count matrix one chip cannot hold inside
+  ``ops/gram.fits_gram``'s gate (2^20 dims at batches of 2048: BASELINE
+  config #4's learner at spark.mllib HashingTF's default width, on config
+  #5's four chips), so that each shard's SLICE passes the gate and the step
+  stays in the Gram basis. Each shard gathers/scatter-adds only tokens
+  hashing into its slice, with a ``psum`` over ``model`` reassembling
+  predictions and the partial G panels — a sharded-embedding pattern, not a
+  translation of any reference code (the reference caps at 1000 dims in one
+  JVM). The body carries the single-device step's stage names (models/sgd.py
+  ``STAGE_SCOPES``), every collective under a ``collective`` scope inside
+  the stage it serves, and the single-device step's quality vector.
 """
 
 from __future__ import annotations
@@ -49,9 +56,13 @@ from ..models.sgd import (
     sgd_inner_loop,
 )
 from ..ops.gram import add_numeric_block, fits_gram, text_gram
+from ..ops.quality import quality_vector
 from ..ops.ragged import ragged_repad
 from ..ops.sparse import sparse_grad_text, sparse_text_dot
-from ..ops.stats import batch_stats
+# ``_psum``: lax.psum under the ``collective`` scope — the device time of the
+# mesh steps' collectives is summed by that name, whatever stage holds them
+# (benchmark/layer_metrics/collective_ms_per_batch.py)
+from ..ops.stats import _maybe_psum as _psum, batch_stats
 from ..ops.text_hash import hash_bigrams_device
 from ..utils.rounding import jnp_round_half_up
 
@@ -146,6 +157,12 @@ def shard_batch(batch: FeatureBatch | UnitBatch | RaggedUnitBatch, mesh):
     ))
 
 
+def _all_gather(x, axis):
+    """Tiled ``lax.all_gather`` of the rows, under the ``collective`` scope."""
+    with jax.named_scope("collective"):
+        return lax.all_gather(x, axis, axis=0, tiled=True)
+
+
 def _make_feature_sharded_step(
     *,
     f_text: int,
@@ -162,6 +179,7 @@ def _make_feature_sharded_step(
     model_axis: str,
     use_gram: bool | None = None,
     gram_int8: bool | None = None,
+    quality: bool = False,
 ):
     """Per-shard body for the 2D (data × model) mesh. Weights arrive as a
     {'text': [f_text_local], 'num': [4]} pytree; token indices are global and
@@ -176,7 +194,33 @@ def _make_feature_sharded_step(
     psum over ``model`` plus one gradient psum over ``data`` per iteration
     (2·numIterations collectives/batch) in the scatter formulation. The
     write-back stays slice-local (this shard's rows × its feature slice)
-    with one psum over ``data``."""
+    with one psum over ``data``.
+
+    What is reduced over which axis, once a batch (Gram basis):
+
+    - over ``model``: the predict partials ``[B_local]`` (psum); the plane
+      gate's inputs inside ``text_gram`` — the ``[B]`` row masses (psum),
+      the two value flags (pmin) and rung 2's verdict (pmin) — so every
+      shard takes the SAME plane on the whole row's figures and the psum of
+      the integer partial panels is exact in f32 (ops/gram.py docstring);
+      the partial G panels ``[B_local, B]`` (psum); ‖W_prev‖² and the
+      quality vector's two weight norms (psum of each slice's share);
+    - over ``data``: the batch's rows — hashed pairs, numeric, label, mask
+      and ``u`` (all-gather); the G panels (all-gather); the slice-local
+      write-back deltas ``[f_text_local]`` and ``[4]`` (psum); the batch
+      statistics and the quality vector's row sums (psum); and two scalars
+      that are equal on every data shard already, the dual scale ``c``
+      (psum-mean) and the plane index (pmin), reduced only to make them
+      statically invariant.
+
+    Stage names are the single-device step's (models/sgd.py
+    ``STAGE_SCOPES``); each collective sits inside the stage it serves,
+    under a further scope ``collective``: the predict psum under
+    ``predict``, the batch all-gather under ``hash`` (it assembles the
+    hashed pairs of all rows) and ``predict`` (the row vectors), the gate's
+    reductions under ``gram_count``, the panel psum and the G all-gather
+    under ``gram_matmul``, ‖W_prev‖² under ``dual_loop`` (before the loop,
+    never inside it), the write-back psum under ``writeback``."""
     residual_fn = residual_fn or (lambda raw, label: raw - label)
     prediction_fn = prediction_fn or (lambda raw: raw)
 
@@ -191,8 +235,6 @@ def _make_feature_sharded_step(
                 batch.mask.shape[0],
             )
             batch = UnitBatch(buf, lens, batch.numeric, batch.label, batch.mask)
-        mask = batch.mask.astype(dtype)
-        labels = batch.label.astype(dtype)
         if isinstance(batch, UnitBatch):
             # on-device featurization: each data shard hashes its own rows'
             # code units to GLOBAL indices, then slices per model shard below
@@ -201,25 +243,59 @@ def _make_feature_sharded_step(
             )
         else:
             # compact wire dtype (batch.compact_tokens) → int32 index math
-            g_idx = batch.token_idx.astype(jnp.int32)
-            token_val = batch.token_val.astype(dtype)
-        numeric = batch.numeric.astype(dtype)
+            with jax.named_scope("unpack"):
+                g_idx = batch.token_idx.astype(jnp.int32)
+                token_val = batch.token_val.astype(dtype)
+        with jax.named_scope("unpack"):
+            mask = batch.mask.astype(dtype)
+            labels = batch.label.astype(dtype)
+            numeric = batch.numeric.astype(dtype)
         lo = lax.axis_index(model_axis) * f_text_local
-        rel = g_idx - lo
-        in_slice = ((rel >= 0) & (rel < f_text_local)).astype(dtype)
-        rel = jnp.clip(rel, 0, f_text_local - 1)
-        local_val = token_val * in_slice  # zero out tokens outside this slice
+
+        def to_slice(idx, val):
+            """Global (idx, val) pairs → this shard's slice: indices
+            relative to it, values zeroed outside it."""
+            rel = idx - lo
+            inside = ((rel >= 0) & (rel < f_text_local)).astype(dtype)
+            return jnp.clip(rel, 0, f_text_local - 1), val * inside
 
         def predict(w):
             part = sparse_text_dot(w["text"], rel, local_val)
-            return lax.psum(part, model_axis) + numeric @ w["num"]
+            return _psum(part, model_axis) + numeric @ w["num"]
 
         # ---- predict + stats with pre-update weights --------------------
-        raw = predict(weights)
-        preds = prediction_fn(raw)
-        if round_predictions:
-            preds = jnp_round_half_up(preds)
-        stats = batch_stats(labels, preds, mask, data_axis)
+        with jax.named_scope("predict"):
+            rel, local_val = to_slice(g_idx, token_val)
+            raw = predict(weights)
+            preds = prediction_fn(raw)
+            if round_predictions:
+                preds = jnp_round_half_up(preds)
+            stats = batch_stats(labels, preds, mask, data_axis)
+
+        def norm_sq_of(text, num):
+            # text slices live on the model axis; num is replicated there
+            return _psum(jnp.sum(text * text), model_axis) + jnp.sum(num * num)
+
+        def _quality(w_new, gram_plane=None):
+            # the ISSUE-8 side channel (models/sgd.py ``_quality``): rows
+            # reduce over ``data``, the text weights' norms over ``model``
+            if not quality:
+                return None
+            with jax.named_scope("quality"):
+                new_t, new_n, old_t, old_n = (
+                    a.astype(jnp.float32)
+                    for a in (w_new["text"], w_new["num"], w_text, w_num)
+                )
+                return quality_vector(
+                    weights, w_new,
+                    residual=residual_fn(raw, labels) * mask,
+                    preds=preds, labels=labels, mask=mask,
+                    numeric=batch.numeric, token_idx=g_idx,
+                    token_val=token_val, gram_plane=gram_plane,
+                    axis_name=data_axis,
+                    weight_sq=norm_sq_of(new_t, new_n),
+                    update_sq=norm_sq_of(new_t - old_t, new_n - old_n),
+                )
 
         # ---- Gram (dual) basis when it applies (see docstring) ----------
         b_local = mask.shape[0]
@@ -231,24 +307,34 @@ def _make_feature_sharded_step(
             else use_gram
         )
         if gram:
-            gather = lambda a: lax.all_gather(a, data_axis, axis=0, tiled=True)
-            idx_g, val_g, num_g, lab_g, mask_g, u = map(
-                gather, (g_idx, token_val, numeric, labels, mask, raw)
-            )
-            rel_g = idx_g - lo
-            in_g = ((rel_g >= 0) & (rel_g < f_text_local)).astype(dtype)
-            panel, _ = text_gram(
-                jnp.clip(rel_g, 0, f_text_local - 1),
-                val_g * in_g,
+            with jax.named_scope("hash"):
+                idx_g = _all_gather(g_idx, data_axis)
+                val_g = _all_gather(token_val, data_axis)
+            with jax.named_scope("predict"):
+                num_g, lab_g, mask_g, u = (
+                    _all_gather(a, data_axis)
+                    for a in (numeric, labels, mask, raw)
+                )
+            with jax.named_scope("gram_count"):
+                rel_g, local_val_g = to_slice(idx_g, val_g)
+            panel, plane = text_gram(
+                rel_g,
+                local_val_g,
                 f_text_local,
                 row_start=lax.axis_index(data_axis) * b_local,
                 rows=b_local,
                 int8_plane=gram_int8,
+                feature_axis=model_axis,
             )  # [B_local, B_global] partial over this feature slice
-            g_mat = lax.all_gather(
-                lax.psum(panel, model_axis), data_axis, axis=0, tiled=True
-            )
+            with jax.named_scope("gram_matmul"):
+                g_mat = _all_gather(_psum(panel, model_axis), data_axis)
+                # every data shard gated the same gathered rows: the pmin
+                # only makes the index statically invariant (models/sgd.py)
+                with jax.named_scope("collective"):
+                    plane = lax.pmin(plane, data_axis)
             g_mat = add_numeric_block(g_mat, num_g, dtype)
+            with jax.named_scope("dual_loop"):
+                p_prev = norm_sq_of(w_text, w_num)  # its convergence norm
 
             dual = run_dual_loop(
                 u=u,
@@ -262,38 +348,40 @@ def _make_feature_sharded_step(
                 mini_batch_fraction=mini_batch_fraction,
                 l2_reg=l2_reg,
                 convergence_tol=convergence_tol,
-                p_prev=lax.psum(jnp.sum(w_text * w_text), model_axis)
-                + jnp.sum(w_num * w_num),
+                p_prev=p_prev,
                 vary_axis=data_axis,
             )
-            # psum-mean of the (identical-everywhere) scale + psum of the
-            # slice-local write-back: statically invariant over ``data``
-            c, alpha_local = dual_scale_and_alpha(dual, data_axis, b_local)
-            delta_text = lax.psum(
-                sparse_grad_text(rel, local_val, alpha_local, f_text_local),
-                data_axis,
+            with jax.named_scope("writeback"):
+                # psum-mean of the (identical-everywhere) scale + psum of
+                # the slice-local write-back: statically invariant over
+                # ``data``
+                c, alpha_local = dual_scale_and_alpha(dual, data_axis, b_local)
+                w_final = {
+                    "text": w_text * c + _psum(
+                        sparse_grad_text(
+                            rel, local_val, alpha_local, f_text_local
+                        ),
+                        data_axis,
+                    ),
+                    "num": w_num * c
+                    + _psum(numeric.T @ alpha_local, data_axis),
+                }
+            return w_final, StepOutput(
+                predictions=preds, quality=_quality(w_final, plane), **stats
             )
-            w_final = {
-                "text": w_text * c + delta_text,
-                "num": w_num * c + lax.psum(numeric.T @ alpha_local, data_axis),
-            }
-            return w_final, StepOutput(predictions=preds, **stats)
 
         # ---- the shared MLlib iteration loop over the sharded pytree ----
         def grad_and_count(w, sel):
             residual = residual_fn(predict(w), labels) * sel
-            g_text = lax.psum(
+            g_text = _psum(
                 sparse_grad_text(rel, local_val, residual, f_text_local), data_axis
             )
-            g_num = lax.psum(residual @ numeric, data_axis)
-            count = lax.psum(jnp.sum(sel), data_axis)
+            g_num = _psum(residual @ numeric, data_axis)
+            count = _psum(jnp.sum(sel), data_axis)
             return {"text": g_text, "num": g_num}, count
 
         def norm_sq(a, b):
-            # text slices live on the model axis; num is replicated there
-            return lax.psum(jnp.sum((a["text"] - b["text"]) ** 2), model_axis) + (
-                jnp.sum((a["num"] - b["num"]) ** 2)
-            )
+            return norm_sq_of(a["text"] - b["text"], a["num"] - b["num"])
 
         w_final = sgd_inner_loop(
             {"text": w_text, "num": w_num},
@@ -307,7 +395,9 @@ def _make_feature_sharded_step(
             grad_and_count=grad_and_count,
             norm_sq=norm_sq,
         )
-        return w_final, StepOutput(predictions=preds, **stats)
+        return w_final, StepOutput(
+            predictions=preds, quality=_quality(w_final), **stats
+        )
 
     return step
 
@@ -345,18 +435,6 @@ class ParallelSGDModel:
         self._wire_sharding = None
         out_pred_spec = P(self.data_axis)
         scalar = P()
-        if quality and self.model_axis is not None:
-            # the feature-sharded (2D) step has its own body below; its
-            # weight norms would need model-axis psums the quality plane
-            # doesn't wire yet — degrade loudly rather than mis-report
-            from ..utils import get_logger
-
-            get_logger("parallel.sharding").warning(
-                "--modelWatch quality vector is not wired for the "
-                "feature-sharded (2D model-axis) layout; disabling the "
-                "in-step quality leaf for this model"
-            )
-            quality = False
         self.quality = quality
 
         if self.model_axis is None:
@@ -402,6 +480,7 @@ class ParallelSGDModel:
                 model_axis=self.model_axis,
                 use_gram=use_gram,
                 gram_int8=gram_int8,
+                quality=quality,
             )
             self._weights = {
                 "text": jax.device_put(
@@ -438,18 +517,23 @@ class ParallelSGDModel:
     def _step_for(self, batch_cls) -> Callable:
         fn = self._sharded.get(batch_cls)
         if fn is None:
-            body = self._step_body
-            if batch_cls is PackedBatch:
-                # per-shard packed ragged wire: each device's local slice is
-                # ONE shard segment; rebuild the shard-local batch in-program
-                # (zero-copy bitcasts) and run the ordinary per-shard body
-                def body(weights, pb, _inner=self._step_body):
-                    return _inner(
-                        weights, unpack_batch(pb.buffer, pb.layout)
-                    )
+            inner = self._step_body
+
+            # ONE function name, so one module name (``jit_sharded_train_
+            # step``) on the device plane and in the ``compile`` spans for
+            # every bucket and wire form of both layouts
+            def sharded_train_step(weights, batch):
+                if isinstance(batch, PackedBatch):
+                    # per-shard packed ragged wire: each device's local
+                    # slice is ONE shard segment; rebuild the shard-local
+                    # batch in-program (zero-copy bitcasts) and run the
+                    # ordinary per-shard body
+                    with jax.named_scope("unpack"):
+                        batch = unpack_batch(batch.buffer, batch.layout)
+                return inner(weights, batch)
 
             sharded = jax.shard_map(
-                body,
+                sharded_train_step,
                 mesh=self.mesh,
                 in_specs=(self._w_spec, _pspecs_for(batch_cls, self.data_axis)),
                 out_specs=self._out_specs,
@@ -475,9 +559,9 @@ class ParallelSGDModel:
             body = self._step_body
             if batch_cls is PackedBatch:
                 def scanned(weights, pb, _inner=body):
-                    return lax.scan(
-                        _inner, weights, unpack_batch(pb.buffer, pb.layout)
-                    )
+                    with jax.named_scope("unpack"):
+                        stacked = unpack_batch(pb.buffer, pb.layout)
+                    return lax.scan(_inner, weights, stacked)
 
                 in_spec = _pspecs_for(PackedBatch, self.data_axis)
             else:
@@ -540,6 +624,18 @@ class ParallelSGDModel:
                 for leaf in jax.tree_util.tree_leaves(self._weights)
             ),
             "batch": len(wire.device_set) if wire is not None else 0,
+        }
+
+    def mesh_layout(self) -> dict:
+        """The mesh as the ``mesh_layout`` trace instant carries it
+        (telemetry/trace.py): axis sizes, hashed features a model shard
+        holds, devices."""
+        num_model = self.mesh.shape[self.model_axis] if self.model_axis else 1
+        return {
+            "data": self.num_data,
+            "model": num_model,
+            "f_text_local": self.num_text_features // num_model,
+            "devices": self.mesh.size,
         }
 
     @property

@@ -66,6 +66,12 @@ or persistent-cache fetch: ``seconds``, ``cache_hit``, ``fun``,
 ``startup``) and ``signature`` (what the dispatch site says of the call:
 rows, row length, units dtype, wire form).
 
+Mesh layout: a mesh model's run opens with one ``mesh_layout`` instant
+(``apps/common.attach_super_batcher``): ``data`` and ``model`` axis sizes,
+``f_text_local`` (hashed features a model shard holds) and ``devices``.
+The device time of the mesh steps' collectives is not a span: it is on the
+device plane under the ``collective`` scope (parallel/sharding.py).
+
 Event sink (r8): the crash flight recorder (telemetry/blackbox.py) attaches
 via ``set_event_sink`` so recent spans ride its bounded in-memory ring —
 one callback per written event, no second file, nothing when tracing is
