@@ -29,10 +29,16 @@ from twtml_tpu.features.batch import (
 from twtml_tpu.features.featurizer import Featurizer
 from twtml_tpu.models import StreamingLinearRegressionWithSGD
 from twtml_tpu.models.sgd import STAGE_SCOPES
-from twtml_tpu.ops.gram import text_gram
+from twtml_tpu.ops import gram as gram_ops
 from twtml_tpu.ops.quality import QUALITY_INDEX
 from twtml_tpu.parallel import ParallelSGDModel, make_mesh
 from twtml_tpu.streaming.sources import SyntheticSource
+
+
+def text_gram(*args, **kwargs):
+    """``(G, plane)``: the switch with G alone as its body."""
+    return gram_ops.text_gram(*args, body=gram_ops.CountPlane.gram, **kwargs)
+
 
 F_TEXT = 1 << 14
 ROWS = 64
@@ -414,3 +420,27 @@ def test_sharded_step_has_no_scope_name_before_one_of_the_nine(op_names):
                 assert part in (
                     "cond", "while", "body", "shard_map", "closed_call"
                 ), name
+
+
+# ---- (vi) PR 28: the mesh steps predict and write back through C ----------
+
+@pytest.mark.parametrize("layout", [(2, 2), (1, 4), (4, 1)],
+                         ids=lambda l: f"{l[0]}x{l[1]}")
+def test_sharded_gram_step_asks_for_no_gather_or_scatter_on_its_weights(
+    layout
+):
+    """Counted on the lowered per-shard program at hash2e20's width, for
+    the 2-D step and (4 x 1) the data-only mesh step: inside the Gram basis
+    no gather reads the shard's ``[F_local]`` text weights and no scatter
+    writes an array of that shape — both went through ``sparse_text_dot`` /
+    ``sparse_grad_text`` until PR 28 (tests/test_step_scopes.py counts the
+    compiled branches, and what a count of this kind finds in the scatter
+    loop)."""
+    from test_step_scopes import gathers_and_scatters
+
+    text = _lowered("packed", layout).as_text()
+    weights = f"tensor<{F_BIG // layout[1]}xf32>"
+    assert weights in text  # the shard's slice is an array of the program
+    gathers, scatters = gathers_and_scatters(text)
+    assert gathers  # the ragged wire's re-pad still gathers units
+    assert weights not in gathers and weights not in scatters

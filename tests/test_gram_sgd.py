@@ -17,8 +17,15 @@ import jax.numpy as jnp
 from twtml_tpu.features.batch import NUM_NUMBER_FEATURES, FeatureBatch, UnitBatch
 from twtml_tpu.models.logistic import StreamingLogisticRegressionWithSGD
 from twtml_tpu.models.sgd import make_sgd_train_step, zero_weights
+from twtml_tpu.ops import gram as gram_ops
 from twtml_tpu.ops.gram import fits_gram, gram_matrix
 from twtml_tpu.ops.sparse import densify_text
+
+
+def text_gram(*args, **kwargs):
+    """``(G, plane)``: the switch with G alone as its body."""
+    return gram_ops.text_gram(*args, body=gram_ops.CountPlane.gram, **kwargs)
+
 
 F_TEXT = 512  # small enough for fast CPU tests; forced sparse via use_sparse
 
@@ -123,8 +130,6 @@ def test_gram_matrix_int8_plane_is_bit_exact():
         densify_text(jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val), F_TEXT)
     )
     ref = dense @ dense.T
-    from twtml_tpu.ops.gram import text_gram
-
     got = np.asarray(
         text_gram(jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val), F_TEXT)[0]
     )
@@ -137,8 +142,6 @@ def test_gram_matrix_int8_gate_mixed_sign_boundary():
     (still correct — counts here are small, so bf16 is exact too; the test
     that actually DISTINGUISHES the planes at the boundary is
     test_gram_matrix_int8_gate_count_wrap_boundary's sign witness)."""
-    from twtml_tpu.ops.gram import text_gram
-
     for vals, exact in [([60.0, -60.0, 7.0, 0.0], True),
                         ([64.0, -57.0, 7.0, 0.0], False)]:
         token_idx = np.array([[3, 3, 9, 11]], np.int32)
@@ -160,8 +163,6 @@ def test_gram_matrix_int8_gate_count_wrap_boundary():
     share feature 7; row0's count is 127 (int8-exact, must be array-equal)
     or 128 (would wrap to −128 if the gate admitted it — G[0,1] flips sign,
     so a gate loosened to ≤128, or a wrong narrowing dtype, fails here)."""
-    from twtml_tpu.ops.gram import text_gram
-
     for count, exact in [(127.0, True), (128.0, False)]:
         token_idx = np.array([[7, 0], [7, 0]], np.int32)
         token_val = np.array([[count, 0.0], [1.0, 0.0]], np.float32)
@@ -179,8 +180,6 @@ def test_gram_matrix_int8_gate_count_wrap_boundary():
 def test_gram_matrix_int8_plane_disabled_still_matches():
     """int8_plane=False rebuilds the r3 two-plane program (the bench A/B
     baseline) and stays on the reference."""
-    from twtml_tpu.ops.gram import text_gram
-
     rng = np.random.default_rng(21)
     batch = random_batch(rng)
     dense = np.asarray(
@@ -606,8 +605,6 @@ def test_gate_takes_the_plane_its_proof_covers(name):
     the exact plane."""
     from jax import lax
 
-    from twtml_tpu.ops.gram import text_gram
-
     token_idx, token_val, want = _gate_case(name)
     g, plane = jax.jit(lambda i, v: text_gram(i, v, F_TEXT))(token_idx, token_val)
     assert int(plane) == want
@@ -625,7 +622,7 @@ def test_gate_count_257_is_not_rounded_to_256():
     """What the gate is for: bf16 holds 256 and not 257, so a count of 257
     on the bf16 plane would give G[0,0] = 256² + … — the exact plane gives
     257² + 20, to the unit."""
-    from twtml_tpu.ops.gram import onehot_counts, text_gram
+    from twtml_tpu.ops.gram import onehot_counts
 
     token_idx, token_val, _ = _gate_case("count_257_on_one_feature")
     g, plane = text_gram(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
@@ -635,8 +632,6 @@ def test_gate_count_257_is_not_rounded_to_256():
 
 
 def test_gate_without_the_int8_plane_takes_bf16_where_s8_would_do():
-    from twtml_tpu.ops.gram import text_gram
-
     token_idx, token_val, _ = _gate_case("pads_have_multiplicity_not_mass")
     _g, plane = text_gram(
         jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT, int8_plane=False
@@ -695,3 +690,128 @@ def test_gate_on_a_feature_slice_ignores_clipped_zeroed_tokens(monkeypatch):
         model.latest_weights, np.asarray(w_ref), rtol=2e-4, atol=2e-4
     )
     assert planes == [1] * 8   # every model shard, on both data shards
+
+
+# ---------------------------------------------------------------------------
+# PR 28: inside the Gram basis the text half of u = Z·W_prev and of Zᵀα are
+# contractions with the plane's count matrix (ops/gram.CountPlane), not a
+# gather from / a scatter into the [F] weights. The gather and the scatter
+# (ops/sparse.py) are the references.
+
+def _contraction_batch(rng, plane: str, b=32, l=12, f_text=F_TEXT):
+    """Duplicate tokens within a row, mixed-sign values, pad slots
+    ``(0, 0.0)``, a masked row, and tokens on both sides of every slice edge
+    a 4-way model axis cuts (a feature-sharded step clips them to its slice
+    with their value zeroed)."""
+    idx = rng.integers(0, f_text, size=(b, l)).astype(np.int32)
+    if plane == "exact":  # fractional values: only the f32 plane holds them
+        val = rng.normal(size=(b, l)).astype(np.float32)
+    elif plane == "bf16":  # integral, row mass > 127: not the s8 plane's
+        val = rng.integers(1, 4, size=(b, l)).astype(np.float32)
+        val[:, 0] = 130.0
+    else:  # s8: integral, every row's absolute mass ≤ 127
+        val = rng.integers(1, 4, size=(b, l)).astype(np.float32)
+    val[:, 1] *= -1.0  # mixed signs
+    idx[:, 2] = idx[:, 3]  # a feature twice in a row…
+    idx[:, 4] = idx[:, 3]  # …and a third time
+    edge = f_text // 4
+    idx[:, 5] = rng.choice([edge - 1, edge, 2 * edge - 1, 2 * edge,
+                            f_text - 1, 0], size=b)
+    idx[:, l - 2:] = 0  # pad slots
+    val[:, l - 2:] = 0.0
+    numeric = rng.normal(size=(b, NUM_NUMBER_FEATURES)).astype(np.float32) * 0.1
+    label = rng.uniform(0, 50.0, size=(b,)).astype(np.float32)
+    mask = np.ones((b,), np.float32)
+    mask[5] = 0.0  # a masked row that still carries tokens
+    return FeatureBatch(idx, val, numeric, label, mask)
+
+
+def _rel_l1(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sum(np.abs(got - want)) / np.sum(np.abs(want)))
+
+
+def _round_bf16(x):
+    return np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
+
+
+_PLANE_INDEX = {"exact": 0, "bf16": 1, "s8": 2}
+
+
+@pytest.mark.parametrize("plane, layout, weights", [
+    (plane, layout, "normal")
+    for plane in ("exact", "bf16", "s8") for layout in ("single", "data", "2x2")
+] + [("bf16", layout, "low_bits") for layout in ("single", "data", "2x2")])
+def test_gram_step_contracts_with_counts_like_gather_and_scatter(
+    plane, layout, weights
+):
+    """The step's ``u`` against ``sparse_predict`` and its new weights
+    against ``w·c + sparse_grad_text(…, α)`` (c, α from the shared dual loop
+    on the reference's own u and G), at f32 tolerance: 1e-6 relative L1.
+    ``low_bits``: weights of the form 1 + k·2⁻²⁰, whose whole signal sits
+    under bf16's 8 bits — a contraction that rounded ``w`` (or ``α``) to
+    bf16 is shown, on the same data, to miss the tolerance by ≥ 100x."""
+    from twtml_tpu.models.sgd import run_dual_loop
+    from twtml_tpu.ops.sparse import sparse_grad_text, sparse_predict
+    from twtml_tpu.parallel import ParallelSGDModel, make_mesh
+    from twtml_tpu.parallel.sharding import shard_batch
+
+    rng = np.random.default_rng(2800 + 10 * _PLANE_INDEX[plane])
+    batch = _contraction_batch(rng, plane)
+    if weights == "low_bits":
+        w0 = 1.0 + rng.integers(1, 256, size=F_TEXT + 4) * 2.0 ** -20
+    else:
+        w0 = rng.normal(size=F_TEXT + 4)
+    w0 = w0.astype(np.float32)
+    kw = dict(num_text_features=F_TEXT, num_iterations=8, step_size=0.02,
+              l2_reg=0.1, use_gram=True, quality=True)
+
+    if layout == "single":
+        step = jax.jit(make_sgd_train_step(
+            use_sparse=True, round_predictions=False, **kw))
+        w_new, out = step(jnp.asarray(w0), batch)
+        w_new = np.asarray(w_new)
+    else:
+        mesh = (make_mesh(num_data=4) if layout == "data"
+                else make_mesh(num_data=2, num_model=2))
+        model = ParallelSGDModel(
+            mesh, use_sparse=True, round_predictions=False, **kw)
+        model.set_initial_weights(w0)
+        out = model.step(shard_batch(batch, mesh))
+        w_new = model.latest_weights
+    assert int(np.asarray(out.quality)[-1]) == _PLANE_INDEX[plane]
+
+    # ---- the references: gather, scatter -------------------------------
+    idx, val = jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val)
+    numeric = jnp.asarray(batch.numeric)
+    w_text, w_num = jnp.asarray(w0[:F_TEXT]), jnp.asarray(w0[F_TEXT:])
+    u_ref = sparse_predict(w_text, w_num, idx, val, numeric)
+    assert _rel_l1(out.predictions, u_ref) <= 1e-6
+    dual = run_dual_loop(
+        u=u_ref, g=gram_matrix(idx, val, numeric, F_TEXT),
+        labels=jnp.asarray(batch.label), mask=jnp.asarray(batch.mask),
+        dtype=jnp.float32, residual_fn=lambda raw, label: raw - label,
+        num_iterations=8, step_size=0.02, mini_batch_fraction=1.0,
+        l2_reg=0.1, convergence_tol=0.001, p_prev=jnp.sum(w0 * w0),
+    )
+    c, alpha = dual["c"], dual["alpha"]
+
+    def written_back(alpha):
+        return np.concatenate([
+            w_text * c + sparse_grad_text(idx, val, alpha, F_TEXT),
+            w_num * c + numeric.T @ alpha,
+        ])
+
+    w_ref = written_back(alpha)
+    # the update alone (w_new − c·w), so that weights the batch never
+    # touches do not pad the denominator
+    scaled = np.asarray(w0 * c)
+    assert _rel_l1(w_new - scaled, w_ref - scaled) <= 1e-6
+    assert _rel_l1(w_new, w_ref) <= 1e-6
+
+    if weights == "low_bits":
+        u_rounded = sparse_predict(
+            jnp.asarray(_round_bf16(w_text)), w_num, idx, val, numeric)
+        assert _rel_l1(u_rounded, u_ref) >= 1e-4
+        w_rounded = written_back(jnp.asarray(_round_bf16(alpha)))
+        assert _rel_l1(w_rounded - scaled, w_ref - scaled) >= 1e-4

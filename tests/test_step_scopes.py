@@ -19,6 +19,9 @@ from twtml_tpu.features.batch import (
 from twtml_tpu.models.sgd import STAGE_SCOPES, make_sgd_train_step
 
 F_TEXT = 1 << 18
+# what a plane's branch of ``text_gram``'s switch holds, in order
+GRAM_BRANCH_SCOPES = (
+    "gram_count", "predict", "gram_matmul", "dual_loop", "writeback")
 
 
 def _ragged(rows: int, row_len: int) -> RaggedUnitBatch:
@@ -75,19 +78,23 @@ def op_names():
 ])
 def test_all_nine_stage_names_on_each_gram_plane(op_names, plane, branch):
     """Every scope name is on some operation of the step, and each of
-    ``text_gram``'s three planes (the branches of its switch) has its count
-    build under ``gram_count`` and its product under ``gram_matmul``: the
-    planes share the names, the operand types tell them apart."""
+    ``text_gram``'s three planes (the branches of its switch) runs the whole
+    Gram basis on its own count matrix (PR 28): the build under
+    ``gram_count``, ``u = C·w`` under ``predict``, the product under
+    ``gram_matmul``, the loop under ``dual_loop`` and ``Cᵀα`` under
+    ``writeback``. The planes share the names, the operand types tell them
+    apart."""
     for scope in STAGE_SCOPES:
         assert any(f"/{scope}/" in n or n.endswith(f"/{scope}")
                    for n in op_names), scope
     inside = [n for n in op_names if f"/branch_{branch}_fun/" in n]
     assert inside, plane
-    for scope in ("gram_count", "gram_matmul"):
+    for scope in GRAM_BRANCH_SCOPES:
         assert any(f"/branch_{branch}_fun/{scope}/" in n for n in inside), (
             plane, scope)
-    # nothing a branch runs is outside the two names
-    assert all("/gram_count/" in n or "/gram_matmul/" in n for n in inside)
+    # nothing a branch runs is outside the five names
+    assert all(any(f"/{scope}/" in n for scope in GRAM_BRANCH_SCOPES)
+               for n in inside)
 
 
 def test_no_scope_name_beyond_the_nine(op_names):
@@ -114,3 +121,245 @@ def test_step_module_name_is_the_same_for_every_bucket_and_wire(
     the step the same way in every cell."""
     text = _lowered(form, rows, row_len).as_text()
     assert re.search(r"^module @(\S+)", text, re.M).group(1) == "jit_train_step"
+
+
+# ---------------------------------------------------------------------------
+# PR 28: inside the Gram basis the step predicts and writes back through the
+# plane's count matrix. What the PROGRAM asks for is in the lowered module
+# (no gather from the [F] text weights, no scatter into them); what the
+# COMPILER made of it — no f32 copy of a bf16 / s8 count matrix — only the
+# TPU's compiler can say, so the step is compiled at hash2e18's / hash2e20's
+# size for a described v5e (nothing runs; this file alone loads libtpu).
+
+_GATHER = re.compile(r'stablehlo\.gather"[^\n]*? : \((tensor<[^>]*>)')
+_SCATTER = re.compile(
+    r'stablehlo\.scatter"[\s\S]*?\}\) : \([^)]*\) -> (tensor<[^>]*>)')
+
+
+def gathers_and_scatters(lowered_text: str) -> tuple:
+    """(operand type of every gather, result type of every scatter)."""
+    return _GATHER.findall(lowered_text), _SCATTER.findall(lowered_text)
+
+
+def test_gram_step_asks_for_no_gather_from_and_no_scatter_into_the_weights():
+    weights = f"tensor<{F_TEXT}xf32>"
+    gathers, scatters = gathers_and_scatters(
+        _lowered("packed", quality=True).as_text())
+    assert gathers and weights not in gathers  # the ragged wire's re-pad
+    assert weights not in scatters
+    # the same count finds both in the scatter loop: it can see them
+    gathers, scatters = gathers_and_scatters(
+        _lowered("packed", use_gram=False, quality=True).as_text())
+    assert weights in gathers and weights in scatters
+
+
+# stablehlo op counts of the two programs that stay OUTSIDE the Gram basis,
+# as the parent of PR 28 lowered them (packed ragged wire, 8 rows of 16)
+_PARENT_OPS = {
+    "serving": (254, {"gather": 4, "scatter": 2, "dot_general": 1,
+                      "reduce": 12, "multiply": 14, "convert": 11}),
+    "scatter_loop": (770, {"gather": 4, "scatter": 4, "dot_general": 3,
+                           "reduce": 63, "multiply": 44, "convert": 14,
+                           "while": 1}),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_PARENT_OPS))
+def test_programs_outside_the_gram_basis_are_the_parents(program):
+    """``serving/engine.py`` pins ``use_gram=False`` and the scatter loop is
+    the differential baseline: PR 28 moved the Gram step's predict into the
+    switch and must not have touched either program."""
+    if program == "serving":
+        from twtml_tpu.serving.engine import PredictEngine
+
+        model = PredictEngine(num_text_features=F_TEXT).model
+        text = model._step.lower(
+            model._weights, _wire("packed", 8, 16)).as_text()
+    else:
+        text = _lowered("packed", use_gram=False, quality=True).as_text()
+    ops = re.findall(r"\bstablehlo\.([a-z_0-9]+)", text)
+    total, some = _PARENT_OPS[program]
+    assert len(ops) == total
+    assert {name: ops.count(name) for name in some} == some
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+ROWS = 2048
+
+
+def _compile_single(topo) -> str:
+    from jax.sharding import SingleDeviceSharding
+
+    dev = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=dev)
+
+    step = make_sgd_train_step(
+        num_text_features=F_TEXT, num_iterations=50, step_size=0.005,
+        l2_reg=0.1, quality=True)
+    batch = UnitBatch(
+        shape((ROWS, 512), jnp.uint8), shape((ROWS,), jnp.int32),
+        shape((ROWS, 4), jnp.float32), shape((ROWS,), jnp.float32),
+        shape((ROWS,), jnp.float32))
+    return jax.jit(step, donate_argnums=0).lower(
+        shape((F_TEXT + 4,), jnp.float32), batch).compile().as_text()
+
+
+def _compile_2x2(topo) -> str:
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from twtml_tpu.models.base import StepOutput
+    from twtml_tpu.parallel.sharding import (
+        _make_feature_sharded_step,
+        unit_batch_pspecs,
+    )
+
+    f_text = 1 << 20  # hash2e20: a 2^19 slice a chip
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+    def shape(dims, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    body = _make_feature_sharded_step(
+        f_text=f_text, f_text_local=f_text // 2, num_iterations=50,
+        step_size=0.005, mini_batch_fraction=1.0, l2_reg=0.1,
+        convergence_tol=0.001, residual_fn=None, prediction_fn=None,
+        round_predictions=True, data_axis="data", model_axis="model",
+        quality=True)
+    w_spec = {"text": P("model"), "num": P()}
+    step = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(w_spec, unit_batch_pspecs("data")),
+        out_specs=(w_spec, StepOutput(
+            predictions=P("data"), count=P(), mse=P(), real_stdev=P(),
+            pred_stdev=P(), quality=P())),
+    ), donate_argnums=0)
+    weights = {"text": shape((f_text,), jnp.float32, "model"),
+               "num": shape((4,), jnp.float32)}
+    batch = UnitBatch(
+        shape((ROWS, 512), jnp.uint8, "data", None),
+        shape((ROWS,), jnp.int32, "data"),
+        shape((ROWS, 4), jnp.float32, "data", None),
+        shape((ROWS,), jnp.float32, "data"),
+        shape((ROWS,), jnp.float32, "data"))
+    return step.lower(weights, batch).compile().as_text()
+
+
+# layout → (compile, width of the [F] weights a chip holds, rows × width
+# of the smallest count-matrix panel a contraction reads)
+_TPU_STEPS = {
+    "single": (_compile_single, F_TEXT, ROWS * F_TEXT),
+    "2x2": (_compile_2x2, 1 << 19, (ROWS // 2) << 19),
+}
+
+
+@pytest.fixture(scope="module")
+def tpu_branches(topo):
+    """layout → the three branches of the compiled step's plane switch."""
+    cache = {}
+
+    def of(layout: str) -> list:
+        if layout not in cache:
+            cache[layout] = plane_branches(_TPU_STEPS[layout][0](topo))
+        return cache[layout]
+
+    return of
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_CALLED = re.compile(
+    r"(?:to_apply|body|condition|calls|true_computation|false_computation)"
+    r"=%([\w.\-]+)|branch_computations=\{([^}]*)\}")
+# result type (a tuple's has spaces) and op of an instruction line
+_RESULT = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z\-]*)\(")
+_ARRAY = re.compile(r"\b(f32|bf16|s8|s32|u8|pred)\[([0-9,]*)\]")
+
+
+def plane_branches(hlo: str) -> list:
+    """The compiled module's plane switch (its one ``conditional`` of three
+    branches) as ``[{"top": [...], "all": [...]}, ...]``: per branch the
+    instruction lines it runs — ``top`` without the insides of fusions (a
+    value inside a fusion lives in registers; a fusion's RESULT is an array
+    in memory), ``all`` with them."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name and line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+
+    def walk(start: str, into_fusions: bool) -> list:
+        seen, order, lines = {start}, [start], []
+        while order:
+            for line in comps[order.pop()]:
+                lines.append(line)
+                op = _RESULT.match(line)
+                if op and op.group(2) == "fusion" and not into_fusions:
+                    continue
+                for one, many in _CALLED.findall(line):
+                    for callee in [one] if one else re.findall(
+                            r"%([\w.\-]+)", many):
+                        if callee in comps and callee not in seen:
+                            seen.add(callee)
+                            order.append(callee)
+        return lines
+
+    switches = [re.findall(r"%([\w.\-]+)", m) for line in hlo.splitlines()
+                for m in re.findall(r"branch_computations=\{([^}]*)\}", line)]
+    (branches,) = [b for b in switches if len(b) == 3]
+    return [{"top": walk(b, False), "all": walk(b, True)} for b in branches]
+
+
+def _results(lines) -> list:
+    """(op, dtype, element count) of every array an instruction yields."""
+    out = []
+    for line in lines:
+        m = _RESULT.match(line)
+        if m:
+            for dtype, dims in _ARRAY.findall(m.group(1)):
+                size = int(np.prod([int(d) for d in dims.split(",") if d]))
+                out.append((m.group(2), dtype, size))
+    return out
+
+
+@pytest.mark.parametrize("layout", sorted(_TPU_STEPS))
+@pytest.mark.parametrize("plane, branch", [
+    ("exact", 0), ("bf16", 1), ("s8", 2),
+])
+def test_compiled_gram_branch_reads_only_its_count_matrix(
+    tpu_branches, layout, plane, branch
+):
+    """Counted on the program the TPU's compiler made, per plane: no gather
+    whose operand is the ``[F]`` text weights, no scatter whose result is;
+    the plane's own count matrix is there in its own type; and on the bf16
+    and s8 planes no f32 array as large as the panel the contractions read
+    is ever in memory (an f32 copy of C would cost more than the gather it
+    replaced: 2 GiB written and read back)."""
+    _compile, width, panel = _TPU_STEPS[layout]
+    took = tpu_branches(layout)[branch]
+    for line in took["all"]:
+        m = _RESULT.match(line)
+        if m and m.group(2) == "gather":
+            assert f"f32[{width}]" not in line.split("gather(")[1], line
+        if m and m.group(2) == "scatter":
+            assert not m.group(1).startswith(f"f32[{width}]"), line
+    arrays = _results(took["top"])
+    own = {"exact": "f32", "bf16": "bf16", "s8": "s8"}[plane]
+    assert any(d == own and n >= panel for _op, d, n in arrays), plane
+    if plane != "exact":
+        wide = [(op, d, n) for op, d, n in arrays if d == "f32" and n >= panel]
+        assert not wide, wide

@@ -311,6 +311,49 @@ def test_keep_alive_lines_skipped(stream_server):
     assert all(s.text for s in got)
 
 
+# a body with every shape the reassembly must keep: CRLF and bare-LF lines,
+# blank keep-alives, a byte that is not UTF-8, a line longer than a chunk,
+# and a last line with no terminator
+RAW_BODY = (
+    b'{"a": 1}\r\n\r\n{"b": "\xc3\xa9"}\n{"c": "\xff"}\r\n\r\n\r\n'
+    + b'{"d": "' + b"x" * 300 + b'"}\r\n{"tail": true}'
+)
+
+
+def _reference_lines(body: bytes) -> list[str]:
+    parts = body.split(b"\n")
+    last = parts.pop()
+    out = [p.rstrip(b"\r").decode("utf-8", errors="replace") for p in parts]
+    if last.strip():
+        out.append(last.decode("utf-8", errors="replace"))
+    return out
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 7, 37, 65536])
+def test_open_stream_reassembles_lines_across_any_chunking(chunk):
+    """``open_stream`` yields the body's lines whatever the chunk framing:
+    a ``\\r\\n`` cut between two chunks, many lines in one chunk, one line
+    over many chunks, keep-alives kept, the unterminated tail last."""
+
+    class Raw(StreamHandler):
+        def do_GET(self):
+            self._start_stream()
+            self._send_raw(RAW_BODY, chunk=chunk)
+            self.wfile.write(b"0\r\n\r\n")
+            self.close_connection = True
+
+    server = ThreadingHTTPServer(("127.0.0.1", 0), Raw)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    try:
+        url = f"http://127.0.0.1:{server.server_address[1]}/raw"
+        assert list(open_stream(url, timeout=10.0)) == _reference_lines(
+            RAW_BODY
+        )
+    finally:
+        server.shutdown()
+        server.server_close()
+
+
 # ---------------------------------------------------------------------------
 # r5: multi-host live intake (id-residue sharding) + live block ingest
 

@@ -44,7 +44,6 @@ from ..features.batch import (
 from ..ops.gram import (
     add_numeric_block,
     dual_norm_sq,
-    dual_writeback,
     fits_gram,
     text_gram,
 )
@@ -67,8 +66,10 @@ MLLIB_SAMPLING_SEED = 42  # GradientDescent samples with seed 42+i
 # and not by HLO text that any edit renumbers (benchmark/stage_times.py
 # reads them; PERF.md §3). Metadata only: the compiled program is the same
 # with or without them. ``predict`` is the raw margin and the batch stats
-# with the pre-update weights (the ``u`` gather); ``gram_count`` the plane
-# gate and the count matrix, ``gram_matmul`` G itself, on whichever plane.
+# with the pre-update weights (in the Gram basis ``u = C·w``, inside the
+# plane's branch; the gather elsewhere); ``gram_count`` the plane gate and
+# the count matrix, ``gram_matmul`` G itself, ``writeback`` ``Cᵀα``, on
+# whichever plane.
 STAGE_SCOPES = (
     "unpack", "repad", "hash", "predict", "gram_count", "gram_matmul",
     "dual_loop", "writeback", "quality",
@@ -229,7 +230,7 @@ def dual_scale_and_alpha(dual, axis_name: str, rows: int):
     """This shard's slice of the dual state for a sharded write-back:
     (c, α_local). The psum-mean of c turns the identical-everywhere scale
     into a statically-invariant value (shard_map's replicated-output check),
-    and slicing α to local rows keeps the write-back scatter 1/shards."""
+    and slicing α to local rows keeps the write-back contraction 1/shards."""
     alpha_local = lax.dynamic_slice_in_dim(
         dual["alpha"], lax.axis_index(axis_name) * rows, rows
     )
@@ -286,12 +287,25 @@ def make_sgd_train_step(
     default (ops/gram.py): one MXU matmul builds G = Z·Zᵀ per batch and the
     loop never touches the 2^18 feature space (the per-iteration
     gather/scatter formulation it replaced is gone; no comparison exists on
-    this machine). With a data axis the
+    this machine). The whole basis runs inside the branch of the plane the
+    gate takes, on that plane's ONE count matrix C, built first: the
+    pre-update raw margin ``u = C·w_text + numeric·w_num`` (``raw``,
+    ``preds``, the batch stats and the quality vector read this ``u``), G,
+    the dual loop, and the write-back ``c·W_prev + [Cᵀα | numericᵀα]`` —
+    two streamed reads of C where a ``[B, L]`` gather from and a ``[B, L]``
+    scatter into the weights used to be (``sparse_predict`` /
+    ``sparse_grad_text`` remain the scatter loop's, serving's, and the
+    differential tests' references). With a data axis the
     batch is all-gathered once (G needs cross-shard row products), each
-    shard computes its row panel of G (matmul FLOPs scale 1/shards), one
-    all-gather replicates G, and the tiny dual loop runs replicated with NO
-    per-iteration collectives — versus one gradient psum per iteration (50/
-    batch) in the scatter loop. ``use_gram`` False forces the scatter loop
+    shard contracts its row panel of C — its rows of ``u`` (all-gathered),
+    its panel of G (matmul FLOPs scale 1/shards; one all-gather replicates
+    G), its share of ``Cᵀα`` (psum) — and the tiny dual loop runs
+    replicated with NO per-iteration collectives — versus one gradient psum
+    per iteration (50/batch) in the scatter loop. The collectives are the
+    ones the gather/scatter form ran (one all-gather each of the batch's
+    arrays, of ``u`` and of the G panels; one psum each of the two
+    write-back deltas and of ``c``; the plane pmin): none added, none
+    resized. ``use_gram`` False forces the scatter loop
     (the differential baseline); None picks Gram whenever it applies (f32
     weights, dense counts within HBM budget — ops/gram.py ``fits_gram``).
     ``gram_int8`` pins the G build's int8 plane on/off at trace time
@@ -333,83 +347,88 @@ def make_sgd_train_step(
             return jnp.concatenate([g_text, g_num])
         return x_dense.T @ residual
 
-    def _gram_sgd(weights, row_args, local_args):
-        """The sparse inner loop in the dual basis: build G (row panels
-        sharded under a data axis), drive the shared ``run_dual_loop``, and
-        write back — locally, or slice-local + psum under a data axis (which
-        both shrinks the scatter 1/shards and gives the replicated-weights
-        output the statically-invariant form shard_map requires).
+    def _gram_sgd(weights, row_args, local_numeric):
+        """The sparse step in the dual basis, all of it inside the branch
+        of the plane ``text_gram``'s gate takes, on that plane's ONE count
+        matrix C: ``u = C·w_text + numeric·w_num`` (the pre-update raw
+        margin), G (row panels sharded under a data axis), the shared
+        ``run_dual_loop``, and the write-back ``Cᵀα`` — locally, or this
+        shard's rows + psum under a data axis (which both shrinks the
+        contraction 1/shards and gives the replicated-weights output the
+        statically-invariant form shard_map requires).
 
         ``row_args`` are GLOBAL (the caller all-gathers the batch under a
-        data axis); ``local_args`` are this shard's rows."""
-        token_idx, token_val, numeric, u, mask, labels = row_args
+        data axis); ``local_numeric`` is this shard's rows. Returns
+        (new weights, this shard's rows of ``u``, plane index)."""
+        token_idx, token_val, numeric, mask, labels = row_args
         dtype = weights.dtype
-        # G is built in f32 (the MXU accumulation type); the dual loop runs
-        # in the weights dtype so the fori_loop carry stays type-stable for
-        # low-precision weights. f64 weights never reach here (the auto gate
-        # is f32-only — the bf16-plane G build would silently downgrade f64).
+        w_text, w_num = weights[:f_text], weights[f_text:]
+        rows = local_numeric.shape[0] if axis_name else 0
+
+        def dual_basis(counts):
+            with jax.named_scope("predict"):
+                # this shard's rows of u = Z·W_prev: one read of C
+                raw = (counts.dot(w_text) + local_numeric @ w_num).astype(dtype)
+                u = raw
+                if axis_name:
+                    u = lax.all_gather(raw, axis_name, axis=0, tiled=True)
+            g_text = counts.gram()
+            if axis_name:
+                # [B_local, B_global] panel: the G matmul's FLOPs scale
+                # 1/shards (the count build replicates per shard — see
+                # text_gram.left)
+                g_text = lax.all_gather(g_text, axis_name, axis=0, tiled=True)
+            with jax.named_scope("dual_loop"):
+                p_prev = jnp.sum(weights * weights)  # its convergence norm
+            # u and G are built in f32 (the accumulation type); the dual
+            # loop runs in the weights dtype so the fori_loop carry stays
+            # type-stable for low-precision weights. f64 weights never
+            # reach here (the auto gate is f32-only — the bf16-plane G
+            # build would silently downgrade f64).
+            dual = run_dual_loop(
+                u=u,
+                g=add_numeric_block(g_text, numeric, dtype),
+                labels=labels,
+                mask=mask,
+                dtype=dtype,
+                residual_fn=residual_fn,
+                num_iterations=num_iterations,
+                step_size=step_size,
+                mini_batch_fraction=mini_batch_fraction,
+                l2_reg=l2_reg,
+                convergence_tol=convergence_tol,
+                p_prev=p_prev,
+                vary_axis=axis_name,
+            )
+            with jax.named_scope("writeback"):
+                # W_new = c·W_prev + Zᵀα: the other read of C
+                c, alpha = dual["c"], dual["alpha"]
+                if axis_name:  # this shard's rows of α, then one psum each
+                    c, alpha = dual_scale_and_alpha(dual, axis_name, rows)
+                delta_text = counts.tdot(alpha)
+                delta_num = local_numeric.T @ alpha
+                if axis_name:
+                    delta_text = lax.psum(delta_text, axis_name)
+                    delta_num = lax.psum(delta_num, axis_name)
+                w_new = jnp.concatenate(
+                    [w_text * c + delta_text, w_num * c + delta_num]
+                ).astype(dtype)
+            return w_new, raw
+
+        (w_new, raw), plane = text_gram(
+            token_idx,
+            token_val,
+            f_text,
+            row_start=lax.axis_index(axis_name) * rows if axis_name else None,
+            rows=rows,
+            int8_plane=gram_int8,
+            body=dual_basis,
+        )
         if axis_name:
-            rows = u.shape[0] // lax.axis_size(axis_name)
-            panel, plane = text_gram(
-                token_idx,
-                token_val,
-                f_text,
-                row_start=lax.axis_index(axis_name) * rows,
-                rows=rows,
-                int8_plane=gram_int8,
-            )  # [B_local, B_global]: the G matmul's FLOPs scale 1/shards
-            # (the count build replicates per shard — see text_gram.left)
-            g_text = lax.all_gather(panel, axis_name, axis=0, tiled=True)
             # every shard gated the same global rows: pmin only makes the
             # index statically invariant, like ``c`` in dual_scale_and_alpha
             plane = lax.pmin(plane, axis_name)
-        else:
-            g_text, plane = text_gram(
-                token_idx, token_val, f_text, int8_plane=gram_int8
-            )
-        g = add_numeric_block(g_text, numeric, dtype)
-
-        dual = run_dual_loop(
-            u=u,
-            g=g,
-            labels=labels,
-            mask=mask,
-            dtype=dtype,
-            residual_fn=residual_fn,
-            num_iterations=num_iterations,
-            step_size=step_size,
-            mini_batch_fraction=mini_batch_fraction,
-            l2_reg=l2_reg,
-            convergence_tol=convergence_tol,
-            p_prev=jnp.sum(weights * weights),
-            vary_axis=axis_name,
-        )
-        if axis_name:
-            l_idx, l_val, l_num = local_args
-            with jax.named_scope("writeback"):
-                c, alpha_local = dual_scale_and_alpha(
-                    dual, axis_name, l_val.shape[0]
-                )
-                delta_text = lax.psum(
-                    sparse_grad_text(l_idx, l_val, alpha_local, f_text),
-                    axis_name,
-                )
-                w_text_new = weights[:f_text] * c + delta_text
-                w_num_new = weights[f_text:] * c + lax.psum(
-                    l_num.T @ alpha_local, axis_name
-                )
-        else:
-            w_text_new, w_num_new = dual_writeback(
-                weights[:f_text],
-                weights[f_text:],
-                dual["c"],
-                dual["alpha"],
-                token_idx,
-                token_val,
-                numeric,
-            )
-        with jax.named_scope("writeback"):
-            return jnp.concatenate([w_text_new, w_num_new]), plane
+        return w_new, raw, plane
 
     def train_step(weights, batch: FeatureBatch | UnitBatch | PackedBatch):
         dtype = weights.dtype
@@ -457,9 +476,34 @@ def make_sgd_train_step(
                 axis=1,
             )
 
+        b_global = batch.mask.shape[0] * (lax.axis_size(axis_name) if axis_name else 1)
+        gram = (
+            sparse
+            and dtype == jnp.float32  # see dtype note in _gram_sgd
+            and fits_gram(b_global, f_text, num_iterations)
+            if use_gram is None
+            else use_gram
+        )
+
         # ---- predict + stats with pre-update weights --------------------
+        if gram:
+            # the Gram basis: the count matrix is built FIRST and the raw
+            # margin u = Z·W_prev, G, the dual loop and the write-back all
+            # read it inside the plane's branch (_gram_sgd)
+            numeric = batch.numeric.astype(dtype)
+            row_args = (batch.token_idx, batch.token_val, numeric, mask, labels)
+            if axis_name:
+                # ONE all-gather of the batch (and one of u, in the
+                # branch); the loop runs replicated and collective-free
+                # (vs a gradient psum per iteration below)
+                row_args = tuple(
+                    lax.all_gather(a, axis_name, axis=0, tiled=True)
+                    for a in row_args
+                )
+            w_new, raw, plane = _gram_sgd(weights, row_args, numeric)
         with jax.named_scope("predict"):
-            raw = _predict_raw(weights, batch, x_dense)
+            if not gram:
+                raw = _predict_raw(weights, batch, x_dense)
             preds = prediction_fn(raw)
             if round_predictions:
                 preds = jnp_round_half_up(preds)
@@ -480,32 +524,12 @@ def make_sgd_train_step(
                     axis_name=axis_name,
                 )
 
-        # ---- numIterations of mini-batch SGD ----------------------------
-        b_global = batch.mask.shape[0] * (lax.axis_size(axis_name) if axis_name else 1)
-        gram = (
-            sparse
-            and dtype == jnp.float32  # see dtype note in _gram_sgd
-            and fits_gram(b_global, f_text, num_iterations)
-            if use_gram is None
-            else use_gram
-        )
         if gram:
-            numeric = batch.numeric.astype(dtype)
-            # ``raw`` above is u = Z·W_prev — the dual loop starts from it
-            local_args = (batch.token_idx, batch.token_val, numeric)
-            row_args = local_args + (raw, mask, labels)
-            if axis_name:
-                # ONE all-gather of the batch; the loop runs replicated and
-                # collective-free (vs a gradient psum per iteration below)
-                row_args = tuple(
-                    lax.all_gather(a, axis_name, axis=0, tiled=True)
-                    for a in row_args
-                )
-            w_new, plane = _gram_sgd(weights, row_args, local_args)
             return w_new, StepOutput(
                 predictions=preds, quality=_quality(w_new, plane), **stats
             )
 
+        # ---- numIterations of mini-batch SGD (the scatter loop) ---------
         def grad_and_count(w, sel):
             residual = residual_fn(_predict_raw(w, batch, x_dense), labels) * sel
             grad_sum = _grad_sum(batch, x_dense, residual)

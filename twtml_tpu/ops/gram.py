@@ -23,13 +23,29 @@ Then with ``u = Z·W_prev`` ([B], the batch's pre-update predictions) and
 so MLlib's exact update rule — √-decay step, SquaredL2Updater pre-scale,
 Bernoulli sampling, zero-sample skip, convergence freeze — runs unchanged
 through ``sgd_inner_loop`` on the tiny dual state {c, α}, and the 2^18
-feature space is touched exactly twice per batch: once building the dense
-count matrix for G (one scatter + ONE bf16×bf16→f32 matmul on the MXU) and
-once scattering ``Zᵀα`` back at write-back. The residual function enters
-only elementwise on ``Z·W``, so the same dual loop serves the logistic
-learner. Nothing here is approximate: it is the same recursion in a
-different basis (floating-point summation order differs; differential tests
-in tests/test_gram_sgd.py pin both paths together).
+feature space is touched only through ONE matrix, built once per batch:
+the dense count matrix C ``[B, F]`` of the text block (Z = [C | numeric]).
+The step contracts with it three times — ``u_text = C·w_text`` for the
+pre-update predictions, ``G_text = C·Cᵀ`` on the MXU, and
+``Δw_text = Cᵀ·α`` at write-back — each one streamed read of C, whatever
+the row length L; no ``[B, L]`` gather from the ``[F]`` weights and no
+``[B, L]`` scatter into them remains in the Gram basis (``ops/sparse.py``
+keeps both for the scatter loop, for serving, and as the references of the
+differential tests). The residual function enters only elementwise on
+``Z·W``, so the same dual loop serves the logistic learner. Nothing here is
+approximate: it is the same recursion in a different basis (floating-point
+summation order differs; differential tests in tests/test_gram_sgd.py pin
+both paths together).
+
+The two vector contractions are multiply-and-reduce fusions in f32:
+``Σ_f f32(C[b, f])·w[f]`` and ``Σ_b f32(C[b, f])·α[b]``, with C converted
+element by element in registers (never as an f32 copy of a bf16 / s8
+matrix) and ``w``, ``α`` left in f32. C's entries are the exact counts on
+every plane (the gate's proof below), so ``C[b, f]·w[f]`` is the product
+the gather formed after summing a row's duplicates; only the order of an
+f32 sum differs. A default-precision ``dot`` would round ``w`` or ``α`` to
+bf16 on the TPU and is not used (PERF.md §5 has the measurement against
+the three-term MXU form).
 
 Even the G build avoids scatters. XLA serializes a [B·L]-update scatter
 into [B, 2^18] update by update (its cost on this machine: not measured,
@@ -202,14 +218,28 @@ def text_gram(
     rows: int = 0,
     int8_plane: bool | None = None,
     feature_axis: str | None = None,
+    *,
+    body,
 ):
-    """Text-feature Gram block and the plane it was built on: ``(G, plane)``
-    with G = X·Xᵀ ([B,B] f32), or the row slice
+    """What ``body`` makes of the batch's count matrix, and the plane it was
+    built on: ``(body's result, plane)``. With ``body=CountPlane.gram`` that
+    is the text-feature Gram block G = X·Xᵀ ([B,B] f32), or the row slice
     ``X[row_start:row_start+rows]·Xᵀ`` ([rows, B]) when ``rows`` > 0 — the
     building block sharded layouts use (each shard computes its row panel
     and/or its feature slice's partial G, then all-gathers/psums) — and
     ``plane`` the int32 index the switch took: 2 s8, 1 bf16, 0 exact (the
     gate is computed here, once per step; ops/quality.py carries it out).
+
+    ``body(counts)`` is all the switch's branch does with the count matrix
+    it built: it is called INSIDE the branch of the plane taken with that
+    plane's ``CountPlane``, and its result (any pytree whose types do not
+    depend on the plane) is what comes out. The train steps pass the whole
+    Gram basis — ``counts.dot`` for ``u``, ``counts.gram()``, the dual
+    loop, ``counts.tdot`` for the write-back — so that every contraction reads the ONE C of the batch, live from its
+    build to the write-back, and nothing typed by the plane has to leave
+    the switch. Under a mesh the body's collectives run inside the branch:
+    every shard enters the same one, because the index is reduced over
+    every axis it could differ on before the switch.
 
     The gate ladder and its proof are the module docstring's. Every rung
     reads the [B, L] token pairs, never the [B, F] counts: row absolute
@@ -307,8 +337,9 @@ def text_gram(
         vals_ok = rung1 | rung2
 
     def left(c):
-        """The (possibly row-sliced) left operand. The slice makes the G
-        MATMUL's FLOPs scale 1/shards in sharded builds; the count build
+        """The (possibly row-sliced) left operand: this shard's rows of C.
+        The slice makes the G MATMUL's FLOPs — and the bytes ``dot`` and
+        ``tdot`` stream — scale 1/shards in sharded builds; the count build
         itself is deliberately replicated per shard — the right operand
         needs all B_global rows anyway, and all-gathering shard-local
         count builds would move [B_global, F_local] bf16 (~0.5 GB at the
@@ -317,34 +348,75 @@ def text_gram(
             return lax.dynamic_slice_in_dim(c, row_start, rows, axis=0)
         return c
 
-    def fast_i8(i, v):
-        with jax.named_scope("gram_count"):
-            c = onehot_counts_int8(i, v, f_text)  # [B, F] int8, exact
-        with jax.named_scope("gram_matmul"):
-            g = jnp.matmul(left(c), c.T, preferred_element_type=jnp.int32)
-            # |G| ≤ (Σ|c_a|)·max|c_b| ≤ 127² < 2²⁴: the f32 cast is exact
-            return g.astype(jnp.float32)
+    def product_i8(a, c):
+        g = jnp.matmul(a, c.T, preferred_element_type=jnp.int32)
+        # |G| ≤ (Σ|c_a|)·max|c_b| ≤ 127² < 2²⁴: the f32 cast is exact
+        return g.astype(jnp.float32)
 
-    def fast(i, v):
-        with jax.named_scope("gram_count"):
-            c = onehot_counts(i, v, f_text)  # [B, F] bf16, exact
-        with jax.named_scope("gram_matmul"):
-            return jnp.matmul(
-                left(c), c.T, preferred_element_type=jnp.float32
-            )
+    def product_bf16(a, c):
+        return jnp.matmul(a, c.T, preferred_element_type=jnp.float32)
 
-    def exact(i, v):
-        with jax.named_scope("gram_count"):
-            c = densify_text(i, v, f_text)  # [B, F] f32
-        with jax.named_scope("gram_matmul"):
-            return jnp.matmul(left(c), c.T, precision=lax.Precision.HIGHEST)
+    def product_exact(a, c):
+        return jnp.matmul(a, c.T, precision=lax.Precision.HIGHEST)
+
+    def branch(build, product):
+        def run(i, v):
+            with jax.named_scope("gram_count"):
+                c = build(i, v, f_text)  # [B, F], exact in the plane's type
+            return body(CountPlane(c, left, product))
+
+        return run
 
     idx = vals_ok.astype(jnp.int32)
-    branches = [exact, fast]
+    branches = [
+        branch(densify_text, product_exact),  # f32 scatter densify
+        branch(onehot_counts, product_bf16),
+    ]
     if int8_plane:
         idx = idx + vals_ok_i8.astype(jnp.int32)  # i8-ok ⊆ bf16-ok: 0/1/2
-        branches.append(fast_i8)
+        branches.append(branch(onehot_counts_int8, product_i8))
     return lax.switch(idx, branches, token_idx, val_f), idx
+
+
+class CountPlane:
+    """One plane's dense count matrix inside its branch of ``text_gram``'s
+    switch, as the three contractions the Gram basis runs with it. C is
+    f32, bf16 or s8 by the plane; ``left(c)`` is this shard's row panel of
+    it (``text_gram.left``: all of C on one device), sliced inside each
+    contraction so the slice fuses into its reader; every result is f32.
+
+    ``dot`` and ``tdot`` are multiply-and-reduce fusions with f32 operands
+    (module docstring): C's element is converted in registers, ``w`` and
+    ``alpha`` are never rounded, the accumulation is f32. Neither carries a
+    stage name: the caller scopes ``dot`` under ``predict`` and ``tdot``
+    under ``writeback`` (models/sgd.py ``STAGE_SCOPES``)."""
+
+    def __init__(self, c, left, product):
+        self.c = c  # [B, F]: every row, the G product's right operand
+        self._left = left
+        self._product = product
+
+    def _rows_f32(self):
+        return self._left(self.c).astype(jnp.float32)
+
+    def dot(self, w):
+        """``rows(C)·w`` → ``[rows]``: the text half of ``u = Z·W_prev``
+        for this shard's rows (a partial over its features under a
+        feature axis; the caller psums)."""
+        return jnp.sum(self._rows_f32() * w[None, :], axis=1)
+
+    def tdot(self, alpha):
+        """``rows(C)ᵀ·alpha`` → ``[F]``: the text half of ``Zᵀα`` from this
+        shard's rows (the caller psums over the row shards). Duplicate
+        (row, feature) occurrences are already summed in C, as the
+        ``sparse_grad_text`` scatter summed them."""
+        return jnp.sum(self._rows_f32() * alpha[:, None], axis=0)
+
+    def gram(self):
+        """``rows(C)·Cᵀ`` → ``[rows, B]`` f32 on the MXU, exact on every
+        plane (module docstring)."""
+        with jax.named_scope("gram_matmul"):
+            return self._product(self._left(self.c), self.c)
 
 
 @jax.named_scope("gram_matmul")
@@ -366,7 +438,10 @@ def gram_matrix(
 ):
     """G = Z·Zᵀ ([B,B] ``dtype``) for Z = [text counts | numeric features]."""
     return add_numeric_block(
-        text_gram(token_idx, token_val, f_text, int8_plane=int8_plane)[0],
+        text_gram(
+            token_idx, token_val, f_text, int8_plane=int8_plane,
+            body=CountPlane.gram,
+        )[0],
         numeric,
         dtype,
     )
@@ -383,15 +458,3 @@ def dual_norm_sq(p_prev, u, g):
         return dc * dc * p_prev + 2.0 * dc * jnp.dot(u, da) + jnp.dot(da, g @ da)
 
     return norm_sq
-
-
-@jax.named_scope("writeback")
-def dual_writeback(w_text, w_num, c, alpha, token_idx, token_val, numeric):
-    """W_new = c·W_prev + Zᵀ·α — the one feature-space scatter of the batch.
-
-    Contributions for duplicate (row, feature) occurrences sum, exactly as
-    the per-iteration ``sparse_grad_text`` scatter summed them."""
-    contrib = token_val * alpha[:, None]  # [B, L]
-    w_text_new = (w_text * c).at[token_idx.reshape(-1)].add(contrib.reshape(-1))  # lawcheck: disable=TW004 -- the ONE budgeted scatter per batch the Gram design ships (50 per-iteration scatters folded into a single writeback; its cost is stage_ms.writeback, PERF.md section 5)
-    w_num_new = w_num * c + numeric.T @ alpha
-    return w_text_new, w_num_new

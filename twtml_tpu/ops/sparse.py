@@ -11,7 +11,10 @@ are two regimes:
 - **sparse path** (numTextFeatures = 2^18, BASELINE config #4): the dense
   matrix would be ~1GB of mostly zeros; instead predictions gather weight
   entries (w[token_idx]·token_val) and gradients scatter-add residuals with
-  one ``segment_sum`` per iteration.
+  one ``segment_sum`` per iteration. That is the scatter loop
+  (``use_gram=False``) and the predict-only serving program; the Gram basis
+  (ops/gram.py, the default at 2^18) contracts with its count matrix
+  instead and keeps these kernels as its differential references.
 
 Token pairs arrive either host-hashed (features/hashing.py, native/) or are
 computed in-program from raw code units (ops/text_hash.py — the default

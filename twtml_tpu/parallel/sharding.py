@@ -18,8 +18,10 @@ Two layouts:
   ``ops/gram.fits_gram``'s gate (2^20 dims at batches of 2048: BASELINE
   config #4's learner at spark.mllib HashingTF's default width, on config
   #5's four chips), so that each shard's SLICE passes the gate and the step
-  stays in the Gram basis. Each shard gathers/scatter-adds only tokens
-  hashing into its slice, with a ``psum`` over ``model`` reassembling
+  stays in the Gram basis. Each shard counts only the tokens hashing into
+  its slice (its slice's count matrix C serves the predict partial, the
+  partial G panel and the write-back alike; the scatter loop gathers /
+  scatter-adds the same tokens), with a ``psum`` over ``model`` reassembling
   predictions and the partial G panels — a sharded-embedding pattern, not a
   translation of any reference code (the reference caps at 1000 dims in one
   JVM). The body carries the single-device step's stage names (models/sgd.py
@@ -194,7 +196,18 @@ def _make_feature_sharded_step(
     psum over ``model`` plus one gradient psum over ``data`` per iteration
     (2·numIterations collectives/batch) in the scatter formulation. The
     write-back stays slice-local (this shard's rows × its feature slice)
-    with one psum over ``data``.
+    with one psum over ``data``. Everything that reads the slice's count
+    matrix C runs inside the branch of the plane ``text_gram``'s gate takes
+    (PR 28): the predict partial ``rows(C)·w_slice`` → ``[B_local]`` in
+    place of the ``sparse_text_dot`` gather, the G panel, the dual loop,
+    and the write-back delta ``rows(C)ᵀ·α_local`` → ``[f_text_local]`` in
+    place of the ``sparse_grad_text`` scatter, ``rows(C)`` being the row
+    panel the G product already slices. The collective inventory below is
+    UNCHANGED by that: the same psums and all-gathers, of the same sizes,
+    under the same scopes — those from the predict psum to the write-back
+    psum now sit inside the branch, which every shard enters together
+    (the plane index is reduced over ``model`` by the gate and computed
+    from all-gathered rows on every ``data`` shard).
 
     What is reduced over which axis, once a batch (Gram basis):
 
@@ -259,22 +272,111 @@ def _make_feature_sharded_step(
             inside = ((rel >= 0) & (rel < f_text_local)).astype(dtype)
             return jnp.clip(rel, 0, f_text_local - 1), val * inside
 
+        def norm_sq_of(text, num):
+            # text slices live on the model axis; num is replicated there
+            return _psum(jnp.sum(text * text), model_axis) + jnp.sum(num * num)
+
+        # ---- Gram (dual) basis when it applies (see docstring) ----------
+        b_local = mask.shape[0]
+        b_global = b_local * lax.axis_size(data_axis)
+        gram = (
+            dtype == jnp.float32
+            and fits_gram(b_global, f_text_local, num_iterations)
+            if use_gram is None
+            else use_gram
+        )
+        if gram:
+            with jax.named_scope("hash"):
+                idx_g = _all_gather(g_idx, data_axis)
+                val_g = _all_gather(token_val, data_axis)
+            with jax.named_scope("predict"):
+                num_g, lab_g, mask_g = (
+                    _all_gather(a, data_axis) for a in (numeric, labels, mask)
+                )
+            with jax.named_scope("gram_count"):
+                rel_g, local_val_g = to_slice(idx_g, val_g)
+
+            def dual_basis(counts):
+                """Everything that reads the slice's count matrix, inside
+                the branch of the plane taken (ops/gram.text_gram): this
+                shard's rows of u, its partial G panel, the dual loop and
+                the slice-local write-back."""
+                with jax.named_scope("predict"):
+                    part = counts.dot(w_text)  # [B_local], over this slice
+                    raw = (_psum(part, model_axis) + numeric @ w_num).astype(
+                        dtype
+                    )
+                    u = _all_gather(raw, data_axis)
+                # [B_local, B_global] partial over this feature slice
+                panel = counts.gram()
+                with jax.named_scope("gram_matmul"):
+                    g_mat = _all_gather(_psum(panel, model_axis), data_axis)
+                g_mat = add_numeric_block(g_mat, num_g, dtype)
+                with jax.named_scope("dual_loop"):
+                    p_prev = norm_sq_of(w_text, w_num)  # its convergence norm
+                dual = run_dual_loop(
+                    u=u,
+                    g=g_mat,
+                    labels=lab_g,
+                    mask=mask_g,
+                    dtype=dtype,
+                    residual_fn=residual_fn,
+                    num_iterations=num_iterations,
+                    step_size=step_size,
+                    mini_batch_fraction=mini_batch_fraction,
+                    l2_reg=l2_reg,
+                    convergence_tol=convergence_tol,
+                    p_prev=p_prev,
+                    vary_axis=data_axis,
+                )
+                with jax.named_scope("writeback"):
+                    # psum-mean of the (identical-everywhere) scale + psum
+                    # of the slice-local write-back (this shard's rows of
+                    # C, transposed, times its rows of α): statically
+                    # invariant over ``data``
+                    c, alpha_local = dual_scale_and_alpha(
+                        dual, data_axis, b_local
+                    )
+                    w_final = {
+                        "text": (
+                            w_text * c
+                            + _psum(counts.tdot(alpha_local), data_axis)
+                        ).astype(dtype),
+                        "num": w_num * c
+                        + _psum(numeric.T @ alpha_local, data_axis),
+                    }
+                return w_final, raw
+
+            (w_final, raw), plane = text_gram(
+                rel_g,
+                local_val_g,
+                f_text_local,
+                row_start=lax.axis_index(data_axis) * b_local,
+                rows=b_local,
+                int8_plane=gram_int8,
+                feature_axis=model_axis,
+                body=dual_basis,
+            )
+            # every data shard gated the same gathered rows: the pmin only
+            # makes the index statically invariant (models/sgd.py)
+            with jax.named_scope("gram_matmul"), jax.named_scope("collective"):
+                plane = lax.pmin(plane, data_axis)
+        else:
+            with jax.named_scope("predict"):
+                rel, local_val = to_slice(g_idx, token_val)
+
         def predict(w):
             part = sparse_text_dot(w["text"], rel, local_val)
             return _psum(part, model_axis) + numeric @ w["num"]
 
         # ---- predict + stats with pre-update weights --------------------
         with jax.named_scope("predict"):
-            rel, local_val = to_slice(g_idx, token_val)
-            raw = predict(weights)
+            if not gram:
+                raw = predict(weights)
             preds = prediction_fn(raw)
             if round_predictions:
                 preds = jnp_round_half_up(preds)
             stats = batch_stats(labels, preds, mask, data_axis)
-
-        def norm_sq_of(text, num):
-            # text slices live on the model axis; num is replicated there
-            return _psum(jnp.sum(text * text), model_axis) + jnp.sum(num * num)
 
         def _quality(w_new, gram_plane=None):
             # the ISSUE-8 side channel (models/sgd.py ``_quality``): rows
@@ -297,75 +399,7 @@ def _make_feature_sharded_step(
                     update_sq=norm_sq_of(new_t - old_t, new_n - old_n),
                 )
 
-        # ---- Gram (dual) basis when it applies (see docstring) ----------
-        b_local = mask.shape[0]
-        b_global = b_local * lax.axis_size(data_axis)
-        gram = (
-            dtype == jnp.float32
-            and fits_gram(b_global, f_text_local, num_iterations)
-            if use_gram is None
-            else use_gram
-        )
         if gram:
-            with jax.named_scope("hash"):
-                idx_g = _all_gather(g_idx, data_axis)
-                val_g = _all_gather(token_val, data_axis)
-            with jax.named_scope("predict"):
-                num_g, lab_g, mask_g, u = (
-                    _all_gather(a, data_axis)
-                    for a in (numeric, labels, mask, raw)
-                )
-            with jax.named_scope("gram_count"):
-                rel_g, local_val_g = to_slice(idx_g, val_g)
-            panel, plane = text_gram(
-                rel_g,
-                local_val_g,
-                f_text_local,
-                row_start=lax.axis_index(data_axis) * b_local,
-                rows=b_local,
-                int8_plane=gram_int8,
-                feature_axis=model_axis,
-            )  # [B_local, B_global] partial over this feature slice
-            with jax.named_scope("gram_matmul"):
-                g_mat = _all_gather(_psum(panel, model_axis), data_axis)
-                # every data shard gated the same gathered rows: the pmin
-                # only makes the index statically invariant (models/sgd.py)
-                with jax.named_scope("collective"):
-                    plane = lax.pmin(plane, data_axis)
-            g_mat = add_numeric_block(g_mat, num_g, dtype)
-            with jax.named_scope("dual_loop"):
-                p_prev = norm_sq_of(w_text, w_num)  # its convergence norm
-
-            dual = run_dual_loop(
-                u=u,
-                g=g_mat,
-                labels=lab_g,
-                mask=mask_g,
-                dtype=dtype,
-                residual_fn=residual_fn,
-                num_iterations=num_iterations,
-                step_size=step_size,
-                mini_batch_fraction=mini_batch_fraction,
-                l2_reg=l2_reg,
-                convergence_tol=convergence_tol,
-                p_prev=p_prev,
-                vary_axis=data_axis,
-            )
-            with jax.named_scope("writeback"):
-                # psum-mean of the (identical-everywhere) scale + psum of
-                # the slice-local write-back: statically invariant over
-                # ``data``
-                c, alpha_local = dual_scale_and_alpha(dual, data_axis, b_local)
-                w_final = {
-                    "text": w_text * c + _psum(
-                        sparse_grad_text(
-                            rel, local_val, alpha_local, f_text_local
-                        ),
-                        data_axis,
-                    ),
-                    "num": w_num * c
-                    + _psum(numeric.T @ alpha_local, data_axis),
-                }
             return w_final, StepOutput(
                 predictions=preds, quality=_quality(w_final, plane), **stats
             )

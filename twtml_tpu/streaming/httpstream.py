@@ -210,17 +210,17 @@ def open_stream(
         if status != 200:
             raise StreamHTTPError(status, reason)
 
-        # reassemble text lines across chunk boundaries
+        # reassemble text lines across chunk boundaries: one split per
+        # chunk (slicing the rest off after every line copies a 64 KiB
+        # chunk once per line it holds)
         pending = b""
         for chunk in _body_chunks(sock, buf, resp_headers):
-            pending += chunk
-            while True:
-                nl = pending.find(b"\n")
-                if nl < 0:
-                    break
-                line_bytes = pending[:nl].rstrip(b"\r")
-                pending = pending[nl + 1 :]
-                yield line_bytes.decode("utf-8", errors="replace")
+            lines = (pending + chunk).split(b"\n")
+            pending = lines.pop()
+            for line_bytes in lines:
+                yield line_bytes.rstrip(b"\r").decode(
+                    "utf-8", errors="replace"
+                )
         if pending.strip():
             yield pending.decode("utf-8", errors="replace")
     finally:
