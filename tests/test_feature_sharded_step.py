@@ -361,6 +361,35 @@ def test_sharded_step_module_name_is_the_same_for_every_bucket_and_wire(
         r"^module @(\S+)", text, re.M).group(1) == "jit_sharded_train_step"
 
 
+@pytest.mark.parametrize("layout", [(4, 1), (2, 2)])
+def test_mesh_model_compiles_one_train_program(layout):
+    """``jit(sharded_train_step)`` is the ONLY train program a mesh model
+    ever compiles, on the 1-D layout and the model-sharded ones alike —
+    once per (wire form, bucket), and nothing else that steps or scans the
+    weights: the model has one step surface."""
+    from test_step_scopes import WIRES, compiled_funs
+
+    # both buckets of the wire the cells ship, one of each other form
+    wires = [w for w in WIRES if w[0] == "packed" or w[1] == 8]
+
+    model = ParallelSGDModel(
+        _mesh(*layout), num_text_features=1 << 12, num_iterations=2)
+
+    def run():
+        for form, rows, row_len in wires:
+            for _ in range(2):  # the repeat is served by jit's cache
+                model.step(_wire(form, model, rows, row_len))
+
+    funs = compiled_funs(run)
+    train = [f for f in funs if "step" in f or "scan" in f]
+    assert set(train) == {"jit(sharded_train_step)"}, funs
+    # one compile per (form, bucket) — and one more for the model's second
+    # step ever: its freshly built weights carry no mesh sharding yet, the
+    # first step's output does (PERF.md §7)
+    assert len(wires) <= len(train) <= len(wires) + 1, funs
+    assert [n for n in dir(model) if "many" in n or "scan" in n] == []
+
+
 @pytest.fixture(scope="module")
 def op_names():
     """The op-name paths of the COMPILED module (each HLO instruction's

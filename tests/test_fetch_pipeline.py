@@ -4,9 +4,11 @@ small pool, so concurrent fetches overlap their latencies. Semantics must stay t
 stats in order, at_boundary only with current weights (drains), exact
 max-batches caps, tail drained by flush()."""
 
+import functools
 import json
 
 import numpy as np
+import pytest
 
 from twtml_tpu.apps.common import FetchPipeline
 from twtml_tpu.config import ConfArguments
@@ -208,3 +210,151 @@ def test_deterministic_mode_emits_only_at_deterministic_points():
     assert events == [0]
     pipe.flush()
     assert events == [0, 1, 2, 3, 4]
+
+
+# -- pipelined == sequential, for every model kind and wire form -------------
+# The engine every cell runs: FetchPipeline at its production depth must
+# deliver, in order, the same StepOutputs and leave the same weights, bit for
+# bit, as a plain ``model.step`` loop with one synchronous fetch per batch.
+
+_N_BATCHES, _ROWS, _NOW = 12, 32, 1785320000000
+
+
+def _statuses(seed=3):
+    return list(SyntheticSource(
+        total=_N_BATCHES * _ROWS, seed=seed, base_ms=_NOW
+    ).produce())
+
+
+def _batches(how, f_text=None, sentiment=False):
+    from twtml_tpu.features.featurizer import Featurizer
+
+    feat = Featurizer(now_ms=_NOW, **(
+        {"num_text_features": f_text} if f_text else {}
+    ))
+    if sentiment:
+        from twtml_tpu.features.sentiment import (
+            sentiment_label, sentiment_labels,
+        )
+
+        feat.label_fn = sentiment_label
+        feat.batch_label_fn = sentiment_labels
+    statuses = _statuses()
+    kwargs = dict(row_bucket=_ROWS, pre_filtered=True)
+    if how == "featurize_batch":
+        kwargs["token_bucket"] = 64
+    if how == "featurize_batch_ragged":
+        kwargs["unit_bucket"] = 8192  # one wire signature for the stream
+    return [
+        getattr(feat, how)(statuses[i * _ROWS : (i + 1) * _ROWS], **kwargs)
+        for i in range(_N_BATCHES)
+    ]
+
+
+def _mesh(**axes):
+    import jax
+
+    from twtml_tpu.parallel import make_mesh
+
+    return make_mesh(devices=jax.devices()[:4], **axes)
+
+
+def _kind(name):
+    """(make_model, batches) of one model kind."""
+    from twtml_tpu.models import (
+        StreamingLinearRegressionWithSGD as Linear,
+        StreamingLogisticRegressionWithSGD as Logistic,
+    )
+    from twtml_tpu.parallel import ParallelSGDModel, TenantStackModel
+
+    if name == "dense":
+        return (lambda: Linear(num_iterations=5)), _batches("featurize_batch")
+    if name == "gram":
+        return (
+            lambda: Linear(
+                num_text_features=2**14, num_iterations=5, l2_reg=0.1
+            ),
+            _batches("featurize_batch_units", f_text=2**14),
+        )
+    if name == "logistic":
+        return (
+            lambda: Logistic(num_iterations=5),
+            _batches("featurize_batch_units", sentiment=True),
+        )
+    if name == "ragged":
+        return (
+            lambda: Linear(num_iterations=5),
+            _batches("featurize_batch_ragged"),
+        )
+    if name == "mesh1d":
+        return (
+            lambda: ParallelSGDModel(
+                _mesh(num_data=4), num_iterations=5, step_size=0.05
+            ),
+            _batches("featurize_batch_ragged"),
+        )
+    if name == "mesh2d":
+        return (
+            lambda: ParallelSGDModel(
+                _mesh(num_data=2, num_model=2), num_iterations=5,
+                step_size=0.05,
+            ),
+            _batches("featurize_batch_ragged"),
+        )
+    assert name == "tenants2"
+    return (
+        lambda: TenantStackModel(2, num_iterations=5, wire_pack="group"),
+        _batches("featurize_batch_ragged"),
+    )
+
+
+def _leaves(out):
+    import jax
+
+    return [np.asarray(a) for a in jax.tree_util.tree_leaves(out)]
+
+
+@functools.lru_cache(maxsize=None)
+def _sequential(kind):
+    """The plain loop both wire forms of a kind are held to: one
+    ``model.step`` and one synchronous fetch per batch."""
+    import jax
+
+    make_model, batches = _kind(kind)
+    seq = make_model()
+    want = [_leaves(jax.device_get(seq.step(b))) for b in batches]
+    return (
+        make_model, batches, want,
+        np.asarray(seq.latest_weights).tobytes(),
+    )
+
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
+@pytest.mark.parametrize("kind", [
+    "dense", "gram", "logistic", "ragged", "mesh1d", "mesh2d", "tenants2",
+])
+def test_pipelined_equals_sequential(kind, packed):
+    make_model, batches, want, want_weights = _sequential(kind)
+    model, got = make_model(), []
+    pipe = FetchPipeline(
+        model,
+        lambda out, b, t, at_boundary: got.append(
+            (_leaves(out), b, t, at_boundary)
+        ),
+        depth=8, pack=packed,
+    )
+    for i, b in enumerate(batches):
+        pipe.on_batch(b, float(i))
+    pipe.flush()
+
+    # every batch, once, in dispatch order, with the UNPACKED batch it was
+    # dispatched for; the last delivery sees current weights
+    assert [t for _, _, t, _ in got] == [float(i) for i in range(len(batches))]
+    assert all(b is sent for (_, b, _, _), sent in zip(got, batches))
+    assert got[-1][3] is True
+    for i, ((leaves, _, _, _), ref) in enumerate(zip(got, want)):
+        assert len(leaves) == len(ref)
+        for a, r in zip(leaves, ref):
+            assert a.dtype == r.dtype and a.tobytes() == r.tobytes(), (kind, i)
+    assert np.asarray(model.latest_weights).tobytes() == want_weights

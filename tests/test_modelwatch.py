@@ -186,19 +186,28 @@ def test_off_program_is_structurally_head_and_observation_only():
     assert len(leaves_on) == len(leaves_off) + 1
 
 
-def test_quality_rides_the_superbatch_scan():
+def test_quality_rides_the_fetch_pipeline():
+    """Under the pipeline every delivered batch carries ITS quality
+    vector — bit-equal to a sequential step's, in dispatch order, on the
+    packed wire the apps ship."""
+    from twtml_tpu.apps.common import FetchPipeline
+
     model = StreamingLinearRegressionWithSGD(quality=True)
     seq = StreamingLinearRegressionWithSGD(quality=True)
-    from twtml_tpu.features.batch import stack_batches
-
-    batches = _ragged_batches()
-    outs = model.step_many(stack_batches(batches))
-    q = np.asarray(outs.quality)
-    assert q.shape == (len(batches), QUALITY_WIDTH)
-    # the scanned program's per-batch quality bit-equals sequential steps
+    batches = _ragged_batches() * 3  # deeper than one window's worth
+    got = []
+    pipe = FetchPipeline(
+        model, lambda out, b, t, at_boundary: got.append(out.quality),
+        depth=8, pack=True,
+    )
+    for i, rb in enumerate(batches):
+        pipe.on_batch(rb, float(i))
+    pipe.flush()
+    assert len(got) == len(batches)
     for k, rb in enumerate(batches):
-        ok = seq.step(rb)
-        assert np.asarray(ok.quality).tobytes() == q[k].tobytes(), k
+        q = np.asarray(got[k])
+        assert q.shape == (QUALITY_WIDTH,)
+        assert np.asarray(seq.step(rb).quality).tobytes() == q.tobytes(), k
 
 
 def test_mesh_quality_is_global_and_finite():

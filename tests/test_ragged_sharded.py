@@ -263,8 +263,3 @@ def test_degenerate_shards_train_identically_on_mesh(n_real):
         np.asarray(out_pk.predictions), np.asarray(out_ref.predictions)
     )
     np.testing.assert_array_equal(m.latest_weights, ref.latest_weights)
-
-    g = ParallelSGDModel(mesh, num_iterations=5, step_size=0.05)
-    many = g.step_many(g.pack_group_for_wire([rb]))
-    assert float(many.count[0]) == n_real
-    np.testing.assert_array_equal(g.latest_weights, ref.latest_weights)

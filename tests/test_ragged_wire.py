@@ -314,3 +314,20 @@ def test_ragged_fuzz_random_unicode(seed):
             )
         statuses.append(rt(text, label=int(rng.integers(100, 1001))))
     assert_identical_training(statuses, rows=16)
+
+
+def test_ragged_stack_rejects_mixed_alignment():
+    """The stacked tenant wire (``stack_batches``) refuses ragged parts
+    whose shard alignment differs — a stacked batch cannot be re-aligned."""
+    from twtml_tpu.features.batch import align_ragged_shards, stack_batches
+
+    statuses = synthetic(64)
+    feat = Featurizer(now_ms=1785320000000)
+    a, b = (
+        feat.featurize_batch_ragged(
+            statuses[i : i + 32], row_bucket=32, pre_filtered=True
+        )
+        for i in (0, 32)
+    )
+    with pytest.raises(ValueError, match="different row_len or shard"):
+        stack_batches([a, align_ragged_shards(b, 2)])

@@ -18,7 +18,6 @@ from twtml_tpu.apps.common import (
     FetchAbort,
     FetchPipeline,
     FetchWatchdog,
-    SuperBatcher,
 )
 from twtml_tpu.config import ConfArguments
 from twtml_tpu.telemetry import metrics as _metrics
@@ -132,22 +131,6 @@ def test_fetch_abort_after_bounded_retries():
     assert len(model.dispatched) == dispatched
     pipe.flush()
     assert events == []
-
-
-def test_superbatcher_partial_path_abort():
-    model = FlakyFetchModel(slow={0: {n: 0.5 for n in range(1, 10)}})
-    aborted = []
-    sb = SuperBatcher(
-        model, 4, lambda out, b, t, at_boundary: None,
-        abort=lambda: aborted.append(True),
-        fetch_deadline_s=0.05, fetch_retries=1,
-    )
-    sb.on_batch(np.asarray(0), 0.0)  # one batch < k: a partial group
-    with pytest.raises(FetchAbort):
-        sb._close_group()  # the partial path's pooled fetch stalls
-    assert sb._watchdog.aborted and aborted == [True]
-    # flush after the abort is a clean no-op (pool shut down, nothing leaks)
-    sb.flush()
 
 
 def test_flush_shuts_pool_down_even_when_handler_raises():
@@ -370,25 +353,91 @@ def test_web_timeout_flag_threads_through():
 
 
 # -- abort refunds (ISSUE 3 satellite): every dispatched batch is either
-# delivered to the handler or refunded — partial singles and coalesced/
-# grouped dispatches alike, so cap accounting stays honest across aborts --
+# delivered to the handler or refunded, and its arena lease is released
+# (retired on delivery, discarded — never pooled — on an abort), so cap
+# accounting and the wire arena stay honest across aborts --
 
 
-def test_superbatcher_partial_abort_refunds_dispatch():
-    """The partial path's batch trains before its synchronous fetch; when
-    that fetch aborts, the dispatch slot is refunded (trained-but-
-    undelivered must not consume max_dispatch budget)."""
-    model = FlakyFetchModel(slow={0: {n: 0.5 for n in range(1, 10)}})
-    sb = SuperBatcher(
-        model, 4, lambda out, b, t, at_boundary: None,
-        fetch_deadline_s=0.05, fetch_retries=1, max_dispatch=8,
+class _LeasedWire:
+    """A batch that carries an arena lease, as a packed wire does."""
+
+    def __init__(self, i, nbytes=4096):
+        from twtml_tpu.features import arena
+
+        self.i = i
+        self._lease = arena.get_arena().lease(nbytes)
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.i, dtype)
+
+
+def _arena():
+    from twtml_tpu.features import arena
+
+    return arena.get_arena()
+
+
+def test_fetch_pipeline_abort_mid_flight_refunds_dispatch():
+    """An abort while batches are in flight: the batch whose fetch wedged
+    trained but was never delivered — its ``max_dispatch`` slot comes back
+    and its lease is discarded; batches delivered before it keep their
+    slots and retire their leases to the pool."""
+    _arena().reset_for_tests()
+    # batch 2 stalls on every attempt; 0 and 1 deliver normally
+    model = FlakyFetchModel(slow={2: {n: 0.5 for n in range(1, 10)}})
+    events, aborted = [], []
+    pipe = FetchPipeline(
+        model, lambda out, b, t, at_boundary: events.append(int(out["i"])),
+        depth=8, deterministic=True, max_dispatch=8,
+        fetch_deadline_s=0.05, fetch_retries=1,
+        abort=lambda: aborted.append(True),
     )
-    sb.on_batch(np.asarray(0), 0.0)
+    for i in range(4):
+        pipe.on_batch(_LeasedWire(i), float(i))
+    assert pipe._dispatched == 4 and _arena().stats()["in_use"] == 4
     with pytest.raises(FetchAbort):
-        sb._close_group()
-    assert sb._dispatched == 0  # the slot came back
-    assert _metrics.get_registry().counter("fetch.refunds").snapshot() == 1
-    sb.flush()  # clean no-op after the abort
+        pipe.drain()  # 0, 1 deliver; 2 aborts mid-flight; 3 stays pending
+    assert events == [0, 1] and aborted == [True]
+    assert pipe._dispatched == 3  # batch 2's slot came back
+    reg = _metrics.get_registry()
+    assert reg.counter("fetch.refunds").snapshot() == 1
+    st = _arena().stats()
+    assert st["in_use"] == 1  # only the still-pending batch 3
+    assert st["free_buffers"] == 2  # 0 and 1 retired; 2 was discarded
+    pipe.flush()  # batch 3's fetch had completed: it still delivers
+    assert events == [0, 1, 3]
+    assert pipe._dispatched == 3  # exactly the delivered batches
+    assert reg.counter("fetch.refunds").snapshot() == 1
+    assert _arena().stats() == {
+        "in_use": 0, "free_buffers": 3, "free_bytes": 3 * 4096,
+    }
+
+
+def test_fetch_pipeline_flush_refunds_undelivered_handles():
+    """The transport wedges with a full window in flight: flush swallows
+    the abort, drops every undelivered handle AND refunds the slot and
+    discards the lease of each."""
+    _arena().reset_for_tests()
+    model = FlakyFetchModel(
+        slow={i: {n: 0.5 for n in range(1, 10)} for i in range(4)}
+    )
+    aborted = []
+    pipe = FetchPipeline(
+        model, lambda out, b, t, at_boundary: None,
+        depth=8, deterministic=True, max_dispatch=8,
+        fetch_deadline_s=0.05, fetch_retries=1,
+        abort=lambda: aborted.append(True),
+    )
+    for i in range(4):
+        pipe.on_batch(_LeasedWire(i), float(i))
+    assert pipe._dispatched == 4  # all four in flight
+    pipe.flush()  # the abort inside the drain is swallowed; refunds land
+    assert aborted == [True]
+    assert pipe._dispatched == 0
+    assert _metrics.get_registry().counter("fetch.refunds").snapshot() == 4
+    st = _arena().stats()
+    assert st["in_use"] == 0 and st["free_buffers"] == 0  # no reuse
+    assert pipe._pool._shutdown
 
 
 def _flight_recorder(tmp_path):
@@ -529,66 +578,3 @@ def test_cadence_disagreement_abort_dumps_postmortem_bundle(
         "lockstep.rollback_disagreements"
     ).snapshot() == 1
     _assert_bundle(tmp_path, "disagree", "abort")
-
-
-def test_superbatcher_flush_refunds_undelivered_groups():
-    """Grouped dispatches (the coalesced-wire path included) that are
-    in flight when the transport wedges: flush drops them AND refunds every
-    batch they carried."""
-    import time as _time
-
-    import jax
-
-    from twtml_tpu.features.featurizer import Featurizer
-    from twtml_tpu.models import StreamingLinearRegressionWithSGD
-    from twtml_tpu.streaming.sources import SyntheticSource
-
-    class WedgedGroupFetch:
-        """Real learner, wedged pooled fetches — groups dispatch fine and
-        every fetch stalls past the watchdog deadline."""
-
-        accepts_packed = True
-
-        def __init__(self):
-            self.inner = StreamingLinearRegressionWithSGD(num_iterations=2)
-
-        def step(self, b):
-            return self.inner.step(b)
-
-        def step_many(self, stacked):
-            return self.inner.step_many(stacked)
-
-        def fetch_output(self, out):
-            _time.sleep(0.5)
-            return jax.device_get(out)
-
-        fetch_output_many = fetch_output
-
-    statuses = list(
-        SyntheticSource(total=64, seed=3, base_ms=1785320000000).produce()
-    )
-    feat = Featurizer(now_ms=1785320000000)
-    batches = [
-        feat.featurize_batch_ragged(
-            statuses[i * 16 : (i + 1) * 16], row_bucket=16, unit_bucket=512,
-            pre_filtered=True,
-        )
-        for i in range(4)
-    ]
-    for wire_pack in ("group", "stacked"):
-        _metrics.reset_for_tests()
-        aborted = []
-        sb = SuperBatcher(
-            WedgedGroupFetch(), 2, lambda out, b, t, at_boundary: None,
-            fetch_depth=4, fetch_deadline_s=0.05, fetch_retries=1,
-            abort=lambda: aborted.append(True), wire_pack=wire_pack,
-        )
-        for i, b in enumerate(batches):
-            sb.on_batch(b, float(i))
-        assert sb._dispatched == 4  # two groups of two, both in flight
-        sb.flush()  # abort inside the drain is swallowed; refunds land
-        assert aborted == [True]
-        assert sb._dispatched == 0, wire_pack
-        assert (
-            _metrics.get_registry().counter("fetch.refunds").snapshot() == 4
-        ), wire_pack

@@ -337,34 +337,36 @@ def test_nan_storm_aborts_cleanly_with_finite_checkpoint(tmp_path):
     assert np.isfinite(np.asarray(state)).all()
 
 
-def test_superbatch_group_rollback_skips_poisoned_group(tmp_path):
-    """--superBatch: the poisoning lands inside a scanned K-group — the
-    whole tainted group's deliveries are skipped (the scan chained the NaN
-    through the group), the rollback recovers, and the run completes."""
+def test_poison_inside_a_full_inflight_window_rolls_back_once(tmp_path):
+    """The poisoning lands while the fetch pipeline holds a full window:
+    slow fetches (``fetch:delay``) keep 8 dispatched batches in flight, so
+    the batches dispatched BEHIND the poisoned one trained on NaN weights
+    and deliver as tainted skips of the SAME episode — one rollback to the
+    batch-8 save, the journal re-ingests everything past it, and the run
+    completes with the full-file ledger."""
     from twtml_tpu.apps import linear_regression as app
 
     import jax
 
     jax.devices()
     path = tmp_path / "tweets.jsonl"
-    _write_lines(path, _corpus(8 * 16, seed=55))
+    _write_lines(path, _corpus(16 * 16, seed=55))
     ck = str(tmp_path / "ck")
     totals = app.run(ConfArguments().parse(
         BASE + ["--replayFile", str(path),
-                "--checkpointDir", ck, "--checkpointEvery", "2",
-                "--superBatch", "2",
-                "--chaos", "source.nan@5"]
+                "--checkpointDir", ck, "--checkpointEvery", "8",
+                # @13 fires on the 13th featurize call only: the replays
+                # bring the total to at most 24 calls, short of the 26th
+                "--chaos", "fetch:delay=0.02,source.nan@13"]
     ))
     reg = _metrics.get_registry()
-    # TWO episodes: batch 5 (featurize call 5) poisons its group (5,6) —
-    # both skipped, 32 rows replayed from the batch-4 cursor. The @5
-    # trigger is every-5th-call, so the 10th featurize call (batch 8; the
-    # replays consumed calls 7-8) poisons AGAIN — rollback to the batch-6
-    # save replays batches 7-8. Each replay re-crosses the seam BELOW the
-    # injection point and trains clean: the full-file ledger, zero lost.
-    assert reg.counter("model.rollbacks").snapshot() == 2
+    assert reg.counter("model.rollbacks").snapshot() == 1
+    # batch 13 and every batch dispatched behind it before its delivery
+    assert reg.counter("model.nonfinite_batches").snapshot() >= 2
     assert reg.counter("fetch.aborts").snapshot() == 0
-    assert totals["batches"] == 8
-    assert totals["count"] == 8 * 16
+    assert reg.counter("model.sentinel_aborts").snapshot() == 0
+    assert totals["batches"] == 16
+    assert totals["count"] == 16 * 16
     assert reg.counter("model.rows_lost").snapshot() == 0
-    assert reg.counter("journal.replayed_rows").snapshot() == 4 * 16
+    # the rollback point is the batch-8 save: batches 9..13 at the least
+    assert reg.counter("journal.replayed_rows").snapshot() >= 5 * 16

@@ -123,6 +123,57 @@ def test_step_module_name_is_the_same_for_every_bucket_and_wire(
     assert re.search(r"^module @(\S+)", text, re.M).group(1) == "jit_train_step"
 
 
+def compiled_funs(run):
+    """The ``fun_name`` of every backend compilation (or persistent-cache
+    fetch) ``run()`` causes — what the ``compile`` spans record."""
+    import jax.monitoring as mon
+
+    from twtml_tpu.telemetry.trace import BACKEND_COMPILE_EVENT
+
+    seen = []
+
+    def on_compile(event, secs, **kw):
+        if event == BACKEND_COMPILE_EVENT:
+            seen.append(str(kw.get("fun_name", "")))
+
+    mon.register_event_duration_secs_listener(on_compile)
+    try:
+        run()
+    finally:
+        mon.unregister_event_duration_listener(on_compile)
+    return seen
+
+
+WIRES = [
+    (form, rows, row_len)
+    for form in ("packed", "ragged", "units", "hashed")
+    for rows, row_len in ((8, 16), (16, 32))
+]
+
+
+@pytest.mark.parametrize("model_cls", ["linear", "logistic"])
+def test_single_device_model_compiles_one_train_program(model_cls):
+    """``jit(train_step)`` is the ONLY train program a single-device model
+    ever compiles — once per (wire form, bucket), and nothing else that
+    steps or scans the weights: the model has one step surface."""
+    from twtml_tpu import models
+
+    model = {
+        "linear": models.StreamingLinearRegressionWithSGD,
+        "logistic": models.StreamingLogisticRegressionWithSGD,
+    }[model_cls](num_text_features=1 << 12, num_iterations=2)
+
+    def run():
+        for form, rows, row_len in WIRES:
+            for _ in range(2):  # the repeat is served by jit's cache
+                model.step(_wire(form, rows, row_len))
+
+    funs = compiled_funs(run)
+    train = [f for f in funs if "step" in f or "scan" in f]
+    assert train == ["jit(train_step)"] * len(WIRES), funs
+    assert [n for n in dir(model) if "many" in n or "scan" in n] == []
+
+
 # ---------------------------------------------------------------------------
 # PR 28: inside the Gram basis the step predicts and writes back through the
 # plane's count matrix. What the PROGRAM asks for is in the lowered module

@@ -1,7 +1,7 @@
-"""Lean wire v2 (ISSUE 3): the coalesced one-buffer superbatch wire
-(``pack_ragged_group``) and the narrow uint16-delta offset wire must be
-BYTE-IDENTICAL in features, per-batch stats, and final weights to the
-shipped packed-ragged path — single-device AND sharded layouts, K ∈
+"""Lean wire v2 (ISSUE 3): the coalesced one-buffer tenant wire
+(``pack_ragged_group``) must unpack BYTE-IDENTICAL to the stacked wire
+(``stack_batches``), and the narrow uint16-delta offset wire to the
+shipped packed-ragged path — flat AND per-shard layouts, K ∈
 {1, 4, 8} — with the int32 offset fallback metadata-gated exactly like the
 uint8/uint16 units switch (rows longer than the uint16 delta range trip
 it). The wire may change transfer count and sideband bytes, never math."""
@@ -31,7 +31,7 @@ from twtml_tpu.streaming.sources import SyntheticSource
 
 def ragged_batches(n=4, rows=16, unit_bucket=512):
     """n same-signature ragged batches (one compiled program's worth —
-    the SuperBatcher grouping precondition)."""
+    what the tenant split emits)."""
     statuses = list(
         SyntheticSource(total=n * rows, seed=3, base_ms=1785320000000).produce()
     )
@@ -83,151 +83,75 @@ def long_row_batch(rows=4, long_len=OFFSET_DELTA_MAX + 2):
     )
 
 
-# -- coalesced group wire: differential vs the shipped paths -----------------
+# -- coalesced group wire: the wire law the tenant stack relies on ----------
+# ``_unpack_ragged_group(pack_ragged_group(bs))`` IS ``stack_batches(bs)``,
+# leaf for leaf and bit for bit — on the host (the whole group back), inside
+# a jit program (one segment: the single-device tenant program), and per
+# shard (each P(data) slice of the shard-major buffer: the mesh tenant
+# program's shard_map-local view).
+
+_FIELDS = ("units", "offsets", "numeric", "label", "mask")
+
+
+def _assert_leaves_equal(got, want):
+    assert got.row_len == want.row_len
+    for f in _FIELDS:
+        g, w = np.asarray(getattr(got, f)), np.asarray(getattr(want, f))
+        assert g.dtype == w.dtype and g.shape == w.shape, f
+        assert g.tobytes() == w.tobytes(), f
+
+
+def _assert_group_wire_law(batches, num_shards):
+    """Host, in-jit and per-shard unpack of the group pack against the
+    stacked wire of the same (shard-aligned) batches."""
+    from twtml_tpu.features.batch import align_ragged_shards
+
+    if num_shards > 1:
+        batches = [align_ragged_shards(b, num_shards) for b in batches]
+    want = stack_batches(batches)
+    pg = pack_ragged_group(batches)
+    assert pg.layout[0] == "RaggedGroupSegments"
+    assert pg.layout[2][1:3] == (num_shards, len(batches))
+    _assert_leaves_equal(unpack_batch(pg.buffer, pg.layout), want)
+
+    unpack = jax.jit(lambda buf: unpack_batch(buf, pg.layout))
+    seg = pg.buffer.shape[0] // num_shards
+    for s in range(num_shards):
+        local = unpack(pg.buffer[s * seg : (s + 1) * seg])
+        assert local.num_shards == 1
+        for f in _FIELDS:
+            full = np.asarray(getattr(want, f))
+            d = full.shape[1] // num_shards
+            w = full[:, s * d : (s + 1) * d]
+            g = np.asarray(getattr(local, f))
+            assert g.dtype == w.dtype and g.shape == w.shape, (f, s)
+            assert g.tobytes() == np.ascontiguousarray(w).tobytes(), (f, s)
+
 
 @pytest.mark.parametrize("k", [1, 4, 8])
-def test_group_wire_matches_sequential_single_device(k):
-    batches = ragged_batches(n=k)
-    seq = StreamingLinearRegressionWithSGD(num_iterations=5)
-    outs = [seq.step(pack_batch(b)) for b in batches]  # the shipped k=1 wire
-
-    sup = StreamingLinearRegressionWithSGD(num_iterations=5)
-    many = sup.step_many(pack_ragged_group(batches))
-    np.testing.assert_array_equal(sup.latest_weights, seq.latest_weights)
-    for i, out in enumerate(outs):
-        assert float(many.mse[i]) == float(out.mse)
-        assert float(many.count[i]) == float(out.count)
-        np.testing.assert_array_equal(
-            np.asarray(many.predictions[i]), np.asarray(out.predictions)
-        )
-
-    # and vs the stacked superbatch wire (the pre-v2 grouping layout)
-    stk = StreamingLinearRegressionWithSGD(num_iterations=5)
-    stk.step_many(stack_batches(batches))
-    np.testing.assert_array_equal(stk.latest_weights, sup.latest_weights)
+def test_group_wire_matches_stacked_single_device(k):
+    _assert_group_wire_law(ragged_batches(n=k), num_shards=1)
 
 
 @pytest.mark.parametrize("k", [1, 4, 8])
-def test_group_wire_matches_sequential_mesh(k):
-    from twtml_tpu.parallel import ParallelSGDModel, make_mesh
-    from twtml_tpu.parallel.sharding import shard_batch
-
-    batches = ragged_batches(n=k, rows=32)
-    mesh = make_mesh(num_data=4, devices=jax.devices()[:4])
-    seq = ParallelSGDModel(mesh, num_iterations=5, step_size=0.05)
-    outs = [seq.step(shard_batch(b, mesh)) for b in batches]
-
-    sup = ParallelSGDModel(mesh, num_iterations=5, step_size=0.05)
-    many = sup.step_many(sup.pack_group_for_wire(batches))
-    np.testing.assert_array_equal(sup.latest_weights, seq.latest_weights)
-    for i, out in enumerate(outs):
-        assert float(many.mse[i]) == float(out.mse)
-        np.testing.assert_array_equal(
-            np.asarray(many.predictions[i]), np.asarray(out.predictions)
-        )
+def test_group_wire_matches_stacked_mesh(k):
+    _assert_group_wire_law(ragged_batches(n=k, rows=32), num_shards=4)
 
 
-def test_group_wire_2d_mesh_matches_sequential():
-    from twtml_tpu.parallel import ParallelSGDModel, make_mesh
-    from twtml_tpu.parallel.sharding import shard_batch
-
-    batches = ragged_batches(n=4, rows=32)
-    mesh = make_mesh(num_data=2, num_model=2, devices=jax.devices()[:4])
-    seq = ParallelSGDModel(mesh, num_iterations=5, step_size=0.05)
-    outs = [seq.step(shard_batch(b, mesh)) for b in batches]
-    sup = ParallelSGDModel(mesh, num_iterations=5, step_size=0.05)
-    many = sup.step_many(sup.pack_group_for_wire(batches))
-    np.testing.assert_array_equal(sup.latest_weights, seq.latest_weights)
-    for i, out in enumerate(outs):
-        assert float(many.mse[i]) == float(out.mse)
+def test_group_wire_2d_mesh_matches_stacked():
+    """A (2 data x 2 model) mesh slices the buffer over its data axis
+    only: two shard segments."""
+    _assert_group_wire_law(ragged_batches(n=4, rows=32), num_shards=2)
 
 
 def test_group_wire_wide_units():
     """Non-ASCII (uint16) units compose with the group wire and the narrow
-    offset wire — features bit-identical to plain sequential steps."""
+    offset wire."""
     batches = [wide_ragged_batch(seed=s) for s in (5, 6, 7, 8)]
     pg = pack_ragged_group(batches)
     assert pg.layout[2][3] == "u16delta"  # narrow offsets despite wide units
-    back = unpack_batch(pg.buffer, pg.layout)
-    for f in ("units", "offsets", "numeric", "label", "mask"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(back, f)),
-            np.asarray(getattr(stack_batches(batches), f)),
-        )
-    seq = StreamingLinearRegressionWithSGD(num_iterations=5)
-    outs = [seq.step(b) for b in batches]
-    sup = StreamingLinearRegressionWithSGD(num_iterations=5)
-    many = sup.step_many(pg)
-    np.testing.assert_array_equal(sup.latest_weights, seq.latest_weights)
-    for i, out in enumerate(outs):
-        assert float(many.mse[i]) == float(out.mse)
-
-
-def test_superbatcher_group_mode_matches_stacked_end_to_end():
-    """The app grouping path with --wirePack group: identical per-batch
-    stats and final weights as stacked mode, partial tail included (the
-    tail rides the k=1 one-buffer wire)."""
-    from twtml_tpu.apps.common import SuperBatcher
-
-    batches = ragged_batches(n=7)
-
-    def run(mode):
-        model = StreamingLinearRegressionWithSGD(num_iterations=5)
-        seen = []
-        sb = SuperBatcher(
-            model, 3,
-            lambda o, b, t, at_boundary: seen.append(
-                (float(o.count), float(o.mse), at_boundary)
-            ),
-            # counter-driven emit points: at_boundary at a non-final group
-            # otherwise races the already-done early-emit probe, and the
-            # two arms can draw different winners
-            deterministic=True,
-            wire_pack=mode,
-        )
-        for i, b in enumerate(batches):
-            sb.on_batch(b, float(i))
-        sb.flush()
-        return model, seen
-
-    m_group, seen_group = run("group")
-    m_stacked, seen_stacked = run("stacked")
-    assert seen_group == seen_stacked and len(seen_group) == 7
-    np.testing.assert_array_equal(
-        m_group.latest_weights, m_stacked.latest_weights
-    )
-
-
-def test_superbatcher_group_mode_traces_wire_pack_mode(tmp_path):
-    """--trace + --wirePack group: wire_pack spans carry the mode attribute
-    ('group' for full groups, 'single' for the partial tail's k=1 pack)."""
-    from tools import trace_report
-    from twtml_tpu.apps.common import SuperBatcher
-    from twtml_tpu.telemetry import trace
-
-    batches = ragged_batches(n=5)
-    path = str(tmp_path / "wire.trace")
-    trace.install(path)
-    try:
-        model = StreamingLinearRegressionWithSGD(num_iterations=5)
-        sb = SuperBatcher(
-            model, 4, lambda o, b, t, at_boundary: None, wire_pack="group"
-        )
-        for i, b in enumerate(batches):
-            sb.on_batch(b, float(i))
-        sb.flush()
-    finally:
-        trace.uninstall()
-    spans = [
-        e for e in trace_report.load_events(path)
-        if e.get("ph") == "X" and e["name"] == "wire_pack"
-    ]
-    modes = [s["args"]["mode"] for s in spans]
-    assert modes.count("group") == 1  # one full group of 4
-    assert modes.count("single") == 1  # the one-batch partial tail
-    group_span = next(s for s in spans if s["args"]["mode"] == "group")
-    assert group_span["args"]["batches"] == 4
-    assert group_span["args"]["wire_bytes"] > 0
+    assert np.asarray(stack_batches(batches).units).dtype == np.uint16
+    _assert_group_wire_law(batches, num_shards=1)
 
 
 # -- narrow offset wire: encode gate + fallback ------------------------------

@@ -450,38 +450,25 @@ def test_fetch_pipeline_discards_leases_on_abort(monkeypatch):
     assert st["free_buffers"] == 0  # abort path: no reuse
 
 
-def test_super_batcher_group_leases_retire():
-    from twtml_tpu.apps.common import SuperBatcher
-
-    class _GroupModel(_EchoModel):
-        def step_many(self, wire):
-            return {"mse": np.zeros(4, np.float32)}
+def test_tenant_group_wire_leases_retire():
+    """The coalesced M-tenant wire (``pack_ragged_group`` behind
+    ``TenantStackModel.pack_for_wire``) leases ONE buffer per dispatch;
+    the pipeline retires it to the pool on delivery."""
+    from twtml_tpu.apps.common import FetchPipeline
+    from twtml_tpu.parallel import TenantStackModel
 
     arena_mod.get_arena().reset_for_tests()
     got = []
-    from twtml_tpu.models.base import StepOutput
-
-    n_fields = len(StepOutput._fields)
-
-    def handle(out, batch, t, at_boundary):
-        got.append(t)
-
-    batcher = SuperBatcher(
-        _GroupModel(), 4,
-        handle, wire_pack="group",
+    pipe = FetchPipeline(
+        TenantStackModel(2, num_iterations=2, wire_pack="group"),
+        lambda out, b, t, at_boundary: got.append(t),
+        depth=3, pack=True, deterministic=True,
     )
-    al = align_ragged_shards(hand_batch(), 1)
-    # step_many's fake output must be StepOutput-shaped for re-emit
-    def step_many(wire):
-        return StepOutput(*(
-            np.zeros((4,), np.float32) for _ in range(n_fields)
-        ))
-
-    batcher.model.step_many = step_many
-    for j, b in enumerate(signature_variants(al, 8)):
-        batcher.on_batch(b, float(j))
-    batcher.flush()
-    assert len(got) == 8
+    for j, b in enumerate(featurized_batches(n=5)):
+        pipe.on_batch(b, float(j))
+        assert arena_mod.get_arena().stats()["in_use"] >= 1
+    pipe.flush()
+    assert got == [0.0, 1.0, 2.0, 3.0, 4.0]
     st = arena_mod.get_arena().stats()
     assert st["in_use"] == 0
     assert st["free_buffers"] >= 1

@@ -337,19 +337,45 @@ def test_mesh_sharded_model_bitwise_identical():
     )
 
 
-def test_scanned_group_wire_bitwise_identical():
-    """step_many over the codec group wire == K sequential raw steps."""
+def test_pipeline_codec_wire_bitwise_identical():
+    """``--wireCodec dict`` as the apps run it — FetchPipeline packing each
+    batch through the codec — delivers the same per-batch stats and leaves
+    the same weights, bit for bit, as the raw packed wire."""
+    from twtml_tpu.apps.common import FetchPipeline
+    from twtml_tpu.telemetry import metrics as _metrics
+
     statuses = synthetic(192, seed=21)
-    chunks = [statuses[i : i + 64] for i in range(0, 192, 64)]
-    batches = [ragged_batch(c, rows=64, unit_bucket=64) for c in chunks]
-    if len({(b.units.shape, b.row_len) for b in batches}) != 1:
-        pytest.skip("synthetic batches landed in different unit buckets")
-    m_seq = StreamingLinearRegressionWithSGD(num_iterations=5, step_size=0.1)
-    m_grp = StreamingLinearRegressionWithSGD(num_iterations=5, step_size=0.1)
-    for b in batches:
-        m_seq.step(b)
-    m_grp.step_many(pack_ragged_group(batches, codec="dict"))
-    np.testing.assert_array_equal(m_seq.latest_weights, m_grp.latest_weights)
+    batches = [
+        ragged_batch(statuses[i : i + 64], rows=64, unit_bucket=64)
+        for i in range(0, 192, 64)
+    ]
+
+    def run(codec):
+        _metrics.reset_for_tests()
+        model = StreamingLinearRegressionWithSGD(
+            num_iterations=5, step_size=0.1
+        )
+        seen = []
+        pipe = FetchPipeline(
+            model,
+            lambda out, b, t, at_boundary: seen.append(
+                [np.asarray(a).tobytes() for a in out if a is not None]
+            ),
+            depth=8, pack=True, wire_codec=codec,
+        )
+        for i, b in enumerate(batches):
+            pipe.on_batch(b, float(i))
+        pipe.flush()
+        gauges = _metrics.get_registry().snapshot()["gauges"]
+        return model.latest_weights, seen, gauges
+
+    w_raw, seen_raw, g_raw = run("")
+    w_codec, seen_codec, g_codec = run("dict")
+    _metrics.reset_for_tests()
+    assert "wire.codec_ratio" not in g_raw
+    assert g_codec["wire.codec_ratio"] > 1.0  # the codec really engaged
+    assert seen_codec == seen_raw and len(seen_raw) == 3
+    np.testing.assert_array_equal(w_codec, w_raw)
 
 
 def test_tenant_group_wire_bitwise_identical():

@@ -5,7 +5,7 @@ matmul is 2·B²·F = ~2.2 TFLOP at B=2048 by arithmetic; its time on this
 machine is not measured beyond PERF.md §5's one smoke figure). The
 G build costs B²·F FLOPs, i.e. PER-TWEET device cost scales linearly with
 batch size, so a smaller batch trades per-batch overheads for less G work
-per tweet. This tool interleaves arms (batch size × wire × superbatch)
+per tweet. This tool interleaves arms (batch size × wire)
 within one window — single passes round-robin, so a slow stretch hits
 every arm equally — and reports each arm's best/median plus per-round
 rates, to pick the config #4 operating point from data.
@@ -19,7 +19,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -41,7 +40,6 @@ def main(argv=None) -> None:
 
     import jax
 
-    from twtml_tpu.features.batch import stack_batches
     from twtml_tpu.features.featurizer import Featurizer
     from twtml_tpu.models import StreamingLinearRegressionWithSGD
     from twtml_tpu.streaming.sources import SyntheticSource
@@ -83,42 +81,6 @@ def main(argv=None) -> None:
 
         arms[name] = one_pass
 
-    def superbatch_arm(name, batch, k):
-        # K batches stacked into one step_many dispatch (padded wire —
-        # ragged doesn't stack); featurize+stack on a prefetch thread
-        from concurrent.futures import ThreadPoolExecutor
-
-        chunks = chunked(batch)
-        groups = [chunks[i : i + k] for i in range(0, len(chunks), k)]
-
-        def fz(group):
-            return stack_batches([
-                feat.featurize_batch_units(
-                    c, row_bucket=batch, pre_filtered=True
-                )
-                for c in group
-            ])
-
-        m = model()
-        warm = fz(groups[0])
-        for _ in range(2):
-            float(m.step_many(warm).mse[-1])
-
-        def one_pass():
-            m.reset()
-            t0 = time.perf_counter()
-            with ThreadPoolExecutor(max_workers=1) as pool:
-                pending = pool.submit(fz, groups[0])
-                for nxt in groups[1:]:
-                    stacked = pending.result()
-                    pending = pool.submit(fz, nxt)
-                    m.step_many(stacked)
-                last = m.step_many(pending.result())
-            float(last.mse[-1])  # completion fetch closes the window
-            return time.perf_counter() - t0, last
-
-        arms[name] = one_pass
-
     pipeline_arm("padded_b2048", 2048, "padded")  # the r2 operating point
     pipeline_arm("ragged_b2048", 2048, "ragged", int8=True)
     pipeline_arm("ragged_b3072", 3072, "ragged", int8=True)  # r4 point
@@ -128,7 +90,6 @@ def main(argv=None) -> None:
     pipeline_arm("ragged_b2048_bf16", 2048, "ragged", int8=False)
     pipeline_arm("ragged_b512", 512, "ragged")
     pipeline_arm("padded_b1024", 1024, "padded")
-    superbatch_arm("padded_b2048_k8", 2048, 8)
 
     # the house interleaved/paired scheduling (tools/pairedbench.py)
     from tools.pairedbench import (

@@ -439,7 +439,6 @@ def run_config(name: str, n_tweets: int, batch_size: int = 0) -> dict:
         # batch: 2048 at the suite's pass shape (see the per-config
         # defaults comment above; tools/bench_2e18.py re-checks the
         # batch curve — b3072 wins long passes, b2048 wins here).
-        # r3's --superBatch NEGATIVE finding stands.
         out.update(_pipeline_rate(model, feat, statuses, batch_size,
                                   ragged=True))
     elif name == "multi_tenant_m8":

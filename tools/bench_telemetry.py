@@ -144,61 +144,6 @@ def main(argv=None) -> None:
         pipe.flush()
         return time.perf_counter() - t0
 
-    from twtml_tpu.features.batch import stack_batches
-    from twtml_tpu.models.base import StepOutput
-
-    groups = [
-        stack_batches(batches[i : i + 8])
-        for i in range(0, len(batches) - len(batches) % 8, 8)
-    ]
-    tail = batches[len(batches) - len(batches) % 8 :]
-    if groups:
-        float(model.step_many(groups[0]).mse[-1])  # warm the scan program
-
-    def super_pool_pass(workers=4):
-        """--superBatch 8 + pooled group fetches: one scan dispatch and one
-        pooled fetch per 8 batches — the two levers stacked. The per-batch
-        consume() runs here too, so every arm measures the same handler
-        work."""
-        model.reset()
-        t0 = time.perf_counter()
-        with ThreadPoolExecutor(max_workers=workers) as pool:
-            futs = [
-                (pool.submit(jax.device_get, model.step_many(g)), True)
-                for g in groups
-            ] + [
-                (pool.submit(jax.device_get, model.step(b)), False)
-                for b in tail
-            ]
-            for f, stacked in futs:
-                host = f.result()
-                if stacked:
-                    for k in range(host.count.shape[0]):
-                        consume(
-                            StepOutput(*(x[k] for x in host)), None, 0.0
-                        )
-                else:
-                    consume(host, None, 0.0)
-        return time.perf_counter() - t0
-
-    from twtml_tpu.apps.common import SuperBatcher
-
-    def super_ragged_pass():
-        """r5: --superBatch 8 on the RAGGED wire through the shipped
-        SuperBatcher (stacked [K, N] buffers scan with row_len static;
-        grouping by shape signature) — the composition VERDICT r4 #1c
-        asked to wire and measure. Same per-batch handler work."""
-        model.reset()
-        t0 = time.perf_counter()
-        sb = SuperBatcher(model, 8, consume, fetch_depth=4)
-        for rb in r_batches:
-            sb.on_batch(rb, 0.0)
-        sb.flush()
-        return time.perf_counter() - t0
-
-    if groups:
-        super_ragged_pass()  # warm the ragged scan programs (per layout)
-
     # the house interleaved/paired scheduling (tools/pairedbench.py)
     from tools.pairedbench import (
         best_median_rate, paired_ratio_median, run_rounds,
@@ -208,9 +153,6 @@ def main(argv=None) -> None:
         "sync": sync_pass, "lag": lag_pass, "pool8": pool_pass,
         "fetchpipe": fetchpipe_pass,
     }
-    if groups:
-        arms["super8_pool4"] = super_pool_pass
-        arms["super8_ragged"] = super_ragged_pass
     times = run_rounds(arms, budget)
 
     out = {"regime": "per-batch-telemetry", "batch": batch,
@@ -222,21 +164,9 @@ def main(argv=None) -> None:
             "tweets_per_sec_best": best,
             "tweets_per_sec_median": median,
         }
-    for name in [
-        k
-        for k in (
-            "lag", "pool8", "fetchpipe", "super8_pool4", "super8_ragged",
-        )
-        if k in times
-    ]:
+    for name in ("lag", "pool8", "fetchpipe"):
         out[name]["paired_speedup_vs_sync"] = paired_ratio_median(
             times["sync"], times[name]
-        )
-    if "super8_ragged" in times:
-        # the composition question directly: does the superbatch stack on
-        # the shipped ragged fetch pipeline?
-        out["super8_ragged"]["paired_vs_fetchpipe"] = paired_ratio_median(
-            times["fetchpipe"], times["super8_ragged"]
         )
     print(json.dumps(out))
 

@@ -9,10 +9,11 @@ byte saving beat the encode cost where upload binds?
 Method: the house harness only (tools/pairedbench.py) — interleaved
 single passes, paired per-round ratios, parity asserted per round (the
 codec may never change the math). Per regime (object / block ingest),
-FOUR arms round-robin in one window: the k=1 packed wire and the K-group
-coalesced wire, each raw and codec ("codec off/on × stacked/group" —
-"stacked" here is the per-batch one-buffer pack; the codec rides packed
-forms only, config.effective_wire_pack rejects the contradictory combo).
+the k=1 packed wire and the K-group coalesced (tenant) wire, each raw
+and codec ("codec off/on × stacked/group" — "stacked" here is the
+per-batch one-buffer pack; the codec rides packed forms only,
+config.effective_wire_pack rejects the contradictory combo). The control
+window steps the k=1 wire only; the group wire is timed pack-only.
 
 Each regime answers twice:
 
@@ -86,11 +87,10 @@ UPLOAD_MBS_SWEEP = (45.0, 55.0, 70.0)
 
 
 def _uniform_groups(batches, k: int):
-    """K-groups of signature-matching batches (the SuperBatcher rule: one
-    compiled scan program per (signature, K)). Batches sharing the MODAL
-    signature are grouped (the bench's corpus is small enough that the
-    data-dependent units bucket can differ batch to batch; production
-    grouping is by-signature too, just streamwise)."""
+    """K-groups of signature-matching batches (the group pack's rule).
+    Batches sharing the MODAL signature are grouped (the bench's corpus is
+    small enough that the data-dependent units bucket can differ batch to
+    batch)."""
     from collections import Counter
 
     sig = lambda b: (b.units.shape, b.units.dtype, b.row_len)  # noqa: E731
@@ -106,7 +106,7 @@ def _uniform_groups(batches, k: int):
 
 def _control_window(batches, k: int, budget_s: float) -> dict:
     """The CPU-control window: the FULL pipeline (pack → step → one
-    completion fetch), 4 arms (single/group × raw/codec) round-robin.
+    completion fetch), the k=1 wire raw and codec, round-robin.
     Every arm trains its OWN model over the same batch sequence each pass
     (arms stay step-for-step comparable because run_rounds completes
     every round); parity is asserted on final mse per window. A light
@@ -117,10 +117,9 @@ def _control_window(batches, k: int, budget_s: float) -> dict:
     import numpy as np
 
     from tools.pairedbench import paired_ratio_median, run_rounds
-    from twtml_tpu.features.batch import pack_batch, pack_ragged_group
+    from twtml_tpu.features.batch import pack_batch
     from twtml_tpu.models import StreamingLinearRegressionWithSGD
 
-    groups = _uniform_groups(batches, k)
     finals: dict[str, float] = {}
 
     def single_arm(name, codec):
@@ -136,38 +135,19 @@ def _control_window(batches, k: int, budget_s: float) -> dict:
 
         return run
 
-    def group_arm(name, codec):
-        model = StreamingLinearRegressionWithSGD(num_iterations=5)
-
-        def run():
-            t0 = time.perf_counter()
-            out = None
-            for g in groups:
-                out = model.step_many(pack_ragged_group(g, codec=codec))
-            finals[name] = float(np.asarray(jax.device_get(out.mse))[-1])
-            return time.perf_counter() - t0
-
-        return run
-
     arms = {
         "single_raw": single_arm("single_raw", None),
         "single_codec": single_arm("single_codec", "dict"),
-        "group_raw": group_arm("group_raw", None),
-        "group_codec": group_arm("group_codec", "dict"),
     }
     for run in arms.values():  # warmup: compile + completion fetch
         run()
     times = run_rounds(arms, budget_s)
     # parity per window: identical batch sequence → identical final mse
     assert finals["single_raw"] == finals["single_codec"], finals
-    assert finals["group_raw"] == finals["group_codec"], finals
     return {
         "rounds": len(times["single_raw"]),
         "paired_single_codec_vs_raw": paired_ratio_median(
             times["single_raw"], times["single_codec"]
-        ),
-        "paired_group_codec_vs_raw": paired_ratio_median(
-            times["group_raw"], times["group_codec"]
         ),
         "final_mse": finals["single_raw"],
     }

@@ -11,8 +11,7 @@ health-phase-safe, because a phase swing hits both members of a pair.
 
 This module extracts the arm scheduling and the ratio math that
 tools/bench_ragged.py, tools/bench_2e18.py and tools/bench_telemetry.py
-each re-implemented (r3–r5), so the method cannot drift between tools;
-tools/bench_superwire.py is built on it directly.
+each re-implemented (r3–r5), so the method cannot drift between tools.
 
 An *arm* is a zero-arg callable running ONE full pass and returning its
 wall-clock seconds (or a ``(seconds, anything)`` tuple — the extra value

@@ -656,8 +656,8 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
             # app-level tenant fleet (r16, PR 7 REMAINING b; ragged wire
             # lifted in r20): the tenant stack behind per-host sharded
             # intake on the 1D process-aligned data mesh — the global
-            # tenant wire assembles on the row axis like the stacked
-            # superbatch wire, ONE pooled fetch per tick, and the elastic
+            # tenant wire assembles on the row axis of the stacked
+            # [M, ...] leaves, ONE pooled fetch per tick, and the elastic
             # membership plane rebuilds it across epochs like the
             # single-model plane. Ragged tenant parts agree one shared
             # per-shard bucket fleet-wide (a single allgather-max per
@@ -734,15 +734,13 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
 
             # the app featurizes only THIS host's rows: its local batch
             # must divide this host's share of the data axis. The codec
-            # bucket (r16, groups in r20) is agreed on the SAME alignment
-            # allgather the raw bucket already pays — zero new collectives;
-            # for --superBatch groups, prepare() records each batch's
-            # agreed bucket and the group pack combines them arithmetically.
+            # bucket (r16) is agreed on the SAME alignment allgather the
+            # raw bucket already pays — zero new collectives.
             mh = MultiHostSGDModel(model, mesh, rebuilder=sgd_rebuilder)
             mh.wire_codec = codec if codec == "dict" else ""
             return mh, max(1, model.num_data // jax.process_count())
         # single-process mesh: the mesh packs compress per shard segment
-        # (parallel/sharding.py pack_for_wire / pack_group_for_wire)
+        # (parallel/sharding.py pack_for_wire)
         model.wire_codec = codec if codec == "dict" else ""
         return model, model.num_data
     return model_cls.from_conf(conf), 1
@@ -773,9 +771,9 @@ class AppCheckpoint:
     point (model checkpoint/resume is this framework's upgrade over the
     reference, SURVEY.md §5.4 — a restarted reference job begins from
     zeros). Restores state + counters at startup, saves on a cadence-
-    crossing test at weight-current boundaries (so ``--superBatch`` groups
-    snap to the first boundary at/after each cadence point instead of
-    stretching to lcm), and saves final state at shutdown.
+    crossing test at weight-current boundaries (a pipeline drain snaps to
+    the first boundary at/after each cadence point), and saves final state
+    at shutdown.
 
     ``get_state()`` returns the checkpointable arrays (flat dict or one
     array); ``set_state(state)`` restores them into the model.
@@ -1374,7 +1372,7 @@ class DivergenceSentinel:
         )
 
     def admit(self, out, batch) -> bool:
-        """Per-delivery gate (wired by ``attach_super_batcher``): True →
+        """Per-delivery gate (wired by ``attach_pipeline``): True →
         hand the batch to the app handler; False → skipped (non-finite
         state; rollback/abort already handled here)."""
         self._delivered += 1
@@ -1511,7 +1509,7 @@ class ModelWatchGuard:
 
     def observe(self, out, at_boundary: bool = True) -> None:
         """Per-delivery hook (wired OUTSIDE the tenant adapter in
-        ``attach_super_batcher``, so the tenant plane's raw [M, Q] quality
+        ``attach_pipeline``, so the tenant plane's raw [M, Q] quality
         leaf is visible here — per-tenant drift for free)."""
         if not self.enabled or getattr(out, "quality", None) is None:
             return
@@ -1567,7 +1565,7 @@ class FreshnessGuard:
     healthy model keeps training; it just leaves a restorable snapshot
     behind from BEFORE the backlog grew).
 
-    Wired OUTERMOST in ``attach_super_batcher`` so every delivery — even
+    Wired OUTERMOST in ``attach_pipeline`` so every delivery — even
     ticks the sentinel skips or the multihost filter drops as globally
     empty — advances the lineage FIFO; the FIFOs stay aligned with the
     dispatch order exactly because nothing upstream can swallow a
@@ -1643,7 +1641,7 @@ class ProcessRecycler:
         self._ticks = 0
         # sample on every boundary by default: rss_mb is a ~µs statm read
         # and boundaries are already sparse in back-to-back mode (the
-        # attach_super_batcher cadence); TWTML_RECYCLE_SAMPLE_EVERY remains
+        # attach_pipeline cadence); TWTML_RECYCLE_SAMPLE_EVERY remains
         # the test hook pinning WHICH boundary recycles
         self._sample_every = max(
             1,
@@ -1729,7 +1727,7 @@ class ProcessRecycler:
 
 class FetchWatchdog:
     """Deadline + bounded-retry + clean-abort guard over the pooled host
-    fetches (FetchPipeline / SuperBatcher).
+    fetches (FetchPipeline).
 
     Why it is safe to retry: a ``device_get`` reads arrays that stay
     resident on the device, so a fetch that missed its deadline or raised
@@ -1876,483 +1874,6 @@ def _record_wire_codec(wire, requested: str) -> None:
         )
 
 
-class SuperBatcher:
-    """Group K featurized micro-batches into ONE device dispatch
-    (``model.step_many``: a lax.scan of the ordinary train step) and re-emit
-    each batch's StepOutput to ``handle`` in order.
-
-    Why: in replay/back-to-back regimes every per-batch stats fetch costs a
-    full host-fetch round trip, which caps the telemetry-on path wherever
-    that round trip dwarfs the device step. The scan fetches K batches'
-    stats as one array (~K× fewer fetches), and the group fetches are
-    additionally POOLED (``fetch_depth`` concurrent in-order
-    ``device_get``s, the FetchPipeline mechanism;
-    tools/bench_telemetry.py ``super8_pool4`` is the harness).
-    Semantics are unchanged: batch boundaries, per-batch stats,
-    predict-then-train ordering, and final weights are bitwise those of K
-    sequential ``step`` calls (tests/test_superbatch.py). Requires pinned
-    batch buckets (every grouped batch must share one shape).
-
-    ``handle(out, batch, batch_time)`` receives plain-numpy per-batch
-    outputs in order; ``at_boundary`` is True only when the model's
-    weights are current as of that batch (group tail with nothing newer
-    dispatched — drains at ``boundary_every`` cadence points keep
-    checkpoint saves correct). ``max_dispatch`` caps trained batches at
-    group granularity (the documented up-to-K−1 overshoot). Call
-    ``flush()`` after the stream terminates.
-
-    Only contiguous SAME-SHAPE batches group (one compiled scan program): a
-    batch that overflowed a pinned bucket, or flipped the units wire dtype,
-    closes the pending group first and starts its own — it is never
-    silently dropped, and partial groups run as plain steps (identical
-    math, no one-off scan compiles at odd lengths). The ragged wire groups
-    too (r5): its data-dependent units bucket is part of the shape
-    signature, so only same-bucket batches share a scan program (totals
-    concentrate tightly — steady state is one or two buckets).
-
-    ``deterministic`` (multi-host mode) disables the opportunistic
-    already-done early emit, exactly like FetchPipeline's: handler side
-    effects then fire only at points driven by the dispatch counter, which
-    advances identically on every lockstep host.
-
-    ``wire_pack="group"`` (Lean wire v2, ``--wirePack``) coalesces each
-    full group's K ragged batches into ONE contiguous buffer
-    (``features/batch.pack_ragged_group`` — mesh/multi-host models lay it
-    out per shard via ``pack_group_for_wire``) uploaded by a single
-    main-thread put, instead of the stacked wire's K-per-field arrays; the
-    scanned program unpacks the segments in-jit, so the math and the
-    per-batch stats stay bitwise identical (tests/test_superwire.py).
-    Partial groups then pack their single batches through the k=1
-    one-buffer wire (``pack_for_wire``/``pack_batch``) for the same lean
-    layout. Grouping is already by shape signature, so the group layout is
-    a pure function of (signature, K) — one compiled program per group
-    shape, exactly like the stacked wire."""
-
-    def __init__(self, model, k: int, handle, fetch_depth: int = 4,
-                 boundary_every: int = 0, max_dispatch: int = 0,
-                 deterministic: bool = False, abort=None,
-                 fetch_deadline_s: float = 0.0,
-                 fetch_retries: "int | None" = None,
-                 wire_pack: str = "stacked",
-                 wire_codec: str = ""):
-        from concurrent.futures import ThreadPoolExecutor
-
-        self.model = model
-        self.k = k
-        self.handle = handle
-        self.fetch_depth = max(1, fetch_depth)
-        self.max_dispatch = max_dispatch
-        self.deterministic = deterministic
-        if wire_pack not in ("stacked", "group"):
-            raise ValueError(f"wire_pack must be 'stacked' or 'group', got {wire_pack!r}")
-        self.wire_pack = wire_pack
-        # compressed units wire (--wireCodec, r15): forwarded to the plain
-        # features/batch packers below; model-aware packers carry their own
-        # ``wire_codec`` attribute (parallel/sharding.py, tenants.py)
-        self.wire_codec = wire_codec
-        # model-aware coalesced/group packers (mesh models shard the one
-        # buffer; multi-host models assemble it globally); plain models use
-        # the features/batch host packers
-        self._group_packer = getattr(model, "pack_group_for_wire", None)
-        self._single_packer = getattr(model, "pack_for_wire", None)
-        # cadence drains count DISPATCHED BATCHES (partial groups included),
-        # honoring the pre-r3 contract: the first boundary at/after each
-        # cadence point
-        self.boundary_every = boundary_every
-        self._last_boundary = 0
-        # model-aware host transfers (MultiHostSGDModel localizes the
-        # lead's predictions inside the pooled fetch); plain models use
-        # jax.device_get
-        self._fetch_many = getattr(model, "fetch_output_many", None)
-        self._fetch_one = getattr(model, "fetch_output", None)
-        # observability: timed group fetches feed the fetch-health monitor
-        # (one fetch REQUEST per group — fetch.count counts requests, so a
-        # K-group still increments by 1)
-        self._registry = _metrics.get_registry()
-        self._health = _metrics.get_health_monitor()
-        self._fetch_count = self._registry.counter("fetch.count")
-        self._fetch_hist = self._registry.histogram("fetch.latency_s")
-        self._depth_gauge = self._registry.gauge("fetch.queue_depth")
-        self._refund_count = self._registry.counter("fetch.refunds")
-        self._pool = ThreadPoolExecutor(
-            max_workers=self.fetch_depth,
-            thread_name_prefix="twtml-group-fetch",
-        )
-        # deadline/retry/abort guard over every pooled group fetch — the
-        # pre-guard future.result() was a silent permanent hang on a
-        # wedged transport (FetchWatchdog)
-        self._watchdog = FetchWatchdog(
-            self._health, abort=abort,
-            deadline_s=fetch_deadline_s, retries=fetch_retries,
-        )
-        self._buf: list = []
-        self._seqs: list = []  # the buffered batches' --trace batch ids
-        self._sig = None
-        # [(future, group, outs, lease, batch ids)] oldest first
-        self._inflight: list = []
-        self._dispatched = 0
-        # checkpoint cadence runs on its own MONOTONIC counter, exactly as
-        # in FetchPipeline: a refund_dispatch adjusts only the cap
-        # accounting and must not drift the boundary cadence (r5 review —
-        # the same r3 advisor finding, re-introduced here)
-        self._cadence = 0
-
-    @staticmethod
-    def _signature(batch):
-        # tree_flatten, not tuple(batch): the ragged wire's batch is not a
-        # NamedTuple, and its static aux (row_len, shard alignment) must be
-        # part of the one-compiled-program signature — the treedef carries
-        # both the class and the aux
-        import jax
-
-        leaves, treedef = jax.tree_util.tree_flatten(batch)
-        return (treedef,) + tuple((a.shape, str(a.dtype)) for a in leaves)
-
-    def on_batch(self, batch, batch_time) -> None:
-        if self._watchdog.aborted:
-            return  # fetch abort in flight: nothing more may train
-        if self.max_dispatch and self._dispatched >= self.max_dispatch:
-            # cap reached: deliver what trained so the handler-side stop
-            # fires (see FetchPipeline), train nothing more
-            self._drain()
-            return
-        sig = self._signature(batch)
-        if self._buf and sig != self._sig:
-            self._close_group()  # shape/dtype changed: close, never drop
-        self._sig = sig
-        self._buf.append((batch, batch_time))
-        self._seqs.append(_trace.current_batch())
-        if len(self._buf) >= self.k:
-            self._close_group()
-
-    def _emit_group(self) -> None:
-        from ..models.base import StepOutput
-
-        future, group, outs, lease, seqs = self._inflight.pop(0)
-        tr = _trace.get()
-        try:
-            # the scheduler's wait for the oldest in-flight group (see
-            # FetchPipeline._emit_one)
-            with tr.batch_scope(seqs[0]), (
-                _trace.NULL_SPAN if future.done()
-                else tr.span("deliver_wait", group=len(group))
-            ):
-                host = self._watchdog.await_result(
-                    future,
-                    lambda: self._pool.submit(
-                        self._timed_fetch_many, outs, len(group), seqs[0]
-                    ),
-                )
-        except FetchAbort:
-            # the group trained but its outputs are gone with the wedged
-            # transport: refund the cap slots so every dispatched batch is
-            # either delivered to the handler or refunded (flush refunds
-            # the remaining in-flight groups the same way); the wire
-            # buffer's arena lease is discarded, never reused — the
-            # dispatch may still execute on the wedged backend
-            if lease is not None:
-                lease.discard()
-            for _ in group:
-                self.refund_dispatch()
-            raise
-        last = len(group) - 1
-        # _buf is provably empty at every emit site, so the pipeline being
-        # drained is the whole weights-current condition
-        boundary_ok = not self._inflight
-        for k, (batch, t) in enumerate(group):
-            with tr.batch_scope(seqs[k]):
-                self.handle(
-                    # a multi-host follower's predictions field is None
-                    # (the lead owns per-row telemetry) — pass None through
-                    StepOutput(*(
-                        None if f is None else f[k] for f in host
-                    )),
-                    batch, t,
-                    at_boundary=(k == last and boundary_ok),
-                )
-        if lease is not None:
-            # fetch delivered ⇒ the dispatch consumed its wire bytes;
-            # retired AFTER the handlers (the lease may chain the group
-            # batches' featurize-stage arrays — see FetchPipeline)
-            lease.retire()
-
-    def _timed_fetch_many(self, outs, group_len: int, seq=None):
-        """Timed pooled group fetch — see FetchPipeline._timed_fetch
-        (``seq``: the batch id of the group's first batch)."""
-        return self._timed(
-            self._fetch_many, outs, seq,
-            depth=self.fetch_depth, group=group_len,
-        )
-
-    def _timed_fetch_one(self, out_dev, seq=None):
-        """Single-batch pooled fetch (the partial-group path), timed like
-        ``_timed_fetch_many``."""
-        return self._timed(self._fetch_one, out_dev, seq, depth=1)
-
-    def _timed(self, fetch, out, seq, **span_args):
-        import time as _time
-
-        import jax
-
-        tr = _trace.get()
-        t0 = _time.perf_counter()
-        with tr.batch_scope(seq), tr.span("fetch", **span_args):
-            _faults.perturb("fetch")  # --chaos: inside the timed window,
-            # so injected stalls feed the health monitor like real ones
-            host = (fetch or jax.device_get)(out)
-        dt = _time.perf_counter() - t0
-        self._fetch_count.inc()
-        self._fetch_hist.observe(dt)
-        self._health.observe(dt)
-        _sideband.record_stage("fetch", dt)
-        return host
-
-    def refund_dispatch(self) -> None:
-        """Give back one ``max_dispatch`` slot (multi-host globally-empty
-        batches — see FetchPipeline.refund_dispatch)."""
-        self._dispatched -= 1
-        self._refund_count.inc()
-
-    def _drain(self) -> None:
-        while self._inflight:
-            self._emit_group()
-
-    def drain(self) -> None:
-        """Deliver every in-flight group NOW without dispatching more —
-        the elastic membership plane calls this before a group re-forms
-        (nothing may stay in flight across a backend rebuild; buffered
-        undispatched batches are host-side and survive untouched)."""
-        self._drain()
-
-    def drain_discard(self, why: str) -> int:
-        """Rescue-path drain (elastic detach, ``clean=False``): a peer
-        died mid-step, so in-flight groups' collectives are POISONED —
-        see FetchPipeline.drain_discard. Discards every in-flight group
-        (cap slots refunded, leases discarded, rows counted in
-        ``elastic.rows_discarded_inflight``); buffered UNDISPATCHED
-        batches stay — they are host-side, never touched a collective,
-        and train correctly against the rolled-back state after the
-        reform. Returns the discarded row count."""
-        if not self._inflight:
-            return 0
-        groups, rows = len(self._inflight), 0
-        for future, group, _outs, lease, _seqs in self._inflight:
-            future.cancel()  # not-yet-started fetches never run
-            for batch, _t in group:
-                rows += int(getattr(batch, "num_valid", 0) or 0)
-                self.refund_dispatch()
-            if lease is not None:
-                lease.discard()  # the dead-peer dispatch may still run
-        self._inflight.clear()
-        self._depth_gauge.set(0)
-        self._registry.counter("elastic.rows_discarded_inflight").inc(rows)
-        log.warning(
-            "elastic rescue: discarded %d in-flight group(s) (~%d row(s))"
-            " — %s; the resync restores the verified checkpoint, so these"
-            " rolled-back rows are counted in "
-            "elastic.rows_discarded_inflight, never awaited", groups, rows,
-            why,
-        )
-        return rows
-
-    def _coalesce(self, batch) -> bool:
-        """Whether this batch rides the coalesced one-buffer wire (group
-        mode, ragged wire, and a model whose jit program unpacks it)."""
-        from ..features.batch import RaggedUnitBatch
-
-        return (
-            self.wire_pack == "group"
-            and isinstance(batch, RaggedUnitBatch)
-            and getattr(self.model, "accepts_packed", False)
-        )
-
-    def _group_wire(self, batches):
-        """The step_many wire for one full group: the coalesced one-buffer
-        pack (ONE main-thread put; uint16-delta offsets) in group mode, the
-        stacked K-per-field arrays otherwise — bit-identical math either
-        way (tests/test_superwire.py)."""
-        from ..features.batch import (
-            pack_ragged_group, stack_batches, wire_nbytes,
-        )
-        import time as _time
-
-        t0 = _time.perf_counter()
-        if not self._coalesce(batches[0]):
-            wire = stack_batches(batches)
-            _sideband.record_stage("wire_pack", _time.perf_counter() - t0)
-            return wire
-        packer = self._group_packer or (
-            lambda bs: pack_ragged_group(bs, codec=self.wire_codec or None)
-        )
-        tr = _trace.get()
-        if tr.enabled:
-            with tr.span(
-                "wire_pack", mode="group", batches=len(batches)
-            ) as sp:
-                wire = packer(batches)
-                sp.add(wire_bytes=wire_nbytes(wire))
-        else:
-            wire = packer(batches)
-        _record_wire_codec(wire, self._codec_requested())
-        _sideband.record_stage("wire_pack", _time.perf_counter() - t0)
-        return wire
-
-    def _codec_requested(self) -> str:
-        """The codec this batcher's wire is SUPPOSED to carry — the
-        pipeline-level setting for the plain packers, the model's own
-        attribute for model-aware packers (they pack with it directly)."""
-        if self._group_packer or self._single_packer:
-            return getattr(self.model, "wire_codec", "") or ""
-        return self.wire_codec
-
-    def _close_group(self) -> None:
-        if not self._buf:
-            return
-        group, self._buf = self._buf, []
-        seqs, self._seqs = self._seqs, []
-        if len(group) < self.k:
-            # partial group (tail, or a shape change): plain steps — the
-            # same math, and no fresh scan compile for a one-off length.
-            # Earlier groups must emit first (strict batch order), and the
-            # max_dispatch cap binds here exactly like on full groups.
-            # In group mode the singles still ride the k=1 one-buffer wire
-            # (pack_for_wire / pack_batch), so a partial tail keeps the
-            # coalesced layout's lean offsets.
-            self._drain()
-            tr = _trace.get()
-            for (batch, t), seq in zip(group, seqs):
-                if self.max_dispatch and self._dispatched >= self.max_dispatch:
-                    return
-                import time as _time
-
-                wire = batch
-                if self._coalesce(batch):
-                    from ..features.batch import pack_batch
-
-                    packer = self._single_packer or (
-                        lambda b: pack_batch(
-                            b, codec=self.wire_codec or None
-                        )
-                    )
-                    t0 = _time.perf_counter()
-                    if tr.enabled:
-                        with tr.span("wire_pack", mode="single"):
-                            wire = packer(batch)
-                    else:
-                        wire = packer(batch)
-                    _sideband.record_stage(
-                        "wire_pack", _time.perf_counter() - t0
-                    )
-                    _record_wire_codec(wire, self._codec_requested())
-                t0 = _time.perf_counter()
-                with tr.batch_scope(seq), tr.span(
-                    "dispatch",
-                    signature=lambda: wire_signature(wire, batch),
-                ):
-                    _faults.perturb("step")  # --chaos dispatch injection
-                    out_dev = self.model.step(wire)
-                dt = _time.perf_counter() - t0
-                _sideband.record_stage("dispatch", dt)
-                _lineage.mark_dispatch()
-                # dispatch-time accounting, as on the grouped path; if the
-                # awaited fetch aborts, the slot is refunded (the batch
-                # trained but was never delivered — cap accounting follows
-                # deliveries, same rule as _emit_group/flush)
-                self._dispatched += 1
-                self._cadence += 1
-                # same watchdog as the pooled paths (the fetch rides the
-                # pool so the deadline can fire; awaited immediately, so
-                # the partial path stays effectively synchronous)
-                lease = _dispatch_lease(wire, batch)
-                try:
-                    out = self._watchdog.await_result(
-                        self._pool.submit(
-                            self._timed_fetch_one, out_dev, seq
-                        ),
-                        lambda: self._pool.submit(
-                            self._timed_fetch_one, out_dev, seq
-                        ),
-                    )
-                except FetchAbort:
-                    if lease is not None:
-                        lease.discard()  # wedged dispatch: no reuse
-                    self.refund_dispatch()
-                    raise
-                with tr.batch_scope(seq):
-                    self.handle(out, batch, t, at_boundary=True)
-                if lease is not None:
-                    lease.retire()  # after the handler — see _emit_one
-            return
-        # backpressure + timeliness, as in FetchPipeline (the already-done
-        # probe is wall-clock-dependent, so deterministic/multi-host mode
-        # skips it — emits then happen only at counter-driven points)
-        while len(self._inflight) >= self.fetch_depth or (
-            not self.deterministic
-            and self._inflight and self._inflight[0][0].done()
-        ):
-            self._emit_group()
-        wire = self._group_wire([b for b, _ in group])
-        import time as _time
-
-        tr = _trace.get()
-        t0 = _time.perf_counter()
-        # one dispatch for the whole group: it carries its first batch's id
-        with tr.batch_scope(seqs[0]), tr.span(
-            "dispatch", group=len(group), depth=len(self._inflight),
-            signature=lambda: wire_signature(wire, group[0][0]),
-        ):
-            _faults.perturb("step")  # --chaos dispatch injection
-            outs = self.model.step_many(wire)
-        dt = _time.perf_counter() - t0
-        _sideband.record_stage("dispatch", dt)
-        _lineage.mark_dispatch(len(group))
-        self._inflight.append(
-            (self._pool.submit(
-                self._timed_fetch_many, outs, len(group), seqs[0]),
-             group, outs, _dispatch_lease(wire, *(b for b, _ in group)),
-             seqs)
-        )
-        self._depth_gauge.set(len(self._inflight))
-        self._dispatched += len(group)
-        self._cadence += len(group)
-        if self.boundary_every and (
-            self._cadence - self._last_boundary >= self.boundary_every
-        ):
-            self._drain()  # cadence point: weights current for checkpoints
-            self._last_boundary = self._cadence
-
-    def flush(self) -> None:
-        try:
-            self._close_group()  # a partial tail drains inflight itself
-            self._drain()
-        except FetchAbort:
-            # already logged + the abort hook fired; the app's shutdown
-            # path owns the final checkpoint flush — never raise into it
-            if self._inflight or self._buf:
-                # refund the dispatched-but-undelivered batches riding the
-                # dropped in-flight groups (they trained, but their outputs
-                # are gone with the wedged transport — cap accounting follows
-                # deliveries; buffered batches never dispatched, nothing to
-                # refund there)
-                for _future, group, _outs, lease, _seqs in self._inflight:
-                    if lease is not None:
-                        lease.discard()  # wedged dispatches: no reuse
-                    for _ in group:
-                        self.refund_dispatch()
-                log.warning(
-                    "dropping %d in-flight group(s) and %d buffered "
-                    "batch(es) after the fetch abort",
-                    len(self._inflight), len(self._buf),
-                )
-                self._inflight.clear()
-                self._buf.clear()
-                self._seqs.clear()
-        finally:
-            # shutdown in a finally: an exception re-raised from
-            # future.result() during the drain must not leak the executor
-            self._pool.shutdown(wait=False)
-
-
 class FetchPipeline:
     """Depth-D concurrent stats fetch for back-to-back regimes: the main
     thread dispatches ``model.step(batch)`` and hands each StepOutput's
@@ -2370,8 +1891,8 @@ class FetchPipeline:
     Semantics vs the synchronous path: per-batch stats identical and in
     order; ``at_boundary`` is True only when nothing newer has been
     dispatched (pipeline drained — end of stream, or a ``boundary_every``
-    cadence drain so checkpoint saves still see current weights, exactly
-    like the superbatch's group boundaries); ``max_dispatch`` caps how
+    cadence drain so checkpoint saves still see current weights);
+    ``max_dispatch`` caps how
     many batches may train, so max-batches stops stay EXACT (the cap is
     enforced before dispatch, not discovered after). ``flush()`` after
     stream termination drains the tail.
@@ -2491,9 +2012,13 @@ class FetchPipeline:
             except FetchAbort:
                 # the dispatch may still execute on the wedged backend:
                 # never donate its wire buffer back for reuse
-                # (features/arena.py)
+                # (features/arena.py); the batch trained but was never
+                # delivered, so its cap slot comes back (every dispatched
+                # batch is either delivered or refunded — flush and
+                # drain_discard hold the same rule)
                 if lease is not None:
                     lease.discard()
+                self.refund_dispatch()
                 raise
             self.handle(host, batch, t, at_boundary=not self._pending)
         if lease is not None:
@@ -2688,6 +2213,7 @@ class FetchPipeline:
                 for _f, _o, _b, _t, lease, _seq in self._pending:
                     if lease is not None:
                         lease.discard()  # wedged dispatches: no reuse
+                    self.refund_dispatch()  # trained, never delivered
                 self._pending.clear()
         finally:
             # shutdown in a finally: an exception re-raised from
@@ -2761,7 +2287,7 @@ def attach_elastic(conf, ssc, model, stream, ckpt, totals):
     post-reform tick doesn't stall.
 
     Returns the plane (or None when the run is not elastic); pass it to
-    ``attach_super_batcher`` so the pipeline drain hook binds."""
+    ``attach_pipeline`` so the pipeline drain hook binds."""
     import jax
 
     from ..parallel import elastic as _elastic
@@ -2789,7 +2315,7 @@ def attach_elastic(conf, ssc, model, stream, ckpt, totals):
                 "standby (residues stay with their adopters)"
             )
     st: dict = {
-        "pipeline": None, "group_k": 1,
+        "pipeline": None,
         "old_members": list(runtime.members),
     }
 
@@ -2881,7 +2407,7 @@ def attach_elastic(conf, ssc, model, stream, ckpt, totals):
         _rebalance_intake(
             source, st["old_members"], plan["members"], runtime.uid, reason,
         )
-        warmup_compile(stream, model, super_batch=st["group_k"])
+        warmup_compile(stream, model)
 
     plane = MembershipPlane(
         runtime, detach, attach,
@@ -2889,7 +2415,7 @@ def attach_elastic(conf, ssc, model, stream, ckpt, totals):
         evict_skew_ms=float(getattr(conf, "elasticEvictSkewMs", 250.0)),
         rejoin=getattr(conf, "elasticRejoin", "on") == "on",
     )
-    plane._bind_box = st  # attach_super_batcher fills st["pipeline"]
+    plane._bind_box = st  # attach_pipeline fills st["pipeline"]
     ssc.membership = plane
     log.info(
         "elastic membership plane ACTIVE: epoch %d, members %s, "
@@ -2917,36 +2443,25 @@ def elastic_exit(failed: bool = False) -> None:
     runtime.finalize_exit(1 if failed else 0)
 
 
-def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
-                         max_dispatch: int = 0, abort=None, sentinel=None,
-                         modelwatch=None, elastic=None, freshness=None):
+def attach_pipeline(conf, stream, model, handle, stop_requested=None,
+                    max_dispatch: int = 0, abort=None, sentinel=None,
+                    modelwatch=None, elastic=None, freshness=None):
     """Wire the app's per-batch ``handle(out, batch, t, at_boundary)`` to the
-    stream: plain step-then-handle by default, grouped through a
-    SuperBatcher when ``--superBatch K`` applies. Returns
-    ``(flush, effective_k)`` — the app must invoke ``flush`` after
-    termination (drains a partial final group) and may pass ``effective_k``
-    to ``warmup_compile`` so the scan program pre-compiles too.
+    stream: pack → dispatch → fetch → the delivery-wrapper chain → ``handle``.
+    Back to back (``--seconds 0``) the fetches ride a ``FetchPipeline``;
+    under a wall clock each batch is fetched synchronously. Returns
+    ``flush`` — the app must invoke it after termination (delivers what is
+    still in flight).
 
     ``at_boundary`` is True whenever the model's weights are current as of
-    this batch (always, except mid-group under a superbatch) — the guard for
-    side effects that read ``model.latest_weights``, e.g. checkpoints.
+    this batch (nothing newer dispatched) — the guard for side effects that
+    read ``model.latest_weights``, e.g. checkpoints.
 
     ``stop_requested``: optional predicate (the app's
     ``ssc.stop_requested``) that lets the fetch pipeline honor a
     max-batches stop; ``max_dispatch`` additionally caps how many batches
     may ever train (exact max-batches under the concurrent fetch pipeline
-    — see FetchPipeline).
-
-    Group-granular caps: a whole group dispatches as one program, so a
-    ``max_batches``-style stop lands on the first group boundary at/after
-    the cap (up to K−1 extra batches, deterministic — the documented
-    trade of the flag).
-
-    The flag applies only to back-to-back regimes (``--seconds 0``): under a
-    wall clock it would delay live telemetry by K intervals, so it downgrades
-    with a warning. Grouped batches must share one XLA shape, which pinned
-    buckets guarantee — unpinned buckets are an error, matching the
-    pre-compile contract (``warmup_compile``)."""
+    — see FetchPipeline)."""
     import jax
 
     from ..utils.rss import RssWatchdog
@@ -3024,34 +2539,12 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
             mw_inner(out, batch, t, at_boundary=at_boundary)
 
     multihost = jax.process_count() > 1
-    k = int(getattr(conf, "superBatch", 1) or 1)
-    if k > 1 and num_tenants >= 1:
-        log.warning(
-            "--superBatch %d ignored with --tenants %d: the tenant stack "
-            "already amortizes the per-tick stats fetch across its %d "
-            "models (scanning K groups of M tenants is future work)",
-            k, num_tenants, num_tenants,
-        )
-        k = 1
-    if k > 1 and conf.seconds > 0:
-        log.warning(
-            "--superBatch %d ignored: wall-clock streaming (--seconds %s) "
-            "would delay live stats by %d intervals", k, conf.seconds, k,
-        )
-        k = 1
-    if k > 1 and (stream.row_bucket <= 0 or stream.token_bucket <= 0):
-        raise ValueError(
-            "--superBatch needs pinned shapes: set --batchBucket and "
-            "--tokenBucket so every grouped batch compiles to one program"
-        )
     if multihost and (stream.row_bucket <= 0 or stream.token_bucket <= 0):
         raise SystemExit(
             "multi-host runs need pinned shapes: set --batchBucket and "
             "--tokenBucket (every host must dispatch the same collective "
             "program every tick, including all-padding batches)"
         )
-    if elastic is not None:
-        elastic._bind_box["group_k"] = k  # reform warmup re-compiles k too
 
     def skip_empty(fn):
         if multihost:
@@ -3121,7 +2614,7 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
 
     # cadence drains exist for checkpoint saves only: without a
     # checkpointDir each drain would stall the fetch pipelining for a
-    # no-op save (one rule for both the k=1 and superbatch paths)
+    # no-op save
     boundary_every = (
         int(getattr(conf, "checkpointEvery", 0) or 0)
         if getattr(conf, "checkpointDir", "")
@@ -3143,7 +2636,7 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
         model, "accepts_packed", False
     )
     # compressed units wire (--wireCodec dict, r15): rides exactly the
-    # packed wire forms (pack_batch / the coalesced group wire / the mesh
+    # packed wire forms (pack_batch / the coalesced tenant wire / the mesh
     # per-shard packs — compression compounds the per-array-overhead trap
     # that made packing the lean-wire default). Model-aware packers carry
     # their own wire_codec attribute (set in build_model / from_conf);
@@ -3153,133 +2646,94 @@ def attach_super_batcher(conf, stream, model, handle, stop_requested=None,
         _codec = getattr(conf, "effective_wire_codec", lambda: "off")()
         wire_codec = _codec if _codec == "dict" else ""
 
-    if k <= 1:
-        if conf.seconds <= 0:
-            # back-to-back: concurrent in-order stats fetches overlap
-            # their latencies (FetchPipeline; what depth 8 buys on this
-            # machine is not measured, ROADMAP S3); checkpoint cadence
-            # points drain the pipeline
-            # so saves see current weights. Multi-host runs emit only at
-            # deterministic points so stop/refund side effects land on the
-            # same tick on every lockstep host.
-            pipe = FetchPipeline(
-                model, handle, stop_requested=stop_requested,
-                boundary_every=boundary_every,
-                max_dispatch=max_dispatch,
-                pack=pack,
-                deterministic=multihost,
-                abort=abort,
-                wire_codec=wire_codec,
+    if conf.seconds <= 0:
+        # back-to-back: concurrent in-order stats fetches overlap their
+        # latencies (FetchPipeline; what depth 8 buys on this machine is
+        # not measured, ROADMAP S3); checkpoint cadence points drain the
+        # pipeline so saves see current weights. Multi-host runs emit only
+        # at deterministic points so stop/refund side effects land on the
+        # same tick on every lockstep host.
+        pipe = FetchPipeline(
+            model, handle, stop_requested=stop_requested,
+            boundary_every=boundary_every,
+            max_dispatch=max_dispatch,
+            pack=pack,
+            deterministic=multihost,
+            abort=abort,
+            wire_codec=wire_codec,
+        )
+        if multihost:
+            pipeline_ref.append(pipe)  # empty-batch refunds (above)
+        if sentinel is not None:
+            sentinel.bind(pipe)  # skipped batches refund their cap slot
+        if elastic is not None:
+            elastic._bind_box["pipeline"] = pipe  # reform drain hook
+        stream.foreach_batch(skip_empty(pipe.on_batch))
+        return pipe.flush
+
+    def per_batch(batch, t):
+        # wall-clock streaming: ONE synchronous host transfer for the
+        # whole StepOutput (sequential scalar fetches each pay a full
+        # round trip). The fetch is ~2% of a 5 s interval; a lagged
+        # fetch here would delay live dashboard stats a full interval
+        # for nothing.
+        import time as _time
+
+        tr = _trace.get()
+        if pack:
+            from ..features.batch import pack_batch
+
+            packer = getattr(model, "pack_for_wire", None) or (
+                lambda b: pack_batch(b, codec=wire_codec or None)
             )
-            if multihost:
-                pipeline_ref.append(pipe)  # empty-batch refunds (above)
-            if sentinel is not None:
-                sentinel.bind(pipe)  # skipped batches refund their cap slot
-            if elastic is not None:
-                elastic._bind_box["pipeline"] = pipe  # reform drain hook
-            stream.foreach_batch(skip_empty(pipe.on_batch))
-            return pipe.flush, 1
-
-        def per_batch(batch, t):
-            # wall-clock streaming: ONE synchronous host transfer for the
-            # whole StepOutput (sequential scalar fetches each pay a full
-            # round trip). The fetch is ~2% of a 5 s interval; a lagged
-            # fetch here would delay live dashboard stats a full interval
-            # for nothing.
-            import time as _time
-
-            tr = _trace.get()
-            if pack:
-                from ..features.batch import pack_batch
-
-                packer = getattr(model, "pack_for_wire", None) or (
-                    lambda b: pack_batch(b, codec=wire_codec or None)
-                )
-                tp = _time.perf_counter()
-                if tr.enabled:
-                    with tr.span("wire_pack", mode="single"):
-                        wire = packer(batch)
-                else:
+            tp = _time.perf_counter()
+            if tr.enabled:
+                with tr.span("wire_pack", mode="single"):
                     wire = packer(batch)
-                _sideband.record_stage(
-                    "wire_pack", _time.perf_counter() - tp
-                )
-                _record_wire_codec(
-                    wire,
-                    (getattr(model, "wire_codec", "") or "")
-                    if getattr(model, "pack_for_wire", None)
-                    else wire_codec,
-                )
             else:
-                wire = batch
-            lease = _dispatch_lease(wire, batch)
-            td = _time.perf_counter()
-            with tr.span("dispatch",
-                         signature=lambda: wire_signature(wire, batch)):
-                _faults.perturb("step")  # --chaos dispatch injection
-                out = model.step(wire)
-            d_dt = _time.perf_counter() - td
-            _sideband.record_stage("dispatch", d_dt)
-            _lineage.mark_dispatch()
-            fetch = getattr(model, "fetch_output", None) or jax.device_get
-            t0 = _time.perf_counter()
-            with tr.span("fetch", depth=1):
-                _faults.perturb("fetch")
-                out = fetch(out)
-            dt = _time.perf_counter() - t0
-            reg = _metrics.get_registry()
-            reg.counter("fetch.count").inc()
-            reg.histogram("fetch.latency_s").observe(dt)
-            _metrics.get_health_monitor().observe(dt)
-            _sideband.record_stage("fetch", dt)
-            handle(out, batch, t, at_boundary=True)
-            if lease is not None:
-                lease.retire()  # synchronous fetch: dispatch consumed it
-                # (after the handler — the lease may chain the batch's
-                # featurize-stage arrays, r18)
+                wire = packer(batch)
+            _sideband.record_stage(
+                "wire_pack", _time.perf_counter() - tp
+            )
+            _record_wire_codec(
+                wire,
+                (getattr(model, "wire_codec", "") or "")
+                if getattr(model, "pack_for_wire", None)
+                else wire_codec,
+            )
+        else:
+            wire = batch
+        lease = _dispatch_lease(wire, batch)
+        td = _time.perf_counter()
+        with tr.span("dispatch",
+                     signature=lambda: wire_signature(wire, batch)):
+            _faults.perturb("step")  # --chaos dispatch injection
+            out = model.step(wire)
+        d_dt = _time.perf_counter() - td
+        _sideband.record_stage("dispatch", d_dt)
+        _lineage.mark_dispatch()
+        fetch = getattr(model, "fetch_output", None) or jax.device_get
+        t0 = _time.perf_counter()
+        with tr.span("fetch", depth=1):
+            _faults.perturb("fetch")
+            out = fetch(out)
+        dt = _time.perf_counter() - t0
+        reg = _metrics.get_registry()
+        reg.counter("fetch.count").inc()
+        reg.histogram("fetch.latency_s").observe(dt)
+        _metrics.get_health_monitor().observe(dt)
+        _sideband.record_stage("fetch", dt)
+        handle(out, batch, t, at_boundary=True)
+        if lease is not None:
+            lease.retire()  # synchronous fetch: dispatch consumed it
+            # (after the handler — the lease may chain the batch's
+            # featurize-stage arrays, r18)
 
-        stream.foreach_batch(skip_empty(per_batch))
-        return (lambda: None), 1
-
-    batcher = SuperBatcher(
-        model, k, handle,
-        boundary_every=boundary_every,
-        max_dispatch=max_dispatch,
-        deterministic=multihost,
-        abort=abort,
-        # the coalesced one-buffer group wire applies exactly where the
-        # k=1 pack does (ragged wire + a model that unpacks in-jit);
-        # --wirePack auto resolves in config.effective_wire_pack
-        wire_pack=(
-            "group"
-            if pack and getattr(
-                conf, "effective_wire_pack", lambda: "stacked"
-            )() == "group"
-            else "stacked"
-        ),
-        wire_codec=wire_codec,
-    )
-    if multihost:
-        pipeline_ref.append(batcher)  # empty-batch refunds (above)
-    if sentinel is not None:
-        sentinel.bind(batcher)  # skipped batches refund their cap slot
-    if elastic is not None:
-        elastic._bind_box["pipeline"] = batcher  # reform drain hook
-    # grouping needs every batch in its FINAL layout before the shape
-    # signature/stacking: mesh and multi-host models shard-align ragged
-    # batches (and harmonize the wire dtype across hosts) in prepare()
-    prepare = getattr(model, "prepare", None)
-    if prepare is None:
-        on_batch = batcher.on_batch
-    else:
-        def on_batch(batch, t):
-            batcher.on_batch(prepare(batch), t)
-
-    stream.foreach_batch(skip_empty(on_batch))
-    return batcher.flush, k
+    stream.foreach_batch(skip_empty(per_batch))
+    return lambda: None
 
 
-def warmup_compile(stream, model, super_batch: int = 1) -> None:
+def warmup_compile(stream, model) -> None:
     """Pre-compile the step for the known batch shape BEFORE the stream
     starts, so the first wall-clock micro-batch doesn't swallow the whole
     compile-time backlog (~30 s on a cold TPU chip, during which a live
@@ -3294,13 +2748,11 @@ def warmup_compile(stream, model, super_batch: int = 1) -> None:
         return
     # what compiled in here is in the trace as ``compile`` spans with
     # ``during: warmup_compile`` (telemetry/trace.py)
-    with _trace.get().span(
-        "warmup_compile", rows=stream.row_bucket, super_batch=super_batch
-    ):
-        _warmup_compile(stream, model, super_batch)
+    with _trace.get().span("warmup_compile", rows=stream.row_bucket):
+        _warmup_compile(stream, model)
 
 
-def _warmup_compile(stream, model, super_batch: int) -> None:
+def _warmup_compile(stream, model) -> None:
     import time as _time
 
     import numpy as np
@@ -3331,13 +2783,6 @@ def _warmup_compile(stream, model, super_batch: int) -> None:
         variants.append(empty._replace(units=empty.units.astype(np.uint16)))
     for v in variants:
         model.step(v)
-    if super_batch > 1:
-        # --superBatch dispatches a scanned program too: warm it for the
-        # same shapes/dtypes so the first full group doesn't stall
-        from ..features.batch import stack_batches
-
-        for v in variants:
-            model.step_many(stack_batches([v] * super_batch))
     log.info(
         "pre-compiled the train step for buckets (%d, %d) in %.1fs",
         stream.row_bucket, stream.token_bucket, _time.perf_counter() - t0,

@@ -28,7 +28,7 @@ from ..utils import get_logger, round_half_up
 from .common import (  # noqa: F401
     AppCheckpoint,
     ProcessRecycler,
-    attach_super_batcher,
+    attach_pipeline,
     build_model,
     build_source,
     init_distributed,
@@ -182,7 +182,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
 
     elastic_plane = attach_elastic(conf, ssc, model, stream, ckpt, totals)
 
-    flush_group, group_k = attach_super_batcher(
+    flush = attach_pipeline(
         conf, stream, model, handle,
         stop_requested=lambda: ssc.stop_requested,
         max_dispatch=(
@@ -195,7 +195,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
         freshness=freshness_guard,
     )
 
-    warmup_compile(stream, model, super_batch=group_k)
+    warmup_compile(stream, model)
 
     log.info("Starting the streaming computation...")
     tracer.start()
@@ -209,7 +209,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
         pass
     finally:
         ssc.stop()
-        flush_group()  # drain a partial superbatch group before final state
+        flush()  # deliver what is still in flight before final state
         # the post-warmup streaming window (start → last batch drained):
         # what a steady-state rate should be computed over — session init,
         # model build, and the warmup compile are startup, not streaming

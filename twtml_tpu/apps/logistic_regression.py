@@ -32,7 +32,7 @@ from .common import (
     AppCheckpoint,
     DivergenceSentinel,
     ProcessRecycler,
-    attach_super_batcher,
+    attach_pipeline,
     build_model,
     build_source,
     init_distributed,
@@ -158,7 +158,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
 
     elastic_plane = attach_elastic(conf, ssc, model, stream, ckpt, totals)
 
-    flush_group, group_k = attach_super_batcher(
+    flush = attach_pipeline(
         conf, stream, model, handle,
         stop_requested=lambda: ssc.stop_requested,
         max_dispatch=(
@@ -170,7 +170,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
         elastic=elastic_plane,
         freshness=freshness_guard,
     )
-    warmup_compile(stream, model, super_batch=group_k)
+    warmup_compile(stream, model)
     ssc.start(lockstep=lockstep)
     try:
         ssc.await_termination()
@@ -178,7 +178,7 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
         pass
     finally:
         ssc.stop()
-        flush_group()  # drain a partial superbatch group
+        flush()  # deliver what is still in flight
         if session is not None:
             session.publish_metrics()  # final dashboard-panel snapshot
         from ..telemetry import trace as pipeline_trace

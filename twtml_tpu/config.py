@@ -187,7 +187,6 @@ class ConfArguments:
         self.faultEvery: int = int(conf.get("faultEvery", "0"))
         self.chaos: str = conf.get("chaos", "")
         self.webTimeout: float = float(conf.get("webTimeout", "2.0"))
-        self.superBatch: int = int(conf.get("superBatch", "1"))
         self.wirePack: str = conf.get("wirePack", "auto")
         if self.wirePack not in ("auto", "stacked", "group"):
             raise ValueError(
@@ -408,7 +407,7 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                (replay source only). Default: {self.ingest}
   --wire <auto|padded|ragged>                  Units wire format: ragged ships concatenated
                                                units + offsets (no pad bytes, on every layout —
-                                               packed, sharded, superbatched), padded ships
+                                               packed, sharded, tenant-stacked), padded ships
                                                a [B, L] buffer.
                                                auto = ragged for hashOn=device back-to-back
                                                runs (--seconds 0); padded for wall-clock
@@ -492,10 +491,6 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                single-host; resume is exact). 0 = off. Made for
                                                host memory that grows with uploaded bytes
                                                (tools/soak.py measures the slope)
-  --superBatch <int>                           Replay-mode superbatch: K micro-batches per device
-                                               dispatch (one scan, one stats fetch; per-batch
-                                               stats preserved; stops/checkpoints land on group
-                                               boundaries). Default: {self.superBatch}
   --elastic <off|on>                           Elastic lockstep membership: a dead or evicted
                                                host SHRINKS the multi-host group (survivors
                                                re-form at an epoch boundary, restore the lead's
@@ -521,10 +516,9 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                (per-topic/per-language/per-A/B-arm) in ONE
                                                jit program — rows route to tenants on the
                                                host, the M per-tenant batches ship as one
-                                               shared wire (the K-batch superbatch wire
-                                               reused as the K-tenant wire; dry tenants ride
-                                               all-padding batches), and all M tenants'
-                                               stats come back in ONE stacked fetch.
+                                               shared wire (stacked or coalesced, --wirePack;
+                                               dry tenants ride all-padding batches), and all
+                                               M tenants' stats come back in ONE stacked fetch.
                                                Per-tenant semantics stay byte-identical to
                                                the single-model path. Default: {self.tenants}
   --tenantKey <hash|lang>                      Tenant routing key: 'hash' = deterministic
@@ -662,11 +656,11 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                key sticks to one replica and only ~1/N of
                                                keys move on membership change.
                                                Default: {self.routePolicy}
-  --wirePack <auto|stacked|group>              Superbatch wire layout on the ragged wire:
-                                               'group' coalesces the K batches into ONE
+  --wirePack <auto|stacked|group>              Tenant wire layout (--tenants) on the ragged wire:
+                                               'group' coalesces the M tenant batches into ONE
                                                contiguous buffer (one put; uint16-delta offsets)
-                                               unpacked inside the scanned program; 'stacked'
-                                               ships K per-field arrays. auto = stacked until
+                                               unpacked inside the tenant program; 'stacked'
+                                               ships M per-field arrays. auto = stacked until
                                                an on-chip paired verdict (ROADMAP S3;
                                                bit-identical
                                                features either way).
@@ -680,7 +674,7 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                Applies to the packed wire forms; non-ASCII
                                                (uint16) units and incompressible batches ship
                                                raw, counted in wire.codec_fallbacks. With
-                                               --superBatch, 'dict' + --wirePack auto resolves
+                                               --tenants, 'dict' + --wirePack auto resolves
                                                the group (coalesced) wire. auto = off until an
                                                on-chip paired verdict (ROADMAP S3).
                                                Default: {self.wireCodec}
@@ -834,8 +828,6 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
             self.blackbox = take()
             if self.blackbox not in ("on", "off"):
                 self.printUsage(1)
-        elif flag == "--superBatch":
-            self.superBatch = int(take())
         elif flag == "--wirePack":
             self.wirePack = take()
             if self.wirePack not in ("auto", "stacked", "group"):
@@ -986,21 +978,20 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
         return self.effective_wire() == "ragged"
 
     def effective_wire_pack(self) -> str:
-        """Resolve ``--wirePack auto`` to the default superbatch
-        wire layout. The coalesced group wire (one contiguous buffer per K
-        batches, uint16-delta offsets) is bit-identical to the stacked wire
-        and ships one large transfer where the stacked wire ships K sets
-        of per-field arrays, but the house rule — measure in the target
-        regime before shipping a wire/dispatch change — holds the default
-        at STACKED until an on-chip paired bench clears it
-        (tools/bench_superwire.py; ROADMAP S3 — the CPU control is
-        wire-insensitive by design).
+        """Resolve ``--wirePack auto`` to the default tenant-stack
+        wire layout. The coalesced group wire (one contiguous buffer per M
+        tenant batches, uint16-delta offsets) is bit-identical to the
+        stacked wire and ships one large transfer where the stacked wire
+        ships M sets of per-field arrays, but the house rule — measure in
+        the target regime before shipping a wire/dispatch change — holds
+        the default at STACKED until an on-chip paired run clears it
+        (ROADMAP D11).
         Explicit ``--wirePack group``/``stacked`` always wins — except the
         contradictory ``--wirePack stacked --wireCodec dict``, which is
         rejected below: the codec lives on the PACKED wire forms
         (compression compounds the per-array-overhead trap that made
-        packing the lean-wire default), so a stacked superbatch wire would
-        silently ship the group's batches uncompressed."""
+        packing the lean-wire default), so a stacked tenant wire would
+        silently ship the tenants' batches uncompressed."""
         if self.effective_wire_codec() == "dict":
             if self.wirePack == "stacked":
                 raise ValueError(
@@ -1062,11 +1053,10 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
     def effective_max_queue_rows(self) -> int:
         """Resolve ``--maxQueueRows``: explicit > 0 wins; 0 (the default)
         sizes the bound from the batch size — 8 pinned row buckets is deep
-        enough that the fill gate and a ``--superBatch`` group never
-        starve, shallow enough that a stalled consumer bounds host RSS at
-        ~8 batches of parsed rows. Without a pinned bucket there is no
-        batch size to derive from, so 0 stays unbounded (as does an
-        explicit -1)."""
+        enough that the fill gate never starves, shallow enough that a
+        stalled consumer bounds host RSS at ~8 batches of parsed rows.
+        Without a pinned bucket there is no batch size to derive from, so
+        0 stays unbounded (as does an explicit -1)."""
         if self.maxQueueRows > 0:
             return self.maxQueueRows
         if self.maxQueueRows < 0:

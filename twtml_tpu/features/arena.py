@@ -9,7 +9,7 @@ the slope). The arena is a
 size-bucketed free list of uint8 buffers: the wire assembler (or the
 numpy fallback's ``np.concatenate(..., out=)``) writes into a LEASED
 buffer, ``device_put`` uploads it, and the lease retires back to the pool
-when the FetchPipeline/SuperBatcher delivers (or refunds) the
+when the FetchPipeline delivers (or refunds) the
 corresponding dispatch — by which point the step has executed and nothing
 can alias the bytes (a ``device_get`` completing is the proof the
 dispatch consumed its inputs; retiring at pack/dispatch time would race

@@ -797,17 +797,18 @@ def pack_ragged_group(
     codec_bucket: "int | None" = None,
 ) -> PackedBatch:
     """K same-signature ragged batches → ONE contiguous uint8 wire buffer
-    (the coalesced superbatch wire, Lean wire v2).
+    (the coalesced tenant wire, ``--wirePack group``: K = M tenants,
+    parallel/tenants.py).
 
-    Why: the stacked superbatch wire ships K separate sets of per-field
-    arrays — K×5 small puts where one large coalesced put pays the
-    per-transfer cost once. The K batches' five fields
+    Why: the stacked tenant wire (``stack_batches``) ships K separate sets
+    of per-field arrays — K×5 small puts where one large coalesced put
+    pays the per-transfer cost once. The K batches' five fields
     flatten into one buffer with a STATIC per-group layout, uploaded by
-    ONE main-thread ``device_put`` (rides the step_many dispatch), and the
+    ONE main-thread ``device_put`` (rides the step dispatch), and the
     in-jit unpack (``_unpack_ragged_group``) slices the K segments back
-    into the stacked [K, ...] leaves the existing scanned K-step program
-    consumes — bit-identical features, differential-tested against the
-    K-separate-wires path (tests/test_superwire.py).
+    into the stacked [K, ...] leaves the tenant program maps over —
+    bit-identical to ``stack_batches`` of the same batches, leaf for leaf
+    (the wire law, tests/test_superwire.py).
 
     Layout: the buffer is laid out SHARD-MAJOR, [S, K, per-segment bytes]
     flattened — ``P(data)`` on the one buffer then hands each device its
@@ -817,8 +818,8 @@ def pack_ragged_group(
     the same static ``row_len`` gate as ``pack_ragged_sharded``.
 
     All batches must share one wire signature (shapes, dtypes, row_len,
-    shard alignment) — the SuperBatcher's signature grouping guarantees
-    this, so each distinct (signature, K) compiles exactly one program.
+    shard alignment) — the tenant split emits M same-signature batches,
+    so each distinct (signature, K) compiles exactly one program.
     ``num_shards_out`` mirrors ``pack_ragged_sharded`` (multi-host callers
     pack local shards, the layout carries the global count); ``codec``
     mirrors it too (per-segment digram compression, shared bucket,
@@ -1082,8 +1083,8 @@ def unpack_batch(buffer, layout: tuple):
 # The tenant plane splits one featurized batch's VALID rows into M per-tenant
 # batches of the SAME padded shape (one wire signature — the lockstep
 # invariant extended to tenants: dry tenants ship all-padding batches so the
-# collective/jit program is identical every tick), then reuses the K-batch
-# superbatch wire (stack_batches / pack_ragged_group) as the K-tenant wire.
+# collective/jit program is identical every tick), then ships them as the
+# stacked / coalesced tenant wire (stack_batches / pack_ragged_group).
 # Routing is a pure deterministic function of the batch, so the delivery-side
 # split (per-tenant stats, prediction re-ordering) recomputes it instead of
 # carrying a permutation through the fetch pipeline.
@@ -1239,12 +1240,11 @@ def split_batch_tenants(batch, tenant_ids: np.ndarray, num_tenants: int):
 
 def stack_batches(batches):
     """K same-shape batches → one batch whose arrays carry a leading [K]
-    axis — the superbatch wire format for ``StreamingSGDModel.step_many``
-    (one transfer + one dispatch per K micro-batches). All batches must
-    share type, shapes, and dtypes (the padded-bucket contract guarantees
-    this within a stream; ragged batches additionally share their
-    data-dependent units bucket — the SuperBatcher's shape signature
-    groups only batches that do)."""
+    axis — the stacked tenant wire (``--wirePack stacked``: K = M tenants,
+    one dispatch maps the step over the axis, parallel/tenants.py). All
+    batches must share type, shapes, and dtypes (the tenant split pads
+    every part to one shape; ragged parts additionally share their units
+    bucket)."""
     first = batches[0]
     for b in batches[1:]:
         if type(b) is not type(first):

@@ -24,7 +24,7 @@ class StepOutput(NamedTuple):
 
     ``quality`` (ISSUE 8, ``--modelWatch``) is the in-step model/data
     quality vector (ops/quality.QUALITY_FIELDS) — [Q] per batch, [M, Q]
-    stacked on the tenant plane, [K, Q] under a superbatch scan. It is a
+    stacked on the tenant plane. It is a
     telemetry side channel riding the existing one-fetch-per-tick
     StepOutput transfer; ``None`` (an empty pytree — the default, and the
     ``--modelWatch off`` state) keeps the step program structurally

@@ -611,9 +611,7 @@ class StreamingSGDModel:
             quality=quality,  # --modelWatch: the in-step quality side channel
         )
         # donate weights: the update happens in-place in HBM
-        self._train_step = step
         self._step = jax.jit(step, donate_argnums=0)
-        self._scan_step = None  # built on first step_many
 
     @classmethod
     def from_conf(cls, conf, **overrides):
@@ -655,38 +653,6 @@ class StreamingSGDModel:
         wire it stays an opt-in."""
         self._weights, out = self._step(self._weights, batch)
         return out
-
-    def step_many(
-        self, stacked: FeatureBatch | UnitBatch | RaggedUnitBatch | PackedBatch
-    ) -> StepOutput:
-        """K micro-batch steps as ONE dispatch — ``lax.scan`` over a stacked
-        batch (every array carries a leading [K] axis; ``stack_batches``
-        builds one from K same-shape batches, the ragged wire included —
-        its [K, N] units buffer scans like any leaf, with row_len static).
-        A stacked batch may also arrive PACKED (``pack_batch`` of the
-        stacked pytree): the scan program unpacks it in-place first, same
-        bitcast contract as ``step``.
-
-        The scan body IS ``step``'s program and the weights chain through it
-        exactly as K sequential ``step`` calls would — identical final
-        weights, and the returned StepOutput holds each micro-batch's
-        predictions/stats along axis 0, so predict-then-train ordering and
-        per-batch telemetry are preserved verbatim. What changes is the
-        wire: one transfer of K batches and one dispatch instead of K —
-        the superbatch ingest mode
-        for replay/bench regimes where the stream is ahead of the device.
-        """
-        if self._scan_step is None:
-            inner = self._train_step
-
-            def scanned(weights, wire):
-                if isinstance(wire, PackedBatch):
-                    wire = unpack_batch(wire.buffer, wire.layout)
-                return lax.scan(inner, weights, wire)
-
-            self._scan_step = jax.jit(scanned, donate_argnums=0)
-        self._weights, outs = self._scan_step(self._weights, stacked)
-        return outs
 
     def train_on(self, stream) -> None:
         """Register the fused step as a stream output (DStream.trainOn analog;

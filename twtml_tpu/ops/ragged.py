@@ -31,7 +31,7 @@ def offsets_from_deltas(deltas, num_segments: int = 1):
     first — sees the int32 wire bit-identically).
 
     Shapes: [..., S·B_s] → [..., S·(B_s+1)] (leading axes pass through —
-    a stacked [K, B] superbatch wire decodes to [K, B+1] in one call).
+    a stacked [M, B] tenant wire decodes to [M, B+1] in one call).
     Each segment's offsets start at 0 by construction
     (``ragged_wire_arrays`` / ``align_ragged_shards``), which is what makes
     the delta encoding lossless."""

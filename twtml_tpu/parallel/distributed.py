@@ -144,9 +144,9 @@ def _ragged_local_aligned_codec(
             # already local-aligned: on the multi-host path the only
             # producer of this layout is a prior call of this function,
             # whose per-shard capacity IS the agreed bucket — skip the
-            # re-allgather (the superbatch partial-group step would
-            # otherwise pay one redundant DCN round trip per batch, r5
-            # review). local_shards == 1 cannot distinguish a fresh flat
+            # re-allgather (a second alignment of the same batch would
+            # otherwise pay one redundant DCN round trip, r5 review).
+            # local_shards == 1 cannot distinguish a fresh flat
             # batch from a prepared one, so that topology keeps the
             # collective.
             return batch, 0
@@ -227,13 +227,6 @@ class MultiHostSGDModel:
         # mesh-sharded model for a re-formed epoch's mesh — a closure over
         # the conf, set by apps/common.build_model
         self._rebuilder = rebuilder
-        # codec groups (r20): per-batch agreed codec buckets recorded at
-        # prepare() time (the one allgather), consumed by
-        # pack_group_for_wire. Keyed by id(batch) WITH the batch held, so
-        # ids cannot be recycled while an entry is live; entries for
-        # batches that never reach a group pack (shutdown flush) are the
-        # only residue.
-        self._group_buckets = {}
 
     def rebuild(self, mesh) -> "MultiHostSGDModel":
         """Swap in a fresh inner model on a NEW epoch's mesh IN PLACE —
@@ -267,13 +260,10 @@ class MultiHostSGDModel:
 
     # the ragged wire packs per shard on multi-host too (pack_for_wire);
     # the app-side pack opt-in keys off this (apps/common.py).
-    # --wireCodec dict (r16, widened to groups in r20): the cross-host
-    # compressed bucket rides the SAME alignment allgather the raw bucket
-    # already pays (_ragged_local_aligned_codec) — zero added collectives,
-    # asserted by the counted elastic acceptance test; set by
-    # apps/common.build_model. Groups (--superBatch > 1): prepare()
-    # records each batch's agreed bucket, pack_group_for_wire combines
-    # them (raw-dominates, else max) with plain arithmetic.
+    # --wireCodec dict (r16): the cross-host compressed bucket rides the
+    # SAME alignment allgather the raw bucket already pays
+    # (_ragged_local_aligned_codec) — zero added collectives, asserted by
+    # the counted elastic acceptance test; set by apps/common.build_model.
     accepts_packed = True
     wire_codec = ""
 
@@ -294,33 +284,6 @@ class MultiHostSGDModel:
             host_local_batch_to_global(local_batch, self.mesh)
         )
 
-    def prepare(self, batch):
-        """Pre-group hook (SuperBatcher calls it per batch BEFORE shape
-        signatures/stacking): harmonize the units wire dtype across hosts
-        and shard-align ragged batches to this host's local shards with the
-        cross-process agreed bucket — so every host's group signatures,
-        closure ticks, and stacked shapes are identical (the lockstep
-        contract extended to groups). Runs at the scheduler tick, a
-        deterministic point, so the agree collective always pairs.
-
-        With ``wire_codec`` set (r20, codec groups), the SAME alignment
-        allgather also agrees this batch's codec bucket — recorded here
-        and consumed by ``pack_group_for_wire``, which combines the K
-        batches' agreed buckets into the group bucket with ZERO additional
-        collectives (the agreed values are fleet-identical, so the
-        combine is plain arithmetic on every host)."""
-        if isinstance(batch, RaggedUnitBatch):
-            if self.wire_codec:
-                aligned, bucket = _ragged_local_aligned_codec(
-                    batch, self.mesh, codec=self.wire_codec
-                )
-                self._group_buckets[id(aligned)] = (aligned, bucket)
-                return aligned
-            return _ragged_local_aligned(batch, self.mesh)
-        if isinstance(batch, UnitBatch) and batch.units.dtype != np.uint16:
-            return batch._replace(units=batch.units.astype(np.uint16))
-        return batch
-
     def pack_for_wire(self, local_batch):
         """The multi-host form of the one-buffer ragged wire: align this
         host's rows to its LOCAL shard segments (agreed bucket — uniform
@@ -340,17 +303,9 @@ class MultiHostSGDModel:
                 "assemble as plain arrays"
             )
         if self.wire_codec:
-            got = self._group_buckets.pop(id(local_batch), None)
-            if got is not None:
-                # already prepared (a partial superbatch tail riding the
-                # k=1 wire): alignment AND bucket were agreed at prepare()
-                # time — no second collective, and the recorded bucket is
-                # fleet-identical by construction
-                aligned, codec_bucket = got
-            else:
-                aligned, codec_bucket = _ragged_local_aligned_codec(
-                    local_batch, self.mesh, codec=self.wire_codec
-                )
+            aligned, codec_bucket = _ragged_local_aligned_codec(
+                local_batch, self.mesh, codec=self.wire_codec
+            )
             pb = pack_ragged_sharded(
                 aligned, num_shards_out=self.num_data,
                 codec=self.wire_codec if codec_bucket else None,
@@ -367,140 +322,6 @@ class MultiHostSGDModel:
         # the local buffer's arena lease rides to the dispatch pipeline
         # (retired once the step's fetch delivers — apps/common.py)
         return PackedBatch(buf, pb.layout)._with_lease(pb._lease)
-
-    def pack_group_for_wire(self, batches):
-        """Multi-host form of the COALESCED superbatch wire: align each of
-        the K local batches to this host's LOCAL shard segments (agreed
-        bucket — uniform per-segment bytes on every host), pack them
-        shard-major into one local buffer (``pack_ragged_group``), and
-        assemble the global buffer from every process's contribution —
-        exactly the ``pack_for_wire`` assembly, K segments deep. The
-        per-process block is this host's local shards' [K, per-segment]
-        bytes, so the shard-major global layout is contiguous per process
-        and the data axis shards it like the single-group wire.
-
-        With ``wire_codec`` set (r20), each batch's cross-host agreed
-        bucket was recorded at ``prepare`` time; the group bucket is raw
-        if ANY batch agreed raw, else the max agreed bucket (covers every
-        batch's segments, and is computed from fleet-identical agreed
-        values — zero collectives at pack time)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from ..features.batch import PackedBatch, pack_ragged_group
-
-        if self.wire_codec:
-            aligned, buckets = [], []
-            for b in batches:
-                got = self._group_buckets.pop(id(b), None)
-                if got is None:
-                    # not prepared through the codec agreement (a direct
-                    # caller outside the SuperBatcher) — align raw, which
-                    # forces the whole group raw on every host identically
-                    aligned.append(_ragged_local_aligned(b, self.mesh))
-                    buckets.append(0)
-                else:
-                    aligned.append(b)
-                    buckets.append(got[1])
-            group_bucket = 0 if 0 in buckets else max(buckets)
-            pb = pack_ragged_group(
-                aligned, num_shards_out=self.num_data,
-                codec=self.wire_codec if group_bucket else None,
-                codec_bucket=group_bucket or None,
-            )
-        else:
-            aligned = [_ragged_local_aligned(b, self.mesh) for b in batches]
-            pb = pack_ragged_group(aligned, num_shards_out=self.num_data)
-        sharding = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
-        buf = jax.make_array_from_process_local_data(
-            sharding, pb.buffer,
-            (pb.buffer.shape[0] * jax.process_count(),),
-        )
-        return PackedBatch(buf, pb.layout)._with_lease(pb._lease)
-
-    def step_many(self, stacked):
-        """K-batch group over the multi-host mesh: the app pre-aligns and
-        harmonizes each LOCAL batch (``prepare``), the SuperBatcher stacks
-        K of them, and this assembles ONE global stacked batch ([K, ...]
-        leaves, rows sharded on axis 1) for the mesh scan — one dispatch
-        and one pooled stats fetch per K batches, multi-host included. A
-        PackedBatch from ``pack_group_for_wire`` is already the assembled
-        global coalesced wire — straight to the mesh scan."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from ..features.batch import PackedBatch
-        from .sharding import _pspecs_for, _stacked
-
-        if isinstance(stacked, PackedBatch):
-            return self.inner.step_many(stacked)
-
-        data_axis = self.mesh.axis_names[0]
-
-        def to_global(host_arr, spec):
-            host_arr = np.asarray(host_arr)
-            global_shape = (
-                host_arr.shape[0],
-                host_arr.shape[1] * jax.process_count(),
-            ) + host_arr.shape[2:]
-            return jax.make_array_from_process_local_data(
-                NamedSharding(self.mesh, spec), host_arr, global_shape
-            )
-
-        if isinstance(stacked, RaggedUnitBatch):
-            local_shards = self.num_data // jax.process_count()
-            if stacked.num_shards != local_shards:
-                raise ValueError(
-                    "stack prepare()-aligned batches (per-host local "
-                    "shard segments)"
-                )
-            spec = P(None, data_axis)
-            stacked = RaggedUnitBatch(
-                *(to_global(a, spec) for a in (
-                    stacked.units, stacked.offsets, stacked.numeric,
-                    stacked.label, stacked.mask,
-                )),
-                row_len=stacked.row_len,
-                num_shards=self.num_data,
-            )
-            return self.inner.step_many(stacked)
-        specs = _stacked(_pspecs_for(type(stacked), data_axis))
-        return self.inner.step_many(
-            type(stacked)(*(
-                to_global(a, s) for a, s in zip(stacked, specs)
-            ))
-        )
-
-    def fetch_output_many(self, outs):
-        """The group form of ``fetch_output``: [K]-vector global stats for
-        every host; the lead localizes its own rows' predictions for each
-        of the K batches ([K, B_local], shards sorted by their ROW offset —
-        the row axis is axis 1 of a stacked output)."""
-        from ..models.base import StepOutput
-
-        # the quality leaf (None when --modelWatch off — an empty pytree)
-        # rides the same ONE pooled transfer as the scalar stats
-        count, mse, real_stdev, pred_stdev, quality = jax.device_get(  # lawcheck: disable=TW002 -- fetch_output_many IS the counted seam: FetchPipeline installs it as _fetch_many, one pooled get per K-group tick
-            (outs.count, outs.mse, outs.real_stdev, outs.pred_stdev,
-             outs.quality)
-        )
-        preds = None
-        if self._lead:
-            shards = sorted(
-                outs.predictions.addressable_shards,
-                key=lambda s: s.index[1].start or 0,
-            )
-            for s in shards:
-                s.data.copy_to_host_async()
-            preds = np.concatenate(
-                [np.asarray(s.data) for s in shards], axis=1
-            )
-        return StepOutput(
-            predictions=preds,
-            count=count,
-            mse=mse,
-            real_stdev=real_stdev,
-            pred_stdev=pred_stdev,
-            quality=quality,
-        )
 
     def fetch_output(self, out):
         """StepOutput → host numpy, the model-aware form of

@@ -111,7 +111,7 @@ def _pspecs_for(batch_cls, data_axis: str):
 
 def _stacked(spec_tree):
     """Prepend an unsharded leading axis to every PartitionSpec — the specs
-    for a superbatch ([K, ...] leaves, K scanned on-device)."""
+    for the tenant stack's [M, ...] leaves (parallel/tenants.py)."""
     return jax.tree_util.tree_map(
         lambda s: P(*((None,) + tuple(s))),
         spec_tree,
@@ -121,14 +121,14 @@ def _stacked(spec_tree):
 
 def shard_batch(batch: FeatureBatch | UnitBatch | RaggedUnitBatch, mesh):
     """Place a host batch onto the mesh with row sharding (explicit
-    device_put so repeated steps don't re-infer layouts). Stacked
-    superbatches ([K, ...] leaves — detected by the mask rank) shard their
-    row axis the same way with K unsharded. A RaggedUnitBatch is
+    device_put so repeated steps don't re-infer layouts). The tenant
+    stack's [M, ...] leaves (detected by the mask rank) shard their row
+    axis the same way with M unsharded. A RaggedUnitBatch is
     shard-ALIGNED first (``align_ragged_shards`` — a host memcpy unless the
     featurizer already aligned it), after which every leaf row-shards over
     ``data`` like the padded wire; a STACKED ragged batch must already be
-    aligned per batch (alignment is a flat-batch operation — the grouping
-    path aligns before stacking, apps/common.py)."""
+    aligned per batch (alignment is a flat-batch operation — the tenant
+    plane aligns its parts before stacking, parallel/tenants.py)."""
     data_axis = mesh.axis_names[0]
     if isinstance(batch, RaggedUnitBatch):
         num_data = mesh.shape[data_axis]
@@ -137,7 +137,7 @@ def shard_batch(batch: FeatureBatch | UnitBatch | RaggedUnitBatch, mesh):
             if stacked:
                 raise ValueError(
                     "stacked ragged batches must be shard-aligned per "
-                    "batch before stacking (model.prepare)"
+                    "batch before stacking (align_ragged_shards)"
                 )
             batch = align_ragged_shards(batch, num_data)
         spec = P(None, data_axis) if stacked else P(data_axis)
@@ -544,8 +544,7 @@ class ParallelSGDModel:
                 quality=scalar if quality else None,
             ),
         )
-        # compiled programs: keyed by batch class, plus (cls, 'scan')
-        # for the superbatch variants
+        # compiled programs, keyed by batch class
         self._sharded: dict[object, Callable] = {}
 
     def _step_for(self, batch_cls) -> Callable:
@@ -574,44 +573,6 @@ class ParallelSGDModel:
             )
             fn = jax.jit(sharded, donate_argnums=0)
             self._sharded[batch_cls] = fn
-        return fn
-
-    def _scan_for(self, batch_cls) -> Callable:
-        """The superbatch program: lax.scan of the per-shard step body over a
-        stacked batch ([K, ...] leaves; K unsharded, rows sharded as usual).
-        Same math as K sequential steps — the scan carries the weights
-        through the identical body (mirrors StreamingSGDModel.step_many).
-
-        A PackedBatch here is the COALESCED group wire
-        (``pack_ragged_group``: one shard-major buffer whose local slice
-        holds this shard's K segments): the body unpacks the slice into the
-        stacked shard-local batch in-program — zero-copy bitcasts plus the
-        narrow-offset cumsum — and runs the identical scan."""
-        key = (batch_cls, "scan")
-        fn = self._sharded.get(key)
-        if fn is None:
-            body = self._step_body
-            if batch_cls is PackedBatch:
-                def scanned(weights, pb, _inner=body):
-                    with jax.named_scope("unpack"):
-                        stacked = unpack_batch(pb.buffer, pb.layout)
-                    return lax.scan(_inner, weights, stacked)
-
-                in_spec = _pspecs_for(PackedBatch, self.data_axis)
-            else:
-                def scanned(weights, stacked_batch):
-                    return lax.scan(body, weights, stacked_batch)
-
-                in_spec = _stacked(_pspecs_for(batch_cls, self.data_axis))
-
-            sharded = jax.shard_map(
-                scanned,
-                mesh=self.mesh,
-                in_specs=(self._w_spec, in_spec),
-                out_specs=(self._out_specs[0], _stacked(self._out_specs[1])),
-            )
-            fn = jax.jit(sharded, donate_argnums=0)
-            self._sharded[key] = fn
         return fn
 
     @classmethod
@@ -721,10 +682,8 @@ class ParallelSGDModel:
     wire_codec = ""
 
     def prepare(self, batch):
-        """Host-side shard alignment WITHOUT device placement — the
-        grouping paths (SuperBatcher) call this per batch so shape
-        signatures and stacking see the final shard-aligned layout (a
-        stacked batch cannot be re-aligned)."""
+        """Host-side shard alignment WITHOUT device placement (the first
+        half of ``pack_for_wire``)."""
         if (
             isinstance(batch, RaggedUnitBatch)
             and batch.num_shards != self.num_data
@@ -753,33 +712,12 @@ class ParallelSGDModel:
             pb.layout,
         )._with_lease(pb._lease)
 
-    def pack_group_for_wire(self, batches) -> PackedBatch:
-        """The mesh form of the COALESCED superbatch wire (Lean wire v2):
-        shard-align each of the K batches, pack them into ONE shard-major
-        buffer (``pack_ragged_group``) and place it with row sharding —
-        one main-thread put whose P(data) slice hands every device its own
-        K segments; ``step_many`` consumes it via the scanned unpack."""
-        from ..features.batch import pack_ragged_group
-
-        pb = pack_ragged_group(
-            [self.prepare(b) for b in batches], codec=self.wire_codec or None
-        )
-        return PackedBatch(
-            jax.device_put(
-                pb.buffer, NamedSharding(self.mesh, P(self.data_axis))
-            ),
-            pb.layout,
-        )._with_lease(pb._lease)
-
-    def _packed_rows(self, pb: PackedBatch, group: bool = False) -> int:
-        """Global row count recorded in a RaggedShardSegments (or, for the
-        coalesced superbatch wire, RaggedGroupSegments) layout."""
-        want = "RaggedGroupSegments" if group else "RaggedShardSegments"
-        if pb.layout[0] != want:
+    def _packed_rows(self, pb: PackedBatch) -> int:
+        """Global row count recorded in a RaggedShardSegments layout."""
+        if pb.layout[0] != "RaggedShardSegments":
             raise ValueError(
                 "mesh models take the per-shard packed layout "
-                f"({'pack_group_for_wire' if group else 'pack_for_wire'}), "
-                "not the flat pack_batch buffer"
+                "(pack_for_wire), not the flat pack_batch buffer"
             )
         s = pb.layout[2][1]
         if s != self.num_data:
@@ -815,41 +753,6 @@ class ParallelSGDModel:
                 batch = shard_batch(batch, self.mesh)
         self._weights, out = self._step_for(type(batch))(self._weights, batch)
         return out
-
-    def step_many(
-        self, stacked: FeatureBatch | UnitBatch | RaggedUnitBatch | PackedBatch
-    ) -> StepOutput:
-        """K micro-batch steps as one dispatch over the mesh (superbatch:
-        ``features.batch.stack_batches``); per-batch stats return along
-        axis 0. Stacked ragged batches must be shard-aligned per batch
-        (``prepare`` before stacking) and are placed explicitly; already-
-        global arrays (multi-host assembly) pass through. A PackedBatch is
-        the coalesced group wire (``pack_group_for_wire``) — one buffer,
-        unpacked inside the scanned program. See ``_scan_for``."""
-        if isinstance(stacked, PackedBatch):
-            self._check_rows(self._packed_rows(stacked, group=True))
-            if not isinstance(stacked.buffer, jax.Array):
-                stacked = PackedBatch(
-                    jax.device_put(
-                        stacked.buffer,
-                        NamedSharding(self.mesh, P(self.data_axis)),
-                    ),
-                    stacked.layout,
-                )
-            self._wire_sharding = stacked.buffer.sharding
-            self._weights, outs = self._scan_for(PackedBatch)(
-                self._weights, stacked
-            )
-            return outs
-        self._check_rows(stacked.mask.shape[1])
-        if isinstance(stacked, RaggedUnitBatch) and not isinstance(
-            stacked.units, jax.Array
-        ):
-            stacked = shard_batch(stacked, self.mesh)
-        self._weights, outs = self._scan_for(type(stacked))(
-            self._weights, stacked
-        )
-        return outs
 
     def train_on(self, stream) -> None:
         stream.foreach_batch(lambda batch, _time: self.step(batch))

@@ -13,8 +13,8 @@ instead of extra host fetches:
 - **the step** maps the EXISTING fused SGD step over the tenant axis.
   Default mapping is ``lax.map`` — a scan of the single-tenant step program
   with no carry, which keeps every tenant's math BIT-IDENTICAL to the
-  reference single-model path (the parity law; ``step_many`` uses the same
-  trick over K batches). ``mapping="vmap"`` batches the tenants across the
+  reference single-model path (the parity law). ``mapping="vmap"``
+  batches the tenants across the
   device instead — mathematically equivalent, but XLA's batched-matmul
   accumulation order differs on the dense path, so it is an opt-in for
   deployments that trade bit-parity for device parallelism (device compute
@@ -22,9 +22,9 @@ instead of extra host fetches:
 - **the wire** is shared: rows route to tenants on the host by a cheap
   deterministic key (``features/batch.tenant_route_keys``), split into M
   same-signature batches (dry tenants = all-padding, the lockstep
-  invariant), and ship as the K-batch superbatch wire reused as the
-  K-tenant wire — ``stack_batches`` (``--wirePack stacked``) or the
-  coalesced one-buffer ``pack_ragged_group`` (``--wirePack group``);
+  invariant), and ship as ONE M-tenant wire — ``stack_batches``
+  (``--wirePack stacked``) or the coalesced one-buffer
+  ``pack_ragged_group`` (``--wirePack group``);
 - **the fetch** is one ``jax.device_get`` of the ``[M, ...]`` StepOutput
   through the existing FetchPipeline — fetch count per tick is ONE
   regardless of M (asserted by the counting tests).
@@ -85,7 +85,7 @@ def aggregate_tenant_output(out, batch, model) -> StepOutput:
     every other leaf; M > 1 leaves the aggregate's quality None — norms of
     M independent models don't pool into one meaningful vector, and the
     model-watch adapter consumes the per-tenant [M, Q] leaf BEFORE this
-    aggregation (apps/common.attach_super_batcher wrapping order)."""
+    aggregation (apps/common.attach_pipeline wrapping order)."""
     from ..features.batch import tenant_rows
 
     m = model.num_tenants
@@ -371,12 +371,11 @@ class TenantStackModel:
         return mask is not None and getattr(mask, "ndim", 1) == 2
 
     def prepare_wire(self, batch):
-        """Host batch → the stacked/coalesced M-tenant wire (the K-batch
-        group wire reused with K = M tenants). ``--wirePack group``
-        coalesces the M ragged batches into ONE contiguous buffer (one
-        main-thread put, uint16-delta offsets); ``stacked`` ships M
-        per-field arrays. Bit-identical math either way (the superbatch
-        wire law, tests/test_superwire.py)."""
+        """Host batch → the stacked/coalesced M-tenant wire.
+        ``--wirePack group`` coalesces the M ragged batches into ONE
+        contiguous buffer (one main-thread put, uint16-delta offsets);
+        ``stacked`` ships M per-field arrays. Bit-identical leaves either
+        way (the wire law, tests/test_superwire.py)."""
         return self.prepare_wire_from_parts(self.split(batch))
 
     def prepare_wire_from_parts(self, parts):
@@ -385,7 +384,7 @@ class TenantStackModel:
         batches → the stacked/coalesced tenant wire."""
         if self.mesh is not None:
             # ragged parts shard-align to the data axis BEFORE stacking
-            # (alignment is a flat-batch operation — the superbatch rule)
+            # (alignment is a flat-batch operation)
             parts = [self._prepare_part(p) for p in parts]
         if (
             self.wire_pack == "group"
@@ -449,8 +448,8 @@ class TenantStackModel:
                 wire.layout,
             )
         if self._tenant_axis is None:
-            # 1D mesh: tenants unsharded, rows over data — exactly the
-            # stacked-superbatch placement shard_batch already implements
+            # 1D mesh: tenants unsharded, rows over data — the stacked
+            # placement shard_batch implements
             from .sharding import shard_batch
 
             return shard_batch(wire, self.mesh)
@@ -570,10 +569,10 @@ class MultiHostTenantModel:
     weights). Each host routes ITS OWN rows into the M-tenant split
     (deterministic key — identical routing on every host), stacks them
     locally, and assembles the global [M, B_global, ...] tenant wire with
-    ``make_array_from_process_local_data`` on the row axis — the
-    ``step_many`` stacked-wire assembly reused with K = M tenants, so no
-    new wire form and no new collective. Stats come back [M]-stacked and
-    psum-global; ONE pooled fetch per tick, exactly like single-host.
+    ``make_array_from_process_local_data`` on the row axis (axis 1 of
+    the [M, ...] leaves), so no new collective. Stats come back
+    [M]-stacked and psum-global; ONE pooled fetch per tick, exactly like
+    single-host.
 
     The stacked wire is the only multi-host tenant wire (the coalesced
     group buffer has no tenant-axis layout across processes). The RAGGED
@@ -582,10 +581,9 @@ class MultiHostTenantModel:
     stacking — agreed by a single allgather-max of this host's max
     per-part need (the ``[need]`` widening template: the agree collective
     rides the same once-per-batch cadence the single-model ragged wire
-    already pays, zero new collectives). The stacked assembly then mirrors
-    ``MultiHostSGDModel.step_many``'s ragged branch: rows shard on axis 1
-    under ``P(None, data)``, per-shard segments land on their devices, and
-    the stacked wire ships raw (the codec rides the packed one-buffer
+    already pays, zero new collectives). In the stacked assembly rows
+    shard on axis 1 under ``P(None, data)``, per-shard segments land on
+    their devices, and the stacked wire ships raw (the codec rides the packed one-buffer
     forms only — same rule as the single-host stacked wire). Elastic
     membership (``--elastic on``) rebuilds this wrapper in place across
     epochs via ``rebuild``, the same contract as MultiHostSGDModel."""
@@ -688,8 +686,7 @@ class MultiHostTenantModel:
     def _to_global_ragged(self, stacked):
         """[M]-stacked local-shard ragged wire → the global tenant wire:
         every leaf assembles on the ROW axis (axis 1) under ``P(None,
-        data)``, exactly ``MultiHostSGDModel.step_many``'s ragged branch
-        with K = M tenants — each process contributes its local shards'
+        data)`` — each process contributes its local shards'
         segments and the data axis hands every device its own."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -601,8 +601,8 @@ class StreamingContext:
         # back-to-back mode (--seconds 0) with a pinned row bucket: cap each
         # batch at the bucket so a fast source yields deterministic
         # fixed-size batches (the run_to_completion semantic) instead of one
-        # giant drain — bounded memory, one compiled shape, and the unit
-        # --superBatch groups. Wall-clock mode drains the full interval.
+        # giant drain — bounded memory, one compiled shape. Wall-clock
+        # mode drains the full interval.
         limit = (
             getattr(self._stream, "row_bucket", 0)
             if self.batch_interval == 0

@@ -15,8 +15,8 @@ host fetch that stalls or is lost, a dispatch that raises, a dashboard that
 hangs): seeded latency spikes / multi-second stalls / exceptions at
 three injection points —
 
-- ``fetch``  — the pooled ``device_get``s (FetchPipeline / SuperBatcher),
-- ``step``   — the device dispatch (``model.step``/``step_many``),
+- ``fetch``  — the pooled ``device_get``s (FetchPipeline),
+- ``step``   — the device dispatch (``model.step``),
 - ``web``    — every dashboard HTTP request (``WebClient._request``),
 
 so the runtime guards those points carry (fetch deadline/retry/abort, the

@@ -7,20 +7,20 @@ existing seams — no new seams, no device work, no fetches:
             featurize: captures a ``stage_seconds()`` snapshot, one
             ``now_ms()`` read (the TWTML_NOW_MS seam), and the event-time
             span of the batch (min/max ``created_at_ms``).
-  dispatch  the four dispatch sites in apps/common (FetchPipeline,
-            SuperBatcher group + partial singles, per_batch): moves the
-            oldest open record into the in-flight FIFO.
+  dispatch  the two dispatch sites in apps/common (FetchPipeline,
+            per_batch): moves the oldest open record into the in-flight
+            FIFO.
   delivery  FreshnessGuard (outermost delivery wrapper): pops the oldest
             in-flight record and diffs the stage clock against the open
             snapshot — the per-stage deltas name the dominant edge.
 
-Two FIFOs instead of a dict keyed on batch identity because SuperBatcher's
-``prepare()`` wrapper hands the handler a DIFFERENT object than the one
-``_process`` opened; deliveries are strictly in dispatch order (FetchPipeline
-resolves futures FIFO), so positional matching is exact. Dispatches with no
-open record (serving-plane predictions, warmup, tests driving a bare
-pipeline) push a blank so the FIFOs stay aligned; both deques are bounded so
-leaked records (shutdown, shed batches) cannot grow host state.
+Two FIFOs instead of a dict keyed on batch identity: deliveries are
+strictly in dispatch order (FetchPipeline resolves futures FIFO), so
+positional matching is exact and needs no key that survives a pack.
+Dispatches with no open record (serving-plane predictions, warmup, tests
+driving a bare pipeline) push a blank so the FIFOs stay aligned; both deques
+are bounded so leaked records (shutdown, shed batches) cannot grow host
+state.
 
 Module is jax-free and every entry point is a cheap no-op until
 ``configure(True)`` — ``--freshness off`` never touches the deques, which is
@@ -40,7 +40,7 @@ from . import sideband as _sideband
 # keys; cumulative wall seconds, diffed open -> delivery per batch)
 EDGES = ("source_read", "parse", "featurize", "wire_pack", "dispatch", "fetch")
 
-# bounded FIFOs: deeper than any fetch-pipeline depth * superbatch K we run,
+# bounded FIFOs: deeper than any fetch-pipeline depth we run,
 # shallow enough that leaked records are noise, not a leak
 MAX_RECORDS = 4096
 

@@ -67,7 +67,7 @@ or persistent-cache fetch: ``seconds``, ``cache_hit``, ``fun``,
 rows, row length, units dtype, wire form).
 
 Mesh layout: a mesh model's run opens with one ``mesh_layout`` instant
-(``apps/common.attach_super_batcher``): ``data`` and ``model`` axis sizes,
+(``apps/common.attach_pipeline``): ``data`` and ``model`` axis sizes,
 ``f_text_local`` (hashed features a model shard holds) and ``devices``.
 The device time of the mesh steps' collectives is not a span: it is on the
 device plane under the ``collective`` scope (parallel/sharding.py).
@@ -98,10 +98,9 @@ STAGES = (
     "parse",         # bytes/lines → Status/ParsedBlock, on the source thread
     "featurize",     # host featurize incl. wire build (FeatureStream)
     "wire_pack",     # one-buffer pack of the ragged wire (when --wire
-                     # ragged); carries a ``mode`` attribute — "single"
-                     # (the k=1 pack) or "group" (the coalesced superbatch
-                     # wire, --wirePack group) — plus ``wire_bytes``, so
-                     # trace reports show the Lean-wire-v2 layout in use
+                     # ragged); carries ``mode="single"`` (one batch,
+                     # one buffer; the tenant stack's M-batch wire
+                     # packs inside this same span) plus ``wire_bytes``
     "dispatch",      # model.step dispatch — argument uploads ride this
     "fetch",         # pipelined StepOutput host fetch (FetchPipeline pool)
     "stats_publish", # telemetry POSTs (SessionStats)
