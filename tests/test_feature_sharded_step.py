@@ -473,3 +473,63 @@ def test_sharded_gram_step_asks_for_no_gather_or_scatter_on_its_weights(
     gathers, scatters = gathers_and_scatters(text)
     assert gathers  # the ragged wire's re-pad still gathers units
     assert weights not in gathers and weights not in scatters
+
+
+# ---- (vii) PR 30: C keeps the shape its build writes; the collectives stay --
+
+# the lowered per-shard program's collectives as the parent of PR 30 had
+# them (packed ragged wire, 8 rows of 16, hash2e20's width): per layout
+# {(op, operand type): count}; the three plane branches each hold the body's
+_PARENT_COLLECTIVES = {
+    (2, 2): {
+        ("all_gather", "4x15xf32"): 1, ("all_gather", "4x15xi32"): 1,
+        ("all_gather", "4x4xf32"): 1, ("all_gather", "4x8xf32"): 3,
+        ("all_gather", "4xf32"): 5,
+        ("all_reduce.add", "32xf32"): 1, ("all_reduce.add", "4x8xf32"): 3,
+        ("all_reduce.add", "4xf32"): 8, ("all_reduce.add", "524288xf32"): 3,
+        ("all_reduce.add", "8xf32"): 1, ("all_reduce.add", "f32"): 26,
+        ("all_reduce.minimum", "2xi32"): 1, ("all_reduce.minimum", "i32"): 2,
+    },
+    (1, 4): {
+        ("all_gather", "8x15xf32"): 1, ("all_gather", "8x15xi32"): 1,
+        ("all_gather", "8x4xf32"): 1, ("all_gather", "8x8xf32"): 3,
+        ("all_gather", "8xf32"): 5,
+        ("all_reduce.add", "262144xf32"): 3, ("all_reduce.add", "32xf32"): 1,
+        ("all_reduce.add", "4xf32"): 5, ("all_reduce.add", "8x8xf32"): 3,
+        ("all_reduce.add", "8xf32"): 4, ("all_reduce.add", "f32"): 26,
+        ("all_reduce.minimum", "2xi32"): 1, ("all_reduce.minimum", "i32"): 2,
+    },
+    (4, 1): {
+        ("all_gather", "2x15xf32"): 1, ("all_gather", "2x15xi32"): 1,
+        ("all_gather", "2x4xf32"): 1, ("all_gather", "2x8xf32"): 3,
+        ("all_gather", "2xf32"): 5,
+        ("all_reduce.add", "1048576xf32"): 3, ("all_reduce.add", "32xf32"): 1,
+        ("all_reduce.add", "4xf32"): 5, ("all_reduce.add", "f32"): 21,
+        ("all_reduce.minimum", "i32"): 1,
+    },
+}
+_ALL_GATHER = re.compile(
+    r'"stablehlo\.all_gather"\([^\n]*? : \(tensor<([^>]*)>\) -> ')
+_ALL_REDUCE = re.compile(
+    r'"stablehlo\.all_reduce"\([\s\S]*?stablehlo\.(add|minimum|maximum)'
+    r'[\s\S]*?\}\) : \(tensor<([^>]*)>\) -> ')
+
+
+@pytest.mark.parametrize("layout", sorted(_PARENT_COLLECTIVES),
+                         ids=lambda l: f"{l[0]}x{l[1]}")
+def test_mesh_step_collectives_are_the_parents(layout):
+    """Contracting C as built changes what a shard computes between its
+    collectives and none of them: the same all-gathers and all-reduces, of
+    the same operands, as before — the predict psum still carries
+    ``[B_local]`` (``dot`` slices its ``[B]`` before the psum), the
+    write-back psum still ``[F_local]`` (``tdot`` flattens and crops before
+    it) — and no other kind of collective."""
+    from collections import Counter
+
+    text = _lowered("packed", layout).as_text()
+    found = Counter(("all_gather", op) for op in _ALL_GATHER.findall(text))
+    found.update((f"all_reduce.{kind}", op)
+                 for kind, op in _ALL_REDUCE.findall(text))
+    assert found == _PARENT_COLLECTIVES[layout]
+    assert sum(found.values()) == len(re.findall(
+        r"stablehlo\.(?:all_|reduce_scatter|collective_)", text))

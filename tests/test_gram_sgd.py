@@ -628,7 +628,9 @@ def test_gate_count_257_is_not_rounded_to_256():
     g, plane = text_gram(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
     assert int(plane) == 0 and float(g[0, 0]) == 257.0**2 + 20.0
     c = onehot_counts(jnp.asarray(token_idx), jnp.asarray(token_val), F_TEXT)
-    assert float(c[0, 7]) == 256.0   # the rounding the gate keeps out
+    # feature 7 of row 0 in the [B, k_hi, k_lo] the build writes: the
+    # rounding the gate keeps out
+    assert float(c[(0, *divmod(7, c.shape[2]))]) == 256.0
 
 
 def test_gate_without_the_int8_plane_takes_bf16_where_s8_would_do():
@@ -815,3 +817,69 @@ def test_gram_step_contracts_with_counts_like_gather_and_scatter(
         assert _rel_l1(u_rounded, u_ref) >= 1e-4
         w_rounded = written_back(jnp.asarray(_round_bf16(alpha)))
         assert _rel_l1(w_rounded - scaled, w_ref - scaled) >= 1e-4
+
+
+# ---------------------------------------------------------------------------
+# PR 30: the count matrix keeps the ``[B, k_hi, k_lo]`` its build writes and
+# ``CountPlane`` contracts it over ``(hi, lo)``. The 2-D form it replaces —
+# ``C.reshape(B, k_hi·k_lo)[:, :f_text]``, a matmul with ``Cᵀ``, row-wise
+# and column-wise multiply-and-reduce — is the reference.
+
+@pytest.mark.parametrize("rows", [0, 8])
+@pytest.mark.parametrize("plane", ["exact", "bf16", "s8"])
+@pytest.mark.parametrize("f_text, split", [
+    (1 << 10, (32, 32)),   # square split
+    (1 << 11, (32, 64)),   # k_lo = 2·k_hi: hash2e20's 2^19 slice
+    (1000, (32, 32)),      # f_text < k_hi·k_lo: w zero-padded, delta cropped
+])
+def test_count_plane_contracts_the_built_shape_like_the_2d_form(
+    f_text, split, plane, rows
+):
+    from jax import lax
+
+    rng = np.random.default_rng(3000 + f_text + _PLANE_INDEX[plane])
+    batch = _contraction_batch(rng, plane, f_text=f_text)
+    idx, val = jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val)
+    b = idx.shape[0]
+    w = jnp.asarray(rng.normal(size=f_text).astype(np.float32))
+    alpha = jnp.asarray(rng.normal(size=rows or b).astype(np.float32))
+    start = 16 if rows else None  # the third of four row shards
+
+    width = split[0] * split[1]
+    built = []  # (dtype, shape) of each plane's C, in the switch's order
+
+    def body(counts):
+        built.append((counts.c.dtype, counts.c.shape))
+        flat = counts.c.astype(jnp.float32).reshape(b, -1)
+        flat = jnp.pad(flat, ((0, 0), (0, width - flat.shape[1])))
+        return flat, counts.gram(), counts.dot(w), counts.tdot(alpha)
+
+    (flat, g, u, delta), took = jax.jit(lambda i, v, r: gram_ops.text_gram(
+        i, v, f_text, row_start=r, rows=rows, body=body))(idx, val, start)
+    assert int(took) == _PLANE_INDEX[plane]
+    dtype = {"exact": jnp.float32, "bf16": jnp.bfloat16, "s8": jnp.int8}[plane]
+    assert built[_PLANE_INDEX[plane]] == (
+        dtype, (b, f_text) if plane == "exact" else (b, *split))
+
+    # the 2-D form: C is the exact counts, and zero past f_text
+    flat = np.asarray(flat)
+    assert not flat[:, f_text:].any()
+    c2 = jnp.asarray(flat[:, :f_text]).astype(dtype)
+    np.testing.assert_array_equal(
+        np.asarray(c2, np.float32), np.asarray(densify_text(idx, val, f_text)))
+    panel = c2[start:start + rows] if rows else c2
+    product = {
+        "exact": dict(precision=lax.Precision.HIGHEST),
+        "bf16": dict(preferred_element_type=jnp.float32),
+        "s8": dict(preferred_element_type=jnp.int32),
+    }[plane]
+    g_ref = np.asarray(jnp.matmul(panel, c2.T, **product), np.float32)
+    assert g.dtype == jnp.float32 and g.shape == (rows or b, b)
+    if plane == "exact":  # an f32 sum of fractions, in another order
+        np.testing.assert_allclose(np.asarray(g), g_ref, rtol=1e-5, atol=1e-5)
+    else:  # integers within f32: bit for bit
+        np.testing.assert_array_equal(np.asarray(g), g_ref)
+    panel = panel.astype(jnp.float32)
+    assert u.shape == (rows or b,) and delta.shape == (f_text,)
+    assert _rel_l1(u, jnp.sum(panel * w[None, :], axis=1)) <= 1e-6
+    assert _rel_l1(delta, jnp.sum(panel * alpha[:, None], axis=0)) <= 1e-6

@@ -266,44 +266,65 @@ def _compile_single(topo) -> str:
         shape((F_TEXT + 4,), jnp.float32), batch).compile().as_text()
 
 
-def _compile_2x2(topo) -> str:
+def _compile_mesh(topo, mesh_shape, axes, body, w_spec, weights_of) -> str:
+    """The mesh step ``body`` under ``shard_map`` on the described chips,
+    compiled for ROWS rows of the padded units wire at row length 512."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from twtml_tpu.models.base import StepOutput
-    from twtml_tpu.parallel.sharding import (
-        _make_feature_sharded_step,
-        unit_batch_pspecs,
-    )
+    from twtml_tpu.parallel.sharding import unit_batch_pspecs
 
-    f_text = 1 << 20  # hash2e20: a 2^19 slice a chip
-    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    mesh = Mesh(np.array(topo.devices).reshape(mesh_shape), axes)
 
     def shape(dims, dtype, *spec):
         return jax.ShapeDtypeStruct(
             dims, dtype, sharding=NamedSharding(mesh, P(*spec)))
 
-    body = _make_feature_sharded_step(
-        f_text=f_text, f_text_local=f_text // 2, num_iterations=50,
-        step_size=0.005, mini_batch_fraction=1.0, l2_reg=0.1,
-        convergence_tol=0.001, residual_fn=None, prediction_fn=None,
-        round_predictions=True, data_axis="data", model_axis="model",
-        quality=True)
-    w_spec = {"text": P("model"), "num": P()}
     step = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(w_spec, unit_batch_pspecs("data")),
         out_specs=(w_spec, StepOutput(
             predictions=P("data"), count=P(), mse=P(), real_stdev=P(),
             pred_stdev=P(), quality=P())),
     ), donate_argnums=0)
-    weights = {"text": shape((f_text,), jnp.float32, "model"),
-               "num": shape((4,), jnp.float32)}
     batch = UnitBatch(
         shape((ROWS, 512), jnp.uint8, "data", None),
         shape((ROWS,), jnp.int32, "data"),
         shape((ROWS, 4), jnp.float32, "data", None),
         shape((ROWS,), jnp.float32, "data"),
         shape((ROWS,), jnp.float32, "data"))
-    return step.lower(weights, batch).compile().as_text()
+    return step.lower(weights_of(shape), batch).compile().as_text()
+
+
+def _compile_2x2(topo) -> str:
+    from jax.sharding import PartitionSpec as P
+
+    from twtml_tpu.parallel.sharding import _make_feature_sharded_step
+
+    f_text = 1 << 20  # hash2e20: a 2^19 slice a chip
+    body = _make_feature_sharded_step(
+        f_text=f_text, f_text_local=f_text // 2, num_iterations=50,
+        step_size=0.005, mini_batch_fraction=1.0, l2_reg=0.1,
+        convergence_tol=0.001, residual_fn=None, prediction_fn=None,
+        round_predictions=True, data_axis="data", model_axis="model",
+        quality=True)
+    return _compile_mesh(
+        topo, (2, 2), ("data", "model"), body,
+        {"text": P("model"), "num": P()},
+        lambda shape: {"text": shape((f_text,), jnp.float32, "model"),
+                       "num": shape((4,), jnp.float32)})
+
+
+def _compile_4x1(topo) -> str:
+    """The data-only mesh step (models/sgd.py under ``axis_name``) at
+    hash2e18's size: replicated weights, a quarter of the rows a chip."""
+    from jax.sharding import PartitionSpec as P
+
+    body = make_sgd_train_step(
+        num_text_features=F_TEXT, num_iterations=50, step_size=0.005,
+        l2_reg=0.1, quality=True, axis_name="data")
+    return _compile_mesh(
+        topo, (4,), ("data",), body, P(),
+        lambda shape: shape((F_TEXT + 4,), jnp.float32))
 
 
 # layout → (compile, width of the [F] weights a chip holds, rows × width
@@ -311,6 +332,7 @@ def _compile_2x2(topo) -> str:
 _TPU_STEPS = {
     "single": (_compile_single, F_TEXT, ROWS * F_TEXT),
     "2x2": (_compile_2x2, 1 << 19, (ROWS // 2) << 19),
+    "4x1": (_compile_4x1, F_TEXT, (ROWS // 4) * F_TEXT),
 }
 
 
@@ -414,3 +436,43 @@ def test_compiled_gram_branch_reads_only_its_count_matrix(
     if plane != "exact":
         wide = [(op, d, n) for op, d, n in arrays if d == "f32" and n >= panel]
         assert not wide, wide
+
+
+# instructions that name an array another instruction wrote
+_ALIASES = ("get-tuple-element", "bitcast", "tuple", "parameter")
+
+
+@pytest.mark.parametrize("layout", sorted(_TPU_STEPS))
+@pytest.mark.parametrize("plane, branch", [("bf16", 1), ("s8", 2)])
+def test_compiled_fast_plane_writes_its_count_matrix_once(
+    tpu_branches, layout, plane, branch
+):
+    """PR 30: C keeps the ``[B, k_hi, k_lo]`` its build writes, so on the
+    program the TPU's compiler made a fast plane's branch holds ONE array
+    of C's size in the plane's type — the build's, never a layout copy or
+    a second materialisation (at hash2e20 the ``reshape`` to ``[B, F]``
+    was a ``copy`` of 2 GiB a batch) — and the fusion that writes it also
+    yields the ``f32[ROWS]`` ``u = C·w``: the predict contraction in the
+    build's epilogue, no read of C of its own. On a mesh (``2x2``: the
+    feature-sharded step; ``4x1``: the data-only one) one more array, of
+    the PANEL's size, may be written (this shard's rows of C, read by the
+    G product and the write-back); on one device none."""
+    _compile, width, panel = _TPU_STEPS[layout]
+    # (line, the arrays it yields) of every instruction that WRITES memory
+    written = [(line, _results([line]))
+               for line in tpu_branches(layout)[branch]["top"]]
+    written = [(line, arrays) for line, arrays in written
+               if arrays and arrays[0][0] not in _ALIASES]
+
+    def holding(least, below):
+        return [(line, arrays) for line, arrays in written
+                if any(d == plane and least <= n < below
+                       for _op, d, n in arrays)]
+
+    full = ROWS * width
+    whole = holding(full, np.inf)
+    assert len(whole) == 1, [line[:200] for line, _arrays in whole]
+    ((line, arrays),) = whole
+    assert ("fusion", "f32", ROWS) in arrays, line
+    panels = holding(panel, full)  # an empty range on one device
+    assert len(panels) <= 1, panels

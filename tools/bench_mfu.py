@@ -161,7 +161,9 @@ def main(argv=None) -> None:
         return jnp.sum(jnp.abs(c.astype(jnp.int32)))
 
     counts = jax.jit(
-        lambda idx, val: onehot_counts_int8(idx, val, F_TEXT)
+        # [B, k_hi, k_lo] as built; 2^18 splits exactly, so [B, F] below
+        lambda idx, val: onehot_counts_int8(idx, val, F_TEXT).reshape(
+            batch, F_TEXT)
     )(tok_idx, tok_val)
     counts = jax.device_put(jax.device_get(counts))
 

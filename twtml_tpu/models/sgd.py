@@ -367,7 +367,8 @@ def make_sgd_train_step(
 
         def dual_basis(counts):
             with jax.named_scope("predict"):
-                # this shard's rows of u = Z·W_prev: one read of C
+                # this shard's rows of u = Z·W_prev: C·w rides the count
+                # build's epilogue (ops/gram.CountPlane.dot)
                 raw = (counts.dot(w_text) + local_numeric @ w_num).astype(dtype)
                 u = raw
                 if axis_name:

@@ -24,12 +24,15 @@ so MLlib's exact update rule — √-decay step, SquaredL2Updater pre-scale,
 Bernoulli sampling, zero-sample skip, convergence freeze — runs unchanged
 through ``sgd_inner_loop`` on the tiny dual state {c, α}, and the 2^18
 feature space is touched only through ONE matrix, built once per batch:
-the dense count matrix C ``[B, F]`` of the text block (Z = [C | numeric]).
+the dense count matrix C of the text block (Z = [C | numeric]), ``B`` rows
+of ``F`` features held as the ``[B, k_hi, k_lo]`` its build writes (below;
+``CountPlane`` has why it is never reshaped to ``[B, F]``).
 The step contracts with it three times — ``u_text = C·w_text`` for the
-pre-update predictions, ``G_text = C·Cᵀ`` on the MXU, and
-``Δw_text = Cᵀ·α`` at write-back — each one streamed read of C, whatever
-the row length L; no ``[B, L]`` gather from the ``[F]`` weights and no
-``[B, L]`` scatter into them remains in the Gram basis (``ops/sparse.py``
+pre-update predictions (reduced in the epilogue of the product that writes
+C: no read of it), ``G_text = C·Cᵀ`` on the MXU, and
+``Δw_text = Cᵀ·α`` at write-back — each at most one streamed read of C,
+whatever the row length L; no ``[B, L]`` gather from the ``[F]`` weights
+and no ``[B, L]`` scatter into them remains in the Gram basis (``ops/sparse.py``
 keeps both for the scatter loop, for serving, and as the references of the
 differential tests). The residual function enters only elementwise on
 ``Z·W``, so the same dual loop serves the logistic learner. Nothing here is
@@ -38,7 +41,8 @@ summation order differs; differential tests in tests/test_gram_sgd.py pin
 both paths together).
 
 The two vector contractions are multiply-and-reduce fusions in f32:
-``Σ_f f32(C[b, f])·w[f]`` and ``Σ_b f32(C[b, f])·α[b]``, with C converted
+``Σ_f f32(C[b, f])·w[f]`` and ``Σ_b f32(C[b, f])·α[b]`` (``f`` running
+over ``(hi, lo)``, ``w`` and the result taking C's shape), with C converted
 element by element in registers (never as an f32 copy of a bf16 / s8
 matrix) and ``w``, ``α`` left in f32. C's entries are the exact counts on
 every plane (the gate's proof below), so ``C[b, f]·w[f]`` is the product
@@ -58,10 +62,13 @@ MXU matmul over a two-level split of the feature index, ``f = hi·K + lo``:
 i.e. one ``[B, √F, L] × [B, L, √F]`` batched matmul (~0.07 TFLOP at
 B=2048, F=2^18 — 3% of the G matmul itself), with 0/1 one-hot operands
 that are exact in bf16 and f32 accumulation, so counts come out exact.
+That ``[B, k_hi, k_lo]`` IS the count matrix from here on: G contracts it
+over ``(hi, lo)`` on both operands, ``u`` and the write-back sum over the
+same axes (``CountPlane``).
 
 Exactness is gated at runtime, never assumed. ``text_gram`` picks one of
 three planes from what it observes in the batch's [B, L] (idx, val) pairs —
-never from the [B, F] counts — and hands the index it took out with G:
+never from the counts — and hands the index it took out with G:
 
   2  s8    integral values, every row's ABSOLUTE token mass ≤ 127;
   1  bf16  integral, bf16-representable values and either
@@ -116,6 +123,7 @@ all.
 
 from __future__ import annotations
 
+import math
 import os
 
 import jax
@@ -166,33 +174,35 @@ _ONEHOT_DIMS = (((1,), (1,)), ((0,), (0,)))  # contract over l, batch over b
 
 
 def onehot_counts(token_idx, token_val, f_text: int, dtype=jnp.bfloat16):
-    """[B, L] (idx, val) pairs → dense [B, F] ``dtype`` counts with NO
-    scatter: the two-level one-hot batched matmul of the module docstring.
-    Accumulation is f32 regardless of ``dtype``; the output cast fuses into
-    the matmul epilogue, so the bf16 default halves the write (and the
-    downstream G matmul's read) vs an f32 count matrix."""
-    b, l = token_idx.shape
+    """[B, L] (idx, val) pairs → dense ``[B, k_hi, k_lo]`` ``dtype`` counts
+    with NO scatter: the two-level one-hot batched matmul of the module
+    docstring, feature ``f`` at ``[b, f // k_lo, f % k_lo]``. Accumulation
+    is f32 regardless of ``dtype``; the output cast fuses into the matmul
+    epilogue, so the bf16 default halves the write (and the downstream G
+    matmul's read) vs an f32 count matrix.
+
+    The result is NOT reshaped to ``[B, F]``: every reader contracts it over
+    ``(hi, lo)`` as built (``CountPlane``). Where ``f_text < k_hi·k_lo`` the
+    positions past ``f_text`` are zero, every index being ``< f_text``."""
     hi, lo, k_hi, k_lo = _split_feature_index(token_idx, f_text)
     oh_hi = (hi[:, :, None] == jnp.arange(k_hi, dtype=hi.dtype)).astype(
         jnp.bfloat16
     ) * token_val[:, :, None].astype(jnp.bfloat16)
     oh_lo = (lo[:, :, None] == jnp.arange(k_lo, dtype=lo.dtype)).astype(jnp.bfloat16)
-    c = lax.dot_general(
+    return lax.dot_general(
         oh_hi,
         oh_lo,
         _ONEHOT_DIMS,
         preferred_element_type=jnp.float32,
-    ).astype(dtype)  # [B, k_hi, k_lo]
-    return c.reshape(b, k_hi * k_lo)[:, :f_text]
+    ).astype(dtype)
 
 
 def onehot_counts_int8(token_idx, token_val, f_text: int):
     """The int8 twin of ``onehot_counts``: [B, L] (idx, val) pairs → dense
-    [B, F] int8 counts via the same two-level one-hot batched matmul, with
-    s8 operands and s32 accumulation — integer-exact whenever the caller's
-    gate holds (integral values, per-row absolute mass ≤ 127, so every
-    count and every partial sum is an integer within range)."""
-    b, l = token_idx.shape
+    ``[B, k_hi, k_lo]`` int8 counts via the same two-level one-hot batched
+    matmul, with s8 operands and s32 accumulation — integer-exact whenever
+    the caller's gate holds (integral values, per-row absolute mass ≤ 127,
+    so every count and every partial sum is an integer within range)."""
     hi, lo, k_hi, k_lo = _split_feature_index(token_idx, f_text)
     val_i8 = token_val.astype(jnp.int8)
     oh_hi = jnp.where(
@@ -201,13 +211,12 @@ def onehot_counts_int8(token_idx, token_val, f_text: int):
         jnp.int8(0),
     )
     oh_lo = (lo[:, :, None] == jnp.arange(k_lo, dtype=lo.dtype)).astype(jnp.int8)
-    c = lax.dot_general(
+    return lax.dot_general(
         oh_hi,
         oh_lo,
         _ONEHOT_DIMS,
         preferred_element_type=jnp.int32,
     ).astype(jnp.int8)  # counts ≤ row mass ≤ 127: the narrowing is exact
-    return c.reshape(b, k_hi * k_lo)[:, :f_text]
 
 
 def text_gram(
@@ -235,14 +244,15 @@ def text_gram(
     plane's ``CountPlane``, and its result (any pytree whose types do not
     depend on the plane) is what comes out. The train steps pass the whole
     Gram basis — ``counts.dot`` for ``u``, ``counts.gram()``, the dual
-    loop, ``counts.tdot`` for the write-back — so that every contraction reads the ONE C of the batch, live from its
-    build to the write-back, and nothing typed by the plane has to leave
-    the switch. Under a mesh the body's collectives run inside the branch:
-    every shard enters the same one, because the index is reduced over
-    every axis it could differ on before the switch.
+    loop, ``counts.tdot`` for the write-back — so that every contraction
+    reads the ONE C of the batch, live from its build to the write-back in
+    the shape and layout the build wrote, and nothing typed by the plane
+    has to leave the switch. Under a mesh the body's collectives run
+    inside the branch: every shard enters the same one, because the index
+    is reduced over every axis it could differ on before the switch.
 
     The gate ladder and its proof are the module docstring's. Every rung
-    reads the [B, L] token pairs, never the [B, F] counts: row absolute
+    reads the [B, L] token pairs, never the counts: row absolute
     mass ≤ 127 ⇒ s8 (one s8×s8→s32 MXU matmul, bit-exact); ≤ 255 ⇒ bf16;
     a batch with a longer row (a 257–280-unit text) reaches rung 2, which
     sorts each row's pairs and takes the bf16 plane while no (row, feature)
@@ -336,54 +346,60 @@ def text_gram(
                 ).astype(bool)
         vals_ok = rung1 | rung2
 
-    def left(c):
-        """The (possibly row-sliced) left operand: this shard's rows of C.
-        The slice makes the G MATMUL's FLOPs — and the bytes ``dot`` and
-        ``tdot`` stream — scale 1/shards in sharded builds; the count build
+    def left(x):
+        """This shard's rows of an array whose leading axis is the batch's
+        rows: C itself, as built (the G product's left operand, the
+        write-back's panel), or a ``[B]`` vector reduced from all of it.
+        The slice makes the G MATMUL's FLOPs — and the bytes ``tdot``
+        streams — scale 1/shards in sharded builds; the count build
         itself is deliberately replicated per shard — the right operand
         needs all B_global rows anyway, and all-gathering shard-local
         count builds would move [B_global, F_local] bf16 (~0.5 GB at the
         2^18 operating point) to save a build worth ~3% of the G matmul."""
         if rows:
-            return lax.dynamic_slice_in_dim(c, row_start, rows, axis=0)
-        return c
+            return lax.dynamic_slice_in_dim(x, row_start, rows, axis=0)
+        return x
 
-    def product_i8(a, c):
-        g = jnp.matmul(a, c.T, preferred_element_type=jnp.int32)
-        # |G| ≤ (Σ|c_a|)·max|c_b| ≤ 127² < 2²⁴: the f32 cast is exact
-        return g.astype(jnp.float32)
-
-    def product_bf16(a, c):
-        return jnp.matmul(a, c.T, preferred_element_type=jnp.float32)
-
-    def product_exact(a, c):
-        return jnp.matmul(a, c.T, precision=lax.Precision.HIGHEST)
-
-    def branch(build, product):
+    def branch(build, **product):
         def run(i, v):
             with jax.named_scope("gram_count"):
-                c = build(i, v, f_text)  # [B, F], exact in the plane's type
-            return body(CountPlane(c, left, product))
+                c = build(i, v, f_text)  # exact in the plane's type
+            return body(CountPlane(c, left, f_text, **product))
 
         return run
 
     idx = vals_ok.astype(jnp.int32)
     branches = [
-        branch(densify_text, product_exact),  # f32 scatter densify
-        branch(onehot_counts, product_bf16),
+        # f32 scatter densify, [B, F]
+        branch(densify_text, precision=lax.Precision.HIGHEST),
+        branch(onehot_counts, preferred_element_type=jnp.float32),
     ]
     if int8_plane:
         idx = idx + vals_ok_i8.astype(jnp.int32)  # i8-ok ⊆ bf16-ok: 0/1/2
-        branches.append(branch(onehot_counts_int8, product_i8))
+        branches.append(
+            branch(onehot_counts_int8, preferred_element_type=jnp.int32)
+        )
     return lax.switch(idx, branches, token_idx, val_f), idx
 
 
 class CountPlane:
     """One plane's dense count matrix inside its branch of ``text_gram``'s
     switch, as the three contractions the Gram basis runs with it. C is
-    f32, bf16 or s8 by the plane; ``left(c)`` is this shard's row panel of
-    it (``text_gram.left``: all of C on one device), sliced inside each
-    contraction so the slice fuses into its reader; every result is f32.
+    f32, bf16 or s8 by the plane and keeps, from its build to its last
+    reader, the shape the build wrote: ``[B, k_hi, k_lo]`` from the one-hot
+    builders (feature ``f`` at ``[hi, lo] = divmod(f, k_lo)``), ``[B, F]``
+    from the exact plane's densify. It is never reshaped to ``[B, F]``:
+    the builders write it tiled over ``(hi, lo)``, a ``[B, F]`` view is
+    tiled over ``(b, f)``, and wherever a row slice sits between that
+    reshape and its readers — every mesh step — the TPU's compiler makes
+    the reshape a physical copy of all of C (2 GiB read + 2 GiB written a
+    batch at ``hash2e20``; PERF.md §6, PR 30). So every contraction runs
+    over ALL trailing axes of C as given, and the ``[F]`` vectors take C's
+    shape instead: ``w`` is zero-padded to ``k_hi·k_lo`` going in and the
+    write-back cropped to ``f_text`` coming out (C is zero past ``f_text``,
+    every index being below it: the same sums). ``left`` is
+    ``text_gram.left``, this shard's rows of whatever has the batch's rows
+    leading (the identity on one device); every result is f32.
 
     ``dot`` and ``tdot`` are multiply-and-reduce fusions with f32 operands
     (module docstring): C's element is converted in registers, ``w`` and
@@ -391,32 +407,47 @@ class CountPlane:
     stage name: the caller scopes ``dot`` under ``predict`` and ``tdot``
     under ``writeback`` (models/sgd.py ``STAGE_SCOPES``)."""
 
-    def __init__(self, c, left, product):
-        self.c = c  # [B, F]: every row, the G product's right operand
+    def __init__(self, c, left, f_text: int, **product):
+        self.c = c  # every row, as built: the G product's right operand
         self._left = left
-        self._product = product
-
-    def _rows_f32(self):
-        return self._left(self.c).astype(jnp.float32)
+        self._f_text = f_text
+        self._product = product  # the G product's precision / result type
+        self._features = tuple(range(1, c.ndim))
 
     def dot(self, w):
         """``rows(C)·w`` → ``[rows]``: the text half of ``u = Z·W_prev``
         for this shard's rows (a partial over its features under a
-        feature axis; the caller psums)."""
-        return jnp.sum(self._rows_f32() * w[None, :], axis=1)
+        feature axis; the caller psums). Reduced over ALL rows of C and
+        then sliced, a ``[B]`` vector: the reduction can then sit in the
+        epilogue of the product that writes C, and costs no read of it."""
+        shape = self.c.shape[1:]
+        w = jnp.pad(w, (0, math.prod(shape) - w.shape[0])).reshape(shape)
+        u = jnp.sum(self.c.astype(jnp.float32) * w[None], axis=self._features)
+        return self._left(u)
 
     def tdot(self, alpha):
         """``rows(C)ᵀ·alpha`` → ``[F]``: the text half of ``Zᵀα`` from this
         shard's rows (the caller psums over the row shards). Duplicate
         (row, feature) occurrences are already summed in C, as the
         ``sparse_grad_text`` scatter summed them."""
-        return jnp.sum(self._rows_f32() * alpha[:, None], axis=0)
+        panel = self._left(self.c).astype(jnp.float32)
+        delta = jnp.sum(panel * jnp.expand_dims(alpha, self._features), axis=0)
+        return delta.reshape(-1)[: self._f_text]
 
     def gram(self):
         """``rows(C)·Cᵀ`` → ``[rows, B]`` f32 on the MXU, exact on every
-        plane (module docstring)."""
+        plane (module docstring): one product contracting every feature
+        axis of both operands."""
         with jax.named_scope("gram_matmul"):
-            return self._product(self._left(self.c), self.c)
+            g = lax.dot_general(
+                self._left(self.c),
+                self.c,
+                ((self._features, self._features), ((), ())),
+                **self._product,
+            )
+            # s8 plane: |G| ≤ (Σ|c_a|)·max|c_b| ≤ 127² < 2²⁴, the s32 →
+            # f32 cast is exact
+            return g.astype(jnp.float32)
 
 
 @jax.named_scope("gram_matmul")

@@ -198,11 +198,15 @@ def _make_feature_sharded_step(
     write-back stays slice-local (this shard's rows × its feature slice)
     with one psum over ``data``. Everything that reads the slice's count
     matrix C runs inside the branch of the plane ``text_gram``'s gate takes
-    (PR 28): the predict partial ``rows(C)·w_slice`` → ``[B_local]`` in
-    place of the ``sparse_text_dot`` gather, the G panel, the dual loop,
+    (PR 28): the predict partial ``rows(C·w_slice)`` → ``[B_local]`` in
+    place of the ``sparse_text_dot`` gather — taken over ALL rows of C and
+    then sliced, so the reduction sits in the epilogue of the product that
+    writes C and reads nothing (PR 30) — the G panel, the dual loop,
     and the write-back delta ``rows(C)ᵀ·α_local`` → ``[f_text_local]`` in
     place of the ``sparse_grad_text`` scatter, ``rows(C)`` being the row
-    panel the G product already slices. The collective inventory below is
+    panel the G product already slices: ONE array, cut from C on its
+    leading axis in the ``[B, k_hi, k_lo]`` the build wrote
+    (ops/gram.CountPlane). The collective inventory below is
     UNCHANGED by that: the same psums and all-gathers, of the same sizes,
     under the same scopes — those from the predict psum to the write-back
     psum now sit inside the branch, which every shard enters together
@@ -302,7 +306,9 @@ def _make_feature_sharded_step(
                 shard's rows of u, its partial G panel, the dual loop and
                 the slice-local write-back."""
                 with jax.named_scope("predict"):
-                    part = counts.dot(w_text)  # [B_local], over this slice
+                    # [B_local], over this slice: C·w over all rows in
+                    # the count build's epilogue, then this shard's
+                    part = counts.dot(w_text)
                     raw = (_psum(part, model_axis) + numeric @ w_num).astype(
                         dtype
                     )
