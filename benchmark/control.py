@@ -14,15 +14,16 @@ from .harness import say
 
 
 def run(cell: dict, args) -> dict:
-    limits = cell["config"]["correct"]["limits"]
+    cfg = cell["config"]
     ref, ref_stats = train.reference(cell, args.seed)
     low, low_stats = train.reference(cell, args.seed, precision=args.control)
+    key = compare.statistic_of(cfg).key
     total, lines = 0, []
     for s in low_stats:
         total += s["count"]
-        lines.append({"count": total, "batch": s["count"], "mse": s["mse"]})
+        lines.append({"count": total, "batch": s["count"], "stat": s[key]})
     v = compare.Verdict()
-    compare.training(v, limits, {"batches": lines, "weights": low.w},
+    compare.training(v, cfg, {"batches": lines, "weights": low.w},
                      ref_stats, ref.w)
     say(f"control {args.control}: correct = {v.ok} (has to be False)")
     return {"control": args.control, "correct": v.ok, "numbers": v.numbers}

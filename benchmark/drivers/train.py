@@ -1,8 +1,14 @@
 """Driver of the training cells (traffic ``kind: train``): the served path
 from outside.
 
-  feeder (child) --HTTP/1.1 chunked--> apps.linear_regression.run (this
+  feeder (child) --HTTP/1.1 chunked--> twtml_tpu.apps.<app>.run (this
   process, owns the chip) --per-batch stats POST--> sink (child)
+
+The LEARNER is data: the configuration file's ``app`` names the entry point,
+its ``reference`` the plain reference (one signature, ``reference`` below),
+its ``correct.statistic`` the rule the printed statistic is held by
+(``compare.STATISTICS``), and the mix's ``generator`` where the labels come
+from. A second learner adds files and edits none.
 
 One run: set-up (children, device, native library), a CHECK run of the same
 entry point with the same flags on the first batches of the seeded pool, the
@@ -15,6 +21,7 @@ come from the sink's record alone.
 from __future__ import annotations
 
 import contextlib
+import importlib
 import json
 import math
 import os
@@ -33,9 +40,12 @@ WARMUP_LIMIT_S = 900.0   # a cold first run compiles several shapes in-stream
 
 
 class Tee:
-    """Takes the program's stdout: keeps its per-batch lines (``count: N
-    batch: b  mse: M ...``, apps/linear_regression.handle) with a stamp and
-    passes nothing on (a window prints hundreds)."""
+    """Takes the program's stdout: keeps its per-batch lines with a stamp
+    and passes nothing on (a window prints hundreds). A line is read BY
+    POSITION, ``count: N  batch: b  <name>: v ...``: every app prints that
+    shape (apps/linear_regression.handle ``mse: M``, an integer;
+    apps/logistic_regression.handle ``errRate: r``, three decimals), and
+    what ``v`` means is the configuration's ``correct.statistic``."""
 
     def __init__(self):
         self.batches: list = []
@@ -49,7 +59,7 @@ class Tee:
                 f = line.split()
                 self.batches.append({
                     "count": int(f[1]), "batch": int(f[3]),
-                    "mse": float(f[5]), "t": time.monotonic(),
+                    "stat": float(f[5]), "t": time.monotonic(),
                 })
         return len(text)
 
@@ -66,6 +76,12 @@ def program_flags(cfg: dict, backend: str, ckpt: str, sink_url: str,
         "--seconds", "0", "--checkpointDir", ckpt, "--twtweb", sink_url,
         "--lightning", harness.CLOSED, *cfg["flags"], *extra,
     ]
+
+
+def app_of(cfg: dict):
+    """The entry point the configuration names: ``twtml_tpu.apps.<app>``,
+    whose ``run(conf, max_batches=0)`` every app has."""
+    return importlib.import_module(f"twtml_tpu.apps.{cfg['app']}")
 
 
 def start_children(work: str, cell: dict, seed: int):
@@ -127,7 +143,6 @@ def prepare_program(cell: dict, feeder, sink, rehearse: bool,
 def check_run(cell: dict, backend: str, sink_url: str, work: str) -> dict:
     """The same entry point, the same flags, on the first ``check_batches``
     batches of the pool: per-batch lines and the verified checkpoint."""
-    from twtml_tpu.apps import linear_regression
     from twtml_tpu.config import ConfArguments
     from twtml_tpu.serving import load_servable
 
@@ -140,7 +155,7 @@ def check_run(cell: dict, backend: str, sink_url: str, work: str) -> dict:
     n = int(cell["traffic"]["check_batches"])
     t0 = time.monotonic()
     with contextlib.redirect_stdout(tee):
-        totals = linear_regression.run(conf, max_batches=n)
+        totals = app_of(cell["config"]).run(conf, max_batches=n)
     snapshot, reason = load_servable(ckpt)
     if snapshot is None:
         raise RuntimeError(f"the check run left no servable checkpoint: {reason}")
@@ -152,12 +167,14 @@ def check_run(cell: dict, backend: str, sink_url: str, work: str) -> dict:
 
 
 def reference(cell: dict, seed: int, precision: str = "float64"):
-    """The plain reference on the same first batches, from the generator's
-    truth columns. Returns ``(model, stats per batch)``."""
-    from ..reference import linear_sgd
-
+    """The configuration's plain reference (the module its ``reference``
+    names) on the same first batches, from the generator's truth columns.
+    Every reference has ONE signature, ``train_on_chunks(chunks, *,
+    batch_rows, n_batches, model, generator, precision)``, and picks what it
+    needs out of the configuration's ``model`` and the mix's ``generator``
+    itself. Returns ``(model with .w, stats per batch)``."""
     cfg, traffic = cell["config"], cell["traffic"]
-    g, m = traffic["generator"], cfg["model"]
+    g = traffic["generator"]
     n = int(traffic["check_batches"])
     rows = n * cfg["batch_rows"]
     # enough chunks to hold `rows` kept lines at the mix's keep share
@@ -171,11 +188,11 @@ def reference(cell: dict, seed: int, precision: str = "float64"):
                        min(gen.CHUNK, g["pool_lines"] - c * gen.CHUNK))
         for c in range(n_chunks)
     ]
-    return linear_sgd.train_on_chunks(
+    return manifest.load_module(
+        os.path.join(manifest.ROOT, cfg["reference"])
+    ).train_on_chunks(
         chunks, batch_rows=cfg["batch_rows"], n_batches=n,
-        num_text_features=m["numTextFeatures"], now_ms=g["now_ms"],
-        num_iterations=m["numIterations"], step_size=m["stepSize"],
-        l2_reg=m["l2Reg"], precision=precision,
+        model=cfg["model"], generator=g, precision=precision,
     )
 
 
@@ -199,9 +216,11 @@ class Window:
     """Warm-up, the measured window and the clean stop, from a harness
     thread while the program runs on the main thread."""
 
-    def __init__(self, cell, sink, seconds, trace, work, stop):
+    def __init__(self, cell, sink, seconds, trace, work, stop,
+                 rehearse=False):
         self.cell, self.sink, self.seconds = cell, sink, float(seconds)
         self.trace, self.work, self.stop = trace, work, stop
+        self.rehearse = rehearse
         self.done = threading.Event()
         self.error = ""
         self.t_begin = time.monotonic()
@@ -251,6 +270,13 @@ class Window:
                     os.path.join(self.work, "profile"))
                 self.profile.take(self.cell["traffic"]["profile_seconds"])
             self.done.wait(max(0.0, self.t_open + self.seconds - time.monotonic()))
+            # a rehearsal (no metric is printed) keeps its window open until
+            # one pass of its tiny pool was published inside it, however slow
+            # a batch is where it runs: seconds each into 2^20 dims on a CPU
+            while self.rehearse and not self.done.is_set() and sum(
+                    1 for r in self.records() if r["t"] >= self.t_open
+            ) < pool_batches(self.cell):
+                self.done.wait(0.1)
             self.t_close = time.monotonic()
         except Exception as exc:   # reported by the main thread
             self.error = f"window thread failed: {exc!r}"
@@ -319,8 +345,8 @@ def check_only(cell: dict, args) -> dict:
             checked = check_run(cell, backend, sink_url, work)
             model, ref_stats = reference(cell, seed)
             v = compare.Verdict()
-            compare.training(v, cell["config"]["correct"]["limits"], checked,
-                             ref_stats, model.w, tag=f"seed{seed}_")
+            compare.training(v, cell["config"], checked, ref_stats, model.w,
+                             tag=f"seed{seed}_")
             readings[str(seed)] = v.numbers
             ok = ok and v.ok
         finally:
@@ -331,11 +357,9 @@ def check_only(cell: dict, args) -> dict:
 
 
 def _run(cell, args, t_start, work, feeder, sink) -> dict:
-    from twtml_tpu.apps import linear_regression
     from twtml_tpu.config import ConfArguments
 
     cfg, traffic = cell["config"], cell["traffic"]
-    limits = cfg["correct"]["limits"]
     ident, backend, sink_url, pool = prepare_program(
         cell, feeder, sink, args.rehearse, t_start)
     compiles = harness.CompileCounter.install()
@@ -353,10 +377,10 @@ def _run(cell, args, t_start, work, feeder, sink) -> dict:
     tee = Tee()
     arm_interrupt()
     window = Window(cell, sink, args.seconds, args.trace, work,
-                    interrupt_main).start()
+                    interrupt_main, args.rehearse).start()
     try:
         with contextlib.redirect_stdout(tee):
-            totals = linear_regression.run(conf)
+            totals = app_of(cfg).run(conf)
     finally:
         window.join()
     if window.error or not window.t_close:
@@ -392,15 +416,15 @@ def _run(cell, args, t_start, work, feeder, sink) -> dict:
     # (the sentinel skipped it, or the publish was dropped), or not finite
     posted = {r["count"] for r in recs}
     failed = sum(1 for b in printed
-                 if b["count"] not in posted or not math.isfinite(b["mse"]))
+                 if b["count"] not in posted or not math.isfinite(b["stat"]))
 
     # ---- correct
     model, ref_stats = reference(cell, args.seed)
-    compare.training(verdict, limits, checked, ref_stats, model.w)
+    compare.training(verdict, cfg, checked, ref_stats, model.w)
     # the timed run itself replays the pool from its start: its own first
     # batches are held to the same reference
     compare.training(
-        verdict, limits,
+        verdict, cfg,
         {"batches": tee.batches[:len(ref_stats)], "weights": None},
         ref_stats, None, tag="window_",
     )
@@ -409,8 +433,8 @@ def _run(cell, args, t_start, work, feeder, sink) -> dict:
     if any(r["batch"] != rows for r in recs) or any(d != b for d, b in steps):
         verdict.fail("the sink's count is not a whole number of full batches "
                      "of kept tweets")
-    if any(not math.isfinite(b["mse"]) for b in tee.batches):
-        verdict.fail("a batch published a non-finite mse")
+    if any(not math.isfinite(b["stat"]) for b in tee.batches):
+        verdict.fail("a batch published a non-finite statistic")
     fed = feeder_share(feeder, t_open, t_close)
     if fed is None:
         verdict.fail("the feeder's record does not span the window on one "

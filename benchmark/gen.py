@@ -73,9 +73,59 @@ class Vocab:
     cdf_other: np.ndarray
 
 
-def _zipf_cdf(n: int, a: float) -> np.ndarray:
+def _zipf_p(n: int, a: float) -> np.ndarray:
     p = 1.0 / np.arange(1, n + 1, dtype=np.float64) ** a
-    return np.cumsum(p / p.sum())
+    return p / p.sum()
+
+
+def _zipf_cdf(n: int, a: float) -> np.ndarray:
+    return np.cumsum(_zipf_p(n, a))
+
+
+def _place_lexicon(g: dict, seed: int, tokens: list) -> None:
+    """The mix's ``generator.lexicon``: ``{"positive": [words], "negative":
+    [words], "slot_share_positive": p, "slot_share_negative": q}`` — the
+    source of a label that is read from the TEXT (a learner whose label is a
+    word count; the rule itself is the program's and the reference's, each
+    its own copy: here are only the words, as data of the mix).
+
+    Each listed word takes the place of an all-ASCII token OF THE SAME
+    LENGTH, so every token keeps its units, its ASCII-ness and its rank: a
+    line's length, its word count and its filler are the lexicon-free mix's,
+    hence a block's multiset of text lengths and every compiled shape. The
+    ranks are chosen so that a list's words together hold ``p`` (``q``) of
+    the word slots: a slot's chance of token ``id`` is its Zipf mass among
+    the ASCII tokens in an all-ASCII tweet and among all tokens in the
+    ``non_ascii_tweet_share`` others. Words are dealt in an order drawn from
+    the seed (a stream of its own: the vocabulary's and the lines' draws are
+    untouched), each to the free token of its length whose mass is nearest
+    to an equal part of what its list still lacks."""
+    lex = g["lexicon"]
+    rng = np.random.default_rng([int(seed), 0x6C6578])
+    a = float(g["zipf_exponent"])
+    s = float(g["non_ascii_tweet_share"])
+    ids = np.flatnonzero([t.isascii() for t in tokens])
+    length = np.array([len(tokens[i]) for i in ids])
+    mass = (1 - s) * _zipf_p(len(ids), a) + s * _zipf_p(len(tokens), a)[ids]
+    free = np.ones(len(ids), dtype=bool)
+    both = list(lex["positive"]) + list(lex["negative"])
+    if len(set(both)) != len(both) or not all(
+            w.isascii() and w.islower() and " " not in w for w in both):
+        raise SystemExit("benchmark: a lexicon lists distinct lower-case "
+                         "ASCII words, each in one list")
+    for words, share in ((lex["positive"], lex["slot_share_positive"]),
+                         (lex["negative"], lex["slot_share_negative"])):
+        lacks = float(share)
+        for dealt, k in enumerate(rng.permutation(len(words))):
+            w = words[k]
+            fits = np.flatnonzero(free & (length == len(w)))
+            if not fits.size:
+                raise SystemExit(f"benchmark: no free ASCII token of "
+                                 f"{len(w)} units for the lexicon's {w!r}")
+            part = max(lacks / (len(words) - dealt), mass.min())
+            j = fits[np.argmin(np.abs(np.log(mass[fits] / part)))]
+            tokens[ids[j]], free[j] = w, False
+            lacks -= mass[j]
 
 
 def build_vocab(g: dict, seed: int) -> Vocab:
@@ -120,6 +170,8 @@ def build_vocab(g: dict, seed: int) -> Vocab:
         elif extra[i] < 0.15:
             w = "http://t.co/" + w
         tokens.append(w)
+    if g.get("lexicon"):   # absent: not one draw more, the pool as it was
+        _place_lexicon(g, seed, tokens)
     is_ascii = np.array([t.isascii() for t in tokens])
     a = float(g["zipf_exponent"])
     ascii_ids = np.flatnonzero(is_ascii)

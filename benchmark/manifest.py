@@ -224,6 +224,22 @@ def lint(manifest_path: str = MANIFEST) -> list:
             wc = work_count_path({"name": n, **body})
             if not os.path.isfile(wc):
                 bad(f"config {n!r}: no work count at {os.path.relpath(wc, ROOT)}")
+            # the learner is named in the file: both names have to lead
+            # somewhere (file checks: the lint imports neither)
+            app = body.get("app")
+            if app is not None and not (
+                isinstance(app, str) and app.isidentifier() and os.path.isfile(
+                    os.path.join(ROOT, "twtml_tpu", "apps", app + ".py"))
+            ):
+                bad(f"config {n!r}: app {app!r} names no twtml_tpu/apps/<app>.py")
+            ref = body.get("reference")
+            if ref is not None and not (
+                isinstance(ref, str) and PATH.match(ref) and under_paths(ref)
+                and ref.endswith(".py")
+                and os.path.isfile(os.path.join(ROOT, ref))
+            ):
+                bad(f"config {n!r}: reference {ref!r} names no .py file "
+                    "under paths")
         files.append(f)
         red = c["reduced"]
         if not (isinstance(red, list) and len(red) <= 16

@@ -137,11 +137,10 @@ class LinearSGD:
         return stats
 
 
-def train_on_chunks(chunks, *, batch_rows, n_batches, num_text_features,
-                    now_ms, **model_kw):
-    """Filter the generated lines, cut them into ``n_batches`` batches of
-    ``batch_rows`` kept lines in stream order and train. Returns
-    ``(model, [stats per batch])``."""
+def kept_batches(chunks, batch_rows, n_batches):
+    """The generator's truth for the lines the filter keeps, in stream
+    order, cut into ``n_batches`` batches of ``batch_rows``: yields
+    ``(texts, followers, favourites, friends, created_ms, retweets)``."""
     text, cols5 = [], [[] for _ in range(5)]
     for ch in chunks:
         keep = np.flatnonzero(ch.kept)
@@ -155,13 +154,31 @@ def train_on_chunks(chunks, *, batch_rows, n_batches, num_text_features,
             f"{len(text)} kept lines cannot fill {n_batches} batches of "
             f"{batch_rows}"
         )
-    model = LinearSGD(num_text_features, **model_kw)
-    out = []
     for b in range(n_batches):
         s = slice(b * batch_rows, (b + 1) * batch_rows)
+        yield (text[s], *(c[s] for c in cols5))
+
+
+def train_on_chunks(chunks, *, batch_rows, n_batches, model, generator,
+                    precision="float64"):
+    """The ONE signature every reference has (``drivers/train.reference``
+    calls the module the configuration's ``reference`` names): ``model`` is
+    the configuration file's ``model`` object, ``generator`` the mix's; a
+    reference picks what it needs out of them itself. Filters the generated
+    lines, cuts them into ``n_batches`` batches of ``batch_rows`` kept lines
+    in stream order and trains. Returns ``(model with .w, [stats per
+    batch])``; the printed statistic is each batch's ``mse``."""
+    f = int(model["numTextFeatures"])
+    learner = LinearSGD(
+        f, num_iterations=model["numIterations"], step_size=model["stepSize"],
+        l2_reg=model["l2Reg"], precision=precision,
+    )
+    out = []
+    for text, followers, favourites, friends, created, retweets in kept_batches(
+            chunks, batch_rows, n_batches):
         rows, cols, numeric = featurize(
-            text[s], cols5[0][s], cols5[1][s], cols5[2][s], cols5[3][s],
-            now_ms, num_text_features,
+            text, followers, favourites, friends, created,
+            generator["now_ms"], f,
         )
-        out.append(model.step_batch(rows, cols, numeric, cols5[4][s]))
-    return model, out
+        out.append(learner.step_batch(rows, cols, numeric, retweets))
+    return learner, out

@@ -7,7 +7,10 @@ a flag of the harness, not of the program); prints no device metric.
   3. every cell end to end through ``run.py --rehearse``, traced and not:
      feeder -> trainer -> sink (a four-chip cell would run on the
      four-device virtual mesh), and in each the reference against the
-     program on the check batches (``correct`` has to come out true).
+     program on the check batches (``correct`` has to come out true). A
+     rehearsal's window stays open until one pass of its tiny pool was
+     published (``drivers/train.Window``), so ``--seconds 2`` serves every
+     cell, however slow a batch is on the CPU.
 """
 
 from __future__ import annotations

@@ -86,7 +86,15 @@ def main(argv=None) -> int:
         driver = manifest.load_module(
             manifest.driver_path(cell["traffic"]["kind"]))
         result = driver.run(cell, args, T_START)
-        result.pop("numbers", None)   # each was printed beside its limit
+    # each number compared, beside its limit: the last lines of stderr, and
+    # the last key of the result's line
+    numbers = result.pop("numbers", None)
+    if numbers is not None:
+        for name, n in numbers.items():
+            print(f"correct: {name} = {n['value']:.6g} (limit {n['limit']:.6g})",
+                  file=sys.stderr)
+        sys.stderr.flush()
+        result["numbers"] = numbers
     print(json.dumps(result), flush=True)
     return 0
 

@@ -1,0 +1,178 @@
+"""What the yardstick promises a later PR, held in seconds and without jax:
+the lint is clean; the pools of the mixes that stand are the bytes they were
+before the generator learned the ``lexicon`` object; the flags the cells hand
+the program are the recorded lists; the comparison gives the recorded
+numbers on a recorded check run; a configuration whose ``app`` or
+``reference`` names nothing fails the lint; a mix with a ``lexicon`` keeps
+every block's multiset of text lengths and yields both labels.
+
+    python -m pytest benchmark/tests/test_contract.py -q
+"""
+
+import hashlib
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from benchmark import compare, feeder, gen, manifest
+from benchmark.drivers import train
+from benchmark.tests import fixture_tree
+from benchmark.tests.fixtures import logistic_ref
+
+# sha256 of feeder.build_body(traffic, seed)[0], recorded FROM THE PARENT
+# TREE (commit d20cfdb, before gen.py was edited in PR 31) by
+#   python3 -c "import hashlib; from benchmark import feeder, manifest; \
+#     t = manifest.load_json(manifest.traffic_path(MIX)); \
+#     print(hashlib.sha256(feeder.build_body(t, SEED)[0]).hexdigest())"
+POOLS = {
+    ("trimmed-kept", 3000000019):
+        "93683bdb1215a444a99d0095013d4ffa9ac68ae3ec1968589733534f4808aecf",
+    ("trimmed-kept", 2147483659):
+        "ea87e13e47abad6bface64f5a1700f506e0306d30b29f66f3b802c59d485c150",
+    ("trimmed-kept-280", 3000000019):
+        "48e8e83d2d215d9e6561f7680c1aca8c0edd5af6a4a08418ff981fa0e4b4985b",
+    ("trimmed-kept-280", 2147483659):
+        "bb769c95dcc68342b15b81d084a90a2fc9cd30792a303b1117f4a3f3ea7b8d12",
+}
+SHARED = ["--backend", "tpu", "--source", "twitter", "--ingest", "block",
+          "--seconds", "0", "--checkpointDir", "CKPT", "--twtweb",
+          "http://sink", "--lightning", "http://127.0.0.1:9"]
+HASH2E18 = ["--numTextFeatures", "262144", "--l2Reg", "0.1", "--batchBucket",
+            "2048", "--master", "local[1]"]
+FLAGS = {   # train.program_flags at the parent tree, per cell
+    "hash2e18-trimmed": SHARED + HASH2E18,
+    "hash2e18-trimmed-280": SHARED + HASH2E18,
+    "hash2e20-trimmed-280": SHARED + [
+        "--numTextFeatures", "1048576", "--l2Reg", "0.1", "--batchBucket",
+        "2048", "--modelShards", "2"],
+}
+
+
+def test_lint_is_clean():
+    assert manifest.lint() == []
+
+
+@pytest.mark.parametrize("mix,seed", sorted(POOLS))
+def test_pool_is_byte_identical_to_the_parents(mix, seed):
+    traffic = manifest.load_json(manifest.traffic_path(mix))
+    assert "lexicon" not in traffic["generator"]
+    body = feeder.build_body(traffic, seed)[0]
+    assert hashlib.sha256(body).hexdigest() == POOLS[mix, seed]
+
+
+@pytest.mark.parametrize("name", sorted(FLAGS))
+def test_program_flags_are_the_recorded_lists(name):
+    cell = manifest.cell(manifest.load(), name)
+    assert train.program_flags(
+        cell["config"], "tpu", "CKPT", "http://sink") == FLAGS[name]
+    assert set(FLAGS) == {w["name"] for w in manifest.load()["workloads"]}
+
+
+def test_half_up_integer_rule_gives_the_parents_numbers():
+    """A recorded check run; ``mse_dev`` and ``weights_dev`` as the parent's
+    ``compare.training`` printed them, to the last digit. A configuration
+    without ``correct.statistic`` takes this rule."""
+    program = {"batches": [{"count": 2048, "batch": 2048, "stat": 85012.0},
+                           {"count": 4096, "batch": 2048, "stat": 84127.0},
+                           {"count": 6144, "batch": 2048, "stat": 83990.0}],
+               "weights": [0.5, -0.25, 0.125, 1.0000005]}
+    ref = [{"count": 2048, "mse": 85012.0}, {"count": 2048, "mse": 84129.0},
+           {"count": 2048, "mse": 83990.0}]
+    cfg = {"correct": {"limits": {
+        "count_diff": 0, "weights_dev": 8.8e-06, "mse_dev": 2e-05}}}
+    v = compare.Verdict()
+    compare.training(v, cfg, program, ref, [0.5, -0.25, 0.125000125, 1.0])
+    assert v.ok and v.numbers == {
+        "count_diff": {"value": 0.0, "limit": 0.0},
+        "mse_dev": {"value": 1.1886507625194642e-05, "limit": 2e-05},
+        "weights_dev": {"value": 3.3333331114290227e-07, "limit": 8.8e-06},
+    }
+
+
+@pytest.mark.parametrize("got,near,dev", [
+    (0.062, 0, 0.0),    # 128/2048 = 0.0625 printed to three decimals: rounding
+    (0.063, 0, 0.0),    # (at the edge it reads float fuzz, 4e-19: under any limit)
+    (0.064, 0, 0.001),  # three rows of 2048 classed otherwise: over the limit
+    (0.064, 3, 0.0),    # ... unless the reference says three rows sit at the edge
+    (0.125, 0, 0.062),  # half of the batch left out
+])
+def test_rate_rule(got, near, dev):
+    ref = {"count": 2048, "rate": 128 / 2048, "near_rows": near}
+    assert compare.STATISTICS["rate"].deviation(got, ref) == pytest.approx(dev, abs=1e-15)
+    with pytest.raises(SystemExit):
+        compare.statistic_of({"correct": {"statistic": "median"}})
+
+
+def _lint_of(tree):
+    p = subprocess.run([sys.executable, "-m", "benchmark.lint"], cwd=tree,
+                       capture_output=True, text=True, timeout=120)
+    return p.returncode, p.stdout
+
+
+@pytest.mark.parametrize("key,value,fault", [
+    (None, None, ""),
+    ("app", "no_such_learner", "names no twtml_tpu/apps/<app>.py"),
+    ("reference", "benchmark/reference/absent.py", "names no .py file"),
+    ("reference", "tests/conftest.py", "names no .py file"),   # outside paths
+])
+def test_lint_follows_the_names_in_a_configuration(key, value, fault):
+    tree = fixture_tree.build(os.path.join(
+        manifest.ROOT, "_scratch", "contract_lint"))
+    try:
+        path = os.path.join(tree, "benchmark", "configs",
+                            fixture_tree.CONFIG + ".json")
+        cfg = manifest.load_json(path)
+        if key:
+            cfg[key] = value
+            with open(path, "w", encoding="utf-8") as fh:
+                json.dump(cfg, fh)
+        rc, out = _lint_of(tree)
+        assert (rc, fault in out) == ((1, True) if key else (0, True)), out
+    finally:
+        fixture_tree.remove(tree)
+
+
+@pytest.mark.parametrize("seed", [3000000019, 7])
+def test_lexicon_keeps_the_lengths_and_yields_both_labels(seed):
+    plain = manifest.load_json(manifest.traffic_path("trimmed-kept-280"))["generator"]
+    lex = manifest.load_json(os.path.join(fixture_tree.FIXTURES, "lexicon.json"))["lexicon"]
+    with_lex = dict(plain, lexicon=lex)
+    v0, v1 = gen.build_vocab(plain, seed), gen.build_vocab(with_lex, seed)
+    # every token keeps its length and its ASCII-ness; only listed words differ
+    assert np.array_equal(v0.units, v1.units)
+    assert np.array_equal(v0.ascii_ids, v1.ascii_ids)
+    changed = [b for a, b in zip(v0.tokens, v1.tokens) if a != b]
+    assert set(changed) <= set(lex["positive"]) | set(lex["negative"])
+    assert set(lex["positive"]) | set(lex["negative"]) <= set(v1.tokens)
+    block = plain["length_block"]
+    c0 = gen.make_chunk(plain, v0, seed, 0, 2 * block)
+    c1 = gen.make_chunk(with_lex, v1, seed, 0, 2 * block)
+    # line for line the same lengths (hence every block's multiset, hence
+    # every compiled shape), the same numeric truth
+    assert [gen._units(t) for t in c0.text] == [gen._units(t) for t in c1.text]
+    assert [len(x) for x in c0.lines] == [len(x) for x in c1.lines]
+    assert np.array_equal(c0.retweets, c1.retweets)
+    assert np.array_equal(c0.followers, c1.followers)
+    for b in range(2):
+        y = logistic_ref.labels_of(c1.text[b * block:(b + 1) * block], lex)
+        assert 0.05 <= np.mean(y == 0.0) <= 0.30
+    # without the lexicon (all but) every tweet is labelled alike
+    assert np.mean(logistic_ref.labels_of(c0.text, lex) == 0.0) < 0.002
+
+
+@pytest.mark.parametrize("seed", [3000000019, 7, 2147483659])
+def test_lexicon_words_hold_their_stated_share_of_the_slots(seed):
+    g = manifest.load_json(manifest.traffic_path("trimmed-kept-280"))["generator"]
+    lex = manifest.load_json(os.path.join(fixture_tree.FIXTURES, "lexicon.json"))["lexicon"]
+    g = dict(g, lexicon=lex)
+    vocab = gen.build_vocab(g, seed)
+    chunk = gen.make_chunk(g, vocab, seed, 0, 4096)
+    words = [w for t in chunk.text for w in t.split(" ")]
+    for key, share in (("positive", "slot_share_positive"),
+                       ("negative", "slot_share_negative")):
+        got = sum(w in set(lex[key]) for w in words) / len(words)
+        assert got == pytest.approx(lex[share], rel=0.15)

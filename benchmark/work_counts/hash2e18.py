@@ -1,10 +1,13 @@
-"""Work one train step of the 2^18-dim hashed learner NEEDS, per chip.
+"""Work one train step of the hashed learner NEEDS, per chip (``hash2e18``
+on one chip, and ``hash2e20`` over its four through the file's
+``work_count``).
 
 FLOPs: the full text Gram ``G = Z·Zᵀ``, 2·B²·F, counted against the INT8
 peak because the s8×s8→s32 plane is the fastest the program has
 (``ops/gram.py``: a batch whose rows all hold at most 127 bigrams takes it;
-the benchmark's mixes take the bf16 and the exact f32 plane, PERF.md section
-4, and are held to the same floor); the [B]-sized dual loop and the
+the benchmark's mixes take the bf16 plane, by rung 1 or by rung 2 of its
+gate, PERF.md section 4, and are held to the same floor; no cell takes the
+exact f32 plane since PR 25); the [B]-sized dual loop and the
 write-back are left out (lower bound). Bytes: the one-hot densify writes the
 ``[B, F]`` s8 count matrix once and the matmul reads it once as each
 operand, plus the packed wire and ``G`` in f32. At B = 2048 that is
