@@ -257,85 +257,147 @@ int32_t pad_units_batch_u8(const uint16_t* units, const int64_t* offsets,
 }
 
 // Lexicon sentiment scorer over raw UTF-16 units (features/sentiment.py's
-// C hot path). Tokenization matches the Python `[a-z']+` regex over
-// lowercased text for ASCII rows: A-Z fold inline, every other unit is a
-// separator. Rows containing units >= 128 are flagged not-ok (out_ok = 0)
-// and the caller scores them in Python — Unicode lowercasing can change
-// token boundaries, so exact parity demands the Python path there.
+// C hot path), exact for EVERY row: it tokenizes as Python's `[a-z']+`
+// regex does over `text.lower()`. A-Z fold inline. Over all of Unicode only
+// two code points >= 128 lower-case into `[a-z']`: U+0130 (to `i` + U+0307:
+// the token takes an `i` and ends, U+0307 being a separator) and U+212A
+// (to `k`); both are handled here, and every other unit >= 128 — the halves
+// of a surrogate pair included, since no astral code point lower-cases into
+// ASCII — is a separator (tests/test_sentiment_labeler.py checks every code
+// point). Units arrive as uint16, or as the narrow wire's uint8
+// (`unit_bytes` 1: no widened copy of the block).
 // Lexicon words arrive as concatenated units + offsets with precomputed
-// Java-hashCode values; a hash hit verifies the actual units, so a
-// colliding non-lexicon token can never flip a label vs the Python set.
+// Java-hashCode values; both lists go into one small open-addressed table
+// on that hash (built per call: 63 inserts), and a hash hit verifies the
+// actual units, so a colliding non-lexicon token can never flip a label vs
+// the Python set.
+}  // extern "C" (templates cannot have C linkage)
+
 namespace {
-int32_t lexicon_find(const uint16_t* tok, int32_t tok_len, int32_t tok_hash,
-                     const uint16_t* words, const int64_t* word_off,
-                     const int32_t* word_hash, int32_t n_words) {
-  for (int32_t w = 0; w < n_words; ++w) {
-    if (word_hash[w] != tok_hash) continue;
-    const int64_t len = word_off[w + 1] - word_off[w];
-    if (len != tok_len) continue;
-    if (std::memcmp(words + word_off[w], tok,
-                    tok_len * sizeof(uint16_t)) == 0)
-      return w;
+constexpr int32_t kLexSlots = 256;  // power of two, >= 2x the words held
+constexpr int32_t kLexMaxWord = 31;  // a longer listed word is refused
+
+// `[a-z']` after lower-casing, else 0 (a separator)
+struct LexFold {
+  uint8_t ascii[128];
+  constexpr LexFold() : ascii() {
+    for (int u = 0; u < 128; ++u)
+      ascii[u] = (u >= 'a' && u <= 'z')   ? u
+                 : (u >= 'A' && u <= 'Z') ? u + 32
+                 : u == '\''              ? u
+                                          : 0;
   }
-  return -1;
+};
+constexpr LexFold kLexFold;
+
+inline uint16_t lex_fold(uint16_t u) {
+  if (u < 128) return kLexFold.ascii[u];
+  if (u == 0x212A) return 'k';
+  return u == 0x0130 ? 'i' : 0;
+}
+
+struct LexTable {
+  int32_t hash[kLexSlots];
+  const uint16_t* word[kLexSlots];
+  int8_t len[kLexSlots];  // 0 = empty slot
+  int8_t sign[kLexSlots];
+  // bit L of lens_of[c & 31]: some word of L units starts with letter c (a
+  // token that fails it is no lexicon word: no fold, no hash, no probe)
+  uint32_t lens_of[32];
+  int32_t used = 0;
+
+  LexTable() {
+    std::memset(len, 0, sizeof(len));
+    std::memset(lens_of, 0, sizeof(lens_of));
+  }
+
+  static int32_t slot_of(int32_t h) {
+    return static_cast<int32_t>((static_cast<uint32_t>(h) * 0x9E3779B1u) >> 24);
+  }
+
+  void add(const uint16_t* words, const int64_t* off, const int32_t* hashes,
+           int32_t n, int8_t s) {
+    for (int32_t w = 0; w < n; ++w) {
+      const int32_t len_w = static_cast<int32_t>(off[w + 1] - off[w]);
+      // sentiment.py's _pack_lexicon refuses a lexicon that would trip these
+      if (len_w <= 0 || len_w > kLexMaxWord || ++used > kLexSlots / 2) return;
+      int32_t i = slot_of(hashes[w]);
+      while (len[i] != 0) i = (i + 1) & (kLexSlots - 1);
+      hash[i] = hashes[w];
+      word[i] = words + off[w];
+      len[i] = static_cast<int8_t>(len_w);
+      sign[i] = s;
+      lens_of[words[off[w]] & 31] |= 1u << len_w;
+    }
+  }
+
+  int32_t find(const uint16_t* tok, int32_t tok_len, int32_t tok_hash) const {
+    for (int32_t i = slot_of(tok_hash); len[i] != 0;
+         i = (i + 1) & (kLexSlots - 1)) {
+      if (hash[i] == tok_hash && len[i] == tok_len &&
+          std::memcmp(word[i], tok, tok_len * sizeof(uint16_t)) == 0)
+        return sign[i];
+    }
+    return 0;
+  }
+};
+
+template <typename Unit>
+void lexicon_score_rows(const Unit* units, const int64_t* offsets,
+                        int32_t batch, const LexTable& table,
+                        int32_t* out_score) {
+  for (int32_t b = 0; b < batch; ++b) {
+    const int64_t end = offsets[b + 1];
+    int32_t score = 0;
+    int64_t tok_start = -1;  // the open token's first unit, -1 = none
+    auto flush = [&](int64_t stop) {
+      const int64_t len = stop - tok_start;
+      const uint16_t first = lex_fold(units[tok_start]);
+      if (len <= kLexMaxWord && ((table.lens_of[first & 31] >> len) & 1u)) {
+        uint16_t tok[kLexMaxWord];
+        int32_t h = 0;
+        for (int64_t k = 0; k < len; ++k) {
+          tok[k] = lex_fold(units[tok_start + k]);
+          h = static_cast<int32_t>(31u * static_cast<uint32_t>(h) +
+                                   static_cast<uint32_t>(tok[k]));
+        }
+        score += table.find(tok, static_cast<int32_t>(len), h);
+      }
+      tok_start = -1;
+    };
+    for (int64_t i = offsets[b]; i < end; ++i) {
+      const uint16_t u = units[i];
+      if (lex_fold(u)) {
+        if (tok_start < 0) tok_start = i;
+        if (u == 0x0130) flush(i + 1);  // `i` + U+0307: the token ends here
+      } else if (tok_start >= 0) {
+        flush(i);
+      }
+    }
+    if (tok_start >= 0) flush(end);
+    out_score[b] = score;
+  }
 }
 }  // namespace
 
-void lexicon_score_batch(const uint16_t* units, const int64_t* offsets,
-                         int32_t batch,
+extern "C" {
+
+void lexicon_score_batch(const void* units, int32_t unit_bytes,
+                         const int64_t* offsets, int32_t batch,
                          const uint16_t* pos_words, const int64_t* pos_off,
                          const int32_t* pos_hash, int32_t n_pos,
                          const uint16_t* neg_words, const int64_t* neg_off,
                          const int32_t* neg_hash, int32_t n_neg,
-                         int32_t* out_score, uint8_t* out_ok) {
-  for (int32_t b = 0; b < batch; ++b) {
-    const int64_t start = offsets[b];
-    const int64_t end = offsets[b + 1];
-    bool ascii = true;
-    for (int64_t i = start; i < end; ++i)
-      if (units[i] >= 128) { ascii = false; break; }
-    if (!ascii) {
-      out_ok[b] = 0;
-      out_score[b] = 0;
-      continue;
-    }
-    int32_t score = 0;
-    uint16_t tok[64];
-    int32_t tok_len = 0;
-    int32_t tok_hash = 0;
-    bool overflow = false;
-    auto flush = [&]() {
-      if (tok_len > 0 && !overflow) {
-        if (lexicon_find(tok, tok_len, tok_hash, pos_words, pos_off,
-                         pos_hash, n_pos) >= 0)
-          ++score;
-        else if (lexicon_find(tok, tok_len, tok_hash, neg_words, neg_off,
-                              neg_hash, n_neg) >= 0)
-          --score;
-      }
-      tok_len = 0;
-      tok_hash = 0;
-      overflow = false;
-    };
-    for (int64_t i = start; i < end; ++i) {
-      uint16_t u = units[i];
-      if (u >= 'A' && u <= 'Z') u += 32;
-      if ((u >= 'a' && u <= 'z') || u == '\'') {
-        if (tok_len < 64) {
-          tok[tok_len++] = u;
-          tok_hash = static_cast<int32_t>(31u * static_cast<uint32_t>(tok_hash) +
-                                          static_cast<uint32_t>(u));
-        } else {
-          overflow = true;  // longer than any lexicon word: never matches
-        }
-      } else {
-        flush();
-      }
-    }
-    flush();
-    out_score[b] = score;
-    out_ok[b] = 1;
-  }
+                         int32_t* out_score) {
+  LexTable table;
+  table.add(pos_words, pos_off, pos_hash, n_pos, 1);
+  table.add(neg_words, neg_off, neg_hash, n_neg, -1);
+  if (unit_bytes == 1)
+    lexicon_score_rows(static_cast<const uint8_t*>(units), offsets, batch,
+                       table, out_score);
+  else
+    lexicon_score_rows(static_cast<const uint16_t*>(units), offsets, batch,
+                       table, out_score);
 }
 
 }  // extern "C"

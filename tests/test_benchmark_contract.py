@@ -1,0 +1,21 @@
+"""Tier-1 sees the benchmark's contract: the cases of
+``benchmark/tests/test_contract.py`` (no jax, seconds) run here too, so a PR
+that breaks what the yardstick promises a later PR — a clean lint, the pools
+of the mixes that stand byte for byte, the flags each cell hands the
+program, the comparison's recorded numbers, the rate rule, the lexicon's
+shares — fails tier-1 and not only a run by hand of ``benchmark/tests``.
+The flags of cells added since that file was written are recorded in
+``benchmark/tests/conftest.py`` (imported first: it is no conftest of THIS
+directory).
+"""
+
+import benchmark.tests.conftest as _added_since  # noqa: F401
+from benchmark.tests.test_contract import *  # noqa: F401,F403
+from benchmark.tests.test_logit2e18 import (  # noqa: F401
+    test_gate_counts_no_row_on_this_program,
+    test_gate_hands_over_to_train_unchanged,
+    test_gate_refuses_a_labeler_that_falls_back,
+    test_program_flags_are_the_recorded_list,
+    test_the_mix_names_the_gated_driver,
+    test_the_cell_is_the_fixtures_learner_on_its_own_files,
+)

@@ -25,7 +25,9 @@ from ..features.sentiment import (
     sentiment_labels_from_units,
 )
 from ..models.logistic import StreamingLogisticRegressionWithSGD
+from ..ops.quality import QUALITY_INDEX
 from ..streaming.context import StreamingContext
+from ..telemetry import metrics as _metrics
 from ..telemetry.session_stats import SessionStats
 from ..utils import get_logger, round_half_up
 from .common import (
@@ -120,11 +122,20 @@ def run(conf: ConfArguments, max_batches: int = 0) -> dict:
     _freshness.configure(conf)
     freshness_guard = FreshnessGuard(conf, ckpt, totals, lead=lead)
 
+    label0_share = _metrics.get_registry().gauge("model.label0_share")
+
     def handle(out, batch, _batch_time, at_boundary=True) -> None:
         b = int(out.count)
         totals["count"] += b
         totals["batches"] += 1
         err_rate = float(out.mse)  # 0/1 preds → MSE == misclassification rate
+        if getattr(out, "quality", None) is not None:
+            # a stream that labels every tweet alike trains nothing: the
+            # share of label 0, from the quality vector the batch's stats
+            # fetch already brought (psum-global; zero added fetches)
+            label0_share.set(round(1.0 - float(
+                np.asarray(out.quality)[..., QUALITY_INDEX["label_mean"]].mean()
+            ), 4))
         if lead:
             # per-row series are lead-local (followers don't fetch
             # predictions) and can be empty when the lead's own shard had

@@ -230,6 +230,9 @@ class Featurizer:
     # spans, so the straggler ladder can name WHICH half of featurize
     # gates a host). Three perf_counter reads per BATCH, never per tweet.
     last_substages: list = field(default_factory=list, init=False, repr=False)
+    # what a sub-stage's trace span carries beside its time, by name (the
+    # labeler's rows and bytes read): {name: {arg: value}}
+    last_substage_args: dict = field(default_factory=dict, init=False, repr=False)
 
     @classmethod
     def from_conf(cls, conf) -> "Featurizer":
@@ -404,6 +407,19 @@ class Featurizer:
         t1 = time.perf_counter()
         self.last_substages.append((name, t0, t1 - t0))
         return t1
+
+    def _label_units(self, label: np.ndarray, block, t0: float) -> float:
+        """``unit_label_fn`` over the block's ORIGINAL raw units into
+        ``label[:rows]``, as a sub-stage of its own (``featurize.label``:
+        host work that grows with the units read, not with the rows)."""
+        n = block.rows
+        label[:n] = self.unit_label_fn(block.units, block.offsets)
+        self.last_substage_args["label"] = {
+            "rows": n,
+            "bytes": int(block.offsets[n] - block.offsets[0])
+            * block.units.dtype.itemsize,
+        }
+        return self._sub("label", t0)
 
     def _apply_label_fns(self, label: np.ndarray, keep, encoded) -> bool:
         """Apply a configured custom labeler over ``label[:n]`` — the ONE
@@ -603,7 +619,7 @@ class Featurizer:
         discipline); only the wire stops paying for padding."""
         from .batch import RaggedUnitBatch, pad_row_count, ragged_wire_arrays
 
-        self.last_substages = []
+        self.last_substages, self.last_substage_args = [], {}
         t0 = time.perf_counter()
         keep, cols, units, offsets, all_ascii = (
             self._encode_batch_texts(statuses, pre_filtered)
@@ -679,7 +695,7 @@ class Featurizer:
         features bit-identical to `featurize_batch`'s. Host cost per batch
         drops to one encode + one vectorized pad — no per-bigram work at all.
         """
-        self.last_substages = []
+        self.last_substages, self.last_substage_args = [], {}
         t0 = time.perf_counter()
         keep, cols, units, offsets, all_ascii = (
             self._encode_batch_texts(statuses, pre_filtered)
@@ -738,7 +754,7 @@ class Featurizer:
                 "(Status-based label_fn/batch_label_fn need the object "
                 "ingest path)"
             )
-        self.last_substages = []
+        self.last_substages, self.last_substage_args = [], {}
         t0 = time.perf_counter()
         n = block.rows
         # one-pass native fast path (r18, --featurizeNative): in the
@@ -775,9 +791,7 @@ class Featurizer:
                 if n and self.unit_label_fn is not None:
                     # labels from the ORIGINAL raw units, like the ground
                     # truth below
-                    label[:n] = self.unit_label_fn(
-                        block.units, block.offsets
-                    )
+                    t0 = self._label_units(label, block, t0)
                 self._sub("numeric", t0)
                 batch = _RB(
                     flat, offs, numeric, label, mask,
@@ -850,16 +864,16 @@ class Featurizer:
             numeric[:n, 1] = cols64[:, COL_FAVOURITES] * COUNT_SCALE
             numeric[:n, 2] = cols64[:, COL_FRIENDS] * COUNT_SCALE
             numeric[:n, 3] = (now - cols64[:, COL_CREATED_MS]) * AGE_SCALE
-            if self.unit_label_fn is not None:
-                # labels from the ORIGINAL raw units (pre-lower/normalize:
-                # the object path labels over the original text too, and
-                # normalize_accents must never leak into labels — stripping
-                # 'bàd'→'bad' would change a lexicon hit)
-                label[:n] = self.unit_label_fn(block.units, block.offsets)
-            else:
-                label[:n] = cols64[:, COL_LABEL]
             mask[:n] = 1.0
+            if self.unit_label_fn is None:
+                label[:n] = cols64[:, COL_LABEL]
         t0 = self._sub("numeric", t0)
+        if n and self.unit_label_fn is not None:
+            # labels from the ORIGINAL raw units (pre-lower/normalize:
+            # the object path labels over the original text too, and
+            # normalize_accents must never leak into labels — stripping
+            # 'bàd'→'bad' would change a lexicon hit)
+            t0 = self._label_units(label, block, t0)
         if ragged:
             # the block ALREADY holds concatenated units + offsets — the
             # ragged wire ships them as-is (no pad copy at all); the jit

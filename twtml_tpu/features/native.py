@@ -269,7 +269,8 @@ def _load(path: str, strict: bool = True) -> ctypes.CDLL:
     ]
     lib.lexicon_score_batch.restype = None
     lib.lexicon_score_batch.argtypes = [
-        ctypes.POINTER(ctypes.c_uint16),  # units
+        ctypes.c_void_p,  # units (uint16, or the narrow wire's uint8)
+        ctypes.c_int32,  # unit_bytes
         ctypes.POINTER(ctypes.c_int64),  # offsets
         ctypes.c_int32,  # batch
         ctypes.POINTER(ctypes.c_uint16),  # pos_words
@@ -281,7 +282,6 @@ def _load(path: str, strict: bool = True) -> ctypes.CDLL:
         ctypes.POINTER(ctypes.c_int32),  # neg_hash
         ctypes.c_int32,  # n_neg
         ctypes.POINTER(ctypes.c_int32),  # out_score
-        ctypes.POINTER(ctypes.c_uint8),  # out_ok
     ]
     lib.parse_tweet_block.restype = ctypes.c_int64
     lib.parse_tweet_block.argtypes = [
@@ -980,25 +980,27 @@ def lexicon_scores(
     n: int,
     pos_lex: tuple[np.ndarray, np.ndarray, np.ndarray],
     neg_lex: tuple[np.ndarray, np.ndarray, np.ndarray],
-) -> tuple[np.ndarray, np.ndarray] | None:
-    """Batch lexicon sentiment scores over ragged UTF-16 units.
+) -> np.ndarray | None:
+    """Batch lexicon sentiment scores over ragged UTF-16 units (uint16, or
+    the narrow wire's uint8: read in place, no widened copy).
 
     ``pos_lex``/``neg_lex`` are (words_units, word_offsets, word_hashes)
-    from features/sentiment.py's packed lexicons. Returns (scores int32 [n],
-    ok uint8 [n]) — ok=0 rows contain non-ASCII units and must be scored in
-    Python for exact tokenization parity. None when the C library is
-    unavailable."""
+    from features/sentiment.py's packed lexicons (at most 64 words a list
+    and 31 units a word: ``_pack_lexicon`` asserts it). Returns scores int32 [n],
+    exact for every row (the scan handles non-ASCII units itself). None
+    when the C library is unavailable."""
     lib = get_lib()
     if lib is None:
         return None
     units, offsets = encoded
     assert offsets.size == n + 1, "encoded does not match the batch"
+    assert units.dtype.itemsize in (1, 2), units.dtype
     score = np.empty((n,), dtype=np.int32)
-    ok = np.empty((n,), dtype=np.uint8)
     pw, po, ph = pos_lex
     nw, no, nh = neg_lex
     lib.lexicon_score_batch(
-        units.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
+        units.ctypes.data,
+        units.dtype.itemsize,
         offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
         n,
         pw.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)),
@@ -1010,6 +1012,5 @@ def lexicon_scores(
         nh.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
         len(nh),
         score.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        ok.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
     )
-    return score, ok
+    return score

@@ -11,7 +11,9 @@ classifier's output if one is available.
 from __future__ import annotations
 
 import re
+import time
 
+from ..telemetry import trace as _trace
 from .featurizer import Status
 
 POSITIVE = frozenset(
@@ -50,6 +52,8 @@ def _pack_lexicon(words: frozenset) -> tuple:
     from .hashing import java_string_hashcode
 
     ws = sorted(words)
+    # what the C scorer's table holds: 64 words a list, 31 units a word
+    assert len(ws) <= 64 and max(map(len, ws)) <= 31, "lexicon too large"
     units = np.concatenate([
         np.frombuffer(w.encode("utf-16-le"), np.uint16) for w in ws
     ])
@@ -63,40 +67,60 @@ _POS_PACKED = _pack_lexicon(POSITIVE)
 _NEG_PACKED = _pack_lexicon(NEGATIVE)
 
 
+def _labels_from_scores(score, n: int, text_of) -> "np.ndarray":
+    """The ONE rule both labelers share: 1.0 where the row's lexicon score
+    is >= 0. ``score`` is the C scan's (exact for every row, non-ASCII
+    included); None — no C library — sends each of the ``n`` rows through
+    the per-row Python rule, ``text_of(i)`` giving row i's text (a
+    ``label_fallback`` span under ``--trace`` says how many rows paid)."""
+    import numpy as np
+
+    if score is not None:
+        return (score >= 0).astype(np.float32)
+    t0 = time.perf_counter()
+    labels = np.fromiter(
+        (1.0 if sentiment_score(text_of(i)) >= 0 else 0.0 for i in range(n)),
+        np.float32, n,
+    )
+    tr = _trace.get()
+    if tr.enabled:
+        tr.complete("label_fallback", t0, time.perf_counter() - t0, rows=n)
+    return labels
+
+
 def sentiment_labels(statuses: list, encoded=None) -> "np.ndarray":
     """Batched ``sentiment_label`` over the ORIGINAL texts — C hot path
-    (one scan over UTF-16 units), exact per-row Python fallback for
-    non-ASCII texts and when the library is unavailable.
+    (one scan over UTF-16 units, exact for non-ASCII rows too), per-row
+    Python rule when the library is unavailable.
 
     ``encoded``: optionally the featurizer's already-computed
     (units, offsets) of the originals' (lowercased) texts — skips a second
-    encode pass; the C scorer's ASCII fold is idempotent on pre-lowered
-    rows, and Python-scored fallback rows lowercase idempotently too."""
+    encode pass; the C scorer's folds (A-Z, U+0130, U+212A) are idempotent
+    on pre-lowered rows."""
     import numpy as np
 
     from . import native
 
     n = len(statuses)
-    out = None
-    if n and native.available():
+    if not n:
+        return np.zeros((0,), np.float32)
+    score = None
+    if native.available():
         if encoded is None:
             encoded = native.encode_texts(
                 [s.retweeted_status.text for s in statuses]
             )
-        out = native.lexicon_scores(encoded, n, _POS_PACKED, _NEG_PACKED)
-    if out is None:
-        return np.array([sentiment_label(s) for s in statuses], np.float32)
-    score, ok = out
-    labels = (score >= 0).astype(np.float32)
-    for i in np.nonzero(ok == 0)[0]:
-        labels[i] = sentiment_label(statuses[i])
-    return labels
+        score = native.lexicon_scores(encoded, n, _POS_PACKED, _NEG_PACKED)
+    return _labels_from_scores(
+        score, n, lambda i: statuses[i].retweeted_status.text
+    )
 
 
 def sentiment_labels_from_units(units, offsets) -> "np.ndarray":
     """Batched labels straight from ragged UTF-16 units — the block-ingest
-    path's labeler (no Status objects exist there). C scan for ASCII rows;
-    non-ASCII rows decode and score in Python (pre-lowered units score
+    path's labeler (no Status objects exist there). One C scan over every
+    row, uint16 units or the narrow wire's uint8 read in place; without the
+    library each row decodes and scores in Python (pre-lowered units score
     identically: sentiment_score lowercases idempotently)."""
     import numpy as np
 
@@ -105,22 +129,13 @@ def sentiment_labels_from_units(units, offsets) -> "np.ndarray":
     n = offsets.size - 1
     if n <= 0:
         return np.zeros((0,), np.float32)
-    if units.dtype == np.uint8:
-        # narrow-wire block (zero-copy parser): the C lexicon scan reads
-        # uint16 units — widen once; values are identical code units
-        units = units.astype(np.uint16)
-    out = native.lexicon_scores((units, offsets), n, _POS_PACKED, _NEG_PACKED)
-    if out is None:  # no C library: every row takes the Python loop below
-        score = np.zeros((n,), np.int32)
-        ok = np.zeros((n,), np.uint8)
-    else:
-        score, ok = out
-    labels = (score >= 0).astype(np.float32)
-    for i in np.nonzero(ok == 0)[0]:
-        text = (
-            units[offsets[i] : offsets[i + 1]]
-            .tobytes()
-            .decode("utf-16-le", "surrogatepass")
-        )
-        labels[i] = 1.0 if sentiment_score(text) >= 0 else 0.0
-    return labels
+    score = native.lexicon_scores(
+        (units, offsets), n, _POS_PACKED, _NEG_PACKED
+    )
+    return _labels_from_scores(
+        score, n,
+        lambda i: units[offsets[i] : offsets[i + 1]]
+        .astype(np.uint16, copy=False)
+        .tobytes()
+        .decode("utf-16-le", "surrogatepass"),
+    )

@@ -385,10 +385,13 @@ class FeatureStream(RawStream):
         if not subs:
             return
         agg: "dict[str, float]" = {}
+        carried = getattr(self.featurizer, "last_substage_args", None) or {}
         for name, sub_t0, dur in subs:
             agg[name] = agg.get(name, 0.0) + dur
             if tr is not None:
-                tr.complete("featurize." + name, sub_t0, dur)
+                tr.complete(
+                    "featurize." + name, sub_t0, dur, **carried.get(name, {})
+                )
         reg = _metrics.get_registry()
         for name, dur in agg.items():
             reg.gauge(f"featurize.{name}_ms").set(round(dur * 1e3, 4))
