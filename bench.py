@@ -100,7 +100,7 @@ def measure(
     def featurize(chunk):
         # ragged device wire: the host encodes raw code units and ships
         # them CONCATENATED (no per-row pad bytes), PACKED into one buffer;
-        # the fused device step re-pads with one gather and hashes bigrams
+        # the fused device step re-pads by lane rows and hashes bigrams
         # in-program. Bit-identical features (tests/test_ragged_wire.py,
         # test_device_hash.py). The tenant plane builds its own routed wire
         # at the model boundary (TenantStackModel.prepare_wire); the

@@ -707,3 +707,27 @@ def test_drain_splits_overshooting_blocks():
     merged = merge_blocks(drained + [b for b in rest])
     np.testing.assert_array_equal(merged.numeric, big.numeric)
     np.testing.assert_array_equal(merged.units, big.units)
+
+
+def test_drain_hands_over_one_merged_block():
+    """PR 33: a drain that takes several parsed blocks merges them ONCE at
+    the seam, so the lineage stamp, the journal's record and featurize all
+    read one block (each used to merge the list again): the drained list
+    holds a single block equal to the merge, the overshoot still split at
+    the cap and left at the queue's front."""
+    from twtml_tpu.features.blocks import slice_block
+    from twtml_tpu.streaming.context import StreamingContext
+    from twtml_tpu.streaming.sources import QueueSource
+
+    big = merge_blocks(list(BlockReplayFileSource(DATA).produce()))
+    assert big.rows >= 5
+    ssc = StreamingContext(batch_interval=0)
+    ssc.raw_stream(QueueSource(), row_bucket=4)
+    for lo, hi in ((0, 1), (1, 3), (3, big.rows)):
+        ssc._queue.put(slice_block(big, lo, hi))
+    (head,) = ssc._drain(4)
+    for got, want in zip(head, slice_block(big, 0, 4), strict=True):
+        np.testing.assert_array_equal(got, want)
+    (rest,) = ssc._drain(0)
+    for got, want in zip(rest, slice_block(big, 4, big.rows), strict=True):
+        np.testing.assert_array_equal(got, want)

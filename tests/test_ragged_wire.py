@@ -1,6 +1,6 @@
 """Ragged units wire (features/batch.RaggedUnitBatch): the concatenated
 units + offsets wire must produce BIT-IDENTICAL training to the padded
-UnitBatch wire — the device-side gather re-pad + ASCII fold replaces the
+UnitBatch wire — the device-side re-pad + ASCII fold replaces the
 host-side pad copy exactly. Parity law: features/hashing.py / the padded
 Status path is ground truth; every fast path carries differential tests."""
 
@@ -331,3 +331,73 @@ def test_ragged_stack_rejects_mixed_alignment():
     )
     with pytest.raises(ValueError, match="different row_len or shard"):
         stack_batches([a, align_ragged_shards(b, 2)])
+
+
+# ---------------------------------------------------------------------------
+# PR 33: the device re-pad moves whole 128-lane rows of the units buffer and
+# shifts (ops/ragged.py). Held to a plain NumPy re-pad, not to another jax
+# program.
+
+
+def _numpy_repad(units, starts, lens, row_len):
+    out = np.zeros((len(starts), row_len), np.int32)
+    for b, (s, n) in enumerate(zip(starts, lens)):
+        row = units[s:s + n].astype(np.int32)
+        out[b, :n] = np.where((row >= 65) & (row <= 90), row + 32, row)
+    return out
+
+
+def _segment_lengths(rng, row_len):
+    """One segment's row lengths: 128 rows of one unit (consecutive starts:
+    every residue mod 128), an empty row, a full row, random rows — and a
+    total that is NOT a multiple of 128."""
+    lens = [1] * 128 + [0, row_len, 0, row_len]
+    lens += list(rng.integers(0, row_len + 1, 24))
+    lens.append(row_len)  # the row that ends the sub-buffer
+    if sum(lens) % 128 == 0:
+        lens[-2] += 1 if lens[-2] < row_len else -1
+    return np.array(lens, np.int64)
+
+
+@pytest.mark.parametrize("segments, deltas", [
+    (1, False), (2, False), (4, False), (1, True),
+], ids=["plain", "aligned2", "aligned4", "deltas"])
+@pytest.mark.parametrize("row_len", [16, 256, 512])
+@pytest.mark.parametrize("dtype", [np.uint8, np.uint16])
+def test_device_repad_matches_numpy(dtype, row_len, segments, deltas):
+    """``ragged_repad`` against NumPy on every shape of the wire: uint8 and
+    uint16 units; the plain ``[B + 1]`` offsets, the shard-aligned layout of
+    2 and 4 segments and the narrow ``deltas`` wire (one segment: on the
+    aligned layout its caller decodes it, ``offsets_from_deltas``); starts at every residue
+    mod 128; rows of length 0 and L; junk (never zeros) after every row; a
+    last row that ends exactly at N with N no multiple of 128 (the static
+    pad) and, second, the same rows in a sub-buffer rounded up to the
+    wire's 4096 (the exact view)."""
+    import jax
+
+    from twtml_tpu.ops.ragged import ragged_repad
+
+    rng = np.random.default_rng(row_len + segments)
+    base = _segment_lengths(rng, row_len)
+    # every segment holds the same multiset of lengths in another order, so
+    # the sub-buffers are equally long, as align_ragged_shards makes them
+    seg_lens = [np.roll(base, 7 * s) for s in range(segments)]
+    total = int(base.sum())
+    assert total % 128
+    repad = jax.jit(ragged_repad, static_argnums=(2, 3, 4))
+    for sub in (total, -(-total // RAGGED_UNIT_MULTIPLE) * RAGGED_UNIT_MULTIPLE):
+        units = rng.integers(
+            1, np.iinfo(dtype).max, sub * segments).astype(dtype)
+        units[::5] = rng.integers(60, 95, len(units[::5]))  # around A-Z
+        rel = [np.concatenate([[0], np.cumsum(ln)]) for ln in seg_lens]
+        starts = np.concatenate(
+            [r[:-1] + s * sub for s, r in enumerate(rel)])
+        lens = np.concatenate(seg_lens)
+        rows = len(lens)
+        wire = (lens.astype(np.uint16) if deltas
+                else np.concatenate(rel).astype(np.int32))
+        buf, got_lens = repad(units, wire, row_len, rows, deltas)
+        assert buf.dtype == np.int32 and buf.shape == (rows, row_len)
+        np.testing.assert_array_equal(np.asarray(got_lens), lens)
+        np.testing.assert_array_equal(
+            np.asarray(buf), _numpy_repad(units, starts, lens, row_len))

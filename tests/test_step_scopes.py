@@ -205,13 +205,16 @@ def test_gram_step_asks_for_no_gather_from_and_no_scatter_into_the_weights():
 
 
 # stablehlo op counts of the two programs that stay OUTSIDE the Gram basis,
-# as the parent of PR 28 lowered them (packed ragged wire, 8 rows of 16)
+# as the parent of PR 28 lowered them (packed ragged wire, 8 rows of 16),
+# plus what PR 33's re-pad of that wire added to both alike: 111 ops (the
+# row gather's index arithmetic and the seven shifter stages), one of them
+# a convert
 _PARENT_OPS = {
-    "serving": (254, {"gather": 4, "scatter": 2, "dot_general": 1,
-                      "reduce": 12, "multiply": 14, "convert": 11}),
-    "scatter_loop": (770, {"gather": 4, "scatter": 4, "dot_general": 3,
-                           "reduce": 63, "multiply": 44, "convert": 14,
-                           "while": 1}),
+    "serving": (254 + 111, {"gather": 4, "scatter": 2, "dot_general": 1,
+                            "reduce": 12, "multiply": 14, "convert": 12}),
+    "scatter_loop": (770 + 111, {"gather": 4, "scatter": 4, "dot_general": 3,
+                                 "reduce": 63, "multiply": 44, "convert": 15,
+                                 "while": 1}),
 }
 
 
@@ -244,7 +247,29 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+# Since PR 33 the TPU-compiled steps below are compiled for the RAGGED wire,
+# the wire every cell ships; until then they were compiled for the padded
+# ``UnitBatch``. The padded wire's COMPILED steps (``--wire padded``, run in
+# no cell) are therefore no longer checked here: everything after the
+# ``repad`` scope is the same traced program for both wires, and the padded
+# form is still lowered by the module-name and program-count tests above.
 ROWS = 2048
+ROW_LEN = 512
+UNITS = 307200  # the 280-unit cells' commonest units bucket
+
+
+def _ragged_shapes(shape, shards: int = 1) -> RaggedUnitBatch:
+    """The cells' wire as shapes: ROWS rows of the ragged units wire
+    (uint16: 30% of the mixes' tweets are not ASCII), shard-aligned over
+    ``shards`` data shards; ``shape(dims, dtype, *spec)`` places a leaf
+    whose leading dim the data axis shards."""
+    return RaggedUnitBatch(
+        shape((UNITS,), jnp.uint16, "data"),
+        shape((ROWS + shards,), jnp.int32, "data"),
+        shape((ROWS, 4), jnp.float32, "data", None),
+        shape((ROWS,), jnp.float32, "data"),
+        shape((ROWS,), jnp.float32, "data"),
+        row_len=ROW_LEN, num_shards=shards)
 
 
 def _compile_single(topo) -> str:
@@ -252,27 +277,24 @@ def _compile_single(topo) -> str:
 
     dev = SingleDeviceSharding(topo.devices[0])
 
-    def shape(dims, dtype):
+    def shape(dims, dtype, *_spec):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=dev)
 
     step = make_sgd_train_step(
         num_text_features=F_TEXT, num_iterations=50, step_size=0.005,
         l2_reg=0.1, quality=True)
-    batch = UnitBatch(
-        shape((ROWS, 512), jnp.uint8), shape((ROWS,), jnp.int32),
-        shape((ROWS, 4), jnp.float32), shape((ROWS,), jnp.float32),
-        shape((ROWS,), jnp.float32))
     return jax.jit(step, donate_argnums=0).lower(
-        shape((F_TEXT + 4,), jnp.float32), batch).compile().as_text()
+        shape((F_TEXT + 4,), jnp.float32), _ragged_shapes(shape),
+    ).compile().as_text()
 
 
 def _compile_mesh(topo, mesh_shape, axes, body, w_spec, weights_of) -> str:
     """The mesh step ``body`` under ``shard_map`` on the described chips,
-    compiled for ROWS rows of the padded units wire at row length 512."""
+    compiled for ROWS rows of the shard-aligned ragged wire at row length
+    512."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from twtml_tpu.models.base import StepOutput
-    from twtml_tpu.parallel.sharding import unit_batch_pspecs
 
     mesh = Mesh(np.array(topo.devices).reshape(mesh_shape), axes)
 
@@ -281,17 +303,12 @@ def _compile_mesh(topo, mesh_shape, axes, body, w_spec, weights_of) -> str:
             dims, dtype, sharding=NamedSharding(mesh, P(*spec)))
 
     step = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(w_spec, unit_batch_pspecs("data")),
+        body, mesh=mesh, in_specs=(w_spec, P("data")),
         out_specs=(w_spec, StepOutput(
             predictions=P("data"), count=P(), mse=P(), real_stdev=P(),
             pred_stdev=P(), quality=P())),
     ), donate_argnums=0)
-    batch = UnitBatch(
-        shape((ROWS, 512), jnp.uint8, "data", None),
-        shape((ROWS,), jnp.int32, "data"),
-        shape((ROWS, 4), jnp.float32, "data", None),
-        shape((ROWS,), jnp.float32, "data"),
-        shape((ROWS,), jnp.float32, "data"))
+    batch = _ragged_shapes(shape, shards=mesh.shape["data"])
     return step.lower(weights_of(shape), batch).compile().as_text()
 
 
@@ -337,13 +354,26 @@ _TPU_STEPS = {
 
 
 @pytest.fixture(scope="module")
-def tpu_branches(topo):
+def tpu_steps(topo):
+    """layout → the text of the step the TPU's compiler made."""
+    cache = {}
+
+    def of(layout: str) -> str:
+        if layout not in cache:
+            cache[layout] = _TPU_STEPS[layout][0](topo)
+        return cache[layout]
+
+    return of
+
+
+@pytest.fixture(scope="module")
+def tpu_branches(tpu_steps):
     """layout → the three branches of the compiled step's plane switch."""
     cache = {}
 
     def of(layout: str) -> list:
         if layout not in cache:
-            cache[layout] = plane_branches(_TPU_STEPS[layout][0](topo))
+            cache[layout] = plane_branches(tpu_steps(layout))
         return cache[layout]
 
     return of
@@ -476,3 +506,32 @@ def test_compiled_fast_plane_writes_its_count_matrix_once(
     assert ("fusion", "f32", ROWS) in arrays, line
     panels = holding(panel, full)  # an empty range on one device
     assert len(panels) <= 1, panels
+
+
+# ---------------------------------------------------------------------------
+# PR 33: the ragged wire is re-padded by whole 128-lane rows of the units
+# buffer and a barrel shifter (ops/ragged.py), never one gather an element.
+
+_SLICE_SIZES = re.compile(r"slice_sizes=\{([0-9,]*)\}")
+
+
+@pytest.mark.parametrize("layout", sorted(_TPU_STEPS))
+def test_compiled_repad_gathers_lane_rows_not_elements(tpu_steps, layout):
+    """Counted on the program the TPU's compiler made for the cells' wire
+    (``jit_train_step``, the feature-sharded ``2x2`` and the data-only
+    ``4x1`` mesh steps): every gather under the ``repad`` scope takes
+    slices of 128 units, and together they take at most
+    ``rows a chip x (ceil(L / 128) + 1)`` of them — the ``[B, L]`` gather
+    of one-element slices (1,048,576 at this size) cannot come back
+    unseen."""
+    data_shards = {"single": 1, "2x2": 2, "4x1": 4}[layout]
+    slices = 0
+    for line in tpu_steps(layout).splitlines():
+        m = _RESULT.match(line)
+        if not (m and m.group(2) == "gather" and "/repad/" in line):
+            continue
+        sizes = [int(n) for n in _SLICE_SIZES.search(line).group(1).split(",")]
+        assert int(np.prod(sizes)) == 128, line
+        ((_dtype, dims),) = _ARRAY.findall(m.group(1))
+        slices += int(np.prod([int(d) for d in dims.split(",")])) // 128
+    assert 0 < slices <= ROWS // data_shards * (-(-ROW_LEN // 128) + 1)

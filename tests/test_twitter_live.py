@@ -447,3 +447,188 @@ def test_block_twitter_source_matches_object_path():
     np.testing.assert_allclose(obj.numeric, blk.numeric, rtol=1e-6)
     np.testing.assert_array_equal(obj.label, blk.label)
     np.testing.assert_array_equal(obj.mask, blk.mask)
+
+
+# ---------------------------------------------------------------------------
+# PR 33: the live block source builds its blocks from the response's byte
+# chunks; C splits the lines
+
+
+def _rt_line(i: int, text: str) -> bytes:
+    return json.dumps({
+        "text": "RT @u: " + text,
+        "retweeted_status": {
+            "text": text, "retweet_count": 100 + i,
+            "user": {"followers_count": 7 * i, "friends_count": i},
+        },
+    }, ensure_ascii=False).encode("utf-8")
+
+
+def _chunk_corpus() -> "tuple[bytes, list[bytes]]":
+    """(the body as the socket would deliver it, its sound lines): CRLF
+    and bare-LF endings, blank keep-alives at the head, between lines and
+    doubled, a non-ASCII text, a delete notice, a line that is not JSON, a
+    retweet whose text holds a byte that is not UTF-8, and a last line
+    with no terminator."""
+    sound = [_rt_line(i, t) for i, t in enumerate([
+        "plain ascii text", "café 中文 \U0001f600 mixed",
+        "UPPER lower 123", "tail without a newline",
+    ])]
+    broken = _rt_line(9, "bad byte X here").replace(b"X", b"\xff")
+    body = (
+        b"\r\n" + sound[0] + b"\r\n\r\n" + sound[1] + b"\n"
+        + b'{"delete": {"status": {"id": 1}}}\r\n' + b"not json\n"
+        + broken + b"\r\n\r\n\r\n" + sound[2] + b"\r\n" + sound[3]
+    )
+    return body, sound
+
+
+def _block_source(chunks, **kw):
+    from twtml_tpu.streaming.twitter import BlockTwitterSource
+
+    return BlockTwitterSource(CREDS, connect_fn=lambda: iter(chunks), **kw)
+
+
+def _merged(blocks):
+    from twtml_tpu.features.blocks import merge_blocks
+
+    return merge_blocks(list(blocks))
+
+
+def _assert_same_block(got, want):
+    import numpy as np
+
+    for g, w in zip(got, want, strict=True):
+        np.testing.assert_array_equal(g, w)
+
+
+def _line_path_block(lines: "list[bytes]", **kw):
+    """What the line path built (PR 32's ``produce``): every line decoded,
+    stripped, re-encoded and joined with ``\\n``, then parsed as one
+    buffer."""
+    data = b"".join(
+        ln.decode("utf-8", errors="replace").strip().encode("utf-8") + b"\n"
+        for ln in lines
+    )
+    return _merged(_block_source([], **kw).parse_buffer(data))
+
+
+@pytest.mark.parametrize("wire", [False, True])
+def test_chunk_path_equals_line_path_cut_at_every_byte_offset(wire):
+    """One corpus cut at EVERY byte offset into two chunks yields the
+    ParsedBlocks the line path did, with a block forced after every chunk
+    that holds a newline (``block_bytes=1``), so the cut at the last
+    newline and the carried tail are both on the path: a line split across
+    chunks, ``\\r\\n`` split between them, keep-alives at a chunk's head,
+    a chunk with no newline, the unterminated tail at the stream's end.
+
+    THE RULE for a line that is not UTF-8 is the C parser's, as in replay
+    block ingest: skipped and counted — the line path had decoded it with
+    U+FFFD and kept the row."""
+    from twtml_tpu.telemetry import metrics
+
+    body, sound = _chunk_corpus()
+    want = _line_path_block(sound, wire=wire)
+    assert want.rows == len(sound) == 4
+    drops = metrics.get_registry().counter("ingest.rows_dropped_parse")
+    for cut in range(len(body) + 1):
+        chunks = [c for c in (body[:cut], body[cut:]) if c]
+        before = drops.value
+        got = _merged(
+            _block_source(chunks, block_bytes=1, wire=wire).produce())
+        _assert_same_block(got, want)
+        # "not json" and the retweet with the bad byte, once each
+        assert drops.value - before == 2, cut
+
+
+@pytest.mark.parametrize("wire", [False, True])
+def test_chunk_path_in_64k_chunks_and_default_blocks(wire):
+    """The cells' regime: 64 KiB chunks into 256 KiB blocks."""
+    body, sound = _chunk_corpus()
+    reps = 400
+    stream = (body + b"\r\n") * reps
+    assert len(stream) > 4 * 65536
+    chunks = [stream[i:i + 65536] for i in range(0, len(stream), 65536)]
+    blocks = list(_block_source(chunks, wire=wire).produce())
+    assert len(blocks) > 1
+    got, one = _merged(blocks), _line_path_block(sound, wire=wire)
+    assert got.rows == one.rows * reps
+    _assert_same_block(got, _merged([one] * reps))
+
+
+def test_chunk_path_str_lines_of_an_injected_stream_are_one_chunk_each():
+    """``connect_fn`` streams of ``str`` lines (no terminators, blank
+    keep-alives) go through the same chunk loop."""
+    body, sound = _chunk_corpus()
+    lines = ["", sound[0].decode(), "", sound[1].decode(), "not json",
+             sound[2].decode(), sound[3].decode()]
+    got = _merged(_block_source(lines, block_bytes=1).produce())
+    _assert_same_block(got, _line_path_block(sound))
+
+
+def test_chunk_path_time_flush_and_a_chunk_without_newline(monkeypatch):
+    """A block is cut when a chunk arrives ``flush_seconds`` after the
+    first byte buffered for it — and only if it holds a whole line; what
+    follows the last newline waits for the next block."""
+    import time as _time
+
+    body, sound = _chunk_corpus()
+    half = len(sound[0]) // 2
+    chunks = [
+        sound[0][:half],          # no newline yet: nothing to cut
+        sound[0][half:] + b"\r\n" + sound[1][:10],
+        sound[1][10:] + b"\n",
+        b"\r\n",                  # a keep-alive is activity too
+        sound[2] + b"\n",
+    ]
+    clock = iter([0.0, 1.0, 1.1, 2.2, 2.3])
+    monkeypatch.setattr(_time, "monotonic", lambda: next(clock))
+    src = _block_source(chunks, block_bytes=1 << 30, flush_seconds=1.0)
+    blocks = list(src.produce())
+    # t=1.0: first block (sound[0]); the tail's clock starts at 1.0, so
+    # t=1.1 cuts nothing; t=2.2 (the keep-alive) cuts sound[1]; the end
+    # of the stream flushes sound[2]
+    assert [b.rows for b in blocks] == [1, 1, 1]
+    _assert_same_block(_merged(blocks), _line_path_block(sound[:3]))
+
+
+def test_chunk_path_source_lines_span_carries_lines_bytes_chunks(tmp_path):
+    from twtml_tpu.telemetry import trace
+
+    body, sound = _chunk_corpus()
+    stream = body + b"\n"
+    chunks = [stream[i:i + 100] for i in range(0, len(stream), 100)]
+    path = str(tmp_path / "spans.json")
+    trace.install(path)
+    try:
+        rows = sum(b.rows for b in _block_source(
+            chunks, block_bytes=len(stream)).produce())
+    finally:
+        trace.uninstall()
+    assert rows == 4
+    events = [json.loads(ln.rstrip(",\n")) for ln in open(path)
+              if ln.startswith("{")]
+    (lines_ev,) = [e for e in events if e["name"] == "source_lines"]
+    assert lines_ev["args"] == {
+        "lines": stream.count(b"\n"), "bytes": len(stream),
+        "chunks": len(chunks),
+    }
+    (recv_ev,) = [e for e in events if e["name"] == "source_recv"]
+    assert recv_ev["ts"] == lines_ev["ts"] and recv_ev["dur"] == 0
+
+
+def test_open_chunks_delivers_the_body_bytes_untouched(stream_server):
+    """``open_chunks`` shares ``open_stream``'s connection, status and
+    error handling and yields the body as framed: joined, it is the body."""
+    from twtml_tpu.streaming.httpstream import RecvClock, open_chunks
+
+    clock = RecvClock()
+    got = list(open_chunks(stream_server + "/stream", recv_clock=clock))
+    assert b"".join(got) == ("\r\n".join(TWEETS[:20]) + "\r\n\r\n\r\n").encode()
+    assert all(len(c) <= 37 for c in got) and len(got) > 20
+    seconds, nbytes = clock.take()
+    assert nbytes > len(b"".join(got)) and seconds >= 0
+    with pytest.raises(RateLimitedError):
+        list(open_chunks(stream_server + "/calm"))
+    with pytest.raises(StreamHTTPError):
+        list(open_chunks(stream_server + "/forbidden"))

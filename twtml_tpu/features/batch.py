@@ -182,11 +182,12 @@ class RaggedUnitBatch:
     Σlengths are real). The ragged wire carries Σlengths units
     (rounded up to ``RAGGED_UNIT_MULTIPLE`` so program count stays finite)
     plus a [B+1] int32 offsets vector; the learner re-pads INSIDE the jit
-    step with one [B, L] gather (its device cost: not measured, ROADMAP
-    S2) and case-folds ASCII on device,
+    step by whole 128-lane rows of the units buffer and a shift
+    (ops/ragged.py; its device cost: PERF.md §5, ``repad``) and case-folds
+    ASCII on device,
     producing bit-identical features (tests/test_ragged_wire.py).
 
-    ``row_len`` (the padded L the device gather rebuilds) is STATIC aux
+    ``row_len`` (the padded L the device re-pad rebuilds) is STATIC aux
     data, like PackedBatch's layout: each distinct (shapes, row_len)
     compiles once.
 

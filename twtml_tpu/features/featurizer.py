@@ -612,7 +612,7 @@ class Featurizer:
         """Filter + encode a micro-batch for the RAGGED device wire
         (features/batch.RaggedUnitBatch): the units ship concatenated
         (Σlengths, rounded to RAGGED_UNIT_MULTIPLE) instead of padded
-        (B·L_bucket) — the learner re-pads with one gather and case-folds
+        (B·L_bucket) — the learner re-pads by lane rows and case-folds
         ASCII inside the jit step, producing features bit-identical to the
         padded paths (differential tests in tests/test_ragged_wire.py).
         ``unit_bucket`` still pins the REBUILT row length L (compile-shape
@@ -877,7 +877,7 @@ class Featurizer:
         if ragged:
             # the block ALREADY holds concatenated units + offsets — the
             # ragged wire ships them as-is (no pad copy at all); the jit
-            # step re-pads with one gather + device ASCII fold, features
+            # step re-pads by lane rows + device ASCII fold, features
             # bit-identical to the padded path (tests/test_ragged_wire.py)
             from .batch import RaggedUnitBatch, pack_batch, ragged_wire_arrays
 

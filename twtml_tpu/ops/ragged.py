@@ -5,13 +5,20 @@ wire semantics cannot drift between layouts.
 The ragged wire (features/batch.py ``RaggedUnitBatch``) ships text as
 concatenated code units + row offsets — no per-row pad bytes on the
 wire. The learner rebuilds the padded [B, L] layout INSIDE the jit
-program with one gather and case-folds ASCII there, which the padded wire's
-C pad copy did on the host. Features are bit-identical either way
+program and case-folds ASCII there, which the padded wire's C pad copy did
+on the host. Features are bit-identical either way
 (tests/test_ragged_wire.py).
+
+The re-pad moves WHOLE 128-lane rows, never single units (PR 33): the
+units buffer is viewed as a [N/128, 128] table, row b gathers the
+ceil(L/128) + 1 table rows from ``start_b // 128`` on, and a seven-stage
+barrel shifter (one static shift per bit of ``start_b % 128``) moves its
+first unit to column 0. A gather of one-element slices with a [B, L] index
+is what the TPU runs an element at a time (PERF.md §6, PR 33).
 
 Under shard_map the arrays arrive SHARD-LOCAL (this shard's sub-buffer and
 its shard-relative offsets — features/batch.py ``align_ragged_shards``),
-and the same gather rebuilds this shard's [B_local, L] rows; ``row_len``
+and the same re-pad rebuilds this shard's [B_local, L] rows; ``row_len``
 (L) is static and global, so every shard's re-pad agrees with the
 single-device layout.
 """
@@ -20,6 +27,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+# lanes of a vector register row: the re-pad moves the units buffer by
+# whole rows of this many units
+LANE_BITS = 7
+LANES = 1 << LANE_BITS
 
 
 def offsets_from_deltas(deltas, num_segments: int = 1):
@@ -102,7 +114,7 @@ def ragged_repad(units, offsets, row_len: int, rows: int | None = None,
     (S = 1 when offsets is the plain [B + 1] vector; None means plain).
     Segment s's sub-buffer starts at s·(N/S) and its offsets are
     segment-relative, so converting to absolute starts is one broadcast
-    add — the gather itself is identical in every layout.
+    add — the re-pad itself is identical in every layout.
 
     ``deltas=True`` accepts the NARROW offset wire directly: ``offsets``
     then holds uint16 per-row length deltas ([B], one segment per
@@ -125,8 +137,28 @@ def ragged_repad(units, offsets, row_len: int, rows: int | None = None,
         lens = (ob[:, 1:] - ob[:, :-1]).reshape(-1)
     else:
         starts, lens = offs[:-1], offs[1:] - offs[:-1]
+    # whole lane rows, then a shift — never one gather per element: the
+    # units as a [T, 128] table; row b's units lie inside the k table
+    # rows from starts[b] // 128 on, ``starts[b] % 128`` lanes in
+    table = units.astype(jnp.int32)
+    table = jnp.pad(table, (0, -table.shape[0] % LANES)).reshape(-1, LANES)
+    k = -(-row_len // LANES) + 1
+    first = (starts >> LANE_BITS)[:, None] + jnp.arange(k, dtype=jnp.int32)
+    # a clipped row index only ever lands beyond the row's own units
+    # (starts + lens <= N), where the mask below writes 0
+    wide = table[jnp.clip(first, 0, table.shape[0] - 1)]
+    wide = wide.reshape(starts.shape[0], -1)
+    # barrel shifter: shift left by starts % 128, one static shift per bit
+    shift = starts & (LANES - 1)
+    for bit in reversed(range(LANE_BITS)):
+        by = 1 << bit
+        # after this stage at most ``by - 1`` lanes of shift remain
+        keep = row_len + by - 1
+        wide = jnp.where(
+            ((shift >> bit) & 1)[:, None] == 1,
+            wide[:, by:by + keep], wide[:, :keep],
+        )
     cols = jnp.arange(row_len, dtype=jnp.int32)[None, :]
-    idx = jnp.clip(starts[:, None] + cols, 0, units.shape[0] - 1)
-    buf = jnp.where(cols < lens[:, None], units[idx].astype(jnp.int32), 0)
+    buf = jnp.where(cols < lens[:, None], wide, 0)
     buf = buf + ((buf >= 65) & (buf <= 90)) * 32  # ASCII case fold
     return buf, lens

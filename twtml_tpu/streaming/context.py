@@ -577,7 +577,7 @@ class StreamingContext:
         return out
 
     def _drain_impl(self, limit: int = 0) -> list[Status]:
-        from ..features.blocks import slice_block
+        from ..features.blocks import ParsedBlock, merge_blocks, slice_block
 
         out = self._queue.drain_rows(
             limit,
@@ -586,6 +586,10 @@ class StreamingContext:
                 slice_block(item, cut, item.rows),
             ),
         )
+        if len(out) > 1 and isinstance(out[0], ParsedBlock):
+            # parsed blocks: ONE merge at the seam — the lineage stamp, the
+            # journal's record and featurize each merged the list again
+            out = [merge_blocks(out)]
         # queue depth is per-BATCH registry state (one gauge set per drain,
         # never per tweet — the intake hot path pays no metric lock)
         _metrics.get_registry().gauge("ingest.queue_rows").set(

@@ -15,13 +15,15 @@ protocol loop below is ~100 lines and fully testable against a local server
 
 from __future__ import annotations
 
+import contextlib
 import socket
 import ssl
 import time
 from typing import Iterator
 from urllib.parse import urlsplit
 
-__all__ = ["StreamHTTPError", "RateLimitedError", "RecvClock", "open_stream"]
+__all__ = ["StreamHTTPError", "RateLimitedError", "RecvClock", "open_chunks",
+           "open_stream"]
 
 
 class StreamHTTPError(ConnectionError):
@@ -40,7 +42,7 @@ class RateLimitedError(StreamHTTPError):
 
 class RecvClock:
     """Seconds spent inside socket reads and bytes read, summed by
-    ``open_stream`` for a caller that asked (the ``source_recv`` trace
+    ``open_chunks`` for a caller that asked (the ``source_recv`` trace
     span, twitter.BlockTwitterSource). Read and reset by the thread that
     iterates the stream."""
 
@@ -139,7 +141,7 @@ def _body_chunks(
             yield data
 
 
-def open_stream(
+def open_chunks(
     url: str,
     headers: dict[str, str] | None = None,
     method: str = "GET",
@@ -147,10 +149,12 @@ def open_stream(
     timeout: float = 90.0,
     ssl_context: ssl.SSLContext | None = None,
     recv_clock: RecvClock | None = None,
-) -> Iterator[str]:
-    """Open ``url`` and yield decoded text lines (without terminators) as
-    they arrive. Blank keep-alive lines ARE yielded — the consumer decides.
-    With a ``recv_clock`` every read of the response is timed into it.
+) -> Iterator[bytes]:
+    """Open ``url`` and yield the response body's bytes as they arrive,
+    one ``bytes`` per transfer chunk (or socket read), the framing taken
+    off and nothing else touched: lines may be cut anywhere. For a consumer
+    that splits lines itself (the C block parser, twitter.py). With a
+    ``recv_clock`` every read of the response is timed into it.
 
     Raises ``RateLimitedError`` on 420/429, ``StreamHTTPError`` on any other
     non-200, plain ``ConnectionError``/``OSError``/``TimeoutError`` on
@@ -210,21 +214,29 @@ def open_stream(
         if status != 200:
             raise StreamHTTPError(status, reason)
 
-        # reassemble text lines across chunk boundaries: one split per
-        # chunk (slicing the rest off after every line copies a 64 KiB
-        # chunk once per line it holds)
-        pending = b""
-        for chunk in _body_chunks(sock, buf, resp_headers):
+        yield from _body_chunks(sock, buf, resp_headers)
+    finally:
+        try:
+            sock.close()
+        except OSError:
+            pass
+
+
+def open_stream(*args, **kw) -> Iterator[str]:
+    """``open_chunks`` (same arguments, same errors) as decoded text lines
+    without terminators. Blank keep-alive lines ARE yielded — the consumer
+    decides."""
+    # reassemble text lines across chunk boundaries: one split per chunk
+    # (slicing the rest off after every line copies a 64 KiB chunk once
+    # per line it holds)
+    pending = b""
+    with contextlib.closing(open_chunks(*args, **kw)) as chunks:
+        for chunk in chunks:
             lines = (pending + chunk).split(b"\n")
             pending = lines.pop()
             for line_bytes in lines:
                 yield line_bytes.rstrip(b"\r").decode(
                     "utf-8", errors="replace"
                 )
-        if pending.strip():
-            yield pending.decode("utf-8", errors="replace")
-    finally:
-        try:
-            sock.close()
-        except OSError:
-            pass
+    if pending.strip():
+        yield pending.decode("utf-8", errors="replace")

@@ -36,6 +36,7 @@ from .httpstream import (
     RateLimitedError,
     RecvClock,
     StreamHTTPError,
+    open_chunks,
     open_stream,
 )
 from .oauth1 import authorization_header
@@ -103,6 +104,11 @@ class TwitterSource(Source):
     def _connect(self) -> Iterator[str]:
         if self._connect_fn is not None:
             return self._connect_fn()
+        return self._open(open_stream)
+
+    def _open(self, opener) -> Iterator:
+        """The signed connection to ``url`` through ``opener``
+        (``httpstream.open_stream``'s lines or ``open_chunks``' bytes)."""
         auth = authorization_header(
             "GET",
             self.url,
@@ -117,8 +123,8 @@ class TwitterSource(Source):
         )
         # 90s read timeout: the stream keep-alives every ~30s, so a silent
         # socket for 90s is a stall and must raise into the supervisor
-        return open_stream(self.url, headers={"Authorization": auth},
-                           recv_clock=self._recv_clock)
+        return opener(self.url, headers={"Authorization": auth},
+                      recv_clock=self._recv_clock)
 
     def _backoff(self, exc: Exception, restarts: int) -> float:
         """Twitter streaming reconnect rules (what Twitter4j implements for
@@ -154,24 +160,31 @@ class TwitterSource(Source):
 
 class BlockTwitterSource(BlockParserMixin, TwitterSource):
     """The live stream through the NATIVE block parser (r5 — live
-    ``--ingest block``): raw JSON lines from the connection accumulate into
-    byte blocks and each block goes through ``native.parse_tweet_block``
-    (the same C scanner + filter as replay block ingest, differential-
-    tested against the Status path), yielding columnar ParsedBlocks with no
-    per-tweet Python objects between the socket and the featurizer.
+    ``--ingest block``): the response's byte CHUNKS, as the socket
+    delivered them (``httpstream.open_chunks``), accumulate into blocks cut
+    at their last newline, and each block goes through
+    ``native.parse_tweet_block`` (the same C scanner + filter as replay
+    block ingest, differential-tested against the Status path), yielding
+    columnar ParsedBlocks. No per-line Python and no per-tweet object
+    between the socket and the featurizer: C splits the lines, takes
+    ``\\r\\n`` or ``\\n`` endings and skips blank keep-alives.
 
-    Why: the per-line ``json.loads`` + Status assembly is the live path's
-    host cost per tweet; the replay path already deletes it with this
-    parser (its rate on this machine: not measured, PERF.md).
+    A line that is not valid UTF-8 is the C parser's to judge, as in replay
+    block ingest: skipped and counted (``ingest.rows_dropped_parse``), where
+    object ingest (``TwitterSource``) decodes it with U+FFFD and keeps it.
 
     Flush policy: a block parses when the buffer reaches ``block_bytes``
-    OR the first stream activity (line or keep-alive) at least
-    ``flush_seconds`` after its first buffered line. The clock is checked
-    when the blocking line iterator yields, so on a QUIET stream the real
-    latency bound is the protocol's ~30 s keep-alive cadence, not
-    ``flush_seconds`` — acceptable for this source's regimes (the real
-    sample stream runs 50–100 tweets/s and measurement streams far
-    faster; a latency-critical quiet stream should keep object ingest)."""
+    OR the first stream activity (a chunk, be it one keep-alive) at least
+    ``flush_seconds`` after the first byte buffered for it, and holds a
+    whole line. The clock is checked when the blocking chunk iterator
+    yields, so on a QUIET stream the real latency bound is the protocol's
+    ~30 s keep-alive cadence, not ``flush_seconds`` — acceptable for this
+    source's regimes (the real sample stream runs 50–100 tweets/s and
+    measurement streams far faster; a latency-critical quiet stream should
+    keep object ingest).
+
+    An injected ``connect_fn`` may yield ``bytes`` chunks, or ``str`` lines
+    without terminators (each becomes one chunk)."""
 
     name = "twitter-block"
 
@@ -211,6 +224,14 @@ class BlockTwitterSource(BlockParserMixin, TwitterSource):
         merged = merge_blocks(blocks)
         return merged if merged.rows else None
 
+    def _chunks(self) -> "Iterator[bytes]":
+        if self._connect_fn is None:
+            return self._open(open_chunks)
+        return (
+            c if isinstance(c, bytes) else c.encode("utf-8") + b"\n"
+            for c in self._connect_fn()
+        )
+
     def produce(self) -> "Iterator":
         import time as _time
 
@@ -219,48 +240,58 @@ class BlockTwitterSource(BlockParserMixin, TwitterSource):
         tr = _trace.get()
         if tr.enabled:
             self._recv_clock = RecvClock()
-        buf: list[bytes] = []
+        buf: list[bytes] = []  # this block's chunks, the carried tail first
         nbytes = 0
+        cut = None  # (index into buf, end) of the newest newline buffered
         first_t = 0.0
-        loop_t0 = _time.perf_counter()  # where this block's line loop began
-        for line in self._connect():
-            line = line.strip()
+        loop_t0 = _time.perf_counter()  # where this block's chunk loop began
+        for chunk in self._chunks():
             now = _time.monotonic()
-            if line:
-                if not buf:
-                    first_t = now
-                raw = line.encode("utf-8") + b"\n"
-                buf.append(raw)
-                nbytes += len(raw)
-            if buf and (
+            if not nbytes:
+                first_t = now
+            nl = chunk.rfind(b"\n")
+            if nl >= 0:
+                cut = (len(buf), nl + 1)
+            buf.append(chunk)
+            nbytes += len(chunk)
+            if cut is not None and (
                 nbytes >= self.block_bytes
                 or now - first_t >= self.flush_seconds
             ):
+                i, end = cut
+                data = b"".join(buf[:i] + [buf[i][:end]])
                 if tr.enabled:
-                    self._trace_line_loop(tr, loop_t0, len(buf), nbytes)
-                block = self._parse_block(b"".join(buf))
-                buf, nbytes = [], 0
+                    self._trace_chunk_loop(tr, loop_t0, data, i + 1)
+                # the unterminated tail opens the next block, now
+                tail = buf[i][end:]
+                buf = ([tail] if tail else []) + buf[i + 1:]
+                nbytes, cut, first_t = nbytes - len(data), None, now
+                block = self._parse_block(data)
                 if block is not None:
                     yield block
                 loop_t0 = _time.perf_counter()  # not the time at ``yield``
-        if buf:
+        if nbytes:
+            # the stream's end: what is left, an unterminated line too
             block = self._parse_block(b"".join(buf))
             if block is not None:
                 yield block
         if self._connect_fn is None:
             raise ConnectionError("stream ended by server; reconnecting")
 
-    def _trace_line_loop(self, tr, t0: float, lines: int, nbytes: int) -> None:
-        """One ``source_lines`` span per parsed block: this thread's line
-        loop (``open_stream``'s reassembly and ``produce``'s per-line work)
-        from the block's first line to the call of ``_parse_block`` — never
-        the parse, never the time suspended while the consumer takes the
-        block — and inside it one ``source_recv`` span: the part spent in
-        socket reads. Their difference is the loop's own Python
-        (PERF.md §3)."""
+    def _trace_chunk_loop(self, tr, t0: float, data: bytes,
+                          chunks: int) -> None:
+        """One ``source_lines`` span per parsed block: this thread's chunk
+        loop (``open_chunks``' framing and ``produce``'s buffering, cut
+        and join) from the block's first chunk to the call of
+        ``_parse_block`` — never the parse, never the time suspended while
+        the consumer takes the block — and inside it one ``source_recv``
+        span: the part spent in socket reads. Their difference is the
+        loop's own Python (PERF.md §3). ``chunks`` says the chunk path
+        ran; ``lines`` is counted after the span's end is read."""
         import time as _time
 
-        tr.complete("source_lines", t0, _time.perf_counter() - t0,
-                    lines=lines, bytes=nbytes)
+        dur = _time.perf_counter() - t0
+        tr.complete("source_lines", t0, dur, lines=data.count(b"\n"),
+                    bytes=len(data), chunks=chunks)
         recv_s, recv_bytes = self._recv_clock.take()
         tr.complete("source_recv", t0, recv_s, bytes=recv_bytes)

@@ -54,7 +54,7 @@ and ``dispatch`` takes it into its annotation, so the n-th
 Waits, written only when one happened: ``intake_wait`` (the source thread
 on the intake queue's row bound, streaming/context.py), ``deliver_wait``
 (the scheduler on the oldest in-flight fetch, apps/common.py); and per
-parsed block ``source_lines`` / ``source_recv`` (the source thread's line
+parsed block ``source_lines`` / ``source_recv`` (the source thread's chunk
 loop and its socket reads, streaming/twitter.py). PERF.md §3 names the
 benchmark metric that reads each.
 
