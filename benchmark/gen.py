@@ -128,6 +128,74 @@ def _place_lexicon(g: dict, seed: int, tokens: list) -> None:
             lacks -= mass[j]
 
 
+def run_lines(g: dict, seed: int, chunk: int, target: np.ndarray,
+              kept: np.ndarray) -> dict:
+    """The mix's ``generator.runs``: ``{"every_blocks": k, "lines_per_block":
+    m, "min_units": u, "chars": [c, ...]}`` — the tweet that is ONE character
+    over and over ("kkkkk…", "!!!!!…", "。。。…"), which every live sample
+    holds. In every block of ``length_block`` lines whose index in the pool
+    is a multiple of ``k``, the first ``m`` KEPT lines whose dealt length L
+    is at least ``u`` have their text replaced by L units of one character of
+    ``chars``, drawn per line from a stream of its own (the lines' draws are
+    untouched): L − 1 identical bigrams in one row. → ``{line: character}``
+    for this chunk's lines.
+
+    Only the text changes: the line keeps its length, so a block keeps its
+    multiset of text lengths, hence every compiled shape under every seed;
+    the numeric columns and the label are what they were. ``chars`` are
+    single UTF-16 units, lower-case (the reference lower-cases, and a
+    character whose lower case is another would still be one run). A block
+    with fewer than ``m`` such lines is an error, never a silent skip:
+    ``lint_runs`` finds it without a seed, since a block's lengths and kept
+    lines are the same multiset under every seed."""
+    runs = g["runs"]
+    block = int(g["length_block"])
+    every, per = int(runs["every_blocks"]), int(runs["lines_per_block"])
+    chars = list(runs["chars"])
+    if CHUNK % block or every < 1 or per < 1 or not chars or not all(
+            isinstance(c, str) and _units(c) == 1 and c == c.lower()
+            and c.isprintable() and c not in '"\\' for c in chars):
+        raise SystemExit(
+            "benchmark: generator.runs needs a length_block that divides "
+            f"{CHUNK}, every_blocks and lines_per_block of at least 1, and "
+            "chars of one printable lower-case UTF-16 unit each")
+    rng = np.random.default_rng([int(seed), 0x72756E, int(chunk)])
+    out = {}
+    for b in range(0, len(target), block):
+        if (chunk * CHUNK + b) // block % every:
+            continue
+        fit = b + np.flatnonzero(kept[b:b + block] & (
+            target[b:b + block] >= int(runs["min_units"])))[:per]
+        if fit.size < per:
+            raise SystemExit(
+                f"benchmark: generator.runs asks for {per} kept line(s) of "
+                f"{runs['min_units']} units or more in block "
+                f"{(chunk * CHUNK + b) // block}, which holds {fit.size}")
+        for i, c in zip(fit, rng.integers(0, len(chars), per)):
+            out[int(i)] = chars[c]
+    return out
+
+
+def lint_runs(g: dict) -> list:
+    """The faults of a mix's ``runs`` object, as strings: made without a
+    seed's pool (the lengths' stream and the kept lines of a block take no
+    seed), over every chunk of the pool."""
+    if not g.get("runs"):
+        return []
+    n = int(g["pool_lines"])
+    try:
+        for c in range((n + CHUNK - 1) // CHUNK):
+            target, kept = _dealt(g, np.random.default_rng(0), c,
+                                  min(CHUNK, n - c * CHUNK))
+            run_lines(g, 0, c, target, kept)
+    except SystemExit as exc:
+        return [str(exc)]
+    except (KeyError, TypeError, ValueError) as exc:
+        return [f"benchmark: generator.runs is not the object gen.run_lines "
+                f"reads: {exc!r}"]
+    return []
+
+
 def build_vocab(g: dict, seed: int) -> Vocab:
     rng = np.random.default_rng([int(seed), 0x766F63])
     n = int(g["vocab_size"])
@@ -200,10 +268,11 @@ class Chunk:
     kept: np.ndarray       # bool: inside the filter's interval
 
 
-def make_chunk(g: dict, vocab: Vocab, seed: int, chunk: int,
-               n: int = CHUNK) -> Chunk:
-    rng = np.random.default_rng([int(seed), 0x6C696E, int(chunk)])
-    now_ms = int(g["now_ms"])
+def _dealt(g: dict, rng, chunk: int, n: int) -> tuple:
+    """``(target, kept_of_block)`` of a chunk's ``n`` lines: each line's text
+    length and whether the filter keeps it, dealt out inside blocks of
+    ``length_block`` lines by ``rng`` (the chunk's line stream: one
+    permutation a block, its first draws)."""
     # Text lengths come from a stream the SEED DOES NOT ENTER, fixed per
     # chunk; the seed only deals them out inside blocks of `length_block`
     # lines. So every seed's batches hold the same totals of code units (the
@@ -226,6 +295,15 @@ def make_chunk(g: dict, vocab: Vocab, seed: int, chunk: int,
         deal = rng.permutation(len(target[b:b + block]))
         target[b:b + block] = target[b:b + block][deal]
         kept_of_block[b:b + block] = kept_of_block[b:b + block][deal]
+    return target, kept_of_block
+
+
+def make_chunk(g: dict, vocab: Vocab, seed: int, chunk: int,
+               n: int = CHUNK) -> Chunk:
+    rng = np.random.default_rng([int(seed), 0x6C696E, int(chunk)])
+    now_ms = int(g["now_ms"])
+    target, kept_of_block = _dealt(g, rng, chunk, n)
+    share = float(g["keep_share"])
     non_ascii = rng.random(n) < float(g["non_ascii_tweet_share"])
     n_slots = slots(g)
     ids = vocab.ascii_ids[
@@ -256,6 +334,8 @@ def make_chunk(g: dict, vocab: Vocab, seed: int, chunk: int,
     raw = 100 + followers * 4e-4 + n_units * 2 + rng.normal(0, 20, n)
     rng.random(n)   # (the draw a random keep took: the stream stays as it was)
     keep = kept_of_block if share < 1.0 else np.ones(n, dtype=bool)
+    # absent: not one draw more, the pool as it was
+    run_of = run_lines(g, seed, chunk, target, keep) if g.get("runs") else {}
     retweets = np.where(
         keep,
         np.clip(np.rint(raw), g["retweets_min"], g["retweets_max"]),
@@ -275,9 +355,13 @@ def make_chunk(g: dict, vocab: Vocab, seed: int, chunk: int,
             tail = "".join([_LATIN[j] for j in filler[i, :r]])
         else:
             tail = " " + "".join([_LATIN[j] for j in filler[i, :r - 1]])
-        text = " ".join([tok[j] for j in row]) + tail
-        body = (text if not non_ascii[i]
-                else " ".join([esc[j] for j in row]) + tail)
+        if i in run_of:   # one character, L times; the line's length stays
+            text = run_of[i] * int(target[i])
+            body = _escape(text)
+        else:
+            text = " ".join([tok[j] for j in row]) + tail
+            body = (text if not non_ascii[i]
+                    else " ".join([esc[j] for j in row]) + tail)
         texts.append(text)
         lines.append(
             f'{{"text":"RT @u{names[i]}: {body}","retweet_count":0,'

@@ -267,10 +267,16 @@ def lint(manifest_path: str = MANIFEST) -> list:
                 f"{w['traffic']}.(json|jsonl|toml|txt|csv)")
         else:
             try:
-                kind = load_json(traffic_path(w["traffic"])).get("kind")
+                mix = load_json(traffic_path(w["traffic"]))
             except ValueError as exc:
                 bad(f"workload {n!r}: traffic file is not JSON: {exc}")
-                kind = None
+                mix = {}
+            kind = mix.get("kind")
+            if (mix.get("generator") or {}).get("runs"):
+                from . import gen   # NumPy: only a mix with runs pays for it
+
+                for fault in gen.lint_runs(mix["generator"]):
+                    bad(f"workload {n!r}: {fault}")
             if kind is not None and not (
                 isinstance(kind, str) and NAME.match(kind)
                 and os.path.isfile(driver_path(kind))

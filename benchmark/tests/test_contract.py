@@ -1,10 +1,20 @@
-"""What the yardstick promises a later PR, held in seconds and without jax:
-the lint is clean; the pools of the mixes that stand are the bytes they were
-before the generator learned the ``lexicon`` object; the flags the cells hand
-the program are the recorded lists; the comparison gives the recorded
-numbers on a recorded check run; a configuration whose ``app`` or
-``reference`` names nothing fails the lint; a mix with a ``lexicon`` keeps
-every block's multiset of text lengths and yields both labels.
+"""What the yardstick promises a later PR, held in seconds: the lint is
+clean; the pools of the mixes that stand are the bytes they were before the
+generator learned the ``lexicon`` object (PR 31) and the ``runs`` object
+(PR 34); the flags the cells hand the program are the recorded lists; the
+comparison gives the recorded numbers on a recorded check run; a
+configuration whose ``app`` or ``reference`` names nothing fails the lint; a
+mix with a ``lexicon`` keeps every block's multiset of text lengths and
+yields both labels; a mix with ``runs`` writes its one-character rows into
+the blocks it names and nowhere else, keeps every block's lengths, and fails
+the lint where no block can serve it. (What the PROGRAM makes of such lines,
+its parsers and its step, is ``test_runs_program.py``'s: the program imports
+jax, and this file runs without.) No mix that stands has the object
+(PERF.md section 7 says why); the cases lay ``RUNS`` over
+``trimmed-kept-280``'s generator.
+
+A cell added later records the flags it hands the program in ``FLAGS``;
+``FLAGS`` may hold fewer cells than the manifest, never one it has not.
 
     python -m pytest benchmark/tests/test_contract.py -q
 """
@@ -37,19 +47,29 @@ POOLS = {
         "48e8e83d2d215d9e6561f7680c1aca8c0edd5af6a4a08418ff981fa0e4b4985b",
     ("trimmed-kept-280", 2147483659):
         "bb769c95dcc68342b15b81d084a90a2fc9cd30792a303b1117f4a3f3ea7b8d12",
+    # recorded from commit 9fb3948, before gen.py was edited in PR 34
+    ("trimmed-kept-280-lex", 3000000019):
+        "5bbc3e5d70133a1704d45b42e29f99f035f2792907cbd78d37485a219de829d7",
+    ("trimmed-kept-280-lex", 2147483659):
+        "62dafe09152139c24a2a8325234bde221459eee76f93f171f120ca8e73811edb",
 }
 SHARED = ["--backend", "tpu", "--source", "twitter", "--ingest", "block",
           "--seconds", "0", "--checkpointDir", "CKPT", "--twtweb",
           "http://sink", "--lightning", "http://127.0.0.1:9"]
 HASH2E18 = ["--numTextFeatures", "262144", "--l2Reg", "0.1", "--batchBucket",
             "2048", "--master", "local[1]"]
-FLAGS = {   # train.program_flags at the parent tree, per cell
+FLAGS = {   # train.program_flags when the cell was added, per cell
     "hash2e18-trimmed": SHARED + HASH2E18,
     "hash2e18-trimmed-280": SHARED + HASH2E18,
     "hash2e20-trimmed-280": SHARED + [
         "--numTextFeatures", "1048576", "--l2Reg", "0.1", "--batchBucket",
         "2048", "--modelShards", "2"],
+    "logit2e18-trimmed-280-lex": SHARED + [   # PR 32
+        "--numTextFeatures", "262144", "--stepSize", "0.1", "--batchBucket",
+        "2048", "--master", "local[1]"],
 }
+RUNS = {"every_blocks": 4, "lines_per_block": 1, "min_units": 258,
+        "chars": ["a", "k", "w", "!", "\u3002", "\uff57"]}
 
 
 def test_lint_is_clean():
@@ -59,7 +79,7 @@ def test_lint_is_clean():
 @pytest.mark.parametrize("mix,seed", sorted(POOLS))
 def test_pool_is_byte_identical_to_the_parents(mix, seed):
     traffic = manifest.load_json(manifest.traffic_path(mix))
-    assert "lexicon" not in traffic["generator"]
+    assert "runs" not in traffic["generator"]
     body = feeder.build_body(traffic, seed)[0]
     assert hashlib.sha256(body).hexdigest() == POOLS[mix, seed]
 
@@ -69,7 +89,7 @@ def test_program_flags_are_the_recorded_lists(name):
     cell = manifest.cell(manifest.load(), name)
     assert train.program_flags(
         cell["config"], "tpu", "CKPT", "http://sink") == FLAGS[name]
-    assert set(FLAGS) == {w["name"] for w in manifest.load()["workloads"]}
+    assert set(FLAGS) <= {w["name"] for w in manifest.load()["workloads"]}
 
 
 def test_half_up_integer_rule_gives_the_parents_numbers():
@@ -176,3 +196,82 @@ def test_lexicon_words_hold_their_stated_share_of_the_slots(seed):
                        ("negative", "slot_share_negative")):
         got = sum(w in set(lex[key]) for w in words) / len(words)
         assert got == pytest.approx(lex[share], rel=0.15)
+
+
+# --------------------------------------------------------------------------
+# generator.runs (PR 34)
+
+
+def runs_generator(**over) -> dict:
+    """``trimmed-kept-280``'s generator with ``RUNS`` (changed by ``over``)
+    laid over it."""
+    g = manifest.load_json(manifest.traffic_path("trimmed-kept-280"))["generator"]
+    return dict(g, runs=dict(RUNS, **over))
+
+
+def _top_bigram(text: str) -> int:
+    """The largest count of one bigram in a text, lower-cased as the
+    featurizer and the reference read it."""
+    t = text.lower()
+    pairs = list(zip(t, t[1:]))
+    return max(map(pairs.count, set(pairs))) if pairs else 0
+
+
+@pytest.mark.parametrize("seed", [3000000019, 7])
+def test_runs_are_written_into_the_named_blocks_and_nowhere_else(seed):
+    plain_g = manifest.load_json(manifest.traffic_path("trimmed-kept-280"))["generator"]
+    runs_g, runs = runs_generator(), RUNS
+    block, every = plain_g["length_block"], runs["every_blocks"]
+    vocab = gen.build_vocab(plain_g, seed)   # the object touches no token
+    c0 = gen.make_chunk(plain_g, vocab, seed, 1)
+    c1 = gen.make_chunk(runs_g, vocab, seed, 1)
+    # line for line the same lengths (hence each block's multiset of text
+    # lengths, the wire's buckets and every compiled shape) and numeric truth
+    units = [gen._units(t) for t in c1.text]
+    assert units == [gen._units(t) for t in c0.text]
+    for col in ("followers", "favourites", "friends", "created_ms",
+                "retweets", "kept"):
+        assert np.array_equal(getattr(c0, col), getattr(c1, col))
+    changed = [i for i in range(gen.CHUNK) if c0.lines[i] != c1.lines[i]]
+    assert changed == [i for i in range(gen.CHUNK) if c0.text[i] != c1.text[i]]
+    first_block = gen.CHUNK // block   # chunk 1's first block, in the pool
+    for b in range(gen.CHUNK // block):
+        rows = range(b * block, (b + 1) * block)
+        # only a row of 258 units or more can hold a bigram 257 times
+        hot = [i for i in rows if units[i] >= 258
+               and _top_bigram(c1.text[i]) >= 257]
+        want = runs["lines_per_block"] if (first_block + b) % every == 0 else 0
+        assert len(hot) == want, (b, hot)
+        assert hot == [i for i in changed if i in rows]
+        for i in hot:
+            assert c1.text[i] == c1.text[i][0] * units[i]
+            assert c1.text[i][0] in runs["chars"] and units[i] >= runs["min_units"]
+            assert c1.kept[i]
+    assert not any(units[i] >= 258 and _top_bigram(c0.text[i]) >= 257
+                   for i in range(gen.CHUNK))
+
+
+def test_a_run_no_block_can_serve_fails_the_lint():
+    g = runs_generator()
+    assert gen.lint_runs(g) == []
+    for runs in (dict(g["runs"], min_units=281),          # longer than any text
+                 dict(g["runs"], lines_per_block=2049),   # more than a block
+                 dict(g["runs"], chars=["kk"]),           # not one unit
+                 {"every_blocks": 4}):                    # not the object
+        assert gen.lint_runs(dict(g, runs=runs)) != [], runs
+    with pytest.raises(SystemExit):   # and the generator never skips it silently
+        bad = dict(g, runs=dict(g["runs"], min_units=281))
+        gen.make_chunk(bad, gen.build_vocab(bad, 7), 7, 0, g["length_block"])
+    tree = fixture_tree.build(os.path.join(
+        manifest.ROOT, "_scratch", "contract_runs"))
+    try:
+        path = os.path.join(tree, "benchmark", "traffic",
+                            fixture_tree.MIX + ".json")
+        mix = manifest.load_json(path)
+        mix["generator"]["runs"] = dict(g["runs"], min_units=281)
+        with open(path, "w", encoding="utf-8") as fh:
+            json.dump(mix, fh)
+        rc, out = _lint_of(tree)
+        assert rc == 1 and "generator.runs asks for 1 kept line(s) of 281" in out, out
+    finally:
+        fixture_tree.remove(tree)

@@ -20,7 +20,7 @@ import pytest
 
 from benchmark import manifest
 from benchmark.drivers import train
-from benchmark.tests import conftest
+from benchmark.tests import test_contract
 from benchmark.tests.test_correct import _drive
 
 CELL = "logit2e18-trimmed-280-lex"
@@ -79,7 +79,7 @@ def test_the_cell_is_the_fixtures_learner_on_its_own_files():
 def test_program_flags_are_the_recorded_list():
     cell = manifest.cell(manifest.load(), CELL)
     assert train.program_flags(
-        cell["config"], "tpu", "CKPT", "http://sink") == conftest.ADDED_SINCE[CELL]
+        cell["config"], "tpu", "CKPT", "http://sink") == test_contract.FLAGS[CELL]
 
 
 # -- the gate of drivers/train_text_label.py ---------------------------------
@@ -129,6 +129,7 @@ def test_gate_refuses_a_labeler_that_falls_back(monkeypatch, labeler, rows):
         monkeypatch.setattr(sentiment, "sentiment_labels_from_units", labeler)
     assert gated.rows_through_the_python_rule() == rows
     started = []
+    monkeypatch.setattr(gated.harness, "place_compile_cache", lambda: "")
     monkeypatch.setattr(gated.train, "run", lambda *a: started.append(a))
     with pytest.raises(SystemExit) as stop:
         gated.run(manifest.cell(manifest.load(), CELL), None, 0.0)
@@ -140,5 +141,14 @@ def test_gate_hands_over_to_train_unchanged(monkeypatch):
     from benchmark.drivers import train_text_label as gated
 
     monkeypatch.setattr(gated.train, "run", lambda *a: {"got": a})
+    # the compile cache is placed BEFORE the probe imports the program, and
+    # jax with it (PR 34: placed after, this cell alone never found its cache)
+    order = []
+    probe = gated.rows_through_the_python_rule
+    monkeypatch.setattr(gated.harness, "place_compile_cache",
+                        lambda: order.append("cache"))
+    monkeypatch.setattr(gated, "rows_through_the_python_rule",
+                        lambda: order.append("probe") or probe())
     cell = manifest.cell(manifest.load(), CELL)
     assert gated.run(cell, "ARGS", 1.5) == {"got": (cell, "ARGS", 1.5)}
+    assert order == ["cache", "probe"]
