@@ -6,7 +6,10 @@ program, the comparison's recorded numbers, the rate rule, the lexicon's
 shares — fails tier-1 and not only a run by hand of ``benchmark/tests``.
 The flags of cells added since that file was written are recorded in
 ``benchmark/tests/conftest.py`` (imported first: it is no conftest of THIS
-directory).
+directory) or, since PR 35, in the cell's own test file: the cases of
+``benchmark/tests/test_hash2e18_ab4.py`` that need no rehearsal run here too
+(its two fault cases drive the harness for a minute each and run by hand;
+``tests/test_tenant_deployment.py`` holds their in-process twin).
 """
 
 import benchmark.tests.conftest as _added_since  # noqa: F401
@@ -18,4 +21,10 @@ from benchmark.tests.test_logit2e18 import (  # noqa: F401
     test_program_flags_are_the_recorded_list,
     test_the_mix_names_the_gated_driver,
     test_the_cell_is_the_fixtures_learner_on_its_own_files,
+)
+from benchmark.tests.test_hash2e18_ab4 import (  # noqa: F401
+    test_program_flags_are_the_recorded_list as test_ab4_program_flags_are_the_recorded_list,
+    test_readers_find_nothing_in_a_program_without_the_plane,
+    test_the_cell_is_hash2e18_trimmed_280_with_the_plane_on,
+    test_the_cell_reports_the_planes_metrics_and_the_shared_ones,
 )

@@ -2518,10 +2518,19 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
         tenant_inner = handle
 
         def handle(out, batch, t, at_boundary=True):  # noqa: F811
-            _tenants.record_tick(
-                np.asarray(out.count, np.int64),
-                np.asarray(out.mse, np.float64),
-            )
+            counts = np.asarray(out.count, np.int64)
+            _tenants.record_tick(counts, np.asarray(out.mse, np.float64))
+            tr = _trace.get()
+            if tr.enabled:
+                # once per delivered batch, from the stacked counts the ONE
+                # fetch brought: every tenant's batch is padded to the full
+                # row bucket, so the step computed on M·B rows for Σ valid
+                tr.instant(
+                    "tenant_rows", batch=_trace.current_batch(),
+                    rows=counts.tolist(),
+                    pad_rows=int(counts.size * batch.mask.shape[0]
+                                 - counts.sum()),
+                )
             tenant_inner(
                 aggregate_tenant_output(out, batch, model), batch, t,
                 at_boundary=at_boundary,

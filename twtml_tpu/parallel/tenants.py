@@ -17,8 +17,18 @@ instead of extra host fetches:
   batches the tenants across the
   device instead — mathematically equivalent, but XLA's batched-matmul
   accumulation order differs on the dense path, so it is an opt-in for
-  deployments that trade bit-parity for device parallelism (device compute
-  is µs either way; the win of this plane is fetch amortization, not FLOPs);
+  deployments that trade bit-parity for device parallelism. What a tenant
+  COSTS on the device depends on the width: every tenant's batch is padded
+  to the full row bucket and a step's cost does not depend on its mask, so
+  a marginal tenant costs one full single-model step. At the reference's
+  1,004 dims that is not measured on the chip; at 2^18 hashed dims it is
+  the whole Gram step: M = 4 runs four steps of 16.97 ms a batch of 2,048
+  rows (30.1k tweets/s where one model trains 116.7k; 75% of the rows the
+  device works on are padding; PERF.md §5–§6, PR 35), and ``vmap`` there
+  reserves 14.1 GiB of temporaries where ``lax.map`` reserves 4.0 and runs
+  14.4x slower (the Gram gate's ``switch`` becomes a ``select``). The
+  win of this plane is fetch amortization, not FLOPs; the FLOPs wait on a
+  row bucket per tenant (ROADMAP S11);
 - **the wire** is shared: rows route to tenants on the host by a cheap
   deterministic key (``features/batch.tenant_route_keys``), split into M
   same-signature batches (dry tenants = all-padding, the lockstep
@@ -56,9 +66,11 @@ from ..features.batch import (
     stack_batches,
     tenant_route_keys,
     unpack_batch,
+    wire_nbytes,
 )
 from ..models.base import StepOutput
 from ..models.sgd import make_sgd_train_step
+from ..telemetry import trace as _trace
 from ..utils import get_logger
 
 log = get_logger("parallel.tenants")
@@ -326,11 +338,18 @@ class TenantStackModel:
             # coalesced tenant wire (pack_ragged_group): rebuild the
             # stacked [M, ...] leaves in-program — zero-copy bitcasts
             batch = unpack_batch(batch.buffer, batch.layout)
-        if self.mapping == "vmap":
-            return jax.vmap(self._one)(weights, hyper, batch)
-        # lax.map = scan of the single-tenant step with no carry: the SAME
-        # program per tenant, hence bit-identical math (the parity law)
-        return lax.map(lambda args: self._one(*args), (weights, hyper, batch))
+        # one scope around the mapped body: a profile shows the M
+        # iterations under ``tenant_map``, with the step's nine stage scopes
+        # (models/sgd.STAGE_SCOPES) inside it unchanged
+        with jax.named_scope("tenant_map"):
+            if self.mapping == "vmap":
+                return jax.vmap(self._one)(weights, hyper, batch)
+            # lax.map = scan of the single-tenant step with no carry: the
+            # SAME program per tenant, hence bit-identical math (the parity
+            # law)
+            return lax.map(
+                lambda args: self._one(*args), (weights, hyper, batch)
+            )
 
     def _prog_for(self, batch_cls) -> Callable:
         fn = self._progs.get(batch_cls)
@@ -375,8 +394,17 @@ class TenantStackModel:
         ``--wirePack group`` coalesces the M ragged batches into ONE
         contiguous buffer (one main-thread put, uint16-delta offsets);
         ``stacked`` ships M per-field arrays. Bit-identical leaves either
-        way (the wire law, tests/test_superwire.py)."""
-        return self.prepare_wire_from_parts(self.split(batch))
+        way (the wire law, tests/test_superwire.py). Under ``--trace`` the
+        whole of it — route key, M-way split, stack or pack — is one
+        ``tenant_split`` span on the scheduler's thread (inside
+        ``wire_pack``), carrying the routed rows, M and the tenant wire's
+        bytes."""
+        tr = _trace.get()
+        with tr.span("tenant_split", tenants=self.num_tenants) as sp:
+            wire = self.prepare_wire_from_parts(self.split(batch))
+            if tr.enabled:
+                sp.add(rows=int(batch.num_valid), bytes=wire_nbytes(wire))
+        return wire
 
     def prepare_wire_from_parts(self, parts):
         """The wire-layout half of ``prepare_wire`` for callers that route

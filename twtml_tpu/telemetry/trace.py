@@ -72,6 +72,14 @@ Mesh layout: a mesh model's run opens with one ``mesh_layout`` instant
 The device time of the mesh steps' collectives is not a span: it is on the
 device plane under the ``collective`` scope (parallel/sharding.py).
 
+Tenant plane (``--tenants M``, PR 35): ``tenant_split`` spans the host's
+route key, M-way split and stack or pack of the tenant wire (``rows``,
+``tenants``, ``bytes``; inside ``wire_pack``, parallel/tenants.py), and every
+delivered batch leaves one ``tenant_rows`` instant with the M valid-row
+counts of its ONE stacked fetch and ``pad_rows`` = M·B − their sum
+(apps/common.attach_pipeline); the mapped device program sits under the
+``tenant_map`` scope. None of the three exists on the single-model plane.
+
 Event sink (r8): the crash flight recorder (telemetry/blackbox.py) attaches
 via ``set_event_sink`` so recent spans ride its bounded in-memory ring —
 one callback per written event, no second file, nothing when tracing is
