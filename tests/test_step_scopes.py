@@ -535,3 +535,40 @@ def test_compiled_repad_gathers_lane_rows_not_elements(tpu_steps, layout):
         ((_dtype, dims),) = _ARRAY.findall(m.group(1))
         slices += int(np.prod([int(d) for d in dims.split(",")])) // 128
     assert 0 < slices <= ROWS // data_shards * (-(-ROW_LEN // 128) + 1)
+
+
+# ---------------------------------------------------------------------------
+# PR 36: the tenant plane's mapped program is sized by the tenant wire's row
+# rung (features/batch.tenant_row_rungs), not by the whole batch.
+
+def test_compiled_tenant_program_is_sized_by_the_rung(topo):
+    """The mapped program the TPU's compiler makes for the tenant cell's
+    wire — four parts of the first rung of 2,048 rows, 640, with the rung's
+    98,304-unit buffer: its Gram product is ``[640, 640]``, nothing in it
+    has the whole batch's 2,048 rows, and its temporaries stay under a
+    third of the 4,318,823,936 B the ``[4, 2048]`` program takes (PERF.md
+    §4; that one is not compiled here)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from twtml_tpu.features.batch import tenant_row_rungs
+    from twtml_tpu.parallel import TenantStackModel
+
+    m, rung = 4, tenant_row_rungs(ROWS, 4)[0]
+    assert rung == 640
+    dev = SingleDeviceSharding(topo.devices[0])
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=dev)
+
+    model = TenantStackModel(m, num_text_features=F_TEXT, l2_reg=0.1,
+                             step_size=0.005, quality=True)
+    wire = RaggedUnitBatch(
+        shape(m, 98304, dtype=jnp.uint16), shape(m, rung + 1, dtype=jnp.int32),
+        shape(m, rung, 4), shape(m, rung), shape(m, rung), row_len=ROW_LEN)
+    compiled = jax.jit(model._mapped, donate_argnums=0).lower(
+        shape(m, F_TEXT + 4), {k: shape(m) for k in model._hyper}, wire,
+    ).compile()
+    text = compiled.as_text()
+    assert f"f32[{rung},{rung}" in text
+    assert f"[{ROWS}," not in text and f",{ROWS}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4_318_823_936 // 3

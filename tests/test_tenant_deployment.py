@@ -289,9 +289,13 @@ def test_parity_law(tmp_path, tenants, wire_pack):
             stack.latest_weights[i].tobytes())
 
 
+@pytest.mark.parametrize("rows,bucket", [
+    (32, 32),      # a batch under one rung: the top rung, its own rows
+    (256, 128),    # 256 rows over 4 tenants: the first rung
+])
 def test_the_planes_spans_under_trace_and_none_without_it(
-        tmp_path, monkeypatch):
-    rows, batches = 32, 3
+        tmp_path, monkeypatch, rows, bucket):
+    batches = 3
     _g, _chunk, path = _stream(tmp_path, rows, batches, 5)
     spans = {}
     for tenants in (4, 1):
@@ -314,8 +318,11 @@ def test_the_planes_spans_under_trace_and_none_without_it(
     for e in routed:
         a = e["args"]
         assert e["ph"] == "i" and len(a["rows"]) == 4
-        assert sum(a["rows"]) == rows
-        assert a["pad_rows"] == 4 * rows - rows      # 75% of M·B is padding
+        assert sum(a["rows"]) == rows and max(a["rows"]) <= bucket
+        # the rung the split took for this batch, and what of M·rung rows
+        # the step worked on was padding
+        assert a["bucket"] == bucket
+        assert a["pad_rows"] == 4 * a["bucket"] - sum(a["rows"])
     # the plane's worst tenant, one instant a delivered batch
     planes = by(spans[4], "gram_plane")
     assert len(planes) == batches
@@ -358,6 +365,52 @@ def test_the_mapped_program_carries_tenant_map_around_the_stage_scopes():
         hits = [n for n in inside if f"/{scope}/" in n]
         assert hits, scope
         assert {stage_times.stage_of(n) for n in hits} == {scope}
+
+
+def test_the_mapped_program_is_compiled_for_the_rung():
+    """The mapped program's row dimension IS the split's output shape: on
+    the compiled program the batch operands are ``[M, R, ...]`` with R the
+    rung (128 for 256 rows over 4 tenants) and the rung's units buffer, and
+    a stream of evenly split batches compiles ONE program per units bucket
+    of the whole batch, not one per tenant or per batch."""
+    import jax
+
+    from twtml_tpu.features.featurizer import Featurizer, Status
+    from twtml_tpu.parallel import TenantStackModel
+
+    m, rows, batches = 4, 256, 6
+    g = _generator(rows, batches)
+    chunk = gen.make_chunk(g, gen.build_vocab(g, 3), 3, 0, rows * batches)
+    feat = Featurizer(now_ms=g["now_ms"])
+    statuses = [Status.from_json(json.loads(line)) for line in chunk.lines]
+    stack = TenantStackModel(m, num_text_features=F_TEXT, l2_reg=0.1,
+                             step_size=0.005)
+    buckets, wires = set(), set()
+    for b in range(batches):
+        rb = feat.featurize_batch_ragged(
+            statuses[b * rows:(b + 1) * rows], row_bucket=rows,
+            pre_filtered=True)
+        wire = stack.prepare_wire(rb)
+        assert wire.mask.shape == (m, 128)
+        assert wire.offsets.shape == (m, 129)
+        assert wire.numeric.shape == (m, 128, 4)
+        # the parent's units bucket scaled by 128/256, in whole 4,096s
+        half = -(-rb.units.shape[0] // 2)
+        assert wire.units.shape == (m, half + (-half) % 4096)
+        buckets.add((rb.units.shape[0], str(rb.units.dtype)))
+        wires.add((wire.units.shape, str(wire.units.dtype)))
+        out = stack.step(wire)
+        assert np.asarray(out.predictions).shape == (m, 128)
+    prog = stack._prog_for(RaggedUnitBatch)
+    # (two neighbouring buckets of the batch may scale to one of the rung)
+    assert prog._cache_size() == len(wires) <= len(buckets)
+    hlo = prog.lower(stack._weights, stack._hyper, wire).compile().as_text()
+    entry = hlo[hlo.index("ENTRY"):]
+    params = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(", entry)
+    for shape in (f"f32[{m},128]", f"f32[{m},128,4]", f"s32[{m},129]",
+                  f"u16[{m},{wire.units.shape[1]}]"):
+        assert shape in params, (shape, params)
+    assert not any(f",{rows}" in p or f",{rows + 1}]" in p for p in params)
 
 
 def test_work_count_is_a_quarter_of_one_step_and_cannot_pass_the_spent():

@@ -27,9 +27,12 @@ sys.path.insert(0, REPO)
 
 from twtml_tpu.config import ConfArguments  # noqa: E402
 from twtml_tpu.features.batch import (  # noqa: E402
+    RAGGED_UNIT_MULTIPLE,
     RaggedUnitBatch,
     split_batch_tenants,
+    stack_batches,
     tenant_route_keys,
+    tenant_row_rungs,
     tenant_rows,
 )
 from twtml_tpu.features.featurizer import Featurizer  # noqa: E402
@@ -105,10 +108,22 @@ def test_route_keys_deterministic_and_in_range():
     assert ids1.min() >= 0 and ids1.max() < 8
 
 
+def _signature(part):
+    """What the compiled program's shape depends on, for any batch type."""
+    leaves = part if isinstance(part, tuple) else (
+        part.units, part.offsets, part.numeric, part.label, part.mask)
+    return (
+        type(part), getattr(part, "row_len", None),
+        tuple((np.asarray(a).shape, np.asarray(a).dtype) for a in leaves),
+    )
+
+
 def test_split_conserves_rows_and_order():
     """Every valid row lands in exactly one tenant, original relative order
-    preserved per tenant, padded shape shared — the row-conservation
-    invariant the CI smoke asserts end-to-end."""
+    preserved per tenant, and all M parts share ONE signature: the row
+    rung's (256 rows over 4 tenants: the first rung, 128), with that
+    rung's units buffer and the parent's units dtype and ``row_len`` — the
+    row-conservation invariant the CI smoke asserts end-to-end."""
     rb = _ragged_batches()[0]
     ids = tenant_route_keys(rb, 4)
     parts = split_batch_tenants(rb, ids, 4)
@@ -116,9 +131,14 @@ def test_split_conserves_rows_and_order():
     assert sum(int(np.asarray(p.mask).sum()) for p in parts) == valid
     offs = np.asarray(rb.offsets, np.int64)
     units = np.asarray(rb.units)
+    assert tenant_row_rungs(256, 4) == (128, 256)
+    assert len({_signature(p) for p in parts}) == 1
     for m, (rows, part) in enumerate(zip(tenant_rows(rb, ids, 4), parts)):
-        # same signature: shapes, dtype, row_len all match the parent
-        assert part.units.shape == rb.units.shape
+        assert part.mask.shape == part.label.shape == (128,)
+        assert part.offsets.shape == (129,)
+        assert part.numeric.shape == (128, 4)
+        # the parent's 8,192-unit bucket scaled by 128/256
+        assert part.units.shape == (rb.units.shape[0] // 2,)
         assert part.units.dtype == rb.units.dtype
         assert part.row_len == rb.row_len
         assert np.all(np.diff(rows) > 0)  # ascending = order preserved
@@ -131,17 +151,139 @@ def test_split_conserves_rows_and_order():
             assert np.array_equal(part.numeric[j], rb.numeric[r])
 
 
-def test_split_dry_tenant_is_all_padding():
+@pytest.mark.parametrize("rows,tenants,row_multiple,want", [
+    (2048, 4, 1, (640, 1280, 2048)),
+    (2048, 8, 1, (384, 768, 1536, 2048)),
+    (1024, 4, 1, (384, 768, 1024)),
+    (2048, 4, 3, (768, 1536, 2048)),    # a data axis of 3: lcm(128, 3)
+    (2048, 4, 8, (640, 1280, 2048)),
+    (2048, 1, 1, (2048,)),              # one tenant: the batch itself
+    (16, 8, 1, (16,)),                  # a batch under one rung
+    (100, 4, 1, (100,)),
+])
+def test_the_rung_ladder_is_a_function_of_rows_and_tenants(
+        rows, tenants, row_multiple, want):
+    assert tenant_row_rungs(rows, tenants, row_multiple) == want
+
+
+def _rung_batch():
+    """1,024 rows, the first 300 of 16 units and the rest of 4: 7,696
+    units in an 8,192-unit bucket. At M = 4 the rungs are 384 rows (3,072
+    units scaled, rounded up to 4,096), 768 (6,144, rounded up to 8,192)
+    and 1,024 (the parent's 8,192)."""
+    rng = np.random.default_rng(36)
+    lens = np.where(np.arange(1024) < 300, 16, 4)
+    offsets = np.zeros(1025, np.int32)
+    np.cumsum(lens, out=offsets[1:])
+    units = np.zeros(8192, np.uint16)
+    units[: offsets[-1]] = rng.integers(32, 0x3000, offsets[-1])
+    return RaggedUnitBatch(
+        units, offsets, rng.random((1024, 4)).astype(np.float32),
+        (rng.random(1024) * 100).astype(np.float32),
+        np.ones(1024, np.float32), row_len=32,
+    )
+
+
+def _rest_over(ids, tenants):
+    """Spread the rows still at -1 evenly over ``tenants``."""
+    rest = np.nonzero(ids < 0)[0]
+    ids[rest] = np.asarray(tenants)[np.arange(rest.size) % len(tenants)]
+    return ids
+
+
+def _ids_even():
+    return (np.arange(1024) % 4).astype(np.int32)
+
+
+def _ids_one_row_over():
+    ids = np.full(1024, -1, np.int32)
+    ids[300:685] = 0          # 385 short rows: one over the first rung
+    return _rest_over(ids, [1, 2, 3])
+
+
+def _ids_units_over():
+    ids = np.full(1024, -1, np.int32)
+    ids[:300] = 0             # 300 rows fit 384; their 4,800 units do not
+    return _rest_over(ids, [1, 2, 3])
+
+
+@pytest.mark.parametrize("tenants,ids,rung,n_units", [
+    pytest.param(4, _ids_even, 384, 4096, id="even-first_rung"),
+    pytest.param(4, _ids_one_row_over, 768, 8192, id="one_row_over-next"),
+    pytest.param(4, _ids_units_over, 768, 8192, id="units_over-next"),
+    pytest.param(3, lambda: np.zeros(1024, np.int32), 1024, 8192,
+                 id="all_to_tenant_0-top_rung"),
+    pytest.param(1, lambda: np.zeros(1024, np.int32), 1024, 8192,
+                 id="one_tenant-pass_through"),
+])
+def test_split_takes_the_rung_the_batch_calls_for(
+        tenants, ids, rung, n_units):
+    rb = _rung_batch()
+    ids = ids()
+    parts = split_batch_tenants(rb, ids, tenants)
+    assert len(parts) == tenants
+    assert len({_signature(p) for p in parts}) == 1
+    assert parts[0].mask.shape == (rung,) and parts[0].row_len == rb.row_len
+    assert parts[0].offsets.shape == (rung + 1,)
+    assert parts[0].units.shape == (n_units,)
+    assert n_units % RAGGED_UNIT_MULTIPLE == 0
+    counts = np.bincount(ids, minlength=tenants)
+    assert [p.num_valid for p in parts] == counts.tolist()
+    for p, n in zip(parts, counts):    # rows to the front, padding behind
+        assert np.asarray(p.mask)[:n].all() and not np.asarray(p.mask)[n:].any()
+        assert (np.asarray(p.offsets)[n:] == np.asarray(p.offsets)[n]).all()
+    if rung == 1024:
+        # the top rung is the parent's tenant wire byte for byte: the tenant
+        # that got every row gets the batch back, a dry one is all padding
+        for f in ("units", "offsets", "numeric", "label", "mask"):
+            got, want = getattr(parts[0], f), getattr(rb, f)
+            assert got.dtype == want.dtype and got.tobytes() == want.tobytes()
+        for p in parts[1:]:
+            assert int(np.asarray(p.mask).sum()) == 0
+            assert int(np.asarray(p.offsets)[-1]) == 0
+            assert not np.asarray(p.units).any()
+    # a pinned rung reproduces the parent's shape for any split
+    top = split_batch_tenants(rb, ids, tenants, rung=1024)
+    assert {_signature(p) for p in top} == {_signature(rb)}
+    for p, q in zip(parts, top):
+        n = p.num_valid
+        assert np.array_equal(p.label[:n], q.label[:n])
+        assert np.array_equal(
+            p.units[: p.offsets[n]], q.units[: q.offsets[n]])
+
+
+def test_compile_signature_of_a_stacked_tenant_wire_is_the_rungs():
+    from twtml_tpu.features.batch import wire_signature
+
     rb = _ragged_batches()[0]
-    ids = np.zeros(rb.mask.shape[0], np.int32)  # everything to tenant 0
-    parts = split_batch_tenants(rb, ids, 3)
-    for p in parts[1:]:
-        assert int(np.asarray(p.mask).sum()) == 0
-        assert int(np.asarray(p.offsets)[-1]) == 0
-    # tenant 0 gets the batch back byte-identically (order + same buckets)
-    assert np.array_equal(parts[0].units, rb.units)
-    assert np.array_equal(parts[0].offsets, rb.offsets)
-    assert np.array_equal(parts[0].label, rb.label)
+    wire = TenantStackModel(4).prepare_wire(rb)
+    sig = wire_signature(wire, rb)
+    assert (sig["rows"], sig["units_len"]) == (128, rb.units.shape[0] // 2)
+    assert sig["row_len"] == rb.row_len and sig["wire"] == "RaggedUnitBatch"
+    assert wire_signature(rb, rb)["rows"] == 256
+
+
+def test_split_rejects_a_pinned_rung_too_small():
+    with pytest.raises(ValueError, match="rung"):
+        split_batch_tenants(_rung_batch(), _ids_even(), 4, rung=128)
+
+
+def test_split_padded_wires_take_the_rung_on_their_row_axis():
+    """``UnitBatch`` / ``FeatureBatch`` parts: the same rung rule on rows,
+    token width unchanged."""
+    ub = _unit_batches()[0]
+    parts = split_batch_tenants(ub, tenant_route_keys(ub, 4), 4)
+    assert len({_signature(p) for p in parts}) == 1
+    assert parts[0].units.shape == (128, ub.units.shape[1])
+    assert parts[0].units.dtype == ub.units.dtype
+    assert parts[0].mask.shape == (128,)
+    assert sum(p.num_valid for p in parts) == ub.num_valid
+    stacked = stack_batches(parts)
+    assert stacked.units.shape == (4, 128, ub.units.shape[1])
+    # a data axis of 3 has no rung under 256 rows but the batch itself
+    wide = split_batch_tenants(
+        ub, tenant_route_keys(ub, 4), 4, row_multiple=3)
+    assert wide[0].mask.shape == (256,)
 
 
 def test_lang_key_separates_scripts():
@@ -232,6 +374,31 @@ def test_m4_each_tenant_bit_equals_separate_model():
         ), i
 
 
+def test_m4_rung_agrees_with_the_top_rung_to_rounding():
+    """Across rungs the results agree to f32 rounding (the reductions over
+    rows run over fewer zeros): the stack fed each batch at the rung it
+    calls for (128 rows a tenant) against the stack fed the parent's shape
+    (256), weights and per-batch stats."""
+    m = 4
+    kw = dict(num_text_features=4096, l2_reg=0.1, step_size=0.005)
+    at_rung, at_top = TenantStackModel(m, **kw), TenantStackModel(m, **kw)
+    for rb in _ragged_batches(unicode_mix=True):
+        o1 = at_rung.step(rb)
+        assert np.asarray(o1.predictions).shape == (m, 128)
+        o2 = at_top.step(at_top.prepare_wire_from_parts(
+            at_top.split(rb, rung=rb.mask.shape[0])))
+        assert np.asarray(o2.predictions).shape == (m, 256)
+        assert np.array_equal(np.asarray(o1.count), np.asarray(o2.count))
+        for f in ("mse", "real_stdev", "pred_stdev"):
+            a = np.asarray(getattr(o1, f), np.float64)
+            b = np.asarray(getattr(o2, f), np.float64)
+            assert np.all(np.abs(a - b) <= 1e-6 * np.abs(b)), f
+    w1 = at_rung.latest_weights.astype(np.float64)
+    w2 = at_top.latest_weights.astype(np.float64)
+    for i in range(m):
+        assert np.abs(w1[i] - w2[i]).sum() <= 1e-6 * np.abs(w2[i]).sum(), i
+
+
 def test_per_tenant_hyperparams_are_mapped_leaves():
     """Per-tenant step sizes: tenant i bit-equals a single model built with
     THAT step size on the same routed rows."""
@@ -297,6 +464,9 @@ def test_aggregate_output_m4_exact_counts_and_mse():
     rb = _ragged_batches()[0]
     out = jax.device_get(mt.step(rb))
     agg = aggregate_tenant_output(out, rb, mt)
+    # fetched at the rung, delivered at the ORIGINAL batch's row count
+    assert np.asarray(out.predictions).shape == (4, 128)
+    assert np.asarray(agg.predictions).shape == rb.mask.shape == (256,)
     counts = np.asarray(out.count, np.float64)
     assert float(agg.count) == counts.sum()
     want_mse = (counts * np.asarray(out.mse, np.float64)).sum() / counts.sum()
@@ -309,6 +479,13 @@ def test_aggregate_output_m4_exact_counts_and_mse():
         assert np.array_equal(
             np.asarray(agg.predictions)[rows],
             np.asarray(out.predictions)[m][: rows.shape[0]],
+        )
+    # ... which is each row's prediction by ITS tenant's single model
+    singles = [StreamingLinearRegressionWithSGD() for _ in range(4)]
+    for single, part, rows in zip(singles, mt.split(rb), rows_per):
+        assert np.array_equal(
+            np.asarray(agg.predictions)[rows],
+            np.asarray(single.step(part).predictions)[: rows.shape[0]],
         )
 
 
@@ -375,6 +552,32 @@ def test_mesh_data_axis_composes(monkeypatch):
     # group wire bit-equals the stacked wire on the mesh (same program law)
     assert mtm.latest_weights.tobytes() == mtg.latest_weights.tobytes()
     # mesh vs single-device: same math, different psum association
+    assert np.allclose(
+        mtm.latest_weights, ref.latest_weights, rtol=1e-5, atol=1e-4
+    )
+
+
+def test_mesh_parts_share_one_unit_capacity_and_a_rung_of_the_axis():
+    """Rows over ``data``: the rung is a multiple of the axis, and parts
+    whose shards need different unit capacities align to ONE (the
+    fullest's), so they stack; the result is the unsharded plane's."""
+    import jax
+
+    from twtml_tpu.parallel import make_mesh
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs >= 2 devices")
+    rb, ids = _rung_batch(), _ids_units_over()
+    mesh = make_mesh(num_data=2, devices=jax.devices()[:2])
+    ref, mtm = TenantStackModel(4), TenantStackModel(4, mesh=mesh)
+    for model in (ref, mtm):
+        parts = split_batch_tenants(
+            rb, ids, 4, row_multiple=getattr(model, "num_data", 1))
+        wire = model.prepare_wire_from_parts(parts)
+        assert wire.mask.shape == (4, 768)
+        out = model.step(wire)
+    assert wire.num_shards == 2 and wire.units.shape == (4, 2 * 8192)
+    assert np.asarray(out.count).tolist() == [300.0, 242.0, 241.0, 241.0]
     assert np.allclose(
         mtm.latest_weights, ref.latest_weights, rtol=1e-5, atol=1e-4
     )

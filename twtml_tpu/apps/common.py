@@ -2522,14 +2522,19 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
             _tenants.record_tick(counts, np.asarray(out.mse, np.float64))
             tr = _trace.get()
             if tr.enabled:
-                # once per delivered batch, from the stacked counts the ONE
-                # fetch brought: every tenant's batch is padded to the full
-                # row bucket, so the step computed on M·B rows for Σ valid
+                # once per delivered batch, from what the ONE fetch brought:
+                # every tenant's part was padded to the row rung the split
+                # took (the [M, rung] predictions leaf has it; ``batch`` is
+                # the ORIGINAL host batch), so the step computed on M·rung
+                # rows for Σ valid
+                preds = out.predictions
+                bucket = int(
+                    batch.mask.shape[0] if preds is None else preds.shape[1]
+                )
                 tr.instant(
                     "tenant_rows", batch=_trace.current_batch(),
-                    rows=counts.tolist(),
-                    pad_rows=int(counts.size * batch.mask.shape[0]
-                                 - counts.sum()),
+                    rows=counts.tolist(), bucket=bucket,
+                    pad_rows=int(counts.size * bucket - counts.sum()),
                 )
             tenant_inner(
                 aggregate_tenant_output(out, batch, model), batch, t,

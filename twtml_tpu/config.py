@@ -519,6 +519,11 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                shared wire (stacked or coalesced, --wirePack;
                                                dry tenants ride all-padding batches), and all
                                                M tenants' stats come back in ONE stacked fetch.
+                                               A tenant costs the step at the rows its part
+                                               is padded to: a row rung read off each batch
+                                               (1.25*B/M up to 128 rows, doubling, B), so a
+                                               uniform key pays for M*rung rows, a lopsided
+                                               one up to M*B (PERF.md section 6, PR 36).
                                                Per-tenant semantics stay byte-identical to
                                                the single-model path. Default: {self.tenants}
   --tenantKey <hash|lang>                      Tenant routing key: 'hash' = deterministic

@@ -11,6 +11,7 @@ of recompiling per batch (SURVEY.md §7 "hard parts" (a)).
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import numpy as np
@@ -263,7 +264,13 @@ def wire_signature(wire, batch) -> dict:
     """What a ``compile`` trace span says of the call being dispatched
     (telemetry/trace.py): everything of ``batch`` (the unpacked view of
     ``wire``) that the compiled program's shape depends on — rows, row
-    length, units dtype and bucket — and the wire form. Shapes only."""
+    length, units dtype and bucket — and the wire form. Shapes only. A
+    stacked tenant wire (``[M, rung, ...]`` leaves) is read itself: its
+    rows and units bucket are the split's rung's, not the host batch's
+    (the one-buffer tenant wire, ``--wirePack group``, still reports the
+    host batch)."""
+    if getattr(getattr(wire, "mask", None), "ndim", 1) == 2:
+        batch = wire
     units = getattr(batch, "units", None)
     width = units if units is not None else batch.token_idx
     return {
@@ -1082,10 +1089,12 @@ def unpack_batch(buffer, layout: tuple):
 
 # ---- multi-tenant routing (ISSUE 7) ---------------------------------------
 # The tenant plane splits one featurized batch's VALID rows into M per-tenant
-# batches of the SAME padded shape (one wire signature — the lockstep
+# batches of ONE shared padded shape (one wire signature — the lockstep
 # invariant extended to tenants: dry tenants ship all-padding batches so the
 # collective/jit program is identical every tick), then ships them as the
-# stacked / coalesced tenant wire (stack_batches / pack_ragged_group).
+# stacked / coalesced tenant wire (stack_batches / pack_ragged_group). That
+# shape is a ROW RUNG read off the batch in hand (tenant_row_rungs): the rows
+# the fullest tenant got, not the whole batch's.
 # Routing is a pure deterministic function of the batch, so the delivery-side
 # split (per-tenant stats, prediction re-ordering) recomputes it instead of
 # carrying a permutation through the fetch pipeline.
@@ -1099,13 +1108,19 @@ def _splitmix(x: np.ndarray) -> np.ndarray:
 
 
 def _ragged_row_sums(units: np.ndarray, offsets: np.ndarray):
-    """(per-row unit sums, per-row lengths) of a FLAT ragged buffer —
-    cumsum-based so the host pass stays vectorized."""
+    """(per-row unit sums, per-row lengths) of a FLAT ragged buffer — one
+    segmented reduction over the units as they are (no widened copy): the
+    non-empty rows' starts ascend strictly, so ``reduceat`` sums each up to
+    the next one's start, and the last up to the units' end."""
     offs = np.asarray(offsets, np.int64)
-    u = np.asarray(units, np.uint64)
-    c = np.zeros((u.shape[0] + 1,), np.uint64)
-    np.cumsum(u, out=c[1:])
-    return c[offs[1:]] - c[offs[:-1]], (offs[1:] - offs[:-1])
+    lengths = offs[1:] - offs[:-1]
+    sums = np.zeros(lengths.shape, np.uint64)
+    rows = np.nonzero(lengths)[0]
+    if rows.size:
+        sums[rows] = np.add.reduceat(
+            np.asarray(units)[: offs[-1]], offs[rows], dtype=np.uint64
+        )
+    return sums, lengths
 
 
 def tenant_route_keys(
@@ -1185,57 +1200,141 @@ def tenant_rows(batch, tenant_ids: np.ndarray, num_tenants: int):
     return [np.nonzero(ids == m)[0] for m in range(num_tenants)]
 
 
-def split_batch_tenants(batch, tenant_ids: np.ndarray, num_tenants: int):
-    """One featurized batch → M per-tenant batches of the SAME padded shape
-    (same row bucket, same units buffer / token shape, same row_len), valid
-    rows routed by ``tenant_ids`` and packed to the front in original
-    relative order; dry tenants come back all-padding. The M batches share
-    one wire signature by construction, so ``stack_batches`` /
-    ``pack_ragged_group`` turn them into the one-tenant-wire upload."""
+def gather_tenant_predictions(
+    tenant_preds, batch, tenant_ids: np.ndarray, num_tenants: int
+) -> np.ndarray:
+    """The fetched ``[M, R, ...]`` per-tenant predictions (R the split's row
+    rung, each tenant's rows packed to the front) → one array in the
+    ORIGINAL batch's row count and row order; padding rows read 0."""
+    tenant_preds = np.asarray(tenant_preds)
+    preds = np.zeros(
+        (batch.mask.shape[0],) + tenant_preds.shape[2:], tenant_preds.dtype
+    )
+    for m, rows in enumerate(tenant_rows(batch, tenant_ids, num_tenants)):
+        preds[rows] = tenant_preds[m][: rows.shape[0]]
+    return preds
+
+
+# every rung below the top one is a multiple of this many rows (lanes, MXU
+# tiles); the top rung is the batch's own row count, whatever it is
+TENANT_RUNG_MULTIPLE = 128
+
+
+def tenant_row_rungs(
+    rows: int, num_tenants: int, row_multiple: int = 1
+) -> "tuple[int, ...]":
+    """The row counts a tenant's part of a ``rows``-row batch may be padded
+    to — a short fixed ladder, a function of ``rows`` and ``num_tenants``
+    (and the mesh's data axis, ``row_multiple``) ONLY, so the programs a
+    stream can compile are known before it starts. First rung: an even
+    split's share with a quarter of slack, ``1.25·rows/num_tenants`` rounded
+    up to ``TENANT_RUNG_MULTIPLE`` (640 at 2,048 rows and 4 tenants, 384 at
+    8: +6.5 sd over a uniform key's binomial share); then doubling; the top
+    rung is ``rows`` itself — the whole batch's shape, which every lopsided
+    split (``--tenantKey lang``, a dry-tenant stream, one tenant) still
+    reaches. A batch too small for a rung under it has the top rung alone."""
+    step = math.lcm(TENANT_RUNG_MULTIPLE, max(int(row_multiple), 1))
+    rung = -(-5 * rows // (4 * num_tenants))
+    rung += (-rung) % step
+    rungs = []
+    while rung < rows:
+        rungs.append(rung)
+        rung *= 2
+    return (*rungs, rows)
+
+
+def _rung_units(parent_units: int, rung: int, rows: int) -> int:
+    """Units buffer of a ragged part padded to ``rung`` of the batch's
+    ``rows``: the parent's units bucket scaled by rung/rows, rounded up to
+    ``RAGGED_UNIT_MULTIPLE`` — a function of the parent's bucket and the
+    rung, never of one tenant's own text, so a rung adds no program per
+    tenant. The top rung keeps the parent's buffer as it is."""
+    if rung >= rows:
+        return parent_units
+    scaled = -(-parent_units * rung // rows)
+    scaled += (-scaled) % RAGGED_UNIT_MULTIPLE
+    return min(parent_units, scaled)
+
+
+def split_batch_tenants(
+    batch, tenant_ids: np.ndarray, num_tenants: int,
+    row_multiple: int = 1, rung: int = 0,
+):
+    """One featurized batch → M per-tenant batches of ONE shared padded
+    shape, valid rows routed by ``tenant_ids`` and packed to the front in
+    original relative order; dry tenants come back all-padding. The M
+    batches share one wire signature by construction, so ``stack_batches``
+    / ``pack_ragged_group`` turn them into the one-tenant-wire upload.
+
+    The shape is the tenant ROW RUNG, read off the batch in hand: the
+    smallest rung of ``tenant_row_rungs`` that holds the fullest tenant's
+    rows — and, on the ragged wire, its units in ``_rung_units``' buffer
+    (rows that fit a rung whose units do not take the next). What the
+    device then works on is M·rung rows, not M·B: under a uniform key every
+    batch takes the first rung (four parts of 640 rows for a batch of
+    2,048; PERF.md §6, PR 36). Token width / ``row_len`` / units dtype are
+    the parent's. At the top rung (everything to one tenant, M = 1, a
+    batch smaller than a rung) a part has the parent's own shape, and a
+    tenant that got every row gets the parent back byte for byte.
+    ``rung`` pins the shape instead (multi-host callers, whose hosts must
+    agree on it, pass the batch's row count)."""
     rows_per = tenant_rows(batch, tenant_ids, num_tenants)
-    if isinstance(batch, RaggedUnitBatch):
+    b = batch.mask.shape[0]
+    need = max(rows.shape[0] for rows in rows_per)
+    ragged = isinstance(batch, RaggedUnitBatch)
+    n_parent = most = 0  # the padded wires have no units buffer to fit
+    if ragged:
         units = np.asarray(batch.units)
         offs = np.asarray(batch.offsets, np.int64)
         lengths = offs[1:] - offs[:-1]
-        b = batch.mask.shape[0]
-        out = []
-        for rows in rows_per:
-            lens_m = lengths[rows]
-            total = int(lens_m.sum())
-            units_m = np.zeros_like(units)
-            cml = np.zeros((rows.shape[0] + 1,), np.int64)
-            np.cumsum(lens_m, out=cml[1:])
-            if total:
-                idx = (
-                    np.arange(total, dtype=np.int64)
-                    - np.repeat(cml[:-1], lens_m)
-                    + np.repeat(offs[rows], lens_m)
-                )
-                units_m[:total] = units[idx]
-            offs_m = np.full((b + 1,), total, np.int32)
-            offs_m[: rows.shape[0] + 1] = cml.astype(np.int32)
-            numeric = np.zeros_like(np.asarray(batch.numeric))
-            label = np.zeros_like(np.asarray(batch.label))
-            mask = np.zeros_like(np.asarray(batch.mask))
-            n = rows.shape[0]
-            numeric[:n] = np.asarray(batch.numeric)[rows]
-            label[:n] = np.asarray(batch.label)[rows]
-            mask[:n] = 1.0
-            out.append(RaggedUnitBatch(
-                units_m, offs_m, numeric, label, mask,
-                row_len=batch.row_len, num_shards=1,
-            ))
-        return out
+        lens_per = [lengths[rows] for rows in rows_per]
+        totals = [int(lens_m.sum()) for lens_m in lens_per]
+        n_parent, most = units.shape[0], max(totals)
+    ladder = (int(rung),) if rung else tenant_row_rungs(
+        b, num_tenants, row_multiple
+    )
+    r = next(
+        (r for r in ladder
+         if r >= need and _rung_units(n_parent, r, b) >= most),
+        0,
+    )
+    if not r:
+        raise ValueError(
+            f"tenant rung {ladder[-1]} cannot hold a part of {need} rows "
+            f"and {most} units"
+        )
+
+    def padded(arr, rows):
+        arr = np.asarray(arr)
+        dest = np.zeros((r,) + arr.shape[1:], arr.dtype)
+        dest[: rows.shape[0]] = arr[rows]
+        return dest
+
+    if not ragged:
+        return [
+            type(batch)(*(padded(arr, rows) for arr in batch))
+            for rows in rows_per
+        ]
+    # every unit's tenant (-1: a row the mask leaves out): a tenant's units
+    # are then one ordered selection of the parent's, its rows' relative
+    # order kept
+    unit_ids = np.repeat(
+        np.where(np.asarray(batch.mask) > 0, tenant_ids, -1).astype(np.int16),
+        lengths,
+    )
+    live = units[offs[0] : offs[-1]]
     out = []
-    for rows in rows_per:
-        n = rows.shape[0]
-        fields = []
-        for arr in batch:
-            arr = np.asarray(arr)
-            dest = np.zeros_like(arr)
-            dest[:n] = arr[rows]
-            fields.append(dest)
-        out.append(type(batch)(*fields))
+    for m, (rows, lens_m, total) in enumerate(zip(rows_per, lens_per, totals)):
+        units_m = np.zeros((_rung_units(n_parent, r, b),), units.dtype)
+        units_m[:total] = live[unit_ids == m]
+        offs_m = np.full((r + 1,), total, np.int32)
+        offs_m[0] = 0
+        np.cumsum(lens_m, out=offs_m[1 : rows.shape[0] + 1])
+        out.append(RaggedUnitBatch(
+            units_m, offs_m, padded(batch.numeric, rows),
+            padded(batch.label, rows), padded(batch.mask, rows),
+            row_len=batch.row_len, num_shards=1,
+        ))
     return out
 
 
@@ -1244,8 +1343,9 @@ def stack_batches(batches):
     axis — the stacked tenant wire (``--wirePack stacked``: K = M tenants,
     one dispatch maps the step over the axis, parallel/tenants.py). All
     batches must share type, shapes, and dtypes (the tenant split pads
-    every part to one shape; ragged parts additionally share their units
-    bucket)."""
+    every part to one row rung; ragged parts additionally share that rung's
+    units buffer), so the wire is ``[K, rung, ...]``: what it uploads and
+    what the mapped step works on follow the rows the fullest tenant got."""
     first = batches[0]
     for b in batches[1:]:
         if type(b) is not type(first):

@@ -18,21 +18,27 @@ instead of extra host fetches:
   device instead — mathematically equivalent, but XLA's batched-matmul
   accumulation order differs on the dense path, so it is an opt-in for
   deployments that trade bit-parity for device parallelism. What a tenant
-  COSTS on the device depends on the width: every tenant's batch is padded
-  to the full row bucket and a step's cost does not depend on its mask, so
-  a marginal tenant costs one full single-model step. At the reference's
-  1,004 dims that is not measured on the chip; at 2^18 hashed dims it is
-  the whole Gram step: M = 4 runs four steps of 16.97 ms a batch of 2,048
-  rows (30.1k tweets/s where one model trains 116.7k; 75% of the rows the
-  device works on are padding; PERF.md §5–§6, PR 35), and ``vmap`` there
-  reserves 14.1 GiB of temporaries where ``lax.map`` reserves 4.0 and runs
-  14.4x slower (the Gram gate's ``switch`` becomes a ``select``). The
-  win of this plane is fetch amortization, not FLOPs; the FLOPs wait on a
-  row bucket per tenant (ROADMAP S11);
+  COSTS on the device: a step's cost does not depend on its mask, so it is
+  the step at the rows its batch is PADDED to — the tenant row rung
+  (``features/batch.tenant_row_rungs``), read off each batch: the smallest
+  of a short ladder (1.25·B/M rounded up to 128 rows, doubling, B) that
+  holds the fullest tenant's rows and units. The jitted program
+  specialises on the wire's shape, so a rung is one more program per units
+  bucket, and a uniform key takes the first rung in every batch: at 2^18
+  hashed dims M = 4 runs four Gram steps of 640 rows a batch of 2,048,
+  11.9 ms of device time, and the HOST sets the pace (107.4k tweets/s
+  where one model trains 116.7k; PERF.md §5–§6, PR 36). A lopsided split
+  (``--tenantKey lang``, a dry-tenant stream) reaches a wider rung or the
+  top one, B: four steps of 16.97 ms and 30.1k tweets/s at that width
+  (PR 35). At the reference's 1,004 dims not measured on the chip.
+  ``vmap`` at hashed widths reserved 14.1 GiB of temporaries where
+  ``lax.map`` reserved 4.0 and ran 14.4x slower (the Gram gate's
+  ``switch`` becomes a ``select``; PR 35, at 2,048 rows);
 - **the wire** is shared: rows route to tenants on the host by a cheap
   deterministic key (``features/batch.tenant_route_keys``), split into M
-  same-signature batches (dry tenants = all-padding, the lockstep
-  invariant), and ship as ONE M-tenant wire — ``stack_batches``
+  same-signature batches of the row rung's shape (dry tenants =
+  all-padding, the lockstep invariant), and ship as ONE M-tenant wire —
+  ``stack_batches``
   (``--wirePack stacked``) or the coalesced one-buffer
   ``pack_ragged_group`` (``--wirePack group``);
 - **the fetch** is one ``jax.device_get`` of the ``[M, ...]`` StepOutput
@@ -61,6 +67,7 @@ from ..features.batch import (
     NUM_NUMBER_FEATURES,
     PackedBatch,
     RaggedUnitBatch,
+    gather_tenant_predictions,
     pack_ragged_group,
     split_batch_tenants,
     stack_batches,
@@ -88,9 +95,11 @@ def aggregate_tenant_output(out, batch, model) -> StepOutput:
     the stdevs are row-weighted POOLED within-tenant stdevs (each tenant is
     an independent model, so a cross-tenant stdev is not a reference
     quantity; the pooled form is documented in PARITY.md). ``predictions``
-    re-order to original rows via the deterministic routing key — the same
-    route the wire used, recomputed instead of carried through the fetch
-    pipeline. A non-finite stat in ANY tenant propagates into the
+    (fetched as ``[M, rung]``, the split's row rung) come back at the
+    ORIGINAL batch's row count, re-ordered to original rows via the
+    deterministic routing key — the same route the wire used, recomputed
+    instead of carried through the fetch pipeline. A non-finite stat in ANY
+    tenant propagates into the
     aggregate, so the divergence sentinel still sees every poisoning.
 
     ``quality`` (ISSUE 8): M = 1 passes tenant 0's vector through like
@@ -98,8 +107,6 @@ def aggregate_tenant_output(out, batch, model) -> StepOutput:
     M independent models don't pool into one meaningful vector, and the
     model-watch adapter consumes the per-tenant [M, Q] leaf BEFORE this
     aggregation (apps/common.attach_pipeline wrapping order)."""
-    from ..features.batch import tenant_rows
-
     m = model.num_tenants
     if m == 1:
         return StepOutput(*(
@@ -119,11 +126,9 @@ def aggregate_tenant_output(out, batch, model) -> StepOutput:
     ))
     preds = None
     if out.predictions is not None:
-        tenant_preds = np.asarray(out.predictions)
-        preds = np.zeros(tenant_preds.shape[1:], tenant_preds.dtype)
-        rows_per = tenant_rows(batch, model.route_ids(batch), m)
-        for i, rows in enumerate(rows_per):
-            preds[rows] = tenant_preds[i][: rows.shape[0]]
+        preds = gather_tenant_predictions(
+            out.predictions, batch, model.route_ids(batch), m
+        )
     return StepOutput(
         predictions=preds,
         count=np.float32(total),
@@ -153,9 +158,12 @@ class TenantStackModel:
     ``step(batch)`` accepts an ORDINARY featurized host batch: it routes the
     rows (``tenant_route_keys`` → ``split_batch_tenants``), builds the
     stacked/coalesced tenant wire, and runs the one mapped jit program;
-    the returned StepOutput carries ``[M]``-leading leaves (``[M, B]``
-    predictions in per-tenant row order — ``route_ids`` re-derives the
-    original-row permutation on the host). A pre-routed wire (a stacked
+    the returned StepOutput carries ``[M]``-leading leaves (``[M, R]``
+    predictions in per-tenant row order, R the row rung the split took for
+    this batch: the wire, the program's row dimension and so a tenant's
+    device cost follow the rows the fullest tenant got, not the whole
+    batch — ``route_ids`` re-derives the original-row permutation on the
+    host). A pre-routed wire (a stacked
     batch from ``prepare_wire`` or a PackedBatch from ``pack_for_wire``)
     passes straight through — the pack happens once, at the model boundary,
     exactly like the single-tenant packed wire."""
@@ -377,10 +385,14 @@ class TenantStackModel:
         it instead of threading a permutation through the fetch pipeline."""
         return tenant_route_keys(batch, self.num_tenants, self.tenant_key)
 
-    def split(self, batch):
-        """Route + split into the M same-signature tenant batches."""
+    def split(self, batch, rung: int = 0):
+        """Route + split into the M same-signature tenant batches, padded
+        to the row rung the batch calls for (a multiple of the mesh's data
+        axis where there is one), or to ``rung`` when pinned."""
         return split_batch_tenants(
-            batch, self.route_ids(batch), self.num_tenants
+            batch, self.route_ids(batch), self.num_tenants,
+            row_multiple=self.num_data if self.mesh is not None else 1,
+            rung=rung,
         )
 
     def _is_tenant_wire(self, batch) -> bool:
@@ -413,7 +425,7 @@ class TenantStackModel:
         if self.mesh is not None:
             # ragged parts shard-align to the data axis BEFORE stacking
             # (alignment is a flat-batch operation)
-            parts = [self._prepare_part(p) for p in parts]
+            parts = self._align_parts(parts)
         if (
             self.wire_pack == "group"
             and isinstance(parts[0], RaggedUnitBatch)
@@ -438,15 +450,21 @@ class TenantStackModel:
             return pack_ragged_group(parts, codec=codec)
         return stack_batches(parts)
 
-    def _prepare_part(self, part):
-        from ..features.batch import align_ragged_shards
+    def _align_parts(self, parts):
+        from ..features.batch import align_ragged_shards, ragged_shard_bucket
 
         if (
-            isinstance(part, RaggedUnitBatch)
-            and part.num_shards != self.num_data
+            not isinstance(parts[0], RaggedUnitBatch)
+            or parts[0].num_shards == self.num_data
         ):
-            return align_ragged_shards(part, self.num_data)
-        return part
+            return parts
+        # one per-shard unit capacity for all M parts (the fullest's), or
+        # they would not stack
+        bucket = max(ragged_shard_bucket(p, self.num_data) for p in parts)
+        return [
+            align_ragged_shards(p, self.num_data, unit_bucket=bucket)
+            for p in parts
+        ]
 
     # FetchPipeline's pack hook: the tenant wire IS the pack (one routed
     # wire per batch, built once at the model boundary)
@@ -602,6 +620,13 @@ class MultiHostTenantModel:
     [M]-stacked and psum-global; ONE pooled fetch per tick, exactly like
     single-host.
 
+    Every host pads its tenant parts to the TOP row rung, its local
+    batch's own row count (``split(..., rung=B_local)``): the hosts must
+    dispatch one program shape a tick, and a rung read off each host's own
+    rows would need a collective of its own to agree on (the padded wires
+    have none). So a tenant here still costs the step at the whole local
+    batch's rows; the single-host plane sizes it by the rows a tenant got.
+
     The stacked wire is the only multi-host tenant wire (the coalesced
     group buffer has no tenant-axis layout across processes). The RAGGED
     tenant split (r20, lifting the padded-only rejection) needs every
@@ -745,7 +770,9 @@ class MultiHostTenantModel:
         tenant wire on the row axis, and run the stacked program. Dispatch
         only — the host transfer lives in ``fetch_output`` (the main
         thread never blocks on a device fetch)."""
-        parts = self.inner.split(local_batch)
+        parts = self.inner.split(
+            local_batch, rung=local_batch.mask.shape[0]
+        )
         if isinstance(parts[0], RaggedUnitBatch):
             # ragged tenant wire (r20): shared-bucket aligned stack; the
             # 1-process degenerate epoch skips only the row-axis assembly

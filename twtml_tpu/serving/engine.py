@@ -137,23 +137,19 @@ class PredictEngine:
     def predictions_for(self, host_out, batch) -> np.ndarray:
         """The fetched StepOutput's predictions re-ordered to the ORIGINAL
         batch rows, valid rows only ([n] float array). Single-model output
-        is already row-ordered; the tenant stack's [M, B] per-tenant-order
+        is already row-ordered; the tenant stack's [M, rung] per-tenant-order
         output re-orders through the recomputed deterministic route exactly
         like ``aggregate_tenant_output`` (routing is host-side metadata —
         PARITY.md)."""
         mask = np.asarray(batch.mask) > 0
         if self.num_tenants == 1:
             return np.asarray(host_out.predictions)[mask]
-        from ..features.batch import tenant_rows
+        from ..features.batch import gather_tenant_predictions
 
-        tenant_preds = np.asarray(host_out.predictions)
-        preds = np.zeros(tenant_preds.shape[1:], tenant_preds.dtype)
-        rows_per = tenant_rows(
-            batch, self.model.route_ids(batch), self.num_tenants
-        )
-        for m, rows in enumerate(rows_per):
-            preds[rows] = tenant_preds[m][: rows.shape[0]]
-        return preds[mask]
+        return gather_tenant_predictions(
+            host_out.predictions, batch, self.model.route_ids(batch),
+            self.num_tenants,
+        )[mask]
 
     def tenant_row_counts(self, batch) -> "np.ndarray | None":
         """[M] valid-row counts this batch routed per tenant (None on the

@@ -76,9 +76,11 @@ Tenant plane (``--tenants M``, PR 35): ``tenant_split`` spans the host's
 route key, M-way split and stack or pack of the tenant wire (``rows``,
 ``tenants``, ``bytes``; inside ``wire_pack``, parallel/tenants.py), and every
 delivered batch leaves one ``tenant_rows`` instant with the M valid-row
-counts of its ONE stacked fetch and ``pad_rows`` = M·B − their sum
-(apps/common.attach_pipeline); the mapped device program sits under the
-``tenant_map`` scope. None of the three exists on the single-model plane.
+counts of its ONE stacked fetch (``rows``), the row rung the split padded
+every tenant's part to for that batch (``bucket``, PR 36: read off the
+fetched ``[M, bucket]`` predictions leaf) and ``pad_rows`` = M·``bucket`` −
+their sum (apps/common.attach_pipeline); the mapped device program sits
+under the ``tenant_map`` scope. None of the three exists on the single-model plane.
 
 Event sink (r8): the crash flight recorder (telemetry/blackbox.py) attaches
 via ``set_event_sink`` so recent spans ride its bounded in-memory ring —
