@@ -6,6 +6,7 @@ max-batches caps, tail drained by flush()."""
 
 import functools
 import json
+import threading
 
 import numpy as np
 import pytest
@@ -37,8 +38,8 @@ def test_emits_in_order_and_flush_drains():
     assert model.dispatched == list(range(10))
     assert [e[0] for e in events] == list(range(10))  # strict order
     # at_boundary True iff the pipeline was empty after the emit (an
-    # instant fake model drains opportunistically, so most emits qualify);
-    # the final drained batch always does
+    # instant fake model's results are done at the next round's count, so
+    # most emits qualify); the final drained batch always does
     assert events[-1][1] is True
 
 
@@ -210,6 +211,197 @@ def test_deterministic_mode_emits_only_at_deterministic_points():
     assert events == [0]
     pipe.flush()
     assert events == [0, 1, 2, 3, 4]
+
+
+# -- one delivery a round (PR 39; PERF.md §7 row 17) -------------------------
+# The round is backpressure -> deliver -> pack -> dispatch, and the delivery is
+# bounded by a count taken ONCE: the leading results whose fetch was done when
+# the delivery began. A model whose fetches complete on command shows each
+# side of the rule without a clock.
+
+class GatedModel:
+    """``step`` returns at once; batch i's pooled fetch blocks until
+    ``finish(pipe, i)`` opens its gate. ``log`` holds dispatches and
+    deliveries in the order the main thread made them."""
+
+    def __init__(self):
+        self.log = []
+        self.gates = {}
+
+    def step(self, batch):
+        self.log.append(("dispatch", batch))
+        self.gates[batch] = threading.Event()
+        return batch
+
+    def fetch_output(self, out):
+        assert self.gates[out].wait(30)
+        return out
+
+    def finish(self, pipe, i):
+        """Batch i's fetch completes, and is SEEN complete (``done()``)."""
+        self.gates[i].set()
+        next(e[0] for e in pipe._pending if e[2] == i).result(timeout=30)
+
+
+def _gated_pipe(depth, deterministic=False, during=None, stop=None):
+    """A pipeline over a GatedModel; ``during[i]()`` runs inside batch i's
+    handler. Returns (model, pipe, delivered-with-at_boundary)."""
+    model, events = GatedModel(), []
+
+    def handle(out, b, t, at_boundary):
+        model.log.append(("deliver", out))
+        events.append((out, at_boundary))
+        (during or {}).get(out, lambda: None)()
+
+    pipe = FetchPipeline(model, handle, depth=depth,
+                         deterministic=deterministic, stop_requested=stop)
+    return model, pipe, events
+
+
+def _case_becomes_done_during_the_heads_handler():
+    # (a) head done, the next becomes done DURING the head's handler: the
+    # round delivers ONE, the next round the other
+    during = {}
+    model, pipe, events = _gated_pipe(4, during=during)
+    during[0] = lambda: model.finish(pipe, 1)
+    pipe.on_batch(0, 0.0)
+    pipe.on_batch(1, 0.0)
+    model.finish(pipe, 0)
+    pipe.on_batch(2, 0.0)
+    assert events == [(0, False)]          # 1 is done by now, and waits
+    assert pipe._pending[0][0].done()
+    assert model.log[-2:] == [("deliver", 0), ("dispatch", 2)]
+    pipe.on_batch(3, 0.0)
+    assert events == [(0, False), (1, False)]
+    return model, pipe, events, 4
+
+
+def _case_two_done_when_the_phase_begins():
+    # (b) a host that fell behind: two done when the delivery begins -> both
+    # in that round, in order; the second leaves nothing in flight, so it
+    # is a boundary (weights current), the first is not
+    model, pipe, events = _gated_pipe(4)
+    pipe.on_batch(0, 0.0)
+    pipe.on_batch(1, 0.0)
+    model.finish(pipe, 0)
+    model.finish(pipe, 1)
+    pipe.on_batch(2, 0.0)
+    assert events == [(0, False), (1, True)]
+    assert model.log[-3:] == [("deliver", 0), ("deliver", 1), ("dispatch", 2)]
+    return model, pipe, events, 3
+
+
+def _case_nothing_done_below_depth():
+    # (c) nothing done and fewer than depth in flight: dispatched, nothing
+    # delivered, nothing waited for
+    model, pipe, events = _gated_pipe(4)
+    for i in range(3):
+        pipe.on_batch(i, 0.0)
+    assert model.log == [("dispatch", i) for i in range(3)]
+    assert events == [] and pipe.pending_fetches == 3
+    return model, pipe, events, 3
+
+
+def _case_depth_reached_blocks_before_the_dispatch():
+    # (d) depth results in flight: the round blocks on the head BEFORE it
+    # dispatches (the device-paced path, as before PR 39)
+    model, pipe, events = _gated_pipe(2)
+    pipe.on_batch(0, 0.0)
+    pipe.on_batch(1, 0.0)
+    assert not pipe._pending[0][0].done()
+    opener = threading.Timer(0.05, model.gates[0].set)
+    opener.start()
+    pipe.on_batch(2, 0.0)                  # returns only once 0 was fetched
+    opener.join()
+    assert model.log == [("dispatch", 0), ("dispatch", 1), ("deliver", 0),
+                         ("dispatch", 2)]
+    assert events == [(0, False)] and pipe.pending_fetches == 2
+    return model, pipe, events, 3
+
+
+def _case_a_counted_delivery_can_veto_the_dispatch():
+    # (e) the counted deliveries come BEFORE the round's dispatch (the
+    # order is today's: PERF.md §6 PR 39), so a handler's request_stop
+    # vetoes it and a capped run stays exact
+    stop = {"flag": False}
+    model, pipe, events = _gated_pipe(
+        4, during={0: lambda: stop.update(flag=True)},
+        stop=lambda: stop["flag"])
+    pipe.on_batch(0, 0.0)
+    model.finish(pipe, 0)
+    pipe.on_batch(1, 0.0)                  # delivers 0, whose handler stops
+    assert model.log == [("dispatch", 0), ("deliver", 0)]
+    assert events == [(0, True)]
+    return model, pipe, events, 1
+
+
+def _case_deterministic_never_delivers_early():
+    # (f) multi-host lockstep: done() never drives a delivery
+    model, pipe, events = _gated_pipe(4, deterministic=True)
+    for i in range(3):
+        pipe.on_batch(i, 0.0)
+        model.finish(pipe, i)
+        assert events == []
+    return model, pipe, events, 3
+
+
+@pytest.mark.parametrize("case", [
+    _case_becomes_done_during_the_heads_handler,
+    _case_two_done_when_the_phase_begins,
+    _case_nothing_done_below_depth,
+    _case_depth_reached_blocks_before_the_dispatch,
+    _case_a_counted_delivery_can_veto_the_dispatch,
+    _case_deterministic_never_delivers_early,
+], ids=lambda c: c.__name__[len("_case_"):])
+def test_one_delivery_a_round(case):
+    model, pipe, events, n = case()
+    for gate in model.gates.values():
+        gate.set()
+    pipe.flush()
+    # whatever the round did: every batch delivered once, in order, and the
+    # last one at a boundary (nothing newer in flight)
+    assert [e[0] for e in events] == list(range(n))
+    assert events[-1][1] is True
+    assert [b for what, b in model.log if what == "dispatch"] == list(range(n))
+
+
+def test_deliver_round_instant_counts_the_round(tmp_path, monkeypatch):
+    """Under --trace every round that dispatched leaves one
+    ``deliver_round`` instant: what was ready when its delivery began, what
+    it delivered (backpressure included) and what it left in flight — what
+    benchmark/layer_metrics/paired_delivery_share.py reads."""
+    from benchmark import manifest, spans, trace_files
+    from twtml_tpu.telemetry import trace
+
+    path = str(tmp_path / "spans.json")
+    trace.install(path)
+    try:
+        model, pipe, events = _gated_pipe(2)
+        pipe.on_batch(0, 0.0)                  # ready 0, delivered 0
+        model.finish(pipe, 0)
+        pipe.on_batch(1, 0.0)                  # ready 1, delivered 1
+        pipe.on_batch(2, 0.0)                  # nothing done: 0 / 0
+        model.finish(pipe, 1)
+        model.finish(pipe, 2)
+        pipe.on_batch(3, 0.0)                  # backpressure 1 + ready 1
+        model.gates[3].set()
+        pipe.flush()
+    finally:
+        trace.uninstall()
+    rounds = [e["args"] for e in spans.load_events(path)
+              if e["name"] == "deliver_round"]
+    assert [(a["ready"], a["delivered"], a["pending"]) for a in rounds] == [
+        (0, 0, 1), (1, 1, 1), (0, 0, 2), (1, 2, 1)]
+    reader = manifest.load_module(
+        manifest.layer_metric_path("paired_delivery_share"))
+    monkeypatch.setattr(trace_files, "span_file", lambda: path)
+    assert reader.read({}) == 100.0 * 2 / 3     # 2 of 3 in a round of two
+    monkeypatch.setattr(trace_files, "span_file", lambda: None)
+    assert reader.read({}) is None              # no live traced run
+    empty = tmp_path / "none.json"
+    empty.write_text('[\n{"name": "gram_plane", "ph": "i"},\n')
+    monkeypatch.setattr(trace_files, "span_file", lambda: str(empty))
+    assert reader.read({}) is None              # the parent's program
 
 
 # -- pipelined == sequential, for every model kind and wire form -------------

@@ -466,6 +466,36 @@ def test_rule_registry_is_stable():
         assert r.title and r.law, f"{r.id} must cite its law"
 
 
+@pytest.mark.parametrize("inside", ["tmpdir", "home", "_scratch/t",
+                                    "_checkout/parent"])
+def test_a_tmpdir_or_home_inside_the_checkout_is_not_the_repo(
+        tmp_path, monkeypatch, inside):
+    """The driver gives each checkout a TMPDIR and a HOME of its own; where
+    they lie inside it, pytest's ``tmp_path`` trees (this file's seeded
+    violations among them) and a tree unpacked for a chip pair are not the
+    repo's code. Anywhere else the same file IS judged."""
+    import tempfile
+
+    bad = {"twtml_tpu/broken.py": "def (:\n"}
+    assert run(tmp_path, bad).exit_code == 2       # in the repo: judged
+    (tmp_path / "twtml_tpu/broken.py").unlink()
+    mini_repo(tmp_path / inside, bad)
+    if inside == "tmpdir":
+        monkeypatch.setattr(tempfile, "tempdir", str(tmp_path / inside))
+    elif inside == "home":
+        monkeypatch.setenv("HOME", str(tmp_path / inside))
+    else:
+        assert inside.split("/")[0] in engine._SKIP_DIRS
+    report = engine.run_repo(root=str(tmp_path),
+                             baseline_path=str(tmp_path / "baseline.json"))
+    assert report.exit_code == 0 and report.malformed == []
+    if inside in ("tmpdir", "home"):
+        monkeypatch.undo()                         # placed elsewhere: judged
+        assert engine.run_repo(
+            root=str(tmp_path),
+            baseline_path=str(tmp_path / "baseline.json")).exit_code == 2
+
+
 def test_repo_is_clean_with_empty_baseline():
     """THE acceptance criterion: the real checkout passes every law with
     nothing grandfathered — every remaining deviation is an inline

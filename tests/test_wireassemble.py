@@ -427,6 +427,52 @@ def test_fetch_pipeline_retires_leases_on_delivery():
     assert st["free_buffers"] >= 1  # and recycled through the pool
 
 
+def test_one_delivery_a_round_keeps_the_arena_at_one_buffer():
+    """PR 39: the round delivers what was done at its count BEFORE it packs,
+    so in the host-paced steady state (each result done one round after its
+    dispatch) the lease of the batch just delivered is back in the pool
+    when the next wire is packed: ``wire.arena_misses`` stays at the one
+    buffer of the first round and every later pack recycles."""
+    import threading
+
+    from twtml_tpu.apps.common import FetchPipeline
+    from twtml_tpu.telemetry import metrics as _metrics
+
+    class _Lagged(_EchoModel):
+        def __init__(self):
+            self.gates = []
+
+        def step(self, wire):
+            self.gates.append(threading.Event())
+            return len(self.gates) - 1
+
+        def fetch_output(self, out):
+            assert self.gates[out].wait(30)
+            return out
+
+    _metrics.reset_for_tests()
+    arena_mod.get_arena().reset_for_tests()
+    model, got = _Lagged(), []
+    pipe = FetchPipeline(
+        model, lambda out, b, t, at_boundary: got.append(out),
+        depth=8, pack=True,
+    )
+    misses = _metrics.get_registry().counter("wire.arena_misses")
+    batch, warm = hand_batch(seed=10), None
+    for i in range(24):
+        pipe.on_batch(batch, float(i))
+        assert got == list(range(i))       # one delivery a round, one late
+        model.gates[i].set()               # done before the next count
+        next(e[0] for e in pipe._pending if e[1] == i).result(timeout=30)
+        if i == 3:
+            warm = misses.snapshot()
+    assert arena_mod.get_arena().stats()["in_use"] == 1
+    assert misses.snapshot() == warm == 1  # the first round's, recycled since
+    pipe.flush()
+    assert got == list(range(24))
+    assert arena_mod.get_arena().stats()["in_use"] == 0
+
+
 def test_fetch_pipeline_discards_leases_on_abort(monkeypatch):
     from twtml_tpu.apps.common import FetchAbort, FetchPipeline
 

@@ -20,16 +20,19 @@ import ast
 import json
 import os
 import sys
+import tempfile
 
 from .findings import Finding, Malformed
 from .rules import FileContext, RepoContext, all_rules, rule_ids
 from .suppress import scan as scan_suppressions
 
 # generated / gitignored trees are not the repo's code (a tree unpacked
-# under target/ for a chip rehearsal must not be judged twice)
+# under target/ or _checkout/ for a chip rehearsal must not be judged twice;
+# _scratch/ and .bench_work/ hold the benchmark tests' fixture trees)
 _SKIP_DIRS = {
     ".git", "__pycache__", ".pytest_cache", "node_modules", "doc",
-    "target", "chiprun_out", ".jax_cache",
+    "target", "chiprun_out", ".jax_cache", ".hypothesis", ".bench_work",
+    "_checkout", "_scratch",
 }
 _DEFAULT_BASELINE = os.path.join(os.path.dirname(__file__), "baseline.json")
 
@@ -40,11 +43,31 @@ def repo_root() -> str:
     )))
 
 
+def _foreign_dirs(root: str) -> set[str]:
+    """A TMPDIR or HOME that lies INSIDE the checkout (the driver's test
+    run gives each checkout its own) is not the repo's code either: pytest
+    keeps its ``tmp_path`` trees there, among them the mini-repos with
+    seeded violations and unparsable files that this checker's own tests
+    write — judged as part of the repo they made the real checkout read
+    "malformed" in every whole run and clean alone (PR 39)."""
+    root = os.path.realpath(root)
+    found = set()
+    for d in (tempfile.gettempdir(), os.path.expanduser("~")):
+        d = os.path.realpath(d)
+        if d.startswith(root + os.sep):
+            found.add(d)
+    return found
+
+
 def iter_py_files(root: str) -> list[str]:
     out: list[str] = []
+    foreign = _foreign_dirs(root)
     for dirpath, dirnames, filenames in os.walk(root):
         dirnames[:] = sorted(
-            d for d in dirnames if d not in _SKIP_DIRS
+            d for d in dirnames if d not in _SKIP_DIRS and not (
+                foreign
+                and os.path.realpath(os.path.join(dirpath, d)) in foreign
+            )
         )
         for name in sorted(filenames):
             if name.endswith(".py"):

@@ -1897,6 +1897,22 @@ class FetchPipeline:
     enforced before dispatch, not discovered after). ``flush()`` after
     stream termination drains the tail.
 
+    The round (``on_batch``) is *backpressure → deliver → pack → dispatch*:
+    while ``depth`` results are in flight it blocks on the oldest (the
+    device-paced path), then delivers, oldest first, the k leading results
+    whose fetch was done WHEN THAT DELIVERY BEGAN — k is counted once, so a
+    result that becomes done during a delivery waits for the next round (at
+    most one round late), and a host that fell behind finds k ≥ 2 and
+    catches up in one round — and only then packs and dispatches. Why the
+    count (PERF.md §7 row 17): re-testing ``done()`` after every delivery
+    made rounds alternate TWO deliveries and none wherever a device step
+    ended inside one delivery's handlers and POSTs (a 12 ms step under a
+    19 ms round: stats arrived ~6 then ~33 ms apart). Why not the dispatch
+    first (PERF.md §6 PR 39, measured): it published each result one
+    featurize sooner and cost the tenant plane 1.5–4% of its rate.
+    ``poll()``, ``drain()`` and ``flush()`` deliver everything that is done
+    or in flight: they dispatch nothing.
+
     ``deterministic`` (multi-host mode) disables the opportunistic
     already-done early emit: handler side effects (request_stop,
     empty-global refunds) then fire only at DETERMINISTIC points — the
@@ -2049,16 +2065,26 @@ class FetchPipeline:
             # live source keeps batching forever
             self._drain()
             return
-        # backpressure + timeliness: block down to depth-1 in flight, then
-        # opportunistically consume whatever already finished (skipped in
-        # deterministic/multi-host mode — see the class docstring)
-        while len(self._pending) >= self.depth or (
-            not self.deterministic
-            and self._pending and self._pending[0][0].done()
-        ):
+        # backpressure: block down to depth-1 in flight (the device-paced
+        # path); then ONE DELIVERY A ROUND (class docstring; PERF.md §7 row
+        # 17): the leading results that are done AT THIS MOMENT, oldest
+        # first, and no more — the count is taken once, never re-tested
+        # after a delivery. Skipped in deterministic/multi-host mode
+        in_flight = len(self._pending)
+        while len(self._pending) >= self.depth:
             self._emit_one()
             if stop is not None and stop():
                 return  # the cap landed on an emitted batch: do not dispatch
+        ready = 0
+        if not self.deterministic:
+            for entry in self._pending:
+                if not entry[0].done():
+                    break
+                ready += 1
+        for _ in range(ready):
+            self._emit_one()
+            if stop is not None and stop():
+                return  # as above
         tr = _trace.get()
         import time as _time
 
@@ -2111,6 +2137,11 @@ class FetchPipeline:
         ):
             self._drain()  # cadence point: weights current for checkpoints
             self._last_boundary = self._cadence
+        if tr.enabled:
+            # the round's engagement counter (paired_delivery_share)
+            left = len(self._pending)
+            tr.instant("deliver_round", batch=seq, ready=ready,
+                       delivered=in_flight + 1 - left, pending=left)
 
     def refund_dispatch(self) -> None:
         """Give back one ``max_dispatch`` slot — called by handlers that

@@ -58,6 +58,15 @@ parsed block ``source_lines`` / ``source_recv`` (the source thread's chunk
 loop and its socket reads, streaming/twitter.py). PERF.md §3 names the
 benchmark metric that reads each.
 
+Rounds (PR 39): every call of ``FetchPipeline.on_batch`` that got as far
+as its dispatch leaves one ``deliver_round`` instant (apps/common.py):
+``batch`` (the one it dispatched), ``ready`` (the leading in-flight results
+whose fetch was done when the round's delivery began: the count that bounds
+it), ``delivered`` (every result the round handed to the handlers:
+backpressure, the ``ready`` ones, a cadence drain's) and ``pending`` (left
+in flight). ``paired_delivery_share`` reads it: the share of deliveries
+made two or more to a round.
+
 ``compile`` spans: ``install()`` registers ``jax.monitoring`` listeners
 (``uninstall()`` takes them away again; nothing is registered while
 tracing is off) that write one ``compile`` span per backend compilation
