@@ -1,9 +1,11 @@
 """Library-level client for the serving front door (``POST /api/predict``).
 
-Same stdlib-urllib shape as ``telemetry/web_client.py`` — no external HTTP
-dependency — but predict calls RAISE on failure instead of the telemetry
-client's best-effort ``Try`` semantics: a load generator or an ops script
-must see a refused/aborted predict, not silently drop it. The paired serving
+Stdlib urllib, no external HTTP dependency (``telemetry/web_client.py``, the
+trainer's per-batch publisher, runs its own exchange on a kept socket since
+PR 41; this client is on no per-batch path) — and predict calls RAISE on
+failure instead of the telemetry client's best-effort ``Try`` semantics: a
+load generator or an ops script must see a refused/aborted predict, not
+silently drop it. The paired serving
 bench (``tools/bench_serving.py``) and the serve-smoke tests drive this
 client as their load face.
 """

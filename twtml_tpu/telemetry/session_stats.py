@@ -36,7 +36,11 @@ SERIES_MAX_POINTS = CHART_MAX_POINTS
 
 # publish a pipeline-metrics snapshot every N stats updates: counters move
 # every batch but the dashboard panel doesn't need per-batch resolution,
-# and each publish is one more best-effort HTTP POST on the hot path
+# and each publish is one more best-effort HTTP POST on the hot path: one
+# buffer, one ``sendall`` and the reads of the reply on the socket the
+# client keeps, plus a ``connect`` where the server closed the last one
+# (telemetry/web_client.py, PR 41; redirects are not followed) — and the
+# scheduler still waits for every reply before it goes on
 METRICS_EVERY = 8
 
 # degraded-phase load shedding: ship only every Nth batch's series frame
@@ -131,9 +135,14 @@ class SessionStats:
             )
             return
         # ``batch`` here is the batch's ROW count; the span's ``batch`` arg
-        # is the scheduler's sequence number (trace.batch_scope)
-        with tr.span("stats_publish", rows=int(batch)):
+        # is the scheduler's sequence number (trace.batch_scope); ``posts`` /
+        # ``connects``: the requests this update sent and the connections it
+        # opened for them (benchmark/layer_metrics/publish_reuse_share.py)
+        with tr.span("stats_publish", rows=int(batch)) as span:
+            posts, connects = self.web.requests, self.web.connects
             self._update(count, batch, mse, real_stdev, pred_stdev, real, pred)
+            span.add(posts=self.web.requests - posts,
+                     connects=self.web.connects - connects)
         _sideband.record_stage("stats_publish", _time.perf_counter() - t0)
 
     def _series_due(self) -> bool:

@@ -67,6 +67,13 @@ backpressure, the ``ready`` ones, a cadence drain's) and ``pending`` (left
 in flight). ``paired_delivery_share`` reads it: the share of deliveries
 made two or more to a round.
 
+Publish (PR 41): every ``stats_publish`` span carries ``posts`` (the
+requests that update sent: ``Stats`` and ``Series``, on every eighth update
+the metrics frames too) and ``connects`` (the connections
+``telemetry/web_client.WebClient`` opened for them: 0 while the server keeps
+the connection, one a request where it closes each). ``publish_reuse_share``
+reads both.
+
 ``compile`` spans: ``install()`` registers ``jax.monitoring`` listeners
 (``uninstall()`` takes them away again; nothing is registered while
 tracing is off) that write one ``compile`` span per backend compilation

@@ -2,15 +2,42 @@
 
 Same surface as the reference's scalaj-http client
 (spark/.../web/WebClient.scala:9-56): POST Config/Stats to ``{server}/api``,
-GET them back from ``/api/config`` and ``/api/stats``. stdlib urllib — no
-external HTTP dependency; callers wrap calls best-effort like the reference
-wraps them in ``Try`` (SessionStats.scala:29-33,60).
+GET them back from ``/api/config`` and ``/api/stats``. No external HTTP
+dependency; callers wrap calls best-effort like the reference wraps them in
+``Try`` (SessionStats.scala:29-33,60).
+
+The exchange is the client's own, over a socket it keeps (PR 41; what a POST
+costs and what it cost through ``urllib.request.urlopen`` is in PERF.md §6):
+a request is ONE buffer handed to ONE ``sendall``; the reply is read into
+one buffer with as few ``recv`` calls as its bytes allow, its head cut at
+the first blank line, and the status and the three headers that matter
+(``Content-Length``, ``Connection``, ``Transfer-Encoding``) taken with
+``bytes`` operations. The connection is kept while the reply allows it
+(HTTP/1.1 without ``Connection: close``: the dashboard, web/server.py) and
+the next request goes out on it with no ``connect``; a request that fails on
+a KEPT connection before any byte of its reply arrived is sent once more on
+a fresh one (the server had closed it while idle), a failure on a fresh
+connection is a failure. ``https://`` wraps the same socket at connect and
+runs the same exchange. Every call returns only after the server's reply to
+it has been read: no pipelining. Redirects are NOT followed (the API has
+none): a 3xx raises like any status outside 2xx. ``timeout`` bounds the
+connect, a TLS handshake and every send and read (``settimeout``); a bound
+that ran out raises ``TimeoutError``.
+
+Counted: ``requests`` (sent; the read side's GETs too) and ``connects``
+(connections opened) on the client, and over every client of the process in
+the registry counters ``web.requests`` / ``web.connects``; SessionStats
+writes each update's share of both on its ``stats_publish`` span as
+``posts`` / ``connects`` (PERF.md §3, ``publish_reuse_share``).
 """
 
 from __future__ import annotations
 
-import urllib.request
+import socket
+import ssl
+import threading
 
+from . import metrics as _metrics
 from .api_types import (
     Config, Fleet, Freshness, History, Hosts, Metrics, ModelHealth, Series,
     Serving, Stats, Tenants, decode, encode,
@@ -18,11 +45,112 @@ from .api_types import (
 
 DEFAULT_SERVER = "http://localhost:8888"  # WebClient.scala:13
 
+_RECV = 65536
+
+
+class WebStatusError(OSError):
+    """The server answered with a status outside 2xx."""
+
+    def __init__(self, code: int, body: bytes):
+        super().__init__(f"HTTP {code}")
+        self.code = code
+        self.body = body
+
+
+class _Stale(ConnectionError):
+    """The peer had closed a kept connection: no byte of a reply came."""
+
+
+def _header(head: bytes, name: bytes) -> bytes:
+    """Value of header ``name`` in a LOWER-CASED reply head (b"" if absent)."""
+    at = head.find(b"\r\n" + name + b":")
+    if at < 0:
+        return b""
+    start = at + len(name) + 3
+    end = head.find(b"\r\n", start)
+    return head[start:end if end >= 0 else len(head)].strip()
+
+
+def _more(sock: socket.socket) -> bytes:
+    data = sock.recv(_RECV)
+    if not data:
+        raise ConnectionError("the server closed inside its reply")
+    return data
+
+
+def _dechunk(sock: socket.socket, buf: bytes) -> bytes:
+    """Body of a chunked reply; ``buf`` is what arrived behind the head."""
+    parts, at = [], 0
+    while True:
+        while (eol := buf.find(b"\r\n", at)) < 0:
+            buf += _more(sock)
+        size = int(buf[at:eol].partition(b";")[0], 16)
+        if size == 0:  # the last chunk: trailers, if any, then a blank line
+            while buf.find(b"\r\n\r\n", eol) < 0:
+                buf += _more(sock)
+            return b"".join(parts)
+        end = eol + 2 + size
+        while len(buf) < end + 2:
+            buf += _more(sock)
+        parts.append(buf[eol + 2:end])
+        at = end + 2
+
 
 class WebClient:
     def __init__(self, server: str = "", timeout: float = 2.0):
         self.server = server or DEFAULT_SERVER
         self.timeout = timeout
+        self.requests = 0
+        self.connects = 0
+        registry = _metrics.get_registry()
+        self._requests_c = registry.counter("web.requests")
+        self._connects_c = registry.counter("web.connects")
+        scheme, sep, rest = self.server.partition("://")
+        if not sep:
+            scheme, rest = "http", self.server
+        authority, slash, path = rest.partition("/")
+        self._tls = scheme.lower() == "https"
+        if authority.startswith("["):  # an IPv6 literal
+            host, _, port = authority[1:].partition("]")
+            port = port.lstrip(":")
+        else:
+            host, _, port = authority.partition(":")
+        self._host = host
+        self._port = int(port) if port else (443 if self._tls else 80)
+        # everything of a request that does not change between two of them
+        self._target = (slash + path + "/api").encode("ascii")
+        self._headers = (
+            b" HTTP/1.1\r\nHost: " + authority.encode("ascii")
+            + b"\r\nContent-Type: application/json"
+            b"\r\nAccept: application/json\r\n"
+        )
+        self._sock: socket.socket | None = None
+        self._context = None  # https: the TLS context, made at first connect
+        self._lock = threading.Lock()  # one exchange at a time
+
+    def close(self) -> None:
+        """Close the kept connection, if any; the next request connects."""
+        with self._lock:
+            sock, self._sock = self._sock, None
+        if sock is not None:
+            sock.close()
+
+    def _connect(self) -> socket.socket:
+        self.connects += 1
+        self._connects_c.inc()
+        sock = socket.create_connection((self._host, self._port), self.timeout)
+        try:
+            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            if self._tls:
+                if self._context is None:
+                    self._context = ssl.create_default_context()
+                sock = self._context.wrap_socket(
+                    sock, server_hostname=self._host
+                )
+        except BaseException:
+            sock.close()
+            raise
+        return sock
 
     def _request(self, kind: str = "", data: bytes | None = None):
         # --chaos web injection point (streaming/faults.py): a dead or
@@ -32,14 +160,72 @@ class WebClient:
         from ..streaming import faults as _faults
 
         _faults.perturb("web")
-        req = urllib.request.Request(
-            self.server + "/api" + kind,
-            data=data,
-            headers={"content-type": "application/json", "accept": "application/json"},
-            method="POST" if data is not None else "GET",
-        )
-        with urllib.request.urlopen(req, timeout=self.timeout) as resp:
-            return resp.read().decode("utf-8")
+        line = (self._target, kind.encode("ascii"), self._headers)
+        if data is None:
+            message = b"".join((b"GET ", *line, b"\r\n"))
+        else:
+            message = b"".join((
+                b"POST ", *line, b"Content-Length: ",
+                str(len(data)).encode("ascii"), b"\r\n\r\n", data,
+            ))
+        with self._lock:
+            self.requests += 1
+            self._requests_c.inc()
+            if self._sock is not None:
+                try:
+                    return self._exchange(self._sock, message)
+                except _Stale:
+                    pass  # closed while idle: once more, on a fresh one
+            return self._exchange(self._connect(), message)
+
+    def _exchange(self, sock: socket.socket, message: bytes) -> str:
+        """Send ``message`` and read its reply from ``sock``, which is kept
+        for the next request if the reply allows it and closed otherwise.
+        Raises ``_Stale`` if ``sock`` was a KEPT connection and the peer had
+        closed it: nothing of a reply arrived."""
+        kept, self._sock = sock is self._sock, None
+        try:
+            try:
+                sock.sendall(message)
+                buf = sock.recv(_RECV)
+                if not buf:
+                    raise ConnectionError("closed before any reply")
+            except (ConnectionError, ssl.SSLEOFError,
+                    ssl.SSLZeroReturnError) as exc:
+                if kept:
+                    raise _Stale(str(exc)) from exc
+                raise
+            while (cut := buf.find(b"\r\n\r\n")) < 0:
+                buf += _more(sock)
+            head, body = buf[:cut].lower(), buf[cut + 4:]
+            status = int(head[9:12])
+            connection = _header(head, b"connection")
+            keep = (b"close" not in connection if head.startswith(b"http/1.1")
+                    else b"keep-alive" in connection)
+            if b"chunked" in _header(head, b"transfer-encoding"):
+                body = _dechunk(sock, body)
+            elif status in (204, 304):
+                body = b""
+            elif length := _header(head, b"content-length"):
+                need = int(length)
+                while len(body) < need:
+                    body += _more(sock)
+                if len(body) > need:  # more than it announced: out of step
+                    body, keep = body[:need], False
+            else:  # no length: the body runs to the close
+                while more := sock.recv(_RECV):
+                    body += more
+                keep = False
+        except BaseException:
+            sock.close()
+            raise
+        if keep:
+            self._sock = sock
+        else:
+            sock.close()
+        if not 200 <= status < 300:
+            raise WebStatusError(status, body)
+        return body.decode("utf-8")
 
     def _post(self, obj: Config | Stats) -> None:
         self._request(data=encode(obj).encode("utf-8"))
