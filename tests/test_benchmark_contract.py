@@ -9,7 +9,9 @@ The flags of cells added since that file was written are recorded in
 directory) or, since PR 35, in the cell's own test file: the cases of
 ``benchmark/tests/test_hash2e18_ab4.py`` that need no rehearsal run here too
 (its two fault cases drive the harness for a minute each and run by hand;
-``tests/test_tenant_deployment.py`` holds their in-process twin).
+``tests/test_tenant_deployment.py`` holds their in-process twin); so do
+those of ``benchmark/tests/test_hash2e18_lang4.py`` (PR 42; its three fault
+cases' twins are in ``tests/test_tenant_lang_deployment.py``).
 """
 
 import benchmark.tests.conftest as _added_since  # noqa: F401
@@ -27,4 +29,10 @@ from benchmark.tests.test_hash2e18_ab4 import (  # noqa: F401
     test_readers_find_nothing_in_a_program_without_the_plane,
     test_the_cell_is_hash2e18_trimmed_280_with_the_plane_on,
     test_the_cell_reports_the_planes_metrics_and_the_shared_ones,
+)
+from benchmark.tests.test_hash2e18_lang4 import (  # noqa: F401
+    test_program_flags_are_the_recorded_list as test_lang4_program_flags_are_the_recorded_list,
+    test_readers_on_a_span_file_worked_by_hand,
+    test_the_cell_is_the_ab4_cell_with_the_other_key,
+    test_the_cell_reports_ab4s_metrics_and_its_own_three,
 )

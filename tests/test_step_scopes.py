@@ -572,3 +572,36 @@ def test_compiled_tenant_program_is_sized_by_the_rung(topo):
     assert f"f32[{rung},{rung}" in text
     assert f"[{ROWS}," not in text and f",{ROWS}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4_318_823_936 // 3
+
+
+def test_compiled_tenant_program_at_the_top_rung_is_the_whole_batchs(topo):
+    """The mapped program of a LOPSIDED split (one tenant with more than
+    62.5% of a batch, as ``--tenantKey lang`` gives on a mostly-ASCII
+    stream): four parts of the TOP rung, the batch's own 2,048 rows, each
+    with the parent's whole units buffer. Its Gram product is
+    ``[2048, 2048]`` and it reserves ONE tenant's temporaries at a time,
+    4.0 GiB (measured on the chip in PR 42: PERF.md section 6). A shape per
+    tenant (ROADMAP R1 (f)) changes this figure."""
+    from jax.sharding import SingleDeviceSharding
+
+    from twtml_tpu.features.batch import tenant_row_rungs
+    from twtml_tpu.parallel import TenantStackModel
+
+    m, rung = 4, tenant_row_rungs(ROWS, 4)[-1]
+    assert rung == ROWS
+    dev = SingleDeviceSharding(topo.devices[0])
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=dev)
+
+    model = TenantStackModel(m, num_text_features=F_TEXT, l2_reg=0.1,
+                             step_size=0.005, quality=True, tenant_key="lang")
+    wire = RaggedUnitBatch(
+        shape(m, UNITS, dtype=jnp.uint16), shape(m, rung + 1, dtype=jnp.int32),
+        shape(m, rung, 4), shape(m, rung), shape(m, rung), row_len=ROW_LEN)
+    compiled = jax.jit(model._mapped, donate_argnums=0).lower(
+        shape(m, F_TEXT + 4), {k: shape(m) for k in model._hyper}, wire,
+    ).compile()
+    assert f"f32[{ROWS},{ROWS}" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 4 * 2**30 <= temp < 5 * 2**30      # 4,318,823,936 B as compiled

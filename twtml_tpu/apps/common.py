@@ -2543,6 +2543,7 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
         # session stats, checkpoints). M=1 passes through bit-exact.
         import numpy as np
 
+        from ..ops.quality import QUALITY_INDEX
         from ..parallel.tenants import aggregate_tenant_output
         from ..telemetry import tenants as _tenants
 
@@ -2562,10 +2563,19 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
                 bucket = int(
                     batch.mask.shape[0] if preds is None else preds.shape[1]
                 )
+                extra = {}
+                if getattr(out, "quality", None) is not None:
+                    # each part's OWN Gram plane (the ``gram_plane`` instant
+                    # keeps the slowest over the tenants with rows): a
+                    # near-dry part of short rows takes s8 beside bf16 ones
+                    extra["planes"] = np.asarray(out.quality)[
+                        :, QUALITY_INDEX["gram_plane"]
+                    ].astype(int).tolist()
                 tr.instant(
                     "tenant_rows", batch=_trace.current_batch(),
                     rows=counts.tolist(), bucket=bucket,
                     pad_rows=int(counts.size * bucket - counts.sum()),
+                    **extra,
                 )
             tenant_inner(
                 aggregate_tenant_output(out, batch, model), batch, t,

@@ -1107,20 +1107,29 @@ def _splitmix(x: np.ndarray) -> np.ndarray:
     return x ^ (x >> np.uint64(33))
 
 
-def _ragged_row_sums(units: np.ndarray, offsets: np.ndarray):
-    """(per-row unit sums, per-row lengths) of a FLAT ragged buffer — one
-    segmented reduction over the units as they are (no widened copy): the
-    non-empty rows' starts ascend strictly, so ``reduceat`` sums each up to
-    the next one's start, and the last up to the units' end."""
+def _ragged_row_reduce(ufunc, units: np.ndarray, offsets: np.ndarray, dtype):
+    """(per-row ``ufunc`` reduction in ``dtype``, per-row lengths) of a FLAT
+    ragged buffer — one segmented reduction over the units as they are (no
+    widened copy): the non-empty rows' starts ascend strictly, so
+    ``reduceat`` reduces each up to the next one's start, and the last up to
+    the units' end. An empty row reads 0."""
     offs = np.asarray(offsets, np.int64)
     lengths = offs[1:] - offs[:-1]
-    sums = np.zeros(lengths.shape, np.uint64)
+    out = np.zeros(lengths.shape, dtype)
     rows = np.nonzero(lengths)[0]
     if rows.size:
-        sums[rows] = np.add.reduceat(
-            np.asarray(units)[: offs[-1]], offs[rows], dtype=np.uint64
+        out[rows] = ufunc.reduceat(
+            np.asarray(units)[: offs[-1]], offs[rows], dtype=dtype
         )
-    return sums, lengths
+    return out, lengths
+
+
+def _script_class_ids(maxs: np.ndarray, num_tenants: int) -> np.ndarray:
+    """``--tenantKey lang``: the script class of each row's LARGEST code
+    unit (0 for a row under 128, else 1 + the unit's high byte), mod M."""
+    maxs = maxs.astype(np.int32)
+    cls = np.where(maxs < 128, 0, 1 + (maxs >> 8))
+    return (cls % num_tenants).astype(np.int32)
 
 
 def tenant_route_keys(
@@ -1136,44 +1145,45 @@ def tenant_route_keys(
     pure-ASCII rows, else keyed by the max unit's high byte) — the
     per-language/per-script scenario axis; requires a raw-units wire
     (device hashing), because host-hashed tokens carry no script signal.
+    The classes fold mod M, so one script can spread over several tenants
+    (a CJK row's follows its largest unit's high byte, an emoji row's the
+    low surrogate's: PERF.md §7) and one tenant holds several classes.
+    Both keys read the units the wire carries, on their own dtype.
 
     Padding rows get tenant 0 (they are masked out of every tenant batch
     anyway). Keys are heuristic ROUTING, not semantics: each tenant's model
     math on its routed rows stays byte-identical to the reference
     single-model path (PARITY.md)."""
-    m = np.uint64(num_tenants)
+    if mode not in ("hash", "lang"):
+        raise ValueError(f"tenant key mode must be 'hash' or 'lang', got {mode!r}")
+    lang = mode == "lang"
     if isinstance(batch, RaggedUnitBatch):
         if batch.num_shards != 1:
             raise ValueError(
                 "route before shard alignment (tenant batches are "
                 "shard-aligned per tenant afterwards)"
             )
-        sums, lengths = _ragged_row_sums(batch.units, batch.offsets)
-        if mode == "lang":
-            units = np.asarray(batch.units, np.uint64)
-            offs = np.asarray(batch.offsets, np.int64)
-            if units.shape[0] == 0:
-                maxs = np.zeros(lengths.shape, np.uint64)
-            else:
-                safe = np.minimum(offs[:-1], units.shape[0] - 1)
-                maxs = np.maximum.reduceat(units, safe)
-            maxs = np.where(lengths > 0, maxs, np.uint64(0))
-            cls = np.where(
-                maxs < 128, np.uint64(0), np.uint64(1) + (maxs >> np.uint64(8))
+        units = np.asarray(batch.units)
+        if lang:
+            maxs, _ = _ragged_row_reduce(
+                np.maximum, units, batch.offsets, units.dtype
             )
-            return (cls % m).astype(np.int32)
+            return _script_class_ids(maxs, num_tenants)
+        sums, lengths = _ragged_row_reduce(
+            np.add, units, batch.offsets, np.uint64
+        )
     elif isinstance(batch, UnitBatch):
-        units = np.asarray(batch.units, np.uint64)
-        sums = units.sum(axis=1)
-        lengths = np.asarray(batch.length, np.uint64)
-        if mode == "lang":
-            maxs = units.max(axis=1) if units.shape[1] else np.zeros_like(sums)
-            cls = np.where(
-                maxs < 128, np.uint64(0), np.uint64(1) + (maxs >> np.uint64(8))
+        units = np.asarray(batch.units)
+        if lang:
+            maxs = (
+                units.max(axis=1) if units.shape[1]
+                else np.zeros(units.shape[:1], units.dtype)
             )
-            return (cls % m).astype(np.int32)
+            return _script_class_ids(maxs, num_tenants)
+        sums = units.sum(axis=1, dtype=np.uint64)
+        lengths = np.asarray(batch.length, np.uint64)
     elif isinstance(batch, FeatureBatch):
-        if mode == "lang":
+        if lang:
             raise ValueError(
                 "--tenantKey lang needs a raw-units wire (--hashOn device); "
                 "host-hashed tokens carry no script signal"
@@ -1182,13 +1192,11 @@ def tenant_route_keys(
         lengths = (np.asarray(batch.token_val) != 0).sum(axis=1).astype(np.uint64)
     else:
         raise TypeError(f"cannot route a {type(batch).__name__}")
-    if mode != "hash":
-        raise ValueError(f"tenant key mode must be 'hash' or 'lang', got {mode!r}")
     x = (
         sums.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
         + lengths.astype(np.uint64) * np.uint64(0xBF58476D1CE4E5B9)
     )
-    return (_splitmix(x) % m).astype(np.int32)
+    return (_splitmix(x) % np.uint64(num_tenants)).astype(np.int32)
 
 
 def tenant_rows(batch, tenant_ids: np.ndarray, num_tenants: int):

@@ -95,7 +95,10 @@ delivered batch leaves one ``tenant_rows`` instant with the M valid-row
 counts of its ONE stacked fetch (``rows``), the row rung the split padded
 every tenant's part to for that batch (``bucket``, PR 36: read off the
 fetched ``[M, bucket]`` predictions leaf) and ``pad_rows`` = M·``bucket`` −
-their sum (apps/common.attach_pipeline); the mapped device program sits
+their sum, and under ``--modelWatch on`` each part's OWN Gram plane
+(``planes``, PR 42: off the stacked quality leaf of the same fetch; a
+near-dry part of short rows takes s8 beside bf16 ones)
+(apps/common.attach_pipeline); the mapped device program sits
 under the ``tenant_map`` scope. None of the three exists on the single-model plane.
 
 Event sink (r8): the crash flight recorder (telemetry/blackbox.py) attaches
