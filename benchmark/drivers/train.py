@@ -106,7 +106,8 @@ def prepare_program(cell: dict, feeder, sink, rehearse: bool,
 
     mark("harness imported, children started")
     os.environ["TWTML_NOW_MS"] = str(cell["traffic"]["generator"]["now_ms"])
-    say(f"compile cache: {harness.place_compile_cache()}")
+    say(f"compile cache: {harness.place_compile_cache()}; runtime: "
+        f"{harness.place_runtime_env()}")
     ident = harness.require_device(cell["workload"]["chips"], rehearse)
     mark("jax imported, devices found")
     harness.CompileCounter.install()

@@ -146,10 +146,22 @@ def fresh_dir(*parts: str) -> str:
     return path
 
 
+def place_runtime_env() -> dict:
+    """The TPU runtime's settings every run starts under
+    (``runtime_env.json`` says what each is for and what it was measured
+    at), unless the machine placed them. The runtime reads them once, as the
+    first device is asked for."""
+    table = manifest.load_json(os.path.join(manifest.HERE, "runtime_env.json"))
+    return {k: os.environ.setdefault(k, v) for k, v in table["env"].items()}
+
+
 def place_compile_cache() -> str:
     """The persistent compile cache lives at one fixed path inside the
     checkout unless the machine placed it; the program takes the variable
-    (``utils/backend.configure_compile_cache``) and sets nothing itself."""
+    (``utils/backend.configure_compile_cache``) and sets nothing itself.
+    Both drivers call this before anything imports jax, so the runtime's
+    settings are placed here too."""
+    place_runtime_env()
     return os.environ.setdefault(
         "JAX_COMPILATION_CACHE_DIR", os.path.join(ROOT, ".jax_cache")
     )

@@ -26,12 +26,14 @@ CELLS = [w["name"] for w in manifest.load()["workloads"]]
 
 # every class whose ``step`` a training cell's timed path runs: the
 # single-device model (and its subclasses: the logistic learner's step is
-# this one) and the mesh model (``test_hash2e20.py``'s BREAK_MESH is this
-# patch for that class alone)
+# this one), the mesh model (``test_hash2e20.py``'s BREAK_MESH is this
+# patch for that class alone) and, since PR 46, the tenant plane's stack
+# (``test_hash2e18_ab4.py``'s BREAK_TENANT_STEP is this patch for that class
+# alone): the two tenant cells enter neither of the other two
 BREAK_TRAIN = """
 import jax
 from twtml_tpu.models import sgd
-from twtml_tpu.parallel import sharding
+from twtml_tpu.parallel import sharding, tenants
 def broken(cls):
     _step = cls.step
     def step(self, batch):
@@ -42,6 +44,7 @@ def broken(cls):
     cls.step = step
 broken(sgd.StreamingSGDModel)
 broken(sharding.ParallelSGDModel)
+broken(tenants.TenantStackModel)
 """
 
 

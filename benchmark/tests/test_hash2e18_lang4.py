@@ -1,6 +1,6 @@
 """The cell ``hash2e18-lang4-trimmed-280`` (PR 42: four SCRIPT-routed
 learners on one stream, ``--tenants 4 --tenantKey lang``): the flags it
-hands the program (recorded here for the next ``benchmark`` PR to move into
+hands the program (recorded here and, since PR 46, in
 ``test_contract.FLAGS``), what its files share with
 ``hash2e18-ab4-trimmed-280``'s, its readers on a span file worked by hand,
 and the faults its comparison is there for, shown as ``test_hash2e18_ab4.py``
@@ -15,13 +15,12 @@ In all three every batch still counts 2,048 rows, so ``count_diff`` stays 0
 and ``weights_dev`` over the whole ``[M, F+4]`` array turns ``correct``
 false. Each run is ``run.py``'s own path at rehearsal sizes with the fault
 patched in underneath; unbroken it is ``test_correct.py``'s case of this
-cell (whose ``BREAK_TRAIN`` case fails for this cell as for ``ab4``, by
-construction: PERF.md section 7 row 15).
+cell (whose ``BREAK_TRAIN`` breaks the plane's class too since PR 46:
+PERF.md section 7 row 15).
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests/test_hash2e18_lang4.py -q
 """
 
-import json
 import os
 
 import pytest
@@ -40,6 +39,7 @@ CONTROL = "hash2e18-ab4-trimmed-280"
 FLAGS = test_contract.SHARED + test_contract.HASH2E18 + [
     "--tenants", "4", "--tenantKey", "lang"]
 ADDED = ["tenant_need_share", "tenant_fullest_share", "tenant_s8_part_share"]
+PLANE = ["tenant_split_ms_per_batch", "tenant_pad_share"]   # PR 35's two
 
 ROUTE_BY_THE_HASH_KEY = """
 from twtml_tpu.parallel import tenants
@@ -94,23 +94,27 @@ def test_the_cell_is_the_ab4_cell_with_the_other_key():
 
 
 def test_the_cell_reports_ab4s_metrics_and_its_own_three():
-    """The three metrics this PR brings are listed on THIS cell alone:
-    ``test_hash2e18_ab4.py`` pins ``ab4``'s set, and a PR that is not a
-    ``benchmark`` PR edits no file here (PERF.md section 7 row 20: the next
-    one lists them on both; the readers read what ``ab4``'s program
-    emits)."""
+    """Re-stated in PR 46, when the control left the rate's list and with it
+    every per-layer metric that moves the rate: this cell reports what the
+    single-model cell on the same mix reports (``hash2e18-trimmed-280``),
+    the PLANE's two (which ``ab4`` reported until then), and its own three;
+    every metric ``ab4`` still reports is among them. The three are listed
+    on THIS cell alone (PERF.md section 7 row 20)."""
     cell = manifest.cell(manifest.load(), CELL)
-    base = manifest.cell(manifest.load(), CONTROL)
+    single = manifest.cell(manifest.load(), "hash2e18-trimmed-280")
+    control = manifest.cell(manifest.load(), CONTROL)
     mine = [m["name"] for m in cell["per_layer"]]
-    assert [m for m in mine if m not in ADDED] == [
-        m["name"] for m in base["per_layer"]]
-    assert sorted(set(mine) - {m["name"] for m in base["per_layer"]}) == (
-        sorted(ADDED))
+    shared = [m["name"] for m in single["per_layer"]]
+    assert [m for m in mine if m not in ADDED + PLANE] == shared
+    assert sorted(set(mine) - set(shared)) == sorted(ADDED + PLANE)
+    assert {m["name"] for m in control["per_layer"]} < set(mine)
+    assert not {m["name"] for m in control["per_layer"]} & set(ADDED + PLANE)
     assert [m["name"] for m in cell["end_to_end"]] == [
-        m["name"] for m in base["end_to_end"]]
+        m["name"] for m in single["end_to_end"]]
     for m in cell["per_layer"]:
         if m["name"] in ADDED:
             assert m["workloads"] == [CELL]
+        if m["name"] in ADDED + PLANE:
             assert m["moves"] == "ingest_tweets_per_s"
 
 
@@ -126,8 +130,7 @@ def test_readers_on_a_span_file_worked_by_hand(tmp_path, monkeypatch):
     path = tmp_path / "spans.json"
 
     def write(events):
-        path.write_text("[\n" + "".join(
-            json.dumps(e) + ",\n" for e in events), encoding="utf-8")
+        test_contract.write_spans(path, events)
 
     monkeypatch.setattr(trace_files, "span_file", lambda: str(path))
     write([{"name": "gram_plane", "ph": "i", "args": {"plane": 1}}])

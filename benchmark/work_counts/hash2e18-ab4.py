@@ -1,5 +1,6 @@
 """Work one train step of the TENANT plane NEEDS (``hash2e18-ab4``: M
-hash-routed learners on one batch of B rows).
+hash-routed learners on one batch of B rows; ``hash2e18-lang4`` takes it by
+name).
 
 Each tenant needs the Gram of ITS OWN rows, 2·n_m²·F with Σ n_m = B. By
 convexity Σ n_m² ≥ B²/M, reached by the even split that hash routing gives
@@ -11,12 +12,15 @@ owns the row (3·B·F), plus the tenant wire as it was sent and the M Gram
 matrices of (B/M)² f32. The dual loops and write-backs are left out (a lower
 bound).
 
-The program SPENDS M·2·B²·F: ``split_batch_tenants`` pads every tenant's
-batch to the full B rows and the Gram step's cost does not depend on its
-mask, so ``step_roofline`` reads M² times under the single-model cells' (a
-few percent) until a row bucket per tenant closes the gap (ROADMAP S11).
-The share cannot pass 100%: no schedule computes M Grams of Σ n_m = B rows
-in fewer operations than the even split's.
+The program SPENDS M·2·R²·F, R the row rung ``split_batch_tenants`` pads
+every part to (``features/batch.tenant_row_rungs``, PR 36; the Gram step's
+cost does not depend on its mask): under the hash key's even split R = 640
+at B = 2,048 and M = 4, 0.39 of the single model's operations, and
+``step_roofline`` reads ~16.5%, bytes-bound; under a lopsided key
+(``hash2e18-lang4``) every part takes R = B, the program spends M·2·B²·F as
+it did everywhere until PR 36, and the share reads ~3%. The share cannot
+pass 100%: no schedule computes M Grams of Σ n_m = B rows in fewer
+operations than the even split's.
 """
 
 
