@@ -11,7 +11,9 @@ directory) or, since PR 35, in the cell's own test file: the cases of
 (its two fault cases drive the harness for a minute each and run by hand;
 ``tests/test_tenant_deployment.py`` holds their in-process twin); so do
 those of ``benchmark/tests/test_hash2e18_lang4.py`` (PR 42; its three fault
-cases' twins are in ``tests/test_tenant_lang_deployment.py``).
+cases' twins are in ``tests/test_tenant_lang_deployment.py``) and of
+``benchmark/tests/test_hash2e18_grid4.py`` (PR 47; its four fault cases'
+twins are in ``tests/test_tenant_grid.py``).
 """
 
 import benchmark.tests.conftest as _added_since  # noqa: F401
@@ -35,4 +37,10 @@ from benchmark.tests.test_hash2e18_lang4 import (  # noqa: F401
     test_readers_on_a_span_file_worked_by_hand,
     test_the_cell_is_the_ab4_cell_with_the_other_key,
     test_the_cell_reports_ab4s_metrics_and_its_own_three,
+)
+from benchmark.tests.test_hash2e18_grid4 import (  # noqa: F401
+    test_program_flags_are_the_recorded_list as test_grid4_program_flags_are_the_recorded_list,
+    test_readers_on_a_trace_made_by_hand,
+    test_the_cell_is_hash2e18_trimmed_280_with_four_recipes_on_its_rows,
+    test_the_cell_reports_the_single_models_metrics_and_its_own_three,
 )

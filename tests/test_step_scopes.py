@@ -605,3 +605,55 @@ def test_compiled_tenant_program_at_the_top_rung_is_the_whole_batchs(topo):
     assert f"f32[{ROWS},{ROWS}" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert 4 * 2**30 <= temp < 5 * 2**30      # 4,318,823,936 B as compiled
+
+
+# ---------------------------------------------------------------------------
+# PR 47: ``--tenantKey all`` — M arms on the SAME rows share the count matrix
+# and G; only ``u = C·w_m``, the dual loop and ``Cᵀα_m`` are mapped.
+
+def test_compiled_arms_program_builds_c_and_g_once_and_maps_the_rest(topo):
+    """The program the TPU's compiler makes for the cell
+    ``hash2e18-grid4-trimmed-280`` (four arms, 2,048 rows, the cells' wire):
+    in every plane's branch ONE count matrix of the plane's type and ONE
+    ``[2048, 2048]`` Gram product, both outside the map's ``while``; inside
+    it no array of C's size is written (an arm READS C twice and copies it
+    never); and the whole of it reserves the single model's temporaries
+    (4,308,146,176 B as compiled) and not M times them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from twtml_tpu.parallel import TenantStackModel
+
+    m = 4
+    dev = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype, *_spec):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=dev)
+
+    model = TenantStackModel(
+        m, num_text_features=F_TEXT, tenant_key="all",
+        step_sizes=[0.005, 0.005, 0.0025, 0.0025],
+        l2_regs=[0.1, 0.01, 0.1, 0.01], quality=True)
+    compiled = jax.jit(model._shared, donate_argnums=0).lower(
+        shape((m, F_TEXT + 4), jnp.float32),
+        {k: shape((m,), jnp.float32) for k in model._hyper},
+        _ragged_shapes(shape),
+    ).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 4 * 2**30 <= temp < 4.1 * 2**30
+    full = ROWS * F_TEXT
+    for plane, took in zip(("f32", "bf16", "s8"),
+                           plane_branches(compiled.as_text())):
+        mapped = [line for line in took["all"] if "/arm_map/" in line]
+        once = [line for line in took["all"] if "/arm_map/" not in line]
+        grams = [line for line in once if "/gram_matmul/" in line
+                 and re.search(r" convolution\(", line)
+                 and f"f32[{ROWS},{ROWS}]" in line]
+        assert grams, plane
+        assert not any("/gram_matmul/" in line or "/gram_count/" in line
+                       for line in mapped), plane
+        assert any(f"/{scope}/" in line for line in mapped
+                   for scope in ("predict", "dual_loop", "writeback")), plane
+        big = [(op, d, n) for op, d, n in _results(
+            [line for line in took["top"] if "/arm_map/" in line])
+            if n >= full and op not in _ALIASES and op != "while"]
+        assert not big, (plane, big)

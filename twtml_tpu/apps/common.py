@@ -651,7 +651,19 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
                 "--hashOn device"
             )
         from ..parallel.tenants import TenantStackModel
+        from ..telemetry import tenants as _tenant_view
 
+        # the Tenants frame and /api/tenants name each tenant's recipe
+        _tenant_view.configure(*conf.tenant_recipes())
+        # champion and challengers on the SAME rows (--tenantKey all): one
+        # host, one device (parallel/tenants.py has each reason)
+        shared_rows = getattr(conf, "tenantKey", "hash") == "all"
+        if shared_rows and _jax.process_count() > 1:
+            raise SystemExit(
+                "--tenantKey all is single-host: the tenant fleet assembles "
+                "a stacked [M, ...] tenant wire across hosts, and under "
+                "'all' there is no tenant wire"
+            )
         if _jax.process_count() > 1:
             # app-level tenant fleet (r16, PR 7 REMAINING b; ragged wire
             # lifted in r20): the tenant stack behind per-host sharded
@@ -687,16 +699,26 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
             )
             return model, max(1, inner.num_data // _jax.process_count())
         mesh = build_mesh(conf, what=f"tenant plane ({model_cls.__name__})")
-        model = TenantStackModel.from_conf(
-            conf, mesh,
-            residual_fn=model_cls.residual_fn,
-            prediction_fn=model_cls.prediction_fn,
-            round_predictions=model_cls.round_predictions,
-        )
+        if shared_rows and mesh is not None:
+            raise SystemExit(
+                "--tenantKey all runs on one device (the arms map runs "
+                "inside one device's Gram branch): use --master local[1]"
+            )
+        try:
+            model = TenantStackModel.from_conf(
+                conf, mesh,
+                residual_fn=model_cls.residual_fn,
+                prediction_fn=model_cls.prediction_fn,
+                round_predictions=model_cls.round_predictions,
+            )
+        except ValueError as exc:  # what the plane refuses, in its words
+            raise SystemExit(str(exc)) from None
         log.info(
             "multi-tenant model plane: %d tenants, key=%s, wire=%s",
             tenants, model.tenant_key, model.wire_pack,
         )
+        log.info("tenant recipes: stepSize %s, l2Reg %s",
+                 *conf.tenant_recipes())
         return model, (mesh.shape[mesh.axis_names[0]] if mesh else 1)
     mesh = build_mesh(
         conf, what=f"training ({model_cls.__name__})", model_axis=True
@@ -766,6 +788,24 @@ def state_checksum(state) -> str:
     return f"{crc:08x}"
 
 
+def tenant_stamp(conf) -> "dict | None":
+    """What a tenant-stack checkpoint's rows ARE: the routing key and each
+    tenant's recipe, in tenant order (``meta["tenants"]``). A ``[M, F+4]``
+    array alone does not say whether row 2 is a hash bucket's model or the
+    challenger under half the step size; ``AppCheckpoint`` refuses to
+    resume a stack under another stamp and ``apps/serve --abtest on``
+    reports it. None on the single-model plane."""
+    m = int(getattr(conf, "tenants", 1) or 1)
+    recipes = getattr(conf, "tenant_recipes", None)
+    if m < 2 or recipes is None:
+        return None
+    steps, l2s = recipes()
+    return {
+        "count": m, "key": getattr(conf, "tenantKey", "hash"),
+        "stepSize": steps, "l2Reg": l2s,
+    }
+
+
 class AppCheckpoint:
     """``--checkpointDir``/``--checkpointEvery`` wiring shared by every entry
     point (model checkpoint/resume is this framework's upgrade over the
@@ -808,6 +848,7 @@ class AppCheckpoint:
         self._shadow = self._elastic and not self._lead
         self.every = int(getattr(conf, "checkpointEvery", 0) or 0)
         self.restored_meta = None
+        self._tenants = tenant_stamp(conf)
         if not conf.checkpointDir:
             self._last = 0
             return
@@ -834,6 +875,16 @@ class AppCheckpoint:
         self.restored_meta = restored[1] if restored is not None else None
         if restored is not None:
             state, meta = restored
+            was = meta.get("tenants")
+            if self._tenants and was and was != self._tenants:
+                raise SystemExit(
+                    f"checkpoint step {meta.get('step')} in {ckpt_dir!r} "
+                    f"holds the tenant stack of {was}; this run asks for "
+                    f"{self._tenants}. A row of the stack is ONE tenant's "
+                    "model under ONE recipe: resume with the key and the "
+                    "per-tenant lists it was trained under, or start a new "
+                    "--checkpointDir"
+                )
             set_state(state)
             totals["count"] = int(meta.get("count", 0))
             totals["batches"] = int(meta.get("batches", 0))
@@ -915,6 +966,8 @@ class AppCheckpoint:
         jstamp = _journal.snapshot_for_checkpoint()
         if jstamp is not None:
             meta["journal"] = jstamp
+        if self._tenants is not None:
+            meta["tenants"] = self._tenants
         self._ckpt.save(totals["batches"], self._get_state(), meta)
         self._last = totals["batches"]
         if jstamp is not None:
@@ -2549,9 +2602,16 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
 
         tenant_inner = handle
 
+        tenant_key = getattr(model, "tenant_key", "hash")
+        shared_batches = _metrics.get_registry().counter(
+            "tenants.shared_batches"
+        )
+
         def handle(out, batch, t, at_boundary=True):  # noqa: F811
             counts = np.asarray(out.count, np.int64)
             _tenants.record_tick(counts, np.asarray(out.mse, np.float64))
+            if tenant_key == "all":
+                shared_batches.inc()  # ONE batch, one C and one G, M arms
             tr = _trace.get()
             if tr.enabled:
                 # once per delivered batch, from what the ONE fetch brought:
@@ -2571,9 +2631,11 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
                     extra["planes"] = np.asarray(out.quality)[
                         :, QUALITY_INDEX["gram_plane"]
                     ].astype(int).tolist()
+                # under ``all`` every arm saw the whole batch: rows is
+                # [B]·M, bucket B, no padding
                 tr.instant(
                     "tenant_rows", batch=_trace.current_batch(),
-                    rows=counts.tolist(), bucket=bucket,
+                    key=tenant_key, rows=counts.tolist(), bucket=bucket,
                     pad_rows=int(counts.size * bucket - counts.sum()),
                     **extra,
                 )

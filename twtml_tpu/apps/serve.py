@@ -70,6 +70,15 @@ def run(conf: ConfArguments, started=None, stop_event=None,
         snapshot.step, snapshot.num_tenants, reason,
     )
     engine = None
+    stamp = snapshot.meta.get("tenants") or {}
+    if stamp.get("key") == "all" and getattr(conf, "abtest", "off") != "on":
+        raise SystemExit(
+            "this checkpoint is a --tenantKey all stack: "
+            f"{snapshot.num_tenants} recipes of one learner trained on the "
+            "SAME rows, so no row belongs to one of them. Serve it with "
+            "--abtest on (the champion answers, the challengers score the "
+            "same rows)"
+        )
     if getattr(conf, "abtest", "off") == "on":
         # champion/challenger (ISSUE 11): the tenant-stack snapshot's
         # variants ride ONE mirrored predict program — the champion
@@ -88,9 +97,18 @@ def run(conf: ConfArguments, started=None, stop_event=None,
         engine = ChampionEngine(
             num_text_features=conf.numTextFeatures,
             num_tenants=snapshot.num_tenants,
-            tenant_key=getattr(conf, "tenantKey", "hash"),
+            # the mirrored wire never routes: 'all' (a training key, which
+            # ships no tenant wire) has nothing to say here
+            tenant_key="hash" if conf.tenantKey == "all" else conf.tenantKey,
             dtype=jnp.dtype(getattr(conf, "dtype", "float32")),
         )
+        for m, (step, l2) in enumerate(zip(
+            stamp.get("stepSize") or (), stamp.get("l2Reg") or ()
+        )):
+            log.info(
+                "tenant %d: stepSize %s, l2Reg %s (key %s)%s", m, step, l2,
+                stamp.get("key"), " — the champion" if m == 0 else "",
+            )
     plane = ServingPlane.from_conf(conf, snapshot, engine=engine)
     log.info("pre-compiling the predict program...")
     plane.warmup()

@@ -21,6 +21,10 @@ import os
 import sys
 from importlib import resources as _importlib_resources
 
+# --tenantKey: two keys PARTITION a batch over the tenants, ``all`` hands
+# every tenant every row (features/batch.tenant_route_keys has the rules)
+TENANT_KEYS = ("hash", "lang", "all")
+
 # Process-wide property table, the moral equivalent of JVM system properties
 # (reference routes OAuth creds there, ConfArguments.scala:58-76).
 _SYSTEM_PROPERTIES: dict[str, str] = {}
@@ -241,10 +245,15 @@ class ConfArguments:
         if self.tenants < 1:
             raise ValueError(f"tenants must be >= 1, got {self.tenants}")
         self.tenantKey: str = conf.get("tenantKey", "hash")
-        if self.tenantKey not in ("hash", "lang"):
+        if self.tenantKey not in TENANT_KEYS:
             raise ValueError(
-                f"tenantKey must be 'hash' or 'lang', got {self.tenantKey!r}"
+                f"tenantKey must be one of {TENANT_KEYS}, got "
+                f"{self.tenantKey!r}"
             )
+        # per-tenant recipes (comma lists of length --tenants; "" = every
+        # tenant takes --stepSize / --l2Reg)
+        self.tenantStepSize: str = conf.get("tenantStepSize", "")
+        self.tenantL2Reg: str = conf.get("tenantL2Reg", "")
         # ingest/state robustness layer (r7)
         self.maxQueueRows: int = int(conf.get("maxQueueRows", "0"))
         self.shedPolicy: str = conf.get("shedPolicy", "block")
@@ -526,11 +535,25 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                one up to M*B (PERF.md section 6, PR 36).
                                                Per-tenant semantics stay byte-identical to
                                                the single-model path. Default: {self.tenants}
-  --tenantKey <hash|lang>                      Tenant routing key: 'hash' = deterministic
+  --tenantKey <hash|lang|all>                  Tenant routing key: 'hash' = deterministic
                                                content hash (A/B-arm style uniform split);
                                                'lang' = script-class heuristic from the
                                                text's code units (per-language scenarios;
-                                               needs --hashOn device). Default: {self.tenantKey}
+                                               needs --hashOn device); 'all' = no routing:
+                                               EVERY tenant trains on EVERY row (a champion,
+                                               tenant 0, and its challengers: the same learner
+                                               under --tenantStepSize / --tenantL2Reg). The
+                                               batch ships ONCE as the single-model wire, the
+                                               count matrix and the Gram matrix are built ONCE
+                                               a batch and only u = C.w, the dual loop and
+                                               C^T.alpha run per tenant; the batch's printed
+                                               line is tenant 0's. One device, --wirePack
+                                               stacked. Default: {self.tenantKey}
+  --tenantStepSize <float,float,...>           Per-tenant step sizes, --tenants values in
+                                               tenant order (tenant 0 first). Default: every
+                                               tenant takes --stepSize
+  --tenantL2Reg <float,float,...>              Per-tenant L2 strengths, --tenants values in
+                                               tenant order. Default: every tenant takes --l2Reg
   --maxQueueRows <int rows>                    Bounded intake backpressure: cap the source→
                                                batcher queue at this many ROWS. 0 = auto
                                                (8 x --batchBucket when pinned, else unbounded);
@@ -713,6 +736,10 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
     def parse(self, args: list[str]) -> "ConfArguments":
         """Recursive flag parser, same shape as ConfArguments.scala:91-158."""
         if not args:
+            try:
+                self.tenant_recipes()  # the lists against --tenants, at once
+            except ValueError as exc:
+                raise SystemExit(str(exc))
             return self
         flag, rest = args[0], args[1:]
 
@@ -869,8 +896,12 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                 self.printUsage(1)
         elif flag == "--tenantKey":
             self.tenantKey = take()
-            if self.tenantKey not in ("hash", "lang"):
+            if self.tenantKey not in TENANT_KEYS:
                 self.printUsage(1)
+        elif flag == "--tenantStepSize":
+            self.tenantStepSize = take()
+        elif flag == "--tenantL2Reg":
+            self.tenantL2Reg = take()
         elif flag == "--maxQueueRows":
             self.maxQueueRows = int(take())
         elif flag == "--shedPolicy":
@@ -981,6 +1012,32 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
         if self.blockWire != "auto":
             return self.blockWire == "on"
         return self.effective_wire() == "ragged"
+
+    def tenant_recipes(self) -> "tuple[list[float], list[float]]":
+        """(step sizes, L2 strengths), one of each per tenant in tenant
+        order: ``--tenantStepSize`` / ``--tenantL2Reg`` where given (exactly
+        ``--tenants`` numbers, or refused), else ``--stepSize`` /
+        ``--l2Reg`` for every tenant."""
+        def one(flag: str, text: str, default: float) -> "list[float]":
+            if not text.strip():
+                return [float(default)] * self.tenants
+            try:
+                values = [float(v) for v in text.split(",")]
+            except ValueError:
+                raise ValueError(
+                    f"{flag} takes comma-separated numbers, got {text!r}"
+                ) from None
+            if len(values) != self.tenants:
+                raise ValueError(
+                    f"{flag} names {len(values)} tenant(s), --tenants is "
+                    f"{self.tenants}: give one value a tenant, tenant 0 first"
+                )
+            return values
+
+        return (
+            one("--tenantStepSize", self.tenantStepSize, self.stepSize),
+            one("--tenantL2Reg", self.tenantL2Reg, self.l2Reg),
+        )
 
     def effective_wire_pack(self) -> str:
         """Resolve ``--wirePack auto`` to the default tenant-stack

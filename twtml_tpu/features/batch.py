@@ -1149,6 +1149,8 @@ def tenant_route_keys(
     (a CJK row's follows its largest unit's high byte, an emoji row's the
     low surrogate's: PERF.md §7) and one tenant holds several classes.
     Both keys read the units the wire carries, on their own dtype.
+    (``--tenantKey all`` is not a routing key: every tenant takes every
+    row, nothing is split, and ``parallel/tenants.py`` never asks here.)
 
     Padding rows get tenant 0 (they are masked out of every tenant batch
     anyway). Keys are heuristic ROUTING, not semantics: each tenant's model

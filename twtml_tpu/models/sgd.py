@@ -267,6 +267,7 @@ def make_sgd_train_step(
     use_gram: bool | None = None,
     gram_int8: bool | None = None,
     quality: bool = False,
+    arms: bool = False,
 ):
     """Build the fused (weights, batch) → (new_weights, StepOutput) step.
 
@@ -321,7 +322,27 @@ def make_sgd_train_step(
     or off, and ``False`` (the default / ``--modelWatch off``) leaves the
     output pytree — hence the compiled program — structurally the
     pre-quality program (the leaf is None).
+
+    ``arms`` (``--tenantKey all``: a champion and its challengers on the
+    SAME rows) builds the step of M models that share their batch:
+    ``weights`` is ``[M, F+4]``, ``step_size`` and ``l2_reg`` are ``[M]``
+    (arm m's own recipe), and every leaf of the output leads with M. What
+    does not depend on the model runs ONCE — unpack, re-pad, hash and, in
+    the Gram basis, the count matrix C and G = C·Cᵀ, which are the step's
+    cost — and the rest is ``lax.map``ped over the arms under the scope
+    ``arm_map``, inside the plane's branch: ``u = C·w_m``, the dual loop
+    under arm m's step size and L2, ``Cᵀα_m``, then the stats. Each arm
+    runs the single model's own contractions on the single model's own C,
+    so arm m is bit-identical to this step built without ``arms`` under
+    arm m's recipe (tests/test_tenant_grid.py). Outside the Gram basis the
+    featurized batch is shared and the whole loop is mapped. One device
+    only: there is no ``axis_name`` form.
     """
+    if arms and axis_name:
+        raise ValueError(
+            "arms on the same rows run on one device: the mapped half has "
+            "no data-axis form (parallel/tenants.py refuses the mesh)"
+        )
     f_text = num_text_features
     sparse = f_text > DENSE_TEXT_FEATURE_LIMIT if use_sparse is None else use_sparse
     residual_fn = residual_fn or (lambda raw, label: raw - label)
@@ -357,64 +378,95 @@ def make_sgd_train_step(
         contraction 1/shards and gives the replicated-weights output the
         statically-invariant form shard_map requires).
 
+        With ``arms`` C and G are built ONCE and the rest — ``u = C·w_m``,
+        the dual loop under arm m's own step size and L2, ``Cᵀα_m`` — is
+        mapped over the ``[M, F+4]`` weights under the scope ``arm_map``
+        (``lax.map``: each arm runs the single model's own contractions).
+
         ``row_args`` are GLOBAL (the caller all-gathers the batch under a
         data axis); ``local_numeric`` is this shard's rows. Returns
         (new weights, this shard's rows of ``u``, plane index)."""
         token_idx, token_val, numeric, mask, labels = row_args
         dtype = weights.dtype
-        w_text, w_num = weights[:f_text], weights[f_text:]
         rows = local_numeric.shape[0] if axis_name else 0
 
+        def split(w):
+            return w[:f_text], w[f_text:]
+
+        whole = None if arms else split(weights)  # an arm splits its own
+
         def dual_basis(counts):
-            with jax.named_scope("predict"):
-                # this shard's rows of u = Z·W_prev: C·w rides the count
-                # build's epilogue (ops/gram.CountPlane.dot)
-                raw = (counts.dot(w_text) + local_numeric @ w_num).astype(dtype)
-                u = raw
+            def margin(w_text, w_num):
+                with jax.named_scope("predict"):
+                    # this shard's rows of u = Z·W_prev: C·w rides the count
+                    # build's epilogue (ops/gram.CountPlane.dot)
+                    raw = (counts.dot(w_text) + local_numeric @ w_num).astype(dtype)
+                    u = raw
+                    if axis_name:
+                        u = lax.all_gather(raw, axis_name, axis=0, tiled=True)
+                return raw, u
+
+            def gram():
+                g_text = counts.gram()
                 if axis_name:
-                    u = lax.all_gather(raw, axis_name, axis=0, tiled=True)
-            g_text = counts.gram()
-            if axis_name:
-                # [B_local, B_global] panel: the G matmul's FLOPs scale
-                # 1/shards (the count build replicates per shard — see
-                # text_gram.left)
-                g_text = lax.all_gather(g_text, axis_name, axis=0, tiled=True)
-            with jax.named_scope("dual_loop"):
-                p_prev = jnp.sum(weights * weights)  # its convergence norm
-            # u and G are built in f32 (the accumulation type); the dual
-            # loop runs in the weights dtype so the fori_loop carry stays
-            # type-stable for low-precision weights. f64 weights never
-            # reach here (the auto gate is f32-only — the bf16-plane G
-            # build would silently downgrade f64).
-            dual = run_dual_loop(
-                u=u,
-                g=add_numeric_block(g_text, numeric, dtype),
-                labels=labels,
-                mask=mask,
-                dtype=dtype,
-                residual_fn=residual_fn,
-                num_iterations=num_iterations,
-                step_size=step_size,
-                mini_batch_fraction=mini_batch_fraction,
-                l2_reg=l2_reg,
-                convergence_tol=convergence_tol,
-                p_prev=p_prev,
-                vary_axis=axis_name,
-            )
-            with jax.named_scope("writeback"):
-                # W_new = c·W_prev + Zᵀα: the other read of C
-                c, alpha = dual["c"], dual["alpha"]
-                if axis_name:  # this shard's rows of α, then one psum each
-                    c, alpha = dual_scale_and_alpha(dual, axis_name, rows)
-                delta_text = counts.tdot(alpha)
-                delta_num = local_numeric.T @ alpha
-                if axis_name:
-                    delta_text = lax.psum(delta_text, axis_name)
-                    delta_num = lax.psum(delta_num, axis_name)
-                w_new = jnp.concatenate(
-                    [w_text * c + delta_text, w_num * c + delta_num]
-                ).astype(dtype)
-            return w_new, raw
+                    # [B_local, B_global] panel: the G matmul's FLOPs scale
+                    # 1/shards (the count build replicates per shard — see
+                    # text_gram.left)
+                    g_text = lax.all_gather(
+                        g_text, axis_name, axis=0, tiled=True
+                    )
+                return add_numeric_block(g_text, numeric, dtype)
+
+            def apply(w, w_text, w_num, u, g, eta, lam):
+                with jax.named_scope("dual_loop"):
+                    p_prev = jnp.sum(w * w)  # its convergence norm
+                # u and G are built in f32 (the accumulation type); the dual
+                # loop runs in the weights dtype so the fori_loop carry stays
+                # type-stable for low-precision weights. f64 weights never
+                # reach here (the auto gate is f32-only — the bf16-plane G
+                # build would silently downgrade f64).
+                dual = run_dual_loop(
+                    u=u,
+                    g=g,
+                    labels=labels,
+                    mask=mask,
+                    dtype=dtype,
+                    residual_fn=residual_fn,
+                    num_iterations=num_iterations,
+                    step_size=eta,
+                    mini_batch_fraction=mini_batch_fraction,
+                    l2_reg=lam,
+                    convergence_tol=convergence_tol,
+                    p_prev=p_prev,
+                    vary_axis=axis_name,
+                )
+                with jax.named_scope("writeback"):
+                    # W_new = c·W_prev + Zᵀα: the other read of C
+                    c, alpha = dual["c"], dual["alpha"]
+                    if axis_name:  # this shard's rows of α, then one psum each
+                        c, alpha = dual_scale_and_alpha(dual, axis_name, rows)
+                    delta_text = counts.tdot(alpha)
+                    delta_num = local_numeric.T @ alpha
+                    if axis_name:
+                        delta_text = lax.psum(delta_text, axis_name)
+                        delta_num = lax.psum(delta_num, axis_name)
+                    return jnp.concatenate(
+                        [w_text * c + delta_text, w_num * c + delta_num]
+                    ).astype(dtype)
+
+            if arms:
+                g = gram()  # ONE count matrix and ONE G for all M arms
+
+                def arm(args):
+                    w, eta, lam = args
+                    parts = split(w)
+                    raw, u = margin(*parts)
+                    return apply(w, *parts, u, g, eta, lam), raw
+
+                with jax.named_scope("arm_map"):
+                    return lax.map(arm, (weights, step_size, l2_reg))
+            raw, u = margin(*whole)
+            return apply(weights, *whole, u, gram(), step_size, l2_reg), raw
 
         (w_new, raw), plane = text_gram(
             token_idx,
@@ -487,6 +539,7 @@ def make_sgd_train_step(
         )
 
         # ---- predict + stats with pre-update weights --------------------
+        w_new = raw = plane = None
         if gram:
             # the Gram basis: the count matrix is built FIRST and the raw
             # margin u = Z·W_prev, G, the dual loop and the write-back all
@@ -502,58 +555,72 @@ def make_sgd_train_step(
                     for a in row_args
                 )
             w_new, raw, plane = _gram_sgd(weights, row_args, numeric)
-        with jax.named_scope("predict"):
-            if not gram:
-                raw = _predict_raw(weights, batch, x_dense)
-            preds = prediction_fn(raw)
-            if round_predictions:
-                preds = jnp_round_half_up(preds)
-            stats = batch_stats(labels, preds, mask, axis_name)
 
-        def _quality(w_new, gram_plane=None):
-            # the ISSUE-8 side channel against the post-update weights;
-            # None (plane off) keeps the output pytree the HEAD program's
-            if not quality:
-                return None
-            with jax.named_scope("quality"):
-                return quality_vector(
-                    weights, w_new,
-                    residual=residual_fn(raw, labels) * mask,
-                    preds=preds, labels=labels, mask=mask,
-                    numeric=batch.numeric, token_idx=batch.token_idx,
-                    token_val=batch.token_val, gram_plane=gram_plane,
-                    axis_name=axis_name,
+        def finish(weights, eta, lam, w_new=None, raw=None):
+            """One model's stats with its pre-update weights and, outside
+            the Gram basis, its iterations: everything of the step that is
+            per MODEL once the batch is featurized."""
+            with jax.named_scope("predict"):
+                if not gram:
+                    raw = _predict_raw(weights, batch, x_dense)
+                preds = prediction_fn(raw)
+                if round_predictions:
+                    preds = jnp_round_half_up(preds)
+                stats = batch_stats(labels, preds, mask, axis_name)
+
+            def _quality(w_new, gram_plane=None):
+                # the ISSUE-8 side channel against the post-update weights;
+                # None (plane off) keeps the output pytree the HEAD program's
+                if not quality:
+                    return None
+                with jax.named_scope("quality"):
+                    return quality_vector(
+                        weights, w_new,
+                        residual=residual_fn(raw, labels) * mask,
+                        preds=preds, labels=labels, mask=mask,
+                        numeric=batch.numeric, token_idx=batch.token_idx,
+                        token_val=batch.token_val, gram_plane=gram_plane,
+                        axis_name=axis_name,
+                    )
+
+            if gram:
+                return w_new, StepOutput(
+                    predictions=preds, quality=_quality(w_new, plane), **stats
                 )
 
-        if gram:
-            return w_new, StepOutput(
-                predictions=preds, quality=_quality(w_new, plane), **stats
+            # ---- numIterations of mini-batch SGD (the scatter loop) -----
+            def grad_and_count(w, sel):
+                residual = residual_fn(_predict_raw(w, batch, x_dense), labels) * sel
+                grad_sum = _grad_sum(batch, x_dense, residual)
+                count = jnp.sum(sel)
+                if axis_name:
+                    grad_sum = lax.psum(grad_sum, axis_name)
+                    count = lax.psum(count, axis_name)
+                return grad_sum, count
+
+            w_final = sgd_inner_loop(
+                weights,
+                num_iterations=num_iterations,
+                step_size=eta,
+                mini_batch_fraction=mini_batch_fraction,
+                l2_reg=lam,
+                convergence_tol=convergence_tol,
+                mask=mask,
+                sample_key=sampling_key(axis_name, mini_batch_fraction),
+                grad_and_count=grad_and_count,
+            )
+            return w_final, StepOutput(
+                predictions=preds, quality=_quality(w_final), **stats
             )
 
-        # ---- numIterations of mini-batch SGD (the scatter loop) ---------
-        def grad_and_count(w, sel):
-            residual = residual_fn(_predict_raw(w, batch, x_dense), labels) * sel
-            grad_sum = _grad_sum(batch, x_dense, residual)
-            count = jnp.sum(sel)
-            if axis_name:
-                grad_sum = lax.psum(grad_sum, axis_name)
-                count = lax.psum(count, axis_name)
-            return grad_sum, count
-
-        w_final = sgd_inner_loop(
-            weights,
-            num_iterations=num_iterations,
-            step_size=step_size,
-            mini_batch_fraction=mini_batch_fraction,
-            l2_reg=l2_reg,
-            convergence_tol=convergence_tol,
-            mask=mask,
-            sample_key=sampling_key(axis_name, mini_batch_fraction),
-            grad_and_count=grad_and_count,
-        )
-        return w_final, StepOutput(
-            predictions=preds, quality=_quality(w_final), **stats
-        )
+        if not arms:
+            return finish(weights, step_size, l2_reg, w_new, raw)
+        # M arms on the SAME rows: the featurized batch (and, in the Gram
+        # basis, C and G) above is shared, what is left is mapped, each arm
+        # through the single model's own program (lax.map: the parity law)
+        per_arm = (weights, step_size, l2_reg) + ((w_new, raw) if gram else ())
+        with jax.named_scope("arm_map"):
+            return lax.map(lambda args: finish(*args), per_arm)
 
     return train_step
 

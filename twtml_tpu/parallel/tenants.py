@@ -45,6 +45,19 @@ instead of extra host fetches:
   through the existing FetchPipeline — fetch count per tick is ONE
   regardless of M (asserted by the counting tests).
 
+``--tenantKey all`` (a champion, tenant 0, and its challengers: ONE learner
+under M recipes, ``--tenantStepSize`` / ``--tenantL2Reg``) is the plane
+WITHOUT the partition: every tenant trains on every row. Nothing is routed,
+split or stacked — the batch ships once as the single-model wire — and the
+program is not the map of the whole step either: the arms share their rows,
+so they share the count matrix C and G = C·Cᵀ, which are the step's cost,
+and ``models/sgd.make_sgd_train_step(arms=True)`` builds both once a batch
+and maps only ``u = C·w_m``, the dual loop and ``Cᵀα_m`` over the arms
+(scope ``arm_map``, ``lax.map``: arm m bit-identical to the single model
+under arm m's recipe). State, fetch, checkpoint and frames are the plane's:
+``[M, F+4]`` weights, one ``[M, ...]`` StepOutput. One device, the default
+mapping and the stacked wire only (refused otherwise, with the reason).
+
 Mesh composition: a 1D ('data',) mesh shards every tenant batch's rows over
 ``data`` (tenant axis unsharded — weights replicated) with the per-shard
 body's psums riding the existing collectives; a 2D ('data','model') mesh
@@ -68,6 +81,7 @@ from ..features.batch import (
     PackedBatch,
     RaggedUnitBatch,
     gather_tenant_predictions,
+    pack_batch,
     pack_ragged_group,
     split_batch_tenants,
     stack_batches,
@@ -101,6 +115,8 @@ def aggregate_tenant_output(out, batch, model) -> StepOutput:
     instead of carried through the fetch pipeline. A non-finite stat in ANY
     tenant propagates into the
     aggregate, so the divergence sentinel still sees every poisoning.
+    Under ``--tenantKey all`` nothing was partitioned, so nothing is
+    pooled: the line is arm 0's (below).
 
     ``quality`` (ISSUE 8): M = 1 passes tenant 0's vector through like
     every other leaf; M > 1 leaves the aggregate's quality None — norms of
@@ -112,6 +128,27 @@ def aggregate_tenant_output(out, batch, model) -> StepOutput:
         return StepOutput(*(
             None if f is None else f[0] for f in out
         ))
+    if getattr(model, "shared_rows", False):
+        # --tenantKey all: every arm saw all B rows, so there is nothing
+        # to pool — the batch's own line is the CHAMPION's (arm 0), its
+        # count the batch's rows (not M·B) and its predictions already in
+        # the batch's row order. A non-finite stat in ANY arm turns the
+        # line's mse NaN, so the sentinel still sees every poisoning.
+        stats = np.stack([
+            np.asarray(f, np.float64)
+            for f in (out.mse, out.real_stdev, out.pred_stdev)
+        ])
+        poison = np.float32(0.0 if np.isfinite(stats).all() else np.nan)
+        return StepOutput(
+            predictions=(
+                None if out.predictions is None
+                else np.asarray(out.predictions)[0]
+            ),
+            count=np.float32(np.asarray(out.count)[0]),
+            mse=np.float32(np.asarray(out.mse)[0]) + poison,
+            real_stdev=np.float32(np.asarray(out.real_stdev)[0]),
+            pred_stdev=np.float32(np.asarray(out.pred_stdev)[0]),
+        )
     counts = np.asarray(out.count, np.float64)
     total = float(counts.sum())
     denom = max(total, 1.0)
@@ -203,6 +240,24 @@ class TenantStackModel:
             raise ValueError(
                 f"wire_pack must be 'stacked' or 'group', got {wire_pack!r}"
             )
+        # --tenantKey all: every tenant sees every row, so there is no
+        # tenant wire — and no form of it for what a tenant wire carries
+        self.shared_rows = tenant_key == "all"
+        if self.shared_rows:
+            for bad, why in (
+                (mapping == "vmap",
+                 "mapping='vmap' batches WHOLE steps over the tenant axis; "
+                 "the shared-rows step maps only its per-arm half"),
+                (wire_pack == "group",
+                 "--wirePack group coalesces M tenant batches into one "
+                 "buffer; under 'all' there is ONE batch, shipped as the "
+                 "single-model wire"),
+                (mesh is not None,
+                 "the per-arm half has no data-axis form (the arms map runs "
+                 "inside one device's Gram branch): use --master local[1]"),
+            ):
+                if bad:
+                    raise ValueError(f"--tenantKey all: {why}")
         self.num_tenants = num_tenants
         self.num_text_features = num_text_features
         self.dtype = dtype
@@ -242,29 +297,39 @@ class TenantStackModel:
             "l2_reg": _vec(l2_regs, l2_reg),
         }
 
+        # the EXISTING fused step under a tenant's (traced) hyperparams —
+        # the parity-critical semantics live in models/sgd.py exactly once
+        step_kw = dict(
+            num_text_features=num_text_features,
+            num_iterations=num_iterations,
+            mini_batch_fraction=mini_batch_fraction,
+            convergence_tol=convergence_tol,
+            residual_fn=residual_fn,
+            prediction_fn=prediction_fn,
+            round_predictions=round_predictions,
+            use_sparse=use_sparse,
+            use_gram=use_gram,
+            gram_int8=gram_int8,
+            quality=quality,
+        )
+
         def one(weights, hyper, batch):
-            # build the EXISTING fused step with this tenant's (traced)
-            # hyperparams closed over — the parity-critical semantics live
-            # in models/sgd.py exactly once
-            step = make_sgd_train_step(
-                num_text_features=num_text_features,
-                num_iterations=num_iterations,
-                step_size=hyper["step_size"],
-                mini_batch_fraction=mini_batch_fraction,
-                l2_reg=hyper["l2_reg"],
-                convergence_tol=convergence_tol,
-                residual_fn=residual_fn,
-                prediction_fn=prediction_fn,
-                round_predictions=round_predictions,
-                axis_name=self._data_axis,
-                use_sparse=use_sparse,
-                use_gram=use_gram,
-                gram_int8=gram_int8,
-                quality=quality,
-            )
-            return step(weights, batch)
+            # ONE tenant's step on its own part (mapped by ``_mapped``)
+            return make_sgd_train_step(
+                step_size=hyper["step_size"], l2_reg=hyper["l2_reg"],
+                axis_name=self._data_axis, **step_kw,
+            )(weights, batch)
+
+        def shared(weights, hyper, batch):
+            # --tenantKey all: ONE step over the batch every arm sees, the
+            # per-arm half mapped inside it (models/sgd.py ``arms``)
+            return make_sgd_train_step(
+                step_size=hyper["step_size"], l2_reg=hyper["l2_reg"],
+                arms=True, **step_kw,
+            )(weights, batch)
 
         self._one = one
+        self._shared = shared
         self._weights = jnp.zeros((num_tenants, f_total), dtype)
         self._progs: dict = {}
         if mesh is not None:
@@ -362,7 +427,9 @@ class TenantStackModel:
     def _prog_for(self, batch_cls) -> Callable:
         fn = self._progs.get(batch_cls)
         if fn is None:
-            if self.mesh is None:
+            if self.shared_rows:
+                fn = jax.jit(self._shared, donate_argnums=0)
+            elif self.mesh is None:
                 fn = jax.jit(self._mapped, donate_argnums=0)
             else:
                 sharded = jax.shard_map(
@@ -382,7 +449,8 @@ class TenantStackModel:
     def route_ids(self, batch) -> np.ndarray:
         """Per-row tenant ids for a host batch — deterministic, so delivery-
         side consumers (per-tenant stats, prediction re-ordering) recompute
-        it instead of threading a permutation through the fetch pipeline."""
+        it instead of threading a permutation through the fetch pipeline.
+        ``all`` routes nothing and has no ids."""
         return tenant_route_keys(batch, self.num_tenants, self.tenant_key)
 
     def split(self, batch, rung: int = 0):
@@ -410,7 +478,16 @@ class TenantStackModel:
         whole of it — route key, M-way split, stack or pack — is one
         ``tenant_split`` span on the scheduler's thread (inside
         ``wire_pack``), carrying the routed rows, M and the tenant wire's
-        bytes."""
+        bytes. Under ``--tenantKey all`` there is no route, no split and no
+        stack, so no span: the ragged batch is packed as the single model's
+        is (``pack_batch``: one buffer, one upload), any other ships as it
+        is."""
+        if self.shared_rows:
+            if isinstance(batch, RaggedUnitBatch):
+                return pack_batch(
+                    batch, codec="dict" if self.wire_codec == "dict" else None
+                )
+            return batch
         tr = _trace.get()
         with tr.span("tenant_split", tenants=self.num_tenants) as sp:
             wire = self.prepare_wire_from_parts(self.split(batch))
@@ -473,7 +550,10 @@ class TenantStackModel:
 
     # -- model surface -------------------------------------------------------
     def step(self, batch) -> StepOutput:
-        wire = batch if self._is_tenant_wire(batch) else self.prepare_wire(batch)
+        wire = (
+            batch if self.shared_rows or self._is_tenant_wire(batch)
+            else self.prepare_wire(batch)
+        )
         if self.mesh is not None and not isinstance(
             jax.tree_util.tree_leaves(wire)[0], jax.Array
         ):
@@ -575,6 +655,17 @@ class TenantStackModel:
 
     @classmethod
     def from_conf(cls, conf, mesh=None, **overrides):
+        key = getattr(conf, "tenantKey", "hash")
+        if key == "all":
+            # only an EXPLICIT group asks for the coalesced tenant wire
+            # here (and is refused): the codec's auto resolution to it is
+            # for tenant wires, and under ``all`` there is none
+            group = conf.wirePack == "group"
+        else:
+            group = (
+                getattr(conf, "effective_wire_pack", lambda: "stacked")()
+                == "group" and conf.effective_wire() == "ragged"
+            )
         kwargs = dict(
             num_tenants=int(getattr(conf, "tenants", 1) or 1),
             num_text_features=conf.numTextFeatures,
@@ -582,15 +673,12 @@ class TenantStackModel:
             step_size=conf.stepSize,
             mini_batch_fraction=conf.miniBatchFraction,
             l2_reg=conf.l2Reg,
+            # --tenantStepSize / --tenantL2Reg: a recipe per tenant
+            **dict(zip(("step_sizes", "l2_regs"), conf.tenant_recipes())),
             convergence_tol=conf.convergenceTol,
             dtype=jnp.dtype(conf.dtype),
-            tenant_key=getattr(conf, "tenantKey", "hash"),
-            wire_pack=(
-                "group"
-                if getattr(conf, "effective_wire_pack", lambda: "stacked")()
-                == "group" and conf.effective_wire() == "ragged"
-                else "stacked"
-            ),
+            tenant_key=key,
+            wire_pack="group" if group else "stacked",
             wire_codec=(
                 getattr(conf, "effective_wire_codec", lambda: "off")()
             ),

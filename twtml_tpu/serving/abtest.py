@@ -159,6 +159,7 @@ class ChampionEngine(PredictEngine):
                 f"2), got {self.num_tenants} — train with --tenants M"
             )
         self.selector = ChampionSelector(self.num_tenants, champion)
+        self._recipes: "dict | None" = None  # the snapshot's own stamp
         self._shadows = [_ShadowTrack() for _ in range(self.num_tenants)]
         self._live_rows = np.zeros((self.num_tenants,), np.int64)
 
@@ -173,6 +174,16 @@ class ChampionEngine(PredictEngine):
         dispatches (ServingPlane._install), so a swap of (weights,
         champion) is one atomic event w.r.t. dispatches."""
         super().set_snapshot(snapshot)
+        meta = getattr(snapshot, "meta", None) or {}
+        stamp = meta.get("tenants")
+        if isinstance(stamp, dict) and len(
+            stamp.get("stepSize") or ()
+        ) == len(stamp.get("l2Reg") or ()) == self.num_tenants:
+            # which variant is which (apps/common.tenant_stamp): the key
+            # the stack was trained under and each tenant's recipe
+            self._recipes = stamp
+        else:
+            self._recipes = None
         self.selector.consider(
             getattr(snapshot, "meta", None), int(snapshot.step)
         )
@@ -235,8 +246,14 @@ class ChampionEngine(PredictEngine):
                 "liveRows": int(self._live_rows[m]),
                 "shadowRows": int(track.rows),
                 "divergence": round(track.divergence or 0.0, 4),
+                **({} if self._recipes is None else {
+                    "stepSize": float(self._recipes["stepSize"][m]),
+                    "l2Reg": float(self._recipes["l2Reg"][m]),
+                }),
             })
         return {
+            **({} if self._recipes is None
+               else {"tenantKey": str(self._recipes.get("key", ""))}),
             "champion": int(self.champion),
             "shadows": shadows,
             "promotions": int(reg.counter("abtest.promotions").snapshot()),

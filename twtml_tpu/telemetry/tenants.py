@@ -25,12 +25,27 @@ from . import metrics as _metrics
 
 _lock = threading.Lock()
 _state: "dict | None" = None
+_recipes: "dict | None" = None
 
 
 def reset_for_tests() -> None:
-    global _state
+    global _state, _recipes
     with _lock:
         _state = None
+        _recipes = None
+
+
+def configure(step_sizes, l2_regs) -> None:
+    """Each tenant's recipe, in tenant order (apps/common.build_model): the
+    view carries them so a dashboard row says which recipe it shows (under
+    ``--tenantKey all`` the tenants are arms of one learner that differ in
+    nothing else)."""
+    global _recipes
+    with _lock:
+        _recipes = {
+            "stepSize": [float(v) for v in step_sizes],
+            "l2Reg": [float(v) for v in l2_regs],
+        }
 
 
 def record_tick(counts, mses) -> None:
@@ -75,6 +90,8 @@ def last_tenants() -> "dict | None":
             return None
         counts = st["last_counts"]
         gating = int(np.argmax(counts)) if counts.any() else -1
+        m = st["rows"].shape[0]
+        rec = _recipes if _recipes and len(_recipes["stepSize"]) == m else None
         return {
             "tenants": [
                 {
@@ -85,8 +102,12 @@ def last_tenants() -> "dict | None":
                         round(float(st["last_mses"][i]), 3)
                         if np.isfinite(st["last_mses"][i]) else -1.0
                     ),
+                    **({} if rec is None else {
+                        "stepSize": rec["stepSize"][i],
+                        "l2Reg": rec["l2Reg"][i],
+                    }),
                 }
-                for i in range(st["rows"].shape[0])
+                for i in range(m)
             ],
             "gating": gating,
             "active": int((counts > 0).sum()),
