@@ -607,6 +607,59 @@ def test_compiled_tenant_program_at_the_top_rung_is_the_whole_batchs(topo):
     assert 4 * 2**30 <= temp < 5 * 2**30      # 4,318,823,936 B as compiled
 
 
+def test_compiled_two_rung_program_reserves_one_top_rung_step(topo):
+    """PR 49: the ONE program of a lopsided split — the fullest tenant's
+    step at the top rung (2,048 rows, ``_two_rung_units``' 327,680 units) and
+    the ``lax.map`` of the step over the three others at the first rung (640
+    rows, that rung's 102,400 units) — as the TPU's compiler makes it for
+    ``hash2e18-lang4-trimmed-280``'s wire. Both Gram products are in it, and
+    ``tenant_map`` with the stage scopes around BOTH halves (the fullest's
+    ops directly under the scope, the others' under its ``while``). The
+    halves run in turn, so it reserves the one-tenant top-rung step's
+    temporaries — under the ``[4, 2048]`` map's 4,318,823,936 B, which also
+    held one tenant at a time — and not that plus the 640-rung map's
+    (~1.36e9 B, ``test_compiled_tenant_program_is_sized_by_the_rung``)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from twtml_tpu.features.batch import TwoRungWire, tenant_row_rungs
+    from twtml_tpu.parallel import TenantStackModel
+
+    m = 4
+    low, _mid, top = tenant_row_rungs(ROWS, m)
+    assert (low, top) == (640, ROWS)
+    dev = SingleDeviceSharding(topo.devices[0])
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=dev)
+
+    def member(k, rung, units):
+        return RaggedUnitBatch(
+            shape(k, units, dtype=jnp.uint16),
+            shape(k, rung + 1, dtype=jnp.int32), shape(k, rung, 4),
+            shape(k, rung), shape(k, rung), row_len=ROW_LEN)
+
+    model = TenantStackModel(m, num_text_features=F_TEXT, l2_reg=0.1,
+                             step_size=0.005, quality=True, tenant_key="lang")
+    # the 280-unit mix's buckets (303,104 … 315,392 units) all ship as this
+    wire = TwoRungWire(member(1, top, 327680), member(m - 1, low, 102400),
+                       shape(m, dtype=jnp.int32))
+    compiled = jax.jit(model._mapped, donate_argnums=0).lower(
+        shape(m, F_TEXT + 4), {k: shape(m) for k in model._hyper}, wire,
+    ).compile()
+    text = compiled.as_text()
+    assert f"f32[{top},{top}" in text and f"f32[{low},{low}" in text
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    mapped = {n for n in names
+              if n.startswith("jit(_mapped)/tenant_map/while/body/")}
+    direct = {n for n in names
+              if n.startswith("jit(_mapped)/tenant_map/") and n not in mapped}
+    for scope in (s for s in STAGE_SCOPES if s != "unpack"):
+        assert any(f"/{scope}/" in n for n in mapped), scope
+        assert any(f"/{scope}/" in n for n in direct), scope
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 4 * 2**30 <= temp <= 4_318_823_936     # 4,313,642,496 B as compiled
+
+
 # ---------------------------------------------------------------------------
 # PR 47: ``--tenantKey all`` — M arms on the SAME rows share the count matrix
 # and G; only ``u = C·w_m``, the dual loop and ``Cᵀα_m`` are mapped.

@@ -239,27 +239,85 @@ def _skewed(rows0: int, long0: int = 2):
     return rb, ids
 
 
-@pytest.mark.parametrize("rows0,long0,rung", [
-    (1280, 2, 1280),    # the fullest tenant fills the middle rung exactly
-    (1281, 2, 2048),    # one row more: the top rung, the batch's own rows
-    (1200, 40, 2048),   # rows that fit 1,280 whose units do not: the next
-    (500, 2, 640),      # an even-ish split: the first rung
+@pytest.mark.parametrize("rows0,long0,rung,rest", [
+    (1280, 2, 1280, 640),   # the fullest tenant fills the middle rung exactly
+    (1281, 2, 2048, 640),   # one row more: the top rung, the batch's own rows
+    (1200, 40, 2048, 640),  # rows that fit 1,280 whose units do not: the next
+    (500, 2, 640, 640),     # an even-ish split: the first rung for all four
 ])
-def test_the_ladder_under_skew(rows0, long0, rung):
+def test_the_ladder_under_skew(rows0, long0, rung, rest):
+    """The fullest tenant's part at the rung IT calls for, the other three
+    at the rung the fullest of THEM calls for (256–516 rows of two units:
+    the first); ``one_rung`` is the split until PR 49, all at the
+    fullest's."""
     assert tenant_row_rungs(2048, 4) == (640, 1280, 2048)
     rb, ids = _skewed(rows0, long0)
     parts = split_batch_tenants(rb, ids, 4)
-    assert {p.mask.shape[0] for p in parts} == {rung}
+    assert [p.mask.shape[0] for p in parts] == [rung, rest, rest, rest]
+    assert {p.mask.shape[0] for p in split_batch_tenants(
+        rb, ids, 4, one_rung=True)} == {rung}
     assert [p.num_valid for p in parts] == np.bincount(
         ids, minlength=4).tolist()
+
+    def scaled(r):       # a lower rung scales the buffer, in whole buckets
+        return (-(-rb.units.shape[0] * r // 2048 // RAGGED_UNIT_MULTIPLE)
+                * RAGGED_UNIT_MULTIPLE)
+
     if rung == 2048:     # the top rung keeps the parent's units buffer
-        assert {p.units.shape[0] for p in parts} == {rb.units.shape[0]}
+        assert parts[0].units.shape[0] == rb.units.shape[0]
         assert parts[0].units[:rows0 * long0].tobytes() == (
             rb.units[:rows0 * long0].tobytes())
-    else:                # a lower rung scales it, in whole buckets
-        assert {p.units.shape[0] for p in parts} == {
-            -(-rb.units.shape[0] * rung // 2048 // RAGGED_UNIT_MULTIPLE)
-            * RAGGED_UNIT_MULTIPLE}
+    else:
+        assert parts[0].units.shape[0] == scaled(rung)
+    assert {p.units.shape[0] for p in parts[1:]} == {scaled(rest)}
+
+
+def test_a_lopsided_stream_holds_one_program_a_units_bucket():
+    """Over a pool whose batches fall into several units buckets of the
+    ragged wire the plane holds ONE program a bucket of the TWO-RUNG wire
+    (``_two_rung_units``: the parent's rounded up to a sixteenth of the next
+    power of two, so no more than the parent's) and no more: both halves in
+    it, never a program a rung beside a program a pair, and never the
+    ``[M, B]`` map of the whole batch's shape, which no batch called for."""
+    import json
+
+    from twtml_tpu.features.batch import TwoRungWire, _two_rung_units
+    from twtml_tpu.features.featurizer import Featurizer, Status
+    from twtml_tpu.parallel import TenantStackModel
+
+    m, rows, batches = 4, 256, 8
+    g = _generator(rows, batches)
+    chunk = gen.make_chunk(g, gen.build_vocab(g, 3), 3, 0, rows * batches)
+    feat = Featurizer(now_ms=g["now_ms"])
+    statuses = [Status.from_json(json.loads(line)) for line in chunk.lines]
+    stack = TenantStackModel(m, num_text_features=F_TEXT, l2_reg=0.1,
+                             step_size=0.005, tenant_key="lang")
+    buckets, wires = set(), set()
+    for b in range(batches):
+        rb = feat.featurize_batch_ragged(
+            statuses[b * rows:(b + 1) * rows], row_bucket=rows,
+            pre_filtered=True)
+        wire = stack.prepare_wire(rb)
+        assert isinstance(wire, TwoRungWire)
+        assert wire.full.mask.shape == (1, 256)
+        coarse = _two_rung_units(rb.units.shape[0])
+        assert rb.units.shape[0] <= coarse < rb.units.shape[0] * 9 / 8
+        assert wire.full.units.shape == (1, coarse)
+        half = -(-coarse // 2 // RAGGED_UNIT_MULTIPLE) * RAGGED_UNIT_MULTIPLE
+        assert wire.rest.units.shape == (m - 1, half)
+        assert wire.rest.mask.shape == (m - 1, 128)
+        buckets.add((rb.units.shape[0], str(rb.units.dtype)))
+        wires.add((wire.full.units.shape, wire.rest.units.shape,
+                   str(wire.full.units.dtype)))
+        out = stack.step(wire)
+        assert np.asarray(out.predictions).shape == (m, 256)
+    assert len(buckets) > 1                 # the stream DOES change bucket
+    assert set(stack._progs) == {TwoRungWire}
+    assert stack._prog_for(TwoRungWire)._cache_size() == len(wires) <= len(
+        buckets)
+    # at the cell's size the 280-unit mix's four buckets are ONE
+    assert {_two_rung_units(n) for n in (303104, 307200, 311296, 315392)
+            } == {327680}
 
 
 def test_a_near_dry_tenants_part_takes_its_own_gram_plane():
@@ -297,11 +355,35 @@ def test_a_near_dry_tenants_part_takes_its_own_gram_plane():
 def test_the_planes_spans_say_the_rung_under_each_key(
         tmp_path, monkeypatch, key, bucket, watch):
     """256 rows over 4 tenants: the ladder is 128 / 256. The hash key takes
-    the first rung; the script key gives tenant 0 more than 128 rows in
-    every batch, so every part is padded to the batch's own 256 rows. Each
-    part's own Gram plane rides the quality leaf, so ``--modelWatch off``
-    (no such leaf in the fetch) leaves ``planes`` out and the rest as it
-    is."""
+    the first rung for all four; the script key gives tenant 0 more than 128
+    rows in every batch, so ITS part is padded to the batch's own 256 rows
+    and the three others to 128 (PR 49): ``bucket`` is the fullest part's
+    rung, ``buckets`` all four in tenant order, ``pad_rows`` their sum less
+    the rows, and the ``tenant_split`` span says the two rungs. Each part's
+    own Gram plane rides the quality leaf, so ``--modelWatch off`` (no such
+    leaf in the fetch) leaves ``planes`` out and the rest as it is. ONE
+    fetch and ONE program call a batch under either key."""
+    import jax
+
+    from twtml_tpu.parallel import TenantStackModel
+
+    calls = {"fetch": 0, "program": 0}
+    real_get, real_prog = jax.device_get, TenantStackModel._prog_for
+
+    def counting_get(x):
+        calls["fetch"] += 1
+        return real_get(x)
+
+    def counting_prog(self, batch_cls):
+        fn = real_prog(self, batch_cls)
+
+        def call(*args):
+            calls["program"] += 1
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    monkeypatch.setattr(TenantStackModel, "_prog_for", counting_prog)
     rows, batches = 256, 3
     _g, _chunk, path = _stream(tmp_path, rows, batches, 5)
     trace = str(tmp_path / "spans.json")
@@ -312,14 +394,19 @@ def test_the_planes_spans_say_the_rung_under_each_key(
     split = [e for e in events if e.get("name") == "tenant_split"]
     routed = [e for e in events if e.get("name") == "tenant_rows"]
     assert len(split) == len(routed) == batches
+    assert calls == {"fetch": batches, "program": batches}
+    buckets = [256, 128, 128, 128] if key == "lang" else [128] * 4
     for e in split:
         a = e["args"]
         assert a["tenants"] == 4 and a["rows"] == rows and a["bytes"] > 0
+        assert a["rungs"] == [buckets[0], buckets[-1]]
     for e in routed:
         a = e["args"]
-        assert a["bucket"] == bucket
-        assert sum(a["rows"]) == rows and max(a["rows"]) <= bucket
-        assert a["pad_rows"] == 4 * bucket - rows
+        assert a["bucket"] == bucket == max(a["buckets"])
+        assert a["buckets"] == buckets
+        assert sum(a["rows"]) == rows
+        assert all(n <= r for n, r in zip(a["rows"], a["buckets"]))
+        assert a["pad_rows"] == sum(buckets) - rows
         if watch == "off":
             assert "planes" not in a
         else:

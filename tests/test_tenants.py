@@ -29,6 +29,7 @@ from twtml_tpu.config import ConfArguments  # noqa: E402
 from twtml_tpu.features.batch import (  # noqa: E402
     RAGGED_UNIT_MULTIPLE,
     RaggedUnitBatch,
+    TwoRungWire,
     split_batch_tenants,
     stack_batches,
     tenant_route_keys,
@@ -207,26 +208,37 @@ def _ids_units_over():
     return _rest_over(ids, [1, 2, 3])
 
 
-@pytest.mark.parametrize("tenants,ids,rung,n_units", [
-    pytest.param(4, _ids_even, 384, 4096, id="even-first_rung"),
-    pytest.param(4, _ids_one_row_over, 768, 8192, id="one_row_over-next"),
-    pytest.param(4, _ids_units_over, 768, 8192, id="units_over-next"),
-    pytest.param(3, lambda: np.zeros(1024, np.int32), 1024, 8192,
+@pytest.mark.parametrize("tenants,ids,rung,n_units,rest", [
+    pytest.param(4, _ids_even, 384, 4096, 384, id="even-first_rung"),
+    pytest.param(4, _ids_one_row_over, 768, 8192, 384,
+                 id="one_row_over-next"),
+    pytest.param(4, _ids_units_over, 768, 8192, 384, id="units_over-next"),
+    pytest.param(3, lambda: np.zeros(1024, np.int32), 1024, 8192, 512,
                  id="all_to_tenant_0-top_rung"),
-    pytest.param(1, lambda: np.zeros(1024, np.int32), 1024, 8192,
+    pytest.param(1, lambda: np.zeros(1024, np.int32), 1024, 8192, 1024,
                  id="one_tenant-pass_through"),
 ])
 def test_split_takes_the_rung_the_batch_calls_for(
-        tenants, ids, rung, n_units):
+        tenants, ids, rung, n_units, rest):
+    """Tenant 0 is the fullest in every case: ITS part takes the rung its
+    rows and units call for, the others the rung the fullest of THEM needs
+    (``rest``: the ladder's first, 384 rows at M = 4 and 512 at M = 3, with
+    a 4,096-unit buffer either way, wherever they hold a quarter of the
+    batch or nothing). ``one_rung`` pads all to the fullest's, as every
+    split did until PR 49."""
     rb = _rung_batch()
     ids = ids()
     parts = split_batch_tenants(rb, ids, tenants)
     assert len(parts) == tenants
-    assert len({_signature(p) for p in parts}) == 1
+    assert len({_signature(p) for p in parts[1:]}) <= 1
+    assert [p.mask.shape[0] for p in parts] == [rung] + [rest] * (tenants - 1)
+    assert {p.units.shape[0] for p in parts[1:]} <= {4096}
     assert parts[0].mask.shape == (rung,) and parts[0].row_len == rb.row_len
     assert parts[0].offsets.shape == (rung + 1,)
     assert parts[0].units.shape == (n_units,)
     assert n_units % RAGGED_UNIT_MULTIPLE == 0
+    one = split_batch_tenants(rb, ids, tenants, one_rung=True)
+    assert {_signature(p) for p in one} == {_signature(parts[0])}
     counts = np.bincount(ids, minlength=tenants)
     assert [p.num_valid for p in parts] == counts.tolist()
     for p, n in zip(parts, counts):    # rows to the front, padding behind
@@ -397,6 +409,168 @@ def test_m4_rung_agrees_with_the_top_rung_to_rounding():
     w2 = at_top.latest_weights.astype(np.float64)
     for i in range(m):
         assert np.abs(w1[i] - w2[i]).sum() <= 1e-6 * np.abs(w2[i]).sum(), i
+
+
+# ---------------------------------------------------------------------------
+# PR 49: a rung for the fullest tenant and a rung for the rest, ONE program
+
+
+def _ids_by_counts(counts, shift=0):
+    """Row r's tenant: the first ``counts[0]`` rows to tenant ``shift``, the
+    next ``counts[1]`` to ``shift + 1`` (mod M), and so on."""
+    m = len(counts)
+    return ((np.repeat(np.arange(m), counts) + shift) % m).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def batches_2048():
+    return _ragged_batches(n=4096, b=2048, unicode_mix=True)
+
+
+@pytest.mark.parametrize("counts,rungs", [
+    pytest.param((1400, 200, 60, 388), (2048, 640), id="one_over_62.5pct"),
+    pytest.param((2048, 0, 0, 0), (2048, 640), id="all_to_one"),
+    pytest.param((700, 700, 300, 348), (1280, 1280), id="two_need_the_middle"),
+    pytest.param((512, 512, 512, 512), (640, 640), id="even"),
+])
+def test_two_rungs_each_tenant_bit_equals_the_single_model_at_its_own_rung(
+        batches_2048, counts, rungs):
+    """The parity law on the two-rung plane: every tenant's weights and its
+    row of the ONE StepOutput are bit-identical to a single model stepped on
+    that tenant's part padded to its OWN rung. The second batch shifts the
+    split by three tenants, so the fullest is tenant 0 and then tenant 3:
+    the weights are gathered and written back by id."""
+    m = 4
+    mt = TenantStackModel(m, step_size=0.1)
+    singles = [StreamingLinearRegressionWithSGD(step_size=0.1)
+               for _ in range(m)]
+    for shift, rb in zip((0, 3), batches_2048):
+        ids = _ids_by_counts(counts, shift)
+        parts = split_batch_tenants(rb, ids, m)
+        fullest = shift % m
+        assert [p.mask.shape[0] for p in parts] == [
+            rungs[0] if i == fullest else rungs[1] for i in range(m)]
+        wire = mt.prepare_wire_from_parts(parts)
+        assert isinstance(wire, TwoRungWire) == (rungs[0] != rungs[1])
+        out = mt.step(wire)
+        assert np.asarray(out.predictions).shape == (m, rungs[0])
+        for i in range(m):
+            oi = singles[i].step(parts[i])
+            for f in ("count", "mse", "real_stdev", "pred_stdev"):
+                assert np.asarray(getattr(oi, f)).tobytes() == (
+                    np.asarray(getattr(out, f))[i].tobytes()), (i, f)
+            r = parts[i].mask.shape[0]
+            got = np.asarray(out.predictions)[i]
+            assert got[:r].tobytes() == np.asarray(oi.predictions).tobytes()
+            assert not got[r:].any()        # the rest, padded to r_full
+    for i in range(m):
+        assert singles[i].latest_weights.tobytes() == (
+            mt.latest_weights[i].tobytes()), i
+
+
+def test_an_even_split_is_the_parents_parts_wire_and_program(batches_2048):
+    """Equal rungs → what the plane did before it knew two: the M
+    same-signature parts byte for byte, the ``[M, rung, ...]`` stacked wire,
+    and — lowered text against lowered text — the ``lax.map`` of the step
+    over the tenant axis and nothing else."""
+    import jax
+    from jax import lax
+
+    rb = batches_2048[0]
+    m = 4
+    mt = TenantStackModel(m, num_text_features=4096, l2_reg=0.1)
+    ids = tenant_route_keys(rb, m)
+    parts = split_batch_tenants(rb, ids, m)
+    before = split_batch_tenants(rb, ids, m, one_rung=True)
+    assert {p.mask.shape[0] for p in parts} == {640}
+    for p, q in zip(parts, before):
+        for f in ("units", "offsets", "numeric", "label", "mask"):
+            assert getattr(p, f).tobytes() == getattr(q, f).tobytes()
+    wire = mt.prepare_wire(rb)
+    assert isinstance(wire, RaggedUnitBatch) and wire.mask.shape == (m, 640)
+    stacked = stack_batches(before)
+    for f in ("units", "offsets", "numeric", "label", "mask"):
+        assert getattr(wire, f).tobytes() == getattr(stacked, f).tobytes()
+
+    def _mapped(weights, hyper, batch):     # the plane's program until PR 49
+        with jax.named_scope("tenant_map"):
+            return lax.map(
+                lambda args: mt._one(*args), (weights, hyper, batch))
+
+    args = (mt._weights, mt._hyper, wire)
+    assert mt._prog_for(type(wire)).lower(*args).as_text() == (
+        jax.jit(_mapped, donate_argnums=0).lower(*args).as_text())
+
+
+def test_the_fullest_tenant_is_a_value_of_the_wire_not_a_program(
+        batches_2048):
+    """Tenant 0 the fullest, then tenant 3, same rungs and units buckets:
+    ONE backend compile, one program held."""
+    import jax.monitoring as mon
+
+    from twtml_tpu.telemetry.trace import BACKEND_COMPILE_EVENT
+
+    m = 4
+    mt = TenantStackModel(m, num_text_features=4096, l2_reg=0.1)
+    rb = batches_2048[0]
+    wires = [
+        mt.prepare_wire_from_parts(split_batch_tenants(
+            rb, _ids_by_counts((1400, 200, 60, 388), shift), m))
+        for shift in (0, 3)
+    ]
+    assert [int(w.ids[0]) for w in wires] == [0, 3]
+    assert wires[1].ids.tolist() == [3, 0, 1, 2]
+    seen = []
+
+    def on_compile(event, _secs, **kw):
+        if event == BACKEND_COMPILE_EVENT:
+            seen.append(str(kw.get("fun_name", "")))
+
+    mon.register_event_duration_secs_listener(on_compile)
+    try:
+        counts = [np.asarray(mt.step(w).count).tolist() for w in wires]
+    finally:
+        mon.unregister_event_duration_listener(on_compile)
+    assert counts == [[1400, 200, 60, 388], [200, 60, 388, 1400]]
+    assert [f for f in seen if "_mapped" in f] == ["jit(_mapped)"], seen
+    assert set(mt._progs) == {TwoRungWire}
+    assert mt._prog_for(TwoRungWire)._cache_size() == 1
+
+
+@pytest.mark.parametrize("how", ["pinned_rung", "group_wire", "mesh_1d"])
+def test_one_rung_for_all_where_the_wire_or_program_has_one_shape(
+        batches_2048, how):
+    """A pinned ``rung=``, ``--wirePack group`` and a 1D mesh keep the
+    fullest tenant's rung for every part of a lopsided split."""
+    import jax
+
+    from twtml_tpu.features.batch import PackedBatch
+    from twtml_tpu.parallel import make_mesh
+
+    rb, m = batches_2048[0], 4
+    kw = dict(num_text_features=4096, l2_reg=0.1, tenant_key="lang")
+    ids = tenant_route_keys(rb, m, "lang")
+    assert np.bincount(ids, minlength=m)[0] > 1280      # tenant 0: top rung
+    two = {p.mask.shape[0] for p in split_batch_tenants(rb, ids, m)}
+    assert len(two) == 2 and max(two) == 2048
+    if how == "pinned_rung":
+        model, rung = TenantStackModel(m, **kw), 2048
+    elif how == "group_wire":
+        model, rung = TenantStackModel(m, wire_pack="group", **kw), 0
+    else:
+        if len(jax.devices()) < 2:
+            pytest.skip("needs >= 2 devices")
+        mesh = make_mesh(num_data=2, devices=jax.devices()[:2])
+        model, rung = TenantStackModel(m, mesh=mesh, **kw), 0
+    parts = model.split(rb, rung=rung)
+    assert {p.mask.shape[0] for p in parts} == {2048}
+    wire = model.prepare_wire_from_parts(parts)
+    assert isinstance(wire, PackedBatch if how == "group_wire"
+                      else RaggedUnitBatch)
+    out = model.step(wire)
+    assert np.asarray(out.predictions).shape == (m, 2048)
+    assert np.asarray(out.count).tolist() == np.bincount(
+        ids, minlength=m).tolist()
 
 
 def test_per_tenant_hyperparams_are_mapped_leaves():
@@ -572,7 +746,8 @@ def test_mesh_parts_share_one_unit_capacity_and_a_rung_of_the_axis():
     ref, mtm = TenantStackModel(4), TenantStackModel(4, mesh=mesh)
     for model in (ref, mtm):
         parts = split_batch_tenants(
-            rb, ids, 4, row_multiple=getattr(model, "num_data", 1))
+            rb, ids, 4, row_multiple=getattr(model, "num_data", 1),
+            one_rung=True)
         wire = model.prepare_wire_from_parts(parts)
         assert wire.mask.shape == (4, 768)
         out = model.step(wire)

@@ -2623,6 +2623,13 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
                 bucket = int(
                     batch.mask.shape[0] if preds is None else preds.shape[1]
                 )
+                # the M rungs in tenant order (PR 49): a lopsided split
+                # pads the fullest tenant's part to ``bucket`` and the
+                # others to a lower rung, which only the split knows
+                take = getattr(model, "take_buckets", None)
+                buckets = (
+                    take(_trace.current_batch()) if take else None
+                ) or [bucket] * counts.size
                 extra = {}
                 if getattr(out, "quality", None) is not None:
                     # each part's OWN Gram plane (the ``gram_plane`` instant
@@ -2636,7 +2643,8 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
                 tr.instant(
                     "tenant_rows", batch=_trace.current_batch(),
                     key=tenant_key, rows=counts.tolist(), bucket=bucket,
-                    pad_rows=int(counts.size * bucket - counts.sum()),
+                    buckets=buckets,
+                    pad_rows=int(sum(buckets) - counts.sum()),
                     **extra,
                 )
             tenant_inner(

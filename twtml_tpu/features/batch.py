@@ -240,6 +240,36 @@ def _register_ragged():
 _register_ragged()
 
 
+class TwoRungWire:
+    """The tenant wire of a LOPSIDED split (``stack_two_rungs``): two
+    stacked members in one pytree — ``full``, the fullest tenant's part
+    ``[1, r_full, ...]``, and ``rest``, the other M−1 parts
+    ``[M−1, r_rest, ...]`` at the rung THEY need — plus ``ids``, ``[M]``
+    int32: ``ids[0]`` the fullest tenant, ``ids[1:]`` the others in tenant
+    order. A traced VALUE, not static: WHICH tenant is fullest may change
+    from batch to batch without a new program; the two rungs are the
+    program's shape (parallel/tenants.py runs both members in ONE jit
+    program)."""
+
+    def __init__(self, full, rest, ids):
+        self.full = full
+        self.rest = rest
+        self.ids = ids
+
+
+def _register_two_rung():
+    import jax
+
+    jax.tree_util.register_pytree_node(
+        TwoRungWire,
+        lambda w: ((w.full, w.rest, w.ids), None),
+        lambda _aux, leaves: TwoRungWire(*leaves),
+    )
+
+
+_register_two_rung()
+
+
 _WIRE_FIELDS = (
     "token_idx", "token_val", "units", "offsets", "length",
     "numeric", "label", "mask", "buffer",
@@ -251,6 +281,11 @@ def wire_nbytes(batch) -> int:
     fields' nbytes, whatever the batch type) — the per-batch upload
     volume, recorded by the telemetry layer
     (telemetry/trace.py spans, ``wire.bytes`` counter)."""
+    if isinstance(batch, TwoRungWire):
+        return (
+            wire_nbytes(batch.full) + wire_nbytes(batch.rest)
+            + int(batch.ids.nbytes)
+        )
     total = 0
     for name in _WIRE_FIELDS:
         arr = getattr(batch, name, None)
@@ -268,7 +303,15 @@ def wire_signature(wire, batch) -> dict:
     stacked tenant wire (``[M, rung, ...]`` leaves) is read itself: its
     rows and units bucket are the split's rung's, not the host batch's
     (the one-buffer tenant wire, ``--wirePack group``, still reports the
-    host batch)."""
+    host batch). A two-rung tenant wire reports its fullest member's and,
+    as ``rest_rows`` / ``rest_units_len``, the other member's rung."""
+    if isinstance(wire, TwoRungWire):
+        rest = wire_signature(wire.rest, batch)
+        return {
+            **wire_signature(wire.full, batch),
+            "rest_rows": rest["rows"], "rest_units_len": rest["units_len"],
+            "wire": type(wire).__name__,
+        }
     if getattr(getattr(wire, "mask", None), "ndim", 1) == 2:
         batch = wire
     units = getattr(batch, "units", None)
@@ -299,6 +342,9 @@ def wire_composition(batch) -> "dict[str, int]":
     agreeing with the unpacked view) and adds ``units_compressed`` — the
     bytes the transport actually carries; their quotient is the live
     ``wire.codec_ratio`` gauge (apps/common.py)."""
+    if isinstance(batch, TwoRungWire):
+        full, rest = wire_composition(batch.full), wire_composition(batch.rest)
+        return {name: full[name] + rest[name] for name in full}
     if isinstance(batch, PackedBatch):
         tag = batch.layout[0]
         if tag in ("RaggedShardSegments", "RaggedGroupSegments"):
@@ -1266,55 +1312,97 @@ def _rung_units(parent_units: int, rung: int, rows: int) -> int:
     return min(parent_units, scaled)
 
 
+def _two_rung_units(parent_units: int) -> int:
+    """The units bucket a TWO-rung split sizes its members' buffers from:
+    the parent's, rounded up to a sixteenth of the next power of two (32,768
+    units at 2,048 rows of 20–280: 303,104 … 327,680 all give 327,680), at
+    most an eighth more units on the wire. Why coarser than
+    ``RAGGED_UNIT_MULTIPLE``: the two-rung program holds TWO whole steps, so
+    each one a stream meets costs twice the one-rung program's trace and
+    lowering and two to five times its load from the compile cache (0.4–0.6
+    + 0.4–0.6 + 0.4–2.0 s against 0.2–0.3 + 0.3 + 0.1–0.8 on the v5e's
+    host), and with the parent's own buckets — four in the 280-unit mix —
+    the lopsided cell's set-up read 20.1–20.6 s against 14.3 (my chip runs,
+    PR 49; PERF.md §6). With this one a stream has one program a pair of
+    rungs, or two where its batches straddle a multiple."""
+    step = max(RAGGED_UNIT_MULTIPLE, _bucket(parent_units) // 16)
+    return -(-parent_units // step) * step
+
+
 def split_batch_tenants(
     batch, tenant_ids: np.ndarray, num_tenants: int,
-    row_multiple: int = 1, rung: int = 0,
+    row_multiple: int = 1, rung: int = 0, one_rung: bool = False,
 ):
-    """One featurized batch → M per-tenant batches of ONE shared padded
-    shape, valid rows routed by ``tenant_ids`` and packed to the front in
-    original relative order; dry tenants come back all-padding. The M
-    batches share one wire signature by construction, so ``stack_batches``
-    / ``pack_ragged_group`` turn them into the one-tenant-wire upload.
+    """One featurized batch → M per-tenant batches, valid rows routed by
+    ``tenant_ids`` and packed to the front in original relative order; dry
+    tenants come back all-padding.
 
-    The shape is the tenant ROW RUNG, read off the batch in hand: the
-    smallest rung of ``tenant_row_rungs`` that holds the fullest tenant's
+    The shapes are TWO tenant ROW RUNGS, read off the batch in hand. Each
+    tenant NEEDS the smallest rung of ``tenant_row_rungs`` that holds its
     rows — and, on the ragged wire, its units in ``_rung_units``' buffer
-    (rows that fit a rung whose units do not take the next). What the
-    device then works on is M·rung rows, not M·B: under a uniform key every
-    batch takes the first rung (four parts of 640 rows for a batch of
-    2,048; PERF.md §6, PR 36). Token width / ``row_len`` / units dtype are
-    the parent's. At the top rung (everything to one tenant, M = 1, a
-    batch smaller than a rung) a part has the parent's own shape, and a
-    tenant that got every row gets the parent back byte for byte.
-    ``rung`` pins the shape instead (multi-host callers, whose hosts must
-    agree on it, pass the batch's row count)."""
+    (rows that fit a rung whose units do not take the next). The FULLEST
+    tenant (the one that needs the highest rung; the first of them) has its
+    part padded to the rung it needs; the other M−1 parts are padded to the
+    rung the fullest of THEM needs. Where the two rungs are equal — every
+    batch of an even key, two tenants that both need the top rung, M = 1 —
+    the M parts share one wire signature and ``stack_batches`` /
+    ``pack_ragged_group`` turn them into the one ``[M, rung, ...]`` tenant
+    wire; where they differ (a lopsided key: one tenant of ``--tenantKey
+    lang`` with over 62.5% of a batch) M−1 parts share the lower rung's
+    signature and ``stack_two_rungs`` makes the two-member wire. What the
+    device then works on is Σ rungs rows, not M·B and not M times the
+    fullest's rung: under a uniform key every batch takes the first rung
+    for all (four parts of 640 rows for a batch of 2,048; PERF.md §6, PR
+    36), under the lopsided one 2,048 + 3·640 (PR 49). Token width /
+    ``row_len`` / units dtype are the parent's. At the top rung a part of a
+    ONE-rung split has the parent's own shape, and a tenant that got every
+    row gets the parent back byte for byte; the members of a TWO-rung split
+    size their units buffers from ``_two_rung_units``' coarser bucket of the
+    parent's (one program a pair of rungs, not one per units bucket).
+
+    ONE rung for all parts, the fullest's — as until PR 49 — under
+    ``one_rung`` (``TenantStackModel.split`` asks for it where its wire or
+    program has one shape for all tenants: ``--wirePack group``,
+    ``mapping="vmap"``, a mesh) and under ``rung``, which pins the shape
+    itself (multi-host callers, whose hosts must agree on it, pass the
+    batch's row count)."""
     rows_per = tenant_rows(batch, tenant_ids, num_tenants)
     b = batch.mask.shape[0]
-    need = max(rows.shape[0] for rows in rows_per)
     ragged = isinstance(batch, RaggedUnitBatch)
-    n_parent = most = 0  # the padded wires have no units buffer to fit
+    n_parent = 0  # the padded wires have no units buffer to fit
+    totals = [0] * num_tenants
     if ragged:
         units = np.asarray(batch.units)
         offs = np.asarray(batch.offsets, np.int64)
         lengths = offs[1:] - offs[:-1]
         lens_per = [lengths[rows] for rows in rows_per]
         totals = [int(lens_m.sum()) for lens_m in lens_per]
-        n_parent, most = units.shape[0], max(totals)
+        n_parent = units.shape[0]
     ladder = (int(rung),) if rung else tenant_row_rungs(
         b, num_tenants, row_multiple
     )
-    r = next(
-        (r for r in ladder
-         if r >= need and _rung_units(n_parent, r, b) >= most),
-        0,
-    )
-    if not r:
-        raise ValueError(
-            f"tenant rung {ladder[-1]} cannot hold a part of {need} rows "
-            f"and {most} units"
+    needs = [
+        next(
+            (r for r in ladder
+             if r >= rows.shape[0] and _rung_units(n_parent, r, b) >= total),
+            0,
         )
+        for rows, total in zip(rows_per, totals)
+    ]
+    if not all(needs):
+        raise ValueError(
+            f"tenant rung {ladder[-1]} cannot hold a part of "
+            f"{max(rows.shape[0] for rows in rows_per)} rows and "
+            f"{max(totals)} units"
+        )
+    fullest = int(np.argmax(needs))
+    rest = max(needs[:fullest] + needs[fullest + 1:], default=needs[fullest])
+    rungs = [
+        needs[fullest] if one_rung or m == fullest else rest
+        for m in range(num_tenants)
+    ]
 
-    def padded(arr, rows):
+    def padded(arr, rows, r):
         arr = np.asarray(arr)
         dest = np.zeros((r,) + arr.shape[1:], arr.dtype)
         dest[: rows.shape[0]] = arr[rows]
@@ -1322,9 +1410,11 @@ def split_batch_tenants(
 
     if not ragged:
         return [
-            type(batch)(*(padded(arr, rows) for arr in batch))
-            for rows in rows_per
+            type(batch)(*(padded(arr, rows, r) for arr in batch))
+            for rows, r in zip(rows_per, rungs)
         ]
+    if len(set(rungs)) > 1:
+        n_parent = _two_rung_units(n_parent)
     # every unit's tenant (-1: a row the mask leaves out): a tenant's units
     # are then one ordered selection of the parent's, its rows' relative
     # order kept
@@ -1334,15 +1424,17 @@ def split_batch_tenants(
     )
     live = units[offs[0] : offs[-1]]
     out = []
-    for m, (rows, lens_m, total) in enumerate(zip(rows_per, lens_per, totals)):
+    for m, (rows, lens_m, total, r) in enumerate(
+        zip(rows_per, lens_per, totals, rungs)
+    ):
         units_m = np.zeros((_rung_units(n_parent, r, b),), units.dtype)
         units_m[:total] = live[unit_ids == m]
         offs_m = np.full((r + 1,), total, np.int32)
         offs_m[0] = 0
         np.cumsum(lens_m, out=offs_m[1 : rows.shape[0] + 1])
         out.append(RaggedUnitBatch(
-            units_m, offs_m, padded(batch.numeric, rows),
-            padded(batch.label, rows), padded(batch.mask, rows),
+            units_m, offs_m, padded(batch.numeric, rows, r),
+            padded(batch.label, rows, r), padded(batch.mask, rows, r),
             row_len=batch.row_len, num_shards=1,
         ))
     return out
@@ -1352,10 +1444,11 @@ def stack_batches(batches):
     """K same-shape batches → one batch whose arrays carry a leading [K]
     axis — the stacked tenant wire (``--wirePack stacked``: K = M tenants,
     one dispatch maps the step over the axis, parallel/tenants.py). All
-    batches must share type, shapes, and dtypes (the tenant split pads
+    batches must share type, shapes, and dtypes (an even tenant split pads
     every part to one row rung; ragged parts additionally share that rung's
-    units buffer), so the wire is ``[K, rung, ...]``: what it uploads and
-    what the mapped step works on follow the rows the fullest tenant got."""
+    units buffer), so the wire is ``[K, rung, ...]``. A lopsided split's
+    parts have TWO rungs and go through ``stack_two_rungs``, which stacks
+    each rung's parts with this."""
     first = batches[0]
     for b in batches[1:]:
         if type(b) is not type(first):
@@ -1376,6 +1469,24 @@ def stack_batches(batches):
             num_shards=first.num_shards,
         )
     return type(first)(*(np.stack(arrs) for arrs in zip(*batches)))
+
+
+def stack_two_rungs(parts):
+    """The M parts of a lopsided tenant split (``split_batch_tenants``: the
+    fullest tenant's at its rung, the others at theirs) → the
+    ``TwoRungWire``: ``[1, r_full, ...]`` and ``[M−1, r_rest, ...]`` stacked
+    members plus the tenant ids of both, so what is uploaded and what the
+    device works on is r_full + (M−1)·r_rest rows where one rung for all
+    was M·r_full (2,048 + 3·640 against 4·2,048 for a batch of 2,048 with
+    one tenant over 62.5% of it)."""
+    rows = [p.mask.shape[0] for p in parts]
+    fullest = int(np.argmax(rows))
+    ids = [fullest] + [m for m in range(len(parts)) if m != fullest]
+    return TwoRungWire(
+        stack_batches([parts[fullest]]),
+        stack_batches([parts[m] for m in ids[1:]]),
+        np.asarray(ids, np.int32),
+    )
 
 
 def _bucket(n: int, minimum: int = 8) -> int:

@@ -19,28 +19,37 @@ instead of extra host fetches:
   accumulation order differs on the dense path, so it is an opt-in for
   deployments that trade bit-parity for device parallelism. What a tenant
   COSTS on the device: a step's cost does not depend on its mask, so it is
-  the step at the rows its batch is PADDED to — the tenant row rung
+  the step at the rows its PART is padded to — a tenant row rung
   (``features/batch.tenant_row_rungs``), read off each batch: the smallest
   of a short ladder (1.25·B/M rounded up to 128 rows, doubling, B) that
-  holds the fullest tenant's rows and units. The jitted program
-  specialises on the wire's shape, so a rung is one more program per units
-  bucket, and a uniform key takes the first rung in every batch: at 2^18
-  hashed dims M = 4 runs four Gram steps of 640 rows a batch of 2,048,
-  11.9 ms of device time, and the HOST sets the pace (107.4k tweets/s
-  where one model trains 116.7k; PERF.md §5–§6, PR 36). A lopsided split
-  (``--tenantKey lang``, a dry-tenant stream) reaches a wider rung or the
-  top one, B: four steps of 16.97 ms and 30.1k tweets/s at that width
-  (PR 35). At the reference's 1,004 dims not measured on the chip.
-  ``vmap`` at hashed widths reserved 14.1 GiB of temporaries where
-  ``lax.map`` reserved 4.0 and ran 14.4x slower (the Gram gate's
-  ``switch`` becomes a ``select``; PR 35, at 2,048 rows);
+  holds the part's rows and units. The split takes TWO rungs a batch: the
+  fullest tenant's, and for the other M−1 the one the fullest of THEM
+  needs. The jitted program specialises on the wire's shape, so a pair of
+  rungs is one more program per units bucket, and a uniform key takes the
+  first rung for all in every batch: at 2^18 hashed dims M = 4 runs four
+  Gram steps of 640 rows a batch of 2,048, 11.9 ms of device time, and the
+  HOST sets the pace (107.4k tweets/s where one model trains 116.7k;
+  PERF.md §5–§6, PR 36). A lopsided split (``--tenantKey lang``: one
+  tenant with ~72% of every batch; a dry-tenant stream) pays the wide rung
+  ONCE — the fullest tenant's step at 2,048 rows and the map over three
+  parts of 640, both in the ONE program (``_two_rungs``; PERF.md §6, PR 49
+  has what that reads on the chip) — where until PR 49 every part took
+  the fullest's rung: four steps of 16.97 ms, 30.1k tweets/s (PR 35, PR
+  42). A mesh, ``--wirePack group``, ``mapping="vmap"`` and a pinned rung
+  (the multi-host fleet) keep ONE rung for all M parts, the fullest's. At
+  the reference's 1,004 dims not measured on the chip. ``vmap`` at hashed
+  widths reserved 14.1 GiB of temporaries where ``lax.map`` reserved 4.0
+  and ran 14.4x slower (the Gram gate's ``switch`` becomes a ``select``;
+  PR 35, at 2,048 rows);
 - **the wire** is shared: rows route to tenants on the host by a cheap
   deterministic key (``features/batch.tenant_route_keys``), split into M
-  same-signature batches of the row rung's shape (dry tenants =
-  all-padding, the lockstep invariant), and ship as ONE M-tenant wire —
-  ``stack_batches``
-  (``--wirePack stacked``) or the coalesced one-buffer
-  ``pack_ragged_group`` (``--wirePack group``);
+  batches of their row rungs' shapes (dry tenants = all-padding, the
+  lockstep invariant), and ship as ONE M-tenant wire in one dispatch —
+  ``stack_batches`` where the parts share a rung, the two-member
+  ``TwoRungWire`` (``stack_two_rungs``: ``[1, r_full, ...]`` +
+  ``[M−1, r_rest, ...]`` + the tenant ids, a traced value) where the fullest
+  takes a wider one (``--wirePack stacked``), or the coalesced one-buffer
+  ``pack_ragged_group`` (``--wirePack group``: one rung);
 - **the fetch** is one ``jax.device_get`` of the ``[M, ...]`` StepOutput
   through the existing FetchPipeline — fetch count per tick is ONE
   regardless of M (asserted by the counting tests).
@@ -80,11 +89,13 @@ from ..features.batch import (
     NUM_NUMBER_FEATURES,
     PackedBatch,
     RaggedUnitBatch,
+    TwoRungWire,
     gather_tenant_predictions,
     pack_batch,
     pack_ragged_group,
     split_batch_tenants,
     stack_batches,
+    stack_two_rungs,
     tenant_route_keys,
     unpack_batch,
     wire_nbytes,
@@ -194,16 +205,19 @@ class TenantStackModel:
 
     ``step(batch)`` accepts an ORDINARY featurized host batch: it routes the
     rows (``tenant_route_keys`` → ``split_batch_tenants``), builds the
-    stacked/coalesced tenant wire, and runs the one mapped jit program;
-    the returned StepOutput carries ``[M]``-leading leaves (``[M, R]``
-    predictions in per-tenant row order, R the row rung the split took for
-    this batch: the wire, the program's row dimension and so a tenant's
-    device cost follow the rows the fullest tenant got, not the whole
-    batch — ``route_ids`` re-derives the original-row permutation on the
-    host). A pre-routed wire (a stacked
-    batch from ``prepare_wire`` or a PackedBatch from ``pack_for_wire``)
-    passes straight through — the pack happens once, at the model boundary,
-    exactly like the single-tenant packed wire."""
+    tenant wire, and runs the one jit program; the returned StepOutput
+    carries ``[M]``-leading leaves in TENANT order (``[M, R]`` predictions
+    in per-tenant row order, R the FULLEST part's row rung for this batch —
+    ``route_ids`` re-derives the original-row permutation on the host). A
+    tenant's device cost follows the rung ITS part took: the split reads
+    two off each batch, the fullest tenant's and the other M−1's; where
+    they differ the wire is a ``TwoRungWire`` and the program runs
+    ``_two_rungs`` (one step at the wide rung, the map over the narrow
+    ones; one dispatch and one fetch all the same). A pre-routed wire (a
+    stacked batch or a ``TwoRungWire`` from ``prepare_wire``, a PackedBatch
+    from ``pack_for_wire``) passes straight through — the pack happens
+    once, at the model boundary, exactly like the single-tenant packed
+    wire."""
 
     accepts_packed = True
 
@@ -282,9 +296,12 @@ class TenantStackModel:
         # unchanged. Structural knobs (num_iterations, miniBatchFraction,
         # convergenceTol) stay shared — they shape the compiled program.
         def _vec(v, default):
+            # made on the host: ``jnp.asarray(list, dtype)`` compiles a
+            # ``convert_element_type`` of its own at every process start
+            # (0.55 s on the v5e's host; PERF.md §6, PR 49)
             if v is None:
-                return jnp.full((num_tenants,), default, dtype)
-            v = jnp.asarray(v, dtype)
+                v = np.full((num_tenants,), default)
+            v = jnp.asarray(np.asarray(v, dtype))
             if v.shape != (num_tenants,):
                 raise ValueError(
                     f"per-tenant hyperparam needs shape ({num_tenants},), "
@@ -330,8 +347,11 @@ class TenantStackModel:
 
         self._one = one
         self._shared = shared
-        self._weights = jnp.zeros((num_tenants, f_total), dtype)
+        # host zeros, uploaded: ``jnp.zeros`` compiles a program to make them
+        self._weights = jnp.asarray(np.zeros((num_tenants, f_total), dtype))
         self._progs: dict = {}
+        # under --trace: each in-flight batch's M rungs (``take_buckets``)
+        self._buckets: dict = {}
         if mesh is not None:
             self._init_mesh(mesh)
 
@@ -407,6 +427,8 @@ class TenantStackModel:
 
     # -- the one mapped program ---------------------------------------------
     def _mapped(self, weights, hyper, batch):
+        if isinstance(batch, TwoRungWire):
+            return self._two_rungs(weights, hyper, batch)
         if isinstance(batch, PackedBatch):
             # coalesced tenant wire (pack_ragged_group): rebuild the
             # stacked [M, ...] leaves in-program — zero-copy bitcasts
@@ -423,6 +445,47 @@ class TenantStackModel:
             return lax.map(
                 lambda args: self._one(*args), (weights, hyper, batch)
             )
+
+    def _two_rungs(self, weights, hyper, wire):
+        """A lopsided split's two members in the ONE program: the fullest
+        tenant's step on its part at its rung, then ``lax.map`` of the step
+        over the others' parts at theirs — each tenant's weights and
+        hyper-parameters gathered by its TRACED id (which tenant is fullest
+        is a value of the wire, not a shape of the program), both halves
+        under ``tenant_map`` with the step's nine stage scopes inside. The
+        new ``[M, F+4]`` weights and every ``[M, ...]`` leaf of the ONE
+        StepOutput come back in TENANT order (one gather by the inverse of
+        ``wire.ids``: no scatter into the donated state), the rest's
+        predictions zero-padded to the fullest's rung, so
+        everything downstream of the fetch reads what the one-rung program
+        gives it. Every tenant's step is still ``make_sgd_train_step``'s own
+        program at its part's shape (the parity law)."""
+        first, others = wire.ids[0], wire.ids[1:]
+        order = jnp.argsort(wire.ids)  # tenant m's row in full ++ rest
+        with jax.named_scope("tenant_map"):
+            full = self._one(
+                weights[first],
+                {k: v[first] for k, v in hyper.items()},
+                jax.tree_util.tree_map(lambda a: a[0], wire.full),
+            )
+            rest = lax.map(
+                lambda args: self._one(*args),
+                (
+                    weights[others],
+                    {k: v[others] for k, v in hyper.items()},
+                    wire.rest,
+                ),
+            )
+
+        def in_tenant_order(a, b):
+            # a: the fullest tenant's leaf; b: the others', [M-1, ...],
+            # padded up to a's shape (predictions: r_rest → r_full rows)
+            pad = [(0, 0)] + [
+                (0, wide - narrow) for wide, narrow in zip(a.shape, b.shape[1:])
+            ]
+            return jnp.concatenate([a[None], jnp.pad(b, pad)])[order]
+
+        return jax.tree_util.tree_map(in_tenant_order, full, rest)
 
     def _prog_for(self, batch_cls) -> Callable:
         fn = self._progs.get(batch_cls)
@@ -454,17 +517,25 @@ class TenantStackModel:
         return tenant_route_keys(batch, self.num_tenants, self.tenant_key)
 
     def split(self, batch, rung: int = 0):
-        """Route + split into the M same-signature tenant batches, padded
-        to the row rung the batch calls for (a multiple of the mesh's data
-        axis where there is one), or to ``rung`` when pinned."""
+        """Route + split into the M tenant batches, padded to the row rungs
+        the batch calls for — the fullest tenant's part to its own, the
+        others to theirs (``split_batch_tenants``) — or all to ``rung`` when
+        pinned. ONE rung for all, the fullest's, where this plane's wire or
+        program has one shape for every tenant: the coalesced group buffer
+        (``--wirePack group``), ``mapping="vmap"``, a mesh (whose rungs are
+        multiples of its data axis)."""
         return split_batch_tenants(
             batch, self.route_ids(batch), self.num_tenants,
             row_multiple=self.num_data if self.mesh is not None else 1,
             rung=rung,
+            one_rung=(
+                self.mesh is not None or self.wire_pack == "group"
+                or self.mapping == "vmap"
+            ),
         )
 
     def _is_tenant_wire(self, batch) -> bool:
-        if isinstance(batch, PackedBatch):
+        if isinstance(batch, (PackedBatch, TwoRungWire)):
             return True
         mask = getattr(batch, "mask", None)
         return mask is not None and getattr(mask, "ndim", 1) == 2
@@ -490,15 +561,37 @@ class TenantStackModel:
             return batch
         tr = _trace.get()
         with tr.span("tenant_split", tenants=self.num_tenants) as sp:
-            wire = self.prepare_wire_from_parts(self.split(batch))
+            parts = self.split(batch)
+            wire = self.prepare_wire_from_parts(parts)
             if tr.enabled:
-                sp.add(rows=int(batch.num_valid), bytes=wire_nbytes(wire))
+                buckets = [int(p.mask.shape[0]) for p in parts]
+                sp.add(rows=int(batch.num_valid), bytes=wire_nbytes(wire),
+                       rungs=(max(buckets), min(buckets)))
+                # for this batch's ``tenant_rows`` instant, which is written
+                # when its fetch delivers (apps/common.attach_pipeline);
+                # far more kept than are ever in flight: deliveries were
+                # skipped, and what they left goes
+                if len(self._buckets) > 64:
+                    self._buckets.clear()
+                self._buckets[_trace.current_batch()] = buckets
         return wire
+
+    def take_buckets(self, seq) -> "list[int] | None":
+        """The M row rungs, in tenant order, of the split that made batch
+        ``seq``'s wire — kept from ``prepare_wire`` while tracing is on, and
+        given out ONCE, at the batch's delivery. None where the wire was not
+        made here (a pre-routed wire, the multi-host fleet): the caller
+        reads the fetched leaf's one rung."""
+        return self._buckets.pop(seq, None)
 
     def prepare_wire_from_parts(self, parts):
         """The wire-layout half of ``prepare_wire`` for callers that route
         themselves (tests, custom routers): M same-signature per-tenant
-        batches → the stacked/coalesced tenant wire."""
+        batches → the stacked/coalesced tenant wire; the parts of a
+        lopsided split, which have two row rungs → the two-member
+        ``TwoRungWire`` (``features/batch.stack_two_rungs``)."""
+        if len({p.mask.shape[0] for p in parts}) > 1:
+            return stack_two_rungs(parts)
         if self.mesh is not None:
             # ragged parts shard-align to the data axis BEFORE stacking
             # (alignment is a flat-batch operation)

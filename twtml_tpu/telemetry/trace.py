@@ -90,16 +90,21 @@ device plane under the ``collective`` scope (parallel/sharding.py).
 
 Tenant plane (``--tenants M``, PR 35): ``tenant_split`` spans the host's
 route key, M-way split and stack or pack of the tenant wire (``rows``,
-``tenants``, ``bytes``; inside ``wire_pack``, parallel/tenants.py), and every
+``tenants``, ``bytes`` and, PR 49, ``rungs`` = the fullest part's row rung and
+the other parts'; inside ``wire_pack``, parallel/tenants.py), and every
 delivered batch leaves one ``tenant_rows`` instant with the M valid-row
 counts of its ONE stacked fetch (``rows``), the row rung the split padded
-every tenant's part to for that batch (``bucket``, PR 36: read off the
-fetched ``[M, bucket]`` predictions leaf) and ``pad_rows`` = M·``bucket`` −
-their sum, and under ``--modelWatch on`` each part's OWN Gram plane
+the FULLEST tenant's part to for that batch (``bucket``, PR 36: read off the
+fetched ``[M, bucket]`` predictions leaf), the M parts' rungs in tenant order
+(``buckets``, PR 49: kept by the plane from the batch's split to its
+delivery; under an even key all equal ``bucket``, under a lopsided one the
+fullest's and a lower one for the rest) and ``pad_rows`` = Σ ``buckets`` −
+Σ ``rows``, and under ``--modelWatch on`` each part's OWN Gram plane
 (``planes``, PR 42: off the stacked quality leaf of the same fetch; a
 near-dry part of short rows takes s8 beside bf16 ones)
-(apps/common.attach_pipeline); the mapped device program sits
-under the ``tenant_map`` scope. None of the three exists on the single-model plane.
+(apps/common.attach_pipeline); the device program sits under the
+``tenant_map`` scope, both halves of a two-rung one. None of the three
+exists on the single-model plane.
 
 Event sink (r8): the crash flight recorder (telemetry/blackbox.py) attaches
 via ``set_event_sink`` so recent spans ride its bounded in-memory ring —
