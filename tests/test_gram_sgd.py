@@ -883,3 +883,78 @@ def test_count_plane_contracts_the_built_shape_like_the_2d_form(
     assert u.shape == (rows or b,) and delta.shape == (f_text,)
     assert _rel_l1(u, jnp.sum(panel * w[None, :], axis=1)) <= 1e-6
     assert _rel_l1(delta, jnp.sum(panel * alpha[:, None], axis=0)) <= 1e-6
+
+
+# ---------------------------------------------------------------------------
+# PR 50: ``CountPlane.dot`` / ``.tdot`` take a leading MODEL axis (M arms on
+# the same rows read C once a batch, models/sgd.py ``arms``). What they do
+# follows from the operand's ``ndim`` and nothing else: a 1-D operand traces
+# to the expression every other step has always run.
+
+def _plane_of(plane: str, batch, f_text: int):
+    """The plane's C as its builder writes it, as a ``CountPlane`` on one
+    device (``left`` the identity)."""
+    idx, val = jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val)
+    c = {
+        "exact": lambda: densify_text(idx, val, f_text),
+        "bf16": lambda: gram_ops.onehot_counts(idx, val, f_text),
+        "s8": lambda: gram_ops.onehot_counts_int8(idx, val, f_text),
+    }[plane]()
+    return c, lambda c: gram_ops.CountPlane(c, lambda x: x, f_text)
+
+
+def _parents_dot(c, w):
+    """``CountPlane.dot`` as PR 30 left it, written out."""
+    shape = c.shape[1:]
+    w = jnp.pad(w, (0, int(np.prod(shape)) - w.shape[0])).reshape(shape)
+    return jnp.sum(c.astype(jnp.float32) * w[None],
+                   axis=tuple(range(1, c.ndim)))
+
+
+def _parents_tdot(f_text):
+    """``CountPlane.tdot`` as PR 30 left it, written out."""
+    def tdot(c, alpha):
+        panel = c.astype(jnp.float32)
+        delta = jnp.sum(
+            panel * jnp.expand_dims(alpha, tuple(range(1, c.ndim))), axis=0)
+        return delta.reshape(-1)[:f_text]
+    return tdot
+
+
+@pytest.mark.parametrize("which", ["dot", "tdot"])
+@pytest.mark.parametrize("plane", ["exact", "bf16", "s8"])
+def test_count_plane_contracts_all_models_at_once_like_each_alone(
+    plane, which
+):
+    """The ``[M, …]`` call against the M 1-D calls, each compiled as a
+    program of its own: BIT FOR BIT on the CPU backend, on every plane (the
+    2-D form is M sibling reductions, each the 1-D expression, so nothing
+    but the compiler's fusion could reorder a sum; here it does not). And
+    the 1-D call's jaxpr is the parent's expression's, character for
+    character: the six cells that run one model keep their programs."""
+    f_text = 1000  # < k_hi·k_lo: w zero-padded going in, delta cropped
+    rng = np.random.default_rng(5000 + _PLANE_INDEX[plane])
+    batch = _contraction_batch(rng, plane, f_text=f_text)
+    c, plane_of = _plane_of(plane, batch, f_text)
+    m, b = 4, c.shape[0]
+    operand = jnp.asarray(rng.normal(
+        size=(m, f_text if which == "dot" else b)).astype(np.float32))
+
+    def contract(c, x):
+        return getattr(plane_of(c), which)(x)
+
+    together = jax.jit(contract)(c, operand)
+    assert together.dtype == jnp.float32
+    assert together.shape == (m, b if which == "dot" else f_text)
+    alone = jax.jit(contract)
+    for k in range(m):
+        assert np.asarray(together[k]).tobytes() == np.asarray(
+            alone(c, operand[k])).tobytes(), (plane, which, k)
+    parents = _parents_dot if which == "dot" else _parents_tdot(f_text)
+    assert str(jax.make_jaxpr(contract)(c, operand[0])) == str(
+        jax.make_jaxpr(parents)(c, operand[0]))
+    # and it is the contraction: against the f64 sum of the same products
+    flat = np.asarray(c, np.float64).reshape(b, -1)[:, :f_text]
+    x = np.asarray(operand, np.float64)
+    want = x @ flat.T if which == "dot" else x @ flat
+    assert _rel_l1(together, want) <= 1e-6

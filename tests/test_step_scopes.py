@@ -662,16 +662,59 @@ def test_compiled_two_rung_program_reserves_one_top_rung_step(topo):
 
 # ---------------------------------------------------------------------------
 # PR 47: ``--tenantKey all`` — M arms on the SAME rows share the count matrix
-# and G; only ``u = C·w_m``, the dual loop and ``Cᵀα_m`` are mapped.
+# and G. PR 50: they share every READ of it too — ``u = C·[w_1…w_M]`` and
+# ``Cᵀ·[α_1…α_M]`` are one pass each, outside the map; only the dual loop
+# is mapped.
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z\-]*)\(([^)]*)\)")
+
+
+def _touching(lines, least: int) -> tuple:
+    """``(writers, readers, loops)`` among the instructions of ``lines``
+    (a branch's ``top``: fusions counted as one operation each): those
+    that WRITE an array of at least ``least`` elements, those that take
+    one as an operand, and the ``while``s that carry one. An instruction
+    that only names another's array (``_ALIASES``) is neither."""
+    big, writers, readers, loops = set(), [], [], []
+    for line in lines:
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, result, op, operands = m.groups()
+        holds = any(n >= least for _op, _d, n in _results([line]))
+        if holds:
+            big.add(name)
+        if op == "while":
+            if holds:
+                loops.append(line)
+            continue
+        if op in _ALIASES:
+            continue
+        if holds:
+            writers.append(line)
+        if any(o in big for o in re.findall(r"%([\w.\-]+)", operands)):
+            readers.append(line)
+    return writers, readers, loops
+
 
 def test_compiled_arms_program_builds_c_and_g_once_and_maps_the_rest(topo):
     """The program the TPU's compiler makes for the cell
-    ``hash2e18-grid4-trimmed-280`` (four arms, 2,048 rows, the cells' wire):
-    in every plane's branch ONE count matrix of the plane's type and ONE
-    ``[2048, 2048]`` Gram product, both outside the map's ``while``; inside
-    it no array of C's size is written (an arm READS C twice and copies it
-    never); and the whole of it reserves the single model's temporaries
-    (4,308,146,176 B as compiled) and not M times them."""
+    ``hash2e18-grid4-trimmed-280`` (four arms, 2,048 rows, the cells' wire).
+    In every plane's branch ONE count matrix of the plane's type and ONE
+    ``[2048, 2048]`` Gram product, both outside the map's ``while``; under
+    ``/arm_map/while/body/`` the ``dual_loop`` and NO ``predict``,
+    ``writeback`` or ``gram_*``, and no array of C's size carried into any
+    loop: C is read OUTSIDE loops only, by at most THREE operations of its
+    size — on the fast planes two, the G product and the ONE write-back
+    pass for all four arms, because the fusion that WRITES C also yields
+    the four ``f32[2048]`` ``u_m = C·w_m`` (the predict contraction of every
+    arm in the build's epilogue: no read of C, as in the single model's
+    step); on the exact plane, whose build is a scatter, one pass more for
+    the four ``u_m`` together. No array of C's size is written but C. No
+    op-name holds both ``arm_map`` and ``predict`` / ``writeback``. And the
+    whole of it reserves the single model's temporaries (4,308,146,176 B as
+    compiled) and not M times them."""
     from jax.sharding import SingleDeviceSharding
 
     from twtml_tpu.parallel import TenantStackModel
@@ -692,21 +735,40 @@ def test_compiled_arms_program_builds_c_and_g_once_and_maps_the_rest(topo):
         _ragged_shapes(shape),
     ).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert 4 * 2**30 <= temp < 4.1 * 2**30
+    assert 4 * 2**30 <= temp < 4.1 * 2**30     # 4,307,057,664 B as compiled
+    text = compiled.as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    both = [n for n in names if "arm_map" in n.split("/")
+            and {"predict", "writeback"} & set(n.split("/"))]
+    assert not both, both
     full = ROWS * F_TEXT
-    for plane, took in zip(("f32", "bf16", "s8"),
-                           plane_branches(compiled.as_text())):
-        mapped = [line for line in took["all"] if "/arm_map/" in line]
+    for plane, took in zip(("f32", "bf16", "s8"), plane_branches(text)):
+        mapped = [line for line in took["all"]
+                  if "/arm_map/while/body/" in line]
         once = [line for line in took["all"] if "/arm_map/" not in line]
         grams = [line for line in once if "/gram_matmul/" in line
                  and re.search(r" convolution\(", line)
                  and f"f32[{ROWS},{ROWS}]" in line]
         assert grams, plane
-        assert not any("/gram_matmul/" in line or "/gram_count/" in line
-                       for line in mapped), plane
-        assert any(f"/{scope}/" in line for line in mapped
-                   for scope in ("predict", "dual_loop", "writeback")), plane
-        big = [(op, d, n) for op, d, n in _results(
-            [line for line in took["top"] if "/arm_map/" in line])
-            if n >= full and op not in _ALIASES and op != "while"]
-        assert not big, (plane, big)
+        assert any("/dual_loop/" in line for line in mapped), plane
+        assert not any(f"/{scope}/" in line for line in mapped for scope in (
+            "predict", "writeback", "gram_matmul", "gram_count")), plane
+        assert any("/predict/" in line for line in once), plane
+        assert any("/writeback/" in line for line in once), plane
+        writers, readers, loops = _touching(took["top"], full)
+        assert not loops, (plane, loops)
+        assert not any("/arm_map/" in line for line in writers + readers)
+        if plane == "f32":
+            # the scatter build (its flat result and the ``[B, F]`` view),
+            # then u for all arms, G, the write-back: one pass each
+            stages = [re.search(r"branch_0_fun/(\w+)/", line).group(1)
+                      for line in readers if "op_name" in line]
+            assert sorted(s for s in stages if s != "gram_count") == [
+                "gram_matmul", "predict", "writeback"], (plane, readers)
+            continue
+        (build,) = writers
+        assert "/gram_count/" in build, plane
+        assert _results([build]).count(("fusion", "f32", ROWS)) == m, build
+        assert len(readers) == 2, (plane, readers)
+        assert sum("/gram_matmul/" in line for line in readers) == 1
+        assert sum("/writeback/" in line for line in readers) == 1

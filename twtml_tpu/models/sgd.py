@@ -328,15 +328,22 @@ def make_sgd_train_step(
     ``weights`` is ``[M, F+4]``, ``step_size`` and ``l2_reg`` are ``[M]``
     (arm m's own recipe), and every leaf of the output leads with M. What
     does not depend on the model runs ONCE — unpack, re-pad, hash and, in
-    the Gram basis, the count matrix C and G = C·Cᵀ, which are the step's
-    cost — and the rest is ``lax.map``ped over the arms under the scope
-    ``arm_map``, inside the plane's branch: ``u = C·w_m``, the dual loop
-    under arm m's step size and L2, ``Cᵀα_m``, then the stats. Each arm
-    runs the single model's own contractions on the single model's own C,
-    so arm m is bit-identical to this step built without ``arms`` under
-    arm m's recipe (tests/test_tenant_grid.py). Outside the Gram basis the
-    featurized batch is shared and the whole loop is mapped. One device
-    only: there is no ``axis_name`` form.
+    the Gram basis, the count matrix C and G = C·Cᵀ — and so does every
+    READ of C: ``u = C·[w_1…w_M]`` comes out of the count build for all
+    arms at once (``CountPlane.dot`` on ``[M, F]``: the build's epilogue,
+    as the single model's) and ``Cᵀ·[α_1…α_M]`` is ONE pass
+    (``CountPlane.tdot`` on ``[M, B]``), both at the top level of the
+    plane's branch. Only the dual loop is ``lax.map``ped over the arms,
+    under the scope ``arm_map`` (G shared, arm m's step size and L2, its
+    own converged-freeze), and after the switch the quality vector; the
+    arms' batch stats are mapped under ``predict`` alone. Arm m IS this
+    step built without ``arms`` under arm m's recipe — every sum it runs is
+    the single model's own expression, a sibling of the other arms' — to
+    float32 rounding in the Gram basis (where a compiler may fuse siblings
+    and order a sum otherwise: PARITY.md, "the arm law") and bit for bit
+    outside it, where the featurized batch is shared and the whole loop is
+    mapped (tests/test_tenant_grid.py). One device only: there is no
+    ``axis_name`` form.
     """
     if arms and axis_name:
         raise ValueError(
@@ -378,10 +385,14 @@ def make_sgd_train_step(
         contraction 1/shards and gives the replicated-weights output the
         statically-invariant form shard_map requires).
 
-        With ``arms`` C and G are built ONCE and the rest — ``u = C·w_m``,
-        the dual loop under arm m's own step size and L2, ``Cᵀα_m`` — is
-        mapped over the ``[M, F+4]`` weights under the scope ``arm_map``
-        (``lax.map``: each arm runs the single model's own contractions).
+        With ``arms`` (``weights`` ``[M, F+4]``) C and G are built ONCE and
+        C is READ once by each contraction: ``u`` for all arms from one
+        expression at the branch's top level (scope ``predict``: the count
+        build's epilogue takes it), the dual loop mapped over
+        ``(w_m, η_m, λ_m, u_m)`` under the scope ``arm_map`` (``lax.map``),
+        then the write-back for all arms in one pass (scope ``writeback``).
+        Nothing under ``arm_map`` is also under ``predict`` or
+        ``writeback``: a reader of the benchmark counts on it.
 
         ``row_args`` are GLOBAL (the caller all-gathers the batch under a
         data axis); ``local_numeric`` is this shard's rows. Returns
@@ -390,22 +401,11 @@ def make_sgd_train_step(
         dtype = weights.dtype
         rows = local_numeric.shape[0] if axis_name else 0
 
-        def split(w):
-            return w[:f_text], w[f_text:]
-
-        whole = None if arms else split(weights)  # an arm splits its own
+        # the single model's halves, sliced outside the switch (the arms'
+        # ``[M, F+4]`` stack is sliced in the branch, under ``predict``)
+        whole = None if arms else (weights[:f_text], weights[f_text:])
 
         def dual_basis(counts):
-            def margin(w_text, w_num):
-                with jax.named_scope("predict"):
-                    # this shard's rows of u = Z·W_prev: C·w rides the count
-                    # build's epilogue (ops/gram.CountPlane.dot)
-                    raw = (counts.dot(w_text) + local_numeric @ w_num).astype(dtype)
-                    u = raw
-                    if axis_name:
-                        u = lax.all_gather(raw, axis_name, axis=0, tiled=True)
-                return raw, u
-
             def gram():
                 g_text = counts.gram()
                 if axis_name:
@@ -417,7 +417,7 @@ def make_sgd_train_step(
                     )
                 return add_numeric_block(g_text, numeric, dtype)
 
-            def apply(w, w_text, w_num, u, g, eta, lam):
+            def dual(w, u, g, eta, lam):
                 with jax.named_scope("dual_loop"):
                     p_prev = jnp.sum(w * w)  # its convergence norm
                 # u and G are built in f32 (the accumulation type); the dual
@@ -425,7 +425,7 @@ def make_sgd_train_step(
                 # type-stable for low-precision weights. f64 weights never
                 # reach here (the auto gate is f32-only — the bf16-plane G
                 # build would silently downgrade f64).
-                dual = run_dual_loop(
+                return run_dual_loop(
                     u=u,
                     g=g,
                     labels=labels,
@@ -440,33 +440,60 @@ def make_sgd_train_step(
                     p_prev=p_prev,
                     vary_axis=axis_name,
                 )
-                with jax.named_scope("writeback"):
-                    # W_new = c·W_prev + Zᵀα: the other read of C
-                    c, alpha = dual["c"], dual["alpha"]
-                    if axis_name:  # this shard's rows of α, then one psum each
-                        c, alpha = dual_scale_and_alpha(dual, axis_name, rows)
-                    delta_text = counts.tdot(alpha)
-                    delta_num = local_numeric.T @ alpha
-                    if axis_name:
-                        delta_text = lax.psum(delta_text, axis_name)
-                        delta_num = lax.psum(delta_num, axis_name)
-                    return jnp.concatenate(
-                        [w_text * c + delta_text, w_num * c + delta_num]
-                    ).astype(dtype)
 
             if arms:
                 g = gram()  # ONE count matrix and ONE G for all M arms
+                with jax.named_scope("predict"):
+                    w_text, w_num = weights[:, :f_text], weights[:, f_text:]
+                    # u for ALL arms from one expression at the branch's top
+                    # level: C·[w_1…w_M] rides the count build's epilogue as
+                    # the single model's C·w does; the numeric half is the
+                    # single model's own matvec, an arm
+                    raw = (
+                        counts.dot(w_text)
+                        + jnp.stack([local_numeric @ w for w in w_num])
+                    ).astype(dtype)
 
                 def arm(args):
-                    w, eta, lam = args
-                    parts = split(w)
-                    raw, u = margin(*parts)
-                    return apply(w, *parts, u, g, eta, lam), raw
+                    w, eta, lam, u = args
+                    return dual(w, u, g, eta, lam)
 
                 with jax.named_scope("arm_map"):
-                    return lax.map(arm, (weights, step_size, l2_reg))
-            raw, u = margin(*whole)
-            return apply(weights, *whole, u, gram(), step_size, l2_reg), raw
+                    # each arm's own loop and converged-freeze, G shared
+                    duals = lax.map(arm, (weights, step_size, l2_reg, raw))
+                with jax.named_scope("writeback"):
+                    # W_new = c·W_prev + Zᵀα for all arms: ONE read of C
+                    c, alpha = duals["c"][:, None], duals["alpha"]
+                    delta_text = counts.tdot(alpha)
+                    delta_num = jnp.stack([local_numeric.T @ a for a in alpha])
+                    w_new = jnp.concatenate(
+                        [w_text * c + delta_text, w_num * c + delta_num], axis=1
+                    ).astype(dtype)
+                return w_new, raw
+
+            w_text, w_num = whole
+            with jax.named_scope("predict"):
+                # this shard's rows of u = Z·W_prev: C·w rides the count
+                # build's epilogue (ops/gram.CountPlane.dot)
+                raw = (counts.dot(w_text) + local_numeric @ w_num).astype(dtype)
+                u = raw
+                if axis_name:
+                    u = lax.all_gather(raw, axis_name, axis=0, tiled=True)
+            dual_state = dual(weights, u, gram(), step_size, l2_reg)
+            with jax.named_scope("writeback"):
+                # W_new = c·W_prev + Zᵀα: the other read of C
+                c, alpha = dual_state["c"], dual_state["alpha"]
+                if axis_name:  # this shard's rows of α, then one psum each
+                    c, alpha = dual_scale_and_alpha(dual_state, axis_name, rows)
+                delta_text = counts.tdot(alpha)
+                delta_num = local_numeric.T @ alpha
+                if axis_name:
+                    delta_text = lax.psum(delta_text, axis_name)
+                    delta_num = lax.psum(delta_num, axis_name)
+                w_new = jnp.concatenate(
+                    [w_text * c + delta_text, w_num * c + delta_num]
+                ).astype(dtype)
+            return w_new, raw
 
         (w_new, raw), plane = text_gram(
             token_idx,
@@ -556,6 +583,29 @@ def make_sgd_train_step(
                 )
             w_new, raw, plane = _gram_sgd(weights, row_args, numeric)
 
+        def observe(raw):
+            """One model's reported predictions and the five batch stats
+            of them, from its pre-update raw margin."""
+            preds = prediction_fn(raw)
+            if round_predictions:
+                preds = jnp_round_half_up(preds)
+            return preds, batch_stats(labels, preds, mask, axis_name)
+
+        def quality_of(weights, w_new, raw, preds, gram_plane=None):
+            # the ISSUE-8 side channel against the post-update weights;
+            # None (plane off) keeps the output pytree the HEAD program's
+            if not quality:
+                return None
+            with jax.named_scope("quality"):
+                return quality_vector(
+                    weights, w_new,
+                    residual=residual_fn(raw, labels) * mask,
+                    preds=preds, labels=labels, mask=mask,
+                    numeric=batch.numeric, token_idx=batch.token_idx,
+                    token_val=batch.token_val, gram_plane=gram_plane,
+                    axis_name=axis_name,
+                )
+
         def finish(weights, eta, lam, w_new=None, raw=None):
             """One model's stats with its pre-update weights and, outside
             the Gram basis, its iterations: everything of the step that is
@@ -563,29 +613,13 @@ def make_sgd_train_step(
             with jax.named_scope("predict"):
                 if not gram:
                     raw = _predict_raw(weights, batch, x_dense)
-                preds = prediction_fn(raw)
-                if round_predictions:
-                    preds = jnp_round_half_up(preds)
-                stats = batch_stats(labels, preds, mask, axis_name)
-
-            def _quality(w_new, gram_plane=None):
-                # the ISSUE-8 side channel against the post-update weights;
-                # None (plane off) keeps the output pytree the HEAD program's
-                if not quality:
-                    return None
-                with jax.named_scope("quality"):
-                    return quality_vector(
-                        weights, w_new,
-                        residual=residual_fn(raw, labels) * mask,
-                        preds=preds, labels=labels, mask=mask,
-                        numeric=batch.numeric, token_idx=batch.token_idx,
-                        token_val=batch.token_val, gram_plane=gram_plane,
-                        axis_name=axis_name,
-                    )
+                preds, stats = observe(raw)
 
             if gram:
                 return w_new, StepOutput(
-                    predictions=preds, quality=_quality(w_new, plane), **stats
+                    predictions=preds,
+                    quality=quality_of(weights, w_new, raw, preds, plane),
+                    **stats,
                 )
 
             # ---- numIterations of mini-batch SGD (the scatter loop) -----
@@ -610,17 +644,37 @@ def make_sgd_train_step(
                 grad_and_count=grad_and_count,
             )
             return w_final, StepOutput(
-                predictions=preds, quality=_quality(w_final), **stats
+                predictions=preds,
+                quality=quality_of(weights, w_final, raw, preds),
+                **stats,
             )
 
         if not arms:
             return finish(weights, step_size, l2_reg, w_new, raw)
-        # M arms on the SAME rows: the featurized batch (and, in the Gram
-        # basis, C and G) above is shared, what is left is mapped, each arm
-        # through the single model's own program (lax.map: the parity law)
-        per_arm = (weights, step_size, l2_reg) + ((w_new, raw) if gram else ())
+        # M arms on the SAME rows: the featurized batch above is shared
+        if gram:
+            # C, G, u = C·[w_1…w_M] and the write-back came out of the
+            # plane's branch for all arms at once (_gram_sgd). An arm's
+            # stats are the single model's own reductions of its row of u,
+            # mapped but NOT under ``arm_map`` (a reader of the benchmark
+            # takes ``arm_map`` + ``predict`` for a pass over C); the
+            # quality vector, per arm, stays there
+            with jax.named_scope("predict"):
+                preds, stats = lax.map(observe, raw)
+            vectors = None
+            if quality:
+                with jax.named_scope("arm_map"):
+                    vectors = lax.map(
+                        lambda args: quality_of(*args, plane),
+                        (weights, w_new, raw, preds),
+                    )
+            return w_new, StepOutput(predictions=preds, quality=vectors, **stats)
+        # outside the Gram basis the whole loop is mapped, each arm through
+        # the single model's own program (lax.map: the parity law)
         with jax.named_scope("arm_map"):
-            return lax.map(lambda args: finish(*args), per_arm)
+            return lax.map(
+                lambda args: finish(*args), (weights, step_size, l2_reg)
+            )
 
     return train_step
 

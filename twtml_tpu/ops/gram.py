@@ -405,7 +405,19 @@ class CountPlane:
     (module docstring): C's element is converted in registers, ``w`` and
     ``alpha`` are never rounded, the accumulation is f32. Neither carries a
     stage name: the caller scopes ``dot`` under ``predict`` and ``tdot``
-    under ``writeback`` (models/sgd.py ``STAGE_SCOPES``)."""
+    under ``writeback`` (models/sgd.py ``STAGE_SCOPES``).
+
+    Both take a leading MODEL axis (``w`` ``[M, F]`` → ``[M, rows]``,
+    ``alpha`` ``[M, rows]`` → ``[M, F]``: M models on the same rows,
+    models/sgd.py ``arms``), decided by the operand's ``ndim`` and nothing
+    else; a 1-D operand traces to the expression it always did. The 2-D
+    form is M SIBLING reductions, each the 1-D one, stacked — not one
+    ``[M, …]`` reduction. The TPU's compiler fuses the siblings into one
+    operation that reads C once (for ``dot`` the epilogue of the product
+    that writes C: no read at all), whereas for one reduction with M
+    leading it writes an f32 copy of C beside the plane's own (compiled for
+    a v5e in PR 50: 2 GiB a batch at 2^18 dims, what PR 28 found of any
+    f32 C); and each model's sum stays the single model's own."""
 
     def __init__(self, c, left, f_text: int, **product):
         self.c = c  # every row, as built: the G product's right operand
@@ -419,7 +431,12 @@ class CountPlane:
         for this shard's rows (a partial over its features under a
         feature axis; the caller psums). Reduced over ALL rows of C and
         then sliced, a ``[B]`` vector: the reduction can then sit in the
-        epilogue of the product that writes C, and costs no read of it."""
+        epilogue of the product that writes C, and costs no read of it.
+
+        ``w`` of shape ``[M, F]`` gives ``[M, rows]`` (class docstring):
+        all M reductions ride that one epilogue."""
+        if w.ndim == 2:
+            return jnp.stack([self.dot(w_m) for w_m in w])
         shape = self.c.shape[1:]
         w = jnp.pad(w, (0, math.prod(shape) - w.shape[0])).reshape(shape)
         u = jnp.sum(self.c.astype(jnp.float32) * w[None], axis=self._features)
@@ -429,7 +446,12 @@ class CountPlane:
         """``rows(C)ᵀ·alpha`` → ``[F]``: the text half of ``Zᵀα`` from this
         shard's rows (the caller psums over the row shards). Duplicate
         (row, feature) occurrences are already summed in C, as the
-        ``sparse_grad_text`` scatter summed them."""
+        ``sparse_grad_text`` scatter summed them.
+
+        ``alpha`` of shape ``[M, rows]`` gives ``[M, F]`` (class docstring):
+        ONE pass over the panel for all M."""
+        if alpha.ndim == 2:
+            return jnp.stack([self.tdot(a_m) for a_m in alpha])
         panel = self._left(self.c).astype(jnp.float32)
         delta = jnp.sum(panel * jnp.expand_dims(alpha, self._features), axis=0)
         return delta.reshape(-1)[: self._f_text]

@@ -60,10 +60,12 @@ WITHOUT the partition: every tenant trains on every row. Nothing is routed,
 split or stacked — the batch ships once as the single-model wire — and the
 program is not the map of the whole step either: the arms share their rows,
 so they share the count matrix C and G = C·Cᵀ, which are the step's cost,
-and ``models/sgd.make_sgd_train_step(arms=True)`` builds both once a batch
-and maps only ``u = C·w_m``, the dual loop and ``Cᵀα_m`` over the arms
-(scope ``arm_map``, ``lax.map``: arm m bit-identical to the single model
-under arm m's recipe). State, fetch, checkpoint and frames are the plane's:
+and ``models/sgd.make_sgd_train_step(arms=True)`` builds both once a batch,
+reads C once for all arms in each of its two contractions (``C·[w_1…w_M]``
+out of the count build, ``Cᵀ·[α_1…α_M]`` in one pass) and maps only the dual
+loop over the arms (scope ``arm_map``, ``lax.map``: arm m is the single
+model under arm m's recipe, to float32 rounding in the Gram basis and bit
+for bit outside it). State, fetch, checkpoint and frames are the plane's:
 ``[M, F+4]`` weights, one ``[M, ...]`` StepOutput. One device, the default
 mapping and the stacked wire only (refused otherwise, with the reason).
 
@@ -339,7 +341,7 @@ class TenantStackModel:
 
         def shared(weights, hyper, batch):
             # --tenantKey all: ONE step over the batch every arm sees, the
-            # per-arm half mapped inside it (models/sgd.py ``arms``)
+            # arms' dual loops mapped inside it (models/sgd.py ``arms``)
             return make_sgd_train_step(
                 step_size=hyper["step_size"], l2_reg=hyper["l2_reg"],
                 arms=True, **step_kw,
