@@ -259,7 +259,6 @@ def write_replay_file(path: str, n: int, seed: int) -> int:
     """``n`` seeded synthetic tweets as JSONL (there is no network and
     tests/data holds ten tweets). Returns how many the filter keeps — the
     exact count a run over the file must report."""
-    from tools.bench_suite import _status_json
     from twtml_tpu.features.featurizer import Featurizer
     from twtml_tpu.streaming.sources import SyntheticSource
 
@@ -268,7 +267,7 @@ def write_replay_file(path: str, n: int, seed: int) -> int:
     with open(path, "w", encoding="utf-8") as fh:
         for s in SyntheticSource(total=n, seed=seed, base_ms=NOW_MS).produce():
             kept += bool(feat.filtrate(s))
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
     return kept
 
 

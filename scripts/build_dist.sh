@@ -12,6 +12,6 @@ python tools/jsminify.py twtml_tpu/web/assets/js/api.js \
     twtml_tpu/web/assets/js/index.js twtml_tpu/web/assets/js/chart.js \
     twtml_tpu/web/assets/js/test.js
 zip -qr "target/twtml-tpu-${version}.zip" \
-    twtml_tpu native pyproject.toml README.md LICENSE bench.py \
+    twtml_tpu native pyproject.toml README.md LICENSE \
     -x "*/__pycache__/*" -x "*.so"
 echo "target/twtml-tpu-${version}.zip"

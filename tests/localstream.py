@@ -1,13 +1,12 @@
-"""In-process v1.1-protocol stream server — lets bench config #2
-(twitter_live) MEASURE the real TwitterSource → train path on rigs without
-Twitter credentials or egress (VERDICT r2 #6), instead of skipping.
+"""In-process v1.1-protocol stream server: the live protocol without
+Twitter credentials or egress, for the tests that drive the real
+TwitterSource path (tests/test_twitter_live.py). The benchmark's own feeder
+is benchmark/feeder.py.
 
 Same protocol shape as the reference's endpoint (chunked HTTP/1.1,
 delimited JSON lines, keep-alive blanks — what Twitter4j consumes at
 LinearRegression.scala:44): the client exercises its full native stack
-(OAuth1 signing, chunked decode, line reassembly, Status parse). Results
-against it are tagged {"mode": "local-protocol"} so they are never
-confused with real-Twitter numbers.
+(OAuth1 signing, chunked decode, line reassembly, Status parse).
 """
 
 from __future__ import annotations

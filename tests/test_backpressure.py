@@ -213,14 +213,13 @@ CLOSED = "http://127.0.0.1:9"
 def _write_replay(path, total, seed):
     import json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     with open(path, "w") as fh:
         for s in SyntheticSource(
             total=total, seed=seed, base_ms=1785320000000
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
 
 def test_app_block_policy_trains_every_row(tmp_path):

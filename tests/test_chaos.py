@@ -188,14 +188,13 @@ def test_install_uninstall_roundtrip():
 # -- end-to-end: the guards under chaos --------------------------------------
 
 def _write_replay(path, total, seed):
-    from tools.bench_suite import _status_json
 
     statuses = list(
         SyntheticSource(total=total, seed=seed, base_ms=1785320000000).produce()
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
 
 CLOSED = "http://127.0.0.1:9"  # closed port: fails fast, no DNS

@@ -4,7 +4,7 @@ The smoke's phases take their sizes as arguments so tier-1 can drive the
 same control flow in seconds (real entry points, real HTTP, a CPU
 "reference" that is the same backend here, so deviations are exactly 0);
 ``main()`` itself has no way to pass without a chip, which is asserted
-through a subprocess, like ``--backend tpu`` and ``bench.py`` below.
+through a subprocess, like ``--backend tpu`` and ``benchmark.run`` below.
 """
 
 import json
@@ -116,17 +116,19 @@ def test_backend_tpu_refuses_a_cpu_only_process():
     assert "count:" not in proc.stdout  # it trained nothing
 
 
-def test_bench_exits_nonzero_with_no_metric_when_device_child_fails():
-    # the device child asks for --backend tpu; on a CPU-only process it
-    # fails, and the parent must neither substitute a CPU rate nor print a
-    # zero under the metric's name
-    proc = _run(["bench.py"], TWTML_BENCH_SERVING="0", TWTML_BENCH_WIRE="0")
+def test_benchmark_exits_nonzero_with_no_metric_without_a_chip():
+    # no chip, no number: the benchmark's own entry point, in a CPU-only
+    # process, must stop at harness.require_device — neither a CPU rate nor
+    # a zero under any metric's name
+    proc = _run(["-m", "benchmark.run", "--workload", "hash2e18-trimmed",
+                 "--seed", "1", "--seconds", "1"])
     assert proc.returncode != 0
-    assert "tweets_per_sec_e2e" not in proc.stdout
-    assert "device measurement failed" in proc.stderr
-    for line in proc.stdout.splitlines():  # nothing JSON-shaped with a value
-        if line.startswith("{"):
-            assert "value" not in json.loads(line)
+    assert "needs 1 TPU chip(s)" in proc.stderr and "'cpu'" in proc.stderr
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as fh:
+        manifest = json.load(fh)
+    names = [m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]]
+    for line in (proc.stdout + proc.stderr).splitlines():
+        assert not any(n in line for n in names), line
 
 
 def test_mesh_devices_follow_the_platform_the_run_record_names(monkeypatch):

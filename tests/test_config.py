@@ -266,8 +266,8 @@ def test_float64_requires_cpu_backend(isolated_env):
 
 def test_default_wire_is_auto_resolving_by_regime(isolated_env):
     """r5 (VERDICT r4 #1a): the fast path is the default path — --wire
-    auto (the default) resolves to the ragged device-hash wire (bench.py's
-    exact wire) in every back-to-back regime. Wall-clock streaming keeps
+    auto (the default) resolves to the ragged device-hash wire (the
+    benchmark's wire) in every back-to-back regime. Wall-clock streaming keeps
     padded (the ragged units bucket is data-dependent, so it cannot
     pre-compile before a live stream starts — warmup_compile); --hashOn
     host keeps padded; explicit --wire always wins."""

@@ -89,7 +89,6 @@ def test_rmse_curve_identical_across_ingest_modes(tmp_path):
     (BASELINE.md north star) applied to the ingest modes."""
     import json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.features.blocks import merge_blocks
     from twtml_tpu.streaming.sources import BlockReplayFileSource
 
@@ -97,7 +96,7 @@ def test_rmse_curve_identical_across_ingest_modes(tmp_path):
     path = tmp_path / "stream.jsonl"
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     feat = Featurizer(now_ms=1785320000000)
     B = 256

@@ -146,7 +146,6 @@ def test_app_level_multihost_cli_trains_in_lockstep(tmp_path):
     the same replay file on the same total device count."""
     import json as _json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -155,7 +154,7 @@ def test_app_level_multihost_cli_trains_in_lockstep(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(_json.dumps(_status_json(s)) + "\n")
+            fh.write(_json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"  # closed port: telemetry Try paths, no DNS
     common = [
@@ -235,7 +234,6 @@ def test_app_level_multihost_ragged_wire(tmp_path):
     import json as _json
     import re
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -244,7 +242,7 @@ def test_app_level_multihost_ragged_wire(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(_json.dumps(_status_json(s)) + "\n")
+            fh.write(_json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"
     common = [
@@ -310,7 +308,6 @@ def test_app_level_multihost_kmeans_lockstep(tmp_path):
     import json as _json
     import re
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -319,7 +316,7 @@ def test_app_level_multihost_kmeans_lockstep(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(_json.dumps(_status_json(s)) + "\n")
+            fh.write(_json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"
     common = [
@@ -464,7 +461,6 @@ def test_app_level_multihost_sentinel_rollback(tmp_path):
     completes cleanly."""
     import json as _json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -473,7 +469,7 @@ def test_app_level_multihost_sentinel_rollback(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(_json.dumps(_status_json(s)) + "\n")
+            fh.write(_json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"
     d_ck = str(tmp_path / "ck")
@@ -618,13 +614,12 @@ def test_app_level_multihost_wall_clock_intervals(tmp_path):
     run completes with all rows trained and one telemetry owner."""
     import json as _json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
     with open(path, "w") as fh:
         for s in SyntheticSource(total=64, seed=8, base_ms=1785320000000).produce():
-            fh.write(_json.dumps(_status_json(s)) + "\n")
+            fh.write(_json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"
     multi = _run_app_group([
@@ -650,7 +645,6 @@ def test_app_level_multihost_block_ingest(tmp_path):
     per-host buckets per tick through one single-device model)."""
     import json as _json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -659,7 +653,7 @@ def test_app_level_multihost_block_ingest(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(_json.dumps(_status_json(s)) + "\n")
+            fh.write(_json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"
     d_multi = str(tmp_path / "ck")
@@ -737,7 +731,6 @@ def test_app_level_multihost_checkpoint_cadence_drains(tmp_path):
     import glob
     import json as _json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -746,7 +739,7 @@ def test_app_level_multihost_checkpoint_cadence_drains(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(_json.dumps(_status_json(s)) + "\n")
+            fh.write(_json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"
     common = [

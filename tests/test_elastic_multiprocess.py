@@ -72,7 +72,6 @@ def _free_port_range(span: int = 10) -> int:
 
 
 def _write_replay(tmp_path, total: int, seed: int = 5):
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -81,7 +80,7 @@ def _write_replay(tmp_path, total: int, seed: int = 5):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
     return path, statuses
 
 

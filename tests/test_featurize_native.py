@@ -109,10 +109,9 @@ def block_from(statuses) -> ParsedBlock:
     """Parse the statuses' JSONL through the native wire parser."""
     import json
 
-    from tools.bench_suite import _status_json
 
     data = (
-        "\n".join(json.dumps(_status_json(s)) for s in statuses) + "\n"
+        "\n".join(json.dumps(s.to_json()) for s in statuses) + "\n"
     ).encode("utf-8")
     parsed = native.parse_tweet_block_wire(data, 0, 10**9)
     assert parsed is not None

@@ -82,6 +82,26 @@ def test_created_at_parsing(statuses):
     assert statuses[0].retweeted_status.created_at_ms == 1785142800000
 
 
+_PLAIN = Status(text="plain words", retweet_count=3, followers_count=40,
+                favourites_count=5, friends_count=6,
+                created_at_ms=1785142800000, lang="en", id=1001)
+
+
+@pytest.mark.parametrize("status", [
+    _PLAIN,
+    Status(text="RT plain words", created_at_ms=1785315612000, lang="en",
+           retweeted_status=_PLAIN),
+    Status(text="RT 東京の天気 café", created_at_ms=1785315612000, lang="ja",
+           retweeted_status=Status(text="東京の天気 café", retweet_count=700,
+                                   followers_count=12345, lang="ja")),
+], ids=["plain", "retweet", "retweet_non_ascii"])
+def test_to_json_is_read_back_by_from_json(status):
+    """A status written as a stream's line parses back to itself, through
+    the JSON text a replay file or a socket carries."""
+    line = json.dumps(status.to_json())
+    assert Status.from_json(json.loads(line)) == status
+
+
 def test_num_text_features_takes_effect():
     """The reference's reset() shadows its own fields (MllibHelper.scala:27-29)
     so --numTextFeatures never reaches the hasher; ours must apply it."""

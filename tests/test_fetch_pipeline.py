@@ -84,7 +84,6 @@ def test_linear_app_max_batches_exact_under_fetch_pipeline(tmp_path):
     pipeline engages) trains EXACTLY max_batches batches."""
     import jax
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.apps import linear_regression as app
 
     jax.devices()  # lock the conftest's 8-device backend before local[1]
@@ -95,7 +94,7 @@ def test_linear_app_max_batches_exact_under_fetch_pipeline(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     conf = ConfArguments().parse([
         "--source", "replay", "--replayFile", str(path),
@@ -114,7 +113,6 @@ def test_linear_app_checkpoint_cadence_under_fetch_pipeline(tmp_path):
     a resumed run continues the counters."""
     import jax
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.apps import linear_regression as app
     from twtml_tpu.checkpoint import Checkpointer
 
@@ -126,7 +124,7 @@ def test_linear_app_checkpoint_cadence_under_fetch_pipeline(tmp_path):
     )
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     ck = str(tmp_path / "ck")
     conf_args = [

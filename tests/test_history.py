@@ -495,7 +495,6 @@ BASE = [
 
 
 def _corpus_file(tmp_path, total=8 * 16, seed=51):
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     statuses = list(
@@ -504,7 +503,7 @@ def _corpus_file(tmp_path, total=8 * 16, seed=51):
     path = tmp_path / "tweets.jsonl"
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
     return path
 
 

@@ -276,14 +276,13 @@ def test_shed_and_reform_token_hygiene(tmp_path):
 
 
 def _write_corpus(path, total, seed):
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     with open(path, "w") as fh:
         for s in SyntheticSource(
             total=total, seed=seed, base_ms=1785320000000
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
 
 BASE = [

@@ -540,14 +540,13 @@ BASE = [
 
 
 def _corpus_file(tmp_path, total=8 * 16, seed=51):
-    from tools.bench_suite import _status_json
 
     path = tmp_path / "tweets.jsonl"
     with open(path, "w") as fh:
         for s in SyntheticSource(
             total=total, seed=seed, base_ms=NOW_MS
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
     return path
 
 

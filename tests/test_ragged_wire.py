@@ -131,7 +131,6 @@ def test_linear_app_ragged_identical_stats(tmp_path, capsys):
 
     import jax
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.apps import linear_regression as app
     from twtml_tpu.config import ConfArguments
 
@@ -140,7 +139,7 @@ def test_linear_app_ragged_identical_stats(tmp_path, capsys):
     path = tmp_path / "tweets.jsonl"
     with open(path, "w") as fh:
         for s in synthetic(n=5 * 16, seed=21):
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     def run(wire):
         conf = ConfArguments().parse([
@@ -193,7 +192,6 @@ def test_ragged_block_ingest_matches_padded(tmp_path):
     units + offsets) trains bit-identically to the padded block path."""
     import json
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.features.blocks import iter_row_chunks
     from twtml_tpu.streaming.sources import BlockReplayFileSource
 
@@ -204,7 +202,7 @@ def test_ragged_block_ingest_matches_padded(tmp_path):
     statuses[40] = rt("MiXeD Ascii ROW")
     with open(path, "w") as fh:
         for s in statuses:
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     feat = Featurizer(now_ms=1785320000000)
     blocks = list(BlockReplayFileSource(str(path)).produce())
@@ -236,7 +234,6 @@ def test_linear_app_block_ragged_identical_stats(tmp_path, capsys):
 
     import jax
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.apps import linear_regression as app
     from twtml_tpu.config import ConfArguments
 
@@ -245,7 +242,7 @@ def test_linear_app_block_ragged_identical_stats(tmp_path, capsys):
     path = tmp_path / "tweets.jsonl"
     with open(path, "w") as fh:
         for s in synthetic(n=5 * 16, seed=23):
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     def run(wire):
         conf = ConfArguments().parse([

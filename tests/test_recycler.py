@@ -19,14 +19,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _write_replay(path, total=96):
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     with open(path, "w") as fh:
         for s in SyntheticSource(
             total=total, seed=11, base_ms=1785320000000
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
 
 def test_auto_recycle_resumes_bit_identically(tmp_path):

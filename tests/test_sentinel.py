@@ -162,11 +162,10 @@ def _write_lines(path, lines):
 
 
 def _corpus(total, seed):
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     return [
-        json.dumps(_status_json(s))
+        json.dumps(s.to_json())
         for s in SyntheticSource(
             total=total, seed=seed, base_ms=1785320000000
         ).produce()

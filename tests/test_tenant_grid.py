@@ -410,7 +410,6 @@ def test_the_parsed_lists_reach_the_models_hyper_parameters():
 
 
 @pytest.mark.parametrize("kw, said", [
-    (dict(mapping="vmap"), "vmap"),
     (dict(wire_pack="group"), "--wirePack group"),
     (dict(mesh="a mesh"), "local[1]"),
 ])

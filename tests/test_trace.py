@@ -3,8 +3,8 @@ nesting, crash flush, the off-by-default null tracer, trace_report's
 malformed-file check — and the tier-1 integration smoke: a ``--trace`` run
 of the linear-regression entry on the local replay source produces a
 Perfetto-valid trace with every expected stage name and ZERO extra host
-fetches vs the untraced run (the BENCHMARKS.md measurement-integrity
-constraint, asserted against FetchPipeline's one-fetch-per-batch)."""
+fetches vs the untraced run (the measurement-integrity constraint of
+lawcheck TW002, asserted against FetchPipeline's one-fetch-per-batch)."""
 
 import json
 
@@ -182,7 +182,6 @@ def test_trace_report_accepts_closed_json_array(tmp_path):
 
 
 def _write_replay(tmp_path, n):
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     path = tmp_path / "tweets.jsonl"
@@ -190,7 +189,7 @@ def _write_replay(tmp_path, n):
         for s in SyntheticSource(
             total=n, seed=7, base_ms=1785320000000
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
     return path
 
 

@@ -359,14 +359,13 @@ def test_open_stream_reassembles_lines_across_any_chunking(chunk):
 
 
 def _corpus_lines(n=40):
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     lines = []
     for i, s in enumerate(
         SyntheticSource(total=n, seed=13, base_ms=1785320000000).produce()
     ):
-        d = _status_json(s)
+        d = s.to_json()
         d["id"] = 1000 + i  # snowflake ids — the shard key
         lines.append(json.dumps(d))
     return lines
@@ -377,7 +376,7 @@ def test_id_sharded_live_intake_disjoint_and_complete():
     stream and keeps rows with id ≡ processId (mod N) — shard-disjoint,
     union-complete, through the real protocol path (N concurrent
     connections against the local v1.1 server)."""
-    from tools.localstream import LocalV11StreamServer
+    from localstream import LocalV11StreamServer
     from twtml_tpu.streaming.sources import IdShardedSource
     from twtml_tpu.streaming.twitter import TwitterSource
 
@@ -415,7 +414,7 @@ def test_block_twitter_source_matches_object_path():
     json.loads Status path (config #2's host bottleneck deleted)."""
     import numpy as np
 
-    from tools.localstream import LocalV11StreamServer
+    from localstream import LocalV11StreamServer
     from twtml_tpu.features.blocks import merge_blocks, slice_block
     from twtml_tpu.features.featurizer import Featurizer, Status
     from twtml_tpu.streaming.twitter import BlockTwitterSource

@@ -122,7 +122,6 @@ def run_storm(
     """Launch the fleet, apply the storm, collect and verify. Returns a
     result dict with ``ok``/``failures`` plus the parsed evidence (epoch
     ladder, election winners, per-reform CRC rounds, counted pauses)."""
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     workdir = workdir or tempfile.mkdtemp(prefix="chaos-fleet-")
@@ -132,7 +131,7 @@ def run_storm(
         for s in SyntheticSource(
             total=tweets, seed=seed, base_ms=NOW_MS
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     base = _free_port_range()
     env = dict(

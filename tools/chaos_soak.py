@@ -111,7 +111,6 @@ def main(argv=None) -> None:
         else:
             raise SystemExit(f"unknown flag {args[i]!r}")
 
-    from tools.bench_suite import _status_json
     from twtml_tpu.apps import linear_regression as app
     from twtml_tpu.config import ConfArguments
     from twtml_tpu.streaming.sources import SyntheticSource
@@ -123,7 +122,7 @@ def main(argv=None) -> None:
         for s in SyntheticSource(
             total=n_tweets, seed=5, base_ms=1785320000000
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
     closed = "http://127.0.0.1:9"  # closed port: fails fast when attempted
     conf_args = [

@@ -7,7 +7,7 @@ ledger):
 - the conftest/driver must pin the virtual mesh BEFORE any backend init,
   so no module may touch the backend at import time (TW001);
 - a host fetch synchronizes host and device, so fetches flow ONLY through
-  the counted seams that pipeline and meter them (TW002);
+  the counted seam that pipelines and meters them (TW002);
 - uploads are issued from ONE thread in dispatch order, so no
   thread-target/executor-submitted code may reach a ``jax.device_put``
   (TW003).
@@ -154,21 +154,19 @@ class TW001BackendInit(Rule):
 
 class TW002FetchSeam(Rule):
     id = "TW002"
-    title = "host fetch outside the blessed counted seams"
+    title = "host fetch outside the blessed counted seam"
     law = (
         "a host fetch (device_get / block_until_ready) synchronizes host "
         "and device and stalls the dispatch pipeline behind it — all "
-        "fetches must flow through the counted seams "
-        "(apps/common.FetchPipeline, benchloop.measure_pipeline/"
-        "measure_passes) that pipeline and time them, so the "
-        "one-fetch-per-tick law stays countable (CLAUDE.md)"
+        "fetches must flow through the counted seam "
+        "(apps/common.FetchPipeline) that pipelines and times them, so "
+        "the one-fetch-per-tick law stays countable (CLAUDE.md)"
     )
-    # the seam implementations themselves; tests/ and tools/ are out of
-    # scope by construction (counting tests monkeypatch device_get, benches
-    # build measurement arms)
+    # the seam implementation itself; tests/ and tools/ are out of scope
+    # by construction (counting tests monkeypatch device_get, the soaks
+    # close each pass with a fetch of their own)
     SEAM_FILES = frozenset({
         "twtml_tpu/apps/common.py",
-        "twtml_tpu/utils/benchloop.py",
     })
 
     def check(self, ctx: FileContext):
@@ -187,7 +185,7 @@ class TW002FetchSeam(Rule):
             ):
                 findings.append(Finding(
                     self.id, ctx.path, node.lineno,
-                    "jax.device_get outside the blessed fetch seams — "
+                    "jax.device_get outside the blessed fetch seam — "
                     + self.law,
                 ))
             elif isinstance(node.func, ast.Attribute) and (
@@ -195,7 +193,7 @@ class TW002FetchSeam(Rule):
             ):
                 findings.append(Finding(
                     self.id, ctx.path, node.lineno,
-                    ".block_until_ready() outside the blessed fetch seams "
+                    ".block_until_ready() outside the blessed fetch seam "
                     "— " + self.law,
                 ))
         return findings
@@ -215,7 +213,7 @@ class TW003ThreadPut(Rule):
     def check(self, ctx: FileContext):
         if not (ctx.path.startswith("twtml_tpu/")
                 or ctx.path.startswith("tools/")
-                or ctx.path in ("bench.py", "__graft_entry__.py")):
+                or ctx.path == "__graft_entry__.py"):
             return []
         aliases = import_aliases(ctx.tree)
         findings: list[Finding] = []

@@ -240,8 +240,9 @@ def _check_block_wire_parity(native, np) -> list[str]:
             errors.append(f"block {tag}: all-ASCII block not narrow")
         # bad-line counts: the wire parser's keyless-line prescreen may
         # UNDERCOUNT JSON-shaped lines with no "retweeted_status" key —
-        # the documented telemetry-only divergence (BENCHMARKS.md r9);
-        # kept-row payloads above are exact either way
+        # the documented telemetry-only divergence (PARITY.md, the
+        # zero-copy wire emitter); kept-row payloads above are exact
+        # either way
         if w_bad > l_bad:
             errors.append(f"block {tag}: wire bad-count exceeds legacy "
                           f"({w_bad} > {l_bad})")

@@ -1,6 +1,5 @@
 """Endurance soak: alternate the flagship configs back-to-back on the chip
-and assert numeric bit-stability — the r2/r3 reliability evidence
-(BENCHMARKS.md "Endurance soaks").
+and assert numeric bit-stability.
 
 Each round runs, on the SAME process/models: the dense ragged-wire pipeline
 at the r4 headline operating point (batch 16384) and the 2^18 int8-Gram
@@ -20,8 +19,7 @@ r17 additions (ISSUE 14):
   pass's completion fetch (every dispatch has provably executed by then),
   so arena-on reuses the same destination buffers pass over pass while
   arena-off is the pre-r17 fresh-allocation control arm. The two slopes,
-  recorded side by side, are the arena's RSS evidence (BENCHMARKS.md
-  "One-pass wire assembly (r17)").
+  recorded side by side, are the arena's RSS evidence.
 
 r22 addition (ISSUE 20): the soak feeds the telemetry historian — one
 ``historian.sample()`` per pass into ``--historyDir`` (default
@@ -67,6 +65,23 @@ def _slope_mb_per_min(samples: "list[tuple[float, float]]") -> float:
     return slope_mb_per_min(samples)
 
 
+def _run_once(model, featurize, chunks) -> float:
+    """One pass: featurize chunk k+1 on a host thread while the device runs
+    chunk k, dispatch freely, and close with ONE real fetch — the weights
+    chain through every step, so the last step's mse cannot arrive before
+    the whole pass has run. Returns that mse."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        pending = pool.submit(featurize, chunks[0])
+        for nxt in chunks[1:]:
+            batch = pending.result()
+            pending = pool.submit(featurize, nxt)
+            model.step(batch)
+        last = model.step(pending.result())
+    return float(last.mse)
+
+
 def main(argv=None) -> None:
     args = list(sys.argv[1:] if argv is None else argv)
     minutes, n_tweets = 15.0, 65536
@@ -100,7 +115,6 @@ def main(argv=None) -> None:
     from twtml_tpu.features.featurizer import Featurizer
     from twtml_tpu.models import StreamingLinearRegressionWithSGD
     from twtml_tpu.streaming.sources import SyntheticSource
-    from twtml_tpu.utils.benchloop import _run_once
     from twtml_tpu.utils.rss import rss_mb
 
     _assemble.configure(assemble_mode)
@@ -150,7 +164,6 @@ def main(argv=None) -> None:
 
     arms = {}
     if configs in ("both", "dense"):
-        # the r4 operating points (BENCHMARKS.md "r4 operating point")
         arms["dense_ragged_b16384"] = arm(1000, 16384, 0.0)
     if configs in ("both", "hash2e18"):
         arms["hash2e18_ragged_b3072"] = arm(2**18, 3072, 0.1)
@@ -172,8 +185,7 @@ def main(argv=None) -> None:
         for name, (model, fz, chunks) in arms.items():
             model.reset()
             pass_leases.clear()
-            _, last = _run_once(model, fz, chunks, prefetch=True)
-            mse = float(last.mse)
+            mse = _run_once(model, fz, chunks)
             # completion fetch done ⇒ every dispatch consumed its wire:
             # the pass's leases retire to the pool (arena-on) or no-op
             for lease in pass_leases:

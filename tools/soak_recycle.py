@@ -40,14 +40,13 @@ CLOSED = "http://127.0.0.1:9"  # closed port: telemetry stays best-effort-off
 
 
 def _write_corpus(path: str, total: int) -> None:
-    from tools.bench_suite import _status_json
     from twtml_tpu.streaming.sources import SyntheticSource
 
     with open(path, "w") as fh:
         for s in SyntheticSource(
             total=total, seed=11, base_ms=1785320000000
         ).produce():
-            fh.write(json.dumps(_status_json(s)) + "\n")
+            fh.write(json.dumps(s.to_json()) + "\n")
 
 
 def _statm_mb(pid: int) -> float | None:

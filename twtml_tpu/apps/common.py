@@ -1936,8 +1936,8 @@ class FetchPipeline:
     Why: a per-batch stats fetch is a latency-bound REQUEST — starting the
     copy early (a one-batch-lagged fetch) does not shorten it, but
     CONCURRENT ``device_get``s overlap their latencies, so ``depth`` of
-    them in flight hide up to ``depth`` fetch round trips behind dispatch
-    (tools/bench_telemetry.py is the harness). Dispatch and ``device_put``
+    them in flight hide up to ``depth`` fetch round trips behind dispatch.
+    Dispatch and ``device_put``
     stay on the main thread (lawcheck TW003); gets from worker threads are
     what this pipeline exists to issue.
 

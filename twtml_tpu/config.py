@@ -1073,8 +1073,8 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
         padded/host-hash wire is rejected, like explicit ragged with
         ``--hashOn host``. ``auto`` follows the wirePack precedent: OFF
         until an on-chip paired verdict clears it (measure in the target
-        regime before shipping a wire change; tools/bench_wirecodec.py
-        has only a modeled-upload-bandwidth arm so far — ROADMAP S3)."""
+        regime before shipping a wire change: no cell runs it, ROADMAP
+        D10)."""
         if self.wireCodec in ("off", "auto"):
             return "off"
         if self.effective_wire() != "ragged":

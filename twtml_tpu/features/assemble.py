@@ -80,7 +80,7 @@ def forced(mode_: str):
 # int64 per-segment encode-length scratch, cached per (thread, size):
 # tiny (8 bytes per segment), but the pack hot path allocates nothing per
 # tick (TW008); thread-local because a prefetch worker may pack while the
-# main thread packs a different stream (utils/benchloop prefetch)
+# main thread packs a different stream (tools/soak.py's featurize thread)
 _len_scratch = __import__("threading").local()
 
 

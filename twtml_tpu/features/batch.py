@@ -1362,10 +1362,9 @@ def split_batch_tenants(
 
     ONE rung for all parts, the fullest's — as until PR 49 — under
     ``one_rung`` (``TenantStackModel.split`` asks for it where its wire or
-    program has one shape for all tenants: ``--wirePack group``,
-    ``mapping="vmap"``, a mesh) and under ``rung``, which pins the shape
-    itself (multi-host callers, whose hosts must agree on it, pass the
-    batch's row count)."""
+    program has one shape for all tenants: ``--wirePack group``, a mesh)
+    and under ``rung``, which pins the shape itself (multi-host callers,
+    whose hosts must agree on it, pass the batch's row count)."""
     rows_per = tenant_rows(batch, tenant_ids, num_tenants)
     b = batch.mask.shape[0]
     ragged = isinstance(batch, RaggedUnitBatch)

@@ -201,6 +201,27 @@ class Status:
             id=int(obj.get("id") or 0),
         )
 
+    def to_json(self) -> dict[str, Any]:
+        """The wire-format tweet JSON object ``from_json`` reads back
+        (recursive on retweets). A stream's line always names a language,
+        so an unset ``lang`` is written as ``"en"``."""
+        d: dict[str, Any] = {
+            "text": self.text,
+            "retweet_count": self.retweet_count,
+            "user": {
+                "followers_count": self.followers_count,
+                "favourites_count": self.favourites_count,
+                "friends_count": self.friends_count,
+            },
+            "timestamp_ms": str(self.created_at_ms),
+            "lang": self.lang or "en",
+        }
+        if self.id:
+            d["id"] = self.id
+        if self.retweeted_status is not None:
+            d["retweeted_status"] = self.retweeted_status.to_json()
+        return d
+
 
 @dataclass
 class Featurizer:

@@ -9,7 +9,7 @@ SIGILL; an mtime survives a copy, a stamp of what was compiled does not).
 Where no compiler is available the apps degrade — at WARNING — to the
 Python path: features/hashing.py stays the semantic ground truth and the
 parity test asserts the two implementations agree bigram-for-bigram.
-Measurement entry points (``chip_smoke.py``, ``bench.py``) call
+Measurement entry points (``chip_smoke.py``, ``benchmark/``) call
 ``require_live()`` and fail instead of timing the fallback.
 """
 
@@ -676,7 +676,7 @@ SYMBOLS = (
 
 
 def require_live() -> dict:
-    """The measurement entry points' gate (``chip_smoke.py``, ``bench.py``):
+    """The measurement entry points' gate (``chip_smoke.py``, ``benchmark/``):
     return {"lib", "stamp", "symbols"} when the library was built on this
     host from the tracked sources and EVERY symbol bound; raise otherwise —
     a host-bound number taken on the Python fallback is a tenth of the real

@@ -265,7 +265,6 @@ def make_sgd_train_step(
     use_sparse: bool | None = None,
     round_predictions: bool = True,
     use_gram: bool | None = None,
-    gram_int8: bool | None = None,
     quality: bool = False,
     arms: bool = False,
 ):
@@ -309,10 +308,6 @@ def make_sgd_train_step(
     resized. ``use_gram`` False forces the scatter loop
     (the differential baseline); None picks Gram whenever it applies (f32
     weights, dense counts within HBM budget — ops/gram.py ``fits_gram``).
-    ``gram_int8`` pins the G build's int8 plane on/off at trace time
-    (None = the module default, ops/gram.py ``GRAM_INT8_PLANE``) — threaded
-    as a parameter, not a global read, so multi-shape callers (the ragged
-    wire retraces per flat-buffer bucket) get ONE consistent plane.
 
     ``quality`` (ISSUE 8) appends the in-step quality vector
     (ops/quality.py) as ``StepOutput.quality`` — weight/update/gradient
@@ -501,7 +496,6 @@ def make_sgd_train_step(
             f_text,
             row_start=lax.axis_index(axis_name) * rows if axis_name else None,
             rows=rows,
-            int8_plane=gram_int8,
             body=dual_basis,
         )
         if axis_name:
@@ -711,7 +705,6 @@ class StreamingSGDModel:
         dtype=jnp.float32,
         use_sparse: bool | None = None,
         use_gram: bool | None = None,
-        gram_int8: bool | None = None,
         quality: bool = False,
     ) -> None:
         self.num_text_features = num_text_features
@@ -729,7 +722,6 @@ class StreamingSGDModel:
             round_predictions=self.round_predictions,
             use_sparse=use_sparse,
             use_gram=use_gram,  # None=auto; False is the scatter-loop escape hatch
-            gram_int8=gram_int8,
             quality=quality,  # --modelWatch: the in-step quality side channel
         )
         # donate weights: the update happens in-place in HBM

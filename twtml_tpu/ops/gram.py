@@ -124,21 +124,12 @@ all.
 from __future__ import annotations
 
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from .sparse import densify_text
-
-# The int8 plane is on by default; the flag exists so benches can build the
-# bf16-only program for paired A/B comparison (trace-time capture: set it
-# before the model's first step). TWTML_GRAM_INT8=0 disables it process-wide.
-GRAM_INT8_PLANE = os.environ.get("TWTML_GRAM_INT8", "1").lower() not in (
-    "0",
-    "false",
-)
 
 # Above this dense-counts footprint (B·F·4 bytes) the Gram build would not
 # fit comfortably in HBM next to the program's other buffers; the learner
@@ -225,7 +216,7 @@ def text_gram(
     f_text: int,
     row_start=None,
     rows: int = 0,
-    int8_plane: bool | None = None,
+    int8_plane: bool = True,
     feature_axis: str | None = None,
     *,
     body,
@@ -269,8 +260,6 @@ def text_gram(
     plane on the whole row's figures (module docstring). G stays this
     slice's PARTIAL product; the caller psums it.
     """
-    if int8_plane is None:
-        int8_plane = GRAM_INT8_PLANE
     # device stage names (models/sgd.py STAGE_SCOPES): the three planes
     # share ``gram_count`` (the plane gate and the count matrix) and
     # ``gram_matmul``; which plane ran is the index handed out with G
@@ -487,7 +476,7 @@ def gram_matrix(
     numeric,
     f_text: int,
     dtype=jnp.float32,
-    int8_plane: bool | None = None,
+    int8_plane: bool = True,
 ):
     """G = Z·Zᵀ ([B,B] ``dtype``) for Z = [text counts | numeric features]."""
     return add_numeric_block(

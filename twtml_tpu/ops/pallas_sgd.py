@@ -35,7 +35,7 @@ at 2048x1024 the whole 50-iteration loop is a small fraction of a batch's
 end-to-end time for BOTH the XLA-compiled loop and this kernel. It stays as
 tested, hardware-lowerable reference code for the VMEM-resident pattern,
 with semantics pinned against the XLA path (tests/test_pallas_sgd.py in
-interpret mode; ``tools/bench_pallas.py`` compiles it for the chip). A
+interpret mode, its only caller: ROADMAP D7). A
 caller states ``interpret`` explicitly — the kernel never decides by itself
 to run interpreted, so a chip run can not silently measure the interpreter.
 """

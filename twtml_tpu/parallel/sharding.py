@@ -180,7 +180,6 @@ def _make_feature_sharded_step(
     data_axis: str,
     model_axis: str,
     use_gram: bool | None = None,
-    gram_int8: bool | None = None,
     quality: bool = False,
 ):
     """Per-shard body for the 2D (data × model) mesh. Weights arrive as a
@@ -359,7 +358,6 @@ def _make_feature_sharded_step(
                 f_text_local,
                 row_start=lax.axis_index(data_axis) * b_local,
                 rows=b_local,
-                int8_plane=gram_int8,
                 feature_axis=model_axis,
                 body=dual_basis,
             )
@@ -461,7 +459,6 @@ class ParallelSGDModel:
         round_predictions: bool = True,
         use_sparse: bool | None = None,
         use_gram: bool | None = None,
-        gram_int8: bool | None = None,
         quality: bool = False,
     ) -> None:
         self.mesh = mesh
@@ -491,7 +488,6 @@ class ParallelSGDModel:
                 axis_name=self.data_axis,
                 use_sparse=use_sparse,
                 use_gram=use_gram,
-                gram_int8=gram_int8,
                 quality=quality,
             )
             self._weights = jnp.zeros(
@@ -519,7 +515,6 @@ class ParallelSGDModel:
                 data_axis=self.data_axis,
                 model_axis=self.model_axis,
                 use_gram=use_gram,
-                gram_int8=gram_int8,
                 quality=quality,
             )
             self._weights = {

@@ -10,16 +10,12 @@ instead of extra host fetches:
 - **weights** are one ``[M, F+4]`` array (one optimizer-state pytree; one
   donated buffer), per-tenant hyperparams (step size, L2) ride as mapped
   scalar leaves of a separate ``hyper`` pytree;
-- **the step** maps the EXISTING fused SGD step over the tenant axis.
-  Default mapping is ``lax.map`` — a scan of the single-tenant step program
-  with no carry, which keeps every tenant's math BIT-IDENTICAL to the
-  reference single-model path (the parity law). ``mapping="vmap"``
-  batches the tenants across the
-  device instead — mathematically equivalent, but XLA's batched-matmul
-  accumulation order differs on the dense path, so it is an opt-in for
-  deployments that trade bit-parity for device parallelism. What a tenant
-  COSTS on the device: a step's cost does not depend on its mask, so it is
-  the step at the rows its PART is padded to — a tenant row rung
+- **the step** maps the EXISTING fused SGD step over the tenant axis with
+  ``lax.map`` — a scan of the single-tenant step program with no carry,
+  which keeps every tenant's math BIT-IDENTICAL to the reference
+  single-model path (the parity law). What a tenant COSTS on the device:
+  a step's cost does not depend on its mask, so it is the step at the
+  rows its PART is padded to — a tenant row rung
   (``features/batch.tenant_row_rungs``), read off each batch: the smallest
   of a short ladder (1.25·B/M rounded up to 128 rows, doubling, B) that
   holds the part's rows and units. The split takes TWO rungs a batch: the
@@ -35,12 +31,9 @@ instead of extra host fetches:
   parts of 640, both in the ONE program (``_two_rungs``; PERF.md §6, PR 49
   has what that reads on the chip) — where until PR 49 every part took
   the fullest's rung: four steps of 16.97 ms, 30.1k tweets/s (PR 35, PR
-  42). A mesh, ``--wirePack group``, ``mapping="vmap"`` and a pinned rung
-  (the multi-host fleet) keep ONE rung for all M parts, the fullest's. At
-  the reference's 1,004 dims not measured on the chip. ``vmap`` at hashed
-  widths reserved 14.1 GiB of temporaries where ``lax.map`` reserved 4.0
-  and ran 14.4x slower (the Gram gate's ``switch`` becomes a ``select``;
-  PR 35, at 2,048 rows);
+  42). A mesh, ``--wirePack group`` and a pinned rung (the multi-host
+  fleet) keep ONE rung for all M parts, the fullest's. At the reference's
+  1,004 dims not measured on the chip;
 - **the wire** is shared: rows route to tenants on the host by a cheap
   deterministic key (``features/batch.tenant_route_keys``), split into M
   batches of their row rungs' shapes (dry tenants = all-padding, the
@@ -66,8 +59,8 @@ out of the count build, ``Cᵀ·[α_1…α_M]`` in one pass) and maps only the d
 loop over the arms (scope ``arm_map``, ``lax.map``: arm m is the single
 model under arm m's recipe, to float32 rounding in the Gram basis and bit
 for bit outside it). State, fetch, checkpoint and frames are the plane's:
-``[M, F+4]`` weights, one ``[M, ...]`` StepOutput. One device, the default
-mapping and the stacked wire only (refused otherwise, with the reason).
+``[M, F+4]`` weights, one ``[M, ...]`` StepOutput. One device and the
+stacked wire only (refused otherwise, with the reason).
 
 Mesh composition: a 1D ('data',) mesh shards every tenant batch's rows over
 ``data`` (tenant axis unsharded — weights replicated) with the per-shard
@@ -238,20 +231,16 @@ class TenantStackModel:
         round_predictions: bool = True,
         use_sparse: bool | None = None,
         use_gram: bool | None = None,
-        gram_int8: bool | None = None,
         tenant_key: str = "hash",
         wire_pack: str = "stacked",
         wire_codec: str = "",
         mesh=None,
         step_sizes=None,
         l2_regs=None,
-        mapping: str = "scan",
         quality: bool = False,
     ) -> None:
         if num_tenants < 1:
             raise ValueError(f"num_tenants must be >= 1, got {num_tenants}")
-        if mapping not in ("scan", "vmap"):
-            raise ValueError(f"mapping must be 'scan' or 'vmap', got {mapping!r}")
         if wire_pack not in ("stacked", "group"):
             raise ValueError(
                 f"wire_pack must be 'stacked' or 'group', got {wire_pack!r}"
@@ -261,9 +250,6 @@ class TenantStackModel:
         self.shared_rows = tenant_key == "all"
         if self.shared_rows:
             for bad, why in (
-                (mapping == "vmap",
-                 "mapping='vmap' batches WHOLE steps over the tenant axis; "
-                 "the shared-rows step maps only its per-arm half"),
                 (wire_pack == "group",
                  "--wirePack group coalesces M tenant batches into one "
                  "buffer; under 'all' there is ONE batch, shipped as the "
@@ -284,7 +270,6 @@ class TenantStackModel:
         # segment; "" / "off" = raw. Stacked wire ships raw by design
         # (the codec rides the packed one-buffer forms only).
         self.wire_codec = wire_codec
-        self.mapping = mapping
         self.mesh = mesh
         # --modelWatch: the mapped step computes each tenant's quality
         # vector inside the one jit program — the stacked [M, Q] leaf rides
@@ -328,7 +313,6 @@ class TenantStackModel:
             round_predictions=round_predictions,
             use_sparse=use_sparse,
             use_gram=use_gram,
-            gram_int8=gram_int8,
             quality=quality,
         )
 
@@ -439,8 +423,6 @@ class TenantStackModel:
         # iterations under ``tenant_map``, with the step's nine stage scopes
         # (models/sgd.STAGE_SCOPES) inside it unchanged
         with jax.named_scope("tenant_map"):
-            if self.mapping == "vmap":
-                return jax.vmap(self._one)(weights, hyper, batch)
             # lax.map = scan of the single-tenant step with no carry: the
             # SAME program per tenant, hence bit-identical math (the parity
             # law)
@@ -524,16 +506,13 @@ class TenantStackModel:
         others to theirs (``split_batch_tenants``) — or all to ``rung`` when
         pinned. ONE rung for all, the fullest's, where this plane's wire or
         program has one shape for every tenant: the coalesced group buffer
-        (``--wirePack group``), ``mapping="vmap"``, a mesh (whose rungs are
-        multiples of its data axis)."""
+        (``--wirePack group``), a mesh (whose rungs are multiples of its data
+        axis)."""
         return split_batch_tenants(
             batch, self.route_ids(batch), self.num_tenants,
             row_multiple=self.num_data if self.mesh is not None else 1,
             rung=rung,
-            one_rung=(
-                self.mesh is not None or self.wire_pack == "group"
-                or self.mapping == "vmap"
-            ),
+            one_rung=self.mesh is not None or self.wire_pack == "group",
         )
 
     def _is_tenant_wire(self, batch) -> bool:

@@ -5,9 +5,8 @@ trainer's per-batch publisher, runs its own exchange on a kept socket since
 PR 41; this client is on no per-batch path) — and predict calls RAISE on
 failure instead of the telemetry client's best-effort ``Try`` semantics: a
 load generator or an ops script must see a refused/aborted predict, not
-silently drop it. The paired serving
-bench (``tools/bench_serving.py``) and the serve-smoke tests drive this
-client as their load face.
+silently drop it. The serve-smoke tests drive this client as their load
+face.
 """
 
 from __future__ import annotations

@@ -79,7 +79,7 @@ _PAYLOAD_MAX = 1 << 31  # sanity bound when scanning possibly-garbage tails
 #  created_at_ms, lang, id, retweeted_status-row-or-null]. Rows, not
 # key-value objects: the C-speed attrgetter + positional JSON encode is
 # faster and smaller than per-status dicts, and the append sits
-# on the hot intake seam (bench_journal.py gates the paired overhead).
+# on the hot intake seam.
 _STATUS_FIELDS = operator.attrgetter(
     "text", "retweet_count", "followers_count", "favourites_count",
     "friends_count", "created_at_ms", "lang", "id", "retweeted_status",

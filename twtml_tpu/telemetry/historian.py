@@ -37,8 +37,7 @@ The historian closes that gap with the cheapest possible sampling:
   not a gate.
 
 ``--history off`` is bit-exact HEAD: no module state, no file handles, the
-sample hook no-ops (tests byte-compare weights; tools/bench_history.py gates
-the paired on/off overhead at >= 0.97x).
+sample hook no-ops (tests byte-compare weights).
 
 Frame format (little-endian): ``b"TWTH" | u32 payload_len | u32
 crc32(payload) | payload`` where payload is one UTF-8 JSON object with a

@@ -9,19 +9,17 @@ host fetches, no threads), snapshot on demand, published to the dashboard as
 a ``Metrics`` message (telemetry/api_types.py) and stamped into traces
 (telemetry/trace.py).
 
-Hard constraints (BENCHMARKS.md "Measurement integrity"): nothing in this
+Hard constraints (lawcheck TW002/TW003): nothing in this
 module may touch the device — no ``device_get``, no ``block_until_ready``,
 no ``device_put``. Everything is host-side bookkeeping over timings the
 pipeline already takes.
 
 The ``FetchHealthMonitor`` is the rolling fetch-latency estimator: it watches
 the fetch latencies the pipeline already measures (FetchPipeline's pooled
-``device_get``s, benchloop's per-pass completion fetch) and classifies the run
-into healthy/degraded **health phases**. Classification is self-relative —
-degraded means the rolling median latency sits ``degrade_factor``× above the
-best latency this process has seen — because the same monitor must work at
-single-fetch scale (app fetches) and at pass scale (multi-second bench
-passes).
+``device_get``s) and classifies the run into healthy/degraded **health
+phases**. Classification is self-relative — degraded means the rolling
+median latency sits ``degrade_factor``× above the best latency this process
+has seen — because no absolute latency is right for every transport.
 """
 
 from __future__ import annotations
@@ -211,7 +209,7 @@ class FetchHealthMonitor:
     best. Latencies under ``floor_s`` never count as degraded (keeps
     µs-scale CPU-backend jitter out of the classifier). Observations are attributed to the phase AFTER
     classification, so ``observations`` splits a run's samples into the two
-    phases the way bench output wants them.
+    phases the way a run record wants them.
 
     Transitions are stamped into the active trace (an instant event) and the
     registry (``fetch_health.phase_transitions`` counter +
@@ -301,7 +299,7 @@ class FetchHealthMonitor:
             return statistics.median(self._window) * 1e3
 
     def summary(self) -> dict:
-        """The health block bench.py and the Metrics message publish."""
+        """The health block the Metrics message publishes."""
         with self._lock:
             return {
                 "phase": self.phase,
@@ -317,7 +315,7 @@ class FetchHealthMonitor:
 # -- process-wide defaults ---------------------------------------------------
 # One registry + one health monitor per process: instrumentation points are
 # scattered (sources, context, fetch pipeline, stats) and all feed the same
-# run-level story the dashboard/bench surface.
+# run-level story the dashboard surfaces.
 
 _REGISTRY = MetricsRegistry()
 _HEALTH = FetchHealthMonitor(registry=_REGISTRY)

@@ -4,7 +4,7 @@ The fleet was observationally blind: the lockstep scheduler
 (streaming/context._lockstep_loop) gates every tick on the slowest host,
 but nothing recorded WHICH host gated or WHAT stage of its pipeline was
 slow. This module is the fix, under the measurement law that made PR 1
-honest (BENCHMARKS.md "Measurement integrity"): **zero added host fetches
+honest (lawcheck TW002/TW003): **zero added host fetches
 and zero added collectives** — the sideband is a compact fixed-width float
 vector of host-side bookkeeping that rides the EXISTING per-tick cadence
 allgather (the flags array widens; no new collective is ever issued), and
@@ -81,21 +81,16 @@ STAGE_FIELDS = {
 # -- stage clock -------------------------------------------------------------
 # Cumulative wall seconds per pipeline stage, always on: the contributing
 # sites run at batch cadence (or chunk cadence for the block parser), so the
-# cost is one lock + one float add per stage per batch. ``_CLOCK_ON`` exists
-# only so the observability-overhead bench can measure an honest "all off"
-# control arm (tools/bench_observability.py).
+# cost is one lock + one float add per stage per batch.
 
 _STAGE_LOCK = threading.Lock()
 _STAGE_SECONDS: "dict[str, float]" = {}
-_CLOCK_ON = True
 
 
 def record_stage(stage: str, dur_s: float) -> None:
     """Accumulate one stage timing (seconds). Pool threads call this for
     ``fetch`` concurrently, so cumulative fetch seconds may exceed wall
     time — fine for attribution, which compares a host against itself."""
-    if not _CLOCK_ON:
-        return
     with _STAGE_LOCK:
         _STAGE_SECONDS[stage] = _STAGE_SECONDS.get(stage, 0.0) + dur_s
 
@@ -103,13 +98,6 @@ def record_stage(stage: str, dur_s: float) -> None:
 def stage_seconds() -> "dict[str, float]":
     with _STAGE_LOCK:
         return dict(_STAGE_SECONDS)
-
-
-def set_stage_clock(on: bool) -> None:
-    """Bench hook (tools/bench_observability.py): the control arm must not
-    pay even the per-batch dict adds."""
-    global _CLOCK_ON
-    _CLOCK_ON = bool(on)
 
 
 # -- per-tick collection -----------------------------------------------------
@@ -188,12 +176,11 @@ def last_hosts() -> "dict | None":
 
 
 def reset_for_tests() -> None:
-    global _LAST_VIEW, _CLOCK_ON
+    global _LAST_VIEW
     with _VIEW_LOCK:
         _LAST_VIEW = None
     with _STAGE_LOCK:
         _STAGE_SECONDS.clear()
-    _CLOCK_ON = True
 
 
 class LockstepTelemetry:

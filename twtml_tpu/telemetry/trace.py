@@ -15,7 +15,7 @@ append and crashes lose nothing, which also makes the file line-parseable as
 JSONL after stripping the decoration (``tools/trace_report.py`` does). Loads
 as-is in Perfetto / ``chrome://tracing``.
 
-Measurement-integrity constraints (BENCHMARKS.md): tracing adds **no**
+Measurement-integrity constraints (lawcheck TW002/TW003): tracing adds **no**
 ``device_get``/``block_until_ready`` calls and no non-main-thread
 ``device_put`` — spans only time work the pipeline already does. Off is the
 default and must stay ~free on the hot path: ``get()`` returns a null tracer
