@@ -13,7 +13,10 @@ directory) or, since PR 35, in the cell's own test file: the cases of
 those of ``benchmark/tests/test_hash2e18_lang4.py`` (PR 42; its three fault
 cases' twins are in ``tests/test_tenant_lang_deployment.py``) and of
 ``benchmark/tests/test_hash2e18_grid4.py`` (PR 47; its four fault cases'
-twins are in ``tests/test_tenant_grid.py``).
+twins are in ``tests/test_tenant_grid.py``) and of
+``benchmark/tests/test_hash2e20_grid4.py`` (PR 52: the arms on the 2 x 2
+mesh; its rehearsal, control and fault cases take minutes each at 2^20 dims
+and run by hand, their twins are in ``tests/test_tenant_grid_mesh.py``).
 """
 
 import benchmark.tests.conftest as _added_since  # noqa: F401
@@ -42,5 +45,14 @@ from benchmark.tests.test_hash2e18_grid4 import (  # noqa: F401
     test_program_flags_are_the_recorded_list as test_grid4_program_flags_are_the_recorded_list,
     test_readers_on_a_trace_made_by_hand,
     test_the_cell_is_hash2e18_trimmed_280_with_four_recipes_on_its_rows,
-    test_the_cell_reports_the_single_models_metrics_and_its_own_three,
+)
+from benchmark.tests.test_hash2e20_grid4 import (  # noqa: F401
+    test_program_flags_are_the_recorded_list as test_mesh_grid4_program_flags_are_the_recorded_list,
+    test_readers_on_a_trace_made_by_hand as test_mesh_grid4_readers_on_a_trace_made_by_hand,
+    test_the_cell_is_hash2e20_with_grid4s_four_recipes_on_its_rows,
+    test_the_cell_reports_hash2e20s_metrics_the_arms_two_and_its_own_two,
+    test_the_four_chip_cells_are_these_two_of_eight,
+    # grid4's case of this name as it now reads: two of its three ``arm_*``
+    # metrics are listed on the mesh cell too (the new file's docstring)
+    test_grid4_reports_the_single_models_metrics_and_its_own_three as test_the_cell_reports_the_single_models_metrics_and_its_own_three,
 )

@@ -772,3 +772,128 @@ def test_compiled_arms_program_builds_c_and_g_once_and_maps_the_rest(topo):
         assert len(readers) == 2, (plane, readers)
         assert sum("/gram_matmul/" in line for line in readers) == 1
         assert sum("/writeback/" in line for line in readers) == 1
+
+
+# ---------------------------------------------------------------------------
+# PR 52: the arms on the 2 x 2 mesh (``--tenantKey all --modelShards 2``,
+# configuration ``hash2e20-grid4``): ``hash2e20``'s sharded C, G panel and
+# collectives ONCE a batch, shared by four models.
+
+_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (.*?) (all-reduce|all-gather|reduce-scatter|"
+    r"all-to-all|collective-permute)(?:-start)?\([^\n]*op_name=\"([^\"]*)\"",
+    re.M)
+
+
+def _compile_2x2_arms(topo):
+    """The feature-sharded step with ``arms`` at hash2e20's width, four
+    recipes, the cells' wire: ``(compiled text, temporaries in bytes)``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from twtml_tpu.models.base import StepOutput
+    from twtml_tpu.parallel.sharding import _make_feature_sharded_step
+
+    m, f_text = 4, 1 << 20
+    body = _make_feature_sharded_step(
+        f_text=f_text, f_text_local=f_text // 2, num_iterations=50,
+        step_size=np.asarray([0.005, 0.005, 0.0025, 0.0025], np.float32),
+        l2_reg=np.asarray([0.1, 0.01, 0.1, 0.01], np.float32),
+        mini_batch_fraction=1.0, convergence_tol=0.001, residual_fn=None,
+        prediction_fn=None, round_predictions=True, data_axis="data",
+        model_axis="model", quality=True, arms=True)
+    mesh = Mesh(np.array(topo.devices).reshape((2, 2)), ("data", "model"))
+
+    def shape(dims, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    w_spec = {"text": P(None, "model"), "num": P()}
+    step = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(w_spec, P("data")),
+        out_specs=(w_spec, StepOutput(
+            predictions=P(None, "data"), count=P(), mse=P(), real_stdev=P(),
+            pred_stdev=P(), quality=P())),
+    ), donate_argnums=0)
+    compiled = step.lower(
+        {"text": shape((m, f_text), jnp.float32, None, "model"),
+         "num": shape((m, 4), jnp.float32)},
+        _ragged_shapes(shape, shards=2)).compile()
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_compiled_mesh_arms_program_is_hash2e20s_with_m_payloads(
+        topo, tpu_steps):
+    """The program the TPU's compiler makes for the cell
+    ``hash2e20-grid4-trimmed-280`` (four arms on the 2 x 2 mesh, 2,048 rows,
+    a 2^19 slice a chip) beside ``hash2e20``'s own (the ``2x2`` case).
+
+    The SAME NUMBER of collective instructions as that program, every one
+    under the ``collective`` scope inside a stage, NONE inside the map's
+    ``while``, the per-arm ones with ``[M, ·]`` payloads: the ``u``
+    partials' psum ``[4, 1024]`` and all-gather ``[4, 2048]`` under
+    ``predict``, the write-back deltas ``[4, 524288]`` and ``[4, 4]`` in
+    one all-reduce under ``writeback``. ``arm_map`` holds the ``dual_loop``
+    (under its ``while``) and no ``predict`` / ``writeback`` / ``gram_*``.
+    In each fast plane's branch C is WRITTEN once, by the fusion that also
+    yields the four ``f32[2048]`` ``u_m = C·w_m`` (no read of C for any
+    arm's predict), its row panel at most once, and the panel is READ by
+    two operations: the G product and the ONE write-back pass, which
+    yields all four ``[524288]`` deltas; nothing of C's or the panel's size
+    is carried into a loop. And the whole of it reserves ``hash2e20``'s
+    temporaries (8,621,713,920 B as compiled: the three planes' count
+    matrices, once) and not M times them."""
+    m, width = 4, 1 << 19
+    text, temp = _compile_2x2_arms(topo)
+    assert 8 * 2**30 <= temp < 8.2 * 2**30
+    mine, single = _COLLECTIVE.findall(text), _COLLECTIVE.findall(
+        tpu_steps("2x2"))
+    assert len(mine) == len(single) >= 20, (len(mine), len(single))
+    for _result, kind, path in mine:
+        parts = path.split("/")
+        assert "collective" in parts, (kind, path)
+        assert "/arm_map/while/" not in path, (kind, path)
+        assert any(s in parts for s in STAGE_SCOPES), (kind, path)
+
+    def carried(kind, stage, array):
+        return [r for r, k, p in mine if k == kind and f"/{stage}/" in p
+                and array in r]
+
+    assert carried("all-reduce", "predict", f"f32[{m},{ROWS // 2}]")
+    assert carried("all-gather", "predict", f"f32[{m},{ROWS}]")
+    deltas = carried("all-reduce", "writeback", f"f32[{m},{width}]")
+    assert deltas and all(f"f32[{m},4]" in r for r in deltas)
+    assert not carried("all-reduce", "writeback", f"f32[{width}]")
+
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert not [n for n in names if "vmap" in n]
+    both = [n for n in names if "arm_map" in n.split("/")
+            and {"predict", "writeback"} & set(n.split("/"))]
+    assert not both, both
+    full, panel = ROWS * width, ROWS // 2 * width
+    for plane, took in zip(("f32", "bf16", "s8"), plane_branches(text)):
+        mapped = [line for line in took["all"]
+                  if "/arm_map/while/body/" in line]
+        once = [line for line in took["all"] if "/arm_map/" not in line]
+        assert any("/dual_loop/" in line for line in mapped), plane
+        assert not any(f"/{scope}/" in line for line in mapped for scope in (
+            "predict", "writeback", "gram_matmul", "gram_count")), plane
+        grams = [line for line in once if "/gram_matmul/" in line
+                 and re.search(r" convolution\(", line)
+                 and f"[{ROWS // 2},{ROWS}" in line]
+        assert len(grams) == 1, (plane, grams)
+        writers, readers, loops = _touching(took["top"], panel)
+        assert not loops, (plane, loops)
+        assert not any("/arm_map/" in line for line in writers + readers)
+        if plane == "f32":
+            continue   # the scatter build: its passes are the one-device's
+        build = [line for line in writers
+                 if any(n >= full for _op, _d, n in _results([line]))]
+        assert len(build) == 1 and "/gram_count/" in build[0], plane
+        assert _results(build).count(("fusion", "f32", ROWS)) == m, build
+        assert len(writers) <= 2, (plane, writers)
+        stages = sorted(
+            re.search(r"branch_\d_fun/(\w+)/", line).group(1)
+            for line in readers if "dynamic_slice" not in line)
+        assert stages == ["gram_matmul", "writeback"], (plane, readers)
+        (back,) = [line for line in readers if "/writeback/" in line]
+        assert _results([back]).count(("fusion", "f32", width)) == m, back

@@ -545,12 +545,14 @@ def build_mesh(conf, what: str = "training", model_axis: bool = False):
     can never diverge between apps.
 
     The mesh is ``('data',)`` over the run's devices. A caller whose model
-    can shard its WEIGHTS (``build_model``'s single-model SGD learners)
-    passes ``model_axis``: then ``--modelShards M`` (> 1) makes it
-    ``('data', 'model')`` = (devices / M) x M, and M must divide the device
-    count and ``--numTextFeatures``. Any other caller refuses M > 1 — a
-    flag that is silently ignored would train another deployment than the
-    one asked for.
+    can shard its WEIGHTS (``build_model``'s single-model SGD learners and,
+    under ``--tenantKey all``, the arms of a champion/challenger run, which
+    ARE that learner under M recipes) passes ``model_axis``: then
+    ``--modelShards M`` (> 1) makes it ``('data', 'model')`` =
+    (devices / M) x M, and M must divide the device count and
+    ``--numTextFeatures``. Any other caller — k-means, the tenant plane
+    under a partitioning key — refuses M > 1: a flag that is silently
+    ignored would train another deployment than the one asked for.
 
     Multi-host runs span the WHOLE process group's devices; jax.devices()
     is process-major, so the 1D data axis is automatically process-aligned
@@ -562,7 +564,8 @@ def build_mesh(conf, what: str = "training", model_axis: bool = False):
     if n_model > 1 and (not model_axis or jax.process_count() > 1):
         raise SystemExit(
             f"--modelShards {n_model}: the model axis is wired at the entry "
-            f"point for the single-model SGD learners on one host, not for "
+            f"point for the single-model SGD learners (and their arms under "
+            f"--tenantKey all) on one host, not for "
             f"{what}" + (" in a multi-host run" if model_axis else "")
         )
     if jax.process_count() > 1:
@@ -620,7 +623,12 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
     Composes with the data mesh (rows P(data), tenant axis
     replicated); the cross-process tenants-on-model-axis layout is driven
     at the library level (tests/test_distributed_multiprocess.py) — the
-    app-level multi-host wiring keeps its single-model plane for now."""
+    app-level multi-host wiring keeps its single-model plane for now.
+    ``--tenantKey all`` (M recipes of the ONE learner on every row) runs
+    on one device or, with ``--modelShards``, as the arms of the
+    feature-sharded mesh model (``ParallelSGDModel(arms=...)``: C and G
+    sharded and built once a batch for all arms); the data-only mesh, the
+    group wire and a multi-host run keep their refusals."""
     import jax as _jax
 
     # --wireAssemble: the fused one-pass native pack (r17) is a process-
@@ -656,7 +664,8 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
         # the Tenants frame and /api/tenants name each tenant's recipe
         _tenant_view.configure(*conf.tenant_recipes())
         # champion and challengers on the SAME rows (--tenantKey all): one
-        # host, one device (parallel/tenants.py has each reason)
+        # host; one device, or a mesh WITH a model axis (below;
+        # parallel/tenants.py has the reason for each refusal)
         shared_rows = getattr(conf, "tenantKey", "hash") == "all"
         if shared_rows and _jax.process_count() > 1:
             raise SystemExit(
@@ -698,19 +707,41 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
                 model.tenant_key,
             )
             return model, max(1, inner.num_data // _jax.process_count())
-        mesh = build_mesh(conf, what=f"tenant plane ({model_cls.__name__})")
-        if shared_rows and mesh is not None:
+        # the arms ARE the single-model learner, so they may take its
+        # model axis; a partitioning key keeps build_mesh's refusal
+        mesh = build_mesh(
+            conf, what=f"tenant plane ({model_cls.__name__})",
+            model_axis=shared_rows,
+        )
+        if shared_rows and mesh is not None and len(mesh.axis_names) < 2:
             raise SystemExit(
-                "--tenantKey all runs on one device (the arms map runs "
-                "inside one device's Gram branch): use --master local[1]"
+                "--tenantKey all runs on one device (--master local[1]) or, "
+                "with --modelShards, on a mesh with a model axis: the "
+                "data-only mesh has no form of the per-arm half"
             )
+        knobs = dict(
+            residual_fn=model_cls.residual_fn,
+            prediction_fn=model_cls.prediction_fn,
+            round_predictions=model_cls.round_predictions,
+        )
         try:
-            model = TenantStackModel.from_conf(
-                conf, mesh,
-                residual_fn=model_cls.residual_fn,
-                prediction_fn=model_cls.prediction_fn,
-                round_predictions=model_cls.round_predictions,
-            )
+            if shared_rows and mesh is not None:
+                # the arms of the feature-sharded mesh model: C and G
+                # sharded, built once a batch for all M recipes
+                from ..parallel import ParallelSGDModel
+                from ..parallel.tenants import SHARED_ROWS_GROUP_WIRE
+
+                if conf.wirePack == "group":
+                    raise ValueError(
+                        f"--tenantKey all: {SHARED_ROWS_GROUP_WIRE}"
+                    )
+                model = ParallelSGDModel.from_conf(
+                    conf, mesh, arms=conf.tenant_recipes(), **knobs
+                )
+                codec = getattr(conf, "effective_wire_codec", lambda: "off")()
+                model.wire_codec = codec if codec == "dict" else ""
+            else:
+                model = TenantStackModel.from_conf(conf, mesh, **knobs)
         except ValueError as exc:  # what the plane refuses, in its words
             raise SystemExit(str(exc)) from None
         log.info(
@@ -2550,9 +2581,17 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
 
     from ..utils.rss import RssWatchdog
 
+    mesh_dm = None
     if hasattr(model, "mesh_layout"):
         # start-up mark of a mesh model (a no-op without --trace)
-        _trace.get().instant("mesh_layout", **model.mesh_layout())
+        layout = model.mesh_layout()
+        _trace.get().instant("mesh_layout", **layout)
+        mesh_dm = [layout["data"], layout["model"]]
+        arms = model.mesh_arms(int(getattr(stream, "row_bucket", 0) or 0))
+        if arms:
+            # --tenantKey all on the mesh: what a chip ships a batch for
+            # the arms (parallel/sharding.ParallelSGDModel.mesh_arms)
+            _trace.get().instant("mesh_arms", **arms)
 
     # RSS watchdog on the batch cadence: the long-running loops are where
     # slow host-memory growth accumulates (utils/rss.py)
@@ -2638,6 +2677,8 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
                     extra["planes"] = np.asarray(out.quality)[
                         :, QUALITY_INDEX["gram_plane"]
                     ].astype(int).tolist()
+                if mesh_dm is not None and tenant_key == "all":
+                    extra["mesh"] = mesh_dm  # the arms' [d, m] mesh
                 # under ``all`` every arm saw the whole batch: rows is
                 # [B]·M, bucket B, no padding
                 tr.instant(

@@ -230,13 +230,76 @@ def dual_scale_and_alpha(dual, axis_name: str, rows: int):
     """This shard's slice of the dual state for a sharded write-back:
     (c, α_local). The psum-mean of c turns the identical-everywhere scale
     into a statically-invariant value (shard_map's replicated-output check),
-    and slicing α to local rows keeps the write-back contraction 1/shards."""
+    and slicing α to local rows keeps the write-back contraction 1/shards.
+    The M arms' stacked state (``c`` ``[M]``, ``alpha`` ``[M, B]``:
+    ``arms_dual_half`` on a mesh) is cut on its row axis, the last, and its
+    scales ride the ONE psum."""
+    alpha = dual["alpha"]
     alpha_local = lax.dynamic_slice_in_dim(
-        dual["alpha"], lax.axis_index(axis_name) * rows, rows
+        alpha, lax.axis_index(axis_name) * rows, rows, axis=alpha.ndim - 1
     )
     with jax.named_scope("collective"):
         c = lax.psum(dual["c"], axis_name) / lax.axis_size(axis_name)
     return c, alpha_local
+
+
+def arms_dual_half(
+    counts,
+    *,
+    w_text,
+    w_num,
+    numeric,
+    mapped,
+    dual: Callable,
+    dtype,
+    sum_features: Callable = lambda part: part,
+    all_rows: Callable = lambda raw: raw,
+    own_rows: Callable = lambda duals: (duals["c"], duals["alpha"]),
+    sum_rows: Callable = lambda delta: delta,
+):
+    """The per-arm half of the Gram basis for M arms on the SAME rows
+    (``--tenantKey all``), inside the branch of the plane taken, on its ONE
+    count matrix ``counts`` — the ONE place it exists: the single-device
+    step below and the feature-sharded step (parallel/sharding.py) both
+    call it, after they built G their own way.
+
+    ``u = C·[w_1…w_M]`` for all arms from one expression at the branch's
+    top level (scope ``predict``: the count build's epilogue takes it; the
+    numeric half is the single model's own matvec, an arm); the dual loop
+    ``lax.map``ped over the arms under the scope ``arm_map`` —
+    ``dual(*mapped_m, u_m)`` is one arm's ``run_dual_loop`` under its own
+    step size and L2, G shared through the closure, and ``mapped`` what it
+    takes per arm besides ``u``; then the write-back ``c_m·W_m + Zᵀα_m``
+    for all arms in ONE pass over C (scope ``writeback``). Nothing under
+    ``arm_map`` is also under ``predict`` or ``writeback``: the readers of
+    the benchmark count on it.
+
+    The four hooks are the reductions a mesh needs, identities on one
+    device: ``sum_features`` the ``[M, rows]`` text partials over the
+    feature shards, ``all_rows`` this shard's ``[M, rows]`` of ``u`` to
+    every row (ONE array for all arms), ``own_rows`` the mapped duals to
+    ``(c [M], α [M, rows])`` of this shard's rows
+    (``dual_scale_and_alpha``), ``sum_rows`` a write-back delta over the
+    row shards — each ONE collective with an ``[M, ·]`` payload, none
+    inside the map. ``w_text`` is ``[M, F]`` (this shard's slice of it),
+    ``w_num`` ``[M, 4]``, ``numeric`` this shard's rows. Returns ``((new
+    text weights, new numeric weights), this shard's rows of u)``."""
+    with jax.named_scope("predict"):
+        raw = (
+            sum_features(counts.dot(w_text))
+            + jnp.stack([numeric @ w for w in w_num])
+        ).astype(dtype)
+        u = all_rows(raw)
+    with jax.named_scope("arm_map"):
+        # each arm's own loop and converged-freeze, G shared
+        duals = lax.map(lambda args: dual(*args), (*mapped, u))
+    with jax.named_scope("writeback"):
+        # W_new = c·W_prev + Zᵀα for all arms: ONE read of C
+        c, alpha = own_rows(duals)
+        c = c[:, None]
+        delta_text = sum_rows(counts.tdot(alpha))
+        delta_num = sum_rows(jnp.stack([numeric.T @ a for a in alpha]))
+        return (w_text * c + delta_text, w_num * c + delta_num), raw
 
 
 def sampling_key(axis_name: str | None, mini_batch_fraction: float):
@@ -337,13 +400,18 @@ def make_sgd_train_step(
     float32 rounding in the Gram basis (where a compiler may fuse siblings
     and order a sum otherwise: PARITY.md, "the arm law") and bit for bit
     outside it, where the featurized batch is shared and the whole loop is
-    mapped (tests/test_tenant_grid.py). One device only: there is no
-    ``axis_name`` form.
+    mapped (tests/test_tenant_grid.py). This builder's ``arms`` step is the
+    one-device one: there is no ``axis_name`` (data-only mesh) form of it.
+    Across chips the arms run on the mesh WITH a model axis — the
+    feature-sharded step (parallel/sharding.py ``arms``), which calls the
+    same per-arm half, ``arms_dual_half``, with its reductions handed in.
     """
     if arms and axis_name:
         raise ValueError(
-            "arms on the same rows run on one device: the mapped half has "
-            "no data-axis form (parallel/tenants.py refuses the mesh)"
+            "arms on the same rows under a data axis alone: this builder's "
+            "arms step runs on one device, and the mesh that runs them has "
+            "a model axis (--modelShards; parallel/sharding.py). The "
+            "data-only mesh has no form of the per-arm half"
         )
     f_text = num_text_features
     sparse = f_text > DENSE_TEXT_FEATURE_LIMIT if use_sparse is None else use_sparse
@@ -381,13 +449,11 @@ def make_sgd_train_step(
         statically-invariant form shard_map requires).
 
         With ``arms`` (``weights`` ``[M, F+4]``) C and G are built ONCE and
-        C is READ once by each contraction: ``u`` for all arms from one
-        expression at the branch's top level (scope ``predict``: the count
-        build's epilogue takes it), the dual loop mapped over
-        ``(w_m, η_m, λ_m, u_m)`` under the scope ``arm_map`` (``lax.map``),
-        then the write-back for all arms in one pass (scope ``writeback``).
-        Nothing under ``arm_map`` is also under ``predict`` or
-        ``writeback``: a reader of the benchmark counts on it.
+        C is READ once by each contraction (``arms_dual_half``, the per-arm
+        half this step shares with the feature-sharded one): ``u`` for all
+        arms out of the count build, the dual loop mapped over
+        ``(w_m, η_m, λ_m, u_m)`` under the scope ``arm_map``, then the
+        write-back for all arms in one pass.
 
         ``row_args`` are GLOBAL (the caller all-gathers the batch under a
         data axis); ``local_numeric`` is this shard's rows. Returns
@@ -440,30 +506,17 @@ def make_sgd_train_step(
                 g = gram()  # ONE count matrix and ONE G for all M arms
                 with jax.named_scope("predict"):
                     w_text, w_num = weights[:, :f_text], weights[:, f_text:]
-                    # u for ALL arms from one expression at the branch's top
-                    # level: C·[w_1…w_M] rides the count build's epilogue as
-                    # the single model's C·w does; the numeric half is the
-                    # single model's own matvec, an arm
-                    raw = (
-                        counts.dot(w_text)
-                        + jnp.stack([local_numeric @ w for w in w_num])
-                    ).astype(dtype)
-
-                def arm(args):
-                    w, eta, lam, u = args
-                    return dual(w, u, g, eta, lam)
-
-                with jax.named_scope("arm_map"):
-                    # each arm's own loop and converged-freeze, G shared
-                    duals = lax.map(arm, (weights, step_size, l2_reg, raw))
+                halves, raw = arms_dual_half(
+                    counts,
+                    w_text=w_text,
+                    w_num=w_num,
+                    numeric=local_numeric,
+                    mapped=(weights, step_size, l2_reg),
+                    dual=lambda w, eta, lam, u: dual(w, u, g, eta, lam),
+                    dtype=dtype,
+                )
                 with jax.named_scope("writeback"):
-                    # W_new = c·W_prev + Zᵀα for all arms: ONE read of C
-                    c, alpha = duals["c"][:, None], duals["alpha"]
-                    delta_text = counts.tdot(alpha)
-                    delta_num = jnp.stack([local_numeric.T @ a for a in alpha])
-                    w_new = jnp.concatenate(
-                        [w_text * c + delta_text, w_num * c + delta_num], axis=1
-                    ).astype(dtype)
+                    w_new = jnp.concatenate(halves, axis=1).astype(dtype)
                 return w_new, raw
 
             w_text, w_num = whole

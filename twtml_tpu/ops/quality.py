@@ -116,7 +116,16 @@ def quality_vector(
     (the feature-sharded step, parallel/sharding.py) hands in ``weight_sq``
     = ‖w_new‖² and ``update_sq`` = ‖w_new − w_prev‖² already reduced over
     its model axis. ``gram_plane`` is ``text_gram``'s plane index
-    (axis-invariant already), None outside the Gram basis."""
+    (axis-invariant already), None outside the Gram basis.
+
+    What depends on the MODEL — ``residual``, ``preds``, ``weight_sq``,
+    ``update_sq`` — may lead with a model axis (``[M, B]`` rows, ``[M]``
+    norms: M arms on the same rows, parallel/sharding.py): every row
+    reduction runs over the LAST axis, each psum carries the M sums at
+    once, what depends on the batch alone (label and numeric moments, the
+    bucket histogram) is computed once, and the result is ``[M,
+    QUALITY_WIDTH]``. Without the axis the expression is what it always
+    was."""
     f32 = jnp.float32
     m = mask.astype(f32)
     n = _maybe_psum(jnp.sum(m), axis_name)
@@ -129,12 +138,17 @@ def quality_vector(
             jax.tree_util.tree_leaves(w_new), jax.tree_util.tree_leaves(w_prev)
         )
     ) if update_sq is None else update_sq
-    grad_sq = _maybe_psum(jnp.sum(residual.astype(f32) ** 2), axis_name)
+    grad_sq = _maybe_psum(
+        jnp.sum(residual.astype(f32) ** 2, axis=-1), axis_name
+    )
 
     def moments(x):
         x = x.astype(f32)
-        mean = _maybe_psum(jnp.sum(x * m), axis_name) / denom
-        var = _maybe_psum(jnp.sum(x * x * m), axis_name) / denom - mean * mean
+        mean = _maybe_psum(jnp.sum(x * m, axis=-1), axis_name) / denom
+        var = (
+            _maybe_psum(jnp.sum(x * x * m, axis=-1), axis_name) / denom
+            - mean * mean
+        )
         return mean, jnp.maximum(var, 0.0)
 
     pred_mean, pred_var = moments(preds)
@@ -172,7 +186,7 @@ def quality_vector(
     occupancy = jnp.mean((bins > 0).astype(f32))
     top_share = jnp.max(bins) / jnp.maximum(total, 1.0)
 
-    return jnp.stack(
+    fields = (
         [
             jnp.sqrt(w_sq),
             jnp.sqrt(upd_sq),
@@ -188,4 +202,6 @@ def quality_vector(
         + [num_var[i] for i in range(NUM_NUMERIC)]
         + [occupancy, top_share]
         + [jnp.asarray(-1.0 if gram_plane is None else gram_plane, f32)]
-    ).astype(f32)
+    )
+    # scalars all, or some leading with the model axis: one [..., Q] vector
+    return jnp.stack(jnp.broadcast_arrays(*fields), axis=-1).astype(f32)

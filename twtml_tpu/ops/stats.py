@@ -9,6 +9,12 @@ population stdev (divide by n), reproduced here.
 
 Every reduction takes an optional ``axis_name`` so the same code runs
 single-device (jit) and data-parallel (shard_map with a psum over ICI).
+
+Every reduction runs over the LAST axis, the rows: ``x`` may lead with a
+model axis (``[M, B]``: M arms on the same rows, parallel/sharding.py)
+against a ``[B]`` mask, and then each statistic of ``x`` leads with M and
+each psum carries the M of them at once. A ``[B]`` input traces to the
+expression it always did.
 """
 
 from __future__ import annotations
@@ -27,11 +33,11 @@ def _maybe_psum(x, axis_name):
 
 
 def masked_sum(x, mask, axis_name=None):
-    return _maybe_psum(jnp.sum(x * mask), axis_name)
+    return _maybe_psum(jnp.sum(x * mask, axis=-1), axis_name)
 
 
 def masked_count(mask, axis_name=None):
-    return _maybe_psum(jnp.sum(mask), axis_name)
+    return _maybe_psum(jnp.sum(mask, axis=-1), axis_name)
 
 
 def masked_mean(x, mask, axis_name=None):

@@ -51,6 +51,7 @@ from ..features.batch import (
 )
 from ..models.base import StepOutput
 from ..models.sgd import (
+    arms_dual_half,
     dual_scale_and_alpha,
     make_sgd_train_step,
     run_dual_loop,
@@ -159,10 +160,11 @@ def shard_batch(batch: FeatureBatch | UnitBatch | RaggedUnitBatch, mesh):
     ))
 
 
-def _all_gather(x, axis):
-    """Tiled ``lax.all_gather`` of the rows, under the ``collective`` scope."""
+def _all_gather(x, axis, rows_axis: int = 0):
+    """Tiled ``lax.all_gather`` of the rows (``rows_axis`` of ``x``), under
+    the ``collective`` scope."""
     with jax.named_scope("collective"):
-        return lax.all_gather(x, axis, axis=0, tiled=True)
+        return lax.all_gather(x, axis, axis=rows_axis, tiled=True)
 
 
 def _make_feature_sharded_step(
@@ -181,10 +183,34 @@ def _make_feature_sharded_step(
     model_axis: str,
     use_gram: bool | None = None,
     quality: bool = False,
+    arms: bool = False,
 ):
     """Per-shard body for the 2D (data × model) mesh. Weights arrive as a
     {'text': [f_text_local], 'num': [4]} pytree; token indices are global and
     each shard contributes only the tokens landing in its slice.
+
+    ``arms`` (``--tenantKey all --modelShards m``: a champion and its
+    challengers on the SAME rows, at a width one chip cannot hold) is the
+    step of M models that share their batch: the weights lead with the arm
+    axis, {'text': [M, f_text_local], 'num': [M, 4]}, ``step_size`` and
+    ``l2_reg`` are ``[M]``, every leaf of the output leads with M. What
+    does not depend on the model is the single model's step, untouched and
+    run ONCE: re-pad, hash, the batch all-gather, the plane gate and its
+    reductions, the slice's count matrix C, its G panel with the panel psum
+    and the G all-gather. The per-arm half is ``models/sgd.arms_dual_half``
+    (the one the single-device ``arms`` step calls) with this mesh's
+    reductions handed in, so C is read once for ``u`` of all arms (the
+    build's epilogue) and once for their write-back, the M dual loops run
+    replicated under ``arm_map`` with no collective inside a loop, and the
+    program issues the SAME NUMBER of collectives as without ``arms``, the
+    per-arm ones carrying ``[M, ·]`` payloads: the ``u`` partials' psum
+    over ``model`` and all-gather over ``data`` (``[M, B_local]``, one
+    array), ‖w_m‖² (``[M]``, one psum), the scale ``c`` (``[M]``), the two
+    write-back deltas (``[M, f_text_local]`` and ``[M, 4]``), and the batch
+    statistics and quality sums (ops/stats.py, ops/quality.py: each psum
+    takes the arm axis along). Arm m is this step without ``arms`` under arm m's
+    recipe, to float32 rounding (PARITY.md, "the arm law"). The Gram basis
+    only: a size outside it is refused when the step is traced.
 
     The inner loop runs in the Gram (dual) basis whenever it applies (f32
     weights, per-shard dense counts within HBM budget — ops/gram.py): one
@@ -277,7 +303,10 @@ def _make_feature_sharded_step(
 
         def norm_sq_of(text, num):
             # text slices live on the model axis; num is replicated there
-            return _psum(jnp.sum(text * text), model_axis) + jnp.sum(num * num)
+            # (over the LAST axis: with arms ``[M, ·]`` in, ``[M]`` out)
+            return _psum(
+                jnp.sum(text * text, axis=-1), model_axis
+            ) + jnp.sum(num * num, axis=-1)
 
         # ---- Gram (dual) basis when it applies (see docstring) ----------
         b_local = mask.shape[0]
@@ -352,6 +381,53 @@ def _make_feature_sharded_step(
                     }
                 return w_final, raw
 
+            def arms_basis(counts):
+                """``dual_basis`` for M arms on the same rows: the G panel
+                and its two collectives as above, ONCE; then the per-arm
+                half (models/sgd.arms_dual_half) with this mesh's
+                reductions, each one collective for all arms."""
+                panel = counts.gram()
+                with jax.named_scope("gram_matmul"):
+                    g_mat = _all_gather(_psum(panel, model_axis), data_axis)
+                g_mat = add_numeric_block(g_mat, num_g, dtype)
+                with jax.named_scope("dual_loop"):
+                    # ‖w_m‖² of every arm in ONE psum, before the map
+                    p_prev = norm_sq_of(w_text, w_num)
+
+                def dual(p, eta, lam, u):
+                    return run_dual_loop(
+                        u=u,
+                        g=g_mat,
+                        labels=lab_g,
+                        mask=mask_g,
+                        dtype=dtype,
+                        residual_fn=residual_fn,
+                        num_iterations=num_iterations,
+                        step_size=eta,
+                        mini_batch_fraction=mini_batch_fraction,
+                        l2_reg=lam,
+                        convergence_tol=convergence_tol,
+                        p_prev=p,
+                        vary_axis=data_axis,
+                    )
+
+                (new_text, new_num), raw = arms_dual_half(
+                    counts,
+                    w_text=w_text,
+                    w_num=w_num,
+                    numeric=numeric,
+                    mapped=(p_prev, step_size, l2_reg),
+                    dual=dual,
+                    dtype=dtype,
+                    sum_features=lambda part: _psum(part, model_axis),
+                    all_rows=lambda raw: _all_gather(raw, data_axis, 1),
+                    own_rows=lambda duals: dual_scale_and_alpha(
+                        duals, data_axis, b_local
+                    ),
+                    sum_rows=lambda delta: _psum(delta, data_axis),
+                )
+                return {"text": new_text.astype(dtype), "num": new_num}, raw
+
             (w_final, raw), plane = text_gram(
                 rel_g,
                 local_val_g,
@@ -359,12 +435,17 @@ def _make_feature_sharded_step(
                 row_start=lax.axis_index(data_axis) * b_local,
                 rows=b_local,
                 feature_axis=model_axis,
-                body=dual_basis,
+                body=arms_basis if arms else dual_basis,
             )
             # every data shard gated the same gathered rows: the pmin only
             # makes the index statically invariant (models/sgd.py)
             with jax.named_scope("gram_matmul"), jax.named_scope("collective"):
                 plane = lax.pmin(plane, data_axis)
+        elif arms:
+            raise ValueError(
+                "arms on a mesh run in the Gram basis only (float32 "
+                "weights, a slice's count matrix inside ops/gram.fits_gram)"
+            )
         else:
             with jax.named_scope("predict"):
                 rel, local_val = to_slice(g_idx, token_val)
@@ -380,14 +461,26 @@ def _make_feature_sharded_step(
             preds = prediction_fn(raw)
             if round_predictions:
                 preds = jnp_round_half_up(preds)
+            # with arms ``preds`` is [M, B_local]: an arm's stats are the
+            # single model's own reductions of its row, and each psum takes
+            # the M of them along (ops/stats.py: one collective, no loop)
             stats = batch_stats(labels, preds, mask, data_axis)
+            if arms:
+                stats = {
+                    k: jnp.broadcast_to(v, preds.shape[:1])
+                    for k, v in stats.items()
+                }
 
         def _quality(w_new, gram_plane=None):
-            # the ISSUE-8 side channel (models/sgd.py ``_quality``): rows
-            # reduce over ``data``, the text weights' norms over ``model``
+            # the ISSUE-8 side channel (models/sgd.py ``quality_of``): rows
+            # reduce over ``data``, the text weights' norms over ``model``.
+            # With arms it is per arm as on one device, under ``arm_map``:
+            # its sums lead with the arm axis (ops/quality.py), what the
+            # batch alone decides runs once
             if not quality:
                 return None
-            with jax.named_scope("quality"):
+            scope = "arm_map/quality" if arms else "quality"
+            with jax.named_scope(scope):
                 new_t, new_n, old_t, old_n = (
                     a.astype(jnp.float32)
                     for a in (w_new["text"], w_new["num"], w_text, w_num)
@@ -442,7 +535,20 @@ def _make_feature_sharded_step(
 
 class ParallelSGDModel:
     """Mesh-sharded streaming SGD learner with the same step surface as the
-    single-device models (models/sgd.py StreamingSGDModel)."""
+    single-device models (models/sgd.py StreamingSGDModel).
+
+    ``arms=(step sizes, L2 strengths)``, one of each per arm, makes it the
+    mesh model of a champion and its challengers on the SAME rows
+    (``--tenantKey all --modelShards m``): M recipes of the one learner on
+    the 2-D mesh, the weights {'text': [M, F], 'num': [M, 4]} with the
+    feature axis sharded over ``model`` and the arm axis leading, one
+    ``[M, ...]`` StepOutput a batch. It then carries the tenant plane's
+    surface too (``num_tenants``, ``tenant_key`` = ``all``,
+    ``shared_rows``, ``wire_pack``), so the delivery chain, the tenant
+    frames and the stacked ``[M, F+4]`` checkpoint treat it as
+    ``parallel/tenants.TenantStackModel`` under that key; the wire is the
+    single mesh model's. It needs the model axis: a data-only mesh has no
+    form of the per-arm half and is refused."""
 
     def __init__(
         self,
@@ -460,6 +566,7 @@ class ParallelSGDModel:
         use_sparse: bool | None = None,
         use_gram: bool | None = None,
         quality: bool = False,
+        arms=None,
     ) -> None:
         self.mesh = mesh
         self.num_text_features = num_text_features
@@ -473,6 +580,31 @@ class ParallelSGDModel:
         out_pred_spec = P(self.data_axis)
         scalar = P()
         self.quality = quality
+        # M arms on the same rows: every weights leaf and every output leaf
+        # leads with the (unsharded) arm axis
+        stack = ()
+        if arms is not None:
+            if self.model_axis is None:
+                raise ValueError(
+                    "--tenantKey all: the data-only mesh has no form of the "
+                    "per-arm half; the arms' mesh has a model axis "
+                    "(--modelShards), or use --master local[1]"
+                )
+            step_size, l2_reg = (np.asarray(v, dtype) for v in arms)
+            if step_size.ndim != 1 or step_size.shape != l2_reg.shape:
+                raise ValueError(
+                    "arms takes one step size and one L2 strength an arm, "
+                    f"got shapes {step_size.shape} and {l2_reg.shape}"
+                )
+            # the tenant plane's surface under --tenantKey all (class
+            # docstring): set on an arms model only, so a reader that asks
+            # ``getattr(model, "num_tenants", 0)`` still tells the planes
+            # apart
+            self.num_tenants = int(step_size.shape[0])
+            self.tenant_key, self.shared_rows = "all", True
+            self.wire_pack = "stacked"
+            stack = (self.num_tenants,)
+            out_pred_spec = P(None, self.data_axis)
 
         if self.model_axis is None:
             step = make_sgd_train_step(
@@ -516,15 +648,18 @@ class ParallelSGDModel:
                 model_axis=self.model_axis,
                 use_gram=use_gram,
                 quality=quality,
+                arms=arms is not None,
             )
+            w_spec = {
+                "text": P(*(None,) * len(stack), self.model_axis), "num": P(),
+            }
             self._weights = {
                 "text": jax.device_put(
-                    jnp.zeros((num_text_features,), dtype),
-                    NamedSharding(mesh, P(self.model_axis)),
+                    jnp.zeros(stack + (num_text_features,), dtype),
+                    NamedSharding(mesh, w_spec["text"]),
                 ),
-                "num": jnp.zeros((NUM_NUMBER_FEATURES,), dtype),
+                "num": jnp.zeros(stack + (NUM_NUMBER_FEATURES,), dtype),
             }
-            w_spec = {"text": P(self.model_axis), "num": P()}
 
         # the shard_map is built lazily per wire format (FeatureBatch and
         # UnitBatch differ in pytree structure, hence in in_specs); a stream
@@ -634,21 +769,54 @@ class ParallelSGDModel:
             "devices": self.mesh.size,
         }
 
+    def mesh_arms(self, rows: int) -> "dict | None":
+        """What a chip ships a batch FOR THE ARMS, as the ``mesh_arms``
+        trace instant carries it (``rows``: the batch's pinned row count, 0
+        where it is not pinned): the ``u`` all-gather over ``data`` hands
+        every chip the other data shards' ``[M, rows/d]`` f32 rows of it,
+        and the write-back psum over ``data`` moves the slice's ``[M, F/m]``
+        and ``[M, 4]`` f32 deltas. None on a model without arms."""
+        m = getattr(self, "num_tenants", 0)
+        if not m:
+            return None
+        layout = self.mesh_layout()
+        d = layout["data"]
+        return {
+            "arms": m, "data": d, "model": layout["model"],
+            "u_gather_bytes": m * (rows // d) * (d - 1) * 4,
+            "delta_psum_bytes": m * (
+                layout["f_text_local"] + NUM_NUMBER_FEATURES) * 4,
+        }
+
     @property
     def latest_weights(self) -> np.ndarray:
+        """``[F+4]``; with arms the stacked ``[M, F+4]``, one checkpointable
+        array for all of them (the tenant plane's layout)."""
         if isinstance(self._weights, dict):
             return np.concatenate(
                 [self._to_host(self._weights["text"]),
-                 self._to_host(self._weights["num"])]
+                 self._to_host(self._weights["num"])],
+                axis=-1,
             )
         return self._to_host(self._weights)
 
     def set_initial_weights(self, weights) -> "ParallelSGDModel":
+        """Takes what ``latest_weights`` gives. An arms model also takes one
+        flat ``[F+4]`` vector for every arm (the sentinel's zeros-reset),
+        as ``TenantStackModel`` does."""
         weights = np.asarray(weights, dtype=self.dtype)
+        m = getattr(self, "num_tenants", 0)
+        if m and weights.ndim == 1:
+            weights = np.broadcast_to(weights, (m,) + weights.shape).copy()
+        if m and weights.shape[0] != m:
+            raise ValueError(
+                f"stacked weights lead with {weights.shape[0]} tenants; "
+                f"this plane has {m}"
+            )
         if isinstance(self._weights, dict):
             ft = self.num_text_features
-            text = weights[:ft]
-            sharding = NamedSharding(self.mesh, P(self.model_axis))
+            text = weights[..., :ft]
+            sharding = NamedSharding(self.mesh, self._w_spec["text"])
             # make_array_from_callback, not device_put: checkpoint restore
             # must also work when the model axis spans processes and this
             # process does not address every shard (the allgather mirror of
@@ -657,7 +825,7 @@ class ParallelSGDModel:
                 "text": jax.make_array_from_callback(
                     text.shape, sharding, lambda idx: text[idx]
                 ),
-                "num": jnp.asarray(weights[ft:]),
+                "num": jnp.asarray(weights[..., ft:]),
             }
         else:
             self._weights = jnp.asarray(weights)

@@ -59,8 +59,13 @@ out of the count build, ``Cᵀ·[α_1…α_M]`` in one pass) and maps only the d
 loop over the arms (scope ``arm_map``, ``lax.map``: arm m is the single
 model under arm m's recipe, to float32 rounding in the Gram basis and bit
 for bit outside it). State, fetch, checkpoint and frames are the plane's:
-``[M, F+4]`` weights, one ``[M, ...]`` StepOutput. One device and the
-stacked wire only (refused otherwise, with the reason).
+``[M, F+4]`` weights, one ``[M, ...]`` StepOutput. THIS class is the
+one-device form, on the stacked wire only (refused otherwise, with the
+reason); on a mesh with a model axis (``--modelShards``: 2^20 dims, whose
+count matrix one chip cannot hold) the arms are the feature-sharded mesh
+model's, ``parallel/sharding.ParallelSGDModel(arms=...)``, which carries
+this plane's surface under the key and calls the same per-arm half
+(``models/sgd.arms_dual_half``).
 
 Mesh composition: a 1D ('data',) mesh shards every tenant batch's rows over
 ``data`` (tenant axis unsharded — weights replicated) with the per-shard
@@ -101,6 +106,13 @@ from ..telemetry import trace as _trace
 from ..utils import get_logger
 
 log = get_logger("parallel.tenants")
+
+# why --tenantKey all has no group wire, wherever it is asked for (here on
+# one device, apps/common.build_model on the mesh)
+SHARED_ROWS_GROUP_WIRE = (
+    "--wirePack group coalesces M tenant batches into one buffer; under "
+    "'all' there is ONE batch, shipped as the single-model wire"
+)
 
 
 def aggregate_tenant_output(out, batch, model) -> StepOutput:
@@ -250,13 +262,13 @@ class TenantStackModel:
         self.shared_rows = tenant_key == "all"
         if self.shared_rows:
             for bad, why in (
-                (wire_pack == "group",
-                 "--wirePack group coalesces M tenant batches into one "
-                 "buffer; under 'all' there is ONE batch, shipped as the "
-                 "single-model wire"),
+                (wire_pack == "group", SHARED_ROWS_GROUP_WIRE),
                 (mesh is not None,
-                 "the per-arm half has no data-axis form (the arms map runs "
-                 "inside one device's Gram branch): use --master local[1]"),
+                 "this stack is the ONE-device form of the arms (the map "
+                 "runs inside one device's Gram branch): use --master "
+                 "local[1]. On a mesh with a model axis (--modelShards) the "
+                 "arms are parallel/sharding.ParallelSGDModel(arms=...); "
+                 "the data-only mesh has no form of the per-arm half"),
             ):
                 if bad:
                     raise ValueError(f"--tenantKey all: {why}")

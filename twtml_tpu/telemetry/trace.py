@@ -86,7 +86,15 @@ Mesh layout: a mesh model's run opens with one ``mesh_layout`` instant
 (``apps/common.attach_pipeline``): ``data`` and ``model`` axis sizes,
 ``f_text_local`` (hashed features a model shard holds) and ``devices``.
 The device time of the mesh steps' collectives is not a span: it is on the
-device plane under the ``collective`` scope (parallel/sharding.py).
+device plane under the ``collective`` scope (parallel/sharding.py). A mesh
+model with ARMS (``--tenantKey all --modelShards m``, PR 52) adds one
+``mesh_arms`` instant beside it: ``arms``, ``data``, ``model`` and what a
+chip ships a batch for the arms, ``u_gather_bytes`` (the other data shards'
+``[M, B/d]`` f32 rows of ``u``) and ``delta_psum_bytes`` (the slice's
+``[M, F/m + 4]`` f32 write-back deltas); its device program maps the arms'
+dual loops under the ``arm_map`` scope, and each collective that carries
+the arms sits under ``collective`` inside ``predict``, ``dual_loop`` or
+``writeback``.
 
 Tenant plane (``--tenants M``, PR 35): ``tenant_split`` spans the host's
 route key, M-way split and stack or pack of the tenant wire (``rows``,
@@ -103,8 +111,10 @@ fullest's and a lower one for the rest) and ``pad_rows`` = Σ ``buckets`` −
 (``planes``, PR 42: off the stacked quality leaf of the same fetch; a
 near-dry part of short rows takes s8 beside bf16 ones)
 (apps/common.attach_pipeline); the device program sits under the
-``tenant_map`` scope, both halves of a two-rung one. None of the three
-exists on the single-model plane.
+``tenant_map`` scope, both halves of a two-rung one. Under ``--tenantKey
+all`` (key ``all``: every arm saw the whole batch, ``rows`` is ``[B]·M``)
+the instant of a MESH run also carries ``mesh`` = ``[d, m]``. None of the
+three exists on the single-model plane.
 
 Event sink (r8): the crash flight recorder (telemetry/blackbox.py) attaches
 via ``set_event_sink`` so recent spans ride its bounded in-memory ring —
