@@ -17,6 +17,9 @@ twins are in ``tests/test_tenant_grid.py``) and of
 ``benchmark/tests/test_hash2e20_grid4.py`` (PR 52: the arms on the 2 x 2
 mesh; its rehearsal, control and fault cases take minutes each at 2^20 dims
 and run by hand, their twins are in ``tests/test_tenant_grid_mesh.py``).
+``benchmark/tests/test_publish_ms_p95.py`` (PR 53) holds the metric that PR
+appended for every cell, and the two cases of the file before it that held a
+cell's own metrics to the END of its list, as they now read.
 """
 
 import benchmark.tests.conftest as _added_since  # noqa: F401
@@ -50,9 +53,13 @@ from benchmark.tests.test_hash2e20_grid4 import (  # noqa: F401
     test_program_flags_are_the_recorded_list as test_mesh_grid4_program_flags_are_the_recorded_list,
     test_readers_on_a_trace_made_by_hand as test_mesh_grid4_readers_on_a_trace_made_by_hand,
     test_the_cell_is_hash2e20_with_grid4s_four_recipes_on_its_rows,
-    test_the_cell_reports_hash2e20s_metrics_the_arms_two_and_its_own_two,
     test_the_four_chip_cells_are_these_two_of_eight,
-    # grid4's case of this name as it now reads: two of its three ``arm_*``
-    # metrics are listed on the mesh cell too (the new file's docstring)
+)
+from benchmark.tests.test_publish_ms_p95 import (  # noqa: F401
+    test_the_metric_is_the_last_entry_and_every_cell_lists_it,
+    # the mesh cell's case and grid4's case of these names as they now read:
+    # a metric appended for every cell stands after a cell's own (the new
+    # file's docstring; grid4's was restated once before, by PR 52)
+    test_the_cell_reports_hash2e20s_metrics_the_arms_two_and_its_own_two,
     test_grid4_reports_the_single_models_metrics_and_its_own_three as test_the_cell_reports_the_single_models_metrics_and_its_own_three,
 )

@@ -792,28 +792,40 @@ def _publish_spans(url, tmp_path, updates):
                   if e["name"] == "stats_publish"]
 
 
-def _frames(bodies):
-    """How many of the requests a server read were neither Stats nor
-    Series: the metrics frames of every eighth update."""
+# what ``modelwatch.last_model`` hands the publisher once a tick was recorded
+_MODEL_VIEW = {"level": "warn", "drift_score": 2.5, "loss_trend": 0.0,
+               "weight_norm": 0.0, "update_norm": 0.0, "grad_norm": 0.0,
+               "mse": [1.0], "tenants": [], "episodes": 0}
+
+
+def _frame_kinds(bodies):
+    """The requests a server read that were neither Stats nor Series: the
+    periodic frames, by kind, in the order they came."""
     kinds = [json.loads(b)["jsonClass"] for b in bodies]
-    return len(kinds) - kinds.count("Stats") - kinds.count("Series")
+    return [k for k in kinds if k not in ("Stats", "Series")]
 
 
 @pytest.mark.parametrize("keeps", [False, True],
                          ids=["closing_stub", "keeping_stub"])
 def test_stats_publish_span_carries_posts_and_connects(
         keeps, stub, tmp_path, monkeypatch):
+    """Two periods and one update: the frames go out one an update, each
+    once a period (PR 53), so no update sends more than three requests."""
     from benchmark import manifest, trace_files
+    from twtml_tpu.telemetry import modelwatch
     from twtml_tpu.telemetry.session_stats import METRICS_EVERY
 
+    monkeypatch.setattr(modelwatch, "last_model", lambda: _MODEL_VIEW)
     srv = stub(_serve_keeping if keeps else _serve_like_sink)
-    path, args = _publish_spans(srv.url, tmp_path, METRICS_EVERY + 1)
-    frames = _frames(srv.bodies)
-    assert frames >= 1  # the Metrics frame, at least
+    path, args = _publish_spans(srv.url, tmp_path, 2 * METRICS_EVERY + 1)
+    frames = _frame_kinds(srv.bodies)
+    assert {"Metrics", "ModelHealth"} <= set(frames)
+    assert all(frames.count(kind) == 2 for kind in set(frames))
     posts = [a["posts"] for a in args]
-    assert posts == [2] * (METRICS_EVERY - 1) + [2 + frames, 2]
+    assert set(posts) == {2, 3} and posts.count(3) == len(frames)
+    assert posts[METRICS_EVERY - 1] == posts[2 * METRICS_EVERY - 1] == 3
     connects = [a["connects"] for a in args]
-    assert connects == ([1] + [0] * METRICS_EVERY if keeps else posts)
+    assert connects == ([1] + [0] * (2 * METRICS_EVERY) if keeps else posts)
     assert sum(posts) == len(srv.bodies)
     assert srv.connections == sum(connects)
     assert all(a["rows"] == 8 for a in args)
@@ -832,6 +844,40 @@ def test_stats_publish_span_carries_posts_and_connects(
     assert reader.read({}) is None              # the parent's program
 
 
+@pytest.mark.parametrize("spans_ms, posts, p95", [
+    ([4.0] * 8 + [11.0], [2] * 8 + [7], 11.0),      # a burst in nine: it
+    ([4.0] * 39 + [11.0], None, 4.0),               # one in forty: not it
+    ([], None, None),                               # no such span
+    (None, None, None),                             # no live traced run
+], ids=["burst_of_nine", "one_in_forty", "no_span", "no_file"])
+def test_publish_ms_p95_reads_the_spans_durations(
+        spans_ms, posts, p95, tmp_path, monkeypatch, capsys):
+    from benchmark import manifest, trace_files
+
+    path = None
+    if spans_ms is not None:
+        path = tmp_path / "spans.json"
+        events = [{"name": "deliver_round", "ph": "i", "ts": 0.5}]
+        for k, ms in enumerate(spans_ms):
+            args = {"rows": 8}
+            if posts is not None:
+                args.update(posts=posts[k], connects=posts[k])
+            events.append({"name": "stats_publish", "ph": "X",
+                           "ts": 1e3 * k, "dur": 1e3 * ms, "args": args})
+        path.write_text("[\n" + "".join(
+            json.dumps(ev) + ",\n" for ev in events))
+    monkeypatch.setattr(trace_files, "span_file",
+                        lambda: None if path is None else str(path))
+    reader = manifest.load_module(manifest.layer_metric_path("publish_ms_p95"))
+    assert reader.read({}) == p95
+    said = capsys.readouterr().out
+    if p95 is None:
+        assert said == ""
+    else:
+        most = max(posts) if posts else "not carried"
+        assert f"most posts in one update: {most}" in said
+
+
 def test_stats_publish_span_against_the_dashboard(server, tmp_path):
     """The repo's own server keeps the connection: 2 / 1 on the first
     update, 2 / 0 on every one after it."""
@@ -841,11 +887,41 @@ def test_stats_publish_span_against_the_dashboard(server, tmp_path):
     assert json.loads(server[2].stats())["count"] == 32
 
 
+def test_the_dashboard_holds_every_frame_after_one_period(
+        server, tmp_path, monkeypatch):
+    """The frames reach the repo's own server one an update (PR 53) and it
+    caches each by its ``jsonClass``: after one period every view a client
+    asks for is there, beside the newest ``Stats``, though no round carried
+    two of them."""
+    from twtml_tpu.telemetry import (
+        freshness, historian, modelwatch, tenants)
+    from twtml_tpu.telemetry.session_stats import METRICS_EVERY
+
+    monkeypatch.setattr(modelwatch, "last_model", lambda: _MODEL_VIEW)
+    monkeypatch.setattr(tenants, "last_tenants", lambda: {
+        "tenants": [], "gating": 3, "active": 4})
+    monkeypatch.setattr(freshness, "last_freshness", lambda: {"batches": 7})
+    monkeypatch.setattr(historian, "last_history", lambda: {"samples": 5})
+    _, args = _publish_spans(server[1], tmp_path, METRICS_EVERY)
+    assert sorted(a["posts"] for a in args) == [2] * 3 + [3] * 5
+    cache = server[2]
+    assert json.loads(cache.stats())["count"] == 8 * METRICS_EVERY
+    assert "counters" in json.loads(cache.metrics())
+    assert json.loads(cache.tenants())["gating"] == 3
+    assert json.loads(cache.model())["driftScore"] == 2.5
+    assert json.loads(cache.freshness())["batches"] == 7
+    assert json.loads(cache.history())["samples"] == 5
+
+
 def test_benchmark_lint_passes_with_the_new_entry():
     from benchmark import manifest
 
-    entry = [m for m in manifest.load()["per_layer"]
-             if m["name"] == "publish_reuse_share"]
-    assert entry and entry[0]["layer"] == "publish"
-    assert entry[0]["moves"] == "batch_gap_ms_p95"
+    loaded = manifest.load()
+    for name in ("publish_reuse_share", "publish_ms_p95"):
+        entry = [m for m in loaded["per_layer"] if m["name"] == name]
+        assert entry and entry[0]["layer"] == "publish"
+        assert entry[0]["moves"] == "batch_gap_ms_p95"
+        assert entry[0]["workloads"] == [
+            w["name"] for w in loaded["workloads"]]
+    assert loaded["per_layer"][-1]["name"] == "publish_ms_p95"
     assert manifest.lint() == []

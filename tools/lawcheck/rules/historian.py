@@ -25,8 +25,8 @@ class TW010HistorianSeam(Rule):
     law = (
         "the telemetry historian adds zero fetches/collectives only "
         "because historian.sample() is called from exactly ONE seam — "
-        "SessionStats.publish_metrics, which has already computed every "
-        "view the sample snapshots; any other sampling site pays new "
+        "SessionStats' once-a-period item (_publish_registry), at the "
+        "stats-publish cadence; any other sampling site pays new "
         "snapshot work on a hot path or invites a device fetch the "
         "counted-fetch law forbids (telemetry/historian.py docstring; "
         "ISSUE 20)"

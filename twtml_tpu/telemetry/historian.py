@@ -9,8 +9,10 @@ trajectory) could not be answered from a run's leftovers.
 The historian closes that gap with the cheapest possible sampling:
 
 - **Sampled at the EXISTING stats-publish cadence.** ``sample()`` is called
-  from exactly one place — ``SessionStats.publish_metrics`` (lawcheck TW010
-  pins the seam the way TW009 pins the journal seam) — and snapshots the
+  from exactly one place — ``SessionStats._publish_registry``, item 0 of
+  the publisher's period, once every ``METRICS_EVERY`` updates (lawcheck
+  TW010 pins the seam file the way TW009 pins the journal seam;
+  ``publish_metrics`` runs the same item) — and snapshots the
   ALREADY-COMPUTED registry/health/stage views. Zero added host fetches,
   zero added collectives (counted in tests/test_history.py like PR 5/8/16).
 - **The journal's durability discipline.** CRC32-framed JSON records in
@@ -275,7 +277,7 @@ class Historian:
 
     def sample(self) -> None:
         """Snapshot the already-computed telemetry views into one durable
-        record. Called ONLY from SessionStats.publish_metrics (TW010) —
+        record. Called ONLY from SessionStats._publish_registry (TW010) —
         pure host-side reads: registry snapshot, health-monitor summary,
         cumulative stage clock, /proc statm. No device traffic."""
         from ..utils.rss import rss_mb
@@ -670,9 +672,9 @@ def get() -> "Historian | None":
 
 
 def sample() -> None:
-    """THE sampling hook (lawcheck TW010: only SessionStats.publish_metrics
-    may call this) — no-op when the historian is off so ``--history off``
-    is bit-exact pre-historian behavior."""
+    """THE sampling hook (lawcheck TW010: only SessionStats, from item 0 of
+    its period, may call this) — no-op when the historian is off so
+    ``--history off`` is bit-exact pre-historian behavior."""
     if _HISTORIAN is not None:
         _HISTORIAN.sample()
 

@@ -34,13 +34,19 @@ log = get_logger("telemetry.session")
 # with every streaming chart — telemetry/lightning.py)
 SERIES_MAX_POINTS = CHART_MAX_POINTS
 
-# publish a pipeline-metrics snapshot every N stats updates: counters move
-# every batch but the dashboard panel doesn't need per-batch resolution,
-# and each publish is one more best-effort HTTP POST on the hot path: one
-# buffer, one ``sendall`` and the reads of the reply on the socket the
-# client keeps, plus a ``connect`` where the server closed the last one
-# (telemetry/web_client.py, PR 41; redirects are not followed) — and the
-# scheduler still waits for every reply before it goes on
+# the PERIOD, in stats updates, of every observability frame (``Metrics``,
+# ``Hosts``, ``Tenants``, ``ModelHealth``, ``Freshness``, ``History``) and
+# of the historian's sample: counters move every batch but the dashboard
+# panel doesn't need per-batch resolution, and each frame is one more
+# best-effort HTTP POST on the hot path: one buffer, one ``sendall`` and
+# the reads of the reply on the socket the client keeps, plus a ``connect``
+# where the server closed the last one (telemetry/web_client.py, PR 41;
+# redirects are not followed) — and the scheduler still waits for every
+# reply before it goes on. So the frames go out ONE AN UPDATE, each at a
+# phase of its own (``SessionStats._PERIODIC``), not all on the eighth:
+# sent together they made one gap in eight ~7 ms longer than the others,
+# and one gap in eight is over 5% of the gaps, so that burst WAS the p95
+# of the time between published batches wherever the host sets the pace
 METRICS_EVERY = 8
 
 # degraded-phase load shedding: ship only every Nth batch's series frame
@@ -206,15 +212,41 @@ class SessionStats:
 
         _freshness.record_publish()
         self._updates += 1
-        if self._updates % METRICS_EVERY == 0:
-            self.publish_metrics()
+        phase = self._updates % METRICS_EVERY
+        if phase < len(self._PERIODIC):
+            self._PERIODIC[phase](self)
 
     def publish_metrics(self) -> None:
-        """Best-effort push of the process metrics registry + fetch-health
-        summary to the dashboard's observability panel (/api/metrics) —
-        with derived per-histogram p50/p95/p99 (the latency tile), and the
-        per-host ``Hosts`` view when a lockstep sideband is live."""
-        # host-process gauges, sampled per publish tick (ISSUE 8 satellite):
+        """Everything the session owes once a period, NOW and in one call:
+        the host gauges, the historian's sample and the ``Metrics`` frame
+        (the process metrics registry + fetch-health summary for the
+        dashboard's observability panel, /api/metrics), then ``Hosts``,
+        ``Tenants``, ``ModelHealth``, ``Freshness`` and ``History``, each a
+        best-effort frame of its own where its view is set. The apps call
+        it for their final snapshot. ``_update`` never does: it sends the
+        same items ONE AN UPDATE, item k on the update whose ordinal is k
+        modulo ``METRICS_EVERY``, each with what its view holds then —
+        all six in one round made one gap in eight ~7 ms longer, and that
+        gap was the p95 of a host-paced run."""
+        for item in self._PERIODIC:
+            item(self)
+
+    def _frame(self, kind: str, send) -> None:
+        """One best-effort observability frame behind the web breaker."""
+        if not self._web_breaker.allow():
+            return
+        try:
+            send()
+            self._web_breaker.record_success()
+        except Exception:
+            self._web_breaker.record_failure()
+            log.debug("web.%s failed", kind, exc_info=True)
+
+    def _publish_registry(self) -> None:
+        """Item 0: the host gauges, the historian's sample, and the
+        ``Metrics`` frame with derived per-histogram p50/p95/p99 (the
+        latency tile)."""
+        # host-process gauges, sampled once a period (ISSUE 8 satellite):
         # makes host RSS growth visible on every /api/metrics payload and post-mortem bundle —
         # statm reads, no device traffic
         try:
@@ -227,7 +259,7 @@ class SessionStats:
                 round(_time_mod.monotonic() - _PROCESS_START_S, 1)
             )
             # continuous leak-rate gauge (ISSUE 16 satellite): least-squares
-            # MB/min over the rolling publish-tick samples — the soak
+            # MB/min over the rolling once-a-period samples — the soak
             # estimator, live, so a host-memory leak shows as a rate
             # without a dedicated soak run
             self._rss_samples.append((_time_mod.monotonic(), cur_mb))
@@ -237,17 +269,16 @@ class SessionStats:
         except Exception:
             pass
         # telemetry historian (ISSUE 20): THE sampling seam — lawcheck
-        # TW010 pins historian.sample() to this method. It snapshots the
-        # registry/health/stage views this publish tick already computed
-        # (pure host reads, zero device traffic); no-op when --history off.
-        # BEFORE the breaker gate: the historian writes to local disk, so a
-        # dead dashboard must not stop the durable timeline
+        # TW010 pins historian.sample() to this file, and this item is its
+        # one caller, once a period. It snapshots the registry/health/stage
+        # views (pure host reads, zero device traffic); no-op when
+        # --history off. BEFORE the breaker gate: the historian writes to
+        # local disk, so a dead dashboard must not stop the durable timeline
         from . import historian as _historian
 
         _historian.sample()
-        if not self._web_breaker.allow():
-            return
-        try:
+
+        def send():
             snap = _metrics.get_registry().snapshot()
             # ship the derived quantiles, not the raw buckets: the
             # dashboard tile wants three numbers per histogram, and the
@@ -263,88 +294,93 @@ class SessionStats:
                 _metrics.get_health_monitor().summary(),
                 histograms=hists,
             )
-            self._web_breaker.record_success()
-        except Exception:
-            self._web_breaker.record_failure()
-            log.debug("web.metrics failed", exc_info=True)
+
+        self._frame("metrics", send)
+
+    def _publish_hosts(self) -> None:
+        """The per-host ``Hosts`` view, when a lockstep sideband is live."""
         view = _sideband.last_hosts()
-        if view is not None and self._web_breaker.allow():
-            try:
-                # elastic membership summary rides the same Hosts frame
-                # (registry gauges the membership plane maintains; zero
-                # when the run is not elastic)
-                msnap = _metrics.get_registry().snapshot()
-                gauges = msnap["gauges"]
-                counters = msnap["counters"]
-                self.web.hosts(
-                    view["hosts"], view["straggler"], view["stage"],
-                    view["skew_ms"],
-                    epoch=int(gauges.get("elastic.epoch", -1)),
-                    live_hosts=int(gauges.get("elastic.live_hosts", 0)),
-                    departed=int(counters.get("elastic.hosts_departed", 0)),
-                    rejoined=int(counters.get("elastic.hosts_rejoined", 0)),
-                    lead_uid=int(gauges.get("elastic.lead_uid", -1)),
-                )
-                self._web_breaker.record_success()
-            except Exception:
-                self._web_breaker.record_failure()
-                log.debug("web.hosts failed", exc_info=True)
-        # per-tenant model-plane view (telemetry/tenants.py — recorded by
-        # the tenant handle adapter from the already-fetched stacked
-        # StepOutput; empty on single-tenant runs)
+        if view is None:
+            return
+
+        def send():
+            # elastic membership summary rides the same Hosts frame
+            # (registry gauges the membership plane maintains; zero
+            # when the run is not elastic)
+            msnap = _metrics.get_registry().snapshot()
+            gauges = msnap["gauges"]
+            counters = msnap["counters"]
+            self.web.hosts(
+                view["hosts"], view["straggler"], view["stage"],
+                view["skew_ms"],
+                epoch=int(gauges.get("elastic.epoch", -1)),
+                live_hosts=int(gauges.get("elastic.live_hosts", 0)),
+                departed=int(counters.get("elastic.hosts_departed", 0)),
+                rejoined=int(counters.get("elastic.hosts_rejoined", 0)),
+                lead_uid=int(gauges.get("elastic.lead_uid", -1)),
+            )
+
+        self._frame("hosts", send)
+
+    def _publish_tenants(self) -> None:
+        """Per-tenant model-plane view (telemetry/tenants.py — recorded by
+        the tenant handle adapter from the already-fetched stacked
+        StepOutput; empty on single-tenant runs)."""
         from . import tenants as _tenants
 
-        tview = _tenants.last_tenants()
-        if tview is not None and self._web_breaker.allow():
-            try:
-                self.web.tenants(
-                    tview["tenants"], tview["gating"], tview["active"],
-                )
-                self._web_breaker.record_success()
-            except Exception:
-                self._web_breaker.record_failure()
-                log.debug("web.tenants failed", exc_info=True)
-        # model-health view (telemetry/modelwatch.py — derived from the
-        # in-step quality vector the pipeline already fetched; empty until
-        # a --modelWatch tick has been recorded)
+        view = _tenants.last_tenants()
+        if view is not None:
+            self._frame("tenants", lambda: self.web.tenants(
+                view["tenants"], view["gating"], view["active"]))
+
+    def _publish_model_health(self) -> None:
+        """Model-health view (telemetry/modelwatch.py — derived from the
+        in-step quality vector the pipeline already fetched; empty until
+        a --modelWatch tick has been recorded)."""
         from . import modelwatch as _modelwatch
 
-        mview = _modelwatch.last_model()
-        if mview is not None and self._web_breaker.allow():
-            try:
-                self.web.model_health(
-                    level=mview["level"],
-                    drift_score=mview["drift_score"],
-                    loss_trend=mview["loss_trend"],
-                    weight_norm=mview["weight_norm"],
-                    update_norm=mview["update_norm"],
-                    grad_norm=mview["grad_norm"],
-                    mse=mview["mse"],
-                    tenants=mview["tenants"],
-                    episodes=mview["episodes"],
-                )
-                self._web_breaker.record_success()
-            except Exception:
-                self._web_breaker.record_failure()
-                log.debug("web.model_health failed", exc_info=True)
-        # end-to-end freshness view (telemetry/freshness.py — derived from
-        # lineage records stamped at seams the pipeline already crosses;
-        # None until a delivery has been observed or when --freshness off)
+        view = _modelwatch.last_model()
+        if view is not None:
+            self._frame("model_health", lambda: self.web.model_health(
+                level=view["level"],
+                drift_score=view["drift_score"],
+                loss_trend=view["loss_trend"],
+                weight_norm=view["weight_norm"],
+                update_norm=view["update_norm"],
+                grad_norm=view["grad_norm"],
+                mse=view["mse"],
+                tenants=view["tenants"],
+                episodes=view["episodes"],
+            ))
+
+    def _publish_freshness(self) -> None:
+        """End-to-end freshness view (telemetry/freshness.py — derived from
+        lineage records stamped at seams the pipeline already crosses;
+        None until a delivery has been observed or when --freshness off)."""
         from . import freshness as _freshness
 
-        fview = _freshness.last_freshness()
-        if fview is not None and self._web_breaker.allow():
-            try:
-                self.web.freshness(fview)
-                self._web_breaker.record_success()
-            except Exception:
-                self._web_breaker.record_failure()
-                log.debug("web.freshness failed", exc_info=True)
-        hview = _historian.last_history()
-        if hview is not None and self._web_breaker.allow():
-            try:
-                self.web.history(hview)
-                self._web_breaker.record_success()
-            except Exception:
-                self._web_breaker.record_failure()
-                log.debug("web.history failed", exc_info=True)
+        view = _freshness.last_freshness()
+        if view is not None:
+            self._frame("freshness", lambda: self.web.freshness(view))
+
+    def _publish_history(self) -> None:
+        """The historian's view as of its latest sample (item 0's, up to
+        five updates back); None when --history off or nothing sampled."""
+        from . import historian as _historian
+
+        view = _historian.last_history()
+        if view is not None:
+            self._frame("history", lambda: self.web.history(view))
+
+    # what the session owes once every ``METRICS_EVERY`` updates, in the
+    # order ``publish_metrics`` sends it: item k goes out on the update
+    # whose ordinal is k modulo ``METRICS_EVERY``, after that update's own
+    # POSTs, and the phases past the last item stay plain
+    _PERIODIC = (
+        _publish_registry,
+        _publish_hosts,
+        _publish_tenants,
+        _publish_model_health,
+        _publish_freshness,
+        _publish_history,
+    )

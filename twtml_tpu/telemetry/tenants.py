@@ -1,7 +1,7 @@
 """Per-tenant telemetry view for the multi-tenant model plane (ISSUE 7).
 
 Mirrors the sideband's ``Hosts`` pattern (telemetry/sideband.py →
-``last_hosts`` → SessionStats.publish_metrics → /api/hosts): the tenant
+``last_hosts`` → SessionStats, one frame a period → /api/hosts): the tenant
 handle adapter (apps/common.attach_tenant_plane) records one row per tenant
 per delivered tick from the ALREADY-FETCHED stacked StepOutput — pure
 host-side bookkeeping, ZERO added host fetches (the r2/r3 measurement law)

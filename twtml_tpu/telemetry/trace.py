@@ -68,8 +68,9 @@ in flight). ``paired_delivery_share`` reads it: the share of deliveries
 made two or more to a round.
 
 Publish (PR 41): every ``stats_publish`` span carries ``posts`` (the
-requests that update sent: ``Stats`` and ``Series``, on every eighth update
-the metrics frames too) and ``connects`` (the connections
+requests that update sent: ``Stats`` and ``Series``, and the one periodic
+frame due on that update, if any: 2 or 3, ``telemetry/session_stats.py``)
+and ``connects`` (the connections
 ``telemetry/web_client.WebClient`` opened for them: 0 while the server keeps
 the connection, one a request where it closes each). ``publish_reuse_share``
 reads both.
