@@ -849,8 +849,14 @@ def test_count_plane_contracts_the_built_shape_like_the_2d_form(
     built = []  # (dtype, shape) of each plane's C, in the switch's order
 
     def body(counts):
-        built.append((counts.c.dtype, counts.c.shape))
-        flat = counts.c.astype(jnp.float32).reshape(b, -1)
+        # PR 54: under a row panel C is BUILT as two arrays, the own rows
+        # and the rest in rolled order (the rows after them, wrapping)
+        parts = [counts.c_own] + ([counts.c_rest] if rows else [])
+        assert (counts.c_rest is None) == (not rows)
+        built.append([(part.dtype, part.shape) for part in parts])
+        flat = jnp.concatenate(
+            [part.astype(jnp.float32).reshape(part.shape[0], -1)
+             for part in parts])
         flat = jnp.pad(flat, ((0, 0), (0, width - flat.shape[1])))
         return flat, counts.gram(), counts.dot(w), counts.tdot(alpha)
 
@@ -858,11 +864,13 @@ def test_count_plane_contracts_the_built_shape_like_the_2d_form(
         i, v, f_text, row_start=r, rows=rows, body=body))(idx, val, start)
     assert int(took) == _PLANE_INDEX[plane]
     dtype = {"exact": jnp.float32, "bf16": jnp.bfloat16, "s8": jnp.int8}[plane]
-    assert built[_PLANE_INDEX[plane]] == (
-        dtype, (b, f_text) if plane == "exact" else (b, *split))
+    features = (f_text,) if plane == "exact" else split
+    assert built[_PLANE_INDEX[plane]] == [
+        (dtype, (n, *features)) for n in ([rows, b - rows] if rows else [b])]
 
-    # the 2-D form: C is the exact counts, and zero past f_text
-    flat = np.asarray(flat)
+    # the 2-D form: C is the exact counts, and zero past f_text (the two
+    # builds un-rolled to the batch's order first)
+    flat = np.roll(np.asarray(flat), start or 0, axis=0)
     assert not flat[:, f_text:].any()
     c2 = jnp.asarray(flat[:, :f_text]).astype(dtype)
     np.testing.assert_array_equal(
@@ -893,14 +901,14 @@ def test_count_plane_contracts_the_built_shape_like_the_2d_form(
 
 def _plane_of(plane: str, batch, f_text: int):
     """The plane's C as its builder writes it, as a ``CountPlane`` on one
-    device (``left`` the identity)."""
+    device (no rest, no row start)."""
     idx, val = jnp.asarray(batch.token_idx), jnp.asarray(batch.token_val)
     c = {
         "exact": lambda: densify_text(idx, val, f_text),
         "bf16": lambda: gram_ops.onehot_counts(idx, val, f_text),
         "s8": lambda: gram_ops.onehot_counts_int8(idx, val, f_text),
     }[plane]()
-    return c, lambda c: gram_ops.CountPlane(c, lambda x: x, f_text)
+    return c, lambda c: gram_ops.CountPlane(c, None, None, f_text)
 
 
 def _parents_dot(c, w):

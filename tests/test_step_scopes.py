@@ -3,6 +3,7 @@
 that ``benchmark/stage_times.py`` sums a profile by. Lowering only — nothing
 runs, so the tiny batch can keep hash2e18's 2^18 text dims."""
 
+import collections
 import re
 
 import jax
@@ -235,6 +236,120 @@ def test_programs_outside_the_gram_basis_are_the_parents(program):
     total, some = _PARENT_OPS[program]
     assert len(ops) == total
     assert {name: ops.count(name) for name in some} == some
+
+
+# ---------------------------------------------------------------------------
+# PR 54: under a row panel (a mesh step) ``text_gram`` builds C as two arrays
+# and ``CountPlane.gram`` concatenates two products; the ONE-DEVICE steps ask
+# for no panel and lower to the parent's program.
+
+_LOC = re.compile(r"^(#loc\d+) = loc\((.*)\)$", re.M)
+_LOCATED_OP = re.compile(
+    r"\bstablehlo\.([a-z_0-9]+)[^\n]*? -> (tensor<[^>]*>)[^\n]*?"
+    r"loc\((#loc\d+)\)\s*$", re.M)
+
+
+def scoped_ops(lowered, scopes, ops) -> list:
+    """(scope, op, elements of its result) of every ``ops`` instruction of
+    the lowered module whose location's op-name path holds one of
+    ``scopes``."""
+    text = lowered.as_text(debug_info=True)
+    locs = dict(_LOC.findall(text))
+
+    def path(ref: str) -> list:
+        for _hop in range(8):  # a callsite / fused location names another
+            body = locs.get(ref, "")
+            named = re.match(r'"([^"]*)"', body)
+            if named:
+                return named.group(1).split("/")
+            inner = re.findall(r"#loc\d+", body)
+            if not inner:
+                break
+            ref = inner[0]
+        return []
+
+    out = []
+    for op, result, ref in _LOCATED_OP.findall(text):
+        if op in ops:
+            size = int(np.prod([int(d) for d in re.findall(r"(\d+)x", result)]))
+            under = path(ref)
+            out += [(s, op, size) for s in scopes if s in under]
+    return out
+
+
+def _one_device_programs():
+    from twtml_tpu.parallel import TenantStackModel
+
+    m, rows, row_len = 4, 8, 16
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    def stacked(model, step, wire):
+        return jax.jit(step, donate_argnums=0).lower(
+            shape(m, F_TEXT + 4), {k: shape(m) for k in model._hyper}, wire)
+
+    def arms():
+        model = TenantStackModel(
+            m, num_text_features=F_TEXT, tenant_key="all",
+            step_sizes=[0.005, 0.005, 0.0025, 0.0025],
+            l2_regs=[0.1, 0.01, 0.1, 0.01], quality=True)
+        return stacked(model, model._shared, _ragged(rows, row_len))
+
+    def tenants():
+        model = TenantStackModel(m, num_text_features=F_TEXT, l2_reg=0.1,
+                                 step_size=0.005, quality=True)
+        return stacked(model, model._mapped, RaggedUnitBatch(
+            shape(m, 64, dtype=jnp.uint16),
+            shape(m, rows + 1, dtype=jnp.int32), shape(m, rows, 4),
+            shape(m, rows), shape(m, rows), row_len=row_len))
+
+    return {"single": lambda: _lowered("packed", quality=True),
+            "arms": arms, "tenants": tenants}
+
+
+# (scope, op) → how many the parent of PR 54 lowered, 8 rows of 16, all three
+# planes' branches together (the concatenates are rung 2's run starts and
+# the ``[F + 4]`` weights; the arms' one dynamic slice takes an arm's row of
+# the ``[M, 4]`` numeric weights)
+_SINGLE_MODEL_OPS = {
+    ("gram_count", "concatenate"): 2, ("gram_count", "dot_general"): 2,
+    ("gram_matmul", "dot_general"): 6, ("predict", "dot_general"): 3,
+    ("writeback", "concatenate"): 3, ("writeback", "dot_general"): 3,
+}
+_PARENT_GRAM_OPS = {
+    "single": _SINGLE_MODEL_OPS,
+    "tenants": _SINGLE_MODEL_OPS,
+    "arms": {
+        ("gram_count", "concatenate"): 2, ("gram_count", "dot_general"): 2,
+        ("gram_matmul", "dot_general"): 6,
+        ("predict", "concatenate"): 6, ("predict", "dot_general"): 12,
+        ("predict", "dynamic_slice"): 1,
+        ("writeback", "concatenate"): 9, ("writeback", "dot_general"): 12,
+    },
+}
+
+
+@pytest.mark.parametrize("program", sorted(_PARENT_GRAM_OPS))
+def test_one_device_gram_scopes_lower_to_the_parents_program(program):
+    """The six one-chip cells call ``text_gram`` with ``rows = 0``: one
+    build, one G product a plane, and NOTHING of C's size sliced or
+    concatenated — the products, dynamic slices and concatenates under the
+    four scopes that touch C are the parent's, count for count (their
+    whole StableHLO text was the parent's character for character when
+    PR 54 was built: PERF.md §6)."""
+    found = scoped_ops(
+        _one_device_programs()[program](),
+        ("gram_count", "gram_matmul", "writeback", "predict"),
+        ("dot_general", "dynamic_slice", "concatenate"))
+    counts = collections.Counter((scope, op) for scope, op, _size in found)
+    assert dict(counts) == _PARENT_GRAM_OPS[program]
+    assert not [(scope, op) for scope, op, _size in found
+                if scope.startswith("gram") and op == "dynamic_slice"]
+    # no slice or concatenate as large as C (8 rows of it, on any plane)
+    moved = [(scope, op, size) for scope, op, size in found
+             if op != "dot_general" and size >= 8 * F_TEXT]
+    assert not moved, moved
 
 
 @pytest.fixture(scope="module")
@@ -473,7 +588,9 @@ _ALIASES = ("get-tuple-element", "bitcast", "tuple", "parameter")
 
 
 @pytest.mark.parametrize("layout", sorted(_TPU_STEPS))
-@pytest.mark.parametrize("plane, branch", [("bf16", 1), ("s8", 2)])
+@pytest.mark.parametrize("plane, branch", [
+    ("exact", 0), ("bf16", 1), ("s8", 2),
+])
 def test_compiled_fast_plane_writes_its_count_matrix_once(
     tpu_branches, layout, plane, branch
 ):
@@ -483,29 +600,45 @@ def test_compiled_fast_plane_writes_its_count_matrix_once(
     a second materialisation (at hash2e20 the ``reshape`` to ``[B, F]``
     was a ``copy`` of 2 GiB a batch) — and the fusion that writes it also
     yields the ``f32[ROWS]`` ``u = C·w``: the predict contraction in the
-    build's epilogue, no read of C of its own. On a mesh (``2x2``: the
-    feature-sharded step; ``4x1``: the data-only one) one more array, of
-    the PANEL's size, may be written (this shard's rows of C, read by the
-    G product and the write-back); on one device none."""
+    build's epilogue, no read of C of its own.
+
+    PR 54, on a mesh (``2x2``: the feature-sharded step; ``4x1``: the
+    data-only one): C is BUILT as two arrays, this shard's rows and the
+    rest (ops/gram.text_gram), each written by the one-hot product's
+    fusion, the own rows' one with its ``f32[rows]`` ``u``; together they
+    are C's bytes, and NO further array of the plane's type as large as
+    the row panel is written — the panel the G product and the write-back
+    read IS the own rows' build (until then a ``dynamic-slice`` fusion
+    wrote it a second time: 1 GiB read + 1 GiB written a batch at
+    hash2e20). The exact plane builds the same two arrays by its scatter
+    (no epilogue there: its ``u`` is a reduction of its own)."""
     _compile, width, panel = _TPU_STEPS[layout]
+    own = panel // width  # this shard's rows: all of them on one device
+    own_type = {"exact": "f32"}.get(plane, plane)
     # (line, the arrays it yields) of every instruction that WRITES memory
     written = [(line, _results([line]))
                for line in tpu_branches(layout)[branch]["top"]]
     written = [(line, arrays) for line, arrays in written
                if arrays and arrays[0][0] not in _ALIASES]
-
-    def holding(least, below):
-        return [(line, arrays) for line, arrays in written
-                if any(d == plane and least <= n < below
-                       for _op, d, n in arrays)]
-
-    full = ROWS * width
-    whole = holding(full, np.inf)
-    assert len(whole) == 1, [line[:200] for line, _arrays in whole]
-    ((line, arrays),) = whole
-    assert ("fusion", "f32", ROWS) in arrays, line
-    panels = holding(panel, full)  # an empty range on one device
-    assert len(panels) <= 1, panels
+    # every array of the plane's type of the panel's size or more (the
+    # exact plane's scatter yields a flat array that a ``reshape`` names
+    # again in C's shape: one array)
+    held = [(line, arrays, n) for line, arrays in written
+            for op, d, n in arrays
+            if d == own_type and n >= panel and op != "reshape"]
+    builds = [own * width] + ([(ROWS - own) * width] if own < ROWS else [])
+    assert sorted(n for _line, _arrays, n in held) == sorted(builds), [
+        line[:200] for line, _arrays, _n in held]
+    assert sum(builds) == ROWS * width  # together: C's bytes, once
+    for line, arrays, _n in held:
+        assert arrays[0][0] == "fusion", line[:200]  # no slice, no copy
+        if plane != "exact":
+            assert "gram_count/dot_general" in line, line[:300]
+    if plane != "exact":
+        # u rides the own rows' build, and that build alone
+        (with_u,) = [n for _line, arrays, n in held
+                     if ("fusion", "f32", own) in arrays]
+        assert with_u == own * width
 
 
 # ---------------------------------------------------------------------------
@@ -834,17 +967,20 @@ def test_compiled_mesh_arms_program_is_hash2e20s_with_m_payloads(
     ``predict``, the write-back deltas ``[4, 524288]`` and ``[4, 4]`` in
     one all-reduce under ``writeback``. ``arm_map`` holds the ``dual_loop``
     (under its ``while``) and no ``predict`` / ``writeback`` / ``gram_*``.
-    In each fast plane's branch C is WRITTEN once, by the fusion that also
-    yields the four ``f32[2048]`` ``u_m = C·w_m`` (no read of C for any
-    arm's predict), its row panel at most once, and the panel is READ by
-    two operations: the G product and the ONE write-back pass, which
-    yields all four ``[524288]`` deltas; nothing of C's or the panel's size
-    is carried into a loop. And the whole of it reserves ``hash2e20``'s
-    temporaries (8,621,713,920 B as compiled: the three planes' count
-    matrices, once) and not M times them."""
+    In each fast plane's branch C is WRITTEN once, as TWO arrays (PR 54:
+    this shard's rows and the rest), the own rows' by the fusion that also
+    yields the four ``f32[1024]`` ``u_m = rows(C)·w_m`` (no read of C for
+    any arm's predict) and NO third array of the panel's size; the own
+    rows' array is READ by three operations — the two G products (against
+    itself, against the rest) and the ONE write-back pass, which yields
+    all four ``[524288]`` deltas — and the rest's by one; nothing of the
+    panel's size is carried into a loop. And the whole of it reserves
+    ``hash2e20``'s temporaries (6,465,938,432 B as compiled: the three
+    planes' count matrices, once; 8,621,713,920 B while the row panel was
+    a second array) and not M times them."""
     m, width = 4, 1 << 19
     text, temp = _compile_2x2_arms(topo)
-    assert 8 * 2**30 <= temp < 8.2 * 2**30
+    assert 6 * 2**30 <= temp < 6.1 * 2**30
     mine, single = _COLLECTIVE.findall(text), _COLLECTIVE.findall(
         tpu_steps("2x2"))
     assert len(mine) == len(single) >= 20, (len(mine), len(single))
@@ -869,7 +1005,7 @@ def test_compiled_mesh_arms_program_is_hash2e20s_with_m_payloads(
     both = [n for n in names if "arm_map" in n.split("/")
             and {"predict", "writeback"} & set(n.split("/"))]
     assert not both, both
-    full, panel = ROWS * width, ROWS // 2 * width
+    own, panel = ROWS // 2, ROWS // 2 * width
     for plane, took in zip(("f32", "bf16", "s8"), plane_branches(text)):
         mapped = [line for line in took["all"]
                   if "/arm_map/while/body/" in line]
@@ -879,21 +1015,21 @@ def test_compiled_mesh_arms_program_is_hash2e20s_with_m_payloads(
             "predict", "writeback", "gram_matmul", "gram_count")), plane
         grams = [line for line in once if "/gram_matmul/" in line
                  and re.search(r" convolution\(", line)
-                 and f"[{ROWS // 2},{ROWS}" in line]
-        assert len(grams) == 1, (plane, grams)
+                 and f"[{own},{own}" in line]
+        assert len(grams) == 2, (plane, grams)
         writers, readers, loops = _touching(took["top"], panel)
         assert not loops, (plane, loops)
         assert not any("/arm_map/" in line for line in writers + readers)
         if plane == "f32":
             continue   # the scatter build: its passes are the one-device's
-        build = [line for line in writers
-                 if any(n >= full for _op, _d, n in _results([line]))]
-        assert len(build) == 1 and "/gram_count/" in build[0], plane
-        assert _results(build).count(("fusion", "f32", ROWS)) == m, build
-        assert len(writers) <= 2, (plane, writers)
+        assert len(writers) == 2, (plane, writers)  # the two builds
+        assert all("/gram_count/" in line for line in writers), plane
+        assert sorted(_results([line]).count(("fusion", "f32", own))
+                      for line in writers) == [0, m], writers
         stages = sorted(
             re.search(r"branch_\d_fun/(\w+)/", line).group(1)
-            for line in readers if "dynamic_slice" not in line)
-        assert stages == ["gram_matmul", "writeback"], (plane, readers)
+            for line in readers)
+        assert stages == ["gram_matmul", "gram_matmul", "writeback"], (
+            plane, readers)
         (back,) = [line for line in readers if "/writeback/" in line]
         assert _results([back]).count(("fusion", "f32", width)) == m, back

@@ -472,7 +472,7 @@ def make_sgd_train_step(
                 if axis_name:
                     # [B_local, B_global] panel: the G matmul's FLOPs scale
                     # 1/shards (the count build replicates per shard — see
-                    # text_gram.left)
+                    # ops/gram.text_gram's ``branch``)
                     g_text = lax.all_gather(
                         g_text, axis_name, axis=0, tiled=True
                     )

@@ -31,7 +31,9 @@ The step contracts with it three times — ``u_text = C·w_text`` for the
 pre-update predictions (reduced in the epilogue of the product that writes
 C: no read of it), ``G_text = C·Cᵀ`` on the MXU, and
 ``Δw_text = Cᵀ·α`` at write-back — each at most one streamed read of C,
-whatever the row length L; no ``[B, L]`` gather from the ``[F]`` weights
+whatever the row length L (a mesh step, which contracts only ITS rows
+against all of them, has C built as two arrays — its rows, the rest — and
+slices nothing: ``text_gram``); no ``[B, L]`` gather from the ``[F]`` weights
 and no ``[B, L]`` scatter into them remains in the Gram basis (``ops/sparse.py``
 keeps both for the scatter loop, for serving, and as the references of the
 differential tests). The residual function enters only elementwise on
@@ -223,12 +225,23 @@ def text_gram(
 ):
     """What ``body`` makes of the batch's count matrix, and the plane it was
     built on: ``(body's result, plane)``. With ``body=CountPlane.gram`` that
-    is the text-feature Gram block G = X·Xᵀ ([B,B] f32), or the row slice
+    is the text-feature Gram block G = X·Xᵀ ([B,B] f32), or the row panel
     ``X[row_start:row_start+rows]·Xᵀ`` ([rows, B]) when ``rows`` > 0 — the
     building block sharded layouts use (each shard computes its row panel
     and/or its feature slice's partial G, then all-gathers/psums) — and
     ``plane`` the int32 index the switch took: 2 s8, 1 bf16, 0 exact (the
     gate is computed here, once per step; ops/quality.py carries it out).
+
+    A row panel is never CUT from a count matrix. With ``rows`` > 0 the
+    ``[B, L]`` pairs are rolled by ``−row_start`` along the rows (8 MB at
+    the cells' size, once, outside the switch) and the branch taken builds
+    two arrays from static slices of them: the caller's ``rows`` rows and
+    the ``B − rows`` after them, wrapping (``branch`` below has why).
+    ``CountPlane`` contracts the first as built and rolls nothing larger
+    than the ``[rows, B]`` panel back to the batch's order. The gate reads
+    the pairs as given, so its collectives carry what they always did.
+    With ``rows`` = 0 (every one-device caller) there is one array and no
+    roll: the program is the one this function always traced.
 
     ``body(counts)`` is all the switch's branch does with the count matrix
     it built: it is called INSIDE the branch of the plane taken with that
@@ -236,11 +249,12 @@ def text_gram(
     depend on the plane) is what comes out. The train steps pass the whole
     Gram basis — ``counts.dot`` for ``u``, ``counts.gram()``, the dual
     loop, ``counts.tdot`` for the write-back — so that every contraction
-    reads the ONE C of the batch, live from its build to the write-back in
-    the shape and layout the build wrote, and nothing typed by the plane
-    has to leave the switch. Under a mesh the body's collectives run
-    inside the branch: every shard enters the same one, because the index
-    is reduced over every axis it could differ on before the switch.
+    reads the ONE C of the batch (one array, or two under a row panel),
+    live from its build to the write-back in the shape and layout the
+    build wrote, and nothing typed by the plane has to leave the switch.
+    Under a mesh the body's collectives run inside the branch: every shard
+    enters the same one, because the index is reduced over every axis it
+    could differ on before the switch.
 
     The gate ladder and its proof are the module docstring's. Every rung
     reads the [B, L] token pairs, never the counts: row absolute
@@ -335,25 +349,38 @@ def text_gram(
                 ).astype(bool)
         vals_ok = rung1 | rung2
 
-    def left(x):
-        """This shard's rows of an array whose leading axis is the batch's
-        rows: C itself, as built (the G product's left operand, the
-        write-back's panel), or a ``[B]`` vector reduced from all of it.
-        The slice makes the G MATMUL's FLOPs — and the bytes ``tdot``
-        streams — scale 1/shards in sharded builds; the count build
-        itself is deliberately replicated per shard — the right operand
-        needs all B_global rows anyway, and all-gathering shard-local
-        count builds would move [B_global, F_local] bf16 (~0.5 GB at the
-        2^18 operating point) to save a build worth ~3% of the G matmul."""
         if rows:
-            return lax.dynamic_slice_in_dim(x, row_start, rows, axis=0)
-        return x
+            # this shard's rows first: a roll of the [B, L] PAIRS, so that
+            # each branch builds its own rows' counts and the rest's as two
+            # arrays and nothing of C's size is ever sliced (``branch``)
+            token_idx, val_f = (
+                jnp.roll(x, -row_start, axis=0) for x in (token_idx, val_f)
+            )
 
     def branch(build, **product):
         def run(i, v):
+            """The plane's count matrix, and ``body`` on it. Under a row
+            panel it is BUILT as two arrays, the caller's own rows and the
+            rest: the build is a batched product with the row as its batch
+            dim, so the two cost what one costs, and the G product, ``dot``
+            and ``tdot`` read the own rows' array as built. Rows sliced out
+            of ONE C are a second array of the panel's size written every
+            batch on the TPU: the G product contracts two dims and takes no
+            dynamic slice as a fused operand (PERF.md §6, PR 54). The panel
+            keeps the G MATMUL's FLOPs — and the bytes ``tdot`` streams —
+            at 1/shards in sharded builds; the count build itself is
+            deliberately replicated per shard — the right operand needs all
+            B_global rows anyway, and all-gathering shard-local count
+            builds would move [B_global, F_local] bf16 (~0.5 GB at the 2^18
+            operating point) to save a build worth ~3% of the G matmul."""
             with jax.named_scope("gram_count"):
-                c = build(i, v, f_text)  # exact in the plane's type
-            return body(CountPlane(c, left, f_text, **product))
+                # each array exact in the plane's type
+                if rows:
+                    c_own = build(i[:rows], v[:rows], f_text)
+                    c_rest = build(i[rows:], v[rows:], f_text)
+                else:
+                    c_own, c_rest = build(i, v, f_text), None
+            return body(CountPlane(c_own, c_rest, row_start, f_text, **product))
 
         return run
 
@@ -386,9 +413,17 @@ class CountPlane:
     over ALL trailing axes of C as given, and the ``[F]`` vectors take C's
     shape instead: ``w`` is zero-padded to ``k_hi·k_lo`` going in and the
     write-back cropped to ``f_text`` coming out (C is zero past ``f_text``,
-    every index being below it: the same sums). ``left`` is
-    ``text_gram.left``, this shard's rows of whatever has the batch's rows
-    leading (the identity on one device); every result is f32.
+    every index being below it: the same sums).
+
+    C comes as the arrays ``text_gram`` built: ``c_own``, the caller's
+    rows — on one device every row, and then ``c_rest`` is ``None`` and
+    each method traces to the one-array expression — and under a row panel
+    ``c_rest``, the rows after them in rolled order (the batch's row
+    ``row_start + rows + j``, wrapping, at ``j``). ``dot`` and ``tdot`` are
+    this shard's and read ``c_own`` alone; ``gram`` reads both and is the
+    one place ``row_start`` is used. No array of C's type is sliced,
+    concatenated or rolled (on the TPU each would be a second array of
+    that size written a batch: PERF.md §6, PR 54). Every result is f32.
 
     ``dot`` and ``tdot`` are multiply-and-reduce fusions with f32 operands
     (module docstring): C's element is converted in registers, ``w`` and
@@ -408,28 +443,30 @@ class CountPlane:
     a v5e in PR 50: 2 GiB a batch at 2^18 dims, what PR 28 found of any
     f32 C); and each model's sum stays the single model's own."""
 
-    def __init__(self, c, left, f_text: int, **product):
-        self.c = c  # every row, as built: the G product's right operand
-        self._left = left
+    def __init__(self, c_own, c_rest, row_start, f_text: int, **product):
+        self.c_own = c_own  # the caller's rows; on one device all of C
+        self.c_rest = c_rest  # the rows after them, in rolled order, or None
+        self._row_start = row_start  # where the own rows sit in the batch
         self._f_text = f_text
         self._product = product  # the G product's precision / result type
-        self._features = tuple(range(1, c.ndim))
+        self._features = tuple(range(1, c_own.ndim))
 
     def dot(self, w):
         """``rows(C)·w`` → ``[rows]``: the text half of ``u = Z·W_prev``
         for this shard's rows (a partial over its features under a
-        feature axis; the caller psums). Reduced over ALL rows of C and
-        then sliced, a ``[B]`` vector: the reduction can then sit in the
-        epilogue of the product that writes C, and costs no read of it.
+        feature axis; the caller psums). It reads the own rows' array
+        alone and sits in the epilogue of the product that writes it: no
+        read of C.
 
         ``w`` of shape ``[M, F]`` gives ``[M, rows]`` (class docstring):
         all M reductions ride that one epilogue."""
         if w.ndim == 2:
             return jnp.stack([self.dot(w_m) for w_m in w])
-        shape = self.c.shape[1:]
+        shape = self.c_own.shape[1:]
         w = jnp.pad(w, (0, math.prod(shape) - w.shape[0])).reshape(shape)
-        u = jnp.sum(self.c.astype(jnp.float32) * w[None], axis=self._features)
-        return self._left(u)
+        return jnp.sum(
+            self.c_own.astype(jnp.float32) * w[None], axis=self._features
+        )
 
     def tdot(self, alpha):
         """``rows(C)ᵀ·alpha`` → ``[F]``: the text half of ``Zᵀα`` from this
@@ -441,24 +478,34 @@ class CountPlane:
         ONE pass over the panel for all M."""
         if alpha.ndim == 2:
             return jnp.stack([self.tdot(a_m) for a_m in alpha])
-        panel = self._left(self.c).astype(jnp.float32)
+        panel = self.c_own.astype(jnp.float32)
         delta = jnp.sum(panel * jnp.expand_dims(alpha, self._features), axis=0)
         return delta.reshape(-1)[: self._f_text]
 
     def gram(self):
         """``rows(C)·Cᵀ`` → ``[rows, B]`` f32 on the MXU, exact on every
         plane (module docstring): one product contracting every feature
-        axis of both operands."""
+        axis of both operands — or, under a row panel, two, the own rows
+        against themselves and against the rest, side by side and rolled
+        back so that the columns are in the batch's order. Every entry is
+        the integer sum the one product gave it."""
         with jax.named_scope("gram_matmul"):
-            g = lax.dot_general(
-                self._left(self.c),
-                self.c,
-                ((self._features, self._features), ((), ())),
-                **self._product,
-            )
             # s8 plane: |G| ≤ (Σ|c_a|)·max|c_b| ≤ 127² < 2²⁴, the s32 →
-            # f32 cast is exact
-            return g.astype(jnp.float32)
+            # f32 casts are exact
+            g = self._against(self.c_own)
+            if self.c_rest is None:
+                return g
+            g = jnp.concatenate([g, self._against(self.c_rest)], axis=1)
+            return jnp.roll(g, self._row_start, axis=1)
+
+    def _against(self, c):
+        """``rows(C)·cᵀ`` → ``[rows, rows of c]`` f32."""
+        return lax.dot_general(
+            self.c_own,
+            c,
+            ((self._features, self._features), ((), ())),
+            **self._product,
+        ).astype(jnp.float32)
 
 
 @jax.named_scope("gram_matmul")

@@ -223,15 +223,17 @@ def _make_feature_sharded_step(
     write-back stays slice-local (this shard's rows × its feature slice)
     with one psum over ``data``. Everything that reads the slice's count
     matrix C runs inside the branch of the plane ``text_gram``'s gate takes
-    (PR 28): the predict partial ``rows(C·w_slice)`` → ``[B_local]`` in
-    place of the ``sparse_text_dot`` gather — taken over ALL rows of C and
-    then sliced, so the reduction sits in the epilogue of the product that
-    writes C and reads nothing (PR 30) — the G panel, the dual loop,
-    and the write-back delta ``rows(C)ᵀ·α_local`` → ``[f_text_local]`` in
-    place of the ``sparse_grad_text`` scatter, ``rows(C)`` being the row
-    panel the G product already slices: ONE array, cut from C on its
-    leading axis in the ``[B, k_hi, k_lo]`` the build wrote
-    (ops/gram.CountPlane). The collective inventory below is
+    (PR 28): the predict partial ``rows(C)·w_slice`` → ``[B_local]`` in
+    place of the ``sparse_text_dot`` gather — reduced in the epilogue of
+    the product that writes ``rows(C)``, so it reads nothing (PR 30) — the
+    G panel ``rows(C)·Cᵀ``, the dual loop, and the write-back delta
+    ``rows(C)ᵀ·α_local`` → ``[f_text_local]`` in place of the
+    ``sparse_grad_text`` scatter. ``rows(C)`` is an array of its own: the
+    slice's count matrix is BUILT as this data shard's rows and the rest,
+    two arrays in the ``[·, k_hi, k_lo]`` the build writes, and the panel
+    is two products side by side — nothing is cut from C, which on the
+    TPU was a second array of the panel's size written every batch (PR 54;
+    ops/gram.text_gram, CountPlane). The collective inventory below is
     UNCHANGED by that: the same psums and all-gathers, of the same sizes,
     under the same scopes — those from the predict psum to the write-back
     psum now sit inside the branch, which every shard enters together
@@ -334,8 +336,8 @@ def _make_feature_sharded_step(
                 shard's rows of u, its partial G panel, the dual loop and
                 the slice-local write-back."""
                 with jax.named_scope("predict"):
-                    # [B_local], over this slice: C·w over all rows in
-                    # the count build's epilogue, then this shard's
+                    # [B_local], over this slice: this shard's rows of
+                    # C·w, out of the epilogue of the build that writes them
                     part = counts.dot(w_text)
                     raw = (_psum(part, model_axis) + numeric @ w_num).astype(
                         dtype
