@@ -53,10 +53,20 @@ from benchmark.tests.test_hash2e20_grid4 import (  # noqa: F401
     test_program_flags_are_the_recorded_list as test_mesh_grid4_program_flags_are_the_recorded_list,
     test_readers_on_a_trace_made_by_hand as test_mesh_grid4_readers_on_a_trace_made_by_hand,
     test_the_cell_is_hash2e20_with_grid4s_four_recipes_on_its_rows,
-    test_the_four_chip_cells_are_these_two_of_eight,
+)
+from benchmark.tests.test_lasso2e18 import (  # noqa: F401
+    test_program_flags_are_the_recorded_list as test_lasso2e18_program_flags_are_the_recorded_list,
+    test_readers_on_a_trace_and_a_span_file_made_by_hand,
+    test_the_cell_is_hash2e18_trimmed_280_under_the_l1_updater,
+    test_the_cell_reports_its_controls_metrics_less_four_and_its_own_four,
+    test_the_limits_cannot_see_the_four_numeric_weights,
+    test_the_work_count_is_the_most_a_batch_reads_and_feeds_no_roofline,
+    # the two cases of files before it that a ninth cell makes stale, as
+    # they now read, under the names they had
+    test_the_four_chip_cells_are_these_two_of_nine as test_the_four_chip_cells_are_these_two_of_eight,
+    test_publish_ms_p95_lists_every_cell_and_a_cells_own_metrics_follow_it as test_the_metric_is_the_last_entry_and_every_cell_lists_it,
 )
 from benchmark.tests.test_publish_ms_p95 import (  # noqa: F401
-    test_the_metric_is_the_last_entry_and_every_cell_lists_it,
     # the mesh cell's case and grid4's case of these names as they now read:
     # a metric appended for every cell stands after a cell's own (the new
     # file's docstring; grid4's was restated once before, by PR 52)

@@ -196,4 +196,6 @@ def test_mesh_step_is_the_step_on_the_sliced_count_matrix(
     _same(weights, parent_weights, plane, (step, "weights"))
     for n, (out, parent_out) in enumerate(zip(outs, parent_outs)):
         for name, a, b in zip(out._fields, out, parent_out):
+            if a is None and b is None:
+                continue   # a leaf this learner does not have (``primal``)
             _same(a, b, plane, (step, n, name))

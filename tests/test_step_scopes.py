@@ -1033,3 +1033,52 @@ def test_compiled_mesh_arms_program_is_hash2e20s_with_m_payloads(
             plane, readers)
         (back,) = [line for line in readers if "/writeback/" in line]
         assert _results([back]).count(("fusion", "f32", width)) == m, back
+
+
+# ---------------------------------------------------------------------------
+# PR 55: the primal learner (``l1_reg``: MLlib's L1Updater), whose 50
+# iterations read the count matrix — compiled for the chip at the cell's
+# size, so that what the compiler refuses (a tiling, the kernel's VMEM) or
+# adds (a copy of C in front of the kernel) costs no chip time to find.
+
+def test_compiled_primal_step_reads_c_through_the_kernel_alone(topo):
+    """``lasso2e18``'s step on one v5e chip: on the bf16 and the s8 plane
+    the loop's body calls the Pallas pass (ONE ``tpu_custom_call`` a plane,
+    inside the ``while`` under ``primal_loop``, taking C as the count build
+    wrote it: no copy, no convert, no transpose of an array of C's size
+    anywhere in the program), the exact plane's two fusions instead; no G
+    product and nothing of the dual basis; ``u = C·w`` for the pre-update
+    margin still rides the count build's epilogue (the build's fusion is
+    the one writer of C and has the ``[ROWS]`` f32 result beside it)."""
+    from jax.sharding import SingleDeviceSharding
+
+    dev = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype, *_spec):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=dev)
+
+    step = make_sgd_train_step(
+        num_text_features=F_TEXT, num_iterations=50, step_size=0.005,
+        l1_reg=0.1, quality=True)
+    compiled = jax.jit(step, donate_argnums=0).lower(
+        shape((F_TEXT + 4,), jnp.float32), _ragged_shapes(shape)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    for line, plane in zip(sorted(calls, key=lambda ln: "s8[" in ln),
+                           ("bf16", "s8")):
+        assert f"{plane}[{ROWS},512,512]" in line
+        assert "/primal_loop/while/body/" in line and "/primal_pass/" in line
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    parts = {p for n in names for p in n.split("/")}
+    assert not {"gram_matmul", "dual_loop", "writeback"} & parts
+    assert not [line for line in text.splitlines() if re.search(
+        rf"= (bf16|s8)\[{ROWS},512,512\]\S* (copy|transpose)\(", line)]
+    builds = [line for line in text.splitlines() if " fusion(" in line
+              and re.search(rf"\(f32\[{ROWS}\]\S*, (bf16|s8)\[{ROWS},512,512\]",
+                            line)]
+    assert len(builds) == 2 and all("/gram_count/" in b for b in builds)
+    # the three planes' count matrices as hash2e18 reserves them, less G
+    assert 3.5 * 2**30 < compiled.memory_analysis().temp_size_in_bytes < (
+        4.2 * 2**30)

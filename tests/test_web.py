@@ -923,5 +923,9 @@ def test_benchmark_lint_passes_with_the_new_entry():
         assert entry[0]["moves"] == "batch_gap_ms_p95"
         assert entry[0]["workloads"] == [
             w["name"] for w in loaded["workloads"]]
-    assert loaded["per_layer"][-1]["name"] == "publish_ms_p95"
+    # the last metric appended for EVERY cell; what stands after it are
+    # metrics a later cell reports alone (PR 55: lasso2e18-trimmed-280's)
+    names = [m["name"] for m in loaded["per_layer"]]
+    after = loaded["per_layer"][names.index("publish_ms_p95") + 1:]
+    assert all(len(m["workloads"]) == 1 for m in after)
     assert manifest.lint() == []

@@ -609,6 +609,38 @@ def build_mesh(conf, what: str = "training", model_axis: bool = False):
     )
 
 
+# --l1Reg (MLlib's L1Updater): where the per-iteration pass over the count
+# matrix has no form yet, in a sentence each (build_model refuses with them)
+L1_REFUSALS = {
+    "arms": (
+        "--l1Reg with --tenantKey all: the arms share C and G and map only "
+        "the DUAL half of the basis (models/sgd.arms_dual_half), and the "
+        "L1Updater's soft threshold has no dual form; run one --l1Reg model"
+    ),
+    "tenants": (
+        "--l1Reg with --tenants > 1: the partitioned tenant plane maps the "
+        "whole step over the tenants (lax.map), and the primal pass over "
+        "each part's count matrix is not wired or tested there; run one "
+        "--l1Reg model"
+    ),
+    "multihost": (
+        "--l1Reg in a multi-host run: every one of the numIterations "
+        "rounds would all-reduce a [numTextFeatures] gradient across the "
+        "hosts; the primal pass runs on one device"
+    ),
+    "model_axis": (
+        "--l1Reg with --modelShards > 1: the feature-sharded step "
+        "(parallel/sharding.py) iterates in the Gram basis only; the primal "
+        "pass has no form under a model axis"
+    ),
+    "data_axis": (
+        "--l1Reg on a data mesh: each of the numIterations rounds would "
+        "psum a [numTextFeatures] gradient (50 a batch); the primal pass "
+        "runs on one device — pass --master local[1]"
+    ),
+}
+
+
 def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
     """Single-device fused learner on one chip; mesh-sharded learner when the
     backend exposes several devices (or local[N] caps a virtual CPU mesh) —
@@ -652,6 +684,21 @@ def build_model(conf, model_cls=StreamingLinearRegressionWithSGD):
     import os as _os
 
     force_plane = _os.environ.get("TWTML_FORCE_TENANT_PLANE") == "1"
+    l1 = float(getattr(conf, "l1Reg", 0.0) or 0.0)
+    if l1 > 0:
+        # MLlib's L1Updater (models/sgd.py primal_basis): one device, one
+        # model. Each refusal says what the per-iteration pass lacks there;
+        # nothing falls to L2 or to the scatter loop in silence.
+        if tenants > 1 or force_plane:
+            if getattr(conf, "tenantKey", "hash") == "all":
+                raise SystemExit(L1_REFUSALS["arms"])
+            raise SystemExit(L1_REFUSALS["tenants"])
+        if _jax.process_count() > 1:
+            raise SystemExit(L1_REFUSALS["multihost"])
+        if int(getattr(conf, "modelShards", 1) or 1) > 1:
+            raise SystemExit(L1_REFUSALS["model_axis"])
+        if mesh_shape(conf) > 1:
+            raise SystemExit(L1_REFUSALS["data_axis"])
     if tenants > 1 or (force_plane and tenants == 1):
         if getattr(conf, "tenantKey", "hash") == "lang" and conf.hashOn != "device":
             raise SystemExit(
@@ -2692,6 +2739,36 @@ def attach_pipeline(conf, stream, model, handle, stop_requested=None,
                 aggregate_tenant_output(out, batch, model), batch, t,
                 at_boundary=at_boundary,
             )
+
+    if float(getattr(conf, "l1Reg", 0.0) or 0.0) > 0:
+        # the primal learner's own view (--l1Reg; models/sgd.py
+        # primal_basis), from the [2] int32 leaf the ONE fetch brought:
+        # the rounds that ran before the freeze, and the share of text
+        # weights that are exactly zero — what an operator runs Lasso for
+        from ..ops.quality import QUALITY_INDEX as _QUALITY_INDEX
+
+        primal_inner = handle
+        n_text = int(conf.numTextFeatures)
+        reg = _metrics.get_registry()
+
+        def handle(out, batch, t, at_boundary=True):  # noqa: F811
+            primal = getattr(out, "primal", None)
+            if primal is not None:
+                ran, zeros = (int(v) for v in primal)
+                reg.gauge("model.primal_iterations").set(ran)
+                reg.gauge("model.weights_zero_share").set(
+                    round(zeros / n_text, 6)
+                )
+                # the plane the gate took rides the quality vector; -1
+                # without it (--modelWatch off) or outside fits_gram
+                plane = -1
+                if getattr(out, "quality", None) is not None:
+                    plane = int(out.quality[_QUALITY_INDEX["gram_plane"]])
+                _trace.get().instant(
+                    "primal", batch=_trace.current_batch(), iterations=ran,
+                    zero_weights=zeros, plane=plane,
+                )
+            primal_inner(out, batch, t, at_boundary=at_boundary)
 
     if modelwatch is not None and modelwatch.enabled:
         # model-watch adapter (ISSUE 8), wrapped OUTSIDE the tenant

@@ -143,6 +143,7 @@ class ConfArguments:
                 f"{self.blockWire!r}"
             )
         self.l2Reg: float = float(conf.get("l2Reg", "0.0"))
+        self.l1Reg: float = float(conf.get("l1Reg", "0.0"))
         self.convergenceTol: float = float(conf.get("convergenceTol", "0.001"))
         self.dtype: str = conf.get("dtype", "float32")
         self.checkpointDir: str = conf.get("checkpointDir", "")
@@ -423,6 +424,17 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                                                streaming (pre-compilable before the stream
                                                starts) and host hashing. Default: {self.wire}
   --l2Reg <float>                              L2 regularization. Default: {self.l2Reg}
+  --l1Reg <float>                              L1 regularization: MLlib's L1Updater (LassoWithSGD's)
+                                               in the updater's place — every weight soft-
+                                               thresholded by stepSize/sqrt(i) x l1Reg after each
+                                               gradient step, so the weights the stream does not
+                                               inform are exactly zero. The iterations then run
+                                               in the feature space itself (one pass over the
+                                               batch's count matrix an iteration; models/sgd.py
+                                               primal_basis), on one device. One updater a run:
+                                               refused with --l2Reg > 0; refused with
+                                               --tenantKey all and on any mesh.
+                                               Default: {self.l1Reg}
   --convergenceTol <float>                     SGD convergence tolerance. Default: {self.convergenceTol}
   --dtype <float32|bfloat16|float64>           Device dtype. Default: {self.dtype}
   --checkpointDir <path>                       Enable model checkpoint/resume
@@ -738,6 +750,7 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
         if not args:
             try:
                 self.tenant_recipes()  # the lists against --tenants, at once
+                self.updater()  # --l1Reg against --l2Reg
             except ValueError as exc:
                 raise SystemExit(str(exc))
             return self
@@ -818,6 +831,10 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
                 self.printUsage(1)
         elif flag == "--l2Reg":
             self.l2Reg = float(take())
+        elif flag == "--l1Reg":
+            self.l1Reg = float(take())
+            if self.l1Reg < 0:
+                self.printUsage(1)
         elif flag == "--convergenceTol":
             self.convergenceTol = float(take())
         elif flag == "--dtype":
@@ -1012,6 +1029,28 @@ Usage: python -m twtml_tpu.apps.linear_regression [options]
         if self.blockWire != "auto":
             return self.blockWire == "on"
         return self.effective_wire() == "ragged"
+
+    def updater(self) -> str:
+        """MLlib's ``GradientDescent`` has ONE updater a run:
+        ``SimpleUpdater`` (no regularization), ``SquaredL2Updater``
+        (``--l2Reg``) or ``L1Updater`` (``--l1Reg``). Both strengths at
+        once are an elastic net, which is none of the three: refused."""
+        if self.l1Reg > 0 and (
+            self.l2Reg > 0 or any(
+                float(v) > 0 for v in self.tenantL2Reg.split(",") if v.strip()
+            )
+        ):
+            raise ValueError(
+                f"--l1Reg {self.l1Reg} with --l2Reg {self.l2Reg}"
+                + (f" / --tenantL2Reg {self.tenantL2Reg}"
+                   if self.tenantL2Reg.strip() else "")
+                + ": MLlib's GradientDescent runs ONE updater (L1Updater or "
+                "SquaredL2Updater); an elastic net is neither — give one of "
+                "the two strengths"
+            )
+        if self.l1Reg > 0:
+            return "l1"
+        return "l2" if self.l2Reg > 0 else "simple"
 
     def tenant_recipes(self) -> "tuple[list[float], list[float]]":
         """(step sizes, L2 strengths), one of each per tenant in tenant

@@ -28,7 +28,13 @@ class StepOutput(NamedTuple):
     telemetry side channel riding the existing one-fetch-per-tick
     StepOutput transfer; ``None`` (an empty pytree — the default, and the
     ``--modelWatch off`` state) keeps the step program structurally
-    identical to the pre-quality program."""
+    identical to the pre-quality program.
+
+    ``primal`` (``--l1Reg``: MLlib's ``L1Updater``, models/sgd.py
+    ``primal_basis``) is ``[2]`` int32 for that learner alone — the
+    iterations that ran before the converged-freeze, and the text weights
+    that are exactly zero after the step — and ``None`` for every other,
+    whose output pytree and program stay what they were."""
 
     predictions: jnp.ndarray  # [B] rounded predictions (pre-update weights)
     count: jnp.ndarray  # scalar — valid rows in this batch (global if psum)
@@ -36,3 +42,4 @@ class StepOutput(NamedTuple):
     real_stdev: jnp.ndarray  # scalar — population stdev of labels
     pred_stdev: jnp.ndarray  # scalar — population stdev of rounded preds
     quality: Optional[jnp.ndarray] = None  # [QUALITY_WIDTH] side channel
+    primal: Optional[jnp.ndarray] = None  # [2] int32: iterations, zero weights

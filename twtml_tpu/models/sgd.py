@@ -12,6 +12,10 @@ MLlib semantics preserved:
 - per-iteration learning rate stepSize/√i, 1-indexed (SimpleUpdater);
 - L2: w scaled by (1 − η·λ) before the gradient step (SquaredL2Updater) when
   l2_reg > 0 (the reference runs regParam 0; BASELINE config #4 adds L2);
+- L1: the gradient step, then every coordinate soft-thresholded by η·λ
+  (L1Updater, LassoWithSGD's) when l1_reg > 0 — the one updater that is
+  not linear in w, so its iterations run in the feature space itself
+  (``primal_basis`` below), never in the Gram basis;
 - Bernoulli mini-batch sampling per iteration, seeded by iteration number
   (MLlib uses seed 42+i) — deterministic replay;
 - convergence tolerance on successive weight vectors:
@@ -89,13 +93,28 @@ def sgd_inner_loop(
     grad_and_count: Callable,
     norm_sq: Callable | None = None,
     vary_axis: str | None = None,
+    l1_reg: float = 0.0,
+    count_iterations: bool = False,
 ):
     """The MLlib GradientDescent iteration loop over an arbitrary weight
     pytree — the ONE place the parity-critical semantics live (1-indexed
-    eta = stepSize/√i, SquaredL2Updater pre-scale, Bernoulli sampling,
+    eta = stepSize/√i, the updater's rule, Bernoulli sampling,
     zero-sample skip, convergence test on successive weight vectors,
     converged-freeze). Both the single-device step below and the
     feature-sharded step (parallel/sharding.py) drive it.
+
+    The update rule is the run's ONE updater (``mllib.optimization.
+    Updater``): ``SimpleUpdater`` / ``SquaredL2Updater`` — ``w·(1 − η·λ₂) −
+    η·∇/n``, the first being the second at ``l2_reg`` 0 — or, with
+    ``l1_reg`` > 0 (a Python number: it picks the traced body),
+    ``L1Updater.compute`` to the letter: ``w' = w − η·∇/n``, then
+    ``sign(w')·max(|w'| − η·λ₁, 0)`` on EVERY leaf and coordinate (MLlib
+    thresholds the whole vector, the numeric weights too). With ``l1_reg``
+    0 the traced body is the one this loop always had.
+    ``count_iterations`` returns ``(weights, iterations)``: the rounds
+    that ran before the converged-freeze (MLlib's loop breaks there), an
+    int32 scalar carried beside the flag; False (every dual and scatter
+    caller) leaves the carry, hence the program, as it was.
 
     ``grad_and_count(w, sel)`` must return (gradient-sum pytree, selected
     count), already globally reduced across any mesh axes. ``norm_sq(a, b)``
@@ -107,6 +126,18 @@ def sgd_inner_loop(
     """
     dtype = jax.tree_util.tree_leaves(weights)[0].dtype
 
+    if l1_reg > 0:
+        def update(eta, denom):
+            def rule(wl, gl):  # L1Updater.compute
+                stepped = wl - eta * gl / denom
+                return jnp.sign(stepped) * jnp.maximum(
+                    jnp.abs(stepped) - eta * l1_reg, 0.0
+                )
+            return rule
+    else:
+        def update(eta, denom):  # SimpleUpdater / SquaredL2Updater
+            return lambda wl, gl: wl * (1.0 - eta * l2_reg) - eta * gl / denom
+
     if norm_sq is None:
         def norm_sq(a, b):
             return sum(
@@ -117,7 +148,7 @@ def sgd_inner_loop(
             )
 
     def body(i, carry):
-        w, converged = carry
+        w, converged, *ran = carry
         it = i + 1  # MLlib iterations are 1-indexed
         if mini_batch_fraction < 1.0:
             sel = mask * jax.random.bernoulli(
@@ -128,9 +159,7 @@ def sgd_inner_loop(
         grad_sum, count = grad_and_count(w, sel)
         denom = jnp.maximum(count, 1.0)
         eta = step_size / jnp.sqrt(jnp.asarray(it, dtype))
-        w_new = jax.tree_util.tree_map(
-            lambda wl, gl: wl * (1.0 - eta * l2_reg) - eta * gl / denom, w, grad_sum
-        )
+        w_new = jax.tree_util.tree_map(update(eta, denom), w, grad_sum)
         # zero sampled points → no update (MLlib warns and skips)
         w_new = jax.tree_util.tree_map(
             lambda nl, wl: jnp.where(count > 0, nl, wl), w_new, w
@@ -149,7 +178,10 @@ def sgd_inner_loop(
         w_out = jax.tree_util.tree_map(
             lambda wl, nl: jnp.where(converged, wl, nl), w, w_new
         )
-        return w_out, converged | conv_now
+        # a round that starts unfrozen ran (MLlib breaks AFTER the round
+        # that converged)
+        ran = [n + (~converged).astype(jnp.int32) for n in ran]
+        return (w_out, converged | conv_now, *ran)
 
     converged0 = jnp.array(False)
     if vary_axis:
@@ -170,8 +202,11 @@ def sgd_inner_loop(
     ))))
     if flag_axes:
         converged0 = lax.pcast(converged0, flag_axes, to="varying")
-    w_final, _ = lax.fori_loop(0, num_iterations, body, (weights, converged0))
-    return w_final
+    ran0 = (jnp.zeros((), jnp.int32),) if count_iterations else ()
+    w_final, _, *ran = lax.fori_loop(
+        0, num_iterations, body, (weights, converged0, *ran0)
+    )
+    return (w_final, ran[0]) if count_iterations else w_final
 
 
 def run_dual_loop(
@@ -330,6 +365,7 @@ def make_sgd_train_step(
     use_gram: bool | None = None,
     quality: bool = False,
     arms: bool = False,
+    l1_reg: float = 0.0,
 ):
     """Build the fused (weights, batch) → (new_weights, StepOutput) step.
 
@@ -405,7 +441,45 @@ def make_sgd_train_step(
     Across chips the arms run on the mesh WITH a model axis — the
     feature-sharded step (parallel/sharding.py ``arms``), which calls the
     same per-arm half, ``arms_dual_half``, with its reductions handed in.
+
+    ``l1_reg`` > 0 (``--l1Reg``: MLlib's ``L1Updater``) is the learner whose
+    iterations CANNOT run in the dual basis: the soft threshold acts on
+    every coordinate, after which ``w_t`` is no longer in ``span{w_prev,
+    rows of C}`` and there is no ``{c, α}``. Inside the same branch of the
+    same gate, on the same ONE count matrix, ``primal_basis`` stands in
+    ``dual_basis``'s place: the pre-update margin under ``predict`` as
+    above (the count build's epilogue), then ``sgd_inner_loop`` over the
+    pytree ``(w_text [F], w_num [4])`` under the scope ``primal_loop``,
+    every round of which reads C once (``CountPlane.primal_pass``: ``u =
+    C·w``, the residual and ``∇ = Cᵀr`` while a block of rows is on chip;
+    scope ``primal_pass``). No G, no ``gram_matmul``, no ``writeback``:
+    the loop's carry IS the new weights. ``primal_loop`` is a scope of
+    ``arm_map``'s kind, not a tenth of ``STAGE_SCOPES`` (the benchmark's
+    stage readers put it under ``other``; its own readers carry their
+    reduction). The step's output then carries ``StepOutput.primal``:
+    the rounds that ran before the freeze and the count of text weights
+    that are exactly zero after the step. Outside ``fits_gram`` (no dense
+    C) the same updater runs through the scatter loop. One device and one
+    model: a data axis would need a ``[F]`` psum an iteration and the arms'
+    per-arm half is dual by construction — both are refused here and, in
+    a sentence, at the entry point (apps/common.build_model). With
+    ``l1_reg`` 0 the output pytree, hence every standing program, is what
+    it was.
     """
+    if l1_reg and not arms and l2_reg:
+        raise ValueError(
+            "l1_reg with l2_reg: MLlib's GradientDescent runs ONE updater "
+            "(L1Updater or SquaredL2Updater), not an elastic net"
+        )
+    if l1_reg and (arms or axis_name):
+        raise ValueError(
+            "l1_reg (MLlib's L1Updater) runs on one device, one model: "
+            + ("the arms' per-arm half (arms_dual_half) is the DUAL half by "
+               "construction and the soft threshold has no dual form"
+               if arms else
+               "under a data axis every iteration would psum a [F] gradient "
+               "(50 a batch) and that pass has no form yet")
+        )
     if arms and axis_name:
         raise ValueError(
             "arms on the same rows under a data axis alone: this builder's "
@@ -457,7 +531,8 @@ def make_sgd_train_step(
 
         ``row_args`` are GLOBAL (the caller all-gathers the batch under a
         data axis); ``local_numeric`` is this shard's rows. Returns
-        (new weights, this shard's rows of ``u``, plane index)."""
+        (new weights, this shard's rows of ``u``, plane index, and under
+        ``l1_reg`` the rounds the primal loop ran, else None)."""
         token_idx, token_val, numeric, mask, labels = row_args
         dtype = weights.dtype
         rows = local_numeric.shape[0] if axis_name else 0
@@ -543,19 +618,56 @@ def make_sgd_train_step(
                 ).astype(dtype)
             return w_new, raw
 
-        (w_new, raw), plane = text_gram(
+        def primal_basis(counts):
+            """``dual_basis``'s stand-in under ``l1_reg``: MLlib's loop on
+            the weights themselves, C read once a round."""
+            w_text, w_num = whole
+            with jax.named_scope("predict"):
+                # the pre-update margin, as the dual basis takes it
+                raw = (counts.dot(w_text) + local_numeric @ w_num).astype(dtype)
+
+            def grad_and_count(w, sel):
+                w_text, w_num = w
+                with jax.named_scope("primal_pass"):
+                    grad_text, residual = counts.primal_pass(
+                        w_text,
+                        base=numeric @ w_num,
+                        labels=labels,
+                        sel=sel,
+                        residual_fn=residual_fn,
+                    )
+                return (grad_text, numeric.T @ residual), jnp.sum(sel)
+
+            with jax.named_scope("primal_loop"):
+                halves, ran = sgd_inner_loop(
+                    whole,
+                    num_iterations=num_iterations,
+                    step_size=step_size,
+                    mini_batch_fraction=mini_batch_fraction,
+                    l2_reg=0.0,
+                    l1_reg=l1_reg,
+                    convergence_tol=convergence_tol,
+                    mask=mask,
+                    sample_key=sampling_key(None, mini_batch_fraction),
+                    grad_and_count=grad_and_count,
+                    count_iterations=True,
+                )
+                w_new = jnp.concatenate(halves).astype(dtype)
+            return w_new, raw, ran
+
+        (w_new, raw, *ran), plane = text_gram(
             token_idx,
             token_val,
             f_text,
             row_start=lax.axis_index(axis_name) * rows if axis_name else None,
             rows=rows,
-            body=dual_basis,
+            body=primal_basis if l1_reg else dual_basis,
         )
         if axis_name:
             # every shard gated the same global rows: pmin only makes the
             # index statically invariant, like ``c`` in dual_scale_and_alpha
             plane = lax.pmin(plane, axis_name)
-        return w_new, raw, plane
+        return w_new, raw, plane, (ran[0] if ran else None)
 
     def train_step(weights, batch: FeatureBatch | UnitBatch | PackedBatch):
         dtype = weights.dtype
@@ -613,7 +725,7 @@ def make_sgd_train_step(
         )
 
         # ---- predict + stats with pre-update weights --------------------
-        w_new = raw = plane = None
+        w_new = raw = plane = ran = None
         if gram:
             # the Gram basis: the count matrix is built FIRST and the raw
             # margin u = Z·W_prev, G, the dual loop and the write-back all
@@ -628,7 +740,7 @@ def make_sgd_train_step(
                     lax.all_gather(a, axis_name, axis=0, tiled=True)
                     for a in row_args
                 )
-            w_new, raw, plane = _gram_sgd(weights, row_args, numeric)
+            w_new, raw, plane, ran = _gram_sgd(weights, row_args, numeric)
 
         def observe(raw):
             """One model's reported predictions and the five batch stats
@@ -653,6 +765,16 @@ def make_sgd_train_step(
                     axis_name=axis_name,
                 )
 
+        def primal_of(w_new, ran):
+            """``StepOutput.primal`` under ``l1_reg``: the rounds that ran
+            and the text weights that are exactly zero; None (the leaf of
+            every other learner) keeps the output pytree as it was."""
+            if not l1_reg:
+                return None
+            return jnp.stack([
+                ran, jnp.sum(w_new[:f_text] == 0, dtype=jnp.int32)
+            ])
+
         def finish(weights, eta, lam, w_new=None, raw=None):
             """One model's stats with its pre-update weights and, outside
             the Gram basis, its iterations: everything of the step that is
@@ -666,6 +788,7 @@ def make_sgd_train_step(
                 return w_new, StepOutput(
                     predictions=preds,
                     quality=quality_of(weights, w_new, raw, preds, plane),
+                    primal=primal_of(w_new, ran),
                     **stats,
                 )
 
@@ -685,14 +808,20 @@ def make_sgd_train_step(
                 step_size=eta,
                 mini_batch_fraction=mini_batch_fraction,
                 l2_reg=lam,
+                l1_reg=l1_reg,
                 convergence_tol=convergence_tol,
                 mask=mask,
                 sample_key=sampling_key(axis_name, mini_batch_fraction),
                 grad_and_count=grad_and_count,
+                count_iterations=bool(l1_reg),
             )
+            scatter_ran = None
+            if l1_reg:  # the same updater outside fits_gram (no dense C)
+                w_final, scatter_ran = w_final
             return w_final, StepOutput(
                 predictions=preds,
                 quality=quality_of(weights, w_final, raw, preds),
+                primal=primal_of(w_final, scatter_ran),
                 **stats,
             )
 
@@ -759,6 +888,7 @@ class StreamingSGDModel:
         use_sparse: bool | None = None,
         use_gram: bool | None = None,
         quality: bool = False,
+        l1_reg: float = 0.0,
     ) -> None:
         self.num_text_features = num_text_features
         self.dtype = dtype
@@ -776,6 +906,7 @@ class StreamingSGDModel:
             use_sparse=use_sparse,
             use_gram=use_gram,  # None=auto; False is the scatter-loop escape hatch
             quality=quality,  # --modelWatch: the in-step quality side channel
+            l1_reg=l1_reg,  # --l1Reg: MLlib's L1Updater, the primal basis
         )
         # donate weights: the update happens in-place in HBM
         self._step = jax.jit(step, donate_argnums=0)
@@ -788,6 +919,7 @@ class StreamingSGDModel:
             step_size=conf.stepSize,
             mini_batch_fraction=conf.miniBatchFraction,
             l2_reg=conf.l2Reg,
+            l1_reg=float(getattr(conf, "l1Reg", 0.0) or 0.0),
             convergence_tol=conf.convergenceTol,
             dtype=jnp.dtype(conf.dtype),
             quality=getattr(conf, "modelWatch", "off") == "on",

@@ -400,7 +400,8 @@ def text_gram(
 
 class CountPlane:
     """One plane's dense count matrix inside its branch of ``text_gram``'s
-    switch, as the three contractions the Gram basis runs with it. C is
+    switch, as the three contractions the Gram basis runs with it (and the
+    primal learner's one pass an iteration, ``primal_pass``). C is
     f32, bf16 or s8 by the plane and keeps, from its build to its last
     reader, the shape the build wrote: ``[B, k_hi, k_lo]`` from the one-hot
     builders (feature ``f`` at ``[hi, lo] = divmod(f, k_lo)``), ``[B, F]``
@@ -443,6 +444,10 @@ class CountPlane:
     a v5e in PR 50: 2 GiB a batch at 2^18 dims, what PR 28 found of any
     f32 C); and each model's sum stays the single model's own."""
 
+    # tests set this to run the primal pass's KERNEL, interpreted, inside a
+    # whole step off the chip (``primal_pass``); no entry point does
+    kernel_off_chip = False
+
     def __init__(self, c_own, c_rest, row_start, f_text: int, **product):
         self.c_own = c_own  # the caller's rows; on one device all of C
         self.c_rest = c_rest  # the rows after them, in rolled order, or None
@@ -481,6 +486,65 @@ class CountPlane:
         panel = self.c_own.astype(jnp.float32)
         delta = jnp.sum(panel * jnp.expand_dims(alpha, self._features), axis=0)
         return delta.reshape(-1)[: self._f_text]
+
+    def primal_pass(self, w, *, base, labels, sel, residual_fn):
+        """One PRIMAL iteration's pass over C (models/sgd.py
+        ``primal_basis``: MLlib's ``L1Updater``, whose iterate leaves the
+        dual basis): ``r = residual_fn(C·w + base, labels)·sel`` and ``∇ =
+        Cᵀr`` → ``(∇ [F], r [rows])``, both f32. ``w`` is the ``[F]`` text
+        weights, ``base`` what the caller adds to the text margin (the
+        numeric features' share), ``sel`` the round's row selection.
+
+        On the planes the one-hot builders write (``[B, k_hi, k_lo]``, bf16
+        or s8) and a TPU that is ONE streamed read of C: the Pallas kernel
+        of ops/primal_pass.py takes the rows in blocks and runs both
+        contractions while a block is on chip, ``w`` and ``∇`` resident in
+        C's own ``[k_hi, k_lo]`` shape (``w`` zero-padded going in, ``∇``
+        cropped coming out, as ``dot`` and ``tdot`` do). Anywhere else —
+        the exact plane's ``[B, F]`` f32 densify (a batch no cell's text
+        reaches), a batch that is not whole blocks of the kernel's 8 rows
+        (a ``--batchBucket`` that is no multiple of 8), and every platform
+        but the TPU — it is ``dot`` then ``tdot``: two reads. Which of the two a 3-D plane takes is decided
+        where the step is LOWERED (``lax.platform_dependent``), never by
+        asking which backend happens to be jax's default: a chip run cannot
+        measure the fallback, and a CPU run does not crawl through the
+        kernel's interpreter (6x the two fusions' time at 2^18 dims; the
+        kernel's own tests run it interpreted, at sizes that allow it, and
+        ``kernel_off_chip`` puts it into a whole step for them). The
+        kernel's sums are ``dot``'s and ``tdot``'s own products in another
+        order (tile by tile), to f32 rounding. One device only: it reads
+        ``c_own`` as ALL of C."""
+        if self.c_rest is not None:
+            raise ValueError(
+                "CountPlane.primal_pass under a row panel: the primal "
+                "iteration has no mesh form (a [F] psum an iteration)"
+            )
+
+        def two_reads(w, base, labels, sel):
+            r = residual_fn(self.dot(w) + base, labels) * sel
+            return self.tdot(r), r
+
+        from .primal_pass import ROWS, primal_pass
+
+        if self.c_own.ndim != 3 or self.c_own.shape[0] % ROWS:
+            return two_reads(w, base, labels, sel)
+
+        shape = self.c_own.shape[1:]
+
+        def one_read(interpret):
+            def run(w, base, labels, sel):
+                w = jnp.pad(w, (0, math.prod(shape) - w.shape[0]))
+                grad, r = primal_pass(
+                    self.c_own, w.reshape(shape), base, labels, sel,
+                    residual_fn=residual_fn, interpret=interpret,
+                )
+                return grad.reshape(-1)[: self._f_text], r
+            return run
+
+        return lax.platform_dependent(
+            w, base, labels, sel, tpu=one_read(False),
+            default=one_read(True) if self.kernel_off_chip else two_reads,
+        )
 
     def gram(self):
         """``rows(C)·Cᵀ`` → ``[rows, B]`` f32 on the MXU, exact on every
